@@ -29,6 +29,7 @@ from lingvo_tpu.core import py_utils
 from lingvo_tpu.core import quant_utils
 from lingvo_tpu.core.nested_map import NestedMap
 from lingvo_tpu.core.py_utils import WeightInit, WeightParams
+from lingvo_tpu.parallel import mesh as mesh_lib
 from lingvo_tpu.quant import kv as kv_quant
 
 _NEG_INF = -2.3819763e38  # lowest bf16-safe additive mask value / 100
@@ -54,6 +55,43 @@ def SegmentMask(q_segment_ids: jax.Array, k_segment_ids: jax.Array,
   """
   same = (q_segment_ids[:, :, None] == k_segment_ids[:, None, :])
   return jnp.where(same, 0.0, _NEG_INF).astype(dtype)[:, None, :, :]
+
+
+def _FlashUnderMesh(q, k, v, segment_ids, causal: bool):
+  """The fused flash kernel, split by hand where GSPMD would have to.
+
+  q/k/v: [b, t, n, h], segment_ids: [b, t] or None. Mosaic kernels are not
+  partitioned automatically: under an ambient mesh the TPU compiler refuses
+  the bare call ("wrap the call in a shard_map"). Attention is independent
+  per batch row and per head, so the split is exact: rows over 'data' and
+  heads over 'model', each where the axis is in the mesh and divides the
+  dim. Every other mesh axis sees a replicated call.
+  """
+  from lingvo_tpu.ops import flash_attention
+
+  def _Kernel(q, k, v, seg=None):
+    return flash_attention.FlashAttention(q, k, v, causal=causal,
+                                          segment_ids=seg)
+
+  mesh = mesh_lib.CurrentMesh()
+  if mesh is None:
+    return _Kernel(q, k, v, segment_ids)
+
+  def _Axis(name, dim):
+    return name if (name in mesh.axis_names
+                    and dim % mesh.shape[name] == 0) else None
+
+  spec = jax.sharding.PartitionSpec(
+      _Axis(mesh_lib.DATA_AXIS, q.shape[0]), None,
+      _Axis(mesh_lib.MODEL_AXIS, q.shape[2]), None)
+  args, specs = (q, k, v), (spec, spec, spec)
+  if segment_ids is not None:
+    args += (segment_ids,)
+    specs += (jax.sharding.PartitionSpec(spec[0], None),)
+  # check_vma off: the kernel does not declare which mesh axes it varies
+  # over (the setting ring_attention's and ulysses' shard_maps use)
+  return mesh_lib.ShardMap(_Kernel, mesh, in_specs=specs, out_specs=spec,
+                           check_vma=False)(*args)
 
 
 class PerDimScaleLayer(base_layer.BaseLayer):
@@ -305,7 +343,6 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       k = self.rotary.FProp(rt, k)
     q = self._ScaleQuery(theta, q)
     if use_flash:
-      from lingvo_tpu.ops import flash_attention
       # paddings/segment_ids both become the kernel's segment mask: padding
       # gets segment 0 (packed inputs already carry 0 there; enforce it so
       # pad keys never leak into real queries)
@@ -317,8 +354,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       # the kernel scales by 1/sqrt(h) internally; q already carries the
       # (learned) query scale, so cancel the kernel's factor.
       h = self._dim_per_head
-      ctx = flash_attention.FlashAttention(
-          q * math.sqrt(h), k, v, causal=causal, segment_ids=seg)
+      ctx = _FlashUnderMesh(q * math.sqrt(h), k, v, seg, causal)
       if paddings is not None:
         # strict path parity: flash pad queries attend only pad keys while
         # the einsum path lets them attend real keys — both garbage, but a

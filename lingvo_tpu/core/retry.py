@@ -3,8 +3,9 @@
 Re-designs the reference's `retry.py:27` (generic exponential-backoff
 decorator) and the error classification of `base_runner._RunLoop`
 (`base_runner.py:399-528`): transient infrastructure errors (Unavailable /
-Aborted / deadline / connection loss — the things a preempted TPU or flaky
-tunnel produce) are retryable, typically by restoring the last checkpoint;
+Aborted / deadline / connection loss — the things a preempted TPU or a lost
+host connection produce) are retryable, typically by restoring the last
+checkpoint;
 compilation and shape/type errors are programmer errors and fatal.
 """
 

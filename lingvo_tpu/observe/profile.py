@@ -24,10 +24,18 @@ ISSUE 12 pillar 3, two tools:
 
 from __future__ import annotations
 
+import collections
+import re
 import time
 from typing import Optional
 
 import jax
+
+# opcode of a collective instruction in optimized HLO text ("-start" is the
+# async form of the same operation; its "-done" half is not counted again)
+_COLLECTIVE_RE = re.compile(
+    r"= [^=\n]*?\b(all-reduce|all-gather|all-to-all|collective-permute|"
+    r"reduce-scatter)(?:-start)?\(")
 
 
 def ProfilerSupported() -> bool:
@@ -87,8 +95,16 @@ class ProfileWindow:
 
 def CompileInfo(compiled) -> dict:
   """XLA static-memory-plan facts of a Compiled object; every accessor is
-  version-guarded (memory_analysis is unavailable on some backends)."""
-  info = {}
+  version-guarded (memory_analysis is unavailable on some backends). Plus
+  two counts from the optimized HLO text: `tpu_custom_calls`, the Pallas
+  kernels the program holds — 0 where one was expected means its XLA twin
+  or interpret mode ran instead — and `collectives` by opcode, which says
+  whether a sharded program really talks across devices."""
+  text = compiled.as_text()
+  info = {
+      "tpu_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+      "collectives": dict(collections.Counter(_COLLECTIVE_RE.findall(text))),
+  }
   try:
     ma = compiled.memory_analysis()
     for rec_key, attr in (("temp_bytes", "temp_size_in_bytes"),

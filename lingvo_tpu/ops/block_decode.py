@@ -62,7 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
-    LANES, NEG_INF, _CompilerParams)
+    LANES, NEG_INF)
 from lingvo_tpu.ops.flash_decode import _DotF32, _Finish, _PageAttend
 
 
@@ -248,7 +248,7 @@ def _PallasBlockDecode(q, k_pool, v_pool, block_tables, seq_lens,
       kernel,
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((b, n, h), q.dtype),
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "arbitrary")),
       interpret=interpret,
   )(*operands)

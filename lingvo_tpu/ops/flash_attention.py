@@ -48,10 +48,6 @@ NEG_INF = -1.0e30
 LANES = 128
 SUBLANES = 8
 
-# jax 0.4.37 ships TPUCompilerParams; newer jax renames it CompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 # Off-TPU, the Pallas kernel runs in interpret mode (~8-10 ms per grid step
 # regardless of the compute inside); below this many T*N*H elements the
 # plain-XLA lowering wins outright — bench measured flash_speedup 0.798 at
@@ -244,7 +240,7 @@ def _FlashForward(q, k, v, seg, block_q: int, block_k: int, causal: bool,
           pltpu.VMEM((block_q, LANES), jnp.float32),
           pltpu.VMEM((block_q, h), jnp.float32),
       ],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel", "arbitrary")),
       interpret=interpret,
   )(*inputs)
@@ -383,7 +379,7 @@ def _FlashBackward(q, k, v, seg, out, lse, do, block_q: int, block_k: int,
           pltpu.VMEM((block_k, h), jnp.float32),
           pltpu.VMEM((block_k, h), jnp.float32),
       ],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel", "arbitrary")),
       interpret=interpret,
   )(*dkdv_inputs)
@@ -414,7 +410,7 @@ def _FlashBackward(q, k, v, seg, out, lse, do, block_q: int, block_k: int,
       in_specs=dq_specs,
       out_specs=pl.BlockSpec((1, block_q, h), lambda b, i, j: (b, i, 0)),
       scratch_shapes=[pltpu.VMEM((block_q, h), jnp.float32)],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel", "arbitrary")),
       interpret=interpret,
   )(*dq_inputs)

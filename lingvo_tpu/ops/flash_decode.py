@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
-    LANES, NEG_INF, SUBLANES, _CompilerParams)
+    LANES, NEG_INF, SUBLANES)
 
 
 def _DotF32(a, b, dims):
@@ -62,9 +62,14 @@ def _PageAttend(q, k_page, v_page, keep, m, l, acc):
   m/l: f32 [N, 1] running max / denominator, acc: f32 [N, H].
   Returns updated (m, l, acc). Both lowerings call exactly this, so the
   float-op sequence (and thus the bits) match across Pallas and XLA.
+
+  The two products are head-batched matrix-vector dots. Mosaic has no dot
+  whose left operand lacks a free dimension (contracting q[N, H] against
+  k_page[P, N, H] with N as the batch dim is refused by the TPU compiler),
+  so the single query row carries a unit free dim: [N, 1, H] x [P, N, H].
   """
-  # [N, H] x [P, N, H] -> [N, P], contraction over H, batch over N.
-  s = _DotF32(q, k_page, (((1,), (2,)), ((0,), (1,))))
+  # [N, 1, H] x [P, N, H] -> [N, 1, P], contraction over H, batch over N.
+  s = _DotF32(q[:, None, :], k_page, (((2,), (2,)), ((0,), (1,))))[:, 0]
   s = jnp.where(keep > 0.5, s, NEG_INF)                  # [N, P]
   m_cur = jnp.max(s, axis=-1, keepdims=True)             # [N, 1]
   m_new = jnp.maximum(m, m_cur)
@@ -74,8 +79,9 @@ def _PageAttend(q, k_page, v_page, keep, m, l, acc):
   p = jnp.exp(s - m_safe)                                # f32 [N, P]
   alpha = jnp.exp(m - m_new)                             # [N, 1]
   l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-  # [N, P] x [P, N, H] -> [N, H]: contraction over P, batch over N.
-  pv = _DotF32(p.astype(v_page.dtype), v_page, (((1,), (0,)), ((0,), (1,))))
+  # [N, 1, P] x [P, N, H] -> [N, 1, H]: contraction over P, batch over N.
+  pv = _DotF32(p.astype(v_page.dtype)[:, None, :], v_page,
+               (((2,), (0,)), ((0,), (1,))))[:, 0]
   acc_new = acc * alpha + pv
   return m_new, l_new, acc_new
 
@@ -209,7 +215,7 @@ def _PallasDecode(q, k_cache, v_cache, time_step, page_size: int,
       kernel,
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((b, n, h), q.dtype),
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "arbitrary")),
       interpret=interpret,
   )(t_arr, q, k_cache, v_cache, pad3)

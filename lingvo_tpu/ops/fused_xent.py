@@ -60,7 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
-    LANES, NEG_INF, SUBLANES, _CompilerParams)
+    LANES, NEG_INF, SUBLANES)
 
 _BIG_IDX = 2 ** 30  # plain int: jnp scalars would be captured consts in Pallas
 
@@ -252,6 +252,40 @@ def _FwdKernel(x_ref, w_ref, b_ref, lab_ref, lse_ref, llog_ref, sum_ref,
     amax_ref[:] = amax_scr[:]
 
 
+_MIB = 1 << 20
+# The compiler's default scoped-VMEM limit, and how far the kernel may raise
+# it: a v5e TensorCore has 128 MiB of VMEM (Google Cloud TPU documentation,
+# "TPU v5e"), the rest is left to the surrounding program.
+_VMEM_DEFAULT_BYTES = 16 * _MIB
+_VMEM_MAX_BYTES = 96 * _MIB
+
+
+def _VmemLimitBytes(rb: int, bs: int, d: int, x_itemsize: int,
+                    w_itemsize: int) -> int:
+  """Scoped VMEM `_FwdKernel` asks for at a [rb, d] x [bs, d] tile pair.
+
+  Pallas double-buffers every blocked operand and output; scratch and the
+  in-kernel temporaries are single:
+    inputs  2 * (rb*d*x_itemsize + bs*d*w_itemsize        x and weight tile
+                 + SUBLANES*bs*4 + rb*LANES*4)            bias, labels
+    outputs 2 * 4 * rb*LANES*4                            four stat columns
+    scratch 5 * rb*LANES*4                                five running stats
+    temps   6 * rb*bs*4          logits, masked logits, p, iota and the
+                                 one-hot / argmax selects, all f32 [rb, bs]
+  At rb=128, bs=1024, d=2048 that is 13 MiB with bf16 weights and 22 MiB
+  with f32 weights: the weight tile dominates, and the f32 one does not fit
+  the default. Raises where even the cap is too small."""
+  stat = rb * LANES * 4
+  inputs = rb * d * x_itemsize + bs * d * w_itemsize + SUBLANES * bs * 4 + stat
+  need = 2 * (inputs + 4 * stat) + 5 * stat + 6 * rb * bs * 4
+  if need > _VMEM_MAX_BYTES:
+    raise ValueError(
+        f"FusedXent Pallas tile [{bs}, {d}] x {w_itemsize} B needs "
+        f"{need / _MIB:.0f} MiB of VMEM, over the {_VMEM_MAX_BYTES // _MIB} "
+        "MiB the kernel may take; use a smaller block_size")
+  return max(need, _VMEM_DEFAULT_BYTES)
+
+
 def _PallasStats(x, w, b, labels, cfg: _Cfg, interpret: bool):
   """Pallas lowering of _XlaStats (row-tiled grid, stats in VMEM)."""
   m_rows, d = x.shape
@@ -287,8 +321,10 @@ def _PallasStats(x, w, b, labels, cfg: _Cfg, interpret: bool):
       out_shape=out_shape,
       scratch_shapes=[pltpu.VMEM((rb, LANES), jnp.float32)] * 4 + [
           pltpu.VMEM((rb, LANES), jnp.int32)],
-      compiler_params=_CompilerParams(
-          dimension_semantics=("parallel", "arbitrary")),
+      compiler_params=pltpu.CompilerParams(
+          dimension_semantics=("parallel", "arbitrary"),
+          vmem_limit_bytes=_VmemLimitBytes(
+              rb, bs, d, x.dtype.itemsize, w_pad.dtype.itemsize)),
       interpret=interpret,
   )(x, w_pad, b2, lab2)
   return (lse[:m_rows, 0], llog[:m_rows, 0],

@@ -40,7 +40,15 @@ def _BuildIfNeeded():
     with open(stamp) as fh:
       have = fh.read().strip()
   if not os.path.exists(_SO_PATH) or have != want:
-    subprocess.run(["make", "-C", _CC_DIR, "-s", "-B"], check=True)
+    try:
+      subprocess.run(["make", "-C", _CC_DIR, "-s", "-B"], check=True)
+    except (OSError, subprocess.CalledProcessError) as e:
+      raise RuntimeError(
+          "lingvo_tpu.ops.native: could not build the input-pipeline library "
+          f"in {_CC_DIR} ({e}). It is built on first use from the committed "
+          "sources and needs `make` and a C++17 compiler ($CXX, default "
+          "g++); only file-based input generators and the C++ tokenizers "
+          "use it.") from e
     with open(stamp, "w") as fh:
       fh.write(want)
 

@@ -58,7 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
-    LANES, NEG_INF, _CompilerParams)
+    LANES, NEG_INF)
 from lingvo_tpu.ops.flash_decode import _Finish, _PageAttend
 from lingvo_tpu.ops.block_decode import _DequantPages
 from lingvo_tpu.ops.block_decode import SupportedOnTpu  # noqa: F401  (same
@@ -253,7 +253,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
       kernel,
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((t, n, h), q.dtype),
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "arbitrary")),
       interpret=interpret,
   )(*operands)

@@ -55,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
-    LANES, SUBLANES, _CompilerParams)
+    LANES, SUBLANES, _DotF32)
 
 # Segment-boundary decay: see the masking contract in the module docstring.
 RESET_LOG = -60.0
@@ -123,29 +123,31 @@ def _ChunkBody(s_in, dl2, b_c, c_c, v_c):
   (per grid step) call exactly this, so the float-op sequence — and the
   bits, in interpret mode — match. Everything stays rank-2: TPU Mosaic
   has no appetite for 1-D vectors, and [Q, 1] broadcasts are free.
+
+  Mosaic lowers neither `cumsum` nor a [Q, 1] <-> [1, Q] transpose, so the
+  running sum and both row/column views of it are masked reductions over
+  [Q, Q] tiles: a diagonal mask moves a column onto a row exactly (one
+  nonzero per sum), a lower-triangular mask accumulates. Matmul operands
+  are contracted in place (`_DotF32`) instead of transposed first.
   """
-  cum = jnp.cumsum(dl2, axis=0)                        # [Q, 1]
-  # Inter-chunk: position t sees s_in through decay exp(cum_t).
-  y_inter = jnp.dot(c_c * jnp.exp(cum),                # [Q, S]
-                    s_in.T, precision=None,
-                    preferred_element_type=jnp.float32)  # [Q, H]
-  # Intra-chunk quadratic form: exp(cum_t - cum_t') (c_t . b_t'), t' <= t.
-  scores = jnp.dot(c_c, b_c.T, precision=None,
-                   preferred_element_type=jnp.float32)   # [Q, P]
-  dmat = cum - cum.swapaxes(0, 1)                        # [Q, P]
   q = dl2.shape[0]
   row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
   col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-  decay = jnp.exp(jnp.where(row >= col, dmat, _MASK_LOG))
-  y_intra = jnp.dot(scores * decay, v_c, precision=None,
-                    preferred_element_type=jnp.float32)  # [Q, H]
+  diag, lower = row == col, row >= col
+  dl_row = jnp.sum(jnp.where(diag, dl2, 0.0), axis=0, keepdims=True)  # [1, Q]
+  cum = jnp.sum(jnp.where(lower, dl_row, 0.0), axis=1, keepdims=True)  # [Q, 1]
+  cum_row = jnp.sum(jnp.where(diag, cum, 0.0), axis=0, keepdims=True)  # [1, Q]
+  # Inter-chunk: position t sees s_in through decay exp(cum_t).
+  y_inter = _DotF32(c_c * jnp.exp(cum), s_in, (1, 1))      # [Q, H]
+  # Intra-chunk quadratic form: exp(cum_t - cum_t') (c_t . b_t'), t' <= t.
+  scores = _DotF32(c_c, b_c, (1, 1))                       # [Q, P]
+  decay = jnp.exp(jnp.where(lower, cum - cum_row, _MASK_LOG))
+  y_intra = _DotF32(scores * decay, v_c, (1, 0))           # [Q, H]
   # State out: decay the incoming state across the whole chunk, add each
   # token's outer-product contribution decayed from its position to the end.
   tot = cum[-1:]                                         # [1, 1]
   w_tail = jnp.exp(tot - cum)                            # [Q, 1]
-  s_out = (jnp.exp(tot) * s_in
-           + jnp.dot((v_c * w_tail).T, b_c, precision=None,
-                     preferred_element_type=jnp.float32))  # [H, S]
+  s_out = jnp.exp(tot) * s_in + _DotF32(v_c * w_tail, b_c, (0, 0))
   return y_inter + y_intra, s_out
 
 
@@ -239,7 +241,7 @@ def _ChunkedPallas(decay_log, b_in, c_in, v, s0, chunk_size,
           jax.ShapeDtypeStruct((r, h, s_dim), jnp.float32),
       ],
       scratch_shapes=[pltpu.VMEM((h, s_dim), jnp.float32)],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "arbitrary")),
       interpret=interpret,
   )(dl, bb, cc, vv, s0)

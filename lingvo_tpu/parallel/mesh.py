@@ -234,59 +234,25 @@ def MeshContext(mesh: Mesh):
   """Enters `mesh` as the ambient mesh so PartitionSpec-based
   with_sharding_constraint hints (MoE dispatch, pipeline buffers) reach
   GSPMD. Use around jit calls: `with mesh_lib.MeshContext(mesh): ...`."""
-  set_mesh = getattr(jax, "set_mesh", None)
-  if set_mesh is not None:  # jax >= 0.6: ambient abstract mesh
-    return set_mesh(mesh)
-  # jax 0.4.x: the Mesh object itself is the context manager (physical
-  # mesh / pjit resource env), which with_sharding_constraint uses to
-  # resolve bare PartitionSpecs
-  return mesh
+  return jax.set_mesh(mesh)
 
 
 def CurrentMesh():
-  """The ambient mesh entered by MeshContext, or None.
-
-  Version-tolerant (the whole point — PR-7's shard_map MoE dispatch silently
-  deactivated on jax 0.4.x because only the abstract-mesh API was queried):
-  jax >= 0.6 exposes the ambient mesh as `jax.sharding.get_abstract_mesh()`;
-  on 0.4.x the Mesh context manager populates the pjit resource env
-  (`thread_resources.env.physical_mesh`) instead. Returns whichever is
-  active and non-empty.
-  """
-  try:
-    from jax.sharding import get_abstract_mesh
-    m = get_abstract_mesh()
-    if m is not None and tuple(m.axis_names):
-      return m
-  except Exception:
-    pass
-  try:  # jax 0.4.x: the physical mesh entered by MeshContext
-    from jax._src import mesh as _mesh_impl
-    m = _mesh_impl.thread_resources.env.physical_mesh
-    if m is not None and not m.empty:
-      return m
-  except Exception:
-    pass
-  return None
+  """The ambient mesh entered by MeshContext, or None."""
+  m = jax.sharding.get_abstract_mesh()
+  return m if tuple(m.axis_names) else None
 
 
 def ShardMap(fn, mesh=None, *, in_specs, out_specs, check_vma=None):
-  """Version-tolerant `shard_map` (jax >= 0.8 `jax.shard_map` with
-  `check_vma`; 0.4.x `jax.experimental.shard_map.shard_map` where the same
-  knob is called `check_rep`). mesh=None resolves the ambient mesh — raises
-  when there is none, since shard_map without a mesh cannot mean anything.
-  """
+  """`jax.shard_map` over `mesh`, or over the ambient mesh when None —
+  raises when there is none, since shard_map without a mesh cannot mean
+  anything."""
   if mesh is None:
     mesh = CurrentMesh()
     assert mesh is not None, "ShardMap outside a MeshContext"
-  try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-    kw = {} if check_vma is None else {"check_vma": check_vma}
-  except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-  return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw)
+  kw = {} if check_vma is None else {"check_vma": check_vma}
+  return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, **kw)
 
 
 def WithShardingConstraint(x, spec_or_names):

@@ -14,14 +14,6 @@ from __future__ import annotations
 import jax
 
 
-def _AxisSize(axis_name: str) -> int:
-  """jax.lax.axis_size where available (>=0.6); psum-of-ones otherwise."""
-  fn = getattr(jax.lax, "axis_size", None)
-  if fn is not None:
-    return fn(axis_name)
-  return jax.lax.psum(1, axis_name)
-
-
 def Shift(x, axis_name: str, offset: int = 1, wrap: bool = False):
   """Sends each shard's `x` to the neighbor `offset` steps up the axis.
 
@@ -29,7 +21,7 @@ def Shift(x, axis_name: str, offset: int = 1, wrap: bool = False):
   Without wrap, the lowest shards receive zeros (XLA's collective-permute
   semantics for unmatched targets) — the pipeline-fill behavior.
   """
-  n = _AxisSize(axis_name)
+  n = jax.lax.axis_size(axis_name)
   if wrap:
     perm = [(i, (i + offset) % n) for i in range(n)]
   else:

@@ -224,7 +224,7 @@ class ExecutorTpu:
 
     Failure taxonomy (ref `base_runner._RunLoop:399-528`): a transient
     infrastructure error (Unavailable/Aborted/deadline — a preempted chip or
-    dropped tunnel) restores the last checkpoint and continues, up to
+    lost host connection) restores the last checkpoint and continues, up to
     `max_train_retries` consecutive failures; anything else (compile errors,
     OOM, shape bugs) is fatal immediately.
     """

@@ -152,6 +152,12 @@ def main(argv=None):
     cluster.InitDistributed(
         coordinator_address=args.coordinator_address,
         num_processes=args.num_processes, process_id=args.process_id)
+  else:
+    # One process only: two processes that share a cache directory load each
+    # other's executables, and on the CPU's gloo collectives a program built
+    # by the other rank aborts with a size mismatch (tests/test_multiprocess).
+    from lingvo_tpu.core import compile_cache
+    compile_cache.Configure()
 
   model_params = model_registry.GetParams(args.model, "Train")
   if args.max_steps is not None:

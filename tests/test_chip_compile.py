@@ -1,0 +1,116 @@
+"""What the chip's compiler must accept, checked without a chip.
+
+The TPU compiler is installed wherever JAX is and compiles for a chip that
+is described and not attached (the `on-chip-measurement` guide, section 2).
+Every Pallas kernel of the train and serve paths is compiled here for one
+v5e chip at the widths `chip_smoke.py` runs them at: kernels that passed
+every interpret-mode test were refused by Mosaic for a dot it does not have,
+a primitive it does not lower and more VMEM than a kernel may take, and only
+a compile shows that. A compile that passes is not a chip run.
+
+Also reads the rehearsal of `chip_smoke.py --tiny` on the CPU, which
+`conftest.py` runs as a child beside the first test files (half a minute of
+tracing that tier-1's time cap has no room for in this process). The file's
+name sorts early on purpose: tier-1 stops at its cap, and a file past that
+point guards nothing.
+"""
+
+import concurrent.futures
+import json
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+
+import jax
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+
+_CASES = {case.name: case for case in chip_smoke.KernelCases(chip_smoke.REAL)}
+
+
+@pytest.fixture(scope="module")
+def compiles():
+  """{case name: Future of the compiled program's text}, all cases at once:
+  the compiler runs outside the interpreter lock, so eight threads finish in
+  a third of the time one would take."""
+  try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+  except Exception as e:  # noqa: BLE001 - no TPU compiler, or another holds it
+    pytest.skip(f"cannot describe a v5e topology: {e}")
+  one_chip = SingleDeviceSharding(topo.devices[0])
+  shapes = jax.eval_shape(
+      lambda key: chip_smoke.KernelInputs(chip_smoke.REAL, key),
+      jax.random.PRNGKey(0))
+
+  def _Compile(case):
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        shapes[case.inputs])
+    return jax.jit(case.fn(True)).lower(*args).compile().as_text()
+
+  # such a compile is written to the persistent cache but cannot be read
+  # back without a chip: the next run would warn and compile again
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    with concurrent.futures.ThreadPoolExecutor(8) as pool:
+      yield {name: pool.submit(_Compile, case)
+             for name, case in _CASES.items()}
+  finally:
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_kernel_compiles_for_v5e(name, compiles):
+  # raises what the chip's compiler would raise
+  assert "tpu_custom_call" in compiles[name].result(timeout=300)
+
+
+@pytest.fixture(scope="module")
+def tiny_lines(tiny_smoke):
+  returncode, out, err = tiny_smoke
+  assert returncode == 0, f"chip_smoke.py --tiny failed:\n{out}\n{err[-3000:]}"
+  return [json.loads(line) for line in out.strip().splitlines()]
+
+
+@pytest.mark.parametrize("phase", ["device", "kernels", "train", "serve"])
+def test_tiny_smoke_phase(phase, tiny_lines):
+  rows = [row for row in tiny_lines[:-1] if row["phase"] == phase]
+  assert len(rows) == 1 and rows[0]["ok"], rows
+  row = rows[0]
+  if phase == "kernels":
+    assert [c["name"] for c in row["cases"]] == list(_CASES)
+    assert all(c["max_abs_err"] <= c["tolerance"] for c in row["cases"])
+  elif phase == "train":
+    assert len(row["losses"]) == 2 and row["checkpoints_restored"] == [0, 4]
+  elif phase == "serve":
+    assert row["step_programs"] == 1 and row["first_tokens_checked"] >= 1
+    assert row["tokens_out"] == len(row["prompt_lens"]) * 4
+
+
+def test_tiny_smoke_last_line(tiny_lines):
+  assert [row["phase"] for row in tiny_lines[:-1]] == [
+      "device", "kernels", "train", "serve"]
+  last = tiny_lines[-1]
+  assert set(last) == {"ok", "device"} and last["ok"] is True
+  # the platform it really saw: a rehearsal never reads as a chip run
+  assert set(last["device"]) == {"platform", "kind", "count"}
+  assert last["device"]["platform"] == "cpu"
+
+
+def test_no_tpu_runs_no_phase(capsys):
+  """Without --tiny a CPU is a failure, at once: nothing is measured here."""
+  assert chip_smoke.main([]) != 0
+  lines = [json.loads(line)
+           for line in capsys.readouterr().out.strip().splitlines()]
+  assert [row.get("phase") for row in lines] == ["device", None]
+  assert not lines[0]["ok"] and "no TPU" in lines[0]["error"]
+  assert lines[-1] == {"ok": False, "device": {
+      "platform": "cpu", "kind": jax.devices()[0].device_kind,
+      "count": len(jax.devices())}}

@@ -119,7 +119,7 @@ class TestChunkedPrefill:
   def test_live_len_trimmed_read_matches_full_cache_read(self):
     """live_len only removes exact-zero (masked) softmax contributions, so
     the trimmed attention read must match the full-cache read, and the
-    written KV cache must be identical."""
+    written KV cache must agree to float rounding."""
     task = _TinyLm()
     theta = task.InstantiateVariables(jax.random.PRNGKey(0))
     b, p_len, total = 2, 6, 24
@@ -134,9 +134,16 @@ class TestChunkedPrefill:
     trimmed = jnp.concatenate([la, lb], axis=1)
     np.testing.assert_allclose(np.asarray(full), np.asarray(trimmed),
                                atol=2e-5)
+    # Not bitwise: the trimmed read sums 4 and 6 softmax terms where the
+    # full read sums 24 (18+ of them exact zeros), and XLA:CPU under jax
+    # 0.9.0 vectorizes the two reduction lengths in different orders, so
+    # layer-1 outputs — and with them the layer-2 K/V written here — differ
+    # in the last bit (1.8e-7 observed). The masked terms are still exact
+    # zeros; only the order of the live terms' additions changed.
     for fl, tl in zip(jax.tree_util.tree_leaves(full_states),
                       jax.tree_util.tree_leaves(trim_states)):
-      np.testing.assert_array_equal(np.asarray(fl), np.asarray(tl))
+      np.testing.assert_allclose(np.asarray(fl), np.asarray(tl), rtol=0,
+                                 atol=1e-6)
 
   def test_prefill_then_extend_matches_pure_extend_rollout(self):
     """End-to-end greedy: prefill + sampled ExtendSteps == all-ExtendStep."""

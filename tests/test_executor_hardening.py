@@ -167,7 +167,7 @@ class TestExecutorRecovery:
     sched, task, _ = _MakeScheduleAndTask(logdir)
 
     def _AlwaysDown(state):
-      raise RuntimeError("UNAVAILABLE: tunnel down")
+      raise RuntimeError("UNAVAILABLE: connection lost")
 
     sched.Run = _AlwaysDown
     ex = executor_lib.ExecutorTpu(_TaskParams(), logdir, schedule=sched,

@@ -107,8 +107,7 @@ def _DecodeTps(jax, jnp, task, theta, on_tpu):
                                length=steps)
     return out
 
-  t = bench._MarginalStepTime(lambda _: run(theta, prompts),
-                              lambda out: float(jnp.sum(out)), 2, 6)
+  t = bench._StepTime(lambda _: run(theta, prompts), 4)
   return {
       "prompt_len": p_len, "decode_steps": steps, "batch": b,
       "wall_ms": round(t * 1e3, 2),
@@ -158,7 +157,6 @@ def _Measure(jax, jnp, name, kv_cache_dtype, slots=8, budget_seq_len=4096):
 
 
 def main():
-  bench._EnsureBackend()
   import gc
   import jax
   import jax.numpy as jnp

@@ -86,9 +86,7 @@ def _DecodeTps(jax, jnp, task, theta, on_tpu):
                                length=steps)
     return out
 
-  reps = (2, 6) if on_tpu else (2, 6)
-  t = bench._MarginalStepTime(lambda _: run(theta, prompts),
-                              lambda out: float(jnp.sum(out)), *reps)
+  t = bench._StepTime(lambda _: run(theta, prompts), 4)
   return {
       "prompt_len": p_len, "decode_steps": steps, "batch": b,
       "wall_ms": round(t * 1e3, 2),
@@ -132,7 +130,6 @@ def _Measure(jax, jnp, model_registry, name, every_n,
 
 
 def main():
-  bench._EnsureBackend()
   import gc
   import jax
   import jax.numpy as jnp

@@ -37,8 +37,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402
-
 # (share_fraction, kv_cache_dtype) per variant
 VARIANTS = {
     "share0-bf16": (0.0, "bfloat16"),
@@ -157,7 +155,6 @@ def _Measure(jax, share, kv_cache_dtype):
 
 
 def main():
-  bench._EnsureBackend()
   import gc
   import jax
   names = sys.argv[1:] or list(VARIANTS)

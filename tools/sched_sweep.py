@@ -44,7 +44,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402
 from tools.fleet_report import JainFairness  # noqa: E402
 
 # (scheduler_mode, allow_preempt, load_scale) per variant; load_scale
@@ -178,7 +177,6 @@ def _Measure(jax, scheduler_mode, allow_preempt, load_scale):
 
 
 def main():
-  bench._EnsureBackend()
   import gc
   import jax
   names = sys.argv[1:] or list(VARIANTS)

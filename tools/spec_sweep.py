@@ -40,7 +40,6 @@ import bench  # noqa: E402
 
 
 def main():
-  bench._EnsureBackend()
   import jax
   import jax.numpy as jnp
   from lingvo_tpu import model_registry
