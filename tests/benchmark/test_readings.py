@@ -1,0 +1,78 @@
+"""How a run turns its window into one number."""
+
+import math
+
+import pytest
+
+from benchmarks.harness import readings as r
+
+
+def test_token_window_rate_counts_tokens_between_two_step_completions():
+  # a step every 0.5 s, 100 tokens a step; the window cuts steps at both ends
+  steps = [(0.5 * i, 100 * i) for i in range(1, 41)]
+  rate, tokens, seconds = r.TokenWindowRate(steps, 2.2, 17.9)
+  assert (tokens, seconds) == (100 * (35 - 5), 17.5 - 2.5)
+  assert rate == pytest.approx(200.0)
+
+
+def test_token_window_rate_counts_a_cut_request_for_what_it_got_done():
+  # one long request is mid-flight at both edges: nothing of it "finished"
+  # in the window, yet every token it did inside counts
+  steps = [(1.0, 0), (2.0, 512), (3.0, 1024), (4.0, 1536), (5.0, 1537)]
+  rate, tokens, _ = r.TokenWindowRate(steps, 1.5, 4.5)
+  assert tokens == 1024 and rate == pytest.approx(512.0)
+
+
+def test_token_window_rate_needs_two_completions():
+  with pytest.raises(ValueError):
+    r.TokenWindowRate([(1.0, 5)], 0.0, 2.0)
+
+
+def test_the_plain_total_keeps_a_stall_that_the_median_of_loops_ignores():
+  """train_tok_s is the plain total: all tokens over all the time."""
+  intervals = [1.4] * 9 + [2.8]          # one loop lost a whole loop's time
+  assert r.MedianOfLoops(intervals, 32768, 1) == pytest.approx(32768 / 1.4)
+  assert r.PlainTotal(intervals, 32768, 1) == pytest.approx(
+      32768 * 10 / (1.4 * 9 + 2.8))
+  assert r.PlainTotal(intervals, 32768, 1) < r.MedianOfLoops(
+      intervals, 32768, 1)
+  assert r.MedianOfLoops(intervals, 32768, 4) == pytest.approx(
+      32768 / 1.4 / 4)
+
+
+def test_the_window_is_a_whole_number_of_loops():
+  assert r.LoopsForWindow(30, 1.4) == math.ceil(30 / 1.4) == 22
+  assert r.LoopsForWindow(10, 1.4) == 10      # never under ten readings
+  assert r.LoopsForWindow(30, 1.5) == 20
+  comps = [10.0, 11.4, 12.8, 14.3]
+  assert r.Intervals(comps) == pytest.approx([1.4, 1.4, 1.5])
+
+
+@pytest.mark.parametrize("intervals,want", [
+    ([1.4, 1.41], False),                 # under three loops
+    ([9.0, 1.6, 1.4, 1.405], True),       # the last two agree to 1%
+    ([1.4, 1.4, 1.45], False),
+    ([1.4, 1.4, 1.4, 1.4], True),
+])
+def test_warm_up_ends_when_two_loops_agree(intervals, want):
+  assert r.WarmedUp(intervals, 3, 0.01) is want
+
+
+def test_percentile_and_jitter():
+  xs = list(range(1, 101))
+  assert r.Percentile(xs, 50) == pytest.approx(50.5)
+  assert r.Percentile(xs, 0) == 1 and r.Percentile(xs, 100) == 100
+  assert r.Percentile([7.0], 95) == 7.0
+  with pytest.raises(ValueError):
+    r.Percentile([], 50)
+  assert r.LoopJitter([1.0] * 20) == 0.0
+  assert r.LoopJitter([1.0] * 18 + [2.0] * 2) > 0.0
+
+
+def test_the_plain_total_is_all_tokens_over_the_whole_window():
+  comps = [100.0, 101.4, 102.8, 104.2, 107.0]     # the last loop stalled
+  intervals = r.Intervals(comps)
+  assert r.PlainTotal(intervals, 32768, 1) == pytest.approx(
+      4 * 32768 / (107.0 - 100.0))
+  assert r.PlainTotal(intervals, 32768, 4) == pytest.approx(
+      4 * 32768 / 7.0 / 4)
