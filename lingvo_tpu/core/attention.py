@@ -721,10 +721,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       # the front, so the update shape is [B, C, N] == the scale shape.
       k_new, k_s = kv_quant.QuantizeKv(k_new)              # int8, [B,C,N]
       v_new, v_s = kv_quant.QuantizeKv(v_new)
-      k_scale = cached_states.key_scale.at[phys, :, off].set(k_s)
-      v_scale = cached_states.value_scale.at[phys, :, off].set(v_s)
-    k_pool = k_pool.at[phys, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v_new.astype(v_pool.dtype))
+      with jax.named_scope("kv_write"):
+        k_scale = cached_states.key_scale.at[phys, :, off].set(k_s)
+        v_scale = cached_states.value_scale.at[phys, :, off].set(v_s)
+    with jax.named_scope("kv_write"):
+      k_pool = k_pool.at[phys, off].set(k_new.astype(k_pool.dtype))
+      v_pool = v_pool.at[phys, off].set(v_new.astype(v_pool.dtype))
     new_states = NestedMap(key=k_pool, value=v_pool)
     if quantized:
       new_states.key_scale = k_scale
@@ -813,10 +815,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if quantized:
       k_new, k_s = kv_quant.QuantizeKv(k_new)              # int8, [1,T,N]
       v_new, v_s = kv_quant.QuantizeKv(v_new)
-      k_scale = cached_states.key_scale.at[phys, :, off].set(k_s[0])
-      v_scale = cached_states.value_scale.at[phys, :, off].set(v_s[0])
-    k_pool = k_pool.at[phys, off].set(k_new[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v_new[0].astype(v_pool.dtype))
+      with jax.named_scope("kv_write"):
+        k_scale = cached_states.key_scale.at[phys, :, off].set(k_s[0])
+        v_scale = cached_states.value_scale.at[phys, :, off].set(v_s[0])
+    with jax.named_scope("kv_write"):
+      k_pool = k_pool.at[phys, off].set(k_new[0].astype(k_pool.dtype))
+      v_pool = v_pool.at[phys, off].set(v_new[0].astype(v_pool.dtype))
     new_states = NestedMap(key=k_pool, value=v_pool)
     if quantized:
       new_states.key_scale = k_scale
@@ -827,10 +831,11 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     # padding (the ragged op emits exact zeros there)
     q_end = jnp.where(valid, pos + 1, 0)
     if eligible:
-      ctx = ragged_block_attend.RaggedAttend(
-          q[0], k_pool, v_pool, block_tables, row, q_end,
-          page_size=page_size, k_scale=k_scale, v_scale=v_scale,
-          q_start=q_start, anc_lo=rows.anc_lo, anc_hi=rows.anc_hi)[None]
+      with jax.named_scope("ragged_attend"):
+        ctx = ragged_block_attend.RaggedAttend(
+            q[0], k_pool, v_pool, block_tables, row, q_end,
+            page_size=page_size, k_scale=k_scale, v_scale=v_scale,
+            q_start=q_start, anc_lo=rows.anc_lo, anc_hi=rows.anc_hi)[None]
     else:
       # gather-dense fallback at token granularity: each token is a batch
       # row of one query over its row's materialized cache view (handles

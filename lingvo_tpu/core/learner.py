@@ -102,6 +102,10 @@ class Learner(base_layer.BaseLayer):
   def Apply(self, theta: NestedMap, grads: NestedMap, step,
             opt_state: NestedMap) -> tuple[NestedMap, NestedMap, NestedMap]:
     """Returns (new_theta, new_opt_state, stats NestedMap)."""
+    with jax.named_scope("optimizer_update"):
+      return self._Apply(theta, grads, step, opt_state)
+
+  def _Apply(self, theta, grads, step, opt_state):
     p = self.p
     if p.grad_aggregation_fn is not None:
       grads = p.grad_aggregation_fn(grads)

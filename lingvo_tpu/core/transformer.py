@@ -68,26 +68,30 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
   def FProp(self, theta, inputs, paddings=None):
     p = self.p
     from lingvo_tpu.core import activations
-    x = self.ln.FProp(theta.ln, inputs)
-    h = self.ffn_in.FProp(theta.ffn_in, x)
-    act = activations.GetFn(p.activation)
-    if p.use_gated_activation:
-      h = act(h) * self.ffn_gate.FProp(theta.ffn_gate, x)
-    else:
-      h = act(h)
-    if p.relu_dropout_prob > 0:
-      h = self.dropout.FProp(
-          self.ChildTheta(theta, "dropout"), h,
-          keep_prob=1.0 - p.relu_dropout_prob, name_suffix="relu")
-    out = self.ffn_out.FProp(theta.ffn_out, h)
-    if p.residual_dropout_prob > 0:
-      out = self.dropout.FProp(
-          self.ChildTheta(theta, "dropout"), out,
-          keep_prob=1.0 - p.residual_dropout_prob, name_suffix="res")
-    if paddings is not None:
-      out = py_utils.ApplyPadding(paddings, out)
-    if p.add_skip_connection:
-      out = inputs + out
+    # named scopes: each device op's op_name in a profiler trace says which
+    # block it belongs to (metadata only; docs/observability.md)
+    with jax.named_scope("norm"):
+      x = self.ln.FProp(theta.ln, inputs)
+    with jax.named_scope("ffn"):
+      h = self.ffn_in.FProp(theta.ffn_in, x)
+      act = activations.GetFn(p.activation)
+      if p.use_gated_activation:
+        h = act(h) * self.ffn_gate.FProp(theta.ffn_gate, x)
+      else:
+        h = act(h)
+      if p.relu_dropout_prob > 0:
+        h = self.dropout.FProp(
+            self.ChildTheta(theta, "dropout"), h,
+            keep_prob=1.0 - p.relu_dropout_prob, name_suffix="relu")
+      out = self.ffn_out.FProp(theta.ffn_out, h)
+      if p.residual_dropout_prob > 0:
+        out = self.dropout.FProp(
+            self.ChildTheta(theta, "dropout"), out,
+            keep_prob=1.0 - p.residual_dropout_prob, name_suffix="res")
+      if paddings is not None:
+        out = py_utils.ApplyPadding(paddings, out)
+      if p.add_skip_connection:
+        out = inputs + out
     return out
 
 
@@ -121,22 +125,24 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
             atten_mask=None, segment_ids=None):
     """Self-attention when source_vecs is None; else cross-attention."""
     p = self.p
-    x = self.ln.FProp(theta.ln, query_vec)
-    if source_vecs is None:
-      # causality is passed as a flag (not a materialized mask) so the fused
-      # flash kernel can take over when eligible.
-      out, probs = self.atten.FProp(
-          theta.atten, x, paddings=paddings, atten_mask=atten_mask,
-          segment_ids=segment_ids, causal=p.is_masked)
-    else:
-      out, probs = self.atten.FProp(
-          theta.atten, x, key_vec=source_vecs, value_vec=source_vecs,
-          paddings=paddings, atten_mask=atten_mask)
-    if p.residual_dropout_prob > 0:
-      out = self.dropout.FProp(
-          self.ChildTheta(theta, "dropout"), out,
-          keep_prob=1.0 - p.residual_dropout_prob)
-    return query_vec + out, probs
+    with jax.named_scope("norm"):
+      x = self.ln.FProp(theta.ln, query_vec)
+    with jax.named_scope("atten"):
+      if source_vecs is None:
+        # causality is passed as a flag (not a materialized mask) so the
+        # fused flash kernel can take over when eligible.
+        out, probs = self.atten.FProp(
+            theta.atten, x, paddings=paddings, atten_mask=atten_mask,
+            segment_ids=segment_ids, causal=p.is_masked)
+      else:
+        out, probs = self.atten.FProp(
+            theta.atten, x, key_vec=source_vecs, value_vec=source_vecs,
+            paddings=paddings, atten_mask=atten_mask)
+      if p.residual_dropout_prob > 0:
+        out = self.dropout.FProp(
+            self.ChildTheta(theta, "dropout"), out,
+            keep_prob=1.0 - p.residual_dropout_prob)
+      return query_vec + out, probs
 
   def InitStates(self, theta, batch_size, max_len):
     return self.atten.InitStates(theta.atten, batch_size, max_len)
@@ -153,10 +159,12 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
 
   def _Step(self, method, theta, query_vec, cached_states, cache_paddings,
             **kw):
-    x = self.ln.FProp(theta.ln, query_vec)
-    out, new_states = getattr(self.atten, method)(
-        theta.atten, x, cached_states, paddings=cache_paddings, **kw)
-    return query_vec + out, new_states
+    with jax.named_scope("norm"):
+      x = self.ln.FProp(theta.ln, query_vec)
+    with jax.named_scope("atten"):
+      out, new_states = getattr(self.atten, method)(
+          theta.atten, x, cached_states, paddings=cache_paddings, **kw)
+      return query_vec + out, new_states
 
   def InitPagedStates(self, theta, num_pages, page_size, num_slots=0,
                       kv_cache_dtype=None):
@@ -173,30 +181,34 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
     (ssm.GatedSSMLayer.PagedStep); attention mixers ignore it (KV-page
     rollback is free — the write cursor is host-side and reads never
     pass q_pos + in_len)."""
-    x = self.ln.FProp(theta.ln, query_vec)
-    if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
-      out, new_states = self.atten.PagedStep(
-          theta.atten, x, cached_states, block_tables, q_pos, in_len,
-          collect_col_states=True)
-    else:
-      out, new_states = self.atten.PagedStep(
-          theta.atten, x, cached_states, block_tables, q_pos, in_len)
-    return query_vec + out, new_states
+    with jax.named_scope("norm"):
+      x = self.ln.FProp(theta.ln, query_vec)
+    with jax.named_scope("atten"):
+      if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
+        out, new_states = self.atten.PagedStep(
+            theta.atten, x, cached_states, block_tables, q_pos, in_len,
+            collect_col_states=True)
+      else:
+        out, new_states = self.atten.PagedStep(
+            theta.atten, x, cached_states, block_tables, q_pos, in_len)
+      return query_vec + out, new_states
 
   def RaggedStep(self, theta, query_vec, cached_states, block_tables, rows,
                  ssm_col_states: bool = False):
     """Packed-token continuous-batching step (core/ragged.py RaggedRows);
     query_vec [1, T, D]. Same pre-LN/residual wrapper and spec-verify
     dispatch as PagedStep — only the inner mixer contract changes."""
-    x = self.ln.FProp(theta.ln, query_vec)
-    if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
-      out, new_states = self.atten.RaggedStep(
-          theta.atten, x, cached_states, block_tables, rows,
-          collect_col_states=True)
-    else:
-      out, new_states = self.atten.RaggedStep(
-          theta.atten, x, cached_states, block_tables, rows)
-    return query_vec + out, new_states
+    with jax.named_scope("norm"):
+      x = self.ln.FProp(theta.ln, query_vec)
+    with jax.named_scope("atten"):
+      if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
+        out, new_states = self.atten.RaggedStep(
+            theta.atten, x, cached_states, block_tables, rows,
+            collect_col_states=True)
+      else:
+        out, new_states = self.atten.RaggedStep(
+            theta.atten, x, cached_states, block_tables, rows)
+      return query_vec + out, new_states
 
 
 class TransformerLayer(base_layer.BaseLayer):

@@ -264,18 +264,22 @@ class TransformerLm(base_model.BaseTask):
   def ComputePredictions(self, theta, input_batch):
     p = self.p
     ids = input_batch.ids
-    x = self.emb.EmbLookup(theta.emb, ids)
-    if not p.use_rotary:
-      pos = input_batch.Get("segment_pos")
-      if pos is not None:
-        pe = self.pos_emb.FProp(NestedMap(), position=pos.astype(jnp.float32))
-      else:
-        pe = self.pos_emb.FProp(NestedMap(), seq_length=ids.shape[1])[None]
-      x = x + pe.astype(x.dtype)
+    # named scopes at the block boundaries: op_name in a profiler trace
+    with jax.named_scope("embed"):
+      x = self.emb.EmbLookup(theta.emb, ids)
+      if not p.use_rotary:
+        pos = input_batch.Get("segment_pos")
+        if pos is not None:
+          pe = self.pos_emb.FProp(NestedMap(),
+                                  position=pos.astype(jnp.float32))
+        else:
+          pe = self.pos_emb.FProp(NestedMap(), seq_length=ids.shape[1])[None]
+        x = x + pe.astype(x.dtype)
     seg_ids = input_batch.Get("segment_ids")
     x = self.stack.FProp(theta.stack, x, paddings=input_batch.paddings,
                          segment_ids=seg_ids, token_ids=ids)
-    x = self.final_ln.FProp(theta.final_ln, x)
+    with jax.named_scope("norm"):
+      x = self.final_ln.FProp(theta.final_ln, x)
     if p.softmax_num_sampled > 0 and not py_utils.DoEval() and \
         py_utils.HasStepSeed():
       # training with a sampled softmax: defer to ComputeLoss (no [B,T,V]
@@ -286,12 +290,17 @@ class TransformerLm(base_model.BaseTask):
       # vocab; only full-distribution consumers (_FullLogits) pay for
       # dense logits
       return NestedMap(hidden=x)
-    logits = self.emb.Logits(theta.emb, x) if p.softmax_num_sampled == 0 \
-        else self.sampled_softmax.Logits(
-            self.ChildTheta(theta, "sampled_softmax"), x)
+    with jax.named_scope("head_loss"):
+      logits = self.emb.Logits(theta.emb, x) if p.softmax_num_sampled == 0 \
+          else self.sampled_softmax.Logits(
+              self.ChildTheta(theta, "sampled_softmax"), x)
     return NestedMap(logits=logits)
 
   def ComputeLoss(self, theta, predictions, input_batch):
+    with jax.named_scope("head_loss"):
+      return self._ComputeLoss(theta, predictions, input_batch)
+
+  def _ComputeLoss(self, theta, predictions, input_batch):
     p = self.p
     weights = py_utils.SequenceMask(input_batch.paddings)
     tot_weight = jnp.maximum(jnp.sum(weights), 1e-8)
@@ -472,16 +481,19 @@ class TransformerLm(base_model.BaseTask):
     ssm_col_states as in PagedStep (per-column state trajectories for
     spec-verify rollback, shaped [B, wmax, ...] here).
     """
-    x = self.emb.EmbLookup(theta.emb, ids)
+    with jax.named_scope("embed"):
+      x = self.emb.EmbLookup(theta.emb, ids)
     x, new_states = self.stack.RaggedStep(theta.stack, x, states,
                                           block_tables, rows,
                                           ssm_col_states=ssm_col_states)
-    x = self.final_ln.FProp(theta.final_ln, x)
-    if self.p.softmax_num_sampled > 0:
-      logits = self.sampled_softmax.Logits(
-          self.ChildTheta(theta, "sampled_softmax"), x)
-    else:
-      logits = self.emb.Logits(theta.emb, x)
+    with jax.named_scope("norm"):
+      x = self.final_ln.FProp(theta.final_ln, x)
+    with jax.named_scope("head_sample"):
+      if self.p.softmax_num_sampled > 0:
+        logits = self.sampled_softmax.Logits(
+            self.ChildTheta(theta, "sampled_softmax"), x)
+      else:
+        logits = self.emb.Logits(theta.emb, x)
     return logits, new_states
 
   def PagedStepPrefix(self, theta, ids, states, block_tables, q_pos, in_len,

@@ -143,7 +143,7 @@ COMPILE_CENSUS_KEY = "step_programs"
 # the router's class-aware load signal ("scheduler/queue_depth_high" in
 # registry snapshots: parked work ABOVE the default priority class).
 SCHEDULER_STATS_KEYS = frozenset({
-    "slots", "slots_live", "slots_prefill", "slots_live_peak", "queue_depth",
+    "slots", "slots_live", "slots_live_peak", "queue_depth",
     "admitted", "finished", "cancelled", "rejected_overlong",
     "needs_kv_pages", "prefix_ordered_admissions", "width_clamps",
     "scheduler_mode", "preemptions", "restores", "preempted_queued",
@@ -198,8 +198,9 @@ FLEET_STATS_KEYS = frozenset({
 
 # observe/trace.py TraceRecorder.Stats()
 TRACE_STATS_KEYS = frozenset({
-    "events_emitted", "events_buffered", "events_dropped",
+    "events_buffered", "events_dropped",
     "requests_open", "requests_completed",
+    "steps_recorded", "steps_buffered",
 })
 
 

@@ -24,6 +24,20 @@ same file into a latency table.
 Every Emit is a timestamp + deque append + a few record-field updates
 under one lock — no allocation-heavy formatting on the hot path; all
 derivation happens at export time.
+
+Beside the per-request record the recorder keeps a per-STEP record
+(`StepTrace`, one `StepDone()` call per engine step): where one iteration
+of the engine loop spent its host time, phase by phase (`STEP_SEGMENTS`),
+and how long the loop took to come back for it (`loop_s`). The engine
+measures the phases on this recorder's clock and opens a
+`jax.profiler.TraceAnnotation` of the same name (`lingvo/serve/<phase>`)
+around each, so a profiler trace shows the same spans on the host plane
+beside the device ops. Steps live in a deque of their own: token events
+cannot evict them. `ChromeTrace()` draws them as one more row, "engine
+loop", and carries them under `perStep`.
+
+Recorders register in a weak set: `Live()` is how a process's exporter
+(or a benchmark reader, which is handed no engine) finds them.
 """
 
 from __future__ import annotations
@@ -31,11 +45,79 @@ from __future__ import annotations
 import json
 import threading
 import time
+import weakref
 from typing import Optional
 
 # Chrome-trace row used for requests that were never admitted to a slot
 # (cancelled while queued). Real slots are tids 0..max_batch-1.
 _QUEUE_ONLY_TID = 10**6
+# Chrome-trace row of the per-step records.
+_ENGINE_LOOP_TID = 10**6 + 1
+
+# The segments of one engine step, in the order they happen; each is a
+# `lingvo/serve/<name>` span in a profiler trace too. `lock_wait` comes
+# twice (the engine lock is taken before admit and again before commit):
+# StepTrace.Phases() adds the two. `draft` is zero without a draft source.
+STEP_SEGMENTS = ("lock_wait", "admit", "build", "draft", "h2d", "dispatch",
+                 "device_wait", "lock_wait", "commit")
+STEP_PHASES = tuple(dict.fromkeys(STEP_SEGMENTS))
+
+_LIVE: "weakref.WeakSet[TraceRecorder]" = weakref.WeakSet()
+_LIVE_LOCK = threading.Lock()
+# The recorder that took the newest step record is kept reachable here, so
+# the steps that led up to an engine's end can still be read once the
+# engine itself is garbage (a post-mortem exporter, the benchmark's
+# readers). One reference, replaced by the next engine that steps.
+_last_stepped: Optional["TraceRecorder"] = None
+
+
+def Live() -> list:
+  """Every TraceRecorder of this process that is still alive, oldest
+  first. A recorder nobody references is forgotten, except the one that
+  recorded the newest engine step (see _last_stepped)."""
+  with _LIVE_LOCK:
+    return sorted(_LIVE, key=lambda r: r.epoch)
+
+
+class StepTrace:
+  """One engine step: when it started, how long the loop took to come back
+  for it, and the seconds spent in each of STEP_SEGMENTS (recorder-clock
+  seconds throughout)."""
+
+  __slots__ = ("step", "start_ts", "loop_s", "segments_s", "valid_tokens",
+               "prefill_tokens", "rows")
+
+  def __init__(self, step, start_ts, loop_s, segments_s, valid_tokens,
+               prefill_tokens, rows):
+    self.step = step
+    self.start_ts = start_ts
+    self.loop_s = loop_s
+    self.segments_s = segments_s
+    self.valid_tokens = valid_tokens
+    self.prefill_tokens = prefill_tokens
+    self.rows = rows
+
+  @property
+  def span_s(self) -> float:
+    """The `lingvo/serve/step` span: the segments tile it."""
+    return sum(self.segments_s)
+
+  @property
+  def end_ts(self) -> float:
+    return self.start_ts + self.span_s
+
+  def Phases(self) -> dict:
+    """{phase: seconds}, the two lock waits added."""
+    out = dict.fromkeys(STEP_PHASES, 0.0)
+    for name, s in zip(STEP_SEGMENTS, self.segments_s):
+      out[name] += s
+    return out
+
+  def Metrics(self) -> dict:
+    return {"step": self.step, "start_s": self.start_ts,
+            "span_s": self.span_s, "loop_s": self.loop_s,
+            "phases_s": self.Phases(), "valid_tokens": self.valid_tokens,
+            "prefill_tokens": self.prefill_tokens, "rows": self.rows}
 
 
 class RequestTrace:
@@ -118,8 +200,9 @@ class TraceRecorder:
   """Lock-cheap lifecycle recorder (module docstring).
 
   capacity: raw-event ring size. completed_capacity: retained completed
-  request records (oldest evicted first). clock: timestamp source —
-  injectable for deterministic tests.
+  request records (oldest evicted first). step_capacity: retained step
+  records (oldest evicted first; the default holds five minutes of 10 ms
+  steps). clock: timestamp source — injectable for deterministic tests.
   """
 
   # event kind -> record update, dispatched in Emit
@@ -127,16 +210,25 @@ class TraceRecorder:
            "spec_verify", "rollback", "retire")
 
   def __init__(self, capacity: int = 8192, completed_capacity: int = 4096,
-               clock=time.perf_counter):
+               clock=time.perf_counter, step_capacity: int = 32768):
     import collections
-    assert capacity >= 1 and completed_capacity >= 1
+    assert capacity >= 1 and completed_capacity >= 1 and step_capacity >= 1
     self._clock = clock
     self._lock = threading.Lock()
     self._ring = collections.deque(maxlen=capacity)
     self._open: dict = {}
     self._completed = collections.deque(maxlen=completed_capacity)
+    self._steps = collections.deque(maxlen=step_capacity)
     self._emitted = 0
+    self._steps_recorded = 0
     self.epoch = clock()
+    with _LIVE_LOCK:
+      _LIVE.add(self)
+
+  @property
+  def clock(self):
+    """The timestamp source; the engine times its step phases on it."""
+    return self._clock
 
   # -- emission (hot path; one lock, no formatting) --------------------------
 
@@ -213,6 +305,21 @@ class TraceRecorder:
   def Retire(self, req_id, reason: str, pages_freed: int = 0):
     self.Emit("retire", req_id, pages_freed, reason=reason)
 
+  def StepDone(self, step: int, start_ts: float, loop_s: float, segments_s,
+               valid_tokens: int = 0, prefill_tokens: int = 0,
+               rows: int = 0):
+    """Records one engine step: the one call a step costs. segments_s: the
+    seconds spent in each of STEP_SEGMENTS, which tile the step from
+    start_ts on; loop_s: from the previous step's end to start_ts."""
+    global _last_stepped
+    rec = StepTrace(step, start_ts, loop_s, tuple(segments_s), valid_tokens,
+                    prefill_tokens, rows)
+    assert len(rec.segments_s) == len(STEP_SEGMENTS), rec.segments_s
+    with self._lock:
+      self._steps.append(rec)
+      self._steps_recorded += 1
+    _last_stepped = self
+
   # -- reads -----------------------------------------------------------------
 
   def Events(self) -> list:
@@ -230,17 +337,23 @@ class TraceRecorder:
   def Get(self, req_id) -> Optional[RequestTrace]:
     return self.Requests().get(req_id)
 
+  def Steps(self) -> list:
+    """Retained StepTrace records, oldest first."""
+    with self._lock:
+      return list(self._steps)
+
   def PerRequestMetrics(self) -> dict:
     return {rid: rec.Metrics() for rid, rec in self.Requests().items()}
 
   def Stats(self) -> dict:
     with self._lock:
       return {
-          "events_emitted": self._emitted,
           "events_buffered": len(self._ring),
           "events_dropped": self._emitted - len(self._ring),
           "requests_open": len(self._open),
           "requests_completed": len(self._completed),
+          "steps_recorded": self._steps_recorded,
+          "steps_buffered": len(self._steps),
       }
 
   # -- Chrome trace-event export ---------------------------------------------
@@ -251,11 +364,14 @@ class TraceRecorder:
   def ChromeTrace(self) -> dict:
     """Chrome trace-event JSON (object form): one pid ("serving"), one tid
     per decode slot, per-request queued/prefill/decode duration pairs plus
-    spec-verify/rollback instants from the ring. Extra top-level key
-    `perRequest` carries the derived metrics (ignored by viewers, consumed
-    by tools/trace_report.py)."""
+    spec-verify/rollback instants from the ring, and one more row, "engine
+    loop", with every retained step and its segments as nested duration
+    pairs. Extra top-level keys `perRequest` and `perStep` carry the
+    derived metrics (ignored by viewers, consumed by
+    tools/trace_report.py)."""
     records = self.Requests()
     raw = self.Events()
+    steps = self.Steps()
     ev = [{"ph": "M", "pid": 0, "tid": 0, "name": "process_name",
            "args": {"name": "serving"}}]
     tids = {}
@@ -302,6 +418,26 @@ class TraceRecorder:
       ev.append({"ph": "i", "pid": 0, "tid": tid, "s": "t",
                  "name": f"{kind} req {req_id}", "cat": "serving",
                  "ts": self._Us(ts), "args": args})
+    per_step = []
+    if steps:
+      tids[_ENGINE_LOOP_TID] = "engine loop"
+    for st in steps:
+      per_step.append(st.Metrics())
+      # the step's B first and its E last, each segment's end computed
+      # the way the next one's start is: pairs nest after the sort below
+      row = {"pid": 0, "tid": _ENGINE_LOOP_TID, "cat": "serving"}
+      ev.append({"ph": "B", "name": f"step {st.step}",
+                 "ts": self._Us(st.start_ts), **row,
+                 "args": {"valid_tokens": st.valid_tokens,
+                          "prefill_tokens": st.prefill_tokens,
+                          "rows": st.rows, "loop_ms": st.loop_s * 1e3}})
+      t = st.start_ts
+      for name, dur in zip(STEP_SEGMENTS, st.segments_s):
+        if dur > 0:
+          _Span(name, _ENGINE_LOOP_TID, t, t + dur)
+        t += dur
+      ev.append({"ph": "E", "name": f"step {st.step}", "ts": self._Us(t),
+                 **row})
     for tid, label in sorted(tids.items()):
       ev.append({"ph": "M", "pid": 0, "tid": tid, "name": "thread_name",
                  "args": {"name": label}})
@@ -310,7 +446,7 @@ class TraceRecorder:
     phase_rank = {"M": -1, "E": 0, "B": 1, "i": 2}
     ev.sort(key=lambda e: (e.get("ts", -1), phase_rank.get(e["ph"], 3)))
     return {"traceEvents": ev, "displayTimeUnit": "ms",
-            "perRequest": per_request}
+            "perRequest": per_request, "perStep": per_step}
 
   def Export(self, path: str) -> dict:
     """Writes ChromeTrace() JSON to `path`; returns the trace dict."""

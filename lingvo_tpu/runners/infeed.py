@@ -213,10 +213,20 @@ class DeviceInfeed:
     # q/stop passed as args (not read from self): a Reset() from the
     # consumer swaps the members, and an abandoned producer must keep
     # honoring ITS stop event rather than the replacement's.
+    # spans: jax.profiler.TraceAnnotation on this thread's line of a
+    # profiler trace (a flag test when none is running); the time spent
+    # parked on the full queue lies under neither
+    import jax
     try:
-      for item in self._make_iter():
+      it = iter(self._make_iter())
+      while True:
+        with jax.profiler.TraceAnnotation("lingvo/infeed/produce"):
+          item = next(it, _EOS)
+        if item is _EOS:
+          break
         if self._place_in_producer:
-          item = self._place_fn(item)
+          with jax.profiler.TraceAnnotation("lingvo/infeed/place"):
+            item = self._place_fn(item)
         while not stop.is_set():
           try:
             q.put(item, timeout=0.2)
@@ -311,19 +321,14 @@ class DeferredTelemetry:
   lag dispatch by at most that many loops (docs/pipelined_executor.md).
   """
 
-  def __init__(self, name: str = "telemetry", registry: Any = None):
+  def __init__(self, name: str = "telemetry"):
     self._name = name
     self._pool: ThreadPoolExecutor | None = None
-    # optional job counter: how many deferred fetch/write jobs ran
-    self._jobs = (registry.Counter(f"infeed/{name}_jobs")
-                  if registry is not None else None)
 
   def Submit(self, fn: Callable[[], Any]) -> Future:
     if self._pool is None:
       self._pool = ThreadPoolExecutor(max_workers=1,
                                       thread_name_prefix=self._name)
-    if self._jobs is not None:
-      self._jobs.Inc()
     return self._pool.submit(fn)
 
   def Shutdown(self) -> None:

@@ -104,6 +104,7 @@ class BaseProgram:
     self._step_fn = None
     self._loop_fn = None
     self._run_count = 0
+    self._loops_run = 0   # the `loop` argument of the lingvo/train/loop span
     self._profiling_run = False
     # async-infeed machinery (runners/infeed.py), created lazily on the
     # first async Run so Compile() can pull warm-up batches without racing
@@ -605,8 +606,7 @@ class TrainProgram(BaseProgram):
     if self._telemetry is None:
       from lingvo_tpu.runners import infeed as infeed_lib
       self._telemetry = infeed_lib.DeferredTelemetry(
-          name=f"{self.p.name or 'train'}-telemetry",
-          registry=self.metrics)
+          name=f"{self.p.name or 'train'}-telemetry")
     return self._telemetry
 
   def _OnCompileRecord(self, name: str, rec: dict) -> None:
@@ -682,9 +682,14 @@ class TrainProgram(BaseProgram):
 
   def Run(self, state: NestedMap) -> tuple[NestedMap, dict[str, float]]:
     self._RefreshHostSchedules()
-    if not self.p.async_infeed:
-      return self._RunSync(state)
-    return self._RunAsync(state)
+    self._loops_run += 1
+    # spans: jax.profiler.TraceAnnotation, on the host plane of a profiler
+    # trace beside the device ops; a flag test when no trace is running
+    with jax.profiler.TraceAnnotation("lingvo/train/loop",
+                                      loop=self._loops_run):
+      if not self.p.async_infeed:
+        return self._RunSync(state)
+      return self._RunAsync(state)
 
   def _RunSync(self, state: NestedMap) -> tuple[NestedMap, dict[str, float]]:
     """The legacy fully-synchronous loop (p.async_infeed = False): host
@@ -697,17 +702,21 @@ class TrainProgram(BaseProgram):
     if p.on_device_loop:
       # host: prefetch + stack steps_per_loop batches; device: one program
       t_in = time.perf_counter()
-      batches = [self.input_generator.GetPreprocessedInputBatch()
-                 for _ in range(p.steps_per_loop)]
-      stacked = jax.tree_util.tree_map(
-          lambda *xs: np.stack(xs), *batches)
-      stacked = self._PutStackedBatch(stacked)
+      with jax.profiler.TraceAnnotation("lingvo/train/infeed_get"):
+        batches = [self.input_generator.GetPreprocessedInputBatch()
+                   for _ in range(p.steps_per_loop)]
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: np.stack(xs), *batches)
+      with jax.profiler.TraceAnnotation("lingvo/train/put_batch"):
+        stacked = self._PutStackedBatch(stacked)
       infeed_wait_s = time.perf_counter() - t_in
       fn = self._GetLoopFn(state)
       self._MaybePublishMfu(fn, state, stacked, steps=p.steps_per_loop)
       with self._MeshScope(), self._ProfilerScope():
-        state, acc, stats_acc = fn(state, stacked)
-        jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
+        with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+          state, acc, stats_acc = fn(state, stacked)
+        with jax.profiler.TraceAnnotation("lingvo/train/backpressure"):
+          jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
     else:
       fn = self._GetStepFn(state)
       acc = None
@@ -716,41 +725,49 @@ class TrainProgram(BaseProgram):
       with self._MeshScope(), self._ProfilerScope():
         for _ in range(p.steps_per_loop):
           t_in = time.perf_counter()
-          batch = self._PutBatch(
-              self.input_generator.GetPreprocessedInputBatch())
+          with jax.profiler.TraceAnnotation("lingvo/train/infeed_get"):
+            batch = self.input_generator.GetPreprocessedInputBatch()
+          with jax.profiler.TraceAnnotation("lingvo/train/put_batch"):
+            batch = self._PutBatch(batch)
           infeed_wait_s += time.perf_counter() - t_in
           self._MaybePublishMfu(fn, state, batch)
-          state, out = fn(state, batch)
-          acc = metrics_lib.AccumulateMetrics(acc, out.metrics)
-          stats_pairs = NestedMap(
-              {k: (v, 1.0) for k, v in out.stats.FlattenItems()})
-          stats_pairs.update(_ScalarSummaryPairs(out))
-          stats_acc = metrics_lib.AccumulateMetrics(stats_acc, stats_pairs)
+          with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+            state, out = fn(state, batch)
+          with jax.profiler.TraceAnnotation("lingvo/train/accumulate"):
+            acc = metrics_lib.AccumulateMetrics(acc, out.metrics)
+            stats_pairs = NestedMap(
+                {k: (v, 1.0) for k, v in out.stats.FlattenItems()})
+            stats_pairs.update(_ScalarSummaryPairs(out))
+            stats_acc = metrics_lib.AccumulateMetrics(stats_acc, stats_pairs)
         # One host sync per loop (ref: one session.run per steps_per_loop);
         # inside the profiler scope so traces capture the device work.
-        jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
+        with jax.profiler.TraceAnnotation("lingvo/train/backpressure"):
+          jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
     wall = time.time() - t0
     self._AttributeRunWall(t0, infeed_wait_s)
     t_tel = time.perf_counter()
-    result = metrics_lib.FinalizeMetrics(acc) if acc else {}
-    if stats_acc:
-      result.update(metrics_lib.FinalizeMetrics(stats_acc))
-    result["steps_per_second"] = p.steps_per_loop / wall
-    result["examples_per_second"] = (
-        p.steps_per_loop * self.input_generator.GlobalBatchSize() / wall)
-    step = int(jax.device_get(state.step))
-    # loop wall attribution (satellite of the async-infeed PR): input wait
-    # vs host-side telemetry fetch — on this path both sit on the critical
-    # path between device loops
-    result["infeed_wait_s"] = round(infeed_wait_s, 6)
-    result["host_overhead_s"] = round(
-        infeed_wait_s + (time.perf_counter() - t_tel), 6)
-    for k, v in self._InputStatsOf(self.input_generator).items():
-      result[f"input_{k}"] = v
-    # smoothed cross-Run rate incl. eval gaps (ref StepRateTracker:393)
-    result["global_steps_per_second"] = self._rate_tracker.Update(
-        step, self.input_generator.GlobalBatchSize())
-    self.WriteSummaries(step, result)
+    with jax.profiler.TraceAnnotation("lingvo/train/finalize"):
+      with jax.profiler.TraceAnnotation("lingvo/train/device_wait"):
+        result = metrics_lib.FinalizeMetrics(acc) if acc else {}
+        if stats_acc:
+          result.update(metrics_lib.FinalizeMetrics(stats_acc))
+      result["steps_per_second"] = p.steps_per_loop / wall
+      result["examples_per_second"] = (
+          p.steps_per_loop * self.input_generator.GlobalBatchSize() / wall)
+      step = int(jax.device_get(state.step))
+      # loop wall attribution (satellite of the async-infeed PR): input wait
+      # vs host-side telemetry fetch — on this path both sit on the critical
+      # path between device loops
+      result["infeed_wait_s"] = round(infeed_wait_s, 6)
+      result["host_overhead_s"] = round(
+          infeed_wait_s + (time.perf_counter() - t_tel), 6)
+      for k, v in self._InputStatsOf(self.input_generator).items():
+        result[f"input_{k}"] = v
+      # smoothed cross-Run rate incl. eval gaps (ref StepRateTracker:393)
+      result["global_steps_per_second"] = self._rate_tracker.Update(
+          step, self.input_generator.GlobalBatchSize())
+      with jax.profiler.TraceAnnotation("lingvo/train/summaries"):
+        self.WriteSummaries(step, result)
     self._NotifyLoopDone()
     return state, result
 
@@ -763,6 +780,7 @@ class TrainProgram(BaseProgram):
     window; the first Run blocks for its own)."""
     p = self.p
     t0 = time.time()
+    t_host0 = time.perf_counter()
     self._MarkRunStart()
     infeed = self._GetInfeed()
     wait0 = infeed.wait_s
@@ -778,15 +796,18 @@ class TrainProgram(BaseProgram):
         self._pipe_wait_mark = wait0
         self._pipe_compile_mark = self._goodput.CompileSeconds()
     if p.on_device_loop:
-      stacked = infeed.Get()
+      with jax.profiler.TraceAnnotation("lingvo/train/infeed_get"):
+        stacked = infeed.Get()
       if stacked is None:
         raise StopIteration("train input exhausted")
       if not infeed.places_batches:
-        stacked = self._PutStackedBatch(stacked)
+        with jax.profiler.TraceAnnotation("lingvo/train/put_batch"):
+          stacked = self._PutStackedBatch(stacked)
       fn = self._GetLoopFn(state)
       self._MaybePublishMfu(fn, state, stacked, steps=p.steps_per_loop)
       with self._MeshScope(), self._ProfilerScope():
-        state, acc, stats_acc = fn(state, stacked)
+        with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+          state, acc, stats_acc = fn(state, stacked)
         if self._profiling_run:
           # opt-in diagnostics: keep the device work inside the trace
           jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
@@ -796,23 +817,27 @@ class TrainProgram(BaseProgram):
       stats_acc = None
       with self._MeshScope(), self._ProfilerScope():
         for _ in range(p.steps_per_loop):
-          batch = infeed.Get()
+          with jax.profiler.TraceAnnotation("lingvo/train/infeed_get"):
+            batch = infeed.Get()
           if batch is None:
             raise StopIteration("train input exhausted")
           if not infeed.places_batches:
-            batch = self._PutBatch(batch)
+            with jax.profiler.TraceAnnotation("lingvo/train/put_batch"):
+              batch = self._PutBatch(batch)
           self._MaybePublishMfu(fn, state, batch)
-          state, out = fn(state, batch)
-          acc = metrics_lib.AccumulateMetrics(acc, out.metrics)
-          stats_pairs = NestedMap(
-              {k: (v, 1.0) for k, v in out.stats.FlattenItems()})
-          stats_pairs.update(_ScalarSummaryPairs(out))
-          stats_acc = metrics_lib.AccumulateMetrics(stats_acc, stats_pairs)
+          with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+            state, out = fn(state, batch)
+          with jax.profiler.TraceAnnotation("lingvo/train/accumulate"):
+            acc = metrics_lib.AccumulateMetrics(acc, out.metrics)
+            stats_pairs = NestedMap(
+                {k: (v, 1.0) for k, v in out.stats.FlattenItems()})
+            stats_pairs.update(_ScalarSummaryPairs(out))
+            stats_acc = metrics_lib.AccumulateMetrics(stats_acc, stats_pairs)
         if self._profiling_run:
           jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
     # host-side cost of this Run (input wait + placement + dispatch);
     # everything below the dispatch is off the critical path
-    host_overhead_s = time.time() - t0
+    host_overhead_s = time.perf_counter() - t_host0
     infeed_wait_s = infeed.wait_s - wait0
     queue_depth = infeed.QueueDepth()
     input_stats = self._InputStatsOf(self.input_generator)
@@ -844,7 +869,8 @@ class TrainProgram(BaseProgram):
       # loop's dispatch); first Run after a Flush blocks for its own — and
       # marks it consumed so Flush won't report it a second time
       self._pending_consumed = prev is None
-      result = (prev if prev is not None else fut).result()[1]
+      with jax.profiler.TraceAnnotation("lingvo/train/backpressure"):
+        result = (prev if prev is not None else fut).result()[1]
       self._AttributeRunWall(t0, infeed_wait_s)
       return state, result
     # k-deep dispatch window: sweep already-completed loops (free), then
@@ -855,10 +881,11 @@ class TrainProgram(BaseProgram):
     self._pending.append(fut)
     while self._pending and self._pending[0].done():
       self._PopPending()
-    while len(self._pending) > int(p.pipeline_depth):
-      self._PopPending()
-    if self._last_result is None:
-      self._PopPending()   # very first loop (or first after recovery)
+    with jax.profiler.TraceAnnotation("lingvo/train/backpressure"):
+      while len(self._pending) > int(p.pipeline_depth):
+        self._PopPending()
+      if self._last_result is None:
+        self._PopPending()   # very first loop (or first after recovery)
     self._last_result_consumed = True
     return state, self._last_result
 
@@ -895,28 +922,31 @@ class TrainProgram(BaseProgram):
     step_val is a host int under host-side step tracking (pipelined), else
     the loop's device step counter."""
     p = self.p
-    result = metrics_lib.FinalizeMetrics(acc) if acc else {}
-    if stats_acc:
-      result.update(metrics_lib.FinalizeMetrics(stats_acc))
-    wall = max(time.time() - t_start, 1e-9)
-    if pipelined:
-      # dispatch->completion spans queue time behind earlier in-flight
-      # loops; the completion-to-completion interval is the honest
-      # per-loop wall (and feeds the goodput step bucket)
-      wall = self._AttributePipelinedLoop()
-    result["steps_per_second"] = p.steps_per_loop / wall
-    result["examples_per_second"] = (
-        p.steps_per_loop * self.input_generator.GlobalBatchSize() / wall)
-    result["infeed_wait_s"] = round(infeed_wait_s, 6)
-    result["host_overhead_s"] = round(host_overhead_s, 6)
-    result["infeed_queue_depth"] = queue_depth
-    for k, v in input_stats.items():
-      result[f"input_{k}"] = v
-    step = (int(step_val) if isinstance(step_val, int)
-            else int(jax.device_get(step_val)))
-    result["global_steps_per_second"] = self._rate_tracker.Update(
-        step, self.input_generator.GlobalBatchSize())
-    self.WriteSummaries(step, result)
+    with jax.profiler.TraceAnnotation("lingvo/train/finalize"):
+      with jax.profiler.TraceAnnotation("lingvo/train/device_wait"):
+        result = metrics_lib.FinalizeMetrics(acc) if acc else {}
+        if stats_acc:
+          result.update(metrics_lib.FinalizeMetrics(stats_acc))
+      wall = max(time.time() - t_start, 1e-9)
+      if pipelined:
+        # dispatch->completion spans queue time behind earlier in-flight
+        # loops; the completion-to-completion interval is the honest
+        # per-loop wall (and feeds the goodput step bucket)
+        wall = self._AttributePipelinedLoop()
+      result["steps_per_second"] = p.steps_per_loop / wall
+      result["examples_per_second"] = (
+          p.steps_per_loop * self.input_generator.GlobalBatchSize() / wall)
+      result["infeed_wait_s"] = round(infeed_wait_s, 6)
+      result["host_overhead_s"] = round(host_overhead_s, 6)
+      result["infeed_queue_depth"] = queue_depth
+      for k, v in input_stats.items():
+        result[f"input_{k}"] = v
+      step = (int(step_val) if isinstance(step_val, int)
+              else int(jax.device_get(step_val)))
+      result["global_steps_per_second"] = self._rate_tracker.Update(
+          step, self.input_generator.GlobalBatchSize())
+      with jax.profiler.TraceAnnotation("lingvo/train/summaries"):
+        self.WriteSummaries(step, result)
     # stamped AFTER the summary write (the jsonl rows are keyed by step
     # already): lets executor metrics rows disambiguate the bounded lag
     result["at_step"] = step

@@ -80,6 +80,7 @@ from lingvo_tpu import observe
 from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.core import sampling
 from lingvo_tpu.observe import schema as observe_schema
+from lingvo_tpu.observe import trace as observe_trace
 from lingvo_tpu.quant import kv as kv_quant
 from lingvo_tpu.quant import weights as quant_weights
 from lingvo_tpu.serving import kv_cache
@@ -88,6 +89,87 @@ from lingvo_tpu.serving import scheduler as scheduler_lib
 from lingvo_tpu.serving import spec_decode
 
 _END = object()   # stream sentinel
+
+# The spans of one engine step. Each is a jax.profiler.TraceAnnotation: with a
+# profiler trace running it lands on the host plane of the same .xplane.pb as
+# the device ops, on the profiler's clock; with none it is a flag test.
+#   TraceAnnotation lingvo/serve/step         one StepOnce that launched
+#   TraceAnnotation lingvo/serve/lock_wait    both takings of the engine lock
+#   TraceAnnotation lingvo/serve/admit        _AdmitPhase
+#   TraceAnnotation lingvo/serve/build        Build*Step, block-table copy
+#   TraceAnnotation lingvo/serve/draft        the draft pass (spec engines)
+#   TraceAnnotation lingvo/serve/h2d          the jnp.asarray placements
+#   TraceAnnotation lingvo/serve/dispatch     _compile_log.Call returning
+#   TraceAnnotation lingvo/serve/device_wait  np.asarray(sampled, out, alen)
+#   TraceAnnotation lingvo/serve/commit       Commit*Step, counters, events
+_STEP_SPAN = "lingvo/serve/step"
+_SEGMENT_SPANS = {
+    "lock_wait": "lingvo/serve/lock_wait", "admit": "lingvo/serve/admit",
+    "build": "lingvo/serve/build", "draft": "lingvo/serve/draft",
+    "h2d": "lingvo/serve/h2d", "dispatch": "lingvo/serve/dispatch",
+    "device_wait": "lingvo/serve/device_wait",
+    "commit": "lingvo/serve/commit"}
+_SEGMENTS = observe_trace.STEP_SEGMENTS
+
+
+class _StepSpans:
+  """Where one engine step's host time goes: the step is cut into the
+  segments of observe.trace.STEP_SEGMENTS at the points the step code names
+  (`To`), each timed on the recorder's clock and wrapped in a
+  TraceAnnotation, and `End` writes the one StepTrace record. The segments
+  tile the step, and `loop` is the time since the previous step's end, so a
+  record adds up to the step's period. Engine-loop thread only."""
+
+  def __init__(self, recorder):
+    self._recorder = recorder
+    self._clock = recorder.clock if recorder is not None else time.perf_counter
+    self._last_end = None     # end of the previous recorded step
+    self._step_ann = None
+    self._seg_ann = None
+    self._acc = None
+
+  def Begin(self):
+    self._step_ann = jax.profiler.TraceAnnotation(_STEP_SPAN)
+    self._t0 = self._t_seg = self._clock()
+    self._acc = [0.0] * len(_SEGMENTS)
+    self._i = -1
+
+  def _EndSegment(self, now):
+    if self._i >= 0:
+      self._seg_ann.__exit__(None, None, None)
+      self._acc[self._i] += now - self._t_seg
+
+  def To(self, name: str):
+    """The step passes into segment `name` (the next one of that name)."""
+    now = self._clock()
+    self._EndSegment(now)
+    self._i = _SEGMENTS.index(name, max(self._i, 0))
+    self._t_seg = now
+    self._seg_ann = jax.profiler.TraceAnnotation(_SEGMENT_SPANS[name])
+
+  def _Close(self, **metadata):
+    now = self._clock()
+    self._EndSegment(now)
+    if metadata:
+      self._step_ann.set_metadata(**metadata)
+    self._step_ann.__exit__(None, None, None)
+    self._step_ann = self._seg_ann = None
+    return now
+
+  def Abandon(self):
+    """An iteration that launched nothing (or raised): no record; its time
+    counts as the next step's `loop`."""
+    if self._step_ann is not None:
+      self._Close()
+
+  def End(self, step: int, valid_tokens: int, prefill_tokens: int, rows: int):
+    now = self._Close(step=step, valid_tokens=valid_tokens,
+                      prefill_tokens=prefill_tokens, rows=rows)
+    loop_s = self._t0 - self._last_end if self._last_end is not None else 0.0
+    self._last_end = now
+    if self._recorder is not None:
+      self._recorder.StepDone(step, self._t0, loop_s, self._acc,
+                              valid_tokens, prefill_tokens, rows)
 
 
 class StreamHandle:
@@ -333,6 +415,7 @@ class ServingLoop:
                   else (observe.TraceRecorder() if trace else None))
     self._compile_log = observe.CompileLog(
         registry=self.metrics, namespace="serving/compile", donate=donate)
+    self._spans = _StepSpans(self.trace)
     # speculative decoding: the runner owns the draft + verify programs
     # and (for ModelDraft) the draft model's recurrent state
     self.spec = None
@@ -504,9 +587,10 @@ class ServingLoop:
         logits = logits[0]                                     # [T, V]
         key = jax.random.PRNGKey(base_key)
         row = jnp.clip(rows.row_of, 0, b - 1)
-        sampled = sampling.SampleFromLogits(
-            logits, key, temperature=temp, top_k=topk,
-            row_seeds=seeds[row], positions=pos[row])
+        with jax.named_scope("head_sample"):
+          sampled = sampling.SampleFromLogits(
+              logits, key, temperature=temp, top_k=topk,
+              row_seeds=seeds[row], positions=pos[row])
         return sampled, new_states
     elif spec_w == 1:
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos,
@@ -517,9 +601,10 @@ class ServingLoop:
         logits = logits[0]                                     # [T, V]
         key = jax.random.PRNGKey(base_key)
         row = jnp.clip(rows.row_of, 0, b - 1)
-        sampled = sampling.SampleFromLogits(
-            logits, key, temperature=temp, top_k=topk,
-            row_seeds=seeds[row], positions=pos[row])
+        with jax.named_scope("head_sample"):
+          sampled = sampling.SampleFromLogits(
+              logits, key, temperature=temp, top_k=topk,
+              row_seeds=seeds[row], positions=pos[row])
         # verify lane: each row's first spec_k+1 token columns, gathered
         # back to [B, k+1] — prefill/no-draft rows gather garbage that
         # draft_valid masks out of acceptance entirely
@@ -596,9 +681,10 @@ class ServingLoop:
         logits = logits[0]                                     # [T, V]
         key = jax.random.PRNGKey(base_key)
         row = jnp.clip(rows.row_of, 0, b - 1)
-        sampled = sampling.SampleFromLogits(
-            logits, key, temperature=temp, top_k=topk,
-            row_seeds=seeds[row], positions=pos[row])
+        with jax.named_scope("head_sample"):
+          sampled = sampling.SampleFromLogits(
+              logits, key, temperature=temp, top_k=topk,
+              row_seeds=seeds[row], positions=pos[row])
         # tree verify lane: draft node j = bi*k + d (the branch-major
         # draft layout) sits at packed column 1 + bi*row_k + d; rows
         # with clamped width/depth leave the tail invalid, so the
@@ -1069,9 +1155,13 @@ class ServingLoop:
     Legacy mode: pure-decode iterations where at least one row
     speculates become draft → verify → commit cycles; mixed steps (and
     all-opted-out batches) take the two-program path."""
-    if self.step_mode == "ragged":
-      return self._StepOnceRagged()
-    return self._StepOnceLegacy()
+    self._spans.Begin()
+    try:
+      if self.step_mode == "ragged":
+        return self._StepOnceRagged()
+      return self._StepOnceLegacy()
+    finally:
+      self._spans.Abandon()   # a no-op after the step's End
 
   def _AdmitPhase(self):
     """Evict + admit + per-admission bookkeeping (caller holds the lock)."""
@@ -1104,8 +1194,12 @@ class ServingLoop:
 
   def _StepOnceRagged(self) -> int:
     """One iteration through the unified ragged step program."""
+    spans = self._spans
+    spans.To("lock_wait")
     with self._lock:
+      spans.To("admit")
       self._AdmitPhase()
+      spans.To("build")
       spec_k = self.spec.k if self.spec is not None else 0
       spec_w = self.spec.w if self.spec is not None else 1
       batch = self.sched.BuildRaggedStep(self._ragged_t, self._ragged_wmax,
@@ -1119,6 +1213,7 @@ class ServingLoop:
     desc = batch.rows_desc
     q_logits = None
     if self.spec is not None:
+      spans.To("draft")
       if batch.any_spec:
         # draft outside the lock (device work), exactly like the legacy
         # spec cycle; the RaggedBatch speaks the StepBatch protocol with
@@ -1140,6 +1235,7 @@ class ServingLoop:
                             ] = d_toks[i, bi * spec_k:bi * spec_k + rk]
       else:
         q_logits = self._ZeroQLogits()
+    spans.To("h2d")
     rows_dev = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in desc))
     args = [self._theta, self._states, jnp.asarray(batch.tok_ids),
             rows_dev, jnp.asarray(tables), jnp.asarray(batch.row_seeds),
@@ -1150,15 +1246,21 @@ class ServingLoop:
       if self.spec.w > 1:
         args += [jnp.asarray(batch.row_w)]
       args += [q_logits]
+      spans.To("dispatch")
       sampled, out, alen, new_states = self._compile_log.Call(
           "ragged", self._ragged_fn, *args)
+      spans.To("device_wait")
       out, alen = np.asarray(out), np.asarray(alen)
     else:
+      spans.To("dispatch")
       sampled, new_states = self._compile_log.Call(
           "ragged", self._ragged_fn, *args)
+      spans.To("device_wait")
     self._states = new_states
     sampled = np.asarray(sampled)
+    spans.To("lock_wait")
     with self._lock:
+      spans.To("commit")
       if self.trace is not None and batch.mixed:
         # emit prefill-chunk spans BEFORE commit advances the cursors
         for i, seq in enumerate(batch.rows):
@@ -1196,12 +1298,18 @@ class ServingLoop:
       self._PushEvents(events)
       self._TickProfile()
       self._BeatWatchdog()
+    spans.End(self._counters["steps"].value, int(desc.row_len.sum()),
+              batch.prompt_tokens, sum(r is not None for r in batch.rows))
     return len(events)
 
   def _StepOnceLegacy(self) -> int:
     """One iteration through the legacy two-to-three-program engine."""
+    spans = self._spans
+    spans.To("lock_wait")
     with self._lock:
+      spans.To("admit")
       self._AdmitPhase()
+      spans.To("build")
       vbatch = None
       if self.spec is not None:
         vbatch = self.sched.BuildVerifyStep(self.spec.k)
@@ -1214,15 +1322,19 @@ class ServingLoop:
         window.Start()
     if vbatch is not None:
       return self._SpecCycle(vbatch, tables)
+    spans.To("h2d")
+    args = [jnp.asarray(a) for a in (batch.ids, batch.q_pos, batch.in_len,
+                                     tables, batch.row_seeds, batch.row_pos)]
+    spans.To("dispatch")
     sampled, new_states = self._compile_log.Call(
         "mixed" if batch.mixed else "decode", self._step_fn,
-        self._theta, self._states, jnp.asarray(batch.ids),
-        jnp.asarray(batch.q_pos), jnp.asarray(batch.in_len),
-        jnp.asarray(tables), jnp.asarray(batch.row_seeds),
-        jnp.asarray(batch.row_pos))
+        self._theta, self._states, *args)
+    spans.To("device_wait")
     self._states = new_states
     sampled = np.asarray(sampled)
+    spans.To("lock_wait")
     with self._lock:
+      spans.To("commit")
       if self.trace is not None and batch.mixed:
         # emit prefill-chunk spans BEFORE CommitStep advances the cursors:
         # row i consumed in_len[i] prompt tokens starting at q_pos[i]
@@ -1242,20 +1354,28 @@ class ServingLoop:
       self._PushEvents(events)
       self._TickProfile()
       self._BeatWatchdog()
+    spans.End(self._counters["steps"].value, int(batch.in_len.sum()),
+              batch.prompt_tokens, sum(r is not None for r in batch.rows))
     return len(events)
 
   def _SpecCycle(self, vbatch, tables) -> int:
     """Draft k tokens per row → ragged [B, k+1] verify → commit prefix."""
     spec = self.spec
+    spans = self._spans
+    spans.To("draft")
     d_toks, q_logits = spec.Draft(self._theta, self._states, vbatch, tables)
     ids = np.array(vbatch.ids)
     ids[:, 1:] = d_toks
     vbatch.ids = ids
+    spans.To("dispatch")    # Verify places its own arguments
     out, alen, new_states = spec.Verify(
         self._theta, self._states, ids, vbatch, tables, q_logits)
+    spans.To("device_wait")
     self._states = new_states
     out, alen = np.asarray(out), np.asarray(alen)
+    spans.To("lock_wait")
     with self._lock:
+      spans.To("commit")
       events = self.sched.CommitVerifyStep(vbatch, out, alen)
       self._counters["steps"].Inc()
       self._counters["decode_steps"].Inc()
@@ -1281,6 +1401,8 @@ class ServingLoop:
       self._PushEvents(events)
       self._TickProfile()
       self._BeatWatchdog()
+    spans.End(self._counters["steps"].value, int(vbatch.in_len.sum()), 0,
+              sum(r is not None for r in vbatch.rows))
     return len(events)
 
   def _PushEvents(self, events):

@@ -1111,7 +1111,6 @@ class Scheduler:
     return {
         "slots": self.max_slots,
         "slots_live": len(live),
-        "slots_prefill": sum(s.state is SeqState.PREFILL for s in live),
         "queue_depth": len(self.waiting),
         "admitted": self.admitted,
         "finished": self.finished,

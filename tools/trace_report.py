@@ -9,7 +9,11 @@ key (ignored by trace viewers) and prints:
 - a per-request table: slot, prompt/output tokens, pages, queue wait,
   TTFT, per-output-token latency, total, finish reason;
 - aggregate TTFT / TPOT / total-latency p50/p99;
-- a queue-wait histogram (how long requests sat before admission).
+- a queue-wait histogram (how long requests sat before admission);
+- where the file holds `perStep` (the engine's step records): the phase
+  table of the engine loop, median and p95 of each `lingvo/serve/*`
+  phase, of `loop`, and of the host's time between one step's results and
+  the next step's launch.
 
 With MULTIPLE trace files (one per serving replica) it prints a merged
 per-replica latency table instead — one row per file plus a fleet row
@@ -84,6 +88,51 @@ def Summary(trace: dict) -> dict:
   }
 
 
+# the host's work between one step's results arriving and the next step's
+# launch: this step's tail, the loop's turn-around, the next step's head
+_HOST_HEAD = ("lock_wait", "admit", "build", "draft", "h2d", "dispatch")
+
+
+def StepSummary(trace: dict) -> dict:
+  """Median and p95 (ms) over the file's step records: the step span, the
+  loop's turn-around, each phase, and `host`: commit of step n, loop, and
+  the phases of step n + 1 up to its launch. {} without perStep."""
+  steps = trace.get("perStep") or []
+  if not steps:
+    return {}
+
+  def _P(values):
+    arr = np.asarray(values, np.float64) * 1e3
+    return {"p50": round(float(np.percentile(arr, 50)), 4),
+            "p95": round(float(np.percentile(arr, 95)), 4)}
+
+  host = [a["phases_s"]["commit"] + b["loop_s"]
+          + sum(b["phases_s"][k] for k in _HOST_HEAD)
+          for a, b in zip(steps, steps[1:])]
+  return {
+      "steps": len(steps),
+      "span_ms": _P([s["span_s"] for s in steps]),
+      "loop_ms": _P([s["loop_s"] for s in steps]),
+      "phases_ms": {k: _P([s["phases_s"][k] for s in steps])
+                    for k in steps[0]["phases_s"]},
+      "host_ms": _P(host) if host else {"p50": 0.0, "p95": 0.0},
+  }
+
+
+def _StepTable(trace: dict) -> list:
+  s = StepSummary(trace)
+  if not s:
+    return []
+  lines = ["", f"engine steps: {s['steps']}",
+           f"  {'phase':<12} {'p50_ms':>10} {'p95_ms':>10}"]
+  rows = [("step span", s["span_ms"]), ("loop", s["loop_ms"])]
+  rows += list(s["phases_ms"].items())
+  rows.append(("host n->n+1", s["host_ms"]))
+  for name, p in rows:
+    lines.append(f"  {name:<12} {p['p50']:>10.3f} {p['p95']:>10.3f}")
+  return lines
+
+
 def _Ms(v) -> str:
   return "-" if v is None else f"{v * 1e3:.2f}"
 
@@ -117,6 +166,7 @@ def Report(trace: dict) -> str:
     for bound, n in hist:
       bar = "#" * round(40 * n / peak)
       lines.append(f"  <= {bound:>9.3f} ms  {n:>4}  {bar}")
+  lines.extend(_StepTable(trace))
   return "\n".join(lines)
 
 
