@@ -85,6 +85,11 @@ class Size:
   ssd_heads: int
   ssd_state: int
   ssd_chunk: int
+  # the ragged pack: block-table rows, packed width, the prefill chunk's
+  # tokens (REAL: the serving step of DenseLm1B, 32 slots + a 512 budget)
+  ragged_rows: int
+  ragged_t: int
+  ragged_chunk: int
 
 
 REAL = Size(
@@ -93,7 +98,8 @@ REAL = Size(
     prompt_lens=(64, 600), new_tokens=32,
     n=16, h=128, d=2048, vocab=32000, xent_block=1024, b=8, t=1024,
     pool_pages=512, table_pages=16, cache_len=2048,
-    ssd_heads=8, ssd_state=128, ssd_chunk=64)
+    ssd_heads=8, ssd_state=128, ssd_chunk=64,
+    ragged_rows=32, ragged_t=544, ragged_chunk=300)
 
 TINY = Size(
     model=TINY_MODEL, train_layers=None, steps_per_loop=2, interpret=True,
@@ -101,7 +107,8 @@ TINY = Size(
     prompt_lens=(4, 24), new_tokens=4,
     n=2, h=16, d=32, vocab=96, xent_block=32, b=2, t=32,
     pool_pages=16, table_pages=4, cache_len=32,
-    ssd_heads=2, ssd_state=8, ssd_chunk=8)
+    ssd_heads=2, ssd_state=8, ssd_chunk=8,
+    ragged_rows=4, ragged_t=24, ragged_chunk=14)
 
 
 # -- kernels -----------------------------------------------------------------
@@ -177,20 +184,28 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
   lens = lens.at[0].set(cap).at[-1].set(0)
   decode = (_Normal((s.b, 1, s.n, s.h), scale=q_scale), _Tables(s.b), lens)
 
-  # ragged: two decode rows, one prefill chunk, one speculating row of five
-  # tokens and two padding tokens (q_end 0) on one packed axis
-  starts = jax.random.randint(next(keys), (4,), page, cap - 8, i32)
+  # ragged: decode rows, one prefill chunk that spans several query blocks
+  # with a ragged last one, a one-token row right after it (so it starts
+  # mid-block), a speculating row of five tokens, then padding (q_end 0):
+  # each row's tokens contiguous on the one packed axis, as the engine
+  # packs them
+  widths = (1,) * (s.ragged_rows - 3) + (s.ragged_chunk, 1, 5)
+  starts = jnp.minimum(
+      jax.random.randint(next(keys), (s.ragged_rows,), page, cap - 8, i32),
+      cap - jnp.asarray(widths, i32))
   row_of, col = [], []
-  for r, width in enumerate((1, 1, 6, 5)):
+  for r, width in enumerate(widths):
     row_of += [r] * width
     col += list(range(width))
   live = len(row_of)
-  t = live + 2
-  row_of = jnp.asarray(row_of + [0, 0], i32)
+  t = s.ragged_t
+  assert live < t, (live, t)
+  row_of = jnp.asarray(row_of + [0] * (t - live), i32)
   q_start = jnp.zeros((t,), i32).at[:live].set(starts[row_of[:live]])
   q_end = jnp.zeros((t,), i32).at[:live].set(
       q_start[:live] + jnp.asarray(col, i32) + 1)
-  ragged = (_Normal((t, s.n, s.h), scale=q_scale), _Tables(4), row_of, q_end)
+  ragged = (_Normal((t, s.n, s.h), scale=q_scale), _Tables(s.ragged_rows),
+            row_of, q_end)
   # the last row as a 2 x 2 token tree: columns root, b0d0, b0d1, b1d0, b1d1;
   # bit c of a token's mask = step column c is an ancestor or the token
   masks = jnp.asarray((0b00001, 0b00011, 0b00111, 0b01001, 0b11001), i32)

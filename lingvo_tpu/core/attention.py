@@ -633,6 +633,15 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       states.value_scale = jnp.zeros((num_pages, n, page_size), jnp.float32)
     return states
 
+  def RaggedQueryBlock(self, page_size: int, kv_cache_dtype=None) -> int:
+    """Queries of one row that the ragged kernel runs against a page
+    together (ops/ragged_block_attend.QueryBlock at this layer's shapes):
+    what the engine's block-fill counters divide by."""
+    from lingvo_tpu.ops import ragged_block_attend
+    return ragged_block_attend.QueryBlock(
+        self.p.num_heads, self._dim_per_head, page_size, self.fprop_dtype,
+        self._KvDtype(kv_cache_dtype)[0])
+
   def BlockDecodeEligible(self, page_size: int) -> bool:
     """Same gate family as PagedDecodeEligible, for the block-table kernel:
     plain masked-softmax attention only. Ineligible configs run PagedStep's

@@ -29,6 +29,9 @@ ENGINE_COUNTER_KEYS = (
     "spec_cycles", "draft_tokens", "accepted_tokens",
     "spec_branches", "spec_width_clamps",
     "prefix_hit_tokens",
+    # query blocks the ragged attend kernel ran, and the valid queries in
+    # them; their ratio over the block size is the block fill
+    "attend_query_blocks", "attend_block_queries",
 )
 
 # Static engine configuration facts (set once at construction).

@@ -24,33 +24,63 @@ Layout contract (the serving engine maintains it, same as block_decode):
 - table entries past a row's live pages are unspecified — freed pages may
   already belong to another sequence and must never influence the output.
 - `q_end[t] = 0` marks a PADDING token: output 0, no pages read.
+- a row's valid tokens are CONTIGUOUS on the packed axis (a row is one run
+  of equal `row_of`; `core/ragged.BuildRaggedRows` packs rows so). Padding
+  tokens may sit anywhere and their `row_of` is not looked at.
 - q arrives PRE-SCALED, exactly like BlockDecode/FlashDecode.
 
-Two lowerings, asserted bit-identical (the established twin pattern):
+Two lowerings of the same arithmetic (bf16 or int8 pages, f32 scores, f32
+online softmax and accumulator), held to each other within rounding:
 
-- `_PallasRaggedAttend` — grid `(T, t_pages)`; `row_of`, the block tables,
-  and `q_end` ride scalar prefetch, so the page index map resolves
-  `block_tables[row_of[t], j]` before the DMA is issued. Dead pages clamp
-  to the token's last live page (DMA elided, `pl.when` skips compute) —
-  and because consecutive tokens of one row walk the same table, the
-  revisited blocks hit the same elision.
-- `_XlaRaggedAttend` — `fori_loop` with a dynamic trip count of
-  `ceil(max(q_end) / P)` over per-token gathered pages. Tokens whose
-  horizon falls short of the batch max process extra pages fully masked —
-  bitwise a no-op through `_PageAttend` (alpha == 1, p == 0), which keeps
-  the twins exactly equal despite different iteration spaces.
+- `_PallasRaggedAttend` — the kernel's unit of work is (a block of up to
+  `Bq` consecutive queries of ONE row, one logical page): grid
+  `(NB, t_pages)`, `NB = B + T // Bq` the static bound on blocks. A page
+  comes into VMEM once per query block, not once per token, and padding
+  costs the blocks past the live ones (no DMA, no compute), not sixteen
+  programs a token.
+  - Block descriptors (`_BuildQueryBlocks`: row, first packed token, valid
+    queries, last live page of the widest horizon, per-query mask columns)
+    are a few integer ops on `row_of`/`q_end`, computed in the jitted step
+    and shipped from nowhere; they ride scalar prefetch, so the page index
+    map resolves `block_tables[row[i], j]` before the DMA is issued.
+  - A row starts at any packed offset, so q and the output stay in HBM and
+    each block copies its own `[Bq, N, H]` window in at its first page and
+    out at its last (`Bq` rows of slack past T). A query that is not the
+    block's computes to an exact zero; programs run in packed order, so
+    the next block overwrites the zeros a block leaves past its own rows,
+    and what is left over padding is the zeros padding must read.
+  - Masks are per query: the causal horizon and the tree ancestor bits are
+    `[Bq, 1]` columns against the `[1, P]` slot iota.
+  - Dead pages clamp to the block's last live page and blocks past the
+    live ones to the last live block (DMA elided, `pl.when` skips
+    compute): a stale table entry never reaches VMEM.
+  - `Bq` is `QueryBlock(shapes, dtypes)`: one page of queries, halved
+    while the working set passes the scoped-VMEM budget. The arithmetic
+    adapts per block to what the kernel sees: a one-query block (a decode
+    row) reads the page as the `[P*N, H]` matrix it already is and runs
+    all heads through two plain matmuls masked to the stripe where the
+    key's head is the query's (nothing is re-laid out, and a decode row is
+    bound by the page's bytes); a larger block runs head-batched
+    `[Bq, N, H] x [P, N, H]`, the page re-laid out once for Bq queries.
+- `_XlaRaggedAttend` — the CPU serving path and the twin the kernel is
+  held to: `fori_loop` with a dynamic trip count of `ceil(max(q_end) / P)`
+  over per-token gathered pages through `flash_decode._PageAttend`. Tokens
+  whose horizon falls short of the batch max process extra pages fully
+  masked, a bitwise no-op (alpha == 1, p == 0). A T-token all-decode pack
+  reproduces `BlockDecode` bit for bit, which is what lets the engine
+  collapse to one program without moving a single token (asserted in
+  tests).
 
-Both route every page through the SAME `_PageAttend` (and int8 pools
-through the same `_DequantPages`), so the float-op sequence is identical
-and interpret-mode equality holds bitwise — including against
-`BlockDecode` itself: a T-token all-decode pack reproduces BlockDecode's
-output bit for bit, which is what lets the engine collapse to one program
-without moving a single token (asserted in tests).
+Both dequantize int8 pages through the same `_DequantPages`. The kernel's
+products have a free dimension of Bq (or sum over the stripe) where the
+twin's have one query, so sums run in another order: the twins agree to
+rounding (2.4e-7 in f32 under the interpreter), not to the bit.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
     LANES, NEG_INF)
-from lingvo_tpu.ops.flash_decode import _Finish, _PageAttend
+from lingvo_tpu.ops.flash_decode import _DotF32, _Finish, _PageAttend
 from lingvo_tpu.ops.block_decode import _DequantPages
 from lingvo_tpu.ops.block_decode import SupportedOnTpu  # noqa: F401  (same
 # Mosaic tiling gate: page_size and h on the 128-lane minor axes; re-exported
@@ -139,124 +169,310 @@ def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
 
 # -- Pallas TPU kernel -------------------------------------------------------
 
+_VMEM_BUDGET = 12 * 2**20   # of the 16 MiB a kernel may scope by default
 
-def _RaggedAttendKernel(row_of_ref, tables_ref, ends_ref, starts_ref,
-                        lo_ref, hi_ref, q_ref, k_ref,
-                        v_ref, *rest, page_size: int, t_pages: int):
-  """One (token, logical page) program step; scratch carried over pages.
 
-  Same body as `_BlockDecodeKernel` with the batch id replaced by the
-  packed-token id: the per-program length is the TOKEN's causal horizon
-  `q_end[t]`, not a per-sequence length. Float and int8 calls share the
-  body (int8 threads two extra scale blocks, dequantized via the shared
-  `_DequantPages`) so the control flow cannot drift."""
-  if len(rest) == 6:
-    ks_ref, vs_ref, out_ref, m_scr, l_scr, acc_scr = rest
+def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype) -> int:
+  """Bq, the most queries of one row that meet a page together.
+
+  One page of queries: the `[Bq, P]` score tile of a head is square, so a
+  K page meets as many query rows as it has key rows. Halved while the
+  kernel's working set (K and V pages double-buffered, f32 where int8
+  pages dequantize; the q block and the f32 accumulator; the lane-broadcast
+  softmax statistics; the per-query mask columns; two `[N, Bq, P]` f32
+  score tiles) passes `_VMEM_BUDGET`. A function of shapes and dtypes
+  alone: the engine calls it for its counters, nothing chooses it."""
+  q_bytes = jnp.dtype(q_dtype).itemsize
+  kv_bytes = jnp.dtype(kv_dtype).itemsize
+  lanes_p = max(page_size, LANES)
+
+  def _WorkingSet(bq):
+    pages = 2 * 2 * page_size * n * h * kv_bytes
+    if kv_bytes == 1:
+      pages += 2 * page_size * n * h * 4
+    q_acc = bq * n * h * (q_bytes + 4)
+    stats = 2 * n * bq * LANES * 4
+    cols = 2 * bq * LANES * 4
+    scores = 2 * n * bq * lanes_p * 4
+    return pages + q_acc + stats + cols + scores
+
+  bq = max(min(page_size, LANES), 8)
+  while bq > 8 and _WorkingSet(bq) > _VMEM_BUDGET:
+    bq //= 2
+  return bq
+
+
+def NumQueryBlocks(b: int, t: int, bq: int) -> int:
+  """Static bound on live query blocks: a row of `len` tokens takes
+  ceil(len / Bq) <= len / Bq + 1 of them, rows are contiguous, so B rows
+  over T tokens take at most B + T // Bq (and never more than T)."""
+  return max(1, min(t, b + t // bq))
+
+
+class _QueryBlocks(NamedTuple):
+  """Descriptors of the step's query blocks (all int32; NB static).
+
+  Block i holds up to Bq consecutive queries of ONE row, starting at
+  packed token `first[i]`. Entries past the live blocks repeat the last
+  live block with `n == 0`, so their programs ask for blocks already in
+  VMEM and compute nothing."""
+  row: jnp.ndarray    # [NB] block-table row
+  last: jnp.ndarray   # [NB] last live logical page (of the widest horizon)
+  n: jnp.ndarray      # [NB] valid queries; 0 = no such block this step
+  first: jnp.ndarray  # [NB] packed index of the block's first query
+  src: jnp.ndarray    # [NB] the `cols` block its programs map
+  cols: jnp.ndarray   # [NB, Bq, 4] per query: q_end (0 = not of this
+  #                     block), q_start, anc_lo, anc_hi
+
+
+def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
+                      page_size: int, t_pages: int) -> _QueryBlocks:
+  """Cuts each row's run of tokens into blocks of Bq queries.
+
+  A few [T]- and [NB, Bq]-sized integer ops on what the step already has
+  on the device. They depend on nothing a layer computes, so under the
+  scan over layers they are loop-invariant: XLA hoists them, then sinks
+  the cheap ones back beside their consumers (PERF.md section 6, PR 25:
+  with q's padding and the output's zeros, 29 us a call)."""
+  t = row_of.shape[0]
+  idx = jnp.arange(t, dtype=jnp.int32)
+  valid = ends > 0
+  prev_valid = jnp.concatenate([jnp.zeros((1,), bool), valid[:-1]])
+  prev_row = jnp.concatenate([row_of[:1], row_of[:-1]])
+  run_start = valid & (~prev_valid | (row_of != prev_row))
+  run_first = jax.lax.cummax(jnp.where(run_start, idx, 0))
+  blk_start = valid & ((idx - run_first) % bq == 0)
+  csum = jnp.cumsum(blk_start.astype(jnp.int32))
+  blk = csum - 1                                            # [T] block id
+  n_live = csum[-1]
+  k = jnp.arange(nb, dtype=jnp.int32)
+  src = jnp.minimum(k, jnp.maximum(n_live - 1, 0))
+  # block k starts at the first token whose running count reaches k + 1
+  first = jnp.sum((csum[None, :] <= src[:, None]).astype(jnp.int32), axis=1)
+  first = jnp.minimum(first, t - 1)
+  tok = first[:, None] + jnp.arange(bq, dtype=jnp.int32)[None, :]
+  in_range = tok < t
+  tok = jnp.minimum(tok, t - 1)
+  member = in_range & valid[tok] & (blk[tok] == src[:, None])
+  blk_ends = jnp.where(member, ends[tok], 0)                # [NB, Bq]
+  cols = jnp.stack([blk_ends, starts[tok], lo[tok], hi[tok]], axis=-1)
+  last = jnp.clip((jnp.max(blk_ends, axis=1) + page_size - 1) // page_size
+                  - 1, 0, t_pages - 1)
+  n = jnp.where(k < n_live, jnp.sum(member.astype(jnp.int32), axis=1), 0)
+  return _QueryBlocks(row=row_of[first], last=last, n=n, first=first,
+                      src=src, cols=cols)
+
+
+def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
+  """`_PageAttend` with a free query dimension: the same float ops in the
+  same order per (query, head, slot). keep is boolean and broadcasts
+  against the scores; m/l keep a trailing unit dim."""
+  s = jnp.where(keep, _DotF32(q, k, dims_qk), NEG_INF)
+  m_cur = jnp.max(s, axis=-1, keepdims=True)
+  m_new = jnp.maximum(m, m_cur)
+  m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+  p = jnp.exp(s - m_safe)
+  alpha = jnp.exp(m - m_new)
+  l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+  pv = _DotF32(p.astype(v.dtype), v, dims_pv)
+  return m_new, l_new, acc * alpha + pv
+
+
+def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
+                        first_ref, end0_ref, start0_ref, lo0_ref, hi0_ref,
+                        q_hbm, cols_ref, k_ref, v_ref, *rest,
+                        page_size: int, t_pages: int):
+  """One (query block, logical page) program; scratch carried over pages.
+
+  q_hbm/out_hbm: [T + Bq, N, H], left in HBM: a block copies its own
+  window in at its first page and out at its last, at its row's packed
+  offset, whatever that is. k_ref/v_ref: [1, P, N, H]; cols_ref:
+  [1, Bq, 4]. The block's size decides its arithmetic, inside the one
+  program:
+
+  - one query (a decode row): the page is read as the `[P*N, H]` matrix it
+    already is in memory and all heads go through two plain matmuls,
+    `[N, H] x [P*N, H]^T` and `[N, P*N] x [P*N, H]`, masked to the stripe
+    where the key's head is the query's. Nothing is re-laid out; the N-fold
+    surplus of MXU work is free beside the page's DMA.
+  - more (a prefill chunk, a verify window): head-batched
+    `[Bq, N, H] x [P, N, H]`, the page re-laid out once for Bq queries.
+
+  Float and int8 pools share the body (int8 threads two scale blocks,
+  dequantized via the shared `_DequantPages`)."""
+  if len(rest) == 13:
+    ks_ref, vs_ref = rest[:2]
+    rest = rest[2:]
   else:
     ks_ref = vs_ref = None
-    out_ref, m_scr, l_scr, acc_scr = rest
-  ti = pl.program_id(0)
+  (_, out_hbm, q1, qb, m1, l1, acc1, mb, lb, accb, sem) = rest
+  i = pl.program_id(0)
   j = pl.program_id(1)
-  ln = ends_ref[ti]
+  nv = n_ref[i]
+  first = first_ref[i]
+  bq, heads, h = qb.shape
+  slot0 = j * page_size
 
-  @pl.when(j == 0)
-  def _Init():
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
+  def _Copy(src, dst):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
 
-  @pl.when(j * page_size < ln)
-  def _Accumulate():
-    slot = j * page_size + jax.lax.broadcasted_iota(
+  def _Block(q_scr, m_scr, l_scr, acc_scr, attend, layout):
+    """The block's life over its pages: window in, accumulate, window out.
+
+    attend(k_page, v_page, m, l, acc) -> (m, l, acc); layout puts the
+    finished block in q_scr's layout (which doubles as the way out)."""
+    window = pl.ds(first, q_scr.shape[0])
+
+    @pl.when(j == 0)
+    def _Init():
+      _Copy(q_hbm.at[window], q_scr)
+      m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+      l_scr[...] = jnp.zeros_like(l_scr)
+      acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j <= last_ref[i])
+    def _Accumulate():
+      k_page, v_page = k_ref[0], v_ref[0]
+      if ks_ref is not None:
+        k_page = _DequantPages(k_page, ks_ref[0])
+        v_page = _DequantPages(v_page, vs_ref[0])
+      m, l, acc = attend(k_page, v_page, m_scr[..., :1], l_scr[..., :1],
+                         acc_scr[...])
+      m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
+      l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
+      acc_scr[...] = acc
+
+    @pl.when(j == t_pages - 1)
+    def _Emit():
+      # a query that is not this block's (q_end 0 in cols) comes out an
+      # exact zero: the rows after the block's own are the next block's to
+      # overwrite (programs run in packed order) or padding
+      q_scr[...] = layout(
+          _Finish(l_scr[..., :1], acc_scr[...], q_scr.dtype))
+      _Copy(q_scr, out_hbm.at[window])
+
+  def _OneQuery(k_page, v_page, m, l, acc):
+    width = page_size * heads
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+    slot = slot0 + col // heads
+    keep = ((col % heads == head) & (slot < end0_ref[i]) & _AncestorOk(
+        slot, slot - start0_ref[i], lo0_ref[i], hi0_ref[i]))
+    return _BlockPageAttend(
+        q1[0], k_page.reshape(width, h), v_page.reshape(width, h), keep,
+        m, l, acc, (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+
+  def _ManyQueries(k_page, v_page, m, l, acc):
+    slot = slot0 + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)                       # [1, P]
-    ok = _AncestorOk(slot, slot - starts_ref[ti],
-                     lo_ref[ti], hi_ref[ti])                # [1, P]
-    keep = ((slot < ln) & ok).astype(jnp.float32)           # [1, P]
-    k_page, v_page = k_ref[0], v_ref[0]
-    if ks_ref is not None:
-      k_page = _DequantPages(k_page, ks_ref[0])
-      v_page = _DequantPages(v_page, vs_ref[0])
-    m, l, acc = _PageAttend(q_ref[0], k_page, v_page, keep, m_scr[:, :1],
-                            l_scr[:, :1], acc_scr[:])
-    m_scr[:] = jnp.broadcast_to(m, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l, l_scr.shape)
-    acc_scr[:] = acc
+    cols = cols_ref[0]                                      # [Bq, 4]
+    keep = (slot < cols[:, 0:1]) & _AncestorOk(
+        slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [Bq, P]
+    return _BlockPageAttend(
+        qb[...], k_page, v_page, keep[None], m, l, acc,
+        (((2,), (2,)), ((1,), (1,))), (((2,), (0,)), ((0,), (1,))))
 
-  @pl.when(j == t_pages - 1)
-  def _Emit():
-    out_ref[0] = _Finish(l_scr[:, :1], acc_scr[:], out_ref.dtype)
+  pl.when(nv == 1)(lambda: _Block(
+      q1, m1, l1, acc1, _OneQuery, lambda out: out[None]))
+  pl.when(nv > 1)(lambda: _Block(
+      qb, mb, lb, accb, _ManyQueries, lambda out: jnp.swapaxes(out, 0, 1)))
 
 
 def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
                         page_size: int, interpret: bool = False,
                         k_scale=None, v_scale=None,
                         q_start=None, anc_lo=None, anc_hi=None):
-  """Pallas lowering of _XlaRaggedAttend. q: [T, N, H] -> [T, N, H]."""
+  """Pallas lowering of _XlaRaggedAttend. q: [T, N, H] -> [T, N, H].
+
+  Grid `(NB, t_pages)`, both axes in order: block i + 1 starts where block
+  i's queries end, so its window overwrites the zeros block i left past
+  its own."""
   t, n, h = q.shape
   np_total, page, _, _ = k_pool.shape
   assert page == page_size, (page, page_size)
-  t_pages = block_tables.shape[1]
+  b, t_pages = block_tables.shape
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-  rows = jnp.clip(row_of.astype(jnp.int32), 0, tables.shape[0] - 1)
+  rows = jnp.clip(row_of.astype(jnp.int32), 0, b - 1)
   ends = q_end.astype(jnp.int32)
   if q_start is None:
     q_start = jnp.zeros((t,), jnp.int32)
     anc_lo = anc_hi = jnp.full((t,), -1, jnp.int32)
-  starts = q_start.astype(jnp.int32)
-  lo = anc_lo.astype(jnp.int32)
-  hi = anc_hi.astype(jnp.int32)
+  bq = QueryBlock(n, h, page_size, q.dtype, k_pool.dtype)
+  nb = NumQueryBlocks(b, t, bq)
+  blocks = _BuildQueryBlocks(
+      rows, ends, q_start.astype(jnp.int32), anc_lo.astype(jnp.int32),
+      anc_hi.astype(jnp.int32), bq=bq, nb=nb, page_size=page_size,
+      t_pages=t_pages)
+  col0 = blocks.cols[:, 0]                                  # [NB, 4]
 
-  # Dead logical pages clamp to the TOKEN's last live page: Pallas
-  # re-requests the same physical block and elides the HBM DMA, pl.when
-  # skips compute. A stale table entry past a token's horizon never
-  # reaches VMEM — the page-reuse-after-eviction guarantee.
-  def _PageIdx(ti, j, row_ref, tables_ref, ends_ref, s_ref, lo_ref, hi_ref):
-    last = jnp.maximum(
-        (ends_ref[ti] + page_size - 1) // page_size - 1, 0)
-    last = jnp.minimum(last, t_pages - 1)
-    return (tables_ref[row_ref[ti], jnp.minimum(j, last)], 0, 0, 0)
+  # A dead logical page clamps to the BLOCK's last live page and a block
+  # past the live ones to the last live block: Pallas asks for the block it
+  # already holds and elides the DMA, pl.when skips compute. A stale table
+  # entry past a block's widest horizon never reaches VMEM, which is the
+  # page-reuse-after-eviction guarantee.
+  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *_):
+    return (tables_ref[row_ref[i], jnp.minimum(j, last_ref[i])], 0, 0, 0)
 
-  def _ScaleIdx(ti, j, row_ref, tables_ref, ends_ref, s_ref, lo_ref, hi_ref):
-    return _PageIdx(ti, j, row_ref, tables_ref, ends_ref,
-                    s_ref, lo_ref, hi_ref)[:3]
+  def _ScaleIdx(i, j, *refs):
+    return _PageIdx(i, j, *refs)[:3]
 
-  def _TokIdx(ti, j, r_ref, t_ref, e_ref, s_ref, lo_ref, hi_ref):
-    return (ti, 0, 0)
+  def _ColsIdx(i, j, row_ref, last_ref, src_ref, *_):
+    return (src_ref[i], 0, 0)
 
+  hbm = pl.BlockSpec(memory_space=pl.ANY)
   in_specs = [
-      pl.BlockSpec((1, n, h), _TokIdx),
+      hbm,
+      pl.BlockSpec((1, bq, 4), _ColsIdx),
       pl.BlockSpec((1, page_size, n, h), _PageIdx),
       pl.BlockSpec((1, page_size, n, h), _PageIdx),
   ]
-  operands = [rows, tables, ends, starts, lo, hi, q, k_pool, v_pool]
+  # Bq rows of slack: the last block's window may run past T
+  slack = ((0, bq), (0, 0), (0, 0))
+  operands = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
+              blocks.first, col0[:, 0], col0[:, 1], col0[:, 2], col0[:, 3],
+              jnp.pad(q, slack), blocks.cols, k_pool, v_pool]
   if k_scale is not None:
     in_specs += [
         pl.BlockSpec((1, n, page_size), _ScaleIdx),
         pl.BlockSpec((1, n, page_size), _ScaleIdx),
     ]
     operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+  # the output starts as zeros and is written in place: a padding token is
+  # in no block's window, or in the zeros past a block's own queries
+  in_specs.append(hbm)
+  operands.append(jnp.zeros((t + bq, n, h), q.dtype))
 
   grid_spec = pltpu.PrefetchScalarGridSpec(
-      num_scalar_prefetch=6,
-      grid=(t, t_pages),
+      num_scalar_prefetch=10,
+      grid=(nb, t_pages),
       in_specs=in_specs,
-      out_specs=pl.BlockSpec((1, n, h), _TokIdx),
+      out_specs=hbm,
       scratch_shapes=[
+          pltpu.VMEM((1, n, h), q.dtype),
+          pltpu.VMEM((bq, n, h), q.dtype),
           pltpu.VMEM((n, LANES), jnp.float32),
           pltpu.VMEM((n, LANES), jnp.float32),
           pltpu.VMEM((n, h), jnp.float32),
+          pltpu.VMEM((n, bq, LANES), jnp.float32),
+          pltpu.VMEM((n, bq, LANES), jnp.float32),
+          pltpu.VMEM((n, bq, h), jnp.float32),
+          pltpu.SemaphoreType.DMA(()),
       ],
   )
   kernel = functools.partial(_RaggedAttendKernel, page_size=page_size,
                              t_pages=t_pages)
-  return pl.pallas_call(
+  out = pl.pallas_call(
       kernel,
       grid_spec=grid_spec,
-      out_shape=jax.ShapeDtypeStruct((t, n, h), q.dtype),
+      out_shape=jax.ShapeDtypeStruct((t + bq, n, h), q.dtype),
+      input_output_aliases={len(operands) - 1: 0},
       compiler_params=pltpu.CompilerParams(
-          dimension_semantics=("parallel", "arbitrary")),
+          dimension_semantics=("arbitrary", "arbitrary")),
       interpret=interpret,
   )(*operands)
+  return out[:t]
 
 
 # -- public entry ------------------------------------------------------------
@@ -274,7 +490,8 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
   k_pool/v_pool: [num_pages, page_size, N, H] global page pool.
   block_tables: [B, pages_per_seq] int32 physical page ids; entries past a
   row's live pages are arbitrary and never influence the output.
-  row_of: [T] int32 — batch row (block-table index) of each token.
+  row_of: [T] int32 — batch row (block-table index) of each token; a
+  row's valid tokens are contiguous on the packed axis.
   q_end: [T] int32 — one past each token's highest attendable global slot
   (its `q_pos + 1`); 0 marks a padding token, whose output is 0.
   k_scale/v_scale: [num_pages, N, page_size] f32 sidecars for int8 pools

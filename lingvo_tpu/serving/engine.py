@@ -454,6 +454,11 @@ class ServingLoop:
     # silent-fallback visibility: classify ONCE which attention path the
     # compiled step will take, and count ineligible (dense-fallback) steps
     self.paged_path = self._ClassifyPath()
+    # block size of the ragged attend kernel at this stack's shapes (0: no
+    # attention layer); only the block-fill counters read it
+    attens = self._AttentionLayers()
+    self._attend_bq = (attens[0].RaggedQueryBlock(page_size, kv_cache_dtype)
+                       if attens else 0)
     self._handles: dict = {}
     # counters live in the registry under serving/* (schema is the single
     # source of the key set); Stats() maps them back to the plain keys.
@@ -517,6 +522,11 @@ class ServingLoop:
     """[(mixer_layer, multiplicity)] — see spec_decode.MixerLayers."""
     return spec_decode.MixerLayers(self._task)
 
+  def _AttentionLayers(self) -> list:
+    """The stack's attention mixers (those that read the page pool)."""
+    return [m for m, _ in self._MixerLayers()
+            if not hasattr(m, "StateBytesPerSlot")]
+
   def _MixerCensus(self) -> dict:
     """Attention vs O(1)-state census — see spec_decode.MixerCensus."""
     return spec_decode.MixerCensus(self._task)
@@ -531,8 +541,7 @@ class ServingLoop:
     dequantize), but loses the in-kernel dequant — equally worth
     surfacing. 'ssm' = no attention layer at all: the page pool is never
     read and classification is about the recurrent-state path instead."""
-    attens = [m for m, _ in self._MixerLayers()
-              if not hasattr(m, "StateBytesPerSlot")]
+    attens = self._AttentionLayers()
     if not attens:
       return "ssm"
     if self._kv_quantized:
@@ -1272,6 +1281,11 @@ class ServingLoop:
       self._counters["steps"].Inc()
       self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
       self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
+      if self._attend_bq:
+        row_len = np.asarray(desc.row_len, np.int64)
+        self._counters["attend_query_blocks"].Inc(
+            int(np.sum(-(-row_len // self._attend_bq))))
+        self._counters["attend_block_queries"].Inc(int(np.sum(row_len)))
       if self.paged_path == "dense":
         self._counters["dense_fallback_steps"].Inc()
       if self._kv_quantized:

@@ -31,6 +31,30 @@ import chip_smoke
 
 _CASES = {case.name: case for case in chip_smoke.KernelCases(chip_smoke.REAL)}
 
+# The ragged attend kernel at the shapes the serving cells of `dense1b` run
+# it at (BENCHMARK.json: 32 slots + a 512-token budget = 544 packed tokens,
+# 16 heads of 128, pages of 128, 16 pages a row, 193 pool pages + trash).
+_SERVING = dict(t=544, n=16, h=128, page=128, table_pages=16, pool_pages=194,
+                rows=32)
+_SERVING_CASES = {"bf16": "ragged_attend_plain", "int8": "ragged_attend_int8",
+                  "tree": "ragged_attend_tree"}
+
+
+def _ServingArgs(variant):
+  """The operands of chip_smoke's `_Ragged` case at `_SERVING` shapes."""
+  import jax.numpy as jnp
+  d = _SERVING
+  i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+  sds = jax.ShapeDtypeStruct
+  kv = jnp.int8 if variant == "int8" else bf16
+  pool = sds((d["pool_pages"], d["page"], d["n"], d["h"]), kv)
+  scale = (sds((d["pool_pages"], d["n"], d["page"]), f32)
+           if variant == "int8" else None)
+  tok = sds((d["t"],), i32)
+  tree = tok if variant == "tree" else None
+  return (sds((d["t"], d["n"], d["h"]), bf16), pool, pool, scale, scale,
+          sds((d["rows"], d["table_pages"]), i32), tok, tok, tree, tree, tree)
+
 
 @pytest.fixture(scope="module")
 def compiles():
@@ -47,10 +71,12 @@ def compiles():
       lambda key: chip_smoke.KernelInputs(chip_smoke.REAL, key),
       jax.random.PRNGKey(0))
 
-  def _Compile(case):
+  def _Compile(case, args=None):
+    if args is None:
+      args = shapes[case.inputs]
     args = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        shapes[case.inputs])
+        args)
     return jax.jit(case.fn(True)).lower(*args).compile().as_text()
 
   # such a compile is written to the persistent cache but cannot be read
@@ -59,8 +85,13 @@ def compiles():
   compilation_cache.reset_cache()
   try:
     with concurrent.futures.ThreadPoolExecutor(8) as pool:
-      yield {name: pool.submit(_Compile, case)
-             for name, case in _CASES.items()}
+      futures = {name: pool.submit(_Compile, case)
+                 for name, case in _CASES.items()}
+      futures.update({
+          f"serving_{variant}": pool.submit(
+              _Compile, _CASES[name], _ServingArgs(variant))
+          for variant, name in _SERVING_CASES.items()})
+      yield futures
   finally:
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
@@ -70,6 +101,12 @@ def compiles():
 def test_kernel_compiles_for_v5e(name, compiles):
   # raises what the chip's compiler would raise
   assert "tpu_custom_call" in compiles[name].result(timeout=300)
+
+
+@pytest.mark.parametrize("variant", sorted(_SERVING_CASES))
+def test_ragged_attend_compiles_at_serving_shapes(variant, compiles):
+  assert "tpu_custom_call" in compiles[f"serving_{variant}"].result(
+      timeout=300)
 
 
 @pytest.fixture(scope="module")

@@ -2,15 +2,22 @@
 
 The op that collapses decode / chunked prefill / spec-verify into one
 program must hold the same guarantees each specialized op held:
-- XLA twin == Pallas(interpret) BITWISE, including dead-page clamp,
-  q_len=1 degenerate rows, q_end=0 padding tokens, and page reuse after a
-  real allocator eviction;
+- XLA twin == Pallas(interpret) within `_ATOL` (5e-6 in f32), including
+  dead-page clamp, q_len=1 degenerate rows, q_end=0 padding tokens, and page
+  reuse after a real allocator eviction. The kernel runs a block of a row's
+  queries against a page in one product where the twin runs one query, so
+  the interpreter's dots sum in another order and the twins agree to
+  rounding, not to the bit (measured: 2.4e-7); what IS bitwise is stated
+  where it is asserted (padding zeros, hostile tables, chain sentinels);
+- a row's valid tokens are CONTIGUOUS on the packed axis (the op's
+  contract since the blocked kernel; padding may sit anywhere);
 - stale block-table entries (freed/foreign pages) never leak into output;
 - an all-decode token pack reproduces `BlockDecode` bit for bit and a
   prefill pack reproduces `BlockPrefill` (same `_PageAttend` float-op
   sequence) — the "three programs become views of one op" claim, at the
   op level;
-- the int8 path stays bitwise-twinned through the shared `_DequantPages`.
+- the int8 path dequantizes on read through the shared `_DequantPages`: the
+  int8 XLA twin is bitwise the float twin on dequantized pools.
 """
 
 import numpy as np
@@ -31,6 +38,9 @@ def _QuantizePools(k_pool, v_pool):
   v8, vs = kv_quant.QuantizeKv(jnp.asarray(v_pool))
   return (k8, jnp.swapaxes(ks, 1, 2).astype(jnp.float32),
           v8, jnp.swapaxes(vs, 1, 2).astype(jnp.float32))
+
+
+_ATOL = 5e-6
 
 
 class TestRaggedAttend:
@@ -72,7 +82,8 @@ class TestRaggedAttend:
         jnp.asarray(q), kp, vp, jnp.asarray(tables), jnp.asarray(row_of),
         jnp.asarray(q_end), page_size=page, lowering="pallas",
         interpret=True, **kw)
-    np.testing.assert_array_equal(np.asarray(out_x), np.asarray(out_p))
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
+                               rtol=0, atol=_ATOL)
     return np.asarray(out_x)
 
   def test_mixed_rows_match_dense_reference(self):
@@ -93,8 +104,8 @@ class TestRaggedAttend:
     """Table entries past a token's horizon may point anywhere (freed or
     foreign pages); they must not change the output."""
     q, k_pool, v_pool, tables = self._Inputs()
-    row_of = np.array([0, 1, 1, 2, 2, 2, 0, 1], np.int32)
-    q_end = np.array([3, 5, 6, 2, 3, 4, 4, 7], np.int32)  # page 1 dead
+    row_of = np.array([0, 0, 1, 1, 1, 2, 2, 2], np.int32)
+    q_end = np.array([3, 4, 5, 6, 7, 2, 3, 4], np.int32)  # page 1 dead
     out1 = self._Both(q, jnp.asarray(k_pool), jnp.asarray(v_pool), tables,
                       row_of, q_end)
     hostile = tables.copy()
@@ -134,7 +145,7 @@ class TestRaggedAttend:
                                atol=5e-6)
 
   def test_twins_bitwise_equal_incl_page_reuse(self):
-    """XLA == Pallas(interpret) bitwise before AND after a real allocator
+    """XLA == Pallas(interpret) (within _ATOL) before AND after a real allocator
     frees one sequence's pages and hands them to another (pool bytes
     overwritten in place — exactly what eviction + admission does)."""
     q, k_pool, v_pool, tables = self._Inputs(b=2, t=5)
@@ -155,15 +166,16 @@ class TestRaggedAttend:
       k_pool = k_pool.at[pg].set(rng.randn(8, 1, 8).astype(np.float32))
       v_pool = v_pool.at[pg].set(rng.randn(8, 1, 8).astype(np.float32))
     tables2 = np.array([reused, list(alloc.PagesOf("b"))], np.int32)
-    q_end2 = np.array([10, 14, 15, 16, 12], np.int32)
-    row_of2 = np.array([0, 1, 1, 1, 0], np.int32)
+    q_end2 = np.array([10, 12, 14, 15, 16], np.int32)
+    row_of2 = np.array([0, 0, 1, 1, 1], np.int32)
     out = self._Both(q, k_pool, v_pool, tables2, row_of2, q_end2)
     ref = self._DenseRef(q, np.asarray(k_pool), np.asarray(v_pool),
                          tables2, row_of2, q_end2)
     np.testing.assert_allclose(out, ref, atol=5e-6)
 
   def test_int8_twins_bitwise_and_match_float_on_dequant(self):
-    """int8 XLA == int8 Pallas(interpret) bitwise, and both == the float
+    """int8 XLA == int8 Pallas(interpret) within _ATOL, and the XLA twin
+    is bitwise the float
     kernel run on elementwise-dequantized pools: dequantize-on-read is the
     ONLY thing the quantized path adds."""
     q, k_pool, v_pool, tables = self._Inputs()
@@ -242,7 +254,7 @@ class TestAncestorMaskedAttend:
   def test_tree_row_matches_masked_dense_reference(self):
     """A w=2,k=2 tree row next to a plain decode row: each tree token
     sees the committed prefix + its own root path, never its siblings;
-    XLA == Pallas(interpret) bitwise throughout."""
+    XLA == Pallas(interpret) within _ATOL throughout."""
     q, k_pool, v_pool, tables = self._Inputs()
     parents = [-1, 0, -1, 2]
     t_end, t_start, t_lo, t_hi = self._TreeRow(6, parents)
@@ -278,7 +290,7 @@ class TestAncestorMaskedAttend:
     np.testing.assert_array_equal(base, masked)
 
   def test_masked_twins_bitwise_incl_page_reuse(self):
-    """XLA == Pallas(interpret) bitwise on ancestor-masked packs before
+    """XLA == Pallas(interpret) within _ATOL on ancestor-masked packs before
     AND after a real allocator eviction hands one row's pages to another
     (the _Both helper asserts the twin equality on every call)."""
     q, k_pool, v_pool, tables = self._Inputs(b=2, t=5)
@@ -307,7 +319,7 @@ class TestAncestorMaskedAttend:
 
   def test_int8_masked_twins_bitwise(self):
     """The int8 path composes with ancestor masks: quantized XLA ==
-    quantized Pallas(interpret) bitwise, both == the float kernel on
+    quantized Pallas(interpret) within _ATOL, the XLA twin bitwise the float kernel on
     dequantized pools."""
     q, k_pool, v_pool, tables = self._Inputs()
     k8, ks, v8, vs = _QuantizePools(k_pool, v_pool)
@@ -328,3 +340,165 @@ class TestAncestorMaskedAttend:
         q_start=jnp.asarray(q_start), anc_lo=jnp.asarray(lo),
         anc_hi=jnp.asarray(hi))
     np.testing.assert_array_equal(out_q, np.asarray(out_f))
+
+
+class TestQueryBlocks:
+  """The blocked kernel where it can go wrong and the per-token one could
+  not: blocks of one row's queries cut at Bq, at any packed offset.
+
+  Pages of 16, so `QueryBlock` gives Bq 16 and a 37-token row spans three
+  blocks. Every case holds Pallas(interpret) to the XLA twin and to the
+  dense numpy reference within `_ATOL` (5e-6; the blocked products sum in
+  another order than the twin's one-query ones), and padding tokens to
+  exact zeros."""
+
+  PAGE, T_PAGES, B, N, H = 16, 4, 6, 2, 8
+
+  # name -> (packed width, [(row, q_pos, tokens)] in packed order,
+  #          padding tokens before the first row)
+  PACKS = {
+      "chunk_spans_blocks_ragged_last": (48, [(0, 9, 37), (1, 30, 1)], 0),
+      "row_starts_mid_block_after_one_token_row":
+          (40, [(0, 20, 1), (1, 3, 20), (2, 40, 1)], 0),
+      "decode_only": (8, [(r, 5 + 9 * r, 1) for r in range(6)], 0),
+      "empty_slots_between_live_rows":
+          (40, [(0, 7, 1), (2, 0, 18), (5, 33, 2)], 0),
+      "all_padding_tail": (64, [(1, 12, 17), (3, 50, 1)], 0),
+      "all_padding_pack": (24, [], 0),
+      "horizons_straddle_page_boundary":
+          (24, [(0, 10, 12), (4, 28, 8)], 0),
+      "padding_before_and_between_rows": (40, [(1, 2, 19), (2, 44, 3)], 5),
+      "full_blocks_exact": (40, [(0, 0, 32), (3, 15, 1)], 0),
+  }
+
+  @classmethod
+  def _Pools(cls, seed=0):
+    rng = np.random.RandomState(seed)
+    np_total = cls.B * cls.T_PAGES + 1
+    shape = (np_total, cls.PAGE, cls.N, cls.H)
+    tables = rng.permutation(np_total - 1).reshape(
+        cls.B, cls.T_PAGES).astype(np.int32)
+    return (rng.randn(*shape).astype(np.float32),
+            rng.randn(*shape).astype(np.float32), tables)
+
+  @classmethod
+  def _Pack(cls, name, seed=1):
+    t, rows, lead = cls.PACKS[name]
+    rng = np.random.RandomState(seed)
+    q = rng.randn(t, cls.N, cls.H).astype(np.float32)
+    row_of = np.zeros((t,), np.int32)
+    q_end = np.zeros((t,), np.int32)
+    q_start = np.zeros((t,), np.int32)
+    cursor = lead
+    for i, (row, q_pos, n) in enumerate(rows):
+      cursor += bool(lead and i)  # and a padding token between its rows
+      sl = slice(cursor, cursor + n)
+      row_of[sl] = row
+      q_end[sl] = q_pos + 1 + np.arange(n)
+      q_start[sl] = q_pos
+      cursor += n
+    assert cursor <= t, (cursor, t)
+    return q, row_of, q_end, q_start
+
+  @classmethod
+  def _Run(cls, lowering, q, kp, vp, tables, row_of, q_end, **kw):
+    kw = {k: jnp.asarray(v) for k, v in kw.items()}
+    extra = dict(interpret=True) if lowering == "pallas" else {}
+    return np.asarray(ragged_block_attend.RaggedAttend(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables), jnp.asarray(row_of), jnp.asarray(q_end),
+        page_size=cls.PAGE, lowering=lowering, **extra, **kw))
+
+  def test_block_size_comes_from_shapes(self):
+    qb = ragged_block_attend.QueryBlock
+    assert qb(self.N, self.H, self.PAGE, np.float32, np.float32) == 16
+    # the serving shapes of dense1b fit a page of queries in every dtype;
+    # twice the heads in f32 pass the VMEM budget and the block halves
+    assert qb(16, 128, 128, jnp.bfloat16, jnp.bfloat16) == 128
+    assert qb(16, 128, 128, jnp.bfloat16, jnp.int8) == 128
+    assert qb(16, 128, 128, np.float32, np.float32) == 128
+    assert qb(32, 128, 128, np.float32, np.float32) == 32
+    assert ragged_block_attend.NumQueryBlocks(32, 544, 128) == 36
+
+  @pytest.mark.parametrize("name", sorted(PACKS))
+  def test_blocked_kernel_matches_twin_and_dense(self, name):
+    kp, vp, tables = self._Pools()
+    q, row_of, q_end, _ = self._Pack(name)
+    out_p = self._Run("pallas", q, kp, vp, tables, row_of, q_end)
+    out_x = self._Run("xla", q, kp, vp, tables, row_of, q_end)
+    ref = TestRaggedAttend._DenseRef(q, kp, vp, tables, row_of, q_end)
+    np.testing.assert_allclose(out_p, out_x, rtol=0, atol=_ATOL)
+    np.testing.assert_allclose(out_p, ref, rtol=0, atol=_ATOL)
+    # bitwise: a padding token reads exact zeros, whatever its neighbours
+    pad = q_end == 0
+    np.testing.assert_array_equal(out_p[pad], np.zeros_like(out_p[pad]))
+    assert np.all(np.isfinite(out_p))
+
+  @pytest.mark.parametrize("name", ["chunk_spans_blocks_ragged_last",
+                                    "horizons_straddle_page_boundary",
+                                    "decode_only"])
+  def test_hostile_entries_past_last_live_page(self, name):
+    """Table entries past a row's last live page point at another row's
+    live pages and at the trash page; the kernel's output is BITWISE what
+    it is with its own tables (same ops on the same pages)."""
+    kp, vp, tables = self._Pools()
+    q, row_of, q_end, _ = self._Pack(name)
+    clean = self._Run("pallas", q, kp, vp, tables, row_of, q_end)
+    hostile = tables.copy()
+    for row in range(self.B):
+      ends = q_end[(row_of == row) & (q_end > 0)]
+      live = 0 if ends.size == 0 else -(-int(ends.max()) // self.PAGE)
+      for j in range(live, self.T_PAGES):
+        hostile[row, j] = (tables[(row + 1) % self.B, 0] if j % 2
+                           else self.B * self.T_PAGES)
+    out = self._Run("pallas", q, kp, vp, hostile, row_of, q_end)
+    np.testing.assert_array_equal(out, clean)
+
+  @pytest.mark.parametrize("name", ["chunk_spans_blocks_ragged_last",
+                                    "row_starts_mid_block_after_one_token_row"])
+  def test_int8_pools(self, name):
+    """Quantized pools through the blocked body: Pallas(interpret) against
+    the int8 twin, which is bitwise the float twin on dequantized pools."""
+    kp, vp, tables = self._Pools()
+    q, row_of, q_end, _ = self._Pack(name)
+    k8, ks, v8, vs = _QuantizePools(kp, vp)
+    out_p = self._Run("pallas", q, k8, v8, tables, row_of, q_end,
+                      k_scale=ks, v_scale=vs)
+    out_x = self._Run("xla", q, k8, v8, tables, row_of, q_end,
+                      k_scale=ks, v_scale=vs)
+    np.testing.assert_allclose(out_p, out_x, rtol=0, atol=_ATOL)
+
+  @pytest.mark.parametrize("tree_len", [5, 21])
+  def test_tree_row_beside_chain_rows(self, tree_len):
+    """A DFS-packed tree row (inside one block at 5 columns, over two at
+    21) between a decode row and a prefill chunk: each tree token sees the
+    committed prefix and its own root path only."""
+    kp, vp, tables = self._Pools()
+    rng = np.random.RandomState(3)
+    parents = [-1] + [int(rng.randint(-1, j)) for j in range(1, tree_len - 1)]
+    lo_t, hi_t = ragged.TreeAncestorMasks(parents)
+    rows = [(0, 30, 1), (2, 11, tree_len), (4, 2, 19)]
+    t = 48
+    q = rng.randn(t, self.N, self.H).astype(np.float32)
+    row_of = np.zeros((t,), np.int32)
+    q_end = np.zeros((t,), np.int32)
+    q_start = np.zeros((t,), np.int32)
+    lo = np.full((t,), -1, np.int32)
+    hi = np.full((t,), -1, np.int32)
+    cursor = 0
+    for row, q_pos, n in rows:
+      sl = slice(cursor, cursor + n)
+      row_of[sl], q_start[sl] = row, q_pos
+      q_end[sl] = q_pos + 1 + np.arange(n)
+      if row == 2:
+        lo[sl], hi[sl] = lo_t, hi_t
+      cursor += n
+    kw = dict(q_start=q_start, anc_lo=lo, anc_hi=hi)
+    out_p = self._Run("pallas", q, kp, vp, tables, row_of, q_end, **kw)
+    out_x = self._Run("xla", q, kp, vp, tables, row_of, q_end, **kw)
+    ref = TestAncestorMaskedAttend._MaskedDenseRef(
+        q, kp, vp, tables, row_of, q_end, q_start, lo, hi)
+    np.testing.assert_allclose(out_p, out_x, rtol=0, atol=_ATOL)
+    np.testing.assert_allclose(out_p, ref, rtol=0, atol=_ATOL)
+    np.testing.assert_array_equal(out_p[cursor:],
+                                  np.zeros_like(out_p[cursor:]))

@@ -184,6 +184,30 @@ class TestStepProgramCensus:
     assert stats["scheduler"]["cancelled"] == 1
     assert stats["scheduler"]["finished"] == 3
 
+  def test_block_fill_counters_count_rows_of_every_step(self, tiny_lm):
+    """`attend_query_blocks` / `attend_block_queries`: per step the query
+    blocks the ragged kernel runs (ceil(row_len / Bq) a row) and the valid
+    queries in them, known on the host. Without a draft source or prefix
+    hits every prompt token and every decode token is one query, and a
+    request's first output rides its last prefill chunk."""
+    task, theta = tiny_lm
+    eng = _Engine(task, theta)                    # pages of 4: Bq is 8
+    bq = eng._AttentionLayers()[0].RaggedQueryBlock(4)
+    assert eng._attend_bq == bq == 8
+    reqs = [([5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17], 5),
+            ([3, 1], 6), ([2, 2, 2], 4)]
+    _RunStream(eng, reqs)
+    stats = eng.Stats()
+    assert observe_schema.ENGINE_STATS_REQUIRED <= set(stats)
+    queries = stats["attend_block_queries"]
+    blocks = stats["attend_query_blocks"]
+    assert queries == (stats["prompt_tokens"] + stats["tokens_emitted"]
+                       - len(reqs))
+    # chunks are at most prefill_chunk = 4 <= Bq tokens: a block a live row
+    # a step, so the fill is queries over blocks x Bq and below one
+    assert 0 < blocks <= queries <= blocks * bq
+    assert blocks >= stats["steps"]
+
   def test_legacy_trio_still_compiles_three(self, tiny_lm):
     """The comparison baseline keeps its three shapes — the 3 -> 1
     collapse is observable in the census, not just asserted in docs."""
