@@ -4,6 +4,7 @@ peak bytes, and the fence that closes a timing."""
 from __future__ import annotations
 
 import os
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -48,10 +49,13 @@ def ConfigureCache() -> str:
 
 class CompileClock:
   """Sums what JAX reports it spent tracing, lowering and compiling (or
-  fetching from the cache), and counts cache hits and misses."""
+  fetching from the cache), counts cache hits and misses, and keeps each
+  event with the time it was reported at (time.perf_counter), so that a run
+  can say which of them fell inside its window."""
 
   def __init__(self):
     import jax
+    self.events: list[tuple[float, str, float]] = []   # (at, event, seconds)
     self.seconds = 0.0
     self.hits = 0
     self.misses = 0
@@ -61,6 +65,7 @@ class CompileClock:
   def _OnDuration(self, event: str, secs: float, **_) -> None:
     if event in _COMPILE_EVENTS:
       self.seconds += secs
+      self.events.append((time.perf_counter(), event.rsplit("/", 1)[-1], secs))
 
   def _OnEvent(self, event: str, **_) -> None:
     if event == "/jax/compilation_cache/cache_hits":

@@ -26,6 +26,20 @@ def PackedOccupancy(run):
   return 100.0 * (inside[-1][3] - inside[0][3]) / (steps * run["packed_t"])
 
 
+def AttendBlockFill(run):
+  """Percent of the ragged kernel's query rows that held a valid query:
+  queries over (blocks x Bq), between the first and the last step completion
+  in the window. A decode row fills one row of its block (1 / Bq), a prefill
+  chunk fills whole blocks. None where the program counts no blocks."""
+  t0, t1 = run["window"]
+  inside = [a for r, a in zip(run["step_records"], run["attend_blocks"])
+            if t0 <= r[0] <= t1]
+  blocks = inside[-1][0] - inside[0][0]
+  if not run["attend_bq"] or blocks <= 0:
+    return None
+  return 100.0 * (inside[-1][1] - inside[0][1]) / (blocks * run["attend_bq"])
+
+
 def KvPoolPeak(run):
   kv = run["kv_pages"]
   return 100.0 * kv["peak_in_use"] / kv["num_pages"]

@@ -104,3 +104,30 @@ def CompareLogits(got, want, tol: float) -> tuple[bool, dict]:
                                            for x in diff.max(-1)],
               "argmax_agree": int((got.argmax(-1) == want.argmax(-1)).sum()),
               "positions": int(got.shape[0]), "tolerance": tol}
+
+
+def RangeLeavingOneOut(values) -> tuple[float, float, float]:
+  """(median, range, range with the farthest run left out) of one metric's
+  readings over a set of runs: how the driver's check judges whether a set is
+  steady enough to tell a change ("a spread leaves out the run farthest from
+  its median where that narrows it"). The run left out is the one farthest
+  from the median, which is the smallest or the largest; where both lie
+  equally far, the one whose leaving narrows the range more. One far-off run
+  in a set therefore does no harm, and two do. Fewer than three runs leave
+  nothing out."""
+  xs = sorted(float(v) for v in values)
+  if not xs:
+    raise ValueError("no readings")
+  median = statistics.median(xs)
+  full = xs[-1] - xs[0]
+  if len(xs) < 3:
+    return median, full, full
+  without_low, without_high = xs[-1] - xs[1], xs[-2] - xs[0]
+  low_far, high_far = median - xs[0], xs[-1] - median
+  if low_far > high_far:
+    left = without_low
+  elif high_far > low_far:
+    left = without_high
+  else:
+    left = min(without_low, without_high)
+  return median, full, left

@@ -5,6 +5,7 @@ clock, and closes the window."""
 
 from __future__ import annotations
 
+import gc
 import importlib
 import threading
 import time
@@ -13,12 +14,15 @@ import numpy as np
 
 from benchmarks.harness import model as model_lib
 from benchmarks.harness import readings
+from benchmarks.harness import spans
 from benchmarks.harness import traffic as traffic_lib
 
 _OK_REASONS = (None, "length", "eos")
 _CLIENT_POLL_S = 1e-3     # how often the client looks at its streams
 _TRACE_TAIL_S = 6.0       # a traced run traces the window's last seconds
 _PROBE_PATIENCE_S = 900.0  # the probe's first call in a checkout compiles
+_LONG_PASS_S = 0.020      # a client pass or a collection worth a note
+_NOTED = 200              # such entries a note lists at most
 
 
 class _Stream:
@@ -47,10 +51,12 @@ class StepRecorder:
     self.engine = engine
     self.records: list[tuple] = []     # (t_end, dur, steps, tokens_done)
     self.rows: list[list[tuple[int, int]]] = []
+    self.attend: list[tuple[int, int]] = []   # (query blocks, queries) so far
     reg = engine.metrics
     self._counters = [reg.Counter("serving/" + k) for k in
                       ("steps", "prompt_tokens", "prefix_hit_tokens",
-                       "tokens_emitted")]
+                       "tokens_emitted", "attend_query_blocks",
+                       "attend_block_queries")]
     self._inner = engine.StepOnce
     engine.StepOnce = self._StepOnce
 
@@ -60,7 +66,8 @@ class StepRecorder:
     t0 = time.perf_counter()
     n = self._inner()
     t1 = time.perf_counter()
-    steps, prompt, shared, emitted = (c.value for c in self._counters)
+    steps, prompt, shared, emitted, blocks, queries = (
+        c.value for c in self._counters)
     if not self.records or steps != self.records[-1][2]:
       rows = []
       after = set()
@@ -72,6 +79,7 @@ class StepRecorder:
       rows.extend((1, p + 1) for i, p in live_before.items() if i not in after)
       self.records.append((t1, t1 - t0, steps, prompt + shared + emitted))
       self.rows.append(rows)
+      self.attend.append((blocks, queries))
     return n
 
   def Detach(self):
@@ -124,6 +132,88 @@ class LogitProbe:
 
   def Detach(self):
     self.engine._compile_log.Call = self._inner
+
+
+class GcWatch:
+  """The interpreter's garbage collections while it is open (gc.callbacks):
+  count and seconds by generation, and each one of _LONG_PASS_S or more with
+  its time. A collection holds the interpreter, so it stops the engine's
+  thread and the client's alike."""
+
+  def __init__(self, clock=time.perf_counter):
+    self._clock = clock
+    self.by_generation: dict[int, list] = {}    # generation: [count, seconds]
+    self.long: list[tuple[float, int, float]] = []   # (start, generation, s)
+    self._t0 = None
+
+  def _On(self, phase, info):
+    now = self._clock()
+    if phase == "start":
+      self._t0 = now
+    elif self._t0 is not None:
+      tot = self.by_generation.setdefault(info["generation"], [0, 0.0])
+      tot[0] += 1
+      tot[1] += now - self._t0
+      if now - self._t0 >= _LONG_PASS_S and len(self.long) < _NOTED:
+        self.long.append((self._t0, info["generation"], now - self._t0))
+      self._t0 = None
+
+  def __enter__(self):
+    gc.callbacks.append(self._On)
+    return self
+
+  def __exit__(self, *exc):
+    gc.callbacks.remove(self._On)
+
+
+def _WindowNotes(ctx, t0, t1, steps, gc_watch, client_gaps):
+  """What every run says about where its window went (tracing on or off):
+  the engine's own step records reduced to note step_stalls, and beside them
+  what the other thread and the interpreter were doing at those times: the
+  client's long passes with the CPU time that went into them (none, in the
+  thread and in the whole process: the machine stood still), the long
+  collections, the compile events. Times are seconds from the window's
+  start; the lead-in is negative."""
+  records = spans.StepRecords({"window": (t0, t1)})
+  if records is not None:
+    ctx.Note("step_stalls", spans.WindowReport(records, t0, t1))
+    # every step of the window, for whoever reads the run afterwards:
+    # [step, start_s, loop_s, rows, prefill_tokens, *segments_s]
+    ctx.Note("step_records", [
+        [s.step, round(s.start_ts - t0, 6), round(s.loop_s, 6), s.rows,
+         s.prefill_tokens, *(round(x, 6) for x in s.segments_s)]
+        for s in records], quiet=True)
+  longs = client_gaps["long"]
+  ctx.Note("client_gaps", {
+      "long_pass_ms": 1e3 * _LONG_PASS_S,
+      "passes": client_gaps["passes"], "long": len(longs),
+      "long_s": sum(x[1] for x in longs),
+      # a pass in which neither this thread nor any other of the process
+      # used the CPU was the process standing still, not the interpreter
+      "at_s_ms_thread_cpu_ms_process_cpu_ms": [
+          [round(t - t0, 3), round(1e3 * d, 1), round(1e3 * th, 1),
+           round(1e3 * pr, 1)] for t, d, th, pr in longs[:_NOTED]]})
+  # the window on the machine's monotonic clock, which every process shares
+  ctx.Note("window_perf_counter", [t0, t1])
+  ctx.Note("gc", {
+      "by_generation": {str(g): {"collections": n, "seconds": round(sec, 4)}
+                        for g, (n, sec) in sorted(
+                            gc_watch.by_generation.items())},
+      "long_at_s_generation_ms": [[round(t - t0, 3), g, round(1e3 * d, 1)]
+                                  for t, g, d in gc_watch.long]})
+  ctx.Note("compiles_in_window", [
+      [round(t - t0, 3), name, round(sec, 4)]
+      for t, name, sec in ctx.compile_clock.events
+      if t0 <= t <= t1])
+  # tokens per second in each second since the clients' start: the start's
+  # wave of prefill and where it ends, which `lead_in_s` has to cover
+  t_gen0 = ctx.t_gen0
+  ctx.Note("tok_s_by_second_from_start", _BySlice(
+      steps, t_gen0, t_gen0 + int(t1 - t_gen0), int(t1 - t_gen0)))
+  # every step completion since then with the tokens done so far: what the
+  # window would have read behind any other lead-in (readings.TokenWindowRate)
+  ctx.Note("step_completions_from_start", [
+      [round(t - t_gen0, 4), n] for t, n in steps if t >= t_gen0], quiet=True)
 
 
 def Run(ctx) -> dict:
@@ -189,7 +279,9 @@ def Run(ctx) -> dict:
     n_warm_steps = len(recorder.records)
     ctx.Note("ready_s", time.perf_counter() - ctx.t_process)
 
-    streams = _Drive(ctx, engine, tr, requests, prompts, lead, died, geo)
+    with GcWatch() as gc_watch:
+      streams, client_gaps = _Drive(ctx, engine, tr, requests, prompts, lead,
+                                    died, geo)
     if ctx.trace and ctx.trace_started:
       jax.profiler.stop_trace()
       ctx.trace_started = False
@@ -209,6 +301,7 @@ def Run(ctx) -> dict:
   rate, tokens, span = readings.TokenWindowRate(steps, t0, t1)
   ctx.Note("serve_tok_s_between_steps", {"tok_s": rate, "tokens": tokens,
                                          "seconds": span})
+  _WindowNotes(ctx, t0, t1, steps, gc_watch, client_gaps)
   sampled = [s for s in streams if s.req.sampled]
   gaps = []
   for s in streams:
@@ -270,6 +363,8 @@ def Run(ctx) -> dict:
       "chips": 1, "sizes": sizes, "packed_t": packed_t,
       "window": (t0, t1), "step_records": recorder.records[n_warm_steps:],
       "step_rows": recorder.rows[n_warm_steps:],
+      "attend_blocks": recorder.attend[n_warm_steps:],
+      "attend_bq": getattr(engine, "_attend_bq", 0),
       "step_durations_ms": [d * 1e3 for _, d in in_win],
       "window_steps": len(in_win),
       "itl_gaps_ms": gaps, "ttft_ms": ttft, "gen_late_ms": late,
@@ -318,7 +413,10 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
   """The client: one loop on this thread. Open loop: submits each request
   when it is due, timed from then. Closed loop: each of the clients sends
   its next request when its last one finished. Every `client_poll_ms` it
-  looks at each open stream and stamps the tokens that arrived."""
+  looks at each open stream and stamps the tokens that arrived. Returns the
+  streams and its own long passes (a pass is a millisecond's sleep and a
+  look at 64 streams: one of 20 ms or more means this thread was kept from
+  running), to lay beside the engine's long steps."""
   import jax
   poll = _CLIENT_POLL_S
   open_loop = tr["loop"] == "open"
@@ -326,7 +424,10 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
   pending = list(streams)
   live: list[_Stream] = []
   cycle = 0
-  t_gen0 = time.perf_counter()
+  # this loop's passes over _LONG_PASS_S: (start, seconds, this thread's CPU
+  # seconds in it, the whole process's)
+  gaps = {"passes": 0, "long": []}
+  t_gen0 = ctx.t_gen0 = time.perf_counter()
   ctx.t_win0 = t_gen0 + lead
   ctx.t_win1 = ctx.t_win0 + ctx.seconds
   ctx.setup_s = ctx.t_win0 - ctx.t_process
@@ -344,8 +445,15 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
     except Exception as e:  # noqa: BLE001 - a refused request is a failed one
       s.error = repr(e)
 
+  last, last_cpu = t_gen0, (time.thread_time(), time.process_time())
   while True:
     now = time.perf_counter()
+    cpu = (time.thread_time(), time.process_time())
+    gaps["passes"] += 1
+    if now - last > _LONG_PASS_S:
+      gaps["long"].append((last, now - last, cpu[0] - last_cpu[0],
+                           cpu[1] - last_cpu[1]))
+    last, last_cpu = now, cpu
     if now >= ctx.t_win1:
       break
     if died:
@@ -377,7 +485,7 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
     live[:] = still
     time.sleep(poll)
   ctx.Note("closed_loop_cycles", cycle)
-  return streams
+  return streams, gaps
 
 
 def _ProbeOneStep(probe, engine, streams, prompts, died):

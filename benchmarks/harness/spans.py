@@ -1,6 +1,7 @@
 """What the program's own spans, step records and scope names say about a
 run: the readers of step_span_ms, step_host_ms, step_host_share,
-train_host_ms, idle_unspanned_share and busy_unscoped_share share this.
+step_h2d_ms, step_stall_share, train_host_ms, idle_unspanned_share and
+busy_unscoped_share share this, and every serving run's note step_stalls.
 
 Three sources:
 - the serving engine's step records (lingvo_tpu.observe.trace: one StepTrace
@@ -55,6 +56,8 @@ _SCOPE = re.compile(r"(?:^|[/(])(" + "|".join(SCOPES) + r")(?=[/)]|$)")
 # (lingvo_tpu.observe.trace.STEP_SEGMENTS by position)
 _DISPATCH, _DEVICE_WAIT = 5, 6
 _TRACE_TAIL_S = 6.0      # serve_cell._TRACE_TAIL_S: what a traced run traces
+_STALL_FACTOR = 2.0      # a period over this many median periods is a stall
+_STALLS_KEPT = 40        # stalled steps a report lists (the longest)
 
 
 # -- where the traced run's file is -------------------------------------------
@@ -466,6 +469,35 @@ def StepRecords(run):
   return best or None
 
 
+def StepsFromSpans(spans) -> list:
+  """The step records a traced stretch's `lingvo/serve/` spans amount to (the
+  plain form's "spans"; times in ns become seconds): one StepTrace per
+  `.../step` span, its segments the phase spans inside it in the order of
+  observe.trace.STEP_SEGMENTS, `loop` the gap since the step before. For a
+  recorded trace read without the process that made it."""
+  from lingvo_tpu.observe import trace as trace_lib
+  segments = trace_lib.STEP_SEGMENTS
+  prefix = SPAN_PREFIX + "serve/"
+  evs = sorted((s, s + d, n[len(prefix):], a) for _, n, s, d, a in spans
+               if n.startswith(prefix))
+  out, last_end = [], None
+  for s0, e0, name, args in evs:
+    if name != "step":
+      continue
+    acc, i = [0.0] * len(segments), 0
+    for s, e, child, _ in evs:
+      if child != "step" and s >= s0 and e <= e0 and child in segments[i:]:
+        i = segments.index(child, i)
+        acc[i] += (e - s) * 1e-9
+    out.append(trace_lib.StepTrace(
+        int(args.get("step", len(out))), s0 * 1e-9,
+        (s0 - last_end) * 1e-9 if last_end is not None else 0.0, tuple(acc),
+        int(args.get("valid_tokens", 0)), int(args.get("prefill_tokens", 0)),
+        int(args.get("rows", 0))))
+    last_end = e0
+  return out
+
+
 def HostGaps(steps) -> list[tuple[float, float]]:
   """(host seconds, period seconds) for each pair of consecutive steps:
   from step n's results arriving (its device_wait over) to step n + 1's
@@ -533,6 +565,89 @@ def StepHostMs(run):
   print(json.dumps({"note": "step_trace_cost",
                     "value": _TraceCost(run, steps)}), flush=True)
   return 1e3 * statistics.median(h for h, _ in gaps)
+
+
+def StepH2dMs(run):
+  """Median `h2d` phase of the window's steps: the host placing the step's
+  arguments on the device, while the device waits for them."""
+  steps = StepRecords(run)
+  if steps is None:
+    return None
+  return 1e3 * statistics.median(s.Phases()["h2d"] for s in steps)
+
+
+def Periods(steps) -> list[tuple]:
+  """(step record, period seconds) for each step whose predecessor is also on
+  record: from the one's end to the other's. A period is the step's span
+  plus the loop's turn-around before it, so the periods tile the window."""
+  return [(b, b.end_ts - a.end_ts) for a, b in zip(steps, steps[1:])
+          if b.step == a.step + 1]
+
+
+def WindowReport(steps, t0: float, t1: float, factor: float = _STALL_FACTOR,
+                 keep: int = _STALLS_KEPT) -> dict | None:
+  """Where a window's seconds went, from its step records alone: the steps,
+  the median period and their product beside the window's length (what the
+  product leaves is time in which no step of the usual length completed),
+  every period over `factor` times the median with its time in the window,
+  its length and its seconds per phase (the `keep` longest, in order of
+  time), their sum and their sum's excess over the median, the periods
+  between 1.25 times the median and `factor` times counted the same way, the
+  phases' p50 and p95, and the median period and `h2d` by sixth of the
+  window (a process that changes its mode inside a run shows there)."""
+  pairs = Periods(steps)
+  if not pairs:
+    return None
+  periods = [p for _, p in pairs]
+  median = statistics.median(periods)
+  long_ = [(s, p) for s, p in pairs if p > factor * median]
+  slow = [p for p in periods if 1.25 * median < p <= factor * median]
+
+  def _Row(s, p):
+    phases = {k: round(v, 5) for k, v in s.Phases().items()}
+    phases["loop"] = round(s.loop_s, 5)
+    return {"at_s": round(s.end_ts - p - t0, 3), "period_s": round(p, 4),
+            "step": s.step, "rows": s.rows, "prefill_tokens": s.prefill_tokens,
+            "phases_s": phases}
+
+  sixths = [[] for _ in range(6)]
+  for s, p in pairs:
+    k = max(0, min(5, int(6 * (s.end_ts - t0) / (t1 - t0))))
+    sixths[k].append((p, s.Phases()["h2d"]))
+  kept = sorted(sorted(long_, key=lambda sp: -sp[1])[:keep],
+                key=lambda sp: sp[0].end_ts)
+  return {
+      "steps": len(steps), "period_ms_median": 1e3 * median,
+      "steps_x_median_s": len(steps) * median, "window_s": t1 - t0,
+      "periods_s": sum(periods),
+      "stall_factor": factor, "stalls": len(long_),
+      "stall_periods_s": sum(p for _, p in long_),
+      "stall_excess_s": sum(p - median for _, p in long_),
+      "slow_periods": len(slow),
+      "slow_excess_s": sum(p - median for p in slow),
+      "stalled_steps": [_Row(s, p) for s, p in kept],
+      "phases_ms": {k: {q: round(v, 3) for q, v in d.items()}
+                    for k, d in _PhaseTable(steps).items()},
+      "period_ms_p50_by_sixth": [
+          round(1e3 * statistics.median(p for p, _ in x), 2) if x else None
+          for x in sixths],
+      "h2d_ms_p50_by_sixth": [
+          round(1e3 * statistics.median(h for _, h in x), 3) if x else None
+          for x in sixths]}
+
+
+def StepStallShare(run):
+  """Percent of the window's summed step periods that lies in periods over
+  twice the median period: seconds in which the engine was held up, by the
+  host, the lock or the device. Every serving run, traced or not, prints
+  the same window's report as note step_stalls (serve_cell.Run)."""
+  steps = StepRecords(run)
+  if steps is None:
+    return None
+  report = WindowReport(steps, *run["window"])
+  if report is None:
+    return None
+  return 100.0 * report["stall_periods_s"] / report["periods_s"]
 
 
 def StepHostShare(run):

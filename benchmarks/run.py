@@ -33,7 +33,7 @@ sys.path.insert(0, ROOT)
 class Ctx:
   """One run's arguments and what the cell code leaves for the line."""
 
-  def __init__(self, cell, args, out_dir):
+  def __init__(self, cell, args, out_dir, compile_clock):
     self.cell = cell
     self.seed = args.seed
     self.seconds = float(args.seconds)
@@ -43,13 +43,17 @@ class Ctx:
     self.trace_dir = os.path.join(out_dir, "trace_" + cell["name"])
     self.t_process = T_PROCESS
     self.setup_s = None
-    self.t_win0 = self.t_win1 = None
+    self.t_gen0 = self.t_win0 = self.t_win1 = None
     self.trace_started = False
+    self.compile_clock = compile_clock
     self.notes = {}
 
-  def Note(self, key, value):
+  def Note(self, key, value, quiet=False):
+    """Into <out>/<workload>.notes.jsonl and, unless `quiet` (a series too
+    long to read in a log), on a line of standard output."""
     self.notes[key] = value
-    print(json.dumps({"note": key, "value": value}, default=str), flush=True)
+    if not quiet:
+      print(json.dumps({"note": key, "value": value}, default=str), flush=True)
 
 
 def main(argv=None) -> int:
@@ -82,7 +86,7 @@ def main(argv=None) -> int:
     return 3
   cache_dir = device.ConfigureCache()
   clock = device.CompileClock()
-  ctx = Ctx(cell, args, args.out)
+  ctx = Ctx(cell, args, args.out, clock)
   ctx.Note("run", {"workload": cell["name"], "seed": args.seed,
                    "seconds": args.seconds, "trace": args.trace,
                    "rehearse": args.rehearse, "cache_dir": cache_dir,

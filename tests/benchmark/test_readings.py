@@ -76,3 +76,29 @@ def test_the_plain_total_is_all_tokens_over_the_whole_window():
       4 * 32768 / (107.0 - 100.0))
   assert r.PlainTotal(intervals, 32768, 4) == pytest.approx(
       4 * 32768 / 7.0 / 4)
+
+
+@pytest.mark.parametrize("values,median,full,left", [
+    # one far-off run (a stalled window) is left out: the rest decide
+    ([4000, 4010, 4020, 3178, 4005, 4015], 4007.5, 842, 20),
+    # two far-off runs: one is left out, the other still decides
+    ([4000, 4010, 4020, 3178, 3783, 4015], 4005.0, 842, 237),
+    # the farthest run lies above the median
+    ([60.2, 60.5, 61.2, 62.2, 64.6, 70.9], 61.7, 10.7, 4.4),
+    # smallest and largest equally far: the one whose leaving narrows more
+    ([10, 13, 15, 16, 20], 15, 10, 6),     # leaving 20 out: 16 - 10
+    ([10, 14, 15, 17, 20], 15, 10, 6),     # leaving 10 out: 20 - 14
+    # all alike, and sets too small to leave anything out
+    ([5, 5, 5, 5], 5, 0, 0),
+    ([3, 9], 6, 6, 6),
+    ([7], 7, 0, 0),
+])
+def test_range_leaving_the_farthest_run_out(values, median, full, left):
+  got = r.RangeLeavingOneOut(values)
+  assert got == pytest.approx((median, full, left))
+  assert r.RangeLeavingOneOut(list(reversed(values))) == pytest.approx(got)
+
+
+def test_range_leaving_one_out_needs_a_reading():
+  with pytest.raises(ValueError):
+    r.RangeLeavingOneOut([])

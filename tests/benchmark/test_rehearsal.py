@@ -42,6 +42,20 @@ def test_rehearsal_runs_the_whole_command_line(cell, tmp_path):
   assert "tolerance" in detail
   notes = [json.loads(x) for x in done.stdout.strip().splitlines()[:-1]]
   assert all("note" in n for n in notes), "only notes before the last line"
+  by_name = {n["note"]: n["value"] for n in notes}
+  if "client" in by_name:       # a serving cell says where its window went
+    stalls = by_name["step_stalls"]
+    assert stalls["steps"] > 2 and stalls["period_ms_median"] > 0
+    assert {"lock_wait", "h2d", "device_wait", "commit", "loop"} <= set(
+        stalls["phases_ms"])
+    assert stalls["stalls"] >= len(stalls["stalled_steps"])
+    assert by_name["client_gaps"]["passes"] > 100
+    assert set(by_name["gc"]) == {"by_generation", "long_at_s_generation_ms"}
+    assert by_name["compiles_in_window"] == [], "nothing compiles in a window"
+    assert "step_records" not in by_name, "too long for a log"
+    with open(os.path.join(str(tmp_path), cell + ".notes.jsonl")) as f:
+      kept = json.loads(f.readlines()[-1])["notes"]
+    assert len(kept["step_records"]) == stalls["steps"]
 
 
 def test_without_a_tpu_a_measured_run_is_an_error(tmp_path):

@@ -23,6 +23,9 @@ SERVE_READERS = [b + s for b in ("step_span_ms", "step_host_ms",
                                  "step_host_share", "idle_unspanned_share",
                                  "busy_unscoped_share")
                  for s in (".lat", ".tput")]
+STALL_READERS = [b + s for b in ("step_stall_share", "step_h2d_ms",
+                                 "attend_block_fill")
+                 for s in (".lat", ".tput")]
 TRAIN_READERS = ["train_host_ms", "idle_unspanned_share",
                  "busy_unscoped_share"]
 
@@ -416,7 +419,7 @@ def test_a_trace_without_spans_or_scopes_gives_nothing_to_read(
 def test_every_new_metric_has_its_reader_and_its_entry():
   bench = spec.LoadBenchmark()
   entries = {m["name"]: m for m in bench["per_layer"]}
-  for name in set(SERVE_READERS + TRAIN_READERS):
+  for name in set(SERVE_READERS + TRAIN_READERS + STALL_READERS):
     assert name in entries, name
     assert spec.LayerMetricReader(name) is not None
   train = ["dense1b_train_packed", "dense8b_train_2x2"]
@@ -424,3 +427,187 @@ def test_every_new_metric_has_its_reader_and_its_entry():
   assert entries["idle_unspanned_share"]["workloads"] == train
   assert entries["step_host_share.lat"]["workloads"] == ["dense1b_serve_chat"]
   assert entries["step_host_share.tput"]["moves"] == "serve_tok_s"
+
+
+# -- where a window's seconds went (step_stall_share, step_h2d_ms) -------------
+
+
+def _RecordedSteps(recorded, long_factor=None):
+  """A recorder holding the recorded trace's three chat steps (as
+  spans.StepsFromSpans reads them) and, with `long_factor`, one more after
+  them: the last whole one over again with its device_wait that many times
+  as long, which is what a stall of the device's queue looks like on
+  record. The recorded trace holds no long step of its own."""
+  from lingvo_tpu.observe import trace as trace_lib
+  steps = spans.StepsFromSpans(recorded["spans"])
+  rec = trace_lib.TraceRecorder()
+  for s in steps:
+    rec.StepDone(s.step, s.start_ts, s.loop_s, list(s.segments_s),
+                 s.valid_tokens, s.prefill_tokens, s.rows)
+  if long_factor:
+    whole = steps[1]
+    seg = list(whole.segments_s)
+    seg[spans._DEVICE_WAIT] *= long_factor
+    rec.StepDone(steps[-1].step + 1, steps[-1].end_ts + whole.loop_s,
+                 whole.loop_s, seg, whole.valid_tokens, 0, whole.rows)
+  return rec, steps
+
+
+def test_recorded_spans_give_back_the_step_records(recorded):
+  steps = spans.StepsFromSpans(recorded["spans"])
+  assert [s.step for s in steps] == [273, 274, 275]
+  whole = steps[1]            # the other two are cut by the recording's edges
+  assert whole.span_s == pytest.approx(0.09193, abs=1e-4)
+  assert set(whole.Phases()) >= {"lock_wait", "h2d", "device_wait", "commit"}
+  assert whole.Phases()["h2d"] == pytest.approx(5.42e-3, rel=1e-3)
+  assert 0 < whole.loop_s < 1e-3
+  # the segments tile the step span but for the clock reads between them
+  span = next(d for _, n, _, d, a in recorded["spans"]
+              if n == "lingvo/serve/step" and a["step"] == 274)
+  assert whole.span_s == pytest.approx(span * 1e-9, rel=2e-3)
+
+
+@pytest.mark.parametrize("sfx", [".lat", ".tput"])
+def test_h2d_and_stall_share_on_the_recorded_steps(recorded, sfx):
+  rec, steps = _RecordedSteps(recorded)
+  run = {"window": (steps[0].end_ts - 1e-6, steps[-1].end_ts + 1e-6)}
+  assert _Read("step_h2d_ms" + sfx, run) == pytest.approx(5.42, abs=0.01)
+  assert _Read("step_stall_share" + sfx, run) == 0.0     # three even steps
+  del rec
+
+
+@pytest.mark.parametrize("sfx", [".lat", ".tput"])
+def test_a_long_step_after_the_recorded_ones_is_a_stall(recorded, sfx):
+  rec, steps = _RecordedSteps(recorded, long_factor=3.0)
+  run = {"window": (steps[0].end_ts - 1e-6, steps[-1].end_ts + 1.0)}
+  records = spans.StepRecords(run)
+  assert len(records) == 4
+  periods = [p for _, p in spans.Periods(records)]
+  assert periods[-1] > 2.0 * periods[0]
+  share = _Read("step_stall_share" + sfx, run)
+  assert share == pytest.approx(100.0 * periods[-1] / sum(periods))
+  assert 55.0 < share < 65.0
+  report = spans.WindowReport(records, *run["window"])
+  assert report["stalls"] == 1 and report["slow_periods"] == 0
+  assert report["stall_periods_s"] == pytest.approx(periods[-1])
+  assert report["stall_excess_s"] == pytest.approx(
+      periods[-1] - report["period_ms_median"] * 1e-3)
+  (row,) = report["stalled_steps"]
+  assert row["step"] == 276 and row["period_s"] == pytest.approx(
+      periods[-1], abs=1e-4)
+  # the phase that held the step is on record: the device's queue here
+  assert max(row["phases_s"], key=row["phases_s"].get) == "device_wait"
+  assert row["phases_s"]["device_wait"] == pytest.approx(3 * 0.08513, rel=1e-3)
+  assert report["steps_x_median_s"] == pytest.approx(
+      4 * report["period_ms_median"] * 1e-3)
+  del rec
+
+
+def test_window_report_counts_stalls_slow_steps_and_sixths():
+  from lingvo_tpu.observe import trace as trace_lib
+  seg = [0.0] * len(trace_lib.STEP_SEGMENTS)
+  seg[4], seg[6] = 0.004, 0.056                       # h2d, device_wait
+  steps, t = [], 0.0
+  # 60 steps of 60 ms; step 20 waits 200 ms for the lock, step 40's h2d
+  # takes 34 ms (a slow step, not a stall); from step 30 on h2d is 7 ms
+  for i in range(60):
+    s = list(seg)
+    loop = 0.0
+    if i == 20:
+      s[0] = 0.2
+    if i == 40:
+      s[4] = 0.034
+    if i >= 30 and i != 40:
+      s[4], s[6] = 0.007, 0.053
+    steps.append(trace_lib.StepTrace(i + 1, t, loop, tuple(s), 8, 0, 4))
+    t += sum(s)
+  report = spans.WindowReport(steps, 0.0, t)
+  assert report["steps"] == 60
+  assert report["period_ms_median"] == pytest.approx(60.0)
+  assert report["stalls"] == 1 and report["slow_periods"] == 1
+  assert report["stall_periods_s"] == pytest.approx(0.26)
+  assert report["stall_excess_s"] == pytest.approx(0.2)
+  assert report["slow_excess_s"] == pytest.approx(0.03)
+  (row,) = report["stalled_steps"]
+  assert row["step"] == 21 and row["at_s"] == pytest.approx(1.2)
+  assert row["phases_s"]["lock_wait"] == pytest.approx(0.2)
+  # what the steps at their usual length leave of the window is the loss
+  assert report["window_s"] - report["steps_x_median_s"] == pytest.approx(
+      0.23, abs=1e-6)
+  assert report["h2d_ms_p50_by_sixth"] == pytest.approx(
+      [4.0, 4.0, 4.0, 7.0, 7.0, 7.0])
+  assert report["phases_ms"]["h2d"]["p50"] == pytest.approx(5.5)
+  assert len(report["period_ms_p50_by_sixth"]) == 6
+
+
+def test_window_report_keeps_the_longest_stalls_in_order_of_time():
+  from lingvo_tpu.observe import trace as trace_lib
+  seg = [0.0] * len(trace_lib.STEP_SEGMENTS)
+  steps, t = [], 0.0
+  for i in range(200):
+    s = list(seg)
+    s[6] = 0.05 if i % 2 else 0.15 + 0.001 * i      # every other one stalls
+    steps.append(trace_lib.StepTrace(i + 1, t, 0.0, tuple(s), 1, 0, 1))
+    t += s[6]
+  report = spans.WindowReport(steps, 0.0, t, factor=2.0, keep=5)
+  assert report["stalls"] == 99 and len(report["stalled_steps"]) == 5
+  kept = [r["step"] for r in report["stalled_steps"]]
+  assert kept == sorted(kept) == [191, 193, 195, 197, 199]
+  assert spans.WindowReport(steps[:1], 0.0, 1.0) is None    # no period
+
+
+def test_a_run_without_step_records_has_no_stall_metrics():
+  run = {"window": (-2.0, -1.0)}
+  for name in STALL_READERS[:4]:
+    assert _Read(name, run) is None
+
+
+# -- attend_block_fill --------------------------------------------------------
+
+
+def _AttendRun(per_step, bq=128):
+  """Steps 0.1 s apart; per_step: (blocks, queries) each step added."""
+  records, attend, blocks, queries = [], [], 0, 0
+  for i, (b, q) in enumerate(per_step):
+    blocks, queries = blocks + b, queries + q
+    records.append((100.0 + 0.1 * i, 0.09, i + 1, 0))
+    attend.append((blocks, queries))
+  return {"window": (99.95, 100.0 + 0.1 * len(per_step)),
+          "step_records": records, "attend_blocks": attend, "attend_bq": bq}
+
+
+@pytest.mark.parametrize("sfx", [".lat", ".tput"])
+def test_attend_block_fill_is_queries_over_block_rows(sfx):
+  # decode-only steps: 14 rows, one query in a block of 128 each
+  run = _AttendRun([(14, 14)] * 10)
+  assert _Read("attend_block_fill" + sfx, run) == pytest.approx(100 / 128)
+  # a chunk step: a dozen decode rows beside a 512-token chunk's four blocks
+  chunk = _AttendRun([(14, 14)] + [(16, 12 + 512)] * 4)
+  assert _Read("attend_block_fill" + sfx, chunk) == pytest.approx(
+      100.0 * 524 / (16 * 128))
+  # the window's first completion is the base: its own counts stay out
+  mixed = _AttendRun([(1000, 1000), (14, 14), (16, 524)])
+  assert _Read("attend_block_fill" + sfx, mixed) == pytest.approx(
+      100.0 * 538 / (30 * 128))
+
+
+def test_attend_block_fill_without_the_counters_gives_nothing_to_read():
+  assert _Read("attend_block_fill.tput", _AttendRun([(0, 0)] * 5)) is None
+  assert _Read("attend_block_fill.tput", _AttendRun([(3, 3)] * 5, bq=0)) is None
+  run = _AttendRun([(3, 3)] * 5)
+  del run["attend_blocks"]            # a run of the harness before the metric
+  with pytest.raises(spec.NothingToRead):
+    _Read("attend_block_fill.lat", run)
+
+
+def test_stall_metrics_have_their_entries():
+  entries = {m["name"]: m for m in spec.LoadBenchmark()["per_layer"]}
+  for base, layer, unit in (("step_stall_share", "serving engine", "%"),
+                            ("step_h2d_ms", "serving engine", "ms"),
+                            ("attend_block_fill", "kernels", "%")):
+    lat, tput = entries[base + ".lat"], entries[base + ".tput"]
+    assert lat["layer"] == tput["layer"] == layer
+    assert lat["unit"] == tput["unit"] == unit
+    assert (lat["moves"], tput["moves"]) == ("itl_p95_ms", "serve_tok_s")
+    assert lat["workloads"] == ["dense1b_serve_chat"]
+    assert tput["workloads"] == ["dense1b_serve_docs"]

@@ -129,3 +129,27 @@ def test_prompt_ids_come_from_the_seed():
   assert a.min() >= 1 and a.max() < 32000
   np.testing.assert_array_equal(a, t.PromptIds(r, 3000000019, 32000))
   assert not np.array_equal(a, t.PromptIds(r, 3000000020, 32000))
+
+
+# what the docs cell read when its list was sized (tokens/s; ledger, PR 26):
+# the dense model, and the OLMoE cell a later PR brings back
+_DOCS_TOK_S_TODAY, _DOCS_TOK_S_OLMOE = 4000.0, 8104.0
+
+
+@pytest.mark.parametrize("tok_s", [2 * _DOCS_TOK_S_TODAY, _DOCS_TOK_S_OLMOE])
+def test_docs_list_does_not_run_out_at_twice_today_s_rate(tok_s):
+  """A closed loop that outruns `requests_per_s_hint` goes round its list
+  again (closed_loop_cycles > 0) and the window then serves other requests
+  than the list states. The list has to outlast a system twice as fast as
+  today's, lead-in and window together."""
+  tr = _Mix("docs")
+  seconds = 30
+  reqs = t.Generate(tr, seconds, SEEDS[0], 32)
+  clients = t.NumClients(tr, 32)
+  work = t.TotalWork(reqs)
+  tokens_per_request = (work["prompt_tokens"] + work["new_tokens"]) / len(reqs)
+  finished = tok_s / tokens_per_request * (tr["lead_in_s"] + seconds)
+  # every client holds one request; each one finished draws the next
+  assert len(reqs) >= clients + finished
+  # and the list is no longer than it has to be (set-up makes every prompt)
+  assert len(reqs) <= clients + 1.5 * finished
