@@ -769,7 +769,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     return self._PostProj(theta, ctx), new_states
 
   def RaggedStep(self, theta, query_vec, cached_states: NestedMap,
-                 block_tables, rows):
+                 block_tables, rows, layer=None):
     """One PACKED continuous-batching step (core/ragged.py RaggedRows).
 
     query_vec: [1, T, D] — all rows' tokens flattened on one token axis;
@@ -782,6 +782,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     updated states). Same numerics per token as PagedStep — the ragged
     op twins (ops/ragged_block_attend.py) carry the bitwise proof at the
     op level.
+
+    layer: None when the pool is this layer's alone ([NP, P, N, H]); a
+    scalar index when every leaf arrives stacked over a repeat axis
+    ([L, NP, ...], RepeatedTransformerLayer's scan carry). The stack is
+    then read and written as ONE pool of L * NP pages with this layer's at
+    page base layer * NP, so no op slices or re-assembles a layer's pool.
     """
     from lingvo_tpu.ops import block_decode
     from lingvo_tpu.ops import ragged_block_attend
@@ -789,8 +795,16 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     assert p.rel_pos_emb_dim <= 0, (
         "RaggedStep computes positions from rows.pos; the T5 relative "
         "bias would use wrong buckets")
+    np_total, page_size = cached_states.key.shape[-4:-2]  # this layer's pages
+    base = 0
+    if layer is not None:
+      # [L, NP, ...] -> [L * NP, ...] (a bitcast, undone on the way out):
+      # this layer's pages are [base, base + np_total) of the flat pool
+      num_layers = cached_states.key.shape[0]
+      base = jnp.asarray(layer, jnp.int32) * np_total
+      cached_states = cached_states.Transform(
+          lambda x: x.reshape((-1,) + x.shape[2:]))
     k_pool, v_pool = cached_states.key, cached_states.value
-    np_total, page_size = k_pool.shape[0], k_pool.shape[1]
     b, t_pages = block_tables.shape
     t = query_vec.shape[1]
     pos = rows.pos.astype(jnp.int32)                               # [T]
@@ -812,11 +826,13 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     q = self._ScaleQuery(theta, q)
     # scatter each token's K/V through ITS row's block table before the
     # read (later tokens of the same prefill chunk attend to earlier ones);
-    # padding tokens write to the trash page (pool page np_total - 1)
+    # padding tokens write to the trash page (this layer's page np_total - 1).
+    # A table entry is clipped to the layer's range BEFORE the base is
+    # added, so no write and no read can reach another layer's pages
     logical = jnp.clip(pos // page_size, 0, t_pages - 1)
-    phys = jnp.clip(block_tables.astype(jnp.int32),
-                    0, np_total - 1)[row, logical]                 # [T]
-    phys = jnp.where(valid, phys, np_total - 1)
+    tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
+    phys = jnp.where(valid, tables[row, logical], np_total - 1) + base  # [T]
+    tables = tables + base
     off = jnp.where(valid, pos % page_size,
                     jnp.arange(t, dtype=jnp.int32) % page_size)
     quantized = "key_scale" in cached_states
@@ -834,6 +850,9 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if quantized:
       new_states.key_scale = k_scale
       new_states.value_scale = v_scale
+    if layer is not None:
+      new_states = new_states.Transform(
+          lambda x: x.reshape((num_layers, -1) + x.shape[1:]))
     eligible = (self.QuantizedDecodeEligible(page_size) if quantized
                 else self.BlockDecodeEligible(page_size))
     # token t attends over its row's slots [0, pos[t]]; q_end = 0 marks
@@ -842,20 +861,20 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if eligible:
       with jax.named_scope("ragged_attend"):
         ctx = ragged_block_attend.RaggedAttend(
-            q[0], k_pool, v_pool, block_tables, row, q_end,
+            q[0], k_pool, v_pool, tables, row, q_end,
             page_size=page_size, k_scale=k_scale, v_scale=v_scale,
             q_start=q_start, anc_lo=rows.anc_lo, anc_hi=rows.anc_hi)[None]
     else:
       # gather-dense fallback at token granularity: each token is a batch
       # row of one query over its row's materialized cache view (handles
       # logit cap / dropout / prob quant exactly like PagedStep's)
-      k_dense = block_decode.GatherPages(k_pool, block_tables)
-      v_dense = block_decode.GatherPages(v_pool, block_tables)
+      k_dense = block_decode.GatherPages(k_pool, tables)
+      v_dense = block_decode.GatherPages(v_pool, tables)
       if quantized:
         k_dense = kv_quant.DequantKv(
-            k_dense, block_decode.GatherScales(k_scale, block_tables))
+            k_dense, block_decode.GatherScales(k_scale, tables))
         v_dense = kv_quant.DequantKv(
-            v_dense, block_decode.GatherScales(v_scale, block_tables))
+            v_dense, block_decode.GatherScales(v_scale, tables))
       slot = jnp.arange(t_pages * page_size)[None, None, None, :]
       # padding tokens see slot 0 only (garbage, but never an all-masked
       # softmax row)

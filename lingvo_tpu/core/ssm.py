@@ -360,7 +360,8 @@ class GatedSSMLayer(base_layer.BaseLayer):
     return out, NestedMap(state=s_new)
 
   def RaggedStep(self, theta, query_vec, cached_states: NestedMap,
-                 block_tables, rows, collect_col_states: bool = False):
+                 block_tables, rows, collect_col_states: bool = False,
+                 layer=None):
     """Packed-token step (core/ragged.py RaggedRows): query_vec [1, T, D].
 
     The O(1) recurrence is inherently per-row, so the ragged step is the
@@ -371,14 +372,25 @@ class GatedSSMLayer(base_layer.BaseLayer):
     token order. rows.row_q_pos carries the slot-reuse reset trigger
     (q_pos == 0), which is why 0-token live rows ride with their true
     sequence position, never 0.
+
+    layer: as in MultiHeadedAttention.RaggedStep — with an index the slot
+    state arrives stacked [L, B, N, H, S]; this layer's part is cut out and
+    written back in place (`col_states` stays this layer's own).
     """
     del block_tables
     x_rows = query_vec[0][rows.row_cols]             # [B, wmax, D]
     wmax = x_rows.shape[1]
+    stack = cached_states.state
+    if layer is not None:
+      cached_states = NestedMap(state=jax.lax.dynamic_index_in_dim(
+          stack, layer, axis=0, keepdims=False))
     out_rows, new_states = self.PagedStep(
         theta, x_rows, cached_states, None, rows.row_q_pos, rows.row_len,
         collect_col_states=collect_col_states,
         col_parent=rows.col_parent if collect_col_states else None)
+    if layer is not None:
+      new_states.state = jax.lax.dynamic_update_index_in_dim(
+          stack, new_states.state, layer, axis=0)
     row = jnp.clip(rows.row_of.astype(jnp.int32), 0, x_rows.shape[0] - 1)
     col = jnp.clip(rows.col_of.astype(jnp.int32), 0, wmax - 1)
     return out_rows[row, col][None], new_states

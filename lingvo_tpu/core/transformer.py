@@ -194,20 +194,20 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
       return query_vec + out, new_states
 
   def RaggedStep(self, theta, query_vec, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False):
+                 ssm_col_states: bool = False, layer=None):
     """Packed-token continuous-batching step (core/ragged.py RaggedRows);
     query_vec [1, T, D]. Same pre-LN/residual wrapper and spec-verify
-    dispatch as PagedStep — only the inner mixer contract changes."""
+    dispatch as PagedStep — only the inner mixer contract changes.
+    layer: the mixer's state is stacked over a repeat axis and this is
+    its index there (MultiHeadedAttention.RaggedStep); None = its own."""
+    kw = {} if layer is None else {"layer": layer}
+    if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
+      kw["collect_col_states"] = True
     with jax.named_scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
     with jax.named_scope("atten"):
-      if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
-        out, new_states = self.atten.RaggedStep(
-            theta.atten, x, cached_states, block_tables, rows,
-            collect_col_states=True)
-      else:
-        out, new_states = self.atten.RaggedStep(
-            theta.atten, x, cached_states, block_tables, rows)
+      out, new_states = self.atten.RaggedStep(
+          theta.atten, x, cached_states, block_tables, rows, **kw)
       return query_vec + out, new_states
 
 
@@ -317,10 +317,10 @@ class TransformerLayer(base_layer.BaseLayer):
     return out, NestedMap(self_atten=new_sa)
 
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False):
+                 ssm_col_states: bool = False, layer=None):
     x, new_sa = self.self_atten.RaggedStep(
         theta.self_atten, inputs, cached_states.self_atten, block_tables,
-        rows, ssm_col_states=ssm_col_states)
+        rows, ssm_col_states=ssm_col_states, layer=layer)
     out = self.fflayer.FProp(theta.fflayer, x)
     return out, NestedMap(self_atten=new_sa)
 
@@ -433,14 +433,19 @@ class StackedTransformerLayers(base_layer.BaseLayer):
     return x, new_states
 
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False):
+                 ssm_col_states: bool = False, layer=None):
+    """layer: None here (distinct layers, each with a pool of its own); an
+    index when this stack is the body of a RepeatedTransformerLayer, whose
+    stacked states every x_layer then addresses by it."""
     kw = {"ssm_col_states": True} if ssm_col_states else {}
+    if layer is not None:
+      kw["layer"] = layer
     x = inputs
     new_states = NestedMap(x_layers=[])
-    for i, layer in enumerate(self.x_layers):
-      x, ns = layer.RaggedStep(theta.x_layers[i], x,
-                               cached_states.x_layers[i], block_tables,
-                               rows, **kw)
+    for i, x_layer in enumerate(self.x_layers):
+      x, ns = x_layer.RaggedStep(theta.x_layers[i], x,
+                                 cached_states.x_layers[i], block_tables,
+                                 rows, **kw)
       new_states.x_layers.append(ns)
     if self.p.final_ln:
       x = self.final_ln.FProp(theta.final_ln, x)
@@ -476,6 +481,11 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
   theta.body has every leaf stacked on axis 0 (length num_layers); FProp scans
   the body over that axis. Compile time is O(1) in depth; per-layer dropout
   folds the scan index into the step seed.
+
+  Paged decode states are stacked the same way ([L, NP, P, N, H] pools).
+  RaggedStep carries them through its scan and the body addresses layer
+  i's pages at base i * NP of the stack seen as one pool: as scanned
+  values XLA copied the stack and re-sliced a layer's pool every layer.
   """
 
   @classmethod
@@ -604,16 +614,37 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
 
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
                  ssm_col_states: bool = False):
+    """The stacked states are the scan's CARRY, one buffer from the caller's
+    (donated) argument to the returned state; layer i's mixers read and
+    write their part in place, addressed by `layer=i`. A leaf a mixer adds
+    for this step only (`col_states`) is its layer's own: a scanned output,
+    which comes back stacked."""
     kw = {"ssm_col_states": True} if ssm_col_states else {}
 
-    def _Body(carry, per_layer):
-      theta_i, states_i = per_layer
-      x, new_states = self.body.RaggedStep(theta_i, carry, states_i,
-                                           block_tables, rows, **kw)
-      return x, new_states
+    def _ByPath(tree):
+      return dict(jax.tree_util.tree_flatten_with_path(tree)[0])
 
-    out, new_states = jax.lax.scan(_Body, inputs,
-                                   (theta.body, cached_states.body))
+    carried = _ByPath(cached_states.body)
+
+    def _Body(carry, per_layer):
+      x, states = carry
+      theta_i, idx = per_layer
+      x, new_states = self.body.RaggedStep(theta_i, x, states, block_tables,
+                                           rows, layer=idx, **kw)
+      new = _ByPath(new_states)
+      states = jax.tree_util.tree_map_with_path(
+          lambda path, _: new[path], states)
+      added = jax.tree_util.tree_map_with_path(
+          lambda path, leaf: None if path in carried else leaf, new_states)
+      return (x, states), added
+
+    (out, states), added = jax.lax.scan(
+        _Body, (inputs, cached_states.body),
+        (theta.body, jnp.arange(self.p.num_layers)))
+    final = _ByPath(states)
+    new_states = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: final[path] if leaf is None else leaf, added,
+        is_leaf=lambda leaf: leaf is None)
     return out, NestedMap(body=new_states)
 
   def PagedStepPrefix(self, theta, inputs, cached_states, block_tables,

@@ -23,8 +23,13 @@ Covers docs/ragged_step.md:
   by `prefix_ordered_admissions`.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from lingvo_tpu.core import ragged as ragged_lib
+from lingvo_tpu.core.nested_map import NestedMap
 
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.serving import engine as engine_lib
@@ -220,6 +225,202 @@ class TestStepProgramCensus:
     assert (set(comp) & observe_schema.STEP_PROGRAM_NAMES
             == {"decode", "mixed", "spec_verify"})
     assert comp[observe_schema.COMPILE_CENSUS_KEY] == 3
+
+
+# -- the stacked pool rides the scan over layers as a carry --------------------
+
+_NP, _PS, _SLOTS, _TPAGES, _T, _WMAX = 12, 4, 4, 6, 16, 8
+# page-pool leaves of the decode state: K and V, and their int8 scale sidecars
+_POOL_LEAVES = {"f32": 2, "int8": 4, "hybrid": 2}
+
+
+def _RepeatLm(kind):
+  """(task, theta, kv_cache_dtype) of a tiny LM whose stack is a
+  RepeatedTransformerLayer: 3 TransformerLayers, or 2 repeats of a stacked
+  [ssm, attention] block (DenseLmSsmHybrid's shape)."""
+  if kind == "hybrid":
+    p = _LmParams(every_n=2, use_repeat=True, num_layers=4)
+  else:
+    p = _LmParams(num_layers=3).Set(use_repeat_layer=True)
+  task, theta = _Instantiate(p, seed=5)
+  return task, theta, {"bf16": "bfloat16", "int8": "int8"}.get(kind)
+
+
+def _RandomStates(task, theta, kv_cache_dtype, seed):
+  """A decode state with every element set, trash pages included, so that
+  an op that moves or drops any part of it shows."""
+  states = task.InitPagedDecodeState(theta, _NP, _PS, _SLOTS, kv_cache_dtype)
+  rng = np.random.RandomState(seed)
+
+  def _Fill(x):
+    if x.dtype == jnp.int8:
+      return jnp.asarray(rng.randint(-127, 128, x.shape), jnp.int8)
+    return jnp.asarray(rng.uniform(0.1, 1.0, x.shape), x.dtype)
+
+  return jax.tree_util.tree_map(_Fill, states)
+
+
+def _Pack(tree_row=False, all_padding=False):
+  """A decode row, a prefill chunk, a 4-token verify row (a tree when
+  asked), an empty slot, and 5 padding tokens at the end of the axis."""
+  if all_padding:
+    lens, q_pos, parents = [0, 0, 0, 0], [6, 10, 8, 1], None
+  else:
+    lens, q_pos = [1, 6, 4, 0], [5, 4, 7, 1]
+    parents = {2: [-1, 0, -1]} if tree_row else None
+  rows = ragged_lib.BuildRaggedRows(lens, q_pos, _T, _WMAX,
+                                    row_parents=parents)
+  return ragged_lib.RaggedRows(*(jnp.asarray(x) for x in rows))
+
+
+def _Tables(stale=False):
+  """Disjoint pages per row; dead entries 0, or (stale) other rows' pages,
+  -5 and 99, with a LIVE entry of row 1 at _NP + 2: past this layer's
+  pages, inside the next layer's in a stack viewed as one pool."""
+  tables = np.zeros((_SLOTS, _TPAGES), np.int32)
+  tables[0, :2] = [0, 1]
+  tables[1, :3] = [2, 3, 4]
+  tables[2, :3] = [5, 6, 7]
+  if stale:
+    tables[0, 2:] = [3, 99, -5, 6]
+    tables[1, 2] = _NP + 2
+    tables[1, 3:] = [0, 5, 99]
+    tables[3] = [2, 7, -1, 99, 1, 4]
+  return jnp.asarray(tables)
+
+
+def _LayerLoopStep(task, theta, ids, states, tables, rows, **kw):
+  """task.RaggedStep with the repeat walked in a Python loop: layer i runs
+  body.RaggedStep on slice [i] of every leaf and the results are stacked,
+  which is what the scan computed when the states were its xs and ys.
+  Each layer is one compiled call, as the scan's body is one computation:
+  a loop unrolled into ONE program is fused across layers on the CPU and
+  differs from the scan (the old one too) in the last bit."""
+  rep = task.stack
+  x = jax.jit(task.emb.EmbLookup)(theta.emb, ids)
+  step = jax.jit(lambda th, x, st: rep.body.RaggedStep(th, x, st, tables,
+                                                       rows, **kw))
+  per_layer = []
+  for i in range(rep.p.num_layers):
+    mine = lambda tree: jax.tree_util.tree_map(lambda a: a[i], tree)  # pylint: disable=cell-var-from-loop
+    x, ns = step(mine(theta.stack.body), x, mine(states.body))
+    per_layer.append(ns)
+  new_body = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *per_layer)
+
+  def _Head(theta, x):
+    x = task.final_ln.FProp(theta.final_ln, x)
+    return task.emb.Logits(theta.emb, x)
+
+  return jax.jit(_Head)(theta, x), NestedMap(body=new_body)
+
+
+def _AssertTreesBitwiseEqual(got, want):
+  got_flat, got_def = jax.tree_util.tree_flatten_with_path(got)
+  want_flat, want_def = jax.tree_util.tree_flatten_with_path(want)
+  assert got_def == want_def, (got_def, want_def)
+  for (path, a), (_, b) in zip(got_flat, want_flat):
+    name = jax.tree_util.keystr(path)
+    assert a.shape == b.shape and a.dtype == b.dtype, (name, a, b)
+    np.testing.assert_array_equal(
+        np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8),
+        err_msg=name)
+
+
+class TestRepeatedStepCarriesThePool:
+
+  @pytest.mark.parametrize("kind,kw,tree_row,stale", [
+      ("bf16", {}, False, False),
+      ("int8", {}, False, False),
+      ("hybrid", {}, False, False),
+      ("hybrid", {"ssm_col_states": True}, True, False),
+      ("f32", {}, True, False),
+      ("f32", {}, False, True),
+      ("int8", {}, True, True),
+  ], ids=["bf16_pool", "int8_pool_with_scales", "hybrid", "hybrid_col_states",
+          "tree_row", "stale_and_out_of_range_tables", "int8_tree_stale"])
+  def test_step_is_bitwise_the_layer_loop(self, kind, kw, tree_row, stale):
+    """Logits and the WHOLE returned state (trash pages, other layers'
+    pages, SSM slots and `col_states`) equal the plain layer loop's."""
+    task, theta, kv = _RepeatLm(kind)
+    states = _RandomStates(task, theta, kv, seed=3)
+    rows, tables = _Pack(tree_row), _Tables(stale)
+    ids = jnp.asarray(
+        np.random.RandomState(4).randint(0, 64, (1, _T)), jnp.int32)
+    logits, new_states = jax.jit(
+        lambda th, st: task.RaggedStep(th, ids, st, tables, rows, **kw))(
+            theta, states)
+    ref_logits, ref_states = _LayerLoopStep(task, theta, ids, states, tables,
+                                            rows, **kw)
+    _AssertTreesBitwiseEqual(logits, ref_logits)
+    _AssertTreesBitwiseEqual(new_states, ref_states)
+    # and the step did write: the chunk's pages differ from what came in
+    assert not np.array_equal(np.asarray(new_states.body.Flatten()[0]),
+                              np.asarray(states.body.Flatten()[0]))
+
+  @pytest.mark.parametrize("kind", ["f32", "int8", "hybrid"])
+  def test_scan_carries_every_state_leaf(self, kind):
+    """In the jaxpr of task.RaggedStep the scan over layers has every
+    stacked state leaf among its CARRIES and nothing of a pool's shape
+    among its constants, scanned inputs or scanned outputs: what a new
+    body (MoE, Mamba) has to keep for the step not to copy the pool."""
+    task, theta, kv = _RepeatLm(kind)
+    states = _RandomStates(task, theta, kv, seed=3)
+    rows, tables = _Pack(), _Tables()
+    ids = jnp.zeros((1, _T), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda th, st: task.RaggedStep(th, ids, st, tables, rows))(
+            theta, states)
+    reps = task.stack.p.num_layers
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == reps]
+    assert len(scans) == 1, [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    scan = scans[0]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    aval = lambda vs: [(v.aval.shape, v.aval.dtype) for v in vs]
+    consts = aval(scan.invars[:n_consts])
+    carries = aval(scan.invars[n_consts:n_consts + n_carry])
+    scanned = (aval(scan.invars[n_consts + n_carry:])
+               + aval(scan.outvars[n_carry:]))
+    leaves = aval(jax.tree_util.tree_leaves(states))
+    pools = [(shape, dtype) for shape, dtype in leaves if shape[1] == _NP]
+    assert len(pools) == _POOL_LEAVES[kind], leaves
+    for leaf in leaves:
+      assert leaf in carries, (leaf, carries)
+    assert aval(scan.outvars[:n_carry]) == carries
+    for shape, dtype in pools:
+      for where in (consts, scanned):
+        # neither the stack nor one layer's pool
+        assert (shape, dtype) not in where, (shape, where)
+        assert (shape[1:], dtype) not in where, (shape, where)
+
+  @pytest.mark.parametrize("kind", ["f32", "int8", "hybrid"])
+  def test_padding_writes_only_its_own_layers_trash_page(self, kind):
+    """A pack of nothing but padding tokens changes page NP - 1 of every
+    layer and not one element besides: no layer writes outside
+    [i * NP, (i + 1) * NP)."""
+    task, theta, kv = _RepeatLm(kind)
+    states = _RandomStates(task, theta, kv, seed=3)
+    rows, tables = _Pack(all_padding=True), _Tables(stale=True)
+    ids = jnp.asarray(
+        np.random.RandomState(4).randint(0, 64, (1, _T)), jnp.int32)
+    _, new_states = jax.jit(
+        lambda th, st: task.RaggedStep(th, ids, st, tables, rows))(
+            theta, states)
+    pools = 0
+    for (path, new), old in zip(
+        jax.tree_util.tree_flatten_with_path(new_states)[0],
+        jax.tree_util.tree_leaves(states)):
+      name = jax.tree_util.keystr(path)
+      new, old = np.asarray(new), np.asarray(old)
+      if new.shape[1] != _NP:                     # an SSM slot state
+        np.testing.assert_array_equal(new, old, err_msg=name)
+        continue
+      pools += 1
+      np.testing.assert_array_equal(new[:, :_NP - 1], old[:, :_NP - 1],
+                                    err_msg=name)
+      for i in range(new.shape[0]):
+        assert not np.array_equal(new[i, _NP - 1], old[i, _NP - 1]), (name, i)
+    assert pools == _POOL_LEAVES[kind]
 
 
 # -- BuildRaggedStep / CommitRaggedStep (device-free) -------------------------
