@@ -85,6 +85,44 @@ def TokenWindowRate(step_records: list[tuple[float, int]], t_start: float,
   return (n1 - n0) / (t1 - t0), n1 - n0, t1 - t0
 
 
+def FinishWindow(step_records: list[tuple[float, int, int]], opening: int,
+                 requests: int) -> dict:
+  """A window whose edges the work defines, for a closed loop whose flow
+  comes in bursts (one per request admitted).
+
+  step_records: (completion time, cumulative tokens done, cumulative requests
+  finished) after every engine step since the clients' start, in order. The
+  window opens at the completion of the step in which the `opening`-th
+  request finishes and closes at the completion of the step in which the
+  (`opening` + `requests`)-th does: a fixed amount of work, the same requests
+  at any step time, so that a faster or slower system reads higher or lower
+  by what its time differs and by nothing else. There is no other way to
+  close it: records that end before that finish raise ValueError.
+  Returns tok_s (tokens between the two completions over the time between
+  them), tokens, seconds, t_open, t_close, and the requests finished by the
+  opening step (finished_at_open) and between the two (finished; more than
+  `requests` where the closing step finished several).
+  """
+  opened = closed = None
+  finished = 0
+  for t, n, f in step_records:
+    finished = f
+    if opened is None:
+      if f >= opening:
+        opened = (t, n, f)
+    elif f >= opening + requests:
+      closed = (t, n, f)
+      break
+  if closed is None:
+    raise ValueError(f"the window is requests {opening + 1} to "
+                     f"{opening + requests}: {finished} finished")
+  (t_open, n_open, f_open), (t_close, n_close, f_close) = opened, closed
+  return {"tok_s": (n_close - n_open) / (t_close - t_open),
+          "tokens": n_close - n_open, "seconds": t_close - t_open,
+          "t_open": t_open, "t_close": t_close,
+          "finished_at_open": f_open, "finished": f_close - f_open}
+
+
 def CompareLogits(got, want, tol: float) -> tuple[bool, dict]:
   """The program's logits [K, V] at K sampled positions against the plain
   reference's [K, V]: every logit of every position within `tol`, all

@@ -23,6 +23,8 @@ _TRACE_TAIL_S = 6.0       # a traced run traces the window's last seconds
 _PROBE_PATIENCE_S = 900.0  # the probe's first call in a checkout compiles
 _LONG_PASS_S = 0.020      # a client pass or a collection worth a note
 _NOTED = 200              # such entries a note lists at most
+_OPEN_PATIENCE_S = 45.0   # a window that the work opens has opened by then,
+_WORK_PATIENCE = 2.0      # and its work is done within `seconds` x this
 
 
 class _Stream:
@@ -49,7 +51,8 @@ class StepRecorder:
 
   def __init__(self, engine):
     self.engine = engine
-    self.records: list[tuple] = []     # (t_end, dur, steps, tokens_done)
+    # (t_end, dur, steps, tokens_done, requests finished so far)
+    self.records: list[tuple] = []
     self.rows: list[list[tuple[int, int]]] = []
     self.attend: list[tuple[int, int]] = []   # (query blocks, queries) so far
     reg = engine.metrics
@@ -77,7 +80,8 @@ class StepRecorder:
           rows.append((s.pos - live_before.get(s.id, s.reused_tokens), s.pos))
       # a sequence that finished in this step left its slot: its last token
       rows.extend((1, p + 1) for i, p in live_before.items() if i not in after)
-      self.records.append((t1, t1 - t0, steps, prompt + shared + emitted))
+      self.records.append((t1, t1 - t0, steps, prompt + shared + emitted,
+                           self.engine.sched.finished))
       self.rows.append(rows)
       self.attend.append((blocks, queries))
     return n
@@ -206,14 +210,17 @@ def _WindowNotes(ctx, t0, t1, steps, gc_watch, client_gaps):
       for t, name, sec in ctx.compile_clock.events
       if t0 <= t <= t1])
   # tokens per second in each second since the clients' start: the start's
-  # wave of prefill and where it ends, which `lead_in_s` has to cover
+  # wave of prefill and where it ends, which the window has to open behind
   t_gen0 = ctx.t_gen0
   ctx.Note("tok_s_by_second_from_start", _BySlice(
-      steps, t_gen0, t_gen0 + int(t1 - t_gen0), int(t1 - t_gen0)))
-  # every step completion since then with the tokens done so far: what the
-  # window would have read behind any other lead-in (readings.TokenWindowRate)
+      [x[:2] for x in steps], t_gen0, t_gen0 + int(t1 - t_gen0),
+      int(t1 - t_gen0)))
+  # every step completion since then with the tokens done and the requests
+  # finished so far: what the window would have read behind any other edges
+  # (readings.FinishWindow, TokenWindowRate; benchmarks/tools/window_offline.py)
   ctx.Note("step_completions_from_start", [
-      [round(t - t_gen0, 4), n] for t, n in steps if t >= t_gen0], quiet=True)
+      [round(t - t_gen0, 4), n, f] for t, n, f in steps if t >= t_gen0],
+           quiet=True)
 
 
 def Run(ctx) -> dict:
@@ -254,7 +261,6 @@ def Run(ctx) -> dict:
   tr = _Scaled(traffic, scale)
   requests = traffic_lib.Generate(tr, ctx.seconds, ctx.seed, geo["max_batch"])
   ctx.Note("offered", traffic_lib.TotalWork(requests))
-  lead = float(tr.get("lead_in_s", 0.0))
   vocab = sizes["vocab_size"]
   prompts = {r.index: traffic_lib.PromptIds(r, ctx.seed, vocab)
              for r in requests}
@@ -277,10 +283,12 @@ def Run(ctx) -> dict:
         raise RuntimeError(f"serving loop died: {died[0].exc_value!r}")
       time.sleep(0.005)
     n_warm_steps = len(recorder.records)
-    ctx.Note("ready_s", time.perf_counter() - ctx.t_process)
+    finished_warm = engine.sched.finished
+    ctx.SetupEnds(time.perf_counter())
+    ctx.Note("ready_s", ctx.t_setup_end - ctx.t_process)
 
     with GcWatch() as gc_watch:
-      streams, client_gaps = _Drive(ctx, engine, tr, requests, prompts, lead,
+      streams, client_gaps = _Drive(ctx, engine, tr, requests, prompts,
                                     died, geo)
     if ctx.trace and ctx.trace_started:
       jax.profiler.stop_trace()
@@ -296,11 +304,27 @@ def Run(ctx) -> dict:
     if ctx.trace and ctx.trace_started:
       jax.profiler.stop_trace()
 
+  steps = [(t, n, f - finished_warm)
+           for t, _, _, n, f in recorder.records[n_warm_steps:]]
+  token_steps = [x[:2] for x in steps]
+  closed_loop = tr["loop"] == "closed"
+  if closed_loop:
+    # the work defines the edges; the client only kept the run going until
+    # it saw the window's last request finish
+    win = readings.FinishWindow(
+        [x for x in steps if x[0] >= ctx.t_gen0], _OpeningFinish(tr, geo),
+        traffic_lib.WindowRequests(tr, ctx.seconds))
+    ctx.t_win0, ctx.t_win1 = win["t_open"], win["t_close"]
+    rate = win["tok_s"]
+    ctx.Note("serve_tok_s_between_finishes", dict(
+        win, t_open=win["t_open"] - ctx.t_gen0,
+        t_close=win["t_close"] - ctx.t_gen0))
+  else:
+    rate, tokens, span = readings.TokenWindowRate(token_steps, ctx.t_win0,
+                                                  ctx.t_win1)
+    ctx.Note("serve_tok_s_between_steps", {"tok_s": rate, "tokens": tokens,
+                                           "seconds": span})
   t0, t1 = ctx.t_win0, ctx.t_win1
-  steps = [(t, n) for t, _, _, n in recorder.records[n_warm_steps:]]
-  rate, tokens, span = readings.TokenWindowRate(steps, t0, t1)
-  ctx.Note("serve_tok_s_between_steps", {"tok_s": rate, "tokens": tokens,
-                                         "seconds": span})
   _WindowNotes(ctx, t0, t1, steps, gc_watch, client_gaps)
   sampled = [s for s in streams if s.req.sampled]
   gaps = []
@@ -316,8 +340,17 @@ def Run(ctx) -> dict:
     ttft.append((first - s.due) * 1e3)
   late = [(s.sent - s.due) * 1e3 for s in streams if s.sent is not None
           and s.due is not None]
-  done_in = [s for s in streams
-             if s.done_at is not None and t0 <= s.done_at <= t1]
+  if closed_loop:
+    # the requests the engine finished between the window's two steps, by
+    # their rank among the finishes as the client saw them (it looks at its
+    # streams many times a step, so ranks agree though stamps lag)
+    done = sorted((s for s in streams if s.done_at is not None),
+                  key=lambda s: s.done_at)
+    first = win["finished_at_open"]
+    done_in = done[first:first + win["finished"]]
+  else:
+    done_in = [s for s in streams if s.done_at is not None
+               and t0 < s.done_at <= t1]
   finished_tok_s = sum(
       s.req.prompt_len + len(s.stamps) for s in done_in) / (t1 - t0)
   failed = sum(1 for s in streams if s.error is not None or (
@@ -328,7 +361,7 @@ def Run(ctx) -> dict:
                       "itl_gaps": len(gaps), "ttft_samples": len(ttft),
                       "finished_in_window": len(done_in)})
 
-  in_win = [(t, d) for t, d, _, _ in recorder.records if t0 <= t <= t1]
+  in_win = [(t, d) for t, d, *_ in recorder.records if t0 <= t <= t1]
   # queue wait by thirds of the window: growing thirds mean a growing backlog
   thirds = [[], [], []]
   for s in sampled:
@@ -348,7 +381,7 @@ def Run(ctx) -> dict:
       "step_ms_median": round(readings.Percentile(
           [d * 1e3 for _, d in in_win], 50), 2) if in_win else None,
       "steps_in_window": len(in_win),
-      "tok_s_by_sixth": _BySlice(steps, t0, t1, 6),
+      "tok_s_by_sixth": _BySlice(token_steps, t0, t1, 6),
       "open_at_end": sum(1 for s in streams if s.sent is not None
                          and s.done_at is None),
       "finished_tok_s": finished_tok_s})
@@ -361,7 +394,8 @@ def Run(ctx) -> dict:
                 and s.handle.admit_time is not None]
   run = {
       "chips": 1, "sizes": sizes, "packed_t": packed_t,
-      "window": (t0, t1), "step_records": recorder.records[n_warm_steps:],
+      "window": (t0, t1), "trace_from": ctx.t_trace0,
+      "step_records": recorder.records[n_warm_steps:],
       "step_rows": recorder.rows[n_warm_steps:],
       "attend_blocks": recorder.attend[n_warm_steps:],
       "attend_bq": getattr(engine, "_attend_bq", 0),
@@ -377,8 +411,12 @@ def Run(ctx) -> dict:
   correct, detail = _Correct(ctx, reference, theta, sizes, by_id,
                              probe.captured)
   ctx.Note("correct_detail", detail)
+  compared = {}
+  if "max_abs_diff" in detail:
+    compared["logit_max_abs_diff"] = {"value": detail["max_abs_diff"],
+                                      "limit": detail["tolerance"]}
   return {"run": run, "end_to_end": end_to_end, "correct": correct,
-          "attempted": attempted, "failed": failed}
+          "attempted": attempted, "failed": failed, "compared": compared}
 
 
 def _BySlice(steps, t0, t1, k):
@@ -399,7 +437,9 @@ def _Scaled(traffic: dict, scale: float) -> dict:
   a lead-in of two seconds at most."""
   if scale == 1.0:
     return traffic
-  out = dict(traffic, lead_in_s=min(2.0, traffic.get("lead_in_s", 0.0)))
+  out = dict(traffic)
+  if "lead_in_s" in traffic:
+    out["lead_in_s"] = min(2.0, traffic["lead_in_s"])
   for k in ("prompt_len", "new_tokens"):
     d = dict(traffic[k])
     for f in ("median", "min", "max", "value"):
@@ -409,11 +449,12 @@ def _Scaled(traffic: dict, scale: float) -> dict:
   return out
 
 
-def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
+def _Drive(ctx, engine, tr, requests, prompts, died, geo):
   """The client: one loop on this thread. Open loop: submits each request
   when it is due, timed from then. Closed loop: each of the clients sends
-  its next request when its last one finished. Every `client_poll_ms` it
-  looks at each open stream and stamps the tokens that arrived. Returns the
+  its next request when its last one finished. Every millisecond
+  (`_CLIENT_POLL_S`) it looks at each open stream and stamps the tokens that
+  arrived. Returns the
   streams and its own long passes (a pass is a millisecond's sleep and a
   look at 64 streams: one of 20 ms or more means this thread was kept from
   running), to lay beside the engine's long steps."""
@@ -428,9 +469,23 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
   # seconds in it, the whole process's)
   gaps = {"passes": 0, "long": []}
   t_gen0 = ctx.t_gen0 = time.perf_counter()
-  ctx.t_win0 = t_gen0 + lead
-  ctx.t_win1 = ctx.t_win0 + ctx.seconds
-  ctx.setup_s = ctx.t_win0 - ctx.t_process
+  # open loop: the window opens `lead_in_s` after the clients' start and
+  # lasts `seconds`. Closed loop: it opens when the opening request has finished
+  # and closes when `in_window` more have, and by nothing else; a system that
+  # takes over `_OPEN_PATIENCE_S` to open it, or over `seconds` x
+  # `_WORK_PATIENCE` for its work, fails the run (the traffic file's
+  # `requests_per_s_hint` is then far off what the cell turns over)
+  opening = in_window = trace_from_done = n_done = 0
+  if not open_loop:
+    opening = _OpeningFinish(tr, geo)
+    in_window = traffic_lib.WindowRequests(tr, ctx.seconds)
+    # a traced run traces the window's last seconds: the last requests
+    trace_from_done = opening + in_window - round(
+        _TRACE_TAIL_S * in_window / ctx.seconds)
+    ctx.t_win0 = ctx.t_win1 = None
+  else:
+    ctx.t_win0 = t_gen0 + float(tr.get("lead_in_s", 0.0))
+    ctx.t_win1 = ctx.t_win0 + ctx.seconds
   for s in streams:
     s.due = t_gen0 + s.req.due_s if open_loop else None
   clients = 0 if open_loop else traffic_lib.NumClients(tr, geo["max_batch"])
@@ -454,11 +509,27 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
       gaps["long"].append((last, now - last, cpu[0] - last_cpu[0],
                            cpu[1] - last_cpu[1]))
     last, last_cpu = now, cpu
-    if now >= ctx.t_win1:
+    if ctx.t_win1 is None:
+      if n_done >= opening:
+        ctx.t_win0 = now
+        ctx.t_win1 = now + ctx.seconds * _WORK_PATIENCE
+      elif now > t_gen0 + _OPEN_PATIENCE_S:
+        raise RuntimeError(f"{n_done} of the {opening} requests that open "
+                           f"the window finished in {_OPEN_PATIENCE_S} s")
+    elif opening and n_done >= opening + in_window:
       break
+    elif now >= ctx.t_win1:
+      if open_loop:
+        break
+      raise RuntimeError(
+          f"{n_done - opening} of the window's {in_window} requests finished "
+          f"in {ctx.seconds * _WORK_PATIENCE} s: no reading")
     if died:
       raise RuntimeError(f"serving loop died: {died[0].exc_value!r}")
-    if ctx.trace and not ctx.trace_started and now >= ctx.t_win1 - _TRACE_TAIL_S:
+    if ctx.trace and not ctx.trace_started and ctx.t_win1 is not None and (
+        now >= ctx.t_win1 - _TRACE_TAIL_S
+        or (opening and n_done >= trace_from_done)):
+      ctx.t_trace0 = now
       jax.profiler.start_trace(ctx.trace_dir)
       ctx.trace_started = True
     if open_loop:
@@ -480,12 +551,19 @@ def _Drive(ctx, engine, tr, requests, prompts, lead, died, geo):
         s.seen = n
       if s.handle.done and s.seen == len(s.handle._tokens):
         s.done_at = now
+        n_done += 1
       else:
         still.append(s)
     live[:] = still
     time.sleep(poll)
   ctx.Note("closed_loop_cycles", cycle)
   return streams, gaps
+
+
+def _OpeningFinish(tr, geo) -> int:
+  """A closed loop's window opens when every client has been served once:
+  the start's wave of simultaneous admissions is behind it."""
+  return traffic_lib.NumClients(tr, geo["max_batch"])
 
 
 def _ProbeOneStep(probe, engine, streams, prompts, died):

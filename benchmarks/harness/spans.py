@@ -533,7 +533,7 @@ def _PhaseTable(steps) -> dict:
 def _TraceCost(run, steps) -> dict:
   """The same run's steps before and during its traced tail (a traced run
   traces the window's last seconds): what tracing costs when it is on."""
-  cut = run["window"][1] - _TRACE_TAIL_S
+  cut = run.get("trace_from") or run["window"][1] - _TRACE_TAIL_S
   out = {}
   for key, part in (("before", [s for s in steps if s.end_ts < cut]),
                     ("during", [s for s in steps if s.start_ts >= cut])):

@@ -105,20 +105,22 @@ def Generate(traffic: dict, seconds: float, seed: int, max_batch: int = 0
   exactly round(rate * seconds) in the window, each at sorted uniform times.
   Closed loop: `clients_per_slot * max_batch` clients all send at the start
   and each sends its next request when its last one finished; the list is
-  what they draw from in order (and cycle through if the system outruns
-  `requests_per_s_hint`). The first `clients` requests count as the lead-in
-  (not sampled for latencies); the engine's queue serves them in list order,
-  so the window too holds requests of this list.
+  what they draw from in order. Its window is a fixed amount of work between
+  two finishes (WindowRequests), so the list is sized in work too
+  (ClosedLoopList) and a closed loop has no `lead_in_s`. The first `clients`
+  requests count as the lead-in (not sampled for latencies); the engine's
+  queue serves them in list order, so the window too holds requests of this
+  list.
   """
   rng = np.random.RandomState(_Seed32(seed, 1))
-  lead = float(traffic.get("lead_in_s", 0.0))
   if traffic["loop"] == "open":
     rate = float(traffic["rate_per_s"])
+    lead = float(traffic.get("lead_in_s", 0.0))
     n_lead, n_win = round(rate * lead), round(rate * seconds)
   else:
-    clients = NumClients(traffic, max_batch)
-    rate = float(traffic["requests_per_s_hint"])
-    n_lead, n_win = clients, max(1, round(rate * (lead + seconds)))
+    lead = 0.0
+    n_lead = NumClients(traffic, max_batch)
+    n_win = ClosedLoopList(traffic, seconds, max_batch) - n_lead
   out = _Phase(traffic, n_lead, 0.0, lead, rng, False, 0) if n_lead else []
   out += _Phase(traffic, n_win, lead, lead + seconds, rng, True, len(out))
   return out
@@ -126,6 +128,22 @@ def Generate(traffic: dict, seconds: float, seed: int, max_batch: int = 0
 
 def NumClients(traffic: dict, max_batch: int) -> int:
   return int(traffic["clients_per_slot"] * max_batch)
+
+
+def WindowRequests(traffic: dict, seconds: float) -> int:
+  """The work a closed loop's window holds: the requests `seconds` hold at
+  `requests_per_s_hint`, the rate the cell turned over when the file was
+  written."""
+  return max(1, round(float(traffic["requests_per_s_hint"]) * seconds))
+
+
+def ClosedLoopList(traffic: dict, seconds: float, max_batch: int) -> int:
+  """How many requests a closed loop's list holds: the clients' first
+  requests (the window opens when that many have finished), the window's own
+  (WindowRequests), and one more in flight for every client when the last of
+  those finishes. The window's work is fixed, so no system, however fast,
+  goes round this list (`closed_loop_cycles` 0)."""
+  return 2 * NumClients(traffic, max_batch) + WindowRequests(traffic, seconds)
 
 
 def PromptIds(req: Request, seed: int, vocab_size: int) -> np.ndarray:

@@ -113,7 +113,7 @@ def Run(ctx) -> dict:
     ctx.Note("warmup_loops", start)
     ctx.Note("warmup_intervals_s", [round(x, 5) for x in warm])
     ctx.Note("window_loops", n_loops)
-    ctx.setup_s = completions[start] - ctx.t_process
+    ctx.SetupEnds(completions[start])
     last = start + n_loops           # index of the window's last completion
 
     if ctx.trace:
@@ -158,9 +158,17 @@ def Run(ctx) -> dict:
   finite = bool(losses) and all(math.isfinite(x) for x in losses)
   ctx.Note("loop_losses", [round(x, 4) for x in losses[-n_loops:]])
   prog.Shutdown()
+  compared = {}
+  if "max_abs_diff" in detail:
+    compared = {
+        "logit_max_abs_diff": {"value": detail["max_abs_diff"],
+                               "limit": detail["tolerance"]},
+        "loss_rel_diff": {"value": detail["loss_rel_diff"],
+                          "limit": detail["loss_tolerance"]}}
   return {"run": run, "end_to_end": end_to_end,
           "correct": bool(correct and finite),
-          "attempted": n_loops * steps_per_loop, "failed": 0}
+          "attempted": n_loops * steps_per_loop, "failed": 0,
+          "compared": compared}
 
 
 def _Correct(ctx, task, reference, mesh, state, sizes) -> tuple[bool, dict]:

@@ -7,7 +7,8 @@ own (as the driver's check runs them), and says whether the set is steady.
 Prints one row per run (its end-to-end readings, `correct`, and for a serving
 cell where its window went: steps in the window, the median step period,
 stalled seconds, the seconds in which the whole process stood still, the
-collector's long passes) and then, for
+collector's long passes; for every cell set-up's wall time and the chip's
+bring-up, which `setup_s` leaves out of it) and then, for
 every end-to-end metric of the cell, the median, the range, the range with the
 farthest run left out (readings.RangeLeavingOneOut: the driver's rule) and the
 cell's bound beside them. Every run's whole output goes to
@@ -45,15 +46,17 @@ def _Notes(lines) -> dict:
   return out
 
 
-def RunOnce(workload, seed, seconds, trace, out_dir, tag, rehearse=False
-            ) -> dict:
-  """One run in its own process; {"rc", "line", "notes"}."""
+def RunOnce(workload, seed, seconds, trace, out_dir, tag, rehearse=False,
+            extra=()) -> dict:
+  """One run in its own process; {"rc", "line", "notes"}. `extra`: further
+  words for run.py's command line (tools/sweep.py's --traffic-override)."""
   cmd = [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
          "--workload", workload, "--seed", str(seed), "--seconds",
          str(seconds), "--trace", str(trace), "--out",
          os.path.join(out_dir, "bench_" + tag)]
   if rehearse:
     cmd.append("--rehearse")
+  cmd.extend(extra)
   log = os.path.join(out_dir, f"{tag}_{workload}_{seed}_t{trace}.log")
   with open(log, "w") as f:
     rc = subprocess.run(cmd, cwd=ROOT, stdout=f,
@@ -97,6 +100,17 @@ def Row(seed, res) -> dict:
                                  3)
     row["stood_still_lead_in_s"] = round(
         sum(ms for at, ms in still if at < 0) / 1e3, 3)
+  if "setup" in notes:
+    # set-up's wall time, and what `setup_s` leaves out of it: the chip's
+    # bring-up (the first jax.devices())
+    for k in ("setup_wall_s", "runtime_start_s"):
+      row[k] = round(notes["setup"][k], 3)
+  if "compile" in notes:
+    row["compile_s"] = round(notes["compile"]["seconds"], 3)
+  if "serve_tok_s_between_finishes" in notes:
+    win = notes["serve_tok_s_between_finishes"]
+    row.update(opened_s=round(win["t_open"], 3),
+               window_s=round(win["seconds"], 3), finished=win["finished"])
   if "gc" in notes:
     row["gc_long"] = len(notes["gc"]["long_at_s_generation_ms"])
   if "closed_loop_cycles" in notes:

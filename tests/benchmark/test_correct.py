@@ -124,3 +124,101 @@ def test_seeded_weights_scale_the_attention_output_and_nothing_else():
   assert float(out["stack"]["body"]["fflayer"]["ffn_out"]["w"].astype(
       jnp.float32).max()) == 1.0
   assert float(out["emb"]["emb"].astype(jnp.float32).max()) == 1.0
+
+
+# -- a whole run with the timed path broken underneath ------------------------
+
+
+def _StateUnchanged(monkeypatch):
+  """The step returns the paged cache as it got it: nothing a request
+  wrote is there when its next token reads it."""
+  from benchmarks.harness import model as model_lib
+  inner = model_lib.Instantiate
+
+  def _Instantiate(task_p):
+    task = inner(task_p)
+    step = task.RaggedStep
+
+    def _RaggedStep(theta, ids, states, *rest, **kw):
+      logits, _ = step(theta, ids, states, *rest, **kw)
+      return logits, states
+
+    task.RaggedStep = _RaggedStep
+    return task
+
+  monkeypatch.setattr(model_lib, "Instantiate", _Instantiate)
+
+
+def _LossyWeights(monkeypatch):
+  """The engine serves its weights a tenth off, the reference keeps what the
+  seed made. (The control proper, weights rounded to fp8 e4m3, averages out
+  under the limit at the rehearsal's width of 64; at the cell's own width it
+  reads 0.41 against 0.2, see the configuration's `serve_reason`.)"""
+  import jax
+  import jax.numpy as jnp
+  from lingvo_tpu.serving import engine as engine_lib
+  inner = engine_lib.ServingLoop.__init__
+
+  def _Init(self, task, theta, *args, **kw):
+    theta = jax.tree_util.tree_map(
+        lambda x: (x * 0.9).astype(x.dtype)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x, theta)
+    inner(self, task, theta, *args, **kw)
+
+  monkeypatch.setattr(engine_lib.ServingLoop, "__init__", _Init)
+
+
+@pytest.mark.parametrize("cell,fault,want", [
+    ("dense1b_serve_docs", None, True),
+    ("dense1b_serve_docs", _StateUnchanged, False),
+    ("dense1b_serve_docs", _LossyWeights, False),
+])
+def test_a_run_with_the_timed_path_broken_is_not_correct(
+    cell, fault, want, monkeypatch, tmp_path, capsys):
+  """The rest of a run as run.py drives it (--rehearse takes the CPU for
+  the chip), with the engine's step broken underneath, or with the control
+  in the program's place: `correct` comes out false, the number compared
+  stands beside its limit, and the sound run of the same cell passes."""
+  import argparse
+  from benchmarks import run as run_mod
+  from benchmarks.harness import device
+  monkeypatch.setattr(device, "ConfigureCache", lambda: "the tests' own")
+  if fault is not None:
+    fault(monkeypatch)
+  args = argparse.Namespace(
+      workload=cell, seed=3100000077, seconds=1.0, trace=0, rehearse=True,
+      out=str(tmp_path), traffic_override="")
+  assert run_mod._Run(args) == 0
+  import json
+  line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+  assert line["correct"] is want
+  compared = line["compared"]["logit_max_abs_diff"]
+  assert (compared["value"] <= compared["limit"]) is want
+  assert line["failed"] == 0 and line["attempted"] > 0
+
+
+def test_the_control_tool_runs_a_cell_with_fp8_weights_underneath(tmp_path):
+  """benchmarks/tools/control.py, the control as it is run on the chip, here
+  on the CPU at the rehearsal's width. There fp8's rounding reads 0.18-0.29
+  against the limit of 0.2 (it averages out over a width of 64; at the
+  cell's own width it reads twice the limit and more, PERF.md section 4), so
+  this holds it to reading several times what a sound run reads (0.01-0.02),
+  and its exit code to saying which side of the limit it fell on."""
+  import json
+  import subprocess
+  import sys
+  root = os.path.dirname(os.path.dirname(os.path.dirname(
+      os.path.abspath(__file__))))
+  env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+  env.update(JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="false")
+  done = subprocess.run(
+      [sys.executable, os.path.join(root, "benchmarks", "tools", "control.py"),
+       "--workload", "dense1b_serve_docs", "--seed", "3100000901",
+       "--seconds", "1", "--rehearse", "--out", str(tmp_path)],
+      cwd=root, env=env, capture_output=True, text=True, timeout=600)
+  line = json.loads(done.stdout.strip().splitlines()[-1])
+  assert line["control"] == "fp8_e4m3_weights" and line["failed"] == 0
+  compared = line["compared"]["logit_max_abs_diff"]
+  assert compared["value"] > 0.1, "five times a sound run's reading and more"
+  assert line["correct"] is (compared["value"] <= compared["limit"])
+  assert done.returncode == (1 if line["correct"] else 0)

@@ -40,16 +40,39 @@ def test_rehearsal_runs_the_whole_command_line(cell, tmp_path):
   assert line["device"]["platform"] == "cpu"
   detail = line["counts"]["correct_detail"]
   assert "tolerance" in detail
+  # every number compared beside its limit: the line's last key, and the
+  # last lines of standard error
+  assert list(line)[-1] == "compared" and line["compared"]
+  said = done.stderr.strip().splitlines()[-len(line["compared"]):]
+  for (name, c), ln in zip(line["compared"].items(), said):
+    assert c["value"] <= c["limit"]
+    assert ln == f"compared {name}: {c['value']!r} limit {c['limit']!r}"
   notes = [json.loads(x) for x in done.stdout.strip().splitlines()[:-1]]
   assert all("note" in n for n in notes), "only notes before the last line"
   by_name = {n["note"]: n["value"] for n in notes}
+  # set-up: the wall time less the chip's bring-up (the first jax.devices(),
+  # which on the CPU takes next to nothing) and less nothing else: JAX's
+  # import is over before that call, in run.py itself, so it is counted
+  # whether or not the program's own import loads JAX; the line says what
+  # was left out
+  setup = by_name["setup"]
+  assert setup["setup_s"] == pytest.approx(
+      setup["setup_wall_s"] - setup["runtime_start_s"])
+  assert 0 < setup["runtime_start_at_s"] < setup["setup_wall_s"]
+  assert setup["runtime_start_s"] < setup["runtime_start_at_s"], (
+      "jax.devices() took longer than every import before it: JAX's import "
+      "has moved into the stretch that setup_s leaves out")
+  assert line["setup"] == {k: setup[k] for k in ("setup_wall_s",
+                                                 "runtime_start_s")}
   if "client" in by_name:       # a serving cell says where its window went
     stalls = by_name["step_stalls"]
     assert stalls["steps"] > 2 and stalls["period_ms_median"] > 0
     assert {"lock_wait", "h2d", "device_wait", "commit", "loop"} <= set(
         stalls["phases_ms"])
     assert stalls["stalls"] >= len(stalls["stalled_steps"])
-    assert by_name["client_gaps"]["passes"] > 100
+    # (a window in work ends when its requests are done, which the tiny
+    # engine does in a tenth of a second; a window in seconds lasts them)
+    assert by_name["client_gaps"]["passes"] > 50
     assert set(by_name["gc"]) == {"by_generation", "long_at_s_generation_ms"}
     assert by_name["compiles_in_window"] == [], "nothing compiles in a window"
     assert "step_records" not in by_name, "too long for a log"

@@ -38,9 +38,13 @@ def test_same_multiset_for_every_seed_in_another_order(mix, max_batch):
   assert len({json.dumps(t.TotalWork(r)) for r in runs}) == 1
 
 
-@pytest.mark.parametrize("seconds", [10, 30, 51])
-def test_open_loop_arrival_count_is_exact(seconds):
+@pytest.mark.parametrize("seconds,rate", [
+    (10, None), (30, None), (51, None),       # the rate chat.json states
+    (30, 1.6), (30, 6.5), (30, 8.8), (30, 12.3)])   # and others a sweep tries
+def test_open_loop_arrival_count_is_exact(seconds, rate):
   tr = _Mix("chat")
+  if rate is not None:
+    tr = dict(tr, rate_per_s=rate)
   rate, lead = tr["rate_per_s"], tr["lead_in_s"]
   for seed in SEEDS:
     reqs = t.Generate(tr, seconds, seed)
@@ -91,9 +95,25 @@ def test_closed_loop_has_its_clients_and_a_window_sized_list():
   clients = t.NumClients(tr, 32)
   assert clients == 64
   assert sum(not r.sampled for r in reqs) == clients
-  assert sum(r.sampled for r in reqs) == round(
-      tr["requests_per_s_hint"] * (tr["lead_in_s"] + 30))
+  assert "lead_in_s" not in tr, "the window opens at a finish, not a second"
+  assert len(reqs) == t.ClosedLoopList(tr, 30, 32) == (
+      2 * clients + t.WindowRequests(tr, 30))
+  assert t.WindowRequests(tr, 30) == round(tr["requests_per_s_hint"] * 30)
   assert all(r.due_s is None for r in reqs)
+
+
+@pytest.mark.parametrize("mix,seconds,max_batch,want", [
+    # the clients' first requests, the window's work, one in flight each
+    ({"clients_per_slot": 2, "requests_per_s_hint": 5.0}, 30, 32,
+     64 + 150 + 64),
+    ({"clients_per_slot": 2, "requests_per_s_hint": 5.0}, 10, 32,
+     64 + 50 + 64),
+    ({"clients_per_slot": 2, "requests_per_s_hint": 5.0}, 2, 4, 8 + 10 + 8),
+    ({"clients_per_slot": 1, "requests_per_s_hint": 0.3}, 30, 8, 8 + 9 + 8),
+    ({"clients_per_slot": 1, "requests_per_s_hint": 0.01}, 30, 8, 8 + 1 + 8),
+])
+def test_a_closed_loop_s_list_is_sized_in_work(mix, seconds, max_batch, want):
+  assert t.ClosedLoopList(mix, seconds, max_batch) == want
 
 
 def test_a_closed_loop_cuts_no_request_short():
@@ -131,25 +151,15 @@ def test_prompt_ids_come_from_the_seed():
   assert not np.array_equal(a, t.PromptIds(r, 3000000020, 32000))
 
 
-# what the docs cell read when its list was sized (tokens/s; ledger, PR 26):
-# the dense model, and the OLMoE cell a later PR brings back
-_DOCS_TOK_S_TODAY, _DOCS_TOK_S_OLMOE = 4000.0, 8104.0
-
-
-@pytest.mark.parametrize("tok_s", [2 * _DOCS_TOK_S_TODAY, _DOCS_TOK_S_OLMOE])
-def test_docs_list_does_not_run_out_at_twice_today_s_rate(tok_s):
-  """A closed loop that outruns `requests_per_s_hint` goes round its list
-  again (closed_loop_cycles > 0) and the window then serves other requests
-  than the list states. The list has to outlast a system twice as fast as
-  today's, lead-in and window together."""
+@pytest.mark.parametrize("seconds", [2, 10, 30, 51])
+def test_docs_list_lasts_any_system_however_fast(seconds):
+  """The window's work is fixed (the requests `seconds` hold at the rate the
+  cell turns over today), so the clients draw at most their first requests,
+  the window's, and one more each: the list holds exactly that, and a closed
+  loop that never outruns its list never serves other requests than the
+  list states (closed_loop_cycles 0; tests/benchmark/test_drive.py)."""
   tr = _Mix("docs")
-  seconds = 30
   reqs = t.Generate(tr, seconds, SEEDS[0], 32)
   clients = t.NumClients(tr, 32)
-  work = t.TotalWork(reqs)
-  tokens_per_request = (work["prompt_tokens"] + work["new_tokens"]) / len(reqs)
-  finished = tok_s / tokens_per_request * (tr["lead_in_s"] + seconds)
-  # every client holds one request; each one finished draws the next
-  assert len(reqs) >= clients + finished
-  # and the list is no longer than it has to be (set-up makes every prompt)
-  assert len(reqs) <= clients + 1.5 * finished
+  in_window = t.WindowRequests(tr, seconds)
+  assert len(reqs) == clients + in_window + clients
