@@ -398,7 +398,7 @@ def _ModelParams(size: Size, *, flash: bool, layers: int | None):
   if layers is not None:
     mp.task.num_layers = layers
   if flash:
-    # as bench._BenchDense sets them for the one number on record
+    # remat on dots + flash attention: the settings of the dense train cells
     mp.task.remat_policy = "dots"
     mp.task.atten_tpl = attention_lib.MultiHeadedAttention.Params().Set(
         use_flash_attention=True)

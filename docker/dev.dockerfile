@@ -14,7 +14,7 @@ COPY pyproject.toml README.md ./
 COPY lingvo_tpu ./lingvo_tpu
 COPY tools ./tools
 COPY tests ./tests
-COPY bench.py __graft_entry__.py ./
+COPY __graft_entry__.py ./
 
 # CPU jax by default; on TPU VMs replace with:
 #   pip install 'jax[tpu]' -f https://storage.googleapis.com/jax-releases/libtpu_releases.html

@@ -2,9 +2,9 @@
 
 The cache key includes the directory, so an entry is only found again at the
 same path: the path must be fixed, never derived from a temp dir, a pid or the
-clock. Every entry point (trainer CLI, chip_smoke, bench, sweep tools, tests)
-calls `Configure()` once before its first compile, so they all share one
-cache and a second run of any of them starts warm.
+clock. Every entry point (trainer CLI, chip_smoke, the benchmark, sweep tools,
+tests) calls `Configure()` once before its first compile, so they all share
+one cache and a second run of any of them starts warm.
 """
 
 from __future__ import annotations

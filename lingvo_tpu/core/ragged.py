@@ -1,8 +1,8 @@
-"""Ragged row descriptors: the shared contract of the unified serving step.
+"""Ragged row descriptors: the shared contract of the serving step.
 
-One compiled serving program replaces the three legacy step shapes (pure
-decode, mixed prefill+decode, spec-verify). Its activations are PACKED on
-a single token axis of static width T: a decode row contributes 1 token,
+One compiled serving program carries every step shape (pure decode,
+mixed prefill+decode, spec-verify). Its activations are PACKED on a
+single token axis of static width T: a decode row contributes 1 token,
 a prefill row a chunk of tokens, a spec-verify row its last committed
 token plus k drafted tokens — and every layer sees the same flat [1, T, D]
 activation with per-token routing metadata instead of a padded [B, C, D]
@@ -125,7 +125,7 @@ def BuildRaggedRows(row_lens, row_q_pos, t: int, wmax: int,
 
   row_lens/row_q_pos: [B] ints. Rows are packed in slot order; the caller
   guarantees sum(row_lens) <= t and max(row_lens) <= wmax. Returns numpy
-  arrays (the engine ships them device-side per step like StepBatch).
+  arrays (the engine ships them device-side each step).
 
   row_parents: optional {slot: [row_len-1] parent pointers} for TREE rows
   (draft j's parent draft index, -1 = root). Rows absent from the dict are

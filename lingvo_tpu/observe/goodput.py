@@ -88,6 +88,8 @@ class GoodputTracker:
     self._lock = threading.Lock()
     self._t0 = clock()
     self._buckets = {b: 0.0 for b in schema.GOODPUT_BUCKETS if b != "other"}
+    # compile seconds by the thread that compiled (CompileSeconds)
+    self._compile_by_thread: dict[int, float] = {}
     if registry is not None:
       registry.SectionFn(section, self.Stats)
 
@@ -95,14 +97,25 @@ class GoodputTracker:
     assert bucket in self._buckets, (
         f"unknown goodput bucket {bucket!r}; schema.GOODPUT_BUCKETS = "
         f"{schema.GOODPUT_BUCKETS}")
+    seconds = max(float(seconds), 0.0)
     with self._lock:
-      self._buckets[bucket] += max(float(seconds), 0.0)
+      self._buckets[bucket] += seconds
+      if bucket == "compile":
+        ident = threading.get_ident()
+        self._compile_by_thread[ident] = (
+            self._compile_by_thread.get(ident, 0.0) + seconds)
 
-  def CompileSeconds(self) -> float:
-    """Monotonic total of the compile bucket — callers snapshot it around
-    a window to find how much compilation happened inside."""
+  def CompileSeconds(self, thread: int | None = None) -> float:
+    """Monotonic compile seconds spent ON one thread (`thread`: its
+    threading.get_ident(); default the caller's) — callers snapshot it
+    around a window to find how much compilation delayed that window. A
+    compile on another thread (the async checkpoint writer, a scrape)
+    runs beside the window's work and takes nothing from it: summed in,
+    two threads compiling at once exceed the wall and zero the window."""
+    if thread is None:
+      thread = threading.get_ident()
     with self._lock:
-      return self._buckets["compile"]
+      return self._compile_by_thread.get(thread, 0.0)
 
   def Snapshot(self) -> dict:
     """Raw bucket totals {bucket: seconds} at this instant — a cheap
@@ -123,8 +136,9 @@ class GoodputTracker:
   @contextlib.contextmanager
   def TrackExcludingCompile(self, bucket: str):
     """Like Track, minus any compile seconds the jax.monitoring listener
-    attributed during the block — lazy jit compiles inside a step/eval
-    window must not be double-counted as productive (or eval) time."""
+    attributed to this thread during the block — lazy jit compiles inside
+    a step/eval window must not be double-counted as productive (or eval)
+    time."""
     t0 = self._clock()
     c0 = self.CompileSeconds()
     try:
@@ -139,6 +153,7 @@ class GoodputTracker:
       self._t0 = self._clock()
       for b in self._buckets:
         self._buckets[b] = 0.0
+      self._compile_by_thread.clear()
 
   def Stats(self) -> dict:
     """`goodput/*` section: per-bucket seconds + wall + productive ratio.
@@ -163,8 +178,9 @@ _TRACKER: GoodputTracker | None = None
 # MLIR lowering, XLA backend compile — they fire on every cache miss,
 # AOT or lazy, so the listener sees each compile exactly once. Inner-jit
 # trace/lowering events nest inside the outer jit's, so the compile
-# bucket can overcount by the nested fraction (<1% in practice): the
-# buckets sum to ~wall, not exactly wall.
+# bucket can overcount by the nested fraction (<1% in practice), and by
+# what a side thread compiles beside a running step: the buckets sum to
+# ~wall, not exactly wall.
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
 
 

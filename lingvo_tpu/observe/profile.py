@@ -16,8 +16,7 @@ ISSUE 12 pillar 3, two tools:
   plan), and the donation set, then dispatches every subsequent call
   through the stored executable. The jit tracing cache does not see
   `.lower().compile()`, so the compiled object MUST be reused for
-  dispatch or each call would pay tracing again (the bench's
-  `_BenchFusedXent` established this idiom). Any failure — lowering,
+  dispatch or each call would pay tracing again. Any failure — lowering,
   memory_analysis, or an aval mismatch at dispatch — permanently falls
   back to calling the original jit fn for that name, recording why.
 """

@@ -127,11 +127,10 @@ def TelemetryFromRegistry(registry, prefix: str = "serving/") -> dict:
 # -- compiled-step-program census ---------------------------------------------
 
 # Names under which serving surfaces register per-step compiled programs
-# with observe.CompileLog. "ragged" is the unified single-program step;
-# decode/mixed/spec_verify are the legacy trio (step_mode='legacy').
+# with observe.CompileLog: "ragged" is the engine's one packed step.
 # Draft programs deliberately don't count: the census answers "how many
 # distinct shapes does one serving iteration dispatch through".
-STEP_PROGRAM_NAMES = frozenset({"ragged", "decode", "mixed", "spec_verify"})
+STEP_PROGRAM_NAMES = frozenset({"ragged"})
 
 # The census key both serving surfaces expose: engine
 # Stats()["compile"]["step_programs"] and GShardDecode telemetry's

@@ -243,8 +243,7 @@ def RingAttentionSingleDevice(q, k, v, *, num_shards: int,
 
   Runs exactly the per-device program each of `num_shards` sp devices would
   run (num_shards q-shards x num_shards KV visits, flash per block, lse
-  merges) without the ppermutes. Used (a) as an exactness oracle for tests,
-  (b) by bench.py to measure the sp compute path on a single chip: with
+  merges) without the ppermutes: the exactness oracle for tests. With
   ideal ICI overlap, per-device ring step time ~= this / num_shards.
   """
   b, t, n, h = q.shape

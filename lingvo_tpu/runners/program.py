@@ -14,6 +14,7 @@ import collections
 import functools
 import json
 import os
+import threading
 import time
 from typing import Any, Callable
 
@@ -136,6 +137,7 @@ class BaseProgram:
     self._pipe_t_mark: float | None = None
     self._pipe_wait_mark = 0.0
     self._pipe_compile_mark = 0.0
+    self._pipe_thread: int | None = None   # the thread that dispatches
     from lingvo_tpu.core import summary_utils
     self._tb = summary_utils.SummaryWriter(
         self._program_dir, enabled=self.p.write_tensorboard)
@@ -794,6 +796,7 @@ class TrainProgram(BaseProgram):
       if self._pipe_t_mark is None:
         self._pipe_t_mark = t0
         self._pipe_wait_mark = wait0
+        self._pipe_thread = threading.get_ident()
         self._pipe_compile_mark = self._goodput.CompileSeconds()
     if p.on_device_loop:
       with jax.profiler.TraceAnnotation("lingvo/train/infeed_get"):
@@ -893,8 +896,11 @@ class TrainProgram(BaseProgram):
     """Pipelined goodput attribution, run on the telemetry worker at loop
     COMPLETION: loops execute serially on device however far ahead the
     host dispatches, so completion-to-completion intervals partition the
-    wall into per-loop spans. Each span minus the infeed wait and
-    lazy-compile seconds that accrued inside it is productive step time.
+    wall into per-loop spans. Each span minus the infeed wait and the
+    lazy-compile seconds the DISPATCHING thread spent inside it is
+    productive step time (a compile on any other thread — this worker,
+    the checkpoint writer — runs beside the device loop and delays
+    nothing).
     Replaces _AttributeRunWall on this path — with a k-deep window the
     Run wall is near zero and measures nothing. Returns the interval (the
     per-loop wall basis for rate metrics)."""
@@ -904,7 +910,7 @@ class TrainProgram(BaseProgram):
     wait_now = self._infeed.wait_s if self._infeed is not None else 0.0
     wait_d = max(wait_now - self._pipe_wait_mark, 0.0)
     self._pipe_wait_mark = wait_now
-    comp_now = self._goodput.CompileSeconds()
+    comp_now = self._goodput.CompileSeconds(self._pipe_thread)
     comp_d = max(comp_now - self._pipe_compile_mark, 0.0)
     self._pipe_compile_mark = comp_now
     interval = max(now - prev_t, 1e-9)

@@ -3,26 +3,22 @@
 Glues the four layers below into a running service:
 
     ops/ragged_block_attend.py   the packed-token paged attention kernel
-    ops/block_decode.py          the legacy-shape paged attention kernels
+    ops/block_decode.py          the [B, C]-shape paged attention kernels
+                                 (draft sources and GatedSSMLayer only)
     serving/kv_cache.py          host-side page ownership
     serving/scheduler.py         admission / step building / retirement
 
-Device-side there is ONE compiled step program (step_mode='ragged', the
-default): every serving iteration packs its work onto a single static
-[T] token axis (core/ragged.py) — a plain decode row contributes 1
-token, a prefilling row a token-budgeted prompt chunk, a speculating row
-its feedback token plus k draft tokens — and dispatches the same
-program. The legacy engine needed a separate compiled shape per step
-kind (pure decode `[B, 1]`, mixed `[B, prefill_chunk]`, spec-verify
-`[B, k+1]`), which cost extra compiles, forced whole-batch padding to
-the widest row, and serialized speculation behind prefill; the packed
-axis removes all three. Admission and eviction only rewrite int32 block
-tables between calls, so sequences enter and leave mid-flight with zero
+Device-side there is ONE compiled step program: every serving iteration
+packs its work onto a single static [T] token axis (core/ragged.py) — a
+plain decode row contributes 1 token, a prefilling row a token-budgeted
+prompt chunk, a speculating row its feedback token plus k draft tokens —
+and dispatches the same program. One shape means one compile, no
+whole-batch padding to the widest row, and speculation that never waits
+behind prefill. Admission and eviction only rewrite int32 block tables
+between calls, so sequences enter and leave mid-flight with zero
 recompilation — the property that lets short requests overtake long
 ones instead of idling behind them (the batch-synchronous `GShardDecode`
-failure mode this engine replaces). `step_mode='legacy'` keeps the old
-two-to-three-program engine as the comparison baseline; its byte-exact
-equivalence to ragged mode at temperature 0 is asserted in tests.
+failure mode this engine replaces).
 
 Speculative decoding (serving/spec_decode.py) configures a draft source
 (`spec=SelfDraft(...)` or `spec=ModelDraft(...)`): each iteration where
@@ -32,7 +28,7 @@ just width-(k+1) rows whose gathered logits flow through
 `SpecVerifyTokens` inside the one program — and commits each row's
 accepted prefix plus a bonus/correction token, rolling write cursors
 back over rejected tails. Prefilling neighbors ride the same step, so
-spec cycles no longer wait for pure-decode iterations. At temperature 0
+spec cycles never wait for pure-decode iterations. At temperature 0
 the output streams are token-identical to the non-spec engine (greedy
 acceptance keeps exactly the argmax prefix); at temperature > 0
 residual speculative sampling preserves each request's seeded output
@@ -54,8 +50,8 @@ state is a [max_batch, ...] per-slot array reset device-side on each
 sequence's first chunk (q_pos == 0). The engine takes a mixer census at
 construction: hybrid stacks price both resources, and pure-SSM stacks
 set `needs_kv_pages=False` so admission is bounded by decode slots only
-(the allocator is never charged — the more-concurrent-requests-at-fixed-
-HBM property the ISSUE's bench demonstrates).
+(the allocator is never charged, so more requests run concurrently at
+fixed HBM).
 
 Two front doors:
 - async: `Start()` + `Submit(prompt, max_new) -> StreamHandle` — tokens
@@ -234,7 +230,6 @@ class ServingLoop:
                serve_int8_weights: bool = False, spec=None,
                prefix_cache=None, trace=True, metrics_registry=None,
                serve_port: Optional[int] = None, watchdog=None,
-               step_mode: str = "ragged",
                prefill_token_budget: Optional[int] = None,
                prefix_swap_persist: bool = False,
                scheduler_mode: str = "fifo",
@@ -254,18 +249,16 @@ class ServingLoop:
     spec: optional speculative-decoding draft source —
     `spec_decode.SelfDraft` (early-exit over the same theta) or
     `spec_decode.ModelDraft` (independent pageless draft model). None
-    keeps the exact two-program legacy engine.
+    serves without speculation.
     prefix_cache: cross-request KV prefix sharing
-    (serving/prefix_cache.py) — None (default) keeps the bit-exact
-    legacy admission path, True builds a fresh PrefixCache over this
-    engine's pool, or pass a PrefixCache instance (rebound via Bind —
-    a cache built against a different pool or kv dtype is invalidated,
-    never cross-shared). Requires an attention-only stack: O(1)-state
+    (serving/prefix_cache.py) — None (default) admits without sharing,
+    True builds a fresh PrefixCache over this engine's pool, or pass a
+    PrefixCache instance (rebound via Bind — a cache built against a
+    different pool or kv dtype is invalidated, never cross-shared). Requires an attention-only stack: O(1)-state
     mixers carry recurrent state the cache can neither share nor skip.
     trace: per-request lifecycle tracing (observe/trace.py) — True (the
-    default; overhead is bounded by the bench's observability section)
-    builds a fresh TraceRecorder, False disables, or pass a TraceRecorder
-    to share/configure one. metrics_registry: the observe.MetricsRegistry
+    default) builds a fresh TraceRecorder, False disables, or pass a
+    TraceRecorder to share/configure one. metrics_registry: the observe.MetricsRegistry
     this engine publishes through (None = a fresh per-engine registry, so
     replicas and tests stay isolated).
     serve_port: opt-in fleet endpoints (observe/export.py) — an integer
@@ -277,15 +270,9 @@ class ServingLoop:
     configured StallWatchdog (capture logdir, injectable clock); the
     engine heartbeats it per step and feeds it queue observations, and
     /healthz runs its Check() at scrape time.
-    step_mode: 'ragged' (default) serves every iteration through ONE
-    compiled packed-token program (core/ragged.py) — prefill chunks,
-    plain decode rows, and spec-verify rows share each step; 'legacy'
-    keeps the previous two-to-three-program engine (the byte-identity
-    and bench baseline this PR's tests compare against).
-    prefill_token_budget: ragged mode only — prompt tokens the packed
-    step reserves beyond the worst-case decode tokens (defaults to
-    prefill_chunk); decode capacity left idle by empty slots flows to
-    prefill on top of it.
+    prefill_token_budget: prompt tokens the packed step reserves beyond
+    the worst-case decode tokens (defaults to prefill_chunk); decode
+    capacity left idle by empty slots flows to prefill on top of it.
     prefix_swap_persist: what UpdateTheta does to the prefix cache —
     False (default) drops the whole radix tree (Invalidate), True keeps
     the tree and marks every page stale (MarkStale): stale pages are
@@ -353,7 +340,7 @@ class ServingLoop:
       self.prefix_cache.Bind(self.alloc, self.kv_cache_dtype)
     self.prefix_swap_persist = bool(prefix_swap_persist)
     self.sched = scheduler_lib.Scheduler(
-        max_batch, self.alloc, table_pages, prefill_chunk,
+        max_batch, self.alloc, table_pages,
         needs_kv_pages=self.mixers["num_attention"] > 0,
         state_pool=self.state_pool, prefix_cache=self.prefix_cache,
         scheduler_mode=scheduler_mode, tenant_quotas=tenant_quotas,
@@ -380,26 +367,6 @@ class ServingLoop:
                            kv_cache_dtype)
     # donate the pool into each step off-cpu (XLA:CPU can't alias + warns)
     donate = (1,) if jax.default_backend() != "cpu" else ()
-    temp, topk = self.temperature, self.top_k
-    base_key = self.sample_seed
-
-    def _Step(theta, states, ids, q_pos, in_len, tables, seeds, pos):
-      logits, states = task.PagedStep(theta, ids, states, tables, q_pos,
-                                      in_len)
-      # sample every chunk column with the row's (seed, output-position)
-      # stream; CommitStep consumes exactly one column per row (col 0 for
-      # decode rows, the last valid prompt column for finishing prefills),
-      # so identical draws across columns are never double-consumed
-      key = jax.random.PRNGKey(base_key)
-      cols = [
-          sampling.SampleFromLogits(logits[:, c], key, temperature=temp,
-                                    top_k=topk, row_seeds=seeds,
-                                    positions=pos)
-          for c in range(logits.shape[1])
-      ]
-      return jnp.stack(cols, axis=1), states
-
-    self._step_fn = jax.jit(_Step, donate_argnums=donate)
     # copy-on-write executor: one jitted page copy across every page-pool
     # leaf of the decode state (compiled once; src/dst are traced scalars)
     self._cow_fn = (self._BuildCowFn(task, theta, kv_cache_dtype)
@@ -416,27 +383,18 @@ class ServingLoop:
     self._compile_log = observe.CompileLog(
         registry=self.metrics, namespace="serving/compile", donate=donate)
     self._spans = _StepSpans(self.trace)
-    # speculative decoding: the runner owns the draft + verify programs
-    # and (for ModelDraft) the draft model's recurrent state
+    # speculative decoding: the runner owns the draft programs and (for
+    # ModelDraft) the draft model's recurrent state
     self.spec = None
     if spec is not None:
       self.spec = spec_decode.SpecRunner(
           spec, task=task, theta=theta, max_batch=max_batch,
           page_size=page_size, prefill_chunk=prefill_chunk,
           temperature=self.temperature, top_k=self.top_k,
-          sample_seed=self.sample_seed, compile_log=self._compile_log)
+          sample_seed=self.sample_seed)
     # unified ragged step geometry: T packed tokens cover every slot's
     # worst-case decode width (1 + draft k) plus a prefill token budget;
     # wmax is the widest single row the one compiled program admits
-    if step_mode not in ("ragged", "legacy"):
-      raise ValueError(
-          "step_mode must be 'ragged' or 'legacy', got %r" % (step_mode,))
-    if (self.spec is not None and self.spec.w > 1
-        and step_mode == "legacy"):
-      raise ValueError(
-          "tree speculation (draft width > 1) requires step_mode='ragged' "
-          "— the legacy verify step is chain-only")
-    self.step_mode = step_mode
     self.prefill_token_budget = int(prefill_token_budget or prefill_chunk)
     # a speculating row is 1 root + w*k tree nodes wide (chain: w == 1)
     spec_width = ((1 + self.spec.w * self.spec.k)
@@ -560,17 +518,18 @@ class ServingLoop:
   def _BuildRaggedFn(self, task, donate):
     """Jits THE serving step: packed-token forward + sampling (+ verify).
 
-    One program covers every iteration shape the legacy engine needed
-    two-to-three programs for: prefill chunks, plain decode rows, and
-    spec-verify rows are just rows of different length on the same [T]
-    token axis (core/ragged.py). Sampling is per TOKEN with each token
-    broadcasting its row's (seed, output-position) stream — bitwise the
-    legacy per-column draws, which sampled every chunk column with the
-    same row stream. When a draft source is configured the verify lane
-    is always computed (static structure): rows with row_k == 0 flow
-    through SpecVerifyTokens as all-invalid and their column-0 output
-    is exactly the plain draw, so no-draft steps run the SAME program
-    with zero q_logits rather than a second compiled shape.
+    One program covers every iteration shape: prefill chunks, plain
+    decode rows, and spec-verify rows are just rows of different length
+    on the same [T] token axis (core/ragged.py). Sampling is per TOKEN
+    with each token broadcasting its row's (seed, output-position)
+    stream; the commit consumes one column per row (a decode row's only
+    column, a finishing prefill's last prompt column), so identical draws
+    across a row's columns are never double-consumed. When a draft
+    source is configured the verify lane is always computed (static
+    structure): rows with row_k == 0 flow through SpecVerifyTokens as
+    all-invalid and their column-0 output is exactly the plain draw, so
+    no-draft steps run the SAME program with zero q_logits rather than a
+    second compiled shape.
 
     Tree speculation (draft width w > 1) stays the SAME one program:
     speculating rows pack a w-ary token tree in DFS order, the verify
@@ -1090,8 +1049,8 @@ class ServingLoop:
     seed: per-request sampling seed (defaults to the request id) — only
     observable at temperature > 0; same seed = same continuation.
     spec_k: per-request speculative-decoding knob — None defers to the
-    engine (full draft length when a draft source is configured, exact
-    legacy behavior otherwise), 0 opts out, n > 0 caps the draft length
+    engine (full draft length when a draft source is configured, plain
+    decode otherwise), 0 opts out, n > 0 caps the draft length
     at min(n, engine k).
     spec_w: per-request tree-speculation WIDTH knob — None defers to the
     engine's draft width, 1 forces a linear chain (exact chain-spec
@@ -1154,24 +1113,6 @@ class ServingLoop:
 
   # -- core step (shared by sync and async modes) ----------------------------
 
-  def StepOnce(self) -> int:
-    """One admit → device step → commit iteration; returns #events.
-
-    Ragged mode (default): every iteration — any mix of prefill chunks,
-    plain decode rows, and spec-verify rows — launches the ONE compiled
-    packed-token program; with a draft source, rows that speculate get a
-    draft pass first while prefilling neighbors ride the same step.
-    Legacy mode: pure-decode iterations where at least one row
-    speculates become draft → verify → commit cycles; mixed steps (and
-    all-opted-out batches) take the two-program path."""
-    self._spans.Begin()
-    try:
-      if self.step_mode == "ragged":
-        return self._StepOnceRagged()
-      return self._StepOnceLegacy()
-    finally:
-      self._spans.Abandon()   # a no-op after the step's End
-
   def _AdmitPhase(self):
     """Evict + admit + per-admission bookkeeping (caller holds the lock)."""
     self.sched.EvictCancelled()
@@ -1201,223 +1142,128 @@ class ServingLoop:
       # split shared pages the new rows will write into BEFORE any step
       self._RunCow(admitted)
 
-  def _StepOnceRagged(self) -> int:
-    """One iteration through the unified ragged step program."""
-    spans = self._spans
-    spans.To("lock_wait")
-    with self._lock:
-      spans.To("admit")
-      self._AdmitPhase()
-      spans.To("build")
-      spec_k = self.spec.k if self.spec is not None else 0
-      spec_w = self.spec.w if self.spec is not None else 1
-      batch = self.sched.BuildRaggedStep(self._ragged_t, self._ragged_wmax,
-                                         spec_k=spec_k, spec_w=spec_w)
-      if batch is None:
-        return 0
-      tables = np.array(self.sched.block_tables)  # freeze under the lock
-      window = self._profile_window
-      if window is not None:
-        window.Start()
-    desc = batch.rows_desc
-    q_logits = None
-    if self.spec is not None:
-      spans.To("draft")
-      if batch.any_spec:
-        # draft outside the lock (device work), exactly like the legacy
-        # spec cycle; the RaggedBatch speaks the StepBatch protocol with
-        # in_len > 0 only on drafting rows, so prefill rows ride the
-        # step without activating the draft pass
-        d_toks, q_logits = self.spec.Draft(self._theta, self._states,
-                                           batch, tables)
-        # one dtype for both the drafted and the no-draft (zeros) case:
-        # the verify program must keep a single compiled signature
-        q_logits = q_logits.astype(jnp.float32)
-        # tree rows pack branch-major: branch bi's depth-d node sits at
-        # packed column 1 + bi*rk + d but draft index bi*spec_k + d —
-        # clamped rows (rk < spec_k) keep only each branch's prefix
-        for i in range(self.max_batch):
-          rk = int(batch.row_k[i])
-          if rk > 0:
-            for bi in range(int(batch.row_w[i])):
-              batch.tok_ids[desc.row_cols[i, 1 + bi * rk:1 + (bi + 1) * rk]
-                            ] = d_toks[i, bi * spec_k:bi * spec_k + rk]
-      else:
-        q_logits = self._ZeroQLogits()
-    spans.To("h2d")
-    rows_dev = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in desc))
-    args = [self._theta, self._states, jnp.asarray(batch.tok_ids),
-            rows_dev, jnp.asarray(tables), jnp.asarray(batch.row_seeds),
-            jnp.asarray(batch.row_pos)]
-    out = alen = None
-    if self.spec is not None:
-      args += [jnp.asarray(batch.row_k)]
-      if self.spec.w > 1:
-        args += [jnp.asarray(batch.row_w)]
-      args += [q_logits]
-      spans.To("dispatch")
-      sampled, out, alen, new_states = self._compile_log.Call(
-          "ragged", self._ragged_fn, *args)
-      spans.To("device_wait")
-      out, alen = np.asarray(out), np.asarray(alen)
-    else:
-      spans.To("dispatch")
-      sampled, new_states = self._compile_log.Call(
-          "ragged", self._ragged_fn, *args)
-      spans.To("device_wait")
-    self._states = new_states
-    sampled = np.asarray(sampled)
-    spans.To("lock_wait")
-    with self._lock:
-      spans.To("commit")
-      if self.trace is not None and batch.mixed:
-        # emit prefill-chunk spans BEFORE commit advances the cursors
-        for i, seq in enumerate(batch.rows):
-          n = int(desc.row_len[i])
-          if (seq is not None
-              and seq.state is scheduler_lib.SeqState.PREFILL and n > 0):
-            self.trace.PrefillChunk(seq.id, n)
-      events = self.sched.CommitRaggedStep(batch, sampled, out, alen)
-      self._counters["steps"].Inc()
-      self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
-      self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
-      if self._attend_bq:
-        row_len = np.asarray(desc.row_len, np.int64)
-        self._counters["attend_query_blocks"].Inc(
-            int(np.sum(-(-row_len // self._attend_bq))))
-        self._counters["attend_block_queries"].Inc(int(np.sum(row_len)))
-      if self.paged_path == "dense":
-        self._counters["dense_fallback_steps"].Inc()
-      if self._kv_quantized:
-        self._counters["quantized_steps"].Inc()
-      if batch.any_spec:
-        self._counters["spec_cycles"].Inc()
-        if batch.width_clamps:
-          self._counters["spec_width_clamps"].Inc(batch.width_clamps)
-        for i, seq in enumerate(batch.rows):
-          rk = int(batch.row_k[i])
-          if (seq is None or rk == 0
-              or seq.state is scheduler_lib.SeqState.CANCELLED):
-            continue
-          rw = int(batch.row_w[i])
-          m = min(int(alen[i]), rk)
-          self._counters["draft_tokens"].Inc(rw * rk)
-          self._counters["accepted_tokens"].Inc(m)
-          self._counters["spec_branches"].Inc(rw)
-          self.spec.accepted_len_hist[m] += 1
-          if self.trace is not None:
-            self.trace.SpecVerify(seq.id, rw * rk, m)
-            if rw * rk - m > 0:
-              self.trace.Rollback(seq.id, rw * rk - m)
-      self._PushEvents(events)
-      self._TickProfile()
-      self._BeatWatchdog()
-    spans.End(self._counters["steps"].value, int(desc.row_len.sum()),
-              batch.prompt_tokens, sum(r is not None for r in batch.rows))
-    return len(events)
+  def StepOnce(self) -> int:
+    """One admit → device step → commit iteration; returns #events.
 
-  def _StepOnceLegacy(self) -> int:
-    """One iteration through the legacy two-to-three-program engine."""
+    Every iteration — any mix of prefill chunks, plain decode rows, and
+    spec-verify rows — launches the ONE compiled packed-token program;
+    with a draft source, rows that speculate get a draft pass first
+    while prefilling neighbors ride the same step."""
     spans = self._spans
-    spans.To("lock_wait")
-    with self._lock:
-      spans.To("admit")
-      self._AdmitPhase()
-      spans.To("build")
-      vbatch = None
+    spans.Begin()
+    try:
+      spans.To("lock_wait")
+      with self._lock:
+        spans.To("admit")
+        self._AdmitPhase()
+        spans.To("build")
+        spec_k = self.spec.k if self.spec is not None else 0
+        spec_w = self.spec.w if self.spec is not None else 1
+        batch = self.sched.BuildRaggedStep(self._ragged_t, self._ragged_wmax,
+                                           spec_k=spec_k, spec_w=spec_w)
+        if batch is None:
+          return 0
+        tables = np.array(self.sched.block_tables)  # freeze under the lock
+        window = self._profile_window
+        if window is not None:
+          window.Start()
+      desc = batch.rows_desc
+      q_logits = None
       if self.spec is not None:
-        vbatch = self.sched.BuildVerifyStep(self.spec.k)
-      batch = None if vbatch is not None else self.sched.BuildStep()
-      if vbatch is None and batch is None:
-        return 0
-      tables = np.array(self.sched.block_tables)  # freeze under the lock
-      window = self._profile_window
-      if window is not None:
-        window.Start()
-    if vbatch is not None:
-      return self._SpecCycle(vbatch, tables)
-    spans.To("h2d")
-    args = [jnp.asarray(a) for a in (batch.ids, batch.q_pos, batch.in_len,
-                                     tables, batch.row_seeds, batch.row_pos)]
-    spans.To("dispatch")
-    sampled, new_states = self._compile_log.Call(
-        "mixed" if batch.mixed else "decode", self._step_fn,
-        self._theta, self._states, *args)
-    spans.To("device_wait")
-    self._states = new_states
-    sampled = np.asarray(sampled)
-    spans.To("lock_wait")
-    with self._lock:
-      spans.To("commit")
-      if self.trace is not None and batch.mixed:
-        # emit prefill-chunk spans BEFORE CommitStep advances the cursors:
-        # row i consumed in_len[i] prompt tokens starting at q_pos[i]
-        for i, seq in enumerate(batch.rows):
-          if (seq is not None
-              and seq.state is scheduler_lib.SeqState.PREFILL
-              and int(batch.in_len[i]) > 0):
-            self.trace.PrefillChunk(seq.id, int(batch.in_len[i]))
-      events = self.sched.CommitStep(batch, sampled)
-      self._counters["steps"].Inc()
-      self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
-      self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
-      if self.paged_path == "dense":
-        self._counters["dense_fallback_steps"].Inc()
-      if self._kv_quantized:
-        self._counters["quantized_steps"].Inc()
-      self._PushEvents(events)
-      self._TickProfile()
-      self._BeatWatchdog()
-    spans.End(self._counters["steps"].value, int(batch.in_len.sum()),
-              batch.prompt_tokens, sum(r is not None for r in batch.rows))
-    return len(events)
-
-  def _SpecCycle(self, vbatch, tables) -> int:
-    """Draft k tokens per row → ragged [B, k+1] verify → commit prefix."""
-    spec = self.spec
-    spans = self._spans
-    spans.To("draft")
-    d_toks, q_logits = spec.Draft(self._theta, self._states, vbatch, tables)
-    ids = np.array(vbatch.ids)
-    ids[:, 1:] = d_toks
-    vbatch.ids = ids
-    spans.To("dispatch")    # Verify places its own arguments
-    out, alen, new_states = spec.Verify(
-        self._theta, self._states, ids, vbatch, tables, q_logits)
-    spans.To("device_wait")
-    self._states = new_states
-    out, alen = np.asarray(out), np.asarray(alen)
-    spans.To("lock_wait")
-    with self._lock:
-      spans.To("commit")
-      events = self.sched.CommitVerifyStep(vbatch, out, alen)
-      self._counters["steps"].Inc()
-      self._counters["decode_steps"].Inc()
-      self._counters["spec_cycles"].Inc()
-      if self.paged_path == "dense":
-        self._counters["dense_fallback_steps"].Inc()
-      if self._kv_quantized:
-        self._counters["quantized_steps"].Inc()
-      for i, seq in enumerate(vbatch.rows):
-        rk = int(vbatch.row_k[i])
-        if (seq is None or rk == 0
-            or seq.state is scheduler_lib.SeqState.CANCELLED):
-          continue
-        m = min(int(alen[i]), rk)
-        self._counters["draft_tokens"].Inc(rk)
-        self._counters["accepted_tokens"].Inc(m)
-        self._counters["spec_branches"].Inc(1)   # legacy verify is chain
-        spec.accepted_len_hist[m] += 1
-        if self.trace is not None:
-          self.trace.SpecVerify(seq.id, rk, m)
-          if rk - m > 0:
-            self.trace.Rollback(seq.id, rk - m)
-      self._PushEvents(events)
-      self._TickProfile()
-      self._BeatWatchdog()
-    spans.End(self._counters["steps"].value, int(vbatch.in_len.sum()), 0,
-              sum(r is not None for r in vbatch.rows))
-    return len(events)
+        spans.To("draft")
+        if batch.any_spec:
+          # draft outside the lock (device work); the batch's row-level
+          # view has in_len > 0 only on drafting rows, so prefill rows ride
+          # the step without activating the draft pass
+          d_toks, q_logits = self.spec.Draft(self._theta, self._states,
+                                             batch, tables)
+          # one dtype for both the drafted and the no-draft (zeros) case:
+          # the verify program must keep a single compiled signature
+          q_logits = q_logits.astype(jnp.float32)
+          # tree rows pack branch-major: branch bi's depth-d node sits at
+          # packed column 1 + bi*rk + d but draft index bi*spec_k + d —
+          # clamped rows (rk < spec_k) keep only each branch's prefix
+          for i in range(self.max_batch):
+            rk = int(batch.row_k[i])
+            if rk > 0:
+              for bi in range(int(batch.row_w[i])):
+                batch.tok_ids[desc.row_cols[i, 1 + bi * rk:1 + (bi + 1) * rk]
+                              ] = d_toks[i, bi * spec_k:bi * spec_k + rk]
+        else:
+          q_logits = self._ZeroQLogits()
+      spans.To("h2d")
+      rows_dev = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in desc))
+      args = [self._theta, self._states, jnp.asarray(batch.tok_ids),
+              rows_dev, jnp.asarray(tables), jnp.asarray(batch.row_seeds),
+              jnp.asarray(batch.row_pos)]
+      out = alen = None
+      if self.spec is not None:
+        args += [jnp.asarray(batch.row_k)]
+        if self.spec.w > 1:
+          args += [jnp.asarray(batch.row_w)]
+        args += [q_logits]
+        spans.To("dispatch")
+        sampled, out, alen, new_states = self._compile_log.Call(
+            "ragged", self._ragged_fn, *args)
+        spans.To("device_wait")
+        out, alen = np.asarray(out), np.asarray(alen)
+      else:
+        spans.To("dispatch")
+        sampled, new_states = self._compile_log.Call(
+            "ragged", self._ragged_fn, *args)
+        spans.To("device_wait")
+      self._states = new_states
+      sampled = np.asarray(sampled)
+      spans.To("lock_wait")
+      with self._lock:
+        spans.To("commit")
+        if self.trace is not None and batch.mixed:
+          # emit prefill-chunk spans BEFORE commit advances the cursors
+          for i, seq in enumerate(batch.rows):
+            n = int(desc.row_len[i])
+            if (seq is not None
+                and seq.state is scheduler_lib.SeqState.PREFILL and n > 0):
+              self.trace.PrefillChunk(seq.id, n)
+        events = self.sched.CommitRaggedStep(batch, sampled, out, alen)
+        self._counters["steps"].Inc()
+        self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
+        self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
+        if self._attend_bq:
+          row_len = np.asarray(desc.row_len, np.int64)
+          self._counters["attend_query_blocks"].Inc(
+              int(np.sum(-(-row_len // self._attend_bq))))
+          self._counters["attend_block_queries"].Inc(int(np.sum(row_len)))
+        if self.paged_path == "dense":
+          self._counters["dense_fallback_steps"].Inc()
+        if self._kv_quantized:
+          self._counters["quantized_steps"].Inc()
+        if batch.any_spec:
+          self._counters["spec_cycles"].Inc()
+          if batch.width_clamps:
+            self._counters["spec_width_clamps"].Inc(batch.width_clamps)
+          for i, seq in enumerate(batch.rows):
+            rk = int(batch.row_k[i])
+            if (seq is None or rk == 0
+                or seq.state is scheduler_lib.SeqState.CANCELLED):
+              continue
+            rw = int(batch.row_w[i])
+            m = min(int(alen[i]), rk)
+            self._counters["draft_tokens"].Inc(rw * rk)
+            self._counters["accepted_tokens"].Inc(m)
+            self._counters["spec_branches"].Inc(rw)
+            self.spec.accepted_len_hist[m] += 1
+            if self.trace is not None:
+              self.trace.SpecVerify(seq.id, rw * rk, m)
+              if rw * rk - m > 0:
+                self.trace.Rollback(seq.id, rw * rk - m)
+        self._PushEvents(events)
+        self._TickProfile()
+        self._BeatWatchdog()
+      spans.End(self._counters["steps"].value, int(desc.row_len.sum()),
+                batch.prompt_tokens, sum(r is not None for r in batch.rows))
+      return len(events)
+    finally:
+      spans.Abandon()   # a no-op after the step's End
 
   def _PushEvents(self, events):
     """Streams committed tokens to their handles (caller holds the lock)."""
@@ -1550,9 +1396,8 @@ class ServingLoop:
         stats["watchdog"] = self.watchdog.Stats()
       records = self._compile_log.Records()
       # compiled-step-program census: how many distinct per-step programs
-      # this engine has actually compiled (ragged mode: exactly 1 across
-      # any admit/decode/spec/retire mix — the tentpole's acceptance bar;
-      # legacy mode: up to 3). Draft programs are NOT step programs.
+      # this engine has actually compiled (exactly 1 across any admit/
+      # decode/spec/retire mix). Draft programs are NOT step programs.
       records[observe_schema.COMPILE_CENSUS_KEY] = sum(
           1 for n in records if n in observe_schema.STEP_PROGRAM_NAMES)
       stats["compile"] = records

@@ -17,18 +17,19 @@ iteration is admit → build → (device step) → commit:
   which is the concurrency jump — and prefill starts at the first
   uncached token. A match covering the whole prompt copy-on-writes its
   final page, because prefill must recompute the last prompt token.
-- `BuildStep` flattens the live slots into one batch for the compiled
-  PagedStep program. Steady state is a pure decode step (chunk width
-  C == 1, every live row feeds its last sampled token). Whenever any slot
-  is still prefilling, the step widens to C == prefill_chunk and becomes a
-  MIXED step: prefilling rows consume up to C prompt tokens, decoding rows
-  ride along with in_len == 1 — decode is never stalled behind prefill,
-  which is the per-step prefill budget the ISSUE asks for.
-- `CommitStep` folds the device's sampled tokens back in: advances prompt
-  cursors, turns finished prefills into decoders (their first generated
-  token is the sample at the last valid chunk position), appends decode
-  tokens, retires sequences on max_new/EOS, and frees their slot + pages
-  immediately so `Admit` can refill the slot on the very next iteration.
+- `BuildRaggedStep` packs the live slots onto one static [T] token axis
+  for the compiled RaggedStep program (core/ragged.py). Decode rows are
+  mandatory and packed first (the last sampled token, plus draft slots
+  when the row speculates); prefilling rows then share what is left of
+  the axis, so a step that carries prompt tokens is a MIXED step and
+  decode is never stalled behind prefill.
+- `CommitRaggedStep` folds the device's sampled tokens back in: advances
+  prompt cursors, turns finished prefills into decoders (their first
+  generated token is the sample at the last prompt column), appends
+  decode tokens (a speculating row's accepted prefix plus its correction
+  token, rolling the cursor back over the rejected tail), retires
+  sequences on max_new/EOS, and frees their slot + pages immediately so
+  `Admit` can refill the slot on the very next iteration.
 
 SLO-aware scheduling (`scheduler_mode='priority'`, opt-in; 'fifo' is the
 bit-exact legacy default): requests carry a `priority` class and a
@@ -124,8 +125,8 @@ class Request:
   which slot or batch neighbors it is scheduled with.
 
   spec_k: per-request speculative-decoding knob. None (default) defers to
-  the engine — full draft length k when the engine speculates, the exact
-  legacy single-token path otherwise. 0 opts this request out of
+  the engine — full draft length k when the engine speculates, the plain
+  single-token row otherwise. 0 opts this request out of
   speculation entirely; n > 0 caps its draft length at min(n, engine k).
   Only consulted by engines with a draft source configured.
 
@@ -198,49 +199,26 @@ class Sequence:
     return len(self.req.prompt) - self.pos
 
 
-class StepBatch:
-  """One flattened device step (numpy; the engine jits over it)."""
-
-  def __init__(self, ids, q_pos, in_len, rows, mixed: bool,
-               prompt_tokens: int, row_seeds=None, row_pos=None,
-               row_k=None):
-    self.ids = ids          # [B, C] int32
-    self.q_pos = q_pos      # [B] int32
-    self.in_len = in_len    # [B] int32 (0 = inactive row)
-    self.rows = rows        # slot -> Sequence or None, frozen at build time
-    self.mixed = mixed      # True if any prefill row rode this step
-    self.prompt_tokens = prompt_tokens  # prompt tokens consumed this step
-    # sampling inputs: per-request seed + per-request output index (tokens
-    # generated so far) — together they make each draw a pure function of
-    # (engine seed, request seed, output position), never of scheduling
-    self.row_seeds = row_seeds  # [B] int32
-    self.row_pos = row_pos      # [B] int32
-    # verify steps only: per-row draft length (in_len = row_k + 1); the
-    # engine fills ids[:, 1:] with the draft's proposals before launch
-    self.row_k = row_k          # [B] int32 or None
-
-
 class RaggedBatch:
   """One packed ragged device step (numpy; the engine jits over it).
 
-  The unified replacement for all three StepBatch shapes: a decode row
-  carries 1 + row_w * row_k tokens (row_k > 0 is the spec-verify lane; a
-  row_w > 1 row packs a token TREE of row_w branches, each a chain of
-  row_k drafts, in DFS order — core/ragged.py), a prefill row a
-  token-budgeted chunk, and every composition launches through the SAME
-  compiled program. `rows_desc` is the core/ragged.RaggedRows routing
+  A decode row carries 1 + row_w * row_k tokens (row_k > 0 is the
+  spec-verify lane; a row_w > 1 row packs a token TREE of row_w
+  branches, each a chain of row_k drafts, in DFS order — core/ragged.py),
+  a prefill row a token-budgeted chunk, and every composition launches
+  through the SAME compiled program. `rows_desc` is the core/ragged.RaggedRows routing
   pytree; `tok_ids` is the matching packed [T] token stream — draft
   columns hold 0 until the engine fills proposals: branch bi's depth-d
   node at rows_desc.row_cols[i, 1 + bi * row_k[i] + d].
 
   The row-level view (ids / q_pos / in_len / rows / row_seeds / row_pos
-  / row_k) deliberately speaks the StepBatch protocol so
-  spec_decode.SpecRunner.Draft consumes a RaggedBatch unchanged. in_len
-  is nonzero ONLY for rows that draft this step, so the draft pass
-  activates exactly those — prefill rows ride the same device step
-  without drafting, which is what lets spec cycles proceed while
-  admissions are still prefilling (the legacy engine had to finish every
-  prefill before its first verify step).
+  / row_k) is what spec_decode.SpecRunner.Draft reads: [B]-shaped, one
+  entry per slot. in_len is nonzero ONLY for rows that draft this step,
+  so the draft pass activates exactly those — prefill rows ride the same
+  device step without drafting, which is what lets spec cycles proceed
+  while admissions are still prefilling. row_seeds and row_pos (per-
+  request seed, tokens generated so far) make each draw a pure function
+  of (engine seed, request seed, output position), never of scheduling.
   """
 
   def __init__(self, tok_ids, rows_desc: ragged.RaggedRows, rows,
@@ -260,7 +238,7 @@ class RaggedBatch:
     self.row_w = (row_w if row_w is not None
                   else np.ones_like(np.asarray(row_k)))
     self.width_clamps = width_clamps  # rows whose width the pack cap shrank
-    # -- StepBatch-protocol adapter for the draft source ----------------
+    # -- row-level view for the draft source ----------------------------
     self.ids = ids0               # [B, 1] int32: column-0 feedback token
     self.q_pos = rows_desc.row_q_pos
     self.in_len = np.where(row_k > 0, 1, 0).astype(np.int32)
@@ -270,15 +248,13 @@ class Scheduler:
   """Admission + step building + commit over B slots and a page pool."""
 
   def __init__(self, max_slots: int, allocator: kv_cache.PageAllocator,
-               table_pages: int, prefill_chunk: int,
-               needs_kv_pages: bool = True,
+               table_pages: int, needs_kv_pages: bool = True,
                state_pool: Optional[kv_cache.StateSlotPool] = None,
                prefix_cache=None, scheduler_mode: str = "fifo",
                host_store: Optional[kv_cache.HostPageStore] = None,
                tenant_quotas=None, tenant_weights=None, clock=None):
     """table_pages: block-table width (pages per sequence) — the static
     max_seq_len / page_size bound every compiled program carries.
-    prefill_chunk: prompt tokens a prefilling row consumes per mixed step.
     needs_kv_pages: False for pure-O(1)-mixer stacks (no attention layer
     writes the paged pool) — admission is then bounded by slots only, and
     the allocator is never charged. state_pool: slot-ownership accounting
@@ -295,12 +271,11 @@ class Scheduler:
     weighted-fair admission within a priority class (default 1.0).
     clock: injectable monotonic-seconds source for quota refill (tests).
     """
-    assert max_slots >= 1 and table_pages >= 1 and prefill_chunk >= 1
+    assert max_slots >= 1 and table_pages >= 1
     assert scheduler_mode in ("fifo", "priority"), scheduler_mode
     self.max_slots = max_slots
     self.alloc = allocator
     self.table_pages = table_pages
-    self.prefill_chunk = prefill_chunk
     self.needs_kv_pages = needs_kv_pages
     self.state_pool = state_pool
     self.prefix_cache = prefix_cache
@@ -703,183 +678,7 @@ class Scheduler:
     return (any(s is not None for s in self.slots) or bool(self.waiting)
             or bool(self.preempted))
 
-  def BuildStep(self) -> Optional[StepBatch]:
-    """Flattens live slots into one [B, C] device step (None if idle)."""
-    rows = list(self.slots)
-    if not any(s is not None for s in rows):
-      return None
-    mixed = any(s is not None and s.state is SeqState.PREFILL for s in rows)
-    c = self.prefill_chunk if mixed else 1
-    b = self.max_slots
-    ids = np.zeros((b, c), np.int32)
-    q_pos = np.zeros((b,), np.int32)
-    in_len = np.zeros((b,), np.int32)
-    row_seeds = np.zeros((b,), np.int32)
-    row_pos = np.zeros((b,), np.int32)
-    prompt_tokens = 0
-    for i, seq in enumerate(rows):
-      if seq is None:
-        continue
-      q_pos[i] = seq.pos
-      row_seeds[i] = seq.req.seed
-      row_pos[i] = len(seq.out)
-      if seq.state is SeqState.PREFILL:
-        n = min(c, seq.prompt_remaining)
-        ids[i, :n] = seq.req.prompt[seq.pos:seq.pos + n]
-        in_len[i] = n
-        prompt_tokens += n
-      else:  # DECODE: feed the last sampled token (writes it to the cache)
-        ids[i, 0] = seq.out[-1]
-        in_len[i] = 1
-      if self.needs_kv_pages:
-        # prefix sharing invariant: this row's KV writes must land only
-        # in pages it exclusively owns (CoW happened at admission)
-        self.alloc.AssertExclusive(seq.id, seq.pos, int(in_len[i]))
-    return StepBatch(ids, q_pos, in_len, rows, mixed, prompt_tokens,
-                     row_seeds=row_seeds, row_pos=row_pos)
-
-  def CommitStep(self, batch: StepBatch, sampled: np.ndarray) -> list:
-    """Folds sampled [B, C] back into the state machine.
-
-    Returns [(request_id, token or None, finished: bool)] events in slot
-    order — one event per live row that produced a token or finished."""
-    events = []
-    for i, seq in enumerate(batch.rows):
-      if seq is None or seq.state is SeqState.CANCELLED:
-        continue   # cancelled mid-step: drop the token, evict at boundary
-      if seq.state is SeqState.PREFILL:
-        n = int(batch.in_len[i])
-        seq.pos += n
-        if seq.prompt_remaining > 0:
-          continue                       # more prompt chunks to go
-        tok = int(sampled[i, n - 1])     # sample after the LAST prompt token
-        seq.state = SeqState.DECODE
-        if self.prefix_cache is not None and self.needs_kv_pages:
-          # the prompt's K/V is now fully resident: cache its full-page
-          # prefix (the partial tail page — and every decode page after
-          # it — stays private to this sequence)
-          n_full = len(seq.req.prompt) // self.alloc.page_size
-          if n_full > 0:
-            self.prefix_cache.Insert(
-                seq.req.prompt, self.alloc.PagesOf(seq.id)[:n_full])
-      elif seq.state is SeqState.DECODE:
-        seq.pos += 1                     # the fed-back token is now cached
-        tok = int(sampled[i, 0])
-      else:
-        continue
-      seq.out.append(tok)
-      done_eos = (seq.req.eos_id is not None and tok == seq.req.eos_id)
-      done_len = len(seq.out) >= seq.req.max_new
-      if done_eos or done_len:
-        self.slots[i] = None
-        self.alloc.Free(seq.id)
-        if self.state_pool is not None:
-          self.state_pool.Release(seq.id)
-        self.finished += 1
-        self._Retire(seq, SeqState.FINISHED, "eos" if done_eos else "length")
-        events.append((seq.id, tok, True))
-      else:
-        events.append((seq.id, tok, False))
-    return events
-
-  # -- speculative decoding (draft-and-verify) -------------------------------
-
-  def BuildVerifyStep(self, k: int) -> Optional[StepBatch]:
-    """Flattens live DECODE slots into one ragged [B, k+1] VERIFY step.
-
-    Row i carries its last emitted token at column 0 (exactly the token a
-    plain decode step would feed) plus row_k[i] draft slots the engine
-    fills after running the draft source; in_len = row_k + 1 makes the
-    step ragged through the SAME masking the mixed prefill path uses, so
-    rows that opt out (spec_k = 0) ride along with in_len == 1 — their
-    column-0 logits are the legacy decode logits.
-
-    row_k is clamped to the request's remaining token budget, which also
-    bounds every KV write to the pages reserved at admission (positions
-    written are q_pos .. q_pos + row_k <= prompt + max_new - 1).
-
-    Returns None when any live row is still prefilling (the caller takes
-    a normal mixed step) or when no row speculates this cycle (the caller
-    falls back to BuildStep)."""
-    assert k >= 1, k
-    rows = list(self.slots)
-    live = [s for s in rows if s is not None]
-    if not live or any(s.state is SeqState.PREFILL for s in live):
-      return None
-    b, c = self.max_slots, k + 1
-    ids = np.zeros((b, c), np.int32)
-    q_pos = np.zeros((b,), np.int32)
-    in_len = np.zeros((b,), np.int32)
-    row_seeds = np.zeros((b,), np.int32)
-    row_pos = np.zeros((b,), np.int32)
-    row_k = np.zeros((b,), np.int32)
-    any_spec = False
-    for i, seq in enumerate(rows):
-      if seq is None or seq.state is not SeqState.DECODE:
-        continue
-      q_pos[i] = seq.pos
-      row_seeds[i] = seq.req.seed
-      row_pos[i] = len(seq.out)
-      ids[i, 0] = seq.out[-1]
-      rk = k if seq.req.spec_k is None else min(seq.req.spec_k, k)
-      rk = min(rk, seq.req.max_new - len(seq.out))
-      row_k[i] = max(rk, 0)
-      in_len[i] = row_k[i] + 1
-      any_spec = any_spec or row_k[i] > 0
-      if self.needs_kv_pages:
-        # rollback safety against prefix sharing: the verify step writes
-        # (and, after rejection, REWRITES) slots pos..pos+row_k — those
-        # pages must never be shared with another request or the cache
-        self.alloc.AssertExclusive(seq.id, seq.pos, int(in_len[i]))
-    if not any_spec:
-      return None
-    return StepBatch(ids, q_pos, in_len, rows, mixed=False, prompt_tokens=0,
-                     row_seeds=row_seeds, row_pos=row_pos, row_k=row_k)
-
-  def CommitVerifyStep(self, batch: StepBatch, out_tokens: np.ndarray,
-                       accept_len: np.ndarray) -> list:
-    """Folds a verify step back in: emits each row's accepted prefix plus
-    the correction/bonus token, rolls the KV cursor back over the
-    rejected tail (pure accounting — rejected slots are re-written next
-    cycle, and reads never pass q_pos + in_len), and retires on
-    eos/max_new exactly like CommitStep.
-
-    out_tokens [B, k+1], accept_len [B] from the verify program. Returns
-    the same [(request_id, token, finished)] event list as CommitStep,
-    possibly several events per row."""
-    events = []
-    for i, seq in enumerate(batch.rows):
-      if seq is None or seq.state is not SeqState.DECODE:
-        continue   # cancelled mid-step: drop the tokens, evict at boundary
-      rk = int(batch.row_k[i])
-      m = min(int(accept_len[i]), rk)
-      # drafted-but-rejected tail: cursor rollback, counted on the pool
-      self.alloc.NoteRollback(rk - m)
-      committed = 0
-      for j in range(m + 1):
-        tok = int(out_tokens[i, j])
-        seq.pos += 1            # verify wrote this column's K/V already
-        seq.out.append(tok)
-        committed += 1
-        done_eos = (seq.req.eos_id is not None and tok == seq.req.eos_id)
-        done_len = len(seq.out) >= seq.req.max_new
-        if done_eos or done_len:
-          self.slots[i] = None
-          self.alloc.Free(seq.id)
-          if self.state_pool is not None:
-            self.state_pool.Release(seq.id)
-          self.finished += 1
-          self._Retire(seq, SeqState.FINISHED,
-                       "eos" if done_eos else "length")
-          events.append((seq.id, tok, True))
-          break
-        events.append((seq.id, tok, False))
-      if committed < m + 1:
-        # accepted tokens truncated by an early eos are rolled back too
-        self.alloc.NoteRollback(m + 1 - committed)
-    return events
-
-  # -- unified ragged step ----------------------------------------------------
+  # -- the packed step --------------------------------------------------------
 
   def BuildRaggedStep(self, t: int, wmax: int, spec_k: int = 0,
                       spec_w: int = 1) -> Optional[RaggedBatch]:
@@ -893,9 +692,10 @@ class Scheduler:
     spec_w: engine draft-tree width (1 = chain speculation).
 
     Decode rows are mandatory and packed first: 1 feedback token plus
-    row_w * row_k draft slots. row_k is clamped per request exactly like
-    BuildVerifyStep (request opt-out/cap, remaining max_new budget, and
-    the packed-row cap); row_w (tree rows only) is clamped WIDTH BEFORE
+    row_w * row_k draft slots. row_k is clamped per request (request
+    opt-out/cap, the remaining max_new budget — which also bounds every
+    KV write to the pages reserved at admission — and the packed-row
+    cap); row_w (tree rows only) is clamped WIDTH BEFORE
     DEPTH under min(wmax, ragged.MAX_TREE_COLS) — under pressure a
     request loses branches before it loses per-branch depth, because a
     deep chain keeps the accepted-length upside that extra siblings only
@@ -1003,9 +803,9 @@ class Scheduler:
       else:
         tok_ids[cols[0]] = seq.out[-1]  # draft columns stay 0 until Draft
       if self.needs_kv_pages:
-        # same exclusivity invariant as BuildStep/BuildVerifyStep: every
-        # slot this row writes (and, on spec rollback, REWRITES) lives in
-        # pages CoW-private to it
+        # prefix sharing invariant: every slot this row writes (and, on
+        # spec rollback, REWRITES) lives in pages CoW-private to it —
+        # never shared with another request or the cache
         self.alloc.AssertExclusive(seq.id, seq.pos, n)
     self.width_clamps += width_clamps
     return RaggedBatch(tok_ids, desc, rows, prompt_tokens > 0,
@@ -1013,7 +813,7 @@ class Scheduler:
                        ids0, row_w=row_w, width_clamps=width_clamps)
 
   def _Finish(self, i: int, seq: Sequence, done_eos: bool):
-    """Retires slot i's sequence (shared CommitRaggedStep epilogue)."""
+    """Retires slot i's sequence (CommitRaggedStep's epilogue)."""
     self.slots[i] = None
     self.alloc.Free(seq.id)
     if self.state_pool is not None:
@@ -1023,18 +823,17 @@ class Scheduler:
 
   def CommitRaggedStep(self, batch: RaggedBatch, sampled_tok: np.ndarray,
                        out_tokens=None, accept_len=None) -> list:
-    """Folds one ragged step back in: CommitStep + CommitVerifyStep, unified.
+    """Folds one ragged step's device outputs back into the state machine.
 
     sampled_tok [T]: the program's per-token draws — token t's draw is a
     pure function of (engine seed, row seed, row output position), so a
     prefill row reads its LAST prompt token's column and a plain decode
-    row its only column, exactly the draws the legacy [B, C] programs
-    made. out_tokens [B, k+1] / accept_len [B]: the verify lane, consumed
-    only by rows with row_k > 0 (their column-0 entry is bitwise the
-    plain draw, so routing rk == 0 rows through sampled_tok is
-    equivalent — and keeps the no-spec engine free of verify outputs).
-    Returns the same [(request_id, token, finished)] event list as the
-    legacy commits, possibly several events per speculating row."""
+    row its only column. out_tokens [B, k+1] / accept_len [B]: the
+    verify lane, consumed only by rows with row_k > 0 (their column-0
+    entry is bitwise the plain draw, so routing rk == 0 rows through
+    sampled_tok is equivalent — and keeps the no-spec engine free of
+    verify outputs). Returns [(request_id, token, finished: bool)] events
+    in slot order, possibly several per speculating row."""
     events = []
     desc = batch.rows_desc
     for i, seq in enumerate(batch.rows):
@@ -1058,11 +857,11 @@ class Scheduler:
         rk = int(batch.row_k[i])
         if rk > 0:
           # spec-verify lane: accepted path + correction/bonus, cursor
-          # rollback over every other tree node — CommitVerifyStep
-          # semantics generalized to row_w branches (chain: row_w == 1).
-          # The engine's in-program KV repair already moved the accepted
-          # path's K/V into the canonical chain slots, so advancing
-          # seq.pos by m + 1 lands on bit-correct cache state.
+          # rollback over every other tree node (pure accounting —
+          # rejected slots are re-written next cycle, and reads never pass
+          # q_pos + row_len). The engine's in-program KV repair already
+          # moved the accepted path's K/V into the canonical chain slots,
+          # so advancing seq.pos by m + 1 lands on bit-correct cache state.
           rw = int(batch.row_w[i])
           m = min(int(accept_len[i]), rk)
           self.alloc.NoteRollback(rw * rk - m)

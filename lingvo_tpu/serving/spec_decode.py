@@ -20,11 +20,10 @@ of the continuous-batching engine with NO new kernels:
      whose state mutations are discarded — so draft rejection needs no
      rollback machinery at all.
 
-2. VERIFY — the scheduler builds ONE ragged [B, k+1] step (the exact
-   mixed-step machinery: `BlockPrefill` already IS "k+1 causal queries
-   against a paged prefix"): each row carries [t0, d_1..d_k] at
-   in_len = row_k + 1; opted-out rows ride along with in_len == 1, which
-   is bitwise the legacy decode step for them.
+2. VERIFY — a speculating row rides the engine's one packed step as a
+   row of width row_k + 1 carrying [t0, d_1..d_k] (k+1 causal queries
+   against its paged prefix); opted-out rows ride along at width 1,
+   which is bitwise the plain decode row.
 
 3. ACCEPT/ROLLBACK — `core/sampling.SpecVerifyTokens` picks the accepted
    prefix (greedy match, or residual speculative sampling at
@@ -34,17 +33,15 @@ of the continuous-batching engine with NO new kernels:
    doesn't advance `seq.pos`); O(1)-state mixers instead return their
    per-column state trajectory (`ssm_col_states`) and `_SelectAcceptedCols`
    restores each slot to the last accepted column on device, inside the
-   same compiled verify program.
+   same compiled step program.
 
-Step-program cost: in the engine's default ragged mode the verify lane is
-FOLDED INTO the one unified step program (spec rows are simply rows of
-width k+1 on the packed token axis, and SpecVerifyTokens runs on their
-gathered logits inside the same jit), so speculation adds only the draft
-program(s). In legacy mode the verify step is a THIRD compiled step
-program ([B, k+1]) next to decode and mixed. Either way,
+Step-program cost: the verify lane is FOLDED INTO the engine's one step
+program (spec rows are simply rows of width k+1 on the packed token
+axis, and SpecVerifyTokens runs on their gathered logits inside the same
+jit), so speculation adds only the draft program(s), and
 admission/eviction still only rewrite int32 block tables.
 
-TREE speculation (w > 1 on either draft source, ragged mode only): the
+TREE speculation (w > 1 on either draft source): the
 draft proposes a token TREE per row — w branches forked at depth 1, each
 a chain of k tokens, packed branch-major so draft index bi * k + d is
 branch bi's depth-(d+1) node. Branch heads are the top-w tokens of the
@@ -195,7 +192,7 @@ def _SelectAcceptedCols(states, accept_len):
 
 
 class SpecRunner:
-  """Owns the draft + verify compiled programs and draft-model state.
+  """Owns the draft compiled programs and draft-model state.
 
   Built by ServingLoop when a draft source is configured; all scheduler
   bookkeeping stays in serving/scheduler.py, all device programs live
@@ -205,14 +202,10 @@ class SpecRunner:
 
   def __init__(self, config, *, task, theta, max_batch: int,
                page_size: int, prefill_chunk: int, temperature: float,
-               top_k: int, sample_seed: int, compile_log=None):
+               top_k: int, sample_seed: int):
     self.config = config
     self.k = config.k
     self.w = getattr(config, "w", 1)
-    # optional observe.CompileLog: routes the verify program through a
-    # one-shot AOT compile so the engine's compile records cover all
-    # three step programs (decode / mixed / spec_verify)
-    self._compile_log = compile_log
     self.is_self = isinstance(config, SelfDraft)
     self._task = task
     self._temperature = float(temperature)
@@ -220,7 +213,6 @@ class SpecRunner:
     self._sample_seed = int(sample_seed)
     self._max_batch = max_batch
     self._prefill_chunk = prefill_chunk
-    self._has_ssm = MixerCensus(task)["num_ssm"] > 0
     # accepted-length histogram: hist[m] = verify rows whose accepted
     # draft prefix (tree: accepted root-to-leaf DEPTH along the winning
     # branch) had length m — each such row committed m + 1 tokens
@@ -263,25 +255,8 @@ class SpecRunner:
 
   def _BuildPrograms(self):
     k, temp, topk = self.k, self._temperature, self._top_k
-    task, has_ssm = self._task, self._has_ssm
+    task = self._task
     base_key = self._sample_seed
-
-    def _Verify(theta, states, ids, q_pos, in_len, tables, seeds, pos,
-                q_logits):
-      logits, new_states = task.PagedStep(theta, ids, states, tables,
-                                          q_pos, in_len,
-                                          ssm_col_states=has_ssm)
-      draft_valid = (jnp.arange(k, dtype=jnp.int32)[None]
-                     < (in_len - 1)[:, None])
-      key = jax.random.PRNGKey(base_key)
-      out, alen = sampling.SpecVerifyTokens(
-          logits, ids[:, 1:], q_logits, key, temperature=temp, top_k=topk,
-          row_seeds=seeds, row_pos=pos, draft_valid=draft_valid)
-      if has_ssm:
-        new_states = _SelectAcceptedCols(new_states, alen)
-      return out, alen, new_states
-
-    self._verify_fn = jax.jit(_Verify)
 
     def _DraftKey():
       return jax.random.fold_in(jax.random.PRNGKey(base_key),
@@ -448,11 +423,7 @@ class SpecRunner:
     the committed stream host-side, which also covers prefix-cache
     admissions whose prefill skipped cached tokens entirely). Runs the
     consume program in prefill_chunk-wide bites before the row's first
-    draft; steady state never enters the loop. This replaced the legacy
-    mixed-step ConsumeStep ride-along, whose prefill-row masking special
-    case existed only because the old engine gave prefill its own step
-    shape — under the unified ragged step there is no separate mixed
-    step to ride."""
+    draft; steady state never enters the loop."""
     cp = self._prefill_chunk
     while True:
       todo = []
@@ -522,18 +493,6 @@ class SpecRunner:
       if clen[i]:
         seq.draft_pos += int(clen[i])
     return np.asarray(d), q
-
-  def Verify(self, theta, states, ids: np.ndarray, vbatch, tables,
-             q_logits):
-    """The third compiled step program: ragged [B, k+1] verify + accept +
-    SSM rollback in ONE jit. Returns (out_tokens, accept_len, states)."""
-    args = (theta, states, jnp.asarray(ids), jnp.asarray(vbatch.q_pos),
-            jnp.asarray(vbatch.in_len), jnp.asarray(tables),
-            jnp.asarray(vbatch.row_seeds), jnp.asarray(vbatch.row_pos),
-            q_logits)
-    if self._compile_log is not None:
-      return self._compile_log.Call("spec_verify", self._verify_fn, *args)
-    return self._verify_fn(*args)
 
   def Describe(self) -> dict:
     return self.config.Describe()

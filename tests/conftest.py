@@ -13,7 +13,7 @@ if "xla_force_host_platform_device_count" not in flags:
       flags + " --xla_force_host_platform_device_count=8").strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
-# One persistent compile cache for tests, trainer, bench and chip_smoke: a
+# One persistent compile cache for tests, trainer and chip_smoke: a
 # cold tier-1 run sits at the edge of its time cap, a warm one does not.
 from lingvo_tpu.core import compile_cache  # noqa: E402  (env set first)
 

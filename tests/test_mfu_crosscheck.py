@@ -1,4 +1,4 @@
-"""MFU cross-check: the analytic FLOPs formula used by bench.py must agree
+"""MFU cross-check: the analytic 6ND FLOPs formula must agree
 with XLA's own cost analysis of the compiled train step (VERDICT r3 weak #3
 — previously reported side by side but never asserted).
 
@@ -17,8 +17,8 @@ from lingvo_tpu.core import computation_cost, input_policy, py_utils
 
 
 def _AnalyticTrainStepFlops(task_p, n_params, batch):
-  """bench.py's formula (bench.py _BenchDense): 6*(N-emb)*tokens matmul +
-  6*emb*tokens softmax + 12*B*T^2*D*L attention."""
+  """6*(N-emb)*tokens matmul + 6*emb*tokens softmax + 12*B*T^2*D*L
+  attention (the full T^2: XLA's count has no causal skip either)."""
   b, t = batch.ids.shape
   tokens = b * t
   emb_params = task_p.vocab_size * task_p.model_dim
@@ -63,4 +63,4 @@ class TestMfuCrossCheck:
     ratio = xla / analytic
     assert 0.9 <= ratio <= 1.1, (
         f"XLA flops {xla:.3g} vs analytic {analytic:.3g} (ratio "
-        f"{ratio:.3f}) — the bench MFU formula has drifted")
+        f"{ratio:.3f}) — the analytic MFU formula has drifted")

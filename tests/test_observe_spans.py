@@ -30,6 +30,7 @@ sys.path.insert(0, os.path.join(
 import trace_report  # noqa: E402
 
 from tests.test_observe import _CheckChromeTrace, _FakeClock, _TinyLmParams
+from tests.test_serving_engine import _GreedyRef
 
 TRAIN_MODEL = "lm.synthetic_packed_input.DenseLmTiny"
 TRAIN_SCOPES = ("atten", "ffn", "norm", "embed", "head_loss",
@@ -162,16 +163,6 @@ class TestEngineStepRecords:
     eng = _Engine(tiny_lm, trace=False)
     out = eng.RunBatch(np.ones((1, 3), np.int32), np.array([3]), 2)
     assert out.shape == (1, 2) and eng.trace is None
-
-  @pytest.mark.parametrize("step_mode", ["legacy"])
-  def test_legacy_steps_are_recorded_too(self, tiny_lm, step_mode):
-    eng = _Engine(tiny_lm, step_mode=step_mode)
-    eng.RunBatch(np.ones((2, 5), np.int32), np.array([5, 4]), 3)
-    steps = eng.trace.Steps()
-    assert len(steps) == eng.Stats()["steps"] >= 3
-    for st in steps:
-      ph = st.Phases()
-      assert ph["dispatch"] > 0 and ph["commit"] > 0 and ph["h2d"] > 0
 
   def test_spec_engine_records_the_draft_phase(self, tiny_lm):
     from lingvo_tpu.serving import spec_decode
@@ -438,10 +429,10 @@ class TestScopeNames:
     assert len(scoped) > 0.6 * len(names), (len(scoped), len(names))
 
   def test_scopes_change_no_number(self, tiny_lm):
-    """Metadata only: the same greedy tokens as ever (a fixed expectation
-    would only pin the init; two engines must agree and be deterministic)."""
-    a = _Engine(tiny_lm).RunBatch(np.ones((2, 4), np.int32),
-                                  np.array([4, 3]), 4)
-    b = _Engine(tiny_lm, step_mode="legacy").RunBatch(
-        np.ones((2, 4), np.int32), np.array([4, 3]), 4)
-    assert np.array_equal(a, b)
+    """Metadata only: the scoped step emits the greedy tokens of the dense
+    per-request rollout, which runs under no scope."""
+    task, theta = tiny_lm
+    out = _Engine(tiny_lm).RunBatch(np.ones((2, 4), np.int32),
+                                    np.array([4, 3]), 4)
+    for row, n in zip(out, (4, 3)):
+      assert list(row) == _GreedyRef(task, theta, [1] * n, 4)

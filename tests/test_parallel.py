@@ -481,7 +481,7 @@ class TestRingAttention:
 
   @pytest.mark.slow
   def test_single_device_decomposition_matches(self):
-    # the bench's sp-simulation path is the same math as full attention
+    # the serial ring decomposition is the same math as full attention
     import math
     b, t, n, h = 2, 64, 2, 8
     q = jax.random.normal(KEY, (b, t, n, h))

@@ -1,17 +1,19 @@
 """One ragged step program (serving/scheduler.py + serving/engine.py).
 
 Covers docs/ragged_step.md:
-- ragged-vs-legacy BYTE-IDENTITY: seeded mixed-length request streams
-  produce token-for-token identical outputs on `step_mode='ragged'` and
-  `step_mode='legacy'` engines — greedy across draft sources (none /
-  SelfDraft / ModelDraft) and target shapes (dense / hybrid-SSM), with
-  the prefix cache on and off; temperature > 0 without a draft source is
-  byte-identical too (per-token draws are position-indexed, so packing
-  never moves a request's sampling stream),
+- engine-vs-reference TOKEN IDENTITY: every request of a seeded
+  mixed-length stream comes out token-for-token as the dense per-request
+  rollout of tests/test_serving_engine.py, which shares no scheduler,
+  allocator, pool or packed step with the engine — greedy (`_GreedyRef`)
+  across draft sources (none / SelfDraft / ModelDraft) and target shapes
+  (dense / hybrid-SSM), with the prefix cache on and off; temperature > 0
+  without a draft source (`_SampledRef`) is identical too (per-token draws
+  are position-indexed, so packing never moves a request's sampling
+  stream),
 - the compiled-program census: one serving lifetime with admissions,
   prefill/decode overlap, spec cycles, a cancellation and retirements
   compiles EXACTLY ONE step program (`Stats()["compile"]` census == 1,
-  name "ragged", no fallback), where the legacy trio compiles three,
+  name "ragged", no fallback),
 - `BuildRaggedStep` packing: decode rows mandatory-first with per-row
   draft clamps, prefill consuming the leftover budget, zero-length rows
   riding with their true q_pos (the SSM-reset trigger is q_pos == 0),
@@ -38,36 +40,36 @@ from lingvo_tpu.serving import prefix_cache as prefix_cache_lib
 from lingvo_tpu.serving import scheduler as scheduler_lib
 from lingvo_tpu.serving import spec_decode
 
+from tests.test_serving_engine import _GreedyRef, _SampledRef
 from tests.test_spec_decode import (_Instantiate, _LmParams, _Stream,
                                     _RunStream)  # noqa: F401
 # tiny_lm / hybrid_lm / ssm_draft_lm fixtures: session-scoped in conftest.py
 
 
-def _Engine(task, theta, spec=None, *, step_mode="ragged", **kw):
+def _Engine(task, theta, spec=None, **kw):
   kw.setdefault("page_size", 4)
   kw.setdefault("num_pages", 24)
   kw.setdefault("max_batch", 3)
   kw.setdefault("max_seq_len", 32)
   kw.setdefault("prefill_chunk", 4)
   kw.setdefault("default_max_new", 8)
-  return engine_lib.ServingLoop(task, theta, spec=spec, step_mode=step_mode,
-                                **kw)
+  return engine_lib.ServingLoop(task, theta, spec=spec, **kw)
 
 
-def _BothModes(task, theta, reqs, spec_fn=None, **kw):
-  """Runs one stream through a ragged and a legacy engine; returns both."""
-  outs = {}
-  for mode in ("ragged", "legacy"):
-    spec = spec_fn() if spec_fn is not None else None
-    eng = _Engine(task, theta, spec, step_mode=mode, **kw)
-    outs[mode] = (_RunStream(eng, reqs), eng)
-  return outs
+def _ServeAgainstGreedyRef(task, theta, reqs, spec=None, **kw):
+  """Runs one stream through an engine, holds every request's tokens to
+  the dense greedy rollout of its prompt, and returns the engine."""
+  eng = _Engine(task, theta, spec, **kw)
+  outs = _RunStream(eng, reqs)
+  for i, ((prompt, max_new), out) in enumerate(zip(reqs, outs)):
+    assert out == _GreedyRef(task, theta, prompt, max_new), (i, kw)
+  return eng
 
 
-# -- ragged vs legacy byte-identity -------------------------------------------
+# -- the engine against the dense per-request rollout -------------------------
 
 
-class TestRaggedLegacyByteIdentity:
+class TestEngineMatchesDenseReference:
 
   def test_greedy_dense_nospec_prefix_on_and_off(self, tiny_lm):
     """Greedy, no draft source — with a repeated-prompt stream so the
@@ -78,47 +80,43 @@ class TestRaggedLegacyByteIdentity:
     # the first copy retires (and inserts its pages) long before the
     # last admits, so the cache-on arm sees a real hit + CoW split
     for cache in (False, True):
-      outs = _BothModes(task, theta, reqs, prefix_cache=cache)
-      assert outs["ragged"][0] == outs["legacy"][0], f"prefix_cache={cache}"
+      eng = _ServeAgainstGreedyRef(task, theta, reqs, prefix_cache=cache)
       if cache:
-        for _, eng in outs.values():
-          assert eng.Stats()["prefix_cache"]["hit_tokens"] > 0
+        assert eng.Stats()["prefix_cache"]["hit_tokens"] > 0
 
-  def test_greedy_self_draft_ragged_matches_legacy(self, tiny_lm):
+  def test_greedy_self_draft(self, tiny_lm):
     task, theta = tiny_lm
-    reqs = _Stream(10, seed=12)
-    outs = _BothModes(
-        task, theta, reqs,
-        spec_fn=lambda: spec_decode.SelfDraft(k=3, num_layers=1))
-    assert outs["ragged"][0] == outs["legacy"][0]
-    for _, eng in outs.values():
-      assert eng.Stats()["spec_cycles"] > 0
-    # the unified step speculates WHILE neighbors prefill; legacy defers
-    # spec cycles to pure-decode steps — so ragged never cycles less
-    assert (outs["ragged"][1].Stats()["spec_cycles"]
-            >= outs["legacy"][1].Stats()["spec_cycles"])
+    eng = _ServeAgainstGreedyRef(
+        task, theta, _Stream(10, seed=12),
+        spec_decode.SelfDraft(k=3, num_layers=1))
+    assert eng.Stats()["spec_cycles"] > 0
 
   def test_greedy_model_draft_hybrid_target(self, hybrid_lm, ssm_draft_lm):
     """Hybrid-SSM target (trajectory restore on the real path) driven by
     an independent pageless draft model."""
     task, theta = hybrid_lm
     dtask, dtheta = ssm_draft_lm
-    reqs = _Stream(8, seed=13)
-    outs = _BothModes(
-        task, theta, reqs,
-        spec_fn=lambda: spec_decode.ModelDraft(dtask, dtheta, k=2))
-    assert outs["ragged"][0] == outs["legacy"][0]
-    assert outs["ragged"][1].Stats()["spec_cycles"] > 0
+    eng = _ServeAgainstGreedyRef(
+        task, theta, _Stream(8, seed=13),
+        spec_decode.ModelDraft(dtask, dtheta, k=2))
+    assert eng.Stats()["spec_cycles"] > 0
 
-  def test_temp_gt0_dense_nospec_byte_identical(self, tiny_lm):
+  def test_temp_gt0_dense_nospec_draws_the_request_stream(self, tiny_lm):
     """temperature > 0: every draw is keyed by (row seed, output
-    position), never by step index or slot — so the ragged packing must
-    reproduce the legacy stream bitwise, not just in distribution."""
+    position), never by step index or slot — so the packed step must
+    reproduce each request's own stream bitwise, not just in
+    distribution."""
     task, theta = tiny_lm
     reqs = _Stream(10, seed=14)
-    outs = _BothModes(task, theta, reqs, temperature=0.8, top_k=8,
-                      sample_seed=7)
-    assert outs["ragged"][0] == outs["legacy"][0]
+    sampling_kw = dict(temperature=0.8, top_k=8, sample_seed=7)
+    eng = _Engine(task, theta, **sampling_kw)
+    handles = [eng.Submit(p, m, eos_id=None, seed=40 + i)
+               for i, (p, m) in enumerate(reqs)]
+    while eng.sched.HasWork():
+      eng.StepOnce()
+    for i, ((prompt, max_new), h) in enumerate(zip(reqs, handles)):
+      assert h.Result(timeout=0) == _SampledRef(
+          task, theta, prompt, max_new, seed=40 + i, **sampling_kw), i
 
   @pytest.mark.slow
   def test_greedy_hybrid_nospec_and_repeat_stack_draft(self, hybrid_lm):
@@ -126,22 +124,18 @@ class TestRaggedLegacyByteIdentity:
     must not reset SSM states) and a RepeatedTransformerLayer target
     under early-exit self-speculation."""
     task, theta = hybrid_lm
-    reqs = _Stream(10, seed=15)
-    outs = _BothModes(task, theta, reqs)
-    assert outs["ragged"][0] == outs["legacy"][0]
+    _ServeAgainstGreedyRef(task, theta, _Stream(10, seed=15))
     rtask, rtheta = _Instantiate(
         _LmParams().Set(use_repeat_layer=True, num_layers=3))
-    reqs = _Stream(8, seed=16)
-    outs = _BothModes(
-        rtask, rtheta, reqs,
-        spec_fn=lambda: spec_decode.SelfDraft(k=3, num_layers=1))
-    assert outs["ragged"][0] == outs["legacy"][0]
+    _ServeAgainstGreedyRef(rtask, rtheta, _Stream(8, seed=16),
+                           spec_decode.SelfDraft(k=3, num_layers=1))
 
   @pytest.mark.slow
   def test_temp_gt0_spec_replays(self, tiny_lm):
     """temperature > 0 WITH a draft source is distribution-preserving,
-    not legacy-byte-identical (the verify coin at a position replaces
-    the plain draw there) — the contract is seeded replay determinism."""
+    not identical to the plain stream (the verify coin at a position
+    replaces the plain draw there) — the contract is seeded replay
+    determinism."""
     task, theta = tiny_lm
     reqs = _Stream(8, seed=17)
     runs = []
@@ -212,19 +206,6 @@ class TestStepProgramCensus:
     # a step, so the fill is queries over blocks x Bq and below one
     assert 0 < blocks <= queries <= blocks * bq
     assert blocks >= stats["steps"]
-
-  def test_legacy_trio_still_compiles_three(self, tiny_lm):
-    """The comparison baseline keeps its three shapes — the 3 -> 1
-    collapse is observable in the census, not just asserted in docs."""
-    task, theta = tiny_lm
-    eng = _Engine(task, theta, spec_decode.SelfDraft(k=3, num_layers=1),
-                  step_mode="legacy")
-    _RunStream(eng, _Stream(4, seed=18))
-    _RunStream(eng, [([5, 6], 3)], spec_k=0)   # opt-out -> plain decode
-    comp = eng.Stats()["compile"]
-    assert (set(comp) & observe_schema.STEP_PROGRAM_NAMES
-            == {"decode", "mixed", "spec_verify"})
-    assert comp[observe_schema.COMPILE_CENSUS_KEY] == 3
 
 
 # -- the stacked pool rides the scan over layers as a carry --------------------
@@ -426,9 +407,9 @@ class TestRepeatedStepCarriesThePool:
 # -- BuildRaggedStep / CommitRaggedStep (device-free) -------------------------
 
 
-def _MakeSched(slots=3, pages=24, page=4, table_pages=8, chunk=4, **kw):
+def _MakeSched(slots=3, pages=24, page=4, table_pages=8, **kw):
   alloc = kv_cache.PageAllocator(pages, page)
-  return scheduler_lib.Scheduler(slots, alloc, table_pages, chunk, **kw), alloc
+  return scheduler_lib.Scheduler(slots, alloc, table_pages, **kw), alloc
 
 
 def _Prefill(sched):
@@ -528,7 +509,7 @@ class TestPrefixOrderedAdmission:
     reused (ordered) or sit behind the head (FIFO)."""
     alloc = kv_cache.PageAllocator(6, 4)
     cache = prefix_cache_lib.PrefixCache(alloc, None)
-    sched = scheduler_lib.Scheduler(2, alloc, 4, 4, prefix_cache=cache)
+    sched = scheduler_lib.Scheduler(2, alloc, 4, prefix_cache=cache)
     if not ordered:
       sched._NextWaiting = lambda: 0     # strict FIFO baseline
     # prime: run one request to completion so its prompt's full pages
@@ -564,7 +545,7 @@ class TestPrefixOrderedAdmission:
     still gets its legacy try — reorder never starves the head."""
     alloc = kv_cache.PageAllocator(4, 4)
     cache = prefix_cache_lib.PrefixCache(alloc, None)
-    sched = scheduler_lib.Scheduler(1, alloc, 4, 4, prefix_cache=cache)
+    sched = scheduler_lib.Scheduler(1, alloc, 4, prefix_cache=cache)
     prime = list(range(1, 9))
     sched.Submit(scheduler_lib.Request("prime", prime, 1))
     _Prefill(sched)

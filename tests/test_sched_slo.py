@@ -21,6 +21,7 @@ Covers docs/multi_tenant_scheduling.md (ISSUE 20):
   queue-wait histograms, router class-aware load routing.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -136,7 +137,7 @@ def _MkSched(**kw):
   kw.setdefault("scheduler_mode", "priority")
   alloc = kw.pop("alloc", None) or kv_cache.PageAllocator(8, 4)
   return scheduler_lib.Scheduler(kw.pop("slots", 2), alloc,
-                                 table_pages=4, prefill_chunk=8, **kw), alloc
+                                 table_pages=4, **kw), alloc
 
 
 class TestPrioritySchedulerLifecycle:
@@ -246,7 +247,7 @@ class TestPrioritySchedulerLifecycle:
     st = sched.Stats()
     assert set(st) == observe_schema.SCHEDULER_STATS_KEYS
     assert st["scheduler_mode"] == "priority"
-    fifo = scheduler_lib.Scheduler(2, kv_cache.PageAllocator(8, 4), 4, 8)
+    fifo = scheduler_lib.Scheduler(2, kv_cache.PageAllocator(8, 4), 4)
     st = fifo.Stats()
     assert set(st) == observe_schema.SCHEDULER_STATS_KEYS
     assert st["scheduler_mode"] == "fifo" and st["preemptions"] == 0
@@ -453,18 +454,38 @@ class TestFleetPreemption:
                            scheduler_mode="priority")
     fl = fleet_lib.ServingFleet({"r0": mk(), "r1": mk()},
                                 policy="round_robin").Start()
+
+    def _Until(cond, what):
+      deadline = time.monotonic() + 60
+      while not cond():
+        if time.monotonic() > deadline:
+          raise TimeoutError(what)
+        time.sleep(0.005)
+
     try:
+      # r0 steps on permits until the high-priority request is in, so that
+      # it arrives while hb0 holds r0's one slot however loaded the host is
+      # (a free-running r0 can finish hb0's 12 tokens before this thread
+      # gets to submit hp, and then nothing is ever preempted)
+      r0 = fl.Engine("r0")
+      permits = threading.Semaphore(0)
+      free_step = r0.StepOnce
+
+      def _StepOnPermit():
+        permits.acquire()
+        return free_step()
+
+      r0.StepOnce = _StepOnPermit
       hb0 = fl.Submit([1, 2, 3, 4], 12)                    # -> r0
       hb1 = fl.Submit([5, 6, 7, 8], 12)                    # -> r1
+      for _ in range(3):
+        permits.release()
+      _Until(lambda: r0.Stats()["steps"] >= 3, "hb0 never started")
       hp = fl.Submit([9, 10, 11, 12], 12, priority=5)      # -> r0: preempts
-      r0 = fl.Engine("r0")
-      deadline = time.monotonic() + 60
-      while time.monotonic() < deadline:
-        if r0.Stats()["scheduler"]["preemptions"] >= 1:
-          break
-        time.sleep(0.005)
-      else:
-        raise TimeoutError("r0 never preempted")
+      r0.StepOnce = free_step
+      permits.release()      # the loop may be waiting for one
+      _Until(lambda: r0.Stats()["scheduler"]["preemptions"] >= 1,
+             "r0 never preempted")
       fl.KillReplica("r0")   # hb0 (or hp) may be PREEMPTED right now
       assert hb0.Result(timeout=120) == _GreedyRef(task, theta,
                                                    [1, 2, 3, 4], 12)

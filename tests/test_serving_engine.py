@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lingvo_tpu.core import sampling
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.ops import block_decode
 from lingvo_tpu.serving import engine as engine_lib
@@ -50,12 +51,10 @@ _REF_EXT = {}
 _REF_CACHE_LEN = 32
 
 
-def _GreedyRef(task, theta, prompt, max_new):
-  """Per-row dense greedy rollout (per-token ExtendStep argmax): the
-  batch-free reference every engine output must match token-for-token."""
-  key = (id(task), id(theta), tuple(int(t) for t in prompt), max_new)
-  if key in _REF_TOKENS:
-    return _REF_TOKENS[key]
+def _Rollout(task, theta, prompt, max_new, pick):
+  """Per-row dense rollout (per-token ExtendStep): shares no scheduler,
+  allocator, page pool or packed step with the engine it judges.
+  pick(logits [1, V], t) chooses output token t."""
   ext = _REF_EXT.get(id(task))
   if ext is None:
     ext = jax.jit(
@@ -67,12 +66,38 @@ def _GreedyRef(task, theta, prompt, max_new):
   for t in prompt:
     logits, states = ext(theta, jnp.asarray([[t]], jnp.int32), states)
   out = []
-  for _ in range(max_new):
-    nxt = int(np.argmax(np.asarray(logits[0])))
+  for t in range(max_new):
+    nxt = pick(logits, t)
     out.append(nxt)
     logits, states = ext(theta, jnp.asarray([[nxt]], jnp.int32), states)
-  _REF_TOKENS[key] = out
   return out
+
+
+def _GreedyRef(task, theta, prompt, max_new):
+  """The rollout's argmax stream: the batch-free reference every greedy
+  engine output must match token-for-token."""
+  key = (id(task), id(theta), tuple(int(t) for t in prompt), max_new)
+  if key not in _REF_TOKENS:
+    _REF_TOKENS[key] = _Rollout(
+        task, theta, prompt, max_new,
+        lambda logits, t: int(np.argmax(np.asarray(logits[0]))))
+  return _REF_TOKENS[key]
+
+
+def _SampledRef(task, theta, prompt, max_new, *, seed, sample_seed,
+                temperature, top_k=0):
+  """The rollout at temperature > 0: output token t of a request with seed
+  s is a pure function of (engine sample_seed, s, t) — the engine's
+  sampling contract, whichever slot, step or neighbors it decoded with."""
+  key = jax.random.PRNGKey(sample_seed)
+  seeds = jnp.asarray([seed], jnp.int32)
+
+  def _Draw(logits, t):
+    return int(sampling.SampleFromLogits(
+        logits, key, temperature=temperature, top_k=top_k, row_seeds=seeds,
+        positions=jnp.asarray([t], jnp.int32))[0])
+
+  return _Rollout(task, theta, prompt, max_new, _Draw)
 
 
 # -- kernel twins ------------------------------------------------------------
@@ -318,20 +343,25 @@ class TestPageAllocator:
 # -- scheduler lifecycle (device-free) ---------------------------------------
 
 
-def _MakeSched(slots=2, pages=8, page=4, table_pages=4, chunk=4):
+def _MakeSched(slots=2, pages=8, page=4, table_pages=4):
   alloc = kv_cache.PageAllocator(pages, page)
-  return scheduler_lib.Scheduler(slots, alloc, table_pages, chunk), alloc
+  return scheduler_lib.Scheduler(slots, alloc, table_pages), alloc
+
+
+# the packed axis of the device-free steps: 2 decode tokens + a prefill
+# budget of 4, the widest row 4
+_T, _WMAX = 6, 4
 
 
 def _Drive(sched, sampled_tok=7):
   """One admit → build → fabricated-sample → commit iteration."""
   sched.EvictCancelled()
   sched.Admit()
-  batch = sched.BuildStep()
+  batch = sched.BuildRaggedStep(_T, _WMAX)
   if batch is None:
     return None, []
-  sampled = np.full(batch.ids.shape, sampled_tok, np.int32)
-  return batch, sched.CommitStep(batch, sampled)
+  sampled = np.full((_T,), sampled_tok, np.int32)
+  return batch, sched.CommitRaggedStep(batch, sampled)
 
 
 class TestScheduler:
@@ -341,16 +371,17 @@ class TestScheduler:
     sched.Submit(scheduler_lib.Request("a", [1, 2, 3, 4, 5], 2))
     # step 1: mixed step consumes the first chunk (4 of 5 prompt tokens)
     batch, events = _Drive(sched)
-    assert batch.mixed and batch.ids.shape == (2, 4)
+    assert batch.mixed and list(batch.rows_desc.row_len) == [4, 0]
     assert batch.prompt_tokens == 4 and events == []
     # step 2: last prompt token -> first sampled token
     batch, events = _Drive(sched)
-    assert batch.in_len[0] == 1 and events == [("a", 7, False)]
+    assert batch.rows_desc.row_len[0] == 1 and events == [("a", 7, False)]
     assert sched._by_id["a"].state is scheduler_lib.SeqState.DECODE
-    # step 3: pure decode step (C == 1) hits max_new -> retire + free
+    # step 3: pure decode step (one token a row) hits max_new -> retire
     batch, events = _Drive(sched)
-    assert not batch.mixed and batch.ids.shape == (2, 1)
-    assert batch.ids[0, 0] == 7   # feeds back the last sampled token
+    assert not batch.mixed and list(batch.rows_desc.row_len) == [1, 0]
+    # feeds back the last sampled token
+    assert batch.tok_ids[batch.rows_desc.row_cols[0, 0]] == 7
     assert events == [("a", 7, True)]
     assert sched._by_id["a"].finish_reason == "length"
     assert alloc.num_free == alloc.num_pages

@@ -9,9 +9,9 @@ Covers docs/speculative_decoding.md:
 - `GatedSSMLayer.PagedStep(collect_col_states=True)` returns per-column
   states matching the chained single-token decode path (snapshot), and
   `_SelectAcceptedCols` restores the chosen column (restore),
-- scheduler `BuildVerifyStep` raggedness (opt-out rows ride with
-  in_len == 1, draft length clamped to the remaining token budget) and
-  `CommitVerifyStep` cursor rollback + eos retirement mid-prefix, with
+- scheduler `BuildRaggedStep(spec_k=...)` raggedness (opt-out rows ride
+  one token wide, draft length clamped to the remaining token budget) and
+  `CommitRaggedStep` cursor rollback + eos retirement mid-prefix, with
   `rolled_back_tokens` accounted on the page pool,
 - the engine bar: greedy spec output streams TOKEN-IDENTICAL to the
   non-speculative engine on a seeded 20-request mixed-length stream, for
@@ -20,7 +20,7 @@ Covers docs/speculative_decoding.md:
   rollback on the real path) and draft-state catch-up after long
   neighbor prefills,
 - acceptance telemetry: `draft_tokens` / `accepted_tokens` /
-  `accepted_len_hist` in engine Stats(), zero/empty on legacy engines,
+  `accepted_len_hist` in engine Stats(), zero/empty without a draft source,
 - (slow) residual speculative sampling preserves the per-position output
   law at temperature > 0.
 """
@@ -178,7 +178,7 @@ class TestSsmColStates:
                                   np.asarray(ns.col_states[2, 4]))
     np.testing.assert_array_equal(np.asarray(ns.col_states[2, 4]),
                                   np.asarray(states.state[2]))
-    # reference: C single-token PagedSteps (the legacy decode path). The
+    # reference: C single-token PagedSteps (the per-token decode path). The
     # projections batch over C in collect mode, so cross-path agreement is
     # float-tolerance, not bitwise — same bar the mixed prefill+decode
     # step already meets vs per-token decode
@@ -218,17 +218,26 @@ class TestSsmColStates:
 # -- scheduler verify-step lifecycle (device-free) ----------------------------
 
 
+# the packed axis of an engine with draft depth 4 over 2 slots and a
+# prefill budget of 4: 2 * (1 + 4) + 4 tokens, the widest row 1 + 4
+_SPEC_K, _T, _WMAX = 4, 14, 5
+
+
+def _BuildSpec(sched):
+  return sched.BuildRaggedStep(_T, _WMAX, spec_k=_SPEC_K)
+
+
 def _DecodingSched(reqs, slots=2):
   """Admits reqs and fast-forwards every row to DECODE with one token out."""
   alloc = kv_cache.PageAllocator(16, 4)
-  sched = scheduler_lib.Scheduler(slots, alloc, 4, 4)
+  sched = scheduler_lib.Scheduler(slots, alloc, 4)
   for r in reqs:
     sched.Submit(r)
   sched.Admit()
   while any(s is not None and s.state is scheduler_lib.SeqState.PREFILL
             for s in sched.slots):
-    batch = sched.BuildStep()
-    sched.CommitStep(batch, np.full(batch.ids.shape, 7, np.int32))
+    batch = sched.BuildRaggedStep(_T, _WMAX)
+    sched.CommitRaggedStep(batch, np.full((_T,), 7, np.int32))
   return sched, alloc
 
 
@@ -239,10 +248,14 @@ class TestVerifySchedulerLifecycle:
         scheduler_lib.Request("a", [1, 2, 3], 8),            # full k
         scheduler_lib.Request("b", [4, 5], 8, spec_k=0),     # opted out
     ])
-    vb = sched.BuildVerifyStep(k=4)
-    assert vb is not None and vb.ids.shape == (2, 5)
-    assert list(vb.row_k) == [4, 0] and list(vb.in_len) == [5, 1]
-    assert vb.ids[0, 0] == 7 and vb.ids[1, 0] == 7   # last emitted token
+    vb = _BuildSpec(sched)
+    assert vb.any_spec and not vb.mixed
+    assert list(vb.row_k) == [4, 0]
+    assert list(vb.rows_desc.row_len) == [5, 1]
+    assert list(vb.in_len) == [1, 0]        # only the drafting row drafts
+    cols0 = vb.rows_desc.row_cols[:, 0]
+    assert list(vb.tok_ids[cols0]) == [7, 7]   # last emitted token
+    assert list(vb.ids[:, 0]) == [7, 7]
     assert list(vb.q_pos) == [3, 2]
 
   def test_build_verify_clamps_to_remaining_budget(self):
@@ -250,55 +263,56 @@ class TestVerifySchedulerLifecycle:
     # written, so row_k must clamp to 1 (KV writes stay inside the pages
     # reserved at admission)
     sched, _ = _DecodingSched([scheduler_lib.Request("a", [1, 2], 2)])
-    vb = sched.BuildVerifyStep(k=4)
-    assert list(vb.row_k)[0] == 1 and list(vb.in_len)[0] == 2
+    vb = _BuildSpec(sched)
+    assert vb.row_k[0] == 1 and vb.rows_desc.row_len[0] == 2
 
-  def test_build_verify_none_during_prefill_or_all_optout(self):
+  def test_nothing_to_verify_during_prefill_or_all_optout(self):
     alloc = kv_cache.PageAllocator(16, 4)
-    sched = scheduler_lib.Scheduler(2, alloc, 4, 4)
+    sched = scheduler_lib.Scheduler(2, alloc, 4)
     sched.Submit(scheduler_lib.Request("a", [1, 2, 3, 4, 5, 6], 4))
     sched.Admit()
-    assert sched.BuildVerifyStep(k=4) is None   # still prefilling
+    vb = _BuildSpec(sched)                      # still prefilling
+    assert vb.mixed and not vb.any_spec and not vb.row_k.any()
     sched2, _ = _DecodingSched(
         [scheduler_lib.Request("b", [1], 8, spec_k=0)])
-    assert sched2.BuildVerifyStep(k=4) is None  # nobody speculates
+    vb = _BuildSpec(sched2)                     # nobody speculates
+    assert not vb.any_spec and not vb.row_k.any()
+    assert list(vb.rows_desc.row_len) == [1, 0] and not vb.in_len.any()
 
-  def test_commit_rolls_back_rejected_tail(self):
-    sched, alloc = _DecodingSched([scheduler_lib.Request("a", [1, 2], 8)])
+  @pytest.mark.parametrize(
+      "req_kw,max_new,out,alen,want,finish,rolled_back", [
+          # 2 accepted + 1 correction committed; 2 drafted tokens rolled back
+          ({}, 8, [11, 12, 13, 14, 15], 2,
+           [("a", 11, False), ("a", 12, False), ("a", 13, False)], None, 2),
+          # eos at the 2nd committed token: the stream truncates there, the
+          # row retires, its pages free, and the 3 unconsumed accepted
+          # tokens are rolled back on top of the 0 rejected ones
+          ({"eos_id": 12}, 8, [11, 12, 13, 14, 15], 4,
+           [("a", 11, False), ("a", 12, True)], "eos", 3),
+          # row_k clamps to 3 - 1 = 2; the correction is never emitted
+          ({}, 3, [11, 12, 13, 0, 0], 2,
+           [("a", 11, False), ("a", 12, True)], "length", 1),
+      ], ids=["rejected_tail", "eos_mid_prefix", "max_new_truncates"])
+  def test_commit_spec_row(self, req_kw, max_new, out, alen, want, finish,
+                           rolled_back):
+    sched, alloc = _DecodingSched(
+        [scheduler_lib.Request("a", [1, 2], max_new, **req_kw)])
     seq = sched._by_id["a"]
     pos0 = seq.pos
-    vb = sched.BuildVerifyStep(k=4)
-    out = np.array([[11, 12, 13, 14, 15]], np.int32)
-    events = sched.CommitVerifyStep(vb, out, np.array([2], np.int32))
-    # 2 accepted + 1 correction committed; 2 drafted tokens rolled back
-    assert events == [("a", 11, False), ("a", 12, False), ("a", 13, False)]
-    assert seq.pos == pos0 + 3 and seq.out[-3:] == [11, 12, 13]
-    assert alloc.rolled_back_tokens == 2
-    assert alloc.Stats()["rolled_back_tokens"] == 2
-
-  def test_commit_eos_mid_prefix_retires_and_rolls_back(self):
-    sched, alloc = _DecodingSched(
-        [scheduler_lib.Request("a", [1, 2], 8, eos_id=12)])
-    vb = sched.BuildVerifyStep(k=4)
-    out = np.array([[11, 12, 13, 14, 15]], np.int32)
-    events = sched.CommitVerifyStep(vb, out, np.array([4], np.int32))
-    # eos at the 2nd committed token: stream truncates there, the row
-    # retires, its pages free, and the 3 unconsumed accepted tokens are
-    # rolled back on top of the 0 rejected ones
-    assert events == [("a", 11, False), ("a", 12, True)]
-    assert sched._by_id["a"].finish_reason == "eos"
-    assert alloc.num_free == alloc.num_pages
-    assert alloc.rolled_back_tokens == 3
-
-  def test_commit_max_new_truncates_prefix(self):
-    sched, alloc = _DecodingSched([scheduler_lib.Request("a", [1, 2], 3)])
-    vb = sched.BuildVerifyStep(k=4)   # row_k clamps to 3 - 1 = 2
-    assert list(vb.row_k)[0] == 2
-    out = np.array([[11, 12, 13, 0, 0]], np.int32)
-    events = sched.CommitVerifyStep(vb, out, np.array([2], np.int32))
-    assert [e[1] for e in events] == [11, 12]
-    assert events[-1][2] and sched._by_id["a"].finish_reason == "length"
-    assert alloc.rolled_back_tokens == 1   # the never-emitted correction
+    vb = _BuildSpec(sched)
+    assert vb.row_k[0] == min(_SPEC_K, max_new - 1)
+    events = sched.CommitRaggedStep(
+        vb, np.zeros((_T,), np.int32), np.array([out], np.int32),
+        np.array([alen], np.int32))
+    assert events == want
+    tokens = [tok for _, tok, _ in want]
+    assert seq.pos == pos0 + len(tokens)
+    assert seq.out[-len(tokens):] == tokens
+    assert seq.finish_reason == finish
+    if finish is not None:
+      assert alloc.num_free == alloc.num_pages and sched.slots[0] is None
+    assert alloc.rolled_back_tokens == rolled_back
+    assert alloc.Stats()["rolled_back_tokens"] == rolled_back
 
 
 # -- the engine bar: token identity + telemetry -------------------------------
@@ -410,10 +424,10 @@ class TestSpecEngine:
   def test_stats_telemetry_surface(self, tiny_lm):
     from lingvo_tpu.observe import schema as observe_schema
     task, theta = tiny_lm
-    legacy = _Engine(task, theta)
-    stats = legacy.Stats()
+    plain = _Engine(task, theta)
+    stats = plain.Stats()
     observe_schema.ValidateEngineStats(stats)
-    # the keys exist on EVERY engine; legacy engines pin them at zero
+    # the keys exist on EVERY engine; without a draft source they are zero
     assert stats["spec_cycles"] == 0 and stats["draft_tokens"] == 0
     assert stats["accepted_tokens"] == 0
     assert stats["accepted_len_hist"] == [] and "spec" not in stats

@@ -267,7 +267,7 @@ class TestTreeResidualSamplingLaw:
 
 def _DecodingSched(reqs, slots=2, pages=24):
   alloc = kv_cache.PageAllocator(pages, 4)
-  sched = scheduler_lib.Scheduler(slots, alloc, 8, 4)
+  sched = scheduler_lib.Scheduler(slots, alloc, 8)
   for r in reqs:
     sched.Submit(r)
   sched.Admit()
