@@ -32,6 +32,10 @@ ENGINE_COUNTER_KEYS = (
     # query blocks the ragged attend kernel ran, and the valid queries in
     # them; their ratio over the block size is the block fill
     "attend_query_blocks", "attend_block_queries",
+    # the loop's pipeline: steps dispatched while the step before was still
+    # undelivered (`steps` less the pipeline's fills), and rows computed
+    # for a sequence that had ended by the time their tokens arrived
+    "steps_overlapped", "inflight_rows_dropped",
 )
 
 # Static engine configuration facts (set once at construction).

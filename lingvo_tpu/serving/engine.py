@@ -20,6 +20,21 @@ recompilation — the property that lets short requests overtake long
 ones instead of idling behind them (the batch-synchronous `GShardDecode`
 failure mode this engine replaces).
 
+Host-side the loop is a pipeline of depth two (`StepOnce`): while step n
+runs on the device the host admits, builds, places and dispatches step n+1,
+and only then fetches and commits step n. Step n+1 does not wait for the
+host to see step n's tokens: the scheduler moves the cursors when a step is
+dispatched (`Scheduler.AdvanceRaggedStep`: cursor, PREFILL -> DECODE, the
+output position, finish by length with the slot and its pages) and the
+tokens step n+1 feeds back are gathered from step n's draws on the device
+(`_FeedTokens`). Only what needs the values waits for the fetch
+(`Scheduler.CommitRaggedStep`: the stream, eos). A row that ends while
+the next step already carries it (eos, Cancel) has that step's result
+dropped; its extra K/V write lands in pages reserved for it at admission.
+A draft source reads the committed token on the host, so an engine with one
+runs the same loop at depth one: it retires a step before it builds the
+next.
+
 Speculative decoding (serving/spec_decode.py) configures a draft source
 (`spec=SelfDraft(...)` or `spec=ModelDraft(...)`): each iteration where
 at least one decode row speculates runs a draft pass proposing k tokens
@@ -63,6 +78,7 @@ Two front doors:
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -86,18 +102,25 @@ from lingvo_tpu.serving import spec_decode
 
 _END = object()   # stream sentinel
 
-# The spans of one engine step. Each is a jax.profiler.TraceAnnotation: with a
-# profiler trace running it lands on the host plane of the same .xplane.pb as
-# the device ops, on the profiler's clock; with none it is a flag test.
-#   TraceAnnotation lingvo/serve/step         one StepOnce that launched
+# The spans of one iteration of the loop. Each is a
+# jax.profiler.TraceAnnotation: with a profiler trace running it lands on the
+# host plane of the same .xplane.pb as the device ops, on the profiler's
+# clock; with none it is a flag test. An iteration dispatches step k and then
+# retires step k-1 (step k itself where a draft source keeps depth one), so
+# its first six spans belong to step k and its last three to step k-1.
+#   TraceAnnotation lingvo/serve/step         one StepOnce (its arguments:
+#                                             those of the step it launched)
 #   TraceAnnotation lingvo/serve/lock_wait    both takings of the engine lock
 #   TraceAnnotation lingvo/serve/admit        _AdmitPhase
-#   TraceAnnotation lingvo/serve/build        Build*Step, block-table copy
+#   TraceAnnotation lingvo/serve/build        BuildRaggedStep, block-table copy,
+#                                             AdvanceRaggedStep, step counters
 #   TraceAnnotation lingvo/serve/draft        the draft pass (spec engines)
 #   TraceAnnotation lingvo/serve/h2d          the jnp.asarray placements
-#   TraceAnnotation lingvo/serve/dispatch     _compile_log.Call returning
-#   TraceAnnotation lingvo/serve/device_wait  np.asarray(sampled, out, alen)
-#   TraceAnnotation lingvo/serve/commit       Commit*Step, counters, events
+#   TraceAnnotation lingvo/serve/dispatch     the feed gather and the step
+#                                             program: _compile_log.Call returning
+#   TraceAnnotation lingvo/serve/device_wait  np.asarray(sampled, out, alen) of
+#                                             the step being retired
+#   TraceAnnotation lingvo/serve/commit       CommitRaggedStep, counters, events
 _STEP_SPAN = "lingvo/serve/step"
 _SEGMENT_SPANS = {
     "lock_wait": "lingvo/serve/lock_wait", "admit": "lingvo/serve/admit",
@@ -153,8 +176,9 @@ class _StepSpans:
     return now
 
   def Abandon(self):
-    """An iteration that launched nothing (or raised): no record; its time
-    counts as the next step's `loop`."""
+    """An iteration that launched nothing (or raised): no record, though it
+    may have retired the last step in flight; its time counts as the next
+    step's `loop`."""
     if self._step_ann is not None:
       self._Close()
 
@@ -166,6 +190,16 @@ class _StepSpans:
     if self._recorder is not None:
       self._recorder.StepDone(step, self._t0, loop_s, self._acc,
                               valid_tokens, prefill_tokens, rows)
+
+
+def _FeedTokens(prev_sampled, tok_ids):
+  """The packed token stream a step receives, from the one the host wrote:
+  a negative entry -1 - j (scheduler.RaggedBatch) is replaced by draw j of
+  the previous step, which the host has not fetched yet. A program of its
+  own, tiny, so that the step program is handed plain token ids."""
+  src = jnp.clip(-1 - tok_ids, 0, prev_sampled.shape[0] - 1)
+  return jnp.where(tok_ids < 0, prev_sampled[src].astype(tok_ids.dtype),
+                   tok_ids)
 
 
 class StreamHandle:
@@ -408,6 +442,12 @@ class ServingLoop:
         and self.mixers["num_attention"] > 0):
       self._kv_leaf_axes = self._PagedLeafAxes(task, theta, kv_cache_dtype)
     self._ragged_fn = self._BuildRaggedFn(task, donate)
+    self._feed_fn = jax.jit(_FeedTokens)
+    # dispatched steps whose tokens are still on the device, oldest first:
+    # (batch, sampled) or, with a draft source, (batch, sampled, out, alen).
+    # Only the thread that drives StepOnce touches it; the scheduler counts
+    # them for HasWork's callers.
+    self._in_flight = collections.deque()
     self._zero_qlogits = None   # lazy [B, w*k, V] f32 (no-draft spec steps)
     # silent-fallback visibility: classify ONCE which attention path the
     # compiled step will take, and count ineligible (dense-fallback) steps
@@ -456,6 +496,7 @@ class ServingLoop:
     self._work = threading.Condition(self._lock)
     self._thread: Optional[threading.Thread] = None
     self._running = False
+    self._cancel_open = False   # Stop(drain=False) asked; _CancelOpen answers
     self._seq_counter = 0
     self._adopt_counter = 0   # transient page-handoff allocation owners
     # stall watchdog: StepOnce heartbeats + queue observations feed it;
@@ -1009,26 +1050,29 @@ class ServingLoop:
     return self
 
   def Stop(self, drain: bool = True, timeout: float = 60.0):
-    """drain=True finishes in-flight + queued work first."""
+    """drain=True finishes in-flight + queued work first. drain=False
+    cancels what is open, but not before the steps already dispatched have
+    been retired: a token the device computed reaches its client. The
+    loop's thread does both (_CancelOpen), between two iterations."""
     with self._lock:
       if not self._running:
         return
       if not drain:
-        for h in list(self._handles.values()):
-          if not h.done:
-            self.Cancel(h.id)   # RLock: reentrant under self._lock
+        self._cancel_open = True
+        if self._thread is None or not self._thread.is_alive():
+          self._CancelOpen()   # the loop died: nobody else will
       self._work.notify_all()
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
       with self._lock:
-        if not self.sched.HasWork():
+        if not self._cancel_open and not self.sched.HasWork():
           self._running = False
           self._work.notify_all()
           break
       time.sleep(0.005)
     else:
       with self._lock:
-        self._running = False
+        self._running = self._cancel_open = False
         self._work.notify_all()
     if self._thread is not None:
       self._thread.join(timeout=timeout)
@@ -1102,14 +1146,18 @@ class ServingLoop:
       with self._lock:
         if not self._running:
           return
-        if not self.sched.HasWork():
+        cancel_open = self._cancel_open
+        if not cancel_open and not self.sched.HasWork():
           self._work.wait(timeout=0.05)
           # no work is not a stall: refresh liveness so an idle replica
           # keeps answering /healthz 200 past the no_heartbeat window
           if self.watchdog is not None:
             self.watchdog.Idle()
           continue
-      self.StepOnce()
+      if cancel_open:
+        self._CancelOpen()
+      else:
+        self.StepOnce()
 
   # -- core step (shared by sync and async modes) ----------------------------
 
@@ -1143,12 +1191,26 @@ class ServingLoop:
       self._RunCow(admitted)
 
   def StepOnce(self) -> int:
-    """One admit → device step → commit iteration; returns #events.
+    """One iteration of the loop: admit, build and dispatch the next step,
+    then fetch and commit the oldest one in flight; returns #events.
 
-    Every iteration — any mix of prefill chunks, plain decode rows, and
+    Every step — any mix of prefill chunks, plain decode rows, and
     spec-verify rows — launches the ONE compiled packed-token program;
     with a draft source, rows that speculate get a draft pass first
-    while prefilling neighbors ride the same step."""
+    while prefilling neighbors ride the same step.
+
+    The loop is a pipeline: step n+1 is dispatched BEFORE step n's tokens
+    are fetched, so the device runs step n while the host builds and
+    places step n+1, and never waits out the host's turn. What step n+1
+    needs of step n it gets without the host: the cursors moved when step
+    n was dispatched (Scheduler.AdvanceRaggedStep), and the tokens it fed
+    back are gathered from step n's draws on the device (_FeedTokens). So
+    the `device_wait` and `commit` of an iteration belong to the step
+    before the one its `build`, `h2d` and `dispatch` belong to. A draft
+    source reads the committed token on the host, so an engine with one
+    leaves nothing in flight between iterations: the same loop at depth
+    one, which is the serial order. An iteration that finds nothing to
+    build retires what is in flight."""
     spans = self._spans
     spans.Begin()
     try:
@@ -1161,109 +1223,165 @@ class ServingLoop:
         spec_w = self.spec.w if self.spec is not None else 1
         batch = self.sched.BuildRaggedStep(self._ragged_t, self._ragged_wmax,
                                            spec_k=spec_k, spec_w=spec_w)
-        if batch is None:
-          return 0
-        tables = np.array(self.sched.block_tables)  # freeze under the lock
-        window = self._profile_window
-        if window is not None:
-          window.Start()
-      desc = batch.rows_desc
-      q_logits = None
-      if self.spec is not None:
-        spans.To("draft")
-        if batch.any_spec:
-          # draft outside the lock (device work); the batch's row-level
-          # view has in_len > 0 only on drafting rows, so prefill rows ride
-          # the step without activating the draft pass
-          d_toks, q_logits = self.spec.Draft(self._theta, self._states,
-                                             batch, tables)
-          # one dtype for both the drafted and the no-draft (zeros) case:
-          # the verify program must keep a single compiled signature
-          q_logits = q_logits.astype(jnp.float32)
-          # tree rows pack branch-major: branch bi's depth-d node sits at
-          # packed column 1 + bi*rk + d but draft index bi*spec_k + d —
-          # clamped rows (rk < spec_k) keep only each branch's prefix
-          for i in range(self.max_batch):
-            rk = int(batch.row_k[i])
-            if rk > 0:
-              for bi in range(int(batch.row_w[i])):
-                batch.tok_ids[desc.row_cols[i, 1 + bi * rk:1 + (bi + 1) * rk]
-                              ] = d_toks[i, bi * spec_k:bi * spec_k + rk]
-        else:
-          q_logits = self._ZeroQLogits()
-      spans.To("h2d")
-      rows_dev = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in desc))
-      args = [self._theta, self._states, jnp.asarray(batch.tok_ids),
-              rows_dev, jnp.asarray(tables), jnp.asarray(batch.row_seeds),
-              jnp.asarray(batch.row_pos)]
-      out = alen = None
-      if self.spec is not None:
-        args += [jnp.asarray(batch.row_k)]
-        if self.spec.w > 1:
-          args += [jnp.asarray(batch.row_w)]
-        args += [q_logits]
-        spans.To("dispatch")
-        sampled, out, alen, new_states = self._compile_log.Call(
-            "ragged", self._ragged_fn, *args)
-        spans.To("device_wait")
-        out, alen = np.asarray(out), np.asarray(alen)
-      else:
-        spans.To("dispatch")
-        sampled, new_states = self._compile_log.Call(
-            "ragged", self._ragged_fn, *args)
-        spans.To("device_wait")
-      self._states = new_states
-      sampled = np.asarray(sampled)
-      spans.To("lock_wait")
-      with self._lock:
-        spans.To("commit")
-        if self.trace is not None and batch.mixed:
-          # emit prefill-chunk spans BEFORE commit advances the cursors
-          for i, seq in enumerate(batch.rows):
-            n = int(desc.row_len[i])
-            if (seq is not None
-                and seq.state is scheduler_lib.SeqState.PREFILL and n > 0):
-              self.trace.PrefillChunk(seq.id, n)
-        events = self.sched.CommitRaggedStep(batch, sampled, out, alen)
-        self._counters["steps"].Inc()
-        self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
-        self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
-        if self._attend_bq:
-          row_len = np.asarray(desc.row_len, np.int64)
-          self._counters["attend_query_blocks"].Inc(
-              int(np.sum(-(-row_len // self._attend_bq))))
-          self._counters["attend_block_queries"].Inc(int(np.sum(row_len)))
-        if self.paged_path == "dense":
-          self._counters["dense_fallback_steps"].Inc()
-        if self._kv_quantized:
-          self._counters["quantized_steps"].Inc()
-        if batch.any_spec:
-          self._counters["spec_cycles"].Inc()
-          if batch.width_clamps:
-            self._counters["spec_width_clamps"].Inc(batch.width_clamps)
-          for i, seq in enumerate(batch.rows):
-            rk = int(batch.row_k[i])
-            if (seq is None or rk == 0
-                or seq.state is scheduler_lib.SeqState.CANCELLED):
-              continue
-            rw = int(batch.row_w[i])
-            m = min(int(alen[i]), rk)
-            self._counters["draft_tokens"].Inc(rw * rk)
-            self._counters["accepted_tokens"].Inc(m)
-            self._counters["spec_branches"].Inc(rw)
-            self.spec.accepted_len_hist[m] += 1
-            if self.trace is not None:
-              self.trace.SpecVerify(seq.id, rw * rk, m)
-              if rw * rk - m > 0:
-                self.trace.Rollback(seq.id, rw * rk - m)
-        self._PushEvents(events)
-        self._TickProfile()
-        self._BeatWatchdog()
-      spans.End(self._counters["steps"].value, int(desc.row_len.sum()),
-                batch.prompt_tokens, sum(r is not None for r in batch.rows))
+        if batch is not None:
+          tables = np.array(self.sched.block_tables)  # freeze under the lock
+          self._NoteDispatch(batch)
+      if batch is None:
+        # nothing to launch (no record): the pipeline drains
+        return len(self._RetireOldest()) if self._in_flight else 0
+      self._Dispatch(batch, tables)
+      # steps left in flight when the iteration ends: one, or none where a
+      # draft source has to see this step's tokens before the next build
+      keep = 0 if self.spec is not None else 1
+      events = self._RetireOldest() if len(self._in_flight) > keep else ()
+      spans.End(self._counters["steps"].value,
+                int(batch.rows_desc.row_len.sum()), batch.prompt_tokens,
+                sum(r is not None for r in batch.rows))
       return len(events)
     finally:
       spans.Abandon()   # a no-op after the step's End
+
+  def _NoteDispatch(self, batch):
+    """What is known of a step when it is built (caller holds the lock):
+    the scheduler's cursors pass it, and the counters that need no token
+    count it."""
+    desc = batch.rows_desc
+    if self.trace is not None and batch.mixed:
+      # emit prefill-chunk spans BEFORE the cursors advance
+      for i, seq in enumerate(batch.rows):
+        n = int(desc.row_len[i])
+        if (seq is not None
+            and seq.state is scheduler_lib.SeqState.PREFILL and n > 0):
+          self.trace.PrefillChunk(seq.id, n)
+    self.sched.AdvanceRaggedStep(batch)
+    self._counters["steps"].Inc()
+    if self._in_flight:
+      self._counters["steps_overlapped"].Inc()
+    self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
+    self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
+    if self._attend_bq:
+      row_len = np.asarray(desc.row_len, np.int64)
+      self._counters["attend_query_blocks"].Inc(
+          int(np.sum(-(-row_len // self._attend_bq))))
+      self._counters["attend_block_queries"].Inc(int(np.sum(row_len)))
+    if self.paged_path == "dense":
+      self._counters["dense_fallback_steps"].Inc()
+    if self._kv_quantized:
+      self._counters["quantized_steps"].Inc()
+    window = self._profile_window
+    if window is not None:
+      window.Start()
+
+  def _Dispatch(self, batch, tables):
+    """Places one built step's arguments and launches it; its results stay
+    on the device, at the tail of `_in_flight`."""
+    spans = self._spans
+    desc = batch.rows_desc
+    q_logits = None
+    if self.spec is not None:
+      spans.To("draft")
+      if batch.any_spec:
+        # draft outside the lock (device work); the batch's row-level
+        # view has in_len > 0 only on drafting rows, so prefill rows ride
+        # the step without activating the draft pass
+        d_toks, q_logits = self.spec.Draft(self._theta, self._states,
+                                           batch, tables)
+        # one dtype for both the drafted and the no-draft (zeros) case:
+        # the verify program must keep a single compiled signature
+        q_logits = q_logits.astype(jnp.float32)
+        # tree rows pack branch-major: branch bi's depth-d node sits at
+        # packed column 1 + bi*rk + d but draft index bi*spec_k + d —
+        # clamped rows (rk < spec_k) keep only each branch's prefix
+        spec_k = self.spec.k
+        for i in range(self.max_batch):
+          rk = int(batch.row_k[i])
+          if rk > 0:
+            for bi in range(int(batch.row_w[i])):
+              batch.tok_ids[desc.row_cols[i, 1 + bi * rk:1 + (bi + 1) * rk]
+                            ] = d_toks[i, bi * spec_k:bi * spec_k + rk]
+      else:
+        q_logits = self._ZeroQLogits()
+    spans.To("h2d")
+    rows_dev = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in desc))
+    tok_ids = jnp.asarray(batch.tok_ids)
+    args = [rows_dev, jnp.asarray(tables), jnp.asarray(batch.row_seeds),
+            jnp.asarray(batch.row_pos)]
+    if self.spec is not None:
+      args += [jnp.asarray(batch.row_k)]
+      if self.spec.w > 1:
+        args += [jnp.asarray(batch.row_w)]
+      args += [q_logits]
+    spans.To("dispatch")
+    if batch.feeds:
+      # the tokens this step feeds back that the host has not seen: they
+      # are draws of the step before, the only one that can be in flight
+      # while a step is built, and reach this step's tok_ids on the device
+      tok_ids = self._compile_log.Call(
+          "feed", self._feed_fn, self._in_flight[-1][1], tok_ids)
+    # (sampled, new_states), with (out, alen) between them under a draft source
+    *drawn, new_states = self._compile_log.Call(
+        "ragged", self._ragged_fn, self._theta, self._states, tok_ids, *args)
+    # the newest decode state, a future: whatever reads or rewrites it from
+    # here on (the next step, a CoW copy, a spill's gather, a restore's
+    # scatter, a prefix export) is run by the device behind this step
+    self._states = new_states
+    self._in_flight.append((batch, *drawn))
+
+  def _RetireOldest(self) -> list:
+    """Fetches the oldest dispatched step's tokens and commits them:
+    the half of the commit that needs the values. Returns the events."""
+    spans = self._spans
+    batch, *drawn = self._in_flight.popleft()
+    spans.To("device_wait")
+    drawn = [np.asarray(x) for x in drawn]   # blocks until the step is done
+    sampled, out, alen = drawn if len(drawn) == 3 else (drawn[0], None, None)
+    spans.To("lock_wait")
+    with self._lock:
+      spans.To("commit")
+      events = self.sched.CommitRaggedStep(batch, sampled, out, alen)
+      if batch.dropped:
+        self._counters["inflight_rows_dropped"].Inc(batch.dropped)
+      if batch.any_spec:
+        self._counters["spec_cycles"].Inc()
+        if batch.width_clamps:
+          self._counters["spec_width_clamps"].Inc(batch.width_clamps)
+        for i, seq in enumerate(batch.rows):
+          rk = int(batch.row_k[i])
+          if (seq is None or rk == 0
+              or seq.state is scheduler_lib.SeqState.CANCELLED):
+            continue
+          rw = int(batch.row_w[i])
+          m = min(int(alen[i]), rk)
+          self._counters["draft_tokens"].Inc(rw * rk)
+          self._counters["accepted_tokens"].Inc(m)
+          self._counters["spec_branches"].Inc(rw)
+          self.spec.accepted_len_hist[m] += 1
+          if self.trace is not None:
+            self.trace.SpecVerify(seq.id, rw * rk, m)
+            if rw * rk - m > 0:
+              self.trace.Rollback(seq.id, rw * rk - m)
+      self._PushEvents(events)
+      self._TickProfile()
+      self._BeatWatchdog()
+    return events
+
+  def _CancelOpen(self):
+    """Stop(drain=False)'s work, on the thread that drives the loop (which
+    alone may retire a step): FIRST the steps already dispatched are
+    retired — their tokens were computed, and the clients get them — and
+    only then is every request still open cancelled."""
+    spans = self._spans
+    while self._in_flight:
+      spans.Begin()
+      try:
+        self._RetireOldest()
+      finally:
+        spans.Abandon()
+    with self._lock:
+      for h in list(self._handles.values()):
+        if not h.done:
+          self.Cancel(h.id)   # RLock: reentrant under self._lock
+      self._cancel_open = False
 
   def _PushEvents(self, events):
     """Streams committed tokens to their handles (caller holds the lock)."""

@@ -23,13 +23,23 @@ iteration is admit → build → (device step) → commit:
   when the row speculates); prefilling rows then share what is left of
   the axis, so a step that carries prompt tokens is a MIXED step and
   decode is never stalled behind prefill.
-- `CommitRaggedStep` folds the device's sampled tokens back in: advances
-  prompt cursors, turns finished prefills into decoders (their first
-  generated token is the sample at the last prompt column), appends
-  decode tokens (a speculating row's accepted prefix plus its correction
-  token, rolling the cursor back over the rejected tail), retires
-  sequences on max_new/EOS, and frees their slot + pages immediately so
-  `Admit` can refill the slot on the very next iteration.
+- A step's commit is split along what the host knows when, so that the
+  engine can build step n+1 while step n's tokens are still on the device:
+  `AdvanceRaggedStep` (at dispatch, no token values) advances prompt and
+  decode cursors, turns finished prefills into decoders, counts the output
+  position each row's draw will fill (`Sequence.n_out`), and retires rows
+  that end BY LENGTH, freeing their slot + pages at once so `Admit` can
+  refill the slot on the very next iteration; `CommitRaggedStep` (with the
+  tokens) appends each draw to its sequence, finds EOS, and returns the
+  events to stream (a speculating row's accepted prefix plus its
+  correction token, rolling the cursor back over the rejected tail: such a
+  row's cursor follows the verify result, so all of its commit happens
+  here). A decode row built while its newest token is still undelivered
+  carries, in place of the token, the column of the previous step's draws
+  that holds it (`RaggedBatch.tok_ids`), and the engine resolves it on the
+  device. `CommitRaggedStep` on a batch that was never advanced does both
+  halves, which is the whole commit of a loop that keeps one step in
+  flight.
 
 SLO-aware scheduling (`scheduler_mode='priority'`, opt-in; 'fifo' is the
 bit-exact legacy default): requests carry a `priority` class and a
@@ -177,6 +187,13 @@ class Sequence:
     self.state = SeqState.QUEUED
     self.pos = 0          # tokens WRITTEN to the KV cache so far
     self.out = []         # generated tokens (out[-1] may not be cached yet)
+    # draws dispatched and not delivered yet: one per step in flight that
+    # this sequence draws a token in. A count, never a placeholder in `out`
+    # that a client could see. A sequence that ends early (eos, cancel)
+    # sets it to 0: the draws still in flight are void and will be dropped.
+    self.pending = 0
+    # where the newest such draw is: its column in its step's sampled [T]
+    self.src_col = -1
     self.finish_reason = None
     self.slot = None      # decode slot index, set at admission (telemetry)
     # committed tokens an independent draft model's recurrent state has
@@ -198,6 +215,12 @@ class Sequence:
   def prompt_remaining(self) -> int:
     return len(self.req.prompt) - self.pos
 
+  @property
+  def n_out(self) -> int:
+    """Output positions dispatched so far: what the sampling stream and
+    finish-by-length go by."""
+    return len(self.out) + self.pending
+
 
 class RaggedBatch:
   """One packed ragged device step (numpy; the engine jits over it).
@@ -209,7 +232,16 @@ class RaggedBatch:
   through the SAME compiled program. `rows_desc` is the core/ragged.RaggedRows routing
   pytree; `tok_ids` is the matching packed [T] token stream — draft
   columns hold 0 until the engine fills proposals: branch bi's depth-d
-  node at rows_desc.row_cols[i, 1 + bi * row_k[i] + d].
+  node at rows_desc.row_cols[i, 1 + bi * row_k[i] + d]. A NEGATIVE entry
+  -1 - j stands for a token the host does not have yet: the draw in column
+  j of the previous step's sampled [T], which the engine gathers into place
+  on the device (`feeds` counts such entries).
+
+  `out_col` ([B], filled by Scheduler.AdvanceRaggedStep): the sampled
+  column that holds row i's draw of this step; -1 where the row draws no
+  token (mid-prompt, no budget, or a speculating row, whose tokens are the
+  verify lane's). `dropped` (set by CommitRaggedStep): rows that were
+  computed for a sequence that had ended by the time the tokens arrived.
 
   The row-level view (ids / q_pos / in_len / rows / row_seeds / row_pos
   / row_k) is what spec_decode.SpecRunner.Draft reads: [B]-shaped, one
@@ -226,6 +258,10 @@ class RaggedBatch:
                row_k, any_spec: bool, ids0, row_w=None,
                width_clamps: int = 0):
     self.tok_ids = tok_ids        # [T] int32 packed token stream
+    self.feeds = int((tok_ids < 0).sum())   # tokens to gather on the device
+    self.advanced = False         # AdvanceRaggedStep ran on this batch
+    self.out_col = np.full((len(rows),), -1, np.int64)
+    self.dropped = 0
     self.rows_desc = rows_desc    # core/ragged.RaggedRows (numpy members)
     self.rows = rows              # slot -> Sequence or None, frozen at build
     self.mixed = mixed            # True if any prompt token rode this step
@@ -319,6 +355,9 @@ class Scheduler:
     self.quota_rejections = 0
     self._arrival = 0
     self._tenant_service: dict = {}   # tenant -> admitted token footprint
+    # steps advanced (dispatched) whose tokens CommitRaggedStep has not
+    # delivered yet: work, as far as HasWork's callers are concerned
+    self.steps_in_flight = 0
 
   # -- submission ------------------------------------------------------------
 
@@ -374,6 +413,7 @@ class Scheduler:
       return True
     seq.state = SeqState.CANCELLED   # slot/pages reclaimed by EvictCancelled
     seq.finish_reason = "cancelled"
+    seq.pending = 0                  # a draw still in flight is dropped
     return True
 
   # -- boundary phases -------------------------------------------------------
@@ -545,7 +585,7 @@ class Scheduler:
             and s.state in (SeqState.PREFILL, SeqState.DECODE)]
     if not live:
       return None
-    return min(live, key=lambda s: (s.req.priority, len(s.out), s.arrival))
+    return min(live, key=lambda s: (s.req.priority, s.n_out, s.arrival))
 
   def _Preempt(self, victim: Sequence):
     """Spills `victim` to the host tier and parks it PREEMPTED.
@@ -557,7 +597,13 @@ class Scheduler:
     resident and pinned, so the prefix cache's nodes stay valid. The
     O(1)-mixer state row rides along (state_spill_fn); the draft-model
     cursor resets so a restored row replays its committed stream into
-    whatever slot it lands in, exactly like a fresh admission."""
+    whatever slot it lands in, exactly like a fresh admission.
+
+    A victim whose newest step is still in flight has its cursor past
+    that step's K/V write: the engine's spill gather reads the newest
+    decode state, so the device runs it behind the step and the write is
+    in the bytes that leave. The draw itself reaches `out` when it
+    arrives (CommitRaggedStep delivers to a PREEMPTED sequence too)."""
     i = victim.slot
     logical_idxs, blocks = [], None
     if self.needs_kv_pages:
@@ -675,8 +721,10 @@ class Scheduler:
     return admitted
 
   def HasWork(self) -> bool:
+    """A live slot, a parked request, or a dispatched step whose tokens
+    are still to be delivered."""
     return (any(s is not None for s in self.slots) or bool(self.waiting)
-            or bool(self.preempted))
+            or bool(self.preempted) or self.steps_in_flight > 0)
 
   # -- the packed step --------------------------------------------------------
 
@@ -732,14 +780,14 @@ class Scheduler:
         continue
       row_q_pos[i] = seq.pos
       row_seeds[i] = seq.req.seed
-      row_pos[i] = len(seq.out)
+      row_pos[i] = seq.n_out
       if seq.state is not SeqState.DECODE:
         continue
       rk = 0
       rw = 1
       if spec_k > 0:
         rk = spec_k if seq.req.spec_k is None else min(seq.req.spec_k, spec_k)
-        rk = min(rk, seq.req.max_new - len(seq.out))
+        rk = min(rk, seq.req.max_new - seq.n_out)
         rk = max(rk, 0)
         if rk > 0 and spec_w > 1:
           rw = spec_w if seq.req.spec_w is None else min(seq.req.spec_w,
@@ -769,7 +817,8 @@ class Scheduler:
       row_k[i] = rk
       row_w[i] = rw
       any_spec = any_spec or rk > 0
-      ids0[i, 0] = seq.out[-1]
+      if not seq.pending:   # a draft source reads it: depth one, never
+        ids0[i, 0] = seq.out[-1]
       row_len[i] = 1 + rw * rk
       budget -= 1 + rw * rk
       if rw > 1:
@@ -801,7 +850,10 @@ class Scheduler:
       if seq.state is SeqState.PREFILL:
         tok_ids[cols] = seq.req.prompt[seq.pos:seq.pos + n]
       else:
-        tok_ids[cols[0]] = seq.out[-1]  # draft columns stay 0 until Draft
+        # draft columns stay 0 until Draft. A row whose newest draw is still
+        # on the device names its column there instead (RaggedBatch)
+        tok_ids[cols[0]] = (-1 - seq.src_col if seq.pending
+                            else seq.out[-1])
       if self.needs_kv_pages:
         # prefix sharing invariant: every slot this row writes (and, on
         # spec rollback, REWRITES) lives in pages CoW-private to it —
@@ -812,83 +864,136 @@ class Scheduler:
                        prompt_tokens, row_seeds, row_pos, row_k, any_spec,
                        ids0, row_w=row_w, width_clamps=width_clamps)
 
-  def _Finish(self, i: int, seq: Sequence, done_eos: bool):
-    """Retires slot i's sequence (CommitRaggedStep's epilogue)."""
-    self.slots[i] = None
-    self.alloc.Free(seq.id)
-    if self.state_pool is not None:
-      self.state_pool.Release(seq.id)
+  def _Finish(self, seq: Sequence, done_eos: bool):
+    """The sequence's last token is in `out`: counts it finished and gives
+    back whatever it still holds. A row that ended by length left its slot
+    when its last step was dispatched (AdvanceRaggedStep); one that meets
+    eos holds slot and pages until here; one that meets eos while parked
+    PREEMPTED (its draw was in flight when it was spilled) leaves the
+    parked queue and the host tier."""
+    if seq.state is SeqState.PREEMPTED:
+      self.preempted.remove(seq)
+      if self.host_store is not None:
+        self.host_store.Drop(seq.id)
+    elif seq.slot is not None and self.slots[seq.slot] is seq:
+      self.slots[seq.slot] = None
+    seq.pending = 0    # a later draw still in flight is void
     self.finished += 1
     self._Retire(seq, SeqState.FINISHED, "eos" if done_eos else "length")
 
-  def CommitRaggedStep(self, batch: RaggedBatch, sampled_tok: np.ndarray,
-                       out_tokens=None, accept_len=None) -> list:
-    """Folds one ragged step's device outputs back into the state machine.
+  def AdvanceRaggedStep(self, batch: RaggedBatch):
+    """The half of a step's commit that needs no token values, done when the
+    step is dispatched: every row's cursor passes the tokens the step
+    writes, a prefill that used up its prompt becomes a decoder (and its
+    full prompt pages go into the prefix cache), each row that draws a
+    token has the draw's column noted (`batch.out_col`, and `seq.src_col`
+    for the next step's build) and counted (`seq.pending`, so `seq.n_out`
+    is the next output position), and a row whose draw is its max_new-th
+    has ended BY LENGTH whatever the token turns out to be: it leaves its
+    slot here.
+    Speculating rows (row_k > 0) are left alone: their cursor follows the
+    verify result, so CommitRaggedStep moves it.
 
-    sampled_tok [T]: the program's per-token draws — token t's draw is a
-    pure function of (engine seed, row seed, row output position), so a
-    prefill row reads its LAST prompt token's column and a plain decode
-    row its only column. out_tokens [B, k+1] / accept_len [B]: the
-    verify lane, consumed only by rows with row_k > 0 (their column-0
-    entry is bitwise the plain draw, so routing rk == 0 rows through
-    sampled_tok is equivalent — and keeps the no-spec engine free of
-    verify outputs). Returns [(request_id, token, finished: bool)] events
-    in slot order, possibly several per speculating row."""
-    events = []
+    Giving a finished row's slot, pages and state row to the next
+    admission while its last step is still running is safe because the
+    device runs programs in dispatch order: whatever the next owner writes
+    there, or resets at its row_q_pos == 0, comes in a later program on
+    the same stream, behind this step's last read and write."""
+    assert not batch.advanced
+    batch.advanced = True
+    self.steps_in_flight += 1
     desc = batch.rows_desc
     for i, seq in enumerate(batch.rows):
-      if seq is None or seq.state is SeqState.CANCELLED:
-        continue   # cancelled mid-step: drop the tokens, evict at boundary
       n = int(desc.row_len[i])
+      if seq is None or n == 0:
+        continue
       if seq.state is SeqState.PREFILL:
-        if n == 0:
-          continue                       # out of token budget this step
         seq.pos += n
         if seq.prompt_remaining > 0:
           continue                       # more prompt tokens to go
-        tok = int(sampled_tok[desc.row_cols[i, n - 1]])
+        col = int(desc.row_cols[i, n - 1])
         seq.state = SeqState.DECODE
         if self.prefix_cache is not None and self.needs_kv_pages:
           n_full = len(seq.req.prompt) // self.alloc.page_size
           if n_full > 0:
             self.prefix_cache.Insert(
                 seq.req.prompt, self.alloc.PagesOf(seq.id)[:n_full])
-      elif seq.state is SeqState.DECODE:
-        rk = int(batch.row_k[i])
-        if rk > 0:
-          # spec-verify lane: accepted path + correction/bonus, cursor
-          # rollback over every other tree node (pure accounting —
-          # rejected slots are re-written next cycle, and reads never pass
-          # q_pos + row_len). The engine's in-program KV repair already
-          # moved the accepted path's K/V into the canonical chain slots,
-          # so advancing seq.pos by m + 1 lands on bit-correct cache state.
-          rw = int(batch.row_w[i])
-          m = min(int(accept_len[i]), rk)
-          self.alloc.NoteRollback(rw * rk - m)
-          committed = 0
-          for j in range(m + 1):
-            tok = int(out_tokens[i, j])
-            seq.pos += 1        # verify wrote this column's K/V already
-            seq.out.append(tok)
-            committed += 1
-            done_eos = (seq.req.eos_id is not None and tok == seq.req.eos_id)
-            if done_eos or len(seq.out) >= seq.req.max_new:
-              self._Finish(i, seq, done_eos)
-              events.append((seq.id, tok, True))
-              break
-            events.append((seq.id, tok, False))
-          if committed < m + 1:
-            # accepted tokens truncated by an early eos roll back too
-            self.alloc.NoteRollback(m + 1 - committed)
-          continue
-        seq.pos += 1                     # the fed-back token is now cached
-        tok = int(sampled_tok[desc.row_cols[i, 0]])
+      elif seq.state is SeqState.DECODE and batch.row_k[i] == 0:
+        seq.pos += 1                     # the fed-back token is cached
+        col = int(desc.row_cols[i, 0])
       else:
         continue
+      batch.out_col[i] = seq.src_col = col
+      seq.pending += 1
+      if seq.n_out >= seq.req.max_new:
+        self.slots[i] = None
+        self._Retire(seq, SeqState.FINISHED, "length")
+
+  def CommitRaggedStep(self, batch: RaggedBatch, sampled_tok: np.ndarray,
+                       out_tokens=None, accept_len=None) -> list:
+    """Folds one ragged step's device outputs back into the state machine
+    (after AdvanceRaggedStep, which it runs itself on a batch that has not
+    been advanced).
+
+    sampled_tok [T]: the program's per-token draws — token t's draw is a
+    pure function of (engine seed, row seed, row output position), so a
+    prefill row reads its LAST prompt token's column and a plain decode
+    row its only column (`batch.out_col`). out_tokens [B, k+1] /
+    accept_len [B]: the verify lane, consumed only by rows with row_k > 0
+    (their column-0 entry is bitwise the plain draw, so routing rk == 0
+    rows through sampled_tok is equivalent — and keeps the no-spec engine
+    free of verify outputs). A row whose sequence ended between dispatch
+    and here (cancelled, or eos in the step before) is dropped; one that
+    was PREEMPTED keeps its token, because its cursor and its spilled
+    pages already hold the step. Returns [(request_id, token, finished:
+    bool)] events in slot order, possibly several per speculating row."""
+    if not batch.advanced:
+      self.AdvanceRaggedStep(batch)
+    self.steps_in_flight -= 1
+    events = []
+    desc = batch.rows_desc
+    for i, seq in enumerate(batch.rows):
+      if seq is None or desc.row_len[i] == 0:
+        continue
+      rk = int(batch.row_k[i])
+      col = int(batch.out_col[i])
+      if seq.state is SeqState.CANCELLED or (col >= 0 and not seq.pending):
+        batch.dropped += 1   # ended mid-step: drop the tokens
+        continue
+      if rk > 0 and seq.state is SeqState.DECODE:
+        # spec-verify lane: accepted path + correction/bonus, cursor
+        # rollback over every other tree node (pure accounting —
+        # rejected slots are re-written next cycle, and reads never pass
+        # q_pos + row_len). The engine's in-program KV repair already
+        # moved the accepted path's K/V into the canonical chain slots,
+        # so advancing seq.pos by m + 1 lands on bit-correct cache state.
+        rw = int(batch.row_w[i])
+        m = min(int(accept_len[i]), rk)
+        self.alloc.NoteRollback(rw * rk - m)
+        committed = 0
+        for j in range(m + 1):
+          tok = int(out_tokens[i, j])
+          seq.pos += 1        # verify wrote this column's K/V already
+          seq.out.append(tok)
+          committed += 1
+          done_eos = (seq.req.eos_id is not None and tok == seq.req.eos_id)
+          if done_eos or len(seq.out) >= seq.req.max_new:
+            self._Finish(seq, done_eos)
+            events.append((seq.id, tok, True))
+            break
+          events.append((seq.id, tok, False))
+        if committed < m + 1:
+          # accepted tokens truncated by an early eos roll back too
+          self.alloc.NoteRollback(m + 1 - committed)
+        continue
+      if col < 0:
+        continue                         # mid-prompt: no draw to deliver
+      tok = int(sampled_tok[col])
+      seq.pending -= 1
       seq.out.append(tok)
       done_eos = (seq.req.eos_id is not None and tok == seq.req.eos_id)
       if done_eos or len(seq.out) >= seq.req.max_new:
-        self._Finish(i, seq, done_eos)
+        self._Finish(seq, done_eos)
         events.append((seq.id, tok, True))
       else:
         events.append((seq.id, tok, False))

@@ -142,9 +142,18 @@ class TestEngineStepRecords:
       assert st.loop_s > 0 and st.start_ts >= prev.end_ts
 
   def test_every_phase_but_draft_took_time(self, stepped_engine):
-    for st in stepped_engine.trace.Steps():
+    # the loop keeps one step in flight: an iteration's device_wait and
+    # commit are the step before's, so the iteration that fills the
+    # pipeline (each RunBatch's first) has neither, nor the lock before them
+    steps = stepped_engine.trace.Steps()
+    fills = [st for st in steps if st.Phases()["device_wait"] == 0.0]
+    assert len(fills) == 2 and fills[0] is steps[0]
+    for st in steps:
       ph = st.Phases()
       assert ph.pop("draft") == 0.0
+      if st in fills:
+        assert ph.pop("commit") == 0.0 and ph.pop("device_wait") == 0.0
+        assert st.segments_s[7] == 0.0      # the second lock_wait
       assert all(v > 0 for v in ph.values()), ph
 
   def test_tokens_and_rows_of_a_step(self, stepped_engine):
@@ -333,7 +342,10 @@ class TestSpansInAProfilerTrace:
 
   def test_step_span_carries_its_arguments(self, profiled):
     spans, first_step, _ = profiled
-    stats = [s for _, s, _, _ in spans["lingvo/serve/step"]]
+    # an iteration that launched nothing (here the one that retires the
+    # last step in flight) has a step span with no arguments
+    stats = [s for _, s, _, _ in spans["lingvo/serve/step"] if "step" in s]
+    assert len(stats) == len(spans["lingvo/serve/step"]) - 1
     assert [s["step"] for s in stats][:3] == [
         first_step, first_step + 1, first_step + 2]
     for s in stats:
