@@ -132,6 +132,18 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     p.Define("hidden_dim", 0, "Total attention hidden dim (N*H).")
     p.Define("num_heads", 1, "Number of heads.")
     p.Define("dim_per_head", 0, "Per-head dim (0 = hidden/num_heads).")
+    p.Define("num_kv_heads", 0,
+             "Key/value heads (0 = num_heads). Divides num_heads: query head "
+             "n reads KV head n // (num_heads // num_kv_heads). The caches "
+             "and the page pool hold the KV heads only.")
+    p.Define("window", 0,
+             "If >0, causal sliding window (left_context semantics: query i "
+             "sees keys j with i - window < j <= i). FProp masks; RaggedStep "
+             "also starts each query block at the first page its window "
+             "reaches, and the serving engine lets go of the pages behind "
+             "it (serving/kv_cache.KindPages).")
+    p.Define("rope_max_timescale", 1e4,
+             "RoPE base (theta) where use_rotary_position_emb.")
     p.Define("use_bias", True, "Bias on projections.")
     p.Define("enable_per_dim_scale", True,
              "Learned per-dim query scale instead of 1/sqrt(H).")
@@ -180,16 +192,21 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     hidden = p.hidden_dim or p.input_dim
     self._dim_per_head = p.dim_per_head or hidden // p.num_heads
     n, h, d = p.num_heads, self._dim_per_head, p.input_dim
+    self._num_kv_heads = p.num_kv_heads or n
+    assert n % self._num_kv_heads == 0, (n, self._num_kv_heads)
+    assert p.window >= 0, p.window
     sd = p.source_dim or d
     wsdm = p.weight_split_dims_mapping  # e.g. (None, 'model', None)
-    for name, in_dim in (("query", d), ("key", sd), ("value", sd)):
+    for name, in_dim, heads in (("query", d, n),
+                                ("key", sd, self._num_kv_heads),
+                                ("value", sd, self._num_kv_heads)):
       self.CreateVariable(
           f"w_{name}",
-          WeightParams((in_dim, n, h), p.params_init, p.dtype,
+          WeightParams((in_dim, heads, h), p.params_init, p.dtype,
                        tensor_split_dims_mapping=wsdm))
       if p.use_bias:
         self.CreateVariable(
-            f"b_{name}", WeightParams((n, h), WeightInit.Constant(0.0),
+            f"b_{name}", WeightParams((heads, h), WeightInit.Constant(0.0),
                                       p.dtype))
     self.CreateVariable(
         "w_post",
@@ -205,7 +222,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       self.CreateChild(
           "rotary",
           layers_lib.RotaryPositionalEmbeddingLayer.Params().Set(
-              embedding_dim=h))
+              embedding_dim=h, max_timescale=p.rope_max_timescale))
     if p.rel_pos_emb_dim > 0:
       self.CreateVariable(
           "rel_pos_bias",
@@ -269,6 +286,24 @@ class MultiHeadedAttention(base_layer.BaseLayer):
           self.ChildTheta(theta, "per_dim_scale"), q)
     return q * (1.0 / math.sqrt(self._dim_per_head))
 
+  @property
+  def kv_group(self) -> int:
+    """Query heads a KV head serves (1: plain multi-head attention)."""
+    return self.p.num_heads // self._num_kv_heads
+
+  def _RepeatKv(self, x):
+    """[B, S, Nkv, H] -> [B, S, N, H] for the dense einsum paths."""
+    return x if self.kv_group == 1 else jnp.repeat(x, self.kv_group, axis=2)
+
+  def _RequirePlainKv(self, method: str):
+    """The dense decode paths keep plain MHA caches and no window."""
+    if self.kv_group > 1 or self.p.window > 0:
+      raise NotImplementedError(
+          f"{method} serves plain multi-head attention without a window; "
+          f"a layer with num_kv_heads={self._num_kv_heads} of "
+          f"{self.p.num_heads} heads or window={self.p.window} is served "
+          "by RaggedStep (ServingLoop) and trained by FProp")
+
   def _RelPosBias(self, theta, t: int, s: int):
     p = self.p
     th = self.CastTheta(theta)
@@ -313,6 +348,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
             and atten_mask is None and
             p.rel_pos_emb_dim == 0 and p.atten_logit_cap == 0 and
             p.atten_dropout_prob == 0 and p.qdomain_softmax is None and
+            p.window == 0 and self.kv_group == 1 and
             t % 16 == 0):
       return False
     if jax.default_backend() == "tpu":
@@ -366,13 +402,20 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if causal:
       cm = CausalMask(query_vec.shape[1])
       mask = cm if mask is None else mask + cm
+    if self.p.window > 0:
+      t, s_len = query_vec.shape[1], key_vec.shape[1]
+      behind = (jnp.arange(t)[:, None] - jnp.arange(s_len)[None, :]
+                >= self.p.window)
+      wm = jnp.where(behind, _NEG_INF, 0.0)[None, None]
+      mask = wm if mask is None else mask + wm
     if paddings is not None:
       pm = PaddingsToMask(paddings)
       mask = pm if mask is None else mask + pm
     if segment_ids is not None:
       sm = SegmentMask(segment_ids, segment_ids)
       mask = sm if mask is None else mask + sm
-    ctx, probs = self._Atten(theta, q, k, v, mask)
+    ctx, probs = self._Atten(theta, q, self._RepeatKv(k), self._RepeatKv(v),
+                             mask)
     return self._PostProj(theta, ctx), probs
 
   # -- chunk streaming (ref conformer streaming / stream_step_test_base) -----
@@ -381,6 +424,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     """Sliding-window streaming state: the last left_context-1 source frames'
     K/V (cached PRE-rotary — rotary attention depends only on relative
     position, so each chunk re-rotates with local positions) + paddings."""
+    self._RequirePlainKv("InitStreamStates")
     n, h = self.p.num_heads, self._dim_per_head
     ctx = max(left_context - 1, 0)
     dtype = self.fprop_dtype
@@ -455,11 +499,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
 
   def KvBytesPerToken(self, kv_cache_dtype=None) -> int:
     """K + V bytes per cached token in this layer, scale sidecars included."""
-    return kv_quant.KvBytesPerToken(self.p.num_heads, self._dim_per_head,
+    return kv_quant.KvBytesPerToken(self._num_kv_heads, self._dim_per_head,
                                     kv_cache_dtype or self.p.kv_cache_dtype,
                                     self.fprop_dtype)
 
   def InitStates(self, theta, batch_size: int, max_len: int) -> NestedMap:
+    self._RequirePlainKv("InitStates (ExtendStep, Prefill)")
     n, h = self.p.num_heads, self._dim_per_head
     dtype, quantized = self._KvDtype()
     states = NestedMap(
@@ -623,8 +668,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     f32 scale sidecars (transposed so the Pallas scale block's minor dim
     is page_size — see lingvo_tpu/quant/kv.py)."""
     del theta, num_slots
-    n, h = self.p.num_heads, self._dim_per_head
+    n, h = self._num_kv_heads, self._dim_per_head
     dtype, quantized = self._KvDtype(kv_cache_dtype)
+    if quantized and self.kv_group > 1:
+      raise NotImplementedError(
+          f"int8 KV pages under num_kv_heads={n} of {self.p.num_heads} "
+          "heads: the grouped ragged kernel reads float pages")
     states = NestedMap(
         key=jnp.zeros((num_pages, page_size, n, h), dtype),
         value=jnp.zeros((num_pages, page_size, n, h), dtype))
@@ -639,8 +688,20 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     what the engine's block-fill counters divide by."""
     from lingvo_tpu.ops import ragged_block_attend
     return ragged_block_attend.QueryBlock(
-        self.p.num_heads, self._dim_per_head, page_size, self.fprop_dtype,
-        self._KvDtype(kv_cache_dtype)[0])
+        self._num_kv_heads, self._dim_per_head, page_size, self.fprop_dtype,
+        self._KvDtype(kv_cache_dtype)[0],
+        grouped=ragged_block_attend.Grouped(self.p.num_heads,
+                                            self._num_kv_heads))
+
+  def RaggedQueriesPerToken(self) -> tuple[int, int]:
+    """(queries of a token the ragged kernel lays on the packed axis, those
+    of them that are the token's own): (1, 1) for plain multi-head
+    attention; a KV head's group where it serves several query heads,
+    padded to whole sublane tiles (the grouped kernel)."""
+    from lingvo_tpu.ops import ragged_block_attend
+    if self.kv_group == 1:
+      return 1, 1
+    return ragged_block_attend.GroupLanes(self.kv_group), self.kv_group
 
   def BlockDecodeEligible(self, page_size: int) -> bool:
     """Same gate family as PagedDecodeEligible, for the block-table kernel:
@@ -688,6 +749,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     only on relative position, so numerics match the right-aligned dense
     path (asserted by the engine parity tests).
     """
+    self._RequirePlainKv("PagedStep")
     from lingvo_tpu.ops import block_decode
     p = self.p
     assert p.rel_pos_emb_dim <= 0, (
@@ -783,6 +845,9 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     op twins (ops/ragged_block_attend.py) carry the bitwise proof at the
     op level.
 
+    block_tables: [B, t_pages]. A window layer's table may hold stale
+    entries behind a row's window, which no query block reaches.
+
     layer: None when the pool is this layer's alone ([NP, P, N, H]); a
     scalar index when every leaf arrives stacked over a repeat axis
     ([L, NP, ...], RepeatedTransformerLayer's scan carry). The stack is
@@ -863,8 +928,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         ctx = ragged_block_attend.RaggedAttend(
             q[0], k_pool, v_pool, tables, row, q_end,
             page_size=page_size, k_scale=k_scale, v_scale=v_scale,
-            q_start=q_start, anc_lo=rows.anc_lo, anc_hi=rows.anc_hi)[None]
+            q_start=q_start, anc_lo=rows.anc_lo, anc_hi=rows.anc_hi,
+            window=p.window)[None]
     else:
+      self._RequirePlainKv("RaggedStep's gather-dense fallback")
       # gather-dense fallback at token granularity: each token is a batch
       # row of one query over its row's materialized cache view (handles
       # logit cap / dropout / prob quant exactly like PagedStep's)

@@ -250,16 +250,38 @@ class TransformerLayer(base_layer.BaseLayer):
       aux_p = (p.tr_aux_atten_tpl or p.tr_atten_tpl).Copy().Set(
           input_dim=p.input_dim, num_heads=p.num_heads, is_masked=False)
       self.CreateChild("aux_atten", aux_p)
-    self.CreateChild(
-        "fflayer",
-        p.tr_fflayer_tpl.Copy().Set(
-            input_dim=p.input_dim,
-            hidden_dim=p.hidden_dim or 4 * p.input_dim))
+    ff_p = p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim)
+    if not ff_p.hidden_dim or p.hidden_dim:
+      # an expert layer states its own width (core/moe.py) and the layer
+      # has no dense feed-forward beside it
+      ff_p.hidden_dim = p.hidden_dim or 4 * p.input_dim
+    self.CreateChild("fflayer", ff_p)
+
+  def _RouterLogits(self, theta, inputs):
+    """Where the feed-forward is an expert layer (core/moe.py): its router
+    reads the layer's INPUT, so its logits are taken here, before the
+    attention block, and ride past it as a keyword of the feed-forward's
+    call. Nothing otherwise."""
+    if hasattr(self.fflayer, "RouterLogits"):
+      with jax.named_scope("ffn"), jax.named_scope("moe_route"):
+        return {"router_logits": self.fflayer.RouterLogits(theta.fflayer,
+                                                           inputs)}
+    return {}
+
+  def StackAddressed(self) -> set:
+    """Paths (tuples of keys into this layer's theta) of the variables a
+    scan over layers hands the layer whole beside its index instead of
+    slicing them: what the feed-forward says of itself
+    (core/moe.DroplessMoELayer.StackAddressed)."""
+    if not hasattr(self.fflayer, "StackAddressed"):
+      return set()
+    return {("fflayer", name) for name in self.fflayer.StackAddressed()}
 
   def FProp(self, theta, inputs, paddings=None, aux_vecs=None,
             aux_paddings=None, atten_mask=None, segment_ids=None,
             token_ids=None):
     del token_ids  # only MoE layers with hash gating consume ids
+    routed = self._RouterLogits(theta, inputs)
     x, _ = self.self_atten.FProp(
         theta.self_atten, inputs, paddings=paddings, atten_mask=atten_mask,
         segment_ids=segment_ids)
@@ -272,7 +294,7 @@ class TransformerLayer(base_layer.BaseLayer):
       coll = py_utils.NamedCollectionTop("cross_atten_probs")
       if coll is not None and aux_probs is not None:
         coll[self.path] = aux_probs
-    return self.fflayer.FProp(theta.fflayer, x, paddings)
+    return self.fflayer.FProp(theta.fflayer, x, paddings, **routed)
 
   def InitStates(self, theta, batch_size, max_len):
     return NestedMap(
@@ -291,38 +313,51 @@ class TransformerLayer(base_layer.BaseLayer):
 
   def _Step(self, method, theta, inputs, cached_states, aux_vecs,
             aux_paddings, cache_paddings, **kw):
+    routed = self._RouterLogits(theta, inputs)
     x, new_sa = getattr(self.self_atten, method)(
         theta.self_atten, inputs, cached_states.self_atten,
         cache_paddings=cache_paddings, **kw)
     if self.p.has_aux_atten:
       x, _ = self.aux_atten.FProp(
           theta.aux_atten, x, source_vecs=aux_vecs, paddings=aux_paddings)
-    out = self.fflayer.FProp(theta.fflayer, x)
+    out = self.fflayer.FProp(theta.fflayer, x, **routed)
     return out, NestedMap(self_atten=new_sa)
 
   def InitPagedStates(self, theta, num_pages, page_size, num_slots=0,
                       kv_cache_dtype=None):
     assert not self.p.has_aux_atten, (
         "continuous-batching serving is decoder-only (no cross-attention)")
-    return NestedMap(self_atten=self.self_atten.InitPagedStates(
+    states = NestedMap(self_atten=self.self_atten.InitPagedStates(
         theta.self_atten, num_pages, page_size, num_slots=num_slots,
         kv_cache_dtype=kv_cache_dtype))
+    if hasattr(self.fflayer, "InitPagedStates"):
+      # an expert layer's tokens by expert of the newest step
+      states.fflayer = self.fflayer.InitPagedStates(theta.fflayer)
+    return states
 
   def PagedStep(self, theta, inputs, cached_states, block_tables, q_pos,
                 in_len, ssm_col_states: bool = False):
+    routed = self._RouterLogits(theta, inputs)
     x, new_sa = self.self_atten.PagedStep(
         theta.self_atten, inputs, cached_states.self_atten, block_tables,
         q_pos, in_len, ssm_col_states=ssm_col_states)
-    out = self.fflayer.FProp(theta.fflayer, x)
-    return out, NestedMap(self_atten=new_sa)
+    out = self.fflayer.FProp(theta.fflayer, x, **routed)
+    new_states = cached_states.Copy()
+    new_states.self_atten = new_sa
+    return out, new_states
 
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
                  ssm_col_states: bool = False, layer=None):
+    routed = self._RouterLogits(theta, inputs)
     x, new_sa = self.self_atten.RaggedStep(
         theta.self_atten, inputs, cached_states.self_atten, block_tables,
         rows, ssm_col_states=ssm_col_states, layer=layer)
-    out = self.fflayer.FProp(theta.fflayer, x)
-    return out, NestedMap(self_atten=new_sa)
+    if "fflayer" not in cached_states:
+      out = self.fflayer.FProp(theta.fflayer, x, **routed)
+      return out, NestedMap(self_atten=new_sa)
+    out, new_ff = self.fflayer.RaggedStep(
+        theta.fflayer, x, cached_states.fflayer, rows, layer=layer, **routed)
+    return out, NestedMap(self_atten=new_sa, fflayer=new_ff)
 
 
 class StackedTransformerLayers(base_layer.BaseLayer):
@@ -378,6 +413,12 @@ class StackedTransformerLayers(base_layer.BaseLayer):
       x = self.final_ln.FProp(theta.final_ln, x)
     return x
 
+  def StackAddressed(self) -> set:
+    """As TransformerLayer.StackAddressed, over this block's layers."""
+    return {("x_layers", str(i)) + path
+            for i, l in enumerate(self.x_layers)
+            if hasattr(l, "StackAddressed") for path in l.StackAddressed()}
+
   def InitStates(self, theta, batch_size, max_len):
     return NestedMap(x_layers=[
         l.InitStates(theta.x_layers[i], batch_size, max_len)
@@ -408,13 +449,41 @@ class StackedTransformerLayers(base_layer.BaseLayer):
       x = self.final_ln.FProp(theta.final_ln, x)
     return x, new_states
 
+  def PageWindows(self):
+    """None, or where this block's layers are attention layers of two
+    kinds, full and sliding-window: each layer's window (0 = full). Such a
+    block keeps ONE pool of uniform pages for all its layers (`kv_pool` in
+    its paged states) and reads a block table a layer, `[layers, B,
+    t_pages]`: a page holds page_size tokens of one layer, so neither kind
+    has a share of the pool fixed for it (serving/kv_cache.KindPages)."""
+    mixers = [getattr(getattr(l, "self_atten", None), "atten", None)
+              for l in self.x_layers]
+    windows = [int(getattr(getattr(m, "p", None), "window", 0) or 0)
+               for m in mixers]
+    if not any(windows) or all(windows):
+      return None
+    assert all(hasattr(m, "RaggedStep") and not hasattr(
+        m, "StateBytesPerSlot") for m in mixers), (
+            "layers of two kinds share a page pool among attention layers "
+            "only")
+    return windows
+
   def InitPagedStates(self, theta, num_pages, page_size, num_slots=0,
                       kv_cache_dtype=None):
-    return NestedMap(x_layers=[
+    states = NestedMap(x_layers=[
         l.InitPagedStates(theta.x_layers[i], num_pages, page_size,
                           num_slots=num_slots, kv_cache_dtype=kv_cache_dtype)
         for i, l in enumerate(self.x_layers)
     ])
+    if self.PageWindows() is not None:
+      # one pool for every layer of the block: the first layer's, and no
+      # layer keeps one of its own
+      states.kv_pool = states.x_layers[0].self_atten
+      for layer_states in states.x_layers:
+        assert jax.tree_util.tree_map(jnp.shape, layer_states.self_atten) == (
+            jax.tree_util.tree_map(jnp.shape, states.kv_pool))
+        layer_states.self_atten = NestedMap()
+    return states
 
   def PagedStep(self, theta, inputs, cached_states, block_tables, q_pos,
                 in_len, ssm_col_states: bool = False):
@@ -436,17 +505,27 @@ class StackedTransformerLayers(base_layer.BaseLayer):
                  ssm_col_states: bool = False, layer=None):
     """layer: None here (distinct layers, each with a pool of its own); an
     index when this stack is the body of a RepeatedTransformerLayer, whose
-    stacked states every x_layer then addresses by it."""
+    stacked states every x_layer then addresses by it. A block of two
+    kinds of layer (PageWindows) hands its one pool from layer to layer
+    and each layer its own table of `block_tables` [layers, B, t_pages]."""
     kw = {"ssm_col_states": True} if ssm_col_states else {}
     if layer is not None:
       kw["layer"] = layer
     x = inputs
     new_states = NestedMap(x_layers=[])
+    pool = cached_states.get("kv_pool")
     for i, x_layer in enumerate(self.x_layers):
-      x, ns = x_layer.RaggedStep(theta.x_layers[i], x,
-                                 cached_states.x_layers[i], block_tables,
+      states_i, tables_i = cached_states.x_layers[i], block_tables
+      if pool is not None:
+        states_i, tables_i = states_i.Copy(), block_tables[i]
+        states_i.self_atten = pool
+      x, ns = x_layer.RaggedStep(theta.x_layers[i], x, states_i, tables_i,
                                  rows, **kw)
+      if pool is not None:
+        pool, ns.self_atten = ns.self_atten, NestedMap()
       new_states.x_layers.append(ns)
+    if pool is not None:
+      new_states.kv_pool = pool
     if self.p.final_ln:
       x = self.final_ln.FProp(theta.final_ln, x)
     return x, new_states
@@ -589,6 +668,10 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
                                    (theta.body, cached_states.body))
     return out, NestedMap(body=new_states)
 
+  def PageWindows(self):
+    """The body's (StackedTransformerLayers.PageWindows), or None."""
+    return getattr(self.body, "PageWindows", lambda: None)()
+
   def InitPagedStates(self, theta, num_pages, page_size, num_slots=0,
                       kv_cache_dtype=None):
     def _One(theta_i):
@@ -626,9 +709,25 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
 
     carried = _ByPath(cached_states.body)
 
+    # variables the body addresses in the stack by `layer` are not
+    # scanned: the body gets the stack whole (StackAddressed)
+    whole = (self.body.StackAddressed()
+             if hasattr(self.body, "StackAddressed") else set())
+    def _Whole(path, leaf):
+      del leaf
+      keys = tuple(str(getattr(k, "key", getattr(k, "name", getattr(
+          k, "idx", "")))) for k in path)
+      return keys in whole
+    scanned_theta = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jnp.zeros(leaf.shape[:1], leaf.dtype)
+        if _Whole(path, leaf) else leaf, theta.body)
+
     def _Body(carry, per_layer):
       x, states = carry
       theta_i, idx = per_layer
+      theta_i = jax.tree_util.tree_map_with_path(
+          lambda path, mine, stack: stack if _Whole(path, stack) else mine,
+          theta_i, theta.body)
       x, new_states = self.body.RaggedStep(theta_i, x, states, block_tables,
                                            rows, layer=idx, **kw)
       new = _ByPath(new_states)
@@ -640,7 +739,7 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
 
     (out, states), added = jax.lax.scan(
         _Body, (inputs, cached_states.body),
-        (theta.body, jnp.arange(self.p.num_layers)))
+        (scanned_theta, jnp.arange(self.p.num_layers)))
     final = _ByPath(states)
     new_states = jax.tree_util.tree_map_with_path(
         lambda path, leaf: final[path] if leaf is None else leaf, added,

@@ -11,6 +11,10 @@ try:
 except ImportError:
   pass
 try:
+  from lingvo_tpu.models.lm.params import smallthinker  # noqa: F401
+except ImportError:
+  pass
+try:
   from lingvo_tpu.models.lm.params import one_billion_wds  # noqa: F401
 except ImportError:
   pass

@@ -58,6 +58,40 @@ class TransformerLm(base_model.BaseTask):
         "use_repeat_layer, num_layers must divide by n (the block is the "
         "scanned repeat body).")
     p.Define("use_rotary", True, "RoPE instead of absolute positions.")
+    p.Define("rope_theta", 1e4,
+             "RoPE base where a layer rotates. KV heads and a head size "
+             "that is not model_dim / num_heads are atten_tpl's keys "
+             "(atten_tpl.num_kv_heads, atten_tpl.dim_per_head).")
+    p.Define("sliding_window_size", 0,
+             "Keys a query of a window layer sees, its own included.")
+    p.Define(
+        "sliding_window_layout", None,
+        "The layer pattern as data: a list of 0 / 1, 1 = the layer attends "
+        "within sliding_window_size (attention.MultiHeadedAttention."
+        "window), 0 = over everything before it. Its length divides "
+        "num_layers and it repeats down the stack. None = no window layer.")
+    p.Define(
+        "rope_layout", None,
+        "A list of 0 / 1 as sliding_window_layout: 1 = the layer rotates "
+        "queries and keys (rope_theta), 0 = it carries no position at all. "
+        "None = every layer as use_rotary says. Under use_repeat_layer the "
+        "shortest period of both layouts is the scanned body (a "
+        "StackedTransformerLayers of that depth).")
+    p.Define("norm_tpl", None,
+             "Norm template of both blocks of a layer and of the final norm "
+             "(None = LayerNorm), e.g. layers.RmsNorm.Params().")
+    p.Define(
+        "expert_ffn_tpl", None,
+        "If set (core/moe.DroplessMoELayer.Params()), every layer's "
+        "feed-forward is this expert layer and nothing else; hidden_dim is "
+        "then unused. Served by ServingLoop; the capacity-based "
+        "num_experts interleave below is the layer that trains "
+        "expert-parallel (docs/moe_collectives.md).")
+    p.Define("tie_embeddings", True,
+             "The softmax reads the embedding table; False = a head of its "
+             "own, [vocab_size, model_dim].")
+    p.Define("scale_emb_sqrt_depth", True,
+             "Embeddings times sqrt(model_dim).")
     p.Define(
         "kv_cache_dtype", None,
         "Decode KV-cache storage dtype for every attention layer in the "
@@ -113,7 +147,11 @@ class TransformerLm(base_model.BaseTask):
             vocab_size=p.vocab_size, embedding_dim=p.model_dim,
             logits_soft_max=p.softmax_logits_soft_max,
             xent_block_size=p.xent_block_size,
+            scale_sqrt_depth=p.scale_emb_sqrt_depth,
             weight_split_dims_mapping=("model", None)))
+    if not p.tie_embeddings:
+      assert p.softmax_num_sampled == 0 and p.xent_block_size == 0
+      self.CreateChild("head", self.emb.p.Copy())
     if not p.use_rotary:
       self.CreateChild(
           "pos_emb",
@@ -127,6 +165,13 @@ class TransformerLm(base_model.BaseTask):
     if atten_tpl is not None:
       layer_body.tr_atten_tpl.atten_tpl = atten_tpl.Copy()
     layer_body.tr_atten_tpl.atten_tpl.use_rotary_position_emb = p.use_rotary
+    layer_body.tr_atten_tpl.atten_tpl.rope_max_timescale = p.rope_theta
+    if layer_body.tr_atten_tpl.atten_tpl.dim_per_head:
+      layer_body.tr_atten_tpl.atten_tpl.hidden_dim = (
+          p.num_heads * layer_body.tr_atten_tpl.atten_tpl.dim_per_head)
+    if p.norm_tpl is not None:
+      layer_body.tr_atten_tpl.norm_tpl = p.norm_tpl.Copy()
+      layer_body.tr_fflayer_tpl.norm_tpl = p.norm_tpl.Copy()
     layer_body.tr_atten_tpl.atten_tpl.kv_cache_dtype = p.kv_cache_dtype
     layer_body.tr_atten_tpl.atten_tpl.atten_dropout_prob = p.atten_dropout_prob
     layer_body.tr_atten_tpl.atten_tpl.weight_split_dims_mapping = (
@@ -134,6 +179,20 @@ class TransformerLm(base_model.BaseTask):
     layer_body.tr_atten_tpl.residual_dropout_prob = p.residual_dropout_prob
     layer_body.tr_fflayer_tpl.residual_dropout_prob = p.residual_dropout_prob
     layer_body.tr_fflayer_tpl.weight_split_dims_mapping = (None, "model")
+    if p.expert_ffn_tpl is not None:
+      assert p.num_experts == 0 and p.mixer_tpl is None
+      layer_body.tr_fflayer_tpl = p.expert_ffn_tpl.Copy()
+      if p.norm_tpl is not None:
+        layer_body.tr_fflayer_tpl.norm_tpl = p.norm_tpl.Copy()
+      layer_body.hidden_dim = 0
+
+    period = self._PatternPeriod()
+    if period is not None and len(period) == 1:
+      # the layouts say the same of every layer: the plain body, so set
+      (windowed, rotates), period = period[0], None
+      layer_body.tr_atten_tpl.atten_tpl.Set(
+          window=p.sliding_window_size if windowed else 0,
+          use_rotary_position_emb=bool(rotates))
 
     ssm_body = None
     if p.mixer_tpl is not None:
@@ -202,6 +261,27 @@ class TransformerLm(base_model.BaseTask):
             transformer_lib.StackedTransformerLayers.Params().Set(
                 num_layers=p.num_layers, input_dim=p.model_dim,
                 layer_tpls=tpls, final_ln=False))
+    elif period is not None:
+      # window and full layers, rotating and position-free ones, by the
+      # layouts: one period is the scanned body, as the SSM hybrid's block
+      assert ssm_body is None, "a layer pattern composes with attention only"
+      depth = len(period) if p.use_repeat_layer else p.num_layers
+      tpls = []
+      for i in range(depth):
+        windowed, rotates = period[i % len(period)]
+        tpl = layer_body.Copy()
+        tpl.tr_atten_tpl.atten_tpl.Set(
+            window=p.sliding_window_size if windowed else 0,
+            use_rotary_position_emb=bool(rotates))
+        tpls.append(tpl)
+      block = transformer_lib.StackedTransformerLayers.Params().Set(
+          num_layers=depth, input_dim=p.model_dim, layer_tpls=tpls,
+          final_ln=False)
+      if p.use_repeat_layer:
+        block = transformer_lib.RepeatedTransformerLayer.Params().Set(
+            num_layers=p.num_layers // depth, body=block,
+            remat_policy=p.remat_policy)
+      self.CreateChild("stack", block)
     elif p.use_repeat_layer:
       self.CreateChild(
           "stack",
@@ -229,7 +309,35 @@ class TransformerLm(base_model.BaseTask):
               num_sampled=p.softmax_num_sampled))
     self.CreateChild(
         "final_ln",
-        layers_lib.LayerNorm.Params().Set(input_dim=p.model_dim))
+        (p.norm_tpl or layers_lib.LayerNorm.Params()).Copy().Set(
+            input_dim=p.model_dim))
+
+  def _PatternPeriod(self):
+    """[(windowed, rotates)] over the shortest period of the two layouts;
+    None where there is no layout."""
+    p = self.p
+    if p.sliding_window_layout is None and p.rope_layout is None:
+      return None
+    n = p.num_layers
+
+    def _Tiled(layout, default):
+      if layout is None:
+        return [default] * n
+      assert n % len(layout) == 0, (n, layout)
+      return [int(x) for x in layout] * (n // len(layout))
+
+    windowed = _Tiled(p.sliding_window_layout, 0)
+    assert not any(windowed) or p.sliding_window_size > 0
+    layers = list(zip(windowed, _Tiled(p.rope_layout, int(p.use_rotary))))
+    for k in range(1, n + 1):
+      if n % k == 0 and layers == layers[:k] * (n // k):
+        return layers[:k]
+
+  def _Head(self, theta, x):
+    """[..., D] -> [..., V] logits: the tied table, or the head's own."""
+    if self.p.tie_embeddings:
+      return self.emb.Logits(theta.emb, x)
+    return self.head.Logits(theta.head, x)
 
   # -- forward ---------------------------------------------------------------
 
@@ -259,7 +367,7 @@ class TransformerLm(base_model.BaseTask):
     when the fused-xent gate deferred them."""
     if "logits" in predictions:
       return predictions.logits
-    return self.emb.Logits(theta.emb, predictions.hidden)
+    return self._Head(theta, predictions.hidden)
 
   def ComputePredictions(self, theta, input_batch):
     p = self.p
@@ -291,7 +399,7 @@ class TransformerLm(base_model.BaseTask):
       # dense logits
       return NestedMap(hidden=x)
     with jax.named_scope("head_loss"):
-      logits = self.emb.Logits(theta.emb, x) if p.softmax_num_sampled == 0 \
+      logits = self._Head(theta, x) if p.softmax_num_sampled == 0 \
           else self.sampled_softmax.Logits(
               self.ChildTheta(theta, "sampled_softmax"), x)
     return NestedMap(logits=logits)
@@ -384,7 +492,7 @@ class TransformerLm(base_model.BaseTask):
       logits = self.sampled_softmax.Logits(
           self.ChildTheta(theta, "sampled_softmax"), x)
     else:
-      logits = self.emb.Logits(theta.emb, x)
+      logits = self._Head(theta, x)
     return logits[:, 0, :], new_states
 
   def Prefill(self, theta, ids, states, cache_paddings=None, live_len=None):
@@ -413,7 +521,7 @@ class TransformerLm(base_model.BaseTask):
       logits = self.sampled_softmax.Logits(
           self.ChildTheta(theta, "sampled_softmax"), x)
     else:
-      logits = self.emb.Logits(theta.emb, x)
+      logits = self._Head(theta, x)
     return logits, new_states
 
   def InitPagedDecodeState(self, theta, num_pages: int, page_size: int,
@@ -463,7 +571,7 @@ class TransformerLm(base_model.BaseTask):
       logits = self.sampled_softmax.Logits(
           self.ChildTheta(theta, "sampled_softmax"), x)
     else:
-      logits = self.emb.Logits(theta.emb, x)
+      logits = self._Head(theta, x)
     return logits, new_states
 
   def RaggedStep(self, theta, ids, states, block_tables, rows,
@@ -493,7 +601,7 @@ class TransformerLm(base_model.BaseTask):
         logits = self.sampled_softmax.Logits(
             self.ChildTheta(theta, "sampled_softmax"), x)
       else:
-        logits = self.emb.Logits(theta.emb, x)
+        logits = self._Head(theta, x)
     return logits, new_states
 
   def PagedStepPrefix(self, theta, ids, states, block_tables, q_pos, in_len,
@@ -513,7 +621,7 @@ class TransformerLm(base_model.BaseTask):
       logits = self.sampled_softmax.Logits(
           self.ChildTheta(theta, "sampled_softmax"), x)
     else:
-      logits = self.emb.Logits(theta.emb, x)
+      logits = self._Head(theta, x)
     return logits, new_states
 
 

@@ -36,6 +36,13 @@ ENGINE_COUNTER_KEYS = (
     # undelivered (`steps` less the pipeline's fills), and rows computed
     # for a sequence that had ended by the time their tokens arrived
     "steps_overlapped", "inflight_rows_dropped",
+    # expert layers (core/moe.py), from the [layers, experts] token counts
+    # a step returns beside its tokens: (token, expert) pairs routed; summed
+    # over steps and layers, the tokens of the fullest expert, the tokens
+    # of the mean expert (routed / experts, a float), and the experts that
+    # got any token. All zero on a stack without expert layers.
+    "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
+    "moe_experts_active",
 )
 
 # Static engine configuration facts (set once at construction).

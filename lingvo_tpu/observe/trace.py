@@ -85,10 +85,10 @@ class StepTrace:
   seconds throughout)."""
 
   __slots__ = ("step", "start_ts", "loop_s", "segments_s", "valid_tokens",
-               "prefill_tokens", "rows")
+               "prefill_tokens", "rows", "counters")
 
   def __init__(self, step, start_ts, loop_s, segments_s, valid_tokens,
-               prefill_tokens, rows):
+               prefill_tokens, rows, counters=None):
     self.step = step
     self.start_ts = start_ts
     self.loop_s = loop_s
@@ -96,6 +96,10 @@ class StepTrace:
     self.valid_tokens = valid_tokens
     self.prefill_tokens = prefill_tokens
     self.rows = rows
+    # {name: value so far} of the engine's cumulative counters that a
+    # reader wants between two steps (expert load, window pages), as they
+    # stood when the step's record closed; None where the engine has none
+    self.counters = counters
 
   @property
   def span_s(self) -> float:
@@ -307,13 +311,13 @@ class TraceRecorder:
 
   def StepDone(self, step: int, start_ts: float, loop_s: float, segments_s,
                valid_tokens: int = 0, prefill_tokens: int = 0,
-               rows: int = 0):
+               rows: int = 0, counters=None):
     """Records one engine step: the one call a step costs. segments_s: the
     seconds spent in each of STEP_SEGMENTS, which tile the step from
     start_ts on; loop_s: from the previous step's end to start_ts."""
     global _last_stepped
     rec = StepTrace(step, start_ts, loop_s, tuple(segments_s), valid_tokens,
-                    prefill_tokens, rows)
+                    prefill_tokens, rows, counters)
     assert len(rec.segments_s) == len(STEP_SEGMENTS), rec.segments_s
     with self._lock:
       self._steps.append(rec)

@@ -88,7 +88,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
-    LANES, NEG_INF)
+    LANES, NEG_INF, SUBLANES)
 from lingvo_tpu.ops.flash_decode import _DotF32, _Finish, _PageAttend
 from lingvo_tpu.ops.block_decode import _DequantPages
 from lingvo_tpu.ops.block_decode import SupportedOnTpu  # noqa: F401  (same
@@ -118,7 +118,7 @@ def _AncestorOk(slot, c, lo, hi):
 
 def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
                      page_size: int, k_scale=None, v_scale=None,
-                     q_start=None, anc_lo=None, anc_hi=None):
+                     q_start=None, anc_lo=None, anc_hi=None, window: int = 0):
   """q: [T, N, H]; pools [NP, P, N, H]; tables [B, t_pages] int32;
   row_of/q_end [T] int32. -> [T, N, H].
 
@@ -126,7 +126,10 @@ def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   O(T * max(q_end)), not O(T * t_pages * P). k_scale/v_scale [NP, N, P]
   switch on the int8 path via the shared `_DequantPages`. q_start/anc_lo/
   anc_hi [T] int32 add per-token in-step ancestor masking for tree rows
-  (None = chain semantics, bitwise the unmasked kernel)."""
+  (None = chain semantics, bitwise the unmasked kernel). window > 0: token t
+  sees slots [q_end - window, q_end) only, the loop starts at the first page
+  any token can reach, and a token's page index is held inside its own
+  reach, so a table entry behind its window is never gathered."""
   t, n, h = q.shape
   np_total, page, _, _ = k_pool.shape
   assert page == page_size, (page, page_size)
@@ -142,12 +145,26 @@ def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
   rows = jnp.clip(row_of.astype(jnp.int32), 0, tables.shape[0] - 1)
   tok_tables = tables[rows]                                # [T, t_pages]
+  first_trip = 0
+  if window:
+    tok_lo = jnp.maximum(ends - window, 0) // page_size    # [T]
+    tok_last = jnp.maximum(ends - 1, 0) // page_size
+    first_trip = jnp.min(jnp.where(ends > 0, tok_lo, t_pages))
+    first_trip = jnp.minimum(first_trip, trip)
 
   batched_attend = jax.vmap(_PageAttend)
 
   def _Body(j, carry):
     m, l, acc = carry
-    pid = jax.lax.dynamic_index_in_dim(tok_tables, j, axis=1, keepdims=False)
+    if window:
+      pid = jnp.take_along_axis(
+          tok_tables, jnp.clip(j, tok_lo, tok_last)[:, None], axis=1)[:, 0]
+      # a padding token has no reach: it reads the pool's last page (the
+      # engine's trash page), never a row's
+      pid = jnp.where(ends > 0, pid, np_total - 1)
+    else:
+      pid = jax.lax.dynamic_index_in_dim(tok_tables, j, axis=1,
+                                         keepdims=False)
     k_page = k_pool[pid]                                   # [T, P, N, H]
     v_page = v_pool[pid]
     if k_scale is not None:
@@ -155,6 +172,8 @@ def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
       v_page = _DequantPages(v_page, v_scale[pid])
     slot = j * page_size + jnp.arange(page_size, dtype=jnp.int32)  # [P]
     causal = slot[None, :] < ends[:, None]                 # [T, P]
+    if window:
+      causal &= slot[None, :] >= ends[:, None] - window
     ok = _AncestorOk(slot[None, :], slot[None, :] - starts[:, None],
                      lo[:, None], hi[:, None])
     keep = (causal & ok).astype(jnp.float32)[:, None, :]
@@ -163,7 +182,7 @@ def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   m0 = jnp.full((t, n, 1), NEG_INF, jnp.float32)
   l0 = jnp.zeros((t, n, 1), jnp.float32)
   acc0 = jnp.zeros((t, n, h), jnp.float32)
-  _, l, acc = jax.lax.fori_loop(0, trip, _Body, (m0, l0, acc0))
+  _, l, acc = jax.lax.fori_loop(first_trip, trip, _Body, (m0, l0, acc0))
   return _Finish(l, acc, q.dtype)
 
 
@@ -172,7 +191,25 @@ def _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
 _VMEM_BUDGET = 12 * 2**20   # of the 16 MiB a kernel may scope by default
 
 
-def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype) -> int:
+_GROUPED_BQ = 512   # queries a block of the grouped kernel: 64 tokens by a
+#                     group padded to 8 (PERF.md section 6, PR 35)
+
+
+def GroupLanes(group: int) -> int:
+  """Queries a token lays on the packed axis where the grouped kernel runs:
+  its group of query heads padded to whole sublane tiles."""
+  return -(-group // SUBLANES) * SUBLANES
+
+
+def Grouped(n: int, n_kv: int) -> bool:
+  """Whether the Pallas lowering runs the grouped kernel: a KV head serves a
+  group of more than one query head. Plain multi-head attention keeps the
+  head-batched kernel."""
+  return n != n_kv
+
+
+def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype,
+               grouped: bool = False) -> int:
   """Bq, the most queries of one row that meet a page together.
 
   One page of queries: the `[Bq, P]` score tile of a head is square, so a
@@ -196,6 +233,10 @@ def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype) -> int:
     scores = 2 * n * bq * lanes_p * 4
     return pages + q_acc + stats + cols + scores
 
+  if grouped:
+    # plain [Bq, H] x [H, P] products a head: two score tiles of one head,
+    # and twice the rows amortise a page's fixed cost over twice the queries
+    return _GROUPED_BQ
   bq = max(min(page_size, LANES), 8)
   while bq > 8 and _WorkingSet(bq) > _VMEM_BUDGET:
     bq //= 2
@@ -209,6 +250,15 @@ def NumQueryBlocks(b: int, t: int, bq: int) -> int:
   return max(1, min(t, b + t // bq))
 
 
+def WindowPages(window: int, bq: int, page_size: int, t_pages: int) -> int:
+  """Pages a block of Bq consecutive queries of one row can reach: all of
+  the row's table without a window; with one, slots [e - window, e + Bq - 1)
+  for the block's narrowest horizon e, which touch at most this many."""
+  if not window:
+    return t_pages
+  return min(t_pages, (window + bq - 2) // page_size + 2)
+
+
 class _QueryBlocks(NamedTuple):
   """Descriptors of the step's query blocks (all int32; NB static).
 
@@ -218,6 +268,8 @@ class _QueryBlocks(NamedTuple):
   VMEM and compute nothing."""
   row: jnp.ndarray    # [NB] block-table row
   last: jnp.ndarray   # [NB] last live logical page (of the widest horizon)
+  page0: jnp.ndarray  # [NB] first logical page a query's window reaches
+  #                     (0 without a window)
   n: jnp.ndarray      # [NB] valid queries; 0 = no such block this step
   first: jnp.ndarray  # [NB] packed index of the block's first query
   src: jnp.ndarray    # [NB] the `cols` block its programs map
@@ -226,7 +278,8 @@ class _QueryBlocks(NamedTuple):
 
 
 def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
-                      page_size: int, t_pages: int) -> _QueryBlocks:
+                      page_size: int, t_pages: int,
+                      window: int = 0) -> _QueryBlocks:
   """Cuts each row's run of tokens into blocks of Bq queries.
 
   A few [T]- and [NB, Bq]-sized integer ops on what the step already has
@@ -259,8 +312,15 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
   last = jnp.clip((jnp.max(blk_ends, axis=1) + page_size - 1) // page_size
                   - 1, 0, t_pages - 1)
   n = jnp.where(k < n_live, jnp.sum(member.astype(jnp.int32), axis=1), 0)
-  return _QueryBlocks(row=row_of[first], last=last, n=n, first=first,
-                      src=src, cols=cols)
+  page0 = jnp.zeros_like(last)
+  if window:
+    # the block's narrowest horizon less the window: no query of the block
+    # sees a slot before it
+    low = jnp.min(jnp.where(member, blk_ends, jnp.iinfo(jnp.int32).max),
+                  axis=1)
+    page0 = jnp.minimum(jnp.maximum(low - window, 0) // page_size, last)
+  return _QueryBlocks(row=row_of[first], last=last, page0=page0, n=n,
+                      first=first, src=src, cols=cols)
 
 
 def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
@@ -280,9 +340,14 @@ def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
 
 def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
                         first_ref, end0_ref, start0_ref, lo0_ref, hi0_ref,
-                        q_hbm, cols_ref, k_ref, v_ref, *rest,
-                        page_size: int, t_pages: int):
+                        *rest, page_size: int, t_pages: int, window: int):
   """One (query block, logical page) program; scratch carried over pages.
+
+  With a window (static) an eleventh prefetched array, the block's first
+  page, leads `rest`: program j of a block runs logical page page0 + j,
+  `t_pages` is then the most pages a block's queries can reach, and a query
+  sees slots [q_end - window, q_end) only. Without one the program is the
+  windowless one, operand for operand.
 
   q_hbm/out_hbm: [T + Bq, N, H], left in HBM: a block copies its own
   window in at its first page and out at its last, at its row's packed
@@ -300,18 +365,23 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
 
   Float and int8 pools share the body (int8 threads two scale blocks,
   dequantized via the shared `_DequantPages`)."""
+  i = pl.program_id(0)
+  j = pl.program_id(1)
+  page = j
+  if window:
+    page = rest[0][i] + j
+    rest = rest[1:]
+  q_hbm, cols_ref, k_ref, v_ref, *rest = rest
   if len(rest) == 13:
     ks_ref, vs_ref = rest[:2]
     rest = rest[2:]
   else:
     ks_ref = vs_ref = None
   (_, out_hbm, q1, qb, m1, l1, acc1, mb, lb, accb, sem) = rest
-  i = pl.program_id(0)
-  j = pl.program_id(1)
   nv = n_ref[i]
   first = first_ref[i]
   bq, heads, h = qb.shape
-  slot0 = j * page_size
+  slot0 = page * page_size
 
   def _Copy(src, dst):
     cp = pltpu.make_async_copy(src, dst, sem)
@@ -332,7 +402,7 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
       l_scr[...] = jnp.zeros_like(l_scr)
       acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j <= last_ref[i])
+    @pl.when(page <= last_ref[i])
     def _Accumulate():
       k_page, v_page = k_ref[0], v_ref[0]
       if ks_ref is not None:
@@ -360,6 +430,8 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
     slot = slot0 + col // heads
     keep = ((col % heads == head) & (slot < end0_ref[i]) & _AncestorOk(
         slot, slot - start0_ref[i], lo0_ref[i], hi0_ref[i]))
+    if window:
+      keep &= slot >= end0_ref[i] - window
     return _BlockPageAttend(
         q1[0], k_page.reshape(width, h), v_page.reshape(width, h), keep,
         m, l, acc, (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
@@ -370,6 +442,8 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
     cols = cols_ref[0]                                      # [Bq, 4]
     keep = (slot < cols[:, 0:1]) & _AncestorOk(
         slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [Bq, P]
+    if window:
+      keep &= slot >= cols[:, 0:1] - window
     return _BlockPageAttend(
         qb[...], k_page, v_page, keep[None], m, l, acc,
         (((2,), (2,)), ((1,), (1,))), (((2,), (0,)), ((0,), (1,))))
@@ -380,15 +454,133 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
       qb, mb, lb, accb, _ManyQueries, lambda out: jnp.swapaxes(out, 0, 1)))
 
 
+# which half of a 32-bit word holds the EVEN row of a packed 16-bit pair
+_EVEN_ROW_LOW = True
+
+
+def _HeadPages(ref, heads: int):
+  """ref: one page as rows, block `[1, P * heads, H]` with row p * heads + g
+  the vector of token p, KV head g. -> `heads` arrays `[P, H]`, a head's
+  rows. 32-bit pages: a strided load a head. 16-bit pages: Mosaic loads
+  with a stride only 32-bit rows, and two consecutive rows share a word
+  row, so the page is read as words (rows 2w and 2w + 1 in the halves of
+  word row w), every (heads / 2)-th word row from s holds heads 2s and
+  2s + 1 of every token, and each half, shifted to the top of a word, IS
+  the 16-bit float's value as an f32."""
+  rows = ref.shape[1]
+  tokens = rows // heads
+  if heads == 1:
+    return [ref[0]]
+  if jnp.dtype(ref.dtype).itemsize == 4:
+    return [ref[0, pl.ds(g, tokens, stride=heads), :] for g in range(heads)]
+  assert ref.dtype == jnp.bfloat16 and heads % 2 == 0, (ref.dtype, heads)
+  words = ref.bitcast(jnp.uint32)                      # [1, rows / 2, H]
+  out = []
+  for s in range(heads // 2):
+    w = words[0, pl.ds(s, tokens, stride=heads // 2), :]
+    low = pltpu.bitcast(w << 16, jnp.float32).astype(ref.dtype)
+    high = pltpu.bitcast(w & jnp.uint32(0xFFFF0000),
+                         jnp.float32).astype(ref.dtype)
+    out += [low, high] if _EVEN_ROW_LOW else [high, low]
+  return out
+
+
+def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
+                         first_ref, end0_ref, start0_ref, lo0_ref, hi0_ref,
+                         *rest, page_size: int, t_pages: int, window: int,
+                         heads: int):
+  """The (query block, logical page) program where a KV head serves a GROUP
+  of query heads: the group rides the packed axis (RaggedAttend), so a
+  block is Bq queries of which each has one vector per KV head, and no block
+  is one query. q and the output arrive with heads and head size MERGED on
+  the minor axis, `[T + Bq, Nkv * H]`: a head's queries are a 128-lane
+  column slice, whole tiles. A page arrives as the pool holds it,
+  `[P, Nkv, H]` seen as `[P * Nkv, H]` rows (token-major, head-minor): on
+  the chip that view is the SAME bytes (a `(Nkv, 128)`-tiled bf16 array
+  packs row pairs exactly as `[P * Nkv, 128]` does), where a view with the
+  heads on the lanes made XLA copy every pool every layer (25 of 98 ms a
+  step, PERF.md section 6, PR 35). `_HeadPages` takes a head's `[P, H]`
+  keys out of the rows by a strided load. The block then runs `heads` plain
+  `[Bq, H] x [H, P]` and `[Bq, P] x [P, H]` products a page. The packed
+  axis is the tiled one here, and a block starts at any token, a multiple
+  of 8 queries: q and the output cross HBM in f32, whose tile is 8 rows (a
+  16-bit tile is 16: half the rows would start mid-tile), and the block's
+  queries are cast once, at its first page. Window and masks as in
+  _RaggedAttendKernel."""
+  i = pl.program_id(0)
+  j = pl.program_id(1)
+  page = j
+  if window:
+    page = rest[0][i] + j
+    rest = rest[1:]
+  q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
+  h = qb.shape[1] // heads
+  # a token's group is padded to whole sublane tiles (RaggedAttend), so a
+  # block starts on one: the packed axis is the tiled one here
+  first = pl.multiple_of(first_ref[i], SUBLANES)
+  window_q = pl.ds(first, qb.shape[0])
+
+  def _Copy(src, dst):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+  @pl.when(n_ref[i] > 0)
+  def _Block():
+    @pl.when(j == 0)
+    def _Init():
+      _Copy(q_hbm.at[window_q], qb)
+      qh[...] = qb[...].astype(qh.dtype)
+      mb[...] = jnp.full_like(mb, NEG_INF)
+      lb[...] = jnp.zeros_like(lb)
+      accb[...] = jnp.zeros_like(accb)
+
+    @pl.when(page <= last_ref[i])
+    def _Accumulate():
+      slot = page * page_size + jax.lax.broadcasted_iota(
+          jnp.int32, (1, page_size), 1)                       # [1, P]
+      cols = cols_ref[0]                                      # [Bq, 4]
+      keep = (slot < cols[:, 0:1]) & _AncestorOk(
+          slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [Bq, P]
+      if window:
+        keep &= slot >= cols[:, 0:1] - window
+      keys, values = _HeadPages(k_ref, heads), _HeadPages(v_ref, heads)
+      for g in range(heads):
+        lanes = pl.ds(g * h, h)
+        m, l, acc = _BlockPageAttend(
+            qh[:, lanes], keys[g], values[g], keep,
+            mb[g, :, :1], lb[g, :, :1], accb[:, lanes],
+            (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+        mb[g] = jnp.broadcast_to(m, mb.shape[1:])
+        lb[g] = jnp.broadcast_to(l, lb.shape[1:])
+        accb[:, lanes] = acc
+
+    @pl.when(j == t_pages - 1)
+    def _Emit():
+      # a query that is not this block's comes out an exact zero, as in
+      # _RaggedAttendKernel
+      for g in range(heads):
+        lanes = pl.ds(g * h, h)
+        qb[:, lanes] = _Finish(lb[g, :, :1], accb[:, lanes], qb.dtype)
+      _Copy(qb, out_hbm.at[window_q])
+
+
 def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
                         page_size: int, interpret: bool = False,
                         k_scale=None, v_scale=None,
-                        q_start=None, anc_lo=None, anc_hi=None):
+                        q_start=None, anc_lo=None, anc_hi=None,
+                        window: int = 0, grouped: bool = False):
   """Pallas lowering of _XlaRaggedAttend. q: [T, N, H] -> [T, N, H].
+
+  grouped (static): the queries are a KV head's group laid beside the
+  tokens (RaggedAttend) and float pages: the same grid, descriptors and
+  page index map run _GroupedAttendKernel over pages seen as rows.
 
   Grid `(NB, t_pages)`, both axes in order: block i + 1 starts where block
   i's queries end, so its window overwrites the zeros block i left past
-  its own."""
+  its own. With a window the grid's second axis is the pages a block's
+  queries can reach, counted from the block's first one (`WindowPages`),
+  not the row's whole table."""
   t, n, h = q.shape
   np_total, page, _, _ = k_pool.shape
   assert page == page_size, (page, page_size)
@@ -399,21 +591,27 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   if q_start is None:
     q_start = jnp.zeros((t,), jnp.int32)
     anc_lo = anc_hi = jnp.full((t,), -1, jnp.int32)
-  bq = QueryBlock(n, h, page_size, q.dtype, k_pool.dtype)
+  bq = QueryBlock(n, h, page_size, q.dtype, k_pool.dtype, grouped=grouped)
   nb = NumQueryBlocks(b, t, bq)
   blocks = _BuildQueryBlocks(
       rows, ends, q_start.astype(jnp.int32), anc_lo.astype(jnp.int32),
       anc_hi.astype(jnp.int32), bq=bq, nb=nb, page_size=page_size,
-      t_pages=t_pages)
+      t_pages=t_pages, window=window)
   col0 = blocks.cols[:, 0]                                  # [NB, 4]
+  grid_pages = WindowPages(window, bq, page_size, t_pages)
+  prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
+              blocks.first, col0[:, 0], col0[:, 1], col0[:, 2], col0[:, 3]]
+  if window:
+    prefetch.append(blocks.page0)
 
   # A dead logical page clamps to the BLOCK's last live page and a block
   # past the live ones to the last live block: Pallas asks for the block it
   # already holds and elides the DMA, pl.when skips compute. A stale table
   # entry past a block's widest horizon never reaches VMEM, which is the
   # page-reuse-after-eviction guarantee.
-  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *_):
-    return (tables_ref[row_ref[i], jnp.minimum(j, last_ref[i])], 0, 0, 0)
+  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *more):
+    page = more[-1][i] + j if window else j
+    return (tables_ref[row_ref[i], jnp.minimum(page, last_ref[i])], 0, 0, 0)
 
   def _ScaleIdx(i, j, *refs):
     return _PageIdx(i, j, *refs)[:3]
@@ -422,6 +620,42 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
     return (src_ref[i], 0, 0)
 
   hbm = pl.BlockSpec(memory_space=pl.ANY)
+  if grouped:
+    out = pl.pallas_call(
+        functools.partial(_GroupedAttendKernel, page_size=page_size,
+                          t_pages=grid_pages, window=window, heads=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(nb, grid_pages),
+            in_specs=[
+                hbm,
+                pl.BlockSpec((1, bq, 4), _ColsIdx),
+                pl.BlockSpec((1, page_size * n, h),
+                             lambda *a: _PageIdx(*a)[:3]),
+                pl.BlockSpec((1, page_size * n, h),
+                             lambda *a: _PageIdx(*a)[:3]),
+                hbm,
+            ],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((bq, n * h), jnp.float32),
+                pltpu.VMEM((bq, n * h), k_pool.dtype),
+                pltpu.VMEM((n, bq, LANES), jnp.float32),
+                pltpu.VMEM((n, bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, n * h), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((t + bq, n * h), jnp.float32),
+        input_output_aliases={len(prefetch) + 4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch,
+      jnp.pad(q.reshape(t, n * h).astype(jnp.float32), ((0, bq), (0, 0))),
+      blocks.cols, k_pool.reshape(np_total, page * n, h),
+      v_pool.reshape(np_total, page * n, h),
+      jnp.zeros((t + bq, n * h), jnp.float32))
+    return out[:t].astype(q.dtype).reshape(t, n, h)
   in_specs = [
       hbm,
       pl.BlockSpec((1, bq, 4), _ColsIdx),
@@ -430,9 +664,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   ]
   # Bq rows of slack: the last block's window may run past T
   slack = ((0, bq), (0, 0), (0, 0))
-  operands = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
-              blocks.first, col0[:, 0], col0[:, 1], col0[:, 2], col0[:, 3],
-              jnp.pad(q, slack), blocks.cols, k_pool, v_pool]
+  operands = prefetch + [jnp.pad(q, slack), blocks.cols, k_pool, v_pool]
   if k_scale is not None:
     in_specs += [
         pl.BlockSpec((1, n, page_size), _ScaleIdx),
@@ -445,8 +677,8 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   operands.append(jnp.zeros((t + bq, n, h), q.dtype))
 
   grid_spec = pltpu.PrefetchScalarGridSpec(
-      num_scalar_prefetch=10,
-      grid=(nb, t_pages),
+      num_scalar_prefetch=len(prefetch),
+      grid=(nb, grid_pages),
       in_specs=in_specs,
       out_specs=hbm,
       scratch_shapes=[
@@ -462,7 +694,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
       ],
   )
   kernel = functools.partial(_RaggedAttendKernel, page_size=page_size,
-                             t_pages=t_pages)
+                             t_pages=grid_pages, window=window)
   out = pl.pallas_call(
       kernel,
       grid_spec=grid_spec,
@@ -480,14 +712,23 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
 
 def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
                  page_size: int, k_scale=None, v_scale=None,
-                 q_start=None, anc_lo=None, anc_hi=None,
+                 q_start=None, anc_lo=None, anc_hi=None, window: int = 0,
                  lowering: str = "auto", interpret: bool | None = None):
   """Packed-token ragged paged attention — decode, prefill, and verify
   rows in one call.
 
   q: [T, N, H] packed query tokens, ALREADY scaled; every token's K/V was
   written to the pool before the call.
-  k_pool/v_pool: [num_pages, page_size, N, H] global page pool.
+  k_pool/v_pool: [num_pages, page_size, Nkv, H] global page pool. Nkv
+  divides N: query head n reads KV head n // (N // Nkv). A group of
+  G = N // Nkv > 1 query heads becomes G more rows of M: a token's G
+  queries of one KV head ride the packed axis as G consecutive queries of
+  its row with its horizon, so both lowerings run [T * G, Nkv, H] queries
+  against the Nkv heads they have, and a query block is Bq / G tokens by G
+  heads. G == 1 is the call as it was, operand for operand.
+  window: 0, or the slots a query sees counting its own: token t attends
+  [q_end - window, q_end). Pages wholly behind a block's window are never
+  read (the table's entries there may be stale).
   block_tables: [B, pages_per_seq] int32 physical page ids; entries past a
   row's live pages are arbitrary and never influence the output.
   row_of: [T] int32 — batch row (block-table index) of each token; a
@@ -518,15 +759,46 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
   on_tpu = jax.default_backend() == "tpu"
   if lowering == "auto":
     lowering = "pallas" if on_tpu else "xla"
+  row_of, q_end = jnp.asarray(row_of), jnp.asarray(q_end)
+  t, n, h = q.shape
+  n_kv = k_pool.shape[2]
+  assert n % n_kv == 0, (n, n_kv)
+  group = n // n_kv
+  # the grouped kernel wants every token's group to start on a sublane
+  # tile: the group is padded with zero queries of the token's own horizon
+  # (computed, dropped)
+  grouped = lowering == "pallas" and Grouped(n, n_kv)
+  if grouped and (k_scale is not None or h % LANES or (
+      k_pool.dtype.itemsize == 2 and n_kv > 1 and n_kv % 2)):
+    raise NotImplementedError(
+        f"the Pallas lowering serves {n} query heads over {n_kv} KV heads "
+        "from f32 pages, or bf16 pages of one or an even number of KV "
+        f"heads, whose heads tile the lanes; got {k_pool.dtype} pages, head "
+        f"size {h}" + (", int8 scales" if k_scale is not None else ""))
+  lanes = GroupLanes(group) if grouped else group
+  if group > 1:
+    # [T, Nkv, G, H] -> [T * G', Nkv, H]: the group beside the tokens
+    q = q.reshape(t, n_kv, group, h).swapaxes(1, 2)
+    q = jnp.pad(q, ((0, 0), (0, lanes - group), (0, 0), (0, 0)))
+    q = q.reshape(-1, n_kv, h)
+    row_of, q_end = jnp.repeat(row_of, lanes), jnp.repeat(q_end, lanes)
+    if q_start is not None:
+      q_start, anc_lo, anc_hi = (jnp.repeat(x, lanes)
+                                 for x in (q_start, anc_lo, anc_hi))
+  kw = dict(k_scale=k_scale, v_scale=v_scale, q_start=q_start,
+            anc_lo=anc_lo, anc_hi=anc_hi)
+  if window:
+    kw["window"] = int(window)
   if lowering == "xla":
-    return _XlaRaggedAttend(q, k_pool, v_pool, block_tables,
-                            jnp.asarray(row_of), jnp.asarray(q_end),
-                            page_size, k_scale=k_scale, v_scale=v_scale,
-                            q_start=q_start, anc_lo=anc_lo, anc_hi=anc_hi)
-  if interpret is None:
-    interpret = not on_tpu
-  return _PallasRaggedAttend(q, k_pool, v_pool, block_tables,
-                             jnp.asarray(row_of), jnp.asarray(q_end),
-                             page_size, interpret=interpret,
-                             k_scale=k_scale, v_scale=v_scale,
-                             q_start=q_start, anc_lo=anc_lo, anc_hi=anc_hi)
+    out = _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
+                           page_size, **kw)
+  else:
+    if interpret is None:
+      interpret = not on_tpu
+    out = _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
+                              page_size, interpret=interpret,
+                              **kw, **({"grouped": True} if grouped else {}))
+  if group > 1:
+    out = out.reshape(t, lanes, n_kv, h)[:, :group]
+    out = out.swapaxes(1, 2).reshape(t, n, h)
+  return out

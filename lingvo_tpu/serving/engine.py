@@ -182,14 +182,26 @@ class _StepSpans:
     if self._step_ann is not None:
       self._Close()
 
-  def End(self, step: int, valid_tokens: int, prefill_tokens: int, rows: int):
+  def End(self, step: int, valid_tokens: int, prefill_tokens: int, rows: int,
+          counters=None):
     now = self._Close(step=step, valid_tokens=valid_tokens,
                       prefill_tokens=prefill_tokens, rows=rows)
     loop_s = self._t0 - self._last_end if self._last_end is not None else 0.0
     self._last_end = now
     if self._recorder is not None:
       self._recorder.StepDone(step, self._t0, loop_s, self._acc,
-                              valid_tokens, prefill_tokens, rows)
+                              valid_tokens, prefill_tokens, rows, counters)
+
+
+def _MoeCountLeaves(states):
+  """The `routed` leaves of a decode state (core/moe.DroplessMoELayer:
+  tokens by expert of the newest step), each as [layers of it, experts];
+  None where the stack has no expert layer."""
+  leaves = [leaf.reshape(-1, leaf.shape[-1]) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(states)[0]
+            if str(getattr(path[-1], "key", getattr(path[-1], "name", "")))
+            == "routed"]
+  return leaves or None
 
 
 def _FeedTokens(prev_sampled, tok_ids):
@@ -347,12 +359,39 @@ class ServingLoop:
     self.kv_bytes_per_token = kv_census.get("kv_bytes_per_token", 0)
     self._kv_quantized = self.kv_cache_dtype == "int8"
     self._kv_override = kv_cache_dtype
-    self.alloc = kv_cache.PageAllocator(
-        num_pages, page_size,
-        page_bytes=page_size * self.kv_bytes_per_token)
-    table_pages = self.alloc.PagesFor(max_seq_len)
     # mixer census: which resource(s) this stack's decode state occupies
     self.mixers = self._MixerCensus()
+    # unified ragged step geometry (the widest row a step admits also sizes
+    # what a row of the window kind holds): see below
+    self.prefill_token_budget = int(prefill_token_budget or prefill_chunk)
+    # layers of two kinds (full attention beside a sliding window) draw
+    # from ONE pool of uniform pages, a page a layer of the scanned block
+    # (kv_cache.KindPages): `num_pages` stays the caller's one number, pages
+    # of page_size tokens at EVERY layer's bytes, so the pool has that many
+    # times the block's layers, each of that fraction of the bytes
+    windows = getattr(task.stack, "PageWindows", lambda: None)()
+    block = len(windows) if windows else 1
+    self.alloc = kv_cache.PageAllocator(
+        num_pages * block, page_size,
+        page_bytes=page_size * self.kv_bytes_per_token // block)
+    table_pages = self.alloc.PagesFor(max_seq_len)
+    self._kind_pages = None
+    if windows:
+      refused = [name for name, on in (
+          ("prefix_cache", prefix_cache not in (None, False)),
+          ("spec (a draft source)", spec is not None),
+          ("scheduler_mode='priority' (preemption by page spill)",
+           scheduler_mode != "fifo")) if on]
+      if refused:
+        raise ValueError(
+            f"{', '.join(refused)}: not over a stack whose attention layers "
+            f"are of two kinds (windows by layer of the block {windows}, "
+            "0 = full): those paths know one block table a sequence, and a "
+            "window layer lets go of pages a prefix, a rollback or a restore "
+            "would read")
+      self._kind_pages = kv_cache.KindPages(
+          self.alloc, windows, max(1, self.prefill_token_budget), max_batch,
+          table_pages)
     self.state_pool = None
     if self.mixers["num_ssm"] > 0:
       self.state_pool = kv_cache.StateSlotPool(
@@ -378,7 +417,7 @@ class ServingLoop:
         needs_kv_pages=self.mixers["num_attention"] > 0,
         state_pool=self.state_pool, prefix_cache=self.prefix_cache,
         scheduler_mode=scheduler_mode, tenant_quotas=tenant_quotas,
-        tenant_weights=tenant_weights)
+        tenant_weights=tenant_weights, kind_pages=self._kind_pages)
     self.scheduler_mode = scheduler_mode
     # device halves of preemption spill/restore (priority mode): whole-
     # page gather/scatter across the paged leaves, slot-row gather/
@@ -397,8 +436,8 @@ class ServingLoop:
     # num_slots sizes the per-slot O(1) mixer states (attention ignores it);
     # the kv dtype override is a static string arg (hashable)
     init_fn = jax.jit(task.InitPagedDecodeState, static_argnums=(1, 2, 3, 4))
-    self._states = init_fn(theta, num_pages + 1, page_size, max_batch,
-                           kv_cache_dtype)
+    self._states = init_fn(theta, self.alloc.num_pages + 1, page_size,
+                           max_batch, kv_cache_dtype)
     # donate the pool into each step off-cpu (XLA:CPU can't alias + warns)
     donate = (1,) if jax.default_backend() != "cpu" else ()
     # copy-on-write executor: one jitted page copy across every page-pool
@@ -429,7 +468,6 @@ class ServingLoop:
     # unified ragged step geometry: T packed tokens cover every slot's
     # worst-case decode width (1 + draft k) plus a prefill token budget;
     # wmax is the widest single row the one compiled program admits
-    self.prefill_token_budget = int(prefill_token_budget or prefill_chunk)
     # a speculating row is 1 root + w*k tree nodes wide (chain: w == 1)
     spec_width = ((1 + self.spec.w * self.spec.k)
                   if self.spec is not None else 1)
@@ -457,6 +495,13 @@ class ServingLoop:
     attens = self._AttentionLayers()
     self._attend_bq = (attens[0].RaggedQueryBlock(page_size, kv_cache_dtype)
                        if attens else 0)
+    # a token is (laid, own) of the kernel's queries where a KV head serves
+    # a group of query heads (ops/ragged_block_attend.RaggedAttend)
+    self._attend_laid, self._attend_own = (
+        attens[0].RaggedQueriesPerToken() if attens else (1, 1))
+    # expert layers: their [layers, experts] token counts leave the step
+    # program beside the tokens (None: the stack has none)
+    self._moe_layers = _MoeCountLeaves(self._states)
     self._handles: dict = {}
     # counters live in the registry under serving/* (schema is the single
     # source of the key set); Stats() maps them back to the plain keys.
@@ -477,7 +522,7 @@ class ServingLoop:
     self.metrics.Gauge("serving/serve_int8_weights").Set(
         self.serve_int8_weights)
     self.metrics.SectionFn("scheduler", self.sched.Stats)
-    self.metrics.SectionFn("kv_pages", self.alloc.Stats)
+    self.metrics.SectionFn("kv_pages", (self._kind_pages or self.alloc).Stats)
     self.metrics.SectionFn(
         "prefix_cache",
         self.prefix_cache.Stats if self.prefix_cache is not None
@@ -600,7 +645,12 @@ class ServingLoop:
           sampled = sampling.SampleFromLogits(
               logits, key, temperature=temp, top_k=topk,
               row_seeds=seeds[row], positions=pos[row])
-        return sampled, new_states
+        routed = _MoeCountLeaves(new_states)
+        if routed is None:
+          return sampled, new_states
+        # [layers, experts] tokens by expert of this step: a copy (the
+        # states are donated to the next step before this one is fetched)
+        return sampled, jnp.concatenate(routed, axis=0), new_states
     elif spec_w == 1:
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos,
                       row_k, q_logits):
@@ -1113,10 +1163,11 @@ class ServingLoop:
                                   spec_k=spec_k, spec_w=spec_w,
                                   priority=priority, tenant=tenant)
       total = len(req.prompt) + req.max_new
-      if self.sched.needs_kv_pages and (
-          self.alloc.PagesFor(total) > self.alloc.num_pages):
+      needs = (self.alloc.PagesFor(total) if self._kind_pages is None
+               else self._kind_pages.Footprint(total))
+      if self.sched.needs_kv_pages and needs > self.alloc.num_pages:
         raise ValueError(
-            f"request needs {self.alloc.PagesFor(total)} pages; the pool "
+            f"request needs {needs} pages; the pool "
             f"only has {self.alloc.num_pages} — it could never be admitted")
       self.sched.Submit(req)
       handle = StreamHandle(req_id, self, time.perf_counter())
@@ -1225,6 +1276,8 @@ class ServingLoop:
                                            spec_k=spec_k, spec_w=spec_w)
         if batch is not None:
           tables = np.array(self.sched.block_tables)  # freeze under the lock
+          if self._kind_pages is not None:
+            tables = np.array(self._kind_pages.tables)   # a layer of the block
           self._NoteDispatch(batch)
       if batch is None:
         # nothing to launch (no record): the pipeline drains
@@ -1236,10 +1289,26 @@ class ServingLoop:
       events = self._RetireOldest() if len(self._in_flight) > keep else ()
       spans.End(self._counters["steps"].value,
                 int(batch.rows_desc.row_len.sum()), batch.prompt_tokens,
-                sum(r is not None for r in batch.rows))
+                sum(r is not None for r in batch.rows),
+                self._StepCounters())
       return len(events)
     finally:
       spans.Abandon()   # a no-op after the step's End
+
+  def _StepCounters(self):
+    """What a step's record carries of the cumulative counters whose
+    readers want them between two steps: expert load as of the newest
+    RETIRED step (one behind the record's own), window pages as of this
+    step's dispatch. None on a stack with neither."""
+    out = {}
+    if self._moe_layers is not None:
+      out.update((k, self._counters[k].value) for k in (
+          "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
+          "moe_experts_active"))
+    if self._kind_pages is not None:
+      out["window_pages_released"] = self._kind_pages.pages_released
+      out["window_pages_allocated"] = self._kind_pages.pages_allocated
+    return out or None
 
   def _NoteDispatch(self, batch):
     """What is known of a step when it is built (caller holds the lock):
@@ -1262,8 +1331,9 @@ class ServingLoop:
     if self._attend_bq:
       row_len = np.asarray(desc.row_len, np.int64)
       self._counters["attend_query_blocks"].Inc(
-          int(np.sum(-(-row_len // self._attend_bq))))
-      self._counters["attend_block_queries"].Inc(int(np.sum(row_len)))
+          int(np.sum(-(-row_len * self._attend_laid // self._attend_bq))))
+      self._counters["attend_block_queries"].Inc(
+          int(np.sum(row_len)) * self._attend_own)
     if self.paged_path == "dense":
       self._counters["dense_fallback_steps"].Inc()
     if self._kv_quantized:
@@ -1334,11 +1404,20 @@ class ServingLoop:
     batch, *drawn = self._in_flight.popleft()
     spans.To("device_wait")
     drawn = [np.asarray(x) for x in drawn]   # blocks until the step is done
+    routed = None
+    if self.spec is None and len(drawn) == 2:
+      routed = drawn.pop()                   # [layers, experts]
     sampled, out, alen = drawn if len(drawn) == 3 else (drawn[0], None, None)
     spans.To("lock_wait")
     with self._lock:
       spans.To("commit")
       events = self.sched.CommitRaggedStep(batch, sampled, out, alen)
+      if routed is not None:
+        self._counters["moe_tokens_routed"].Inc(int(routed.sum()))
+        self._counters["moe_expert_load_max"].Inc(int(routed.max(-1).sum()))
+        self._counters["moe_expert_load_mean"].Inc(
+            float(routed.mean(-1).sum()))
+        self._counters["moe_experts_active"].Inc(int((routed > 0).sum()))
       if batch.dropped:
         self._counters["inflight_rows_dropped"].Inc(batch.dropped)
       if batch.any_spec:
@@ -1490,7 +1569,7 @@ class ServingLoop:
       stats["kv_bytes_per_token"] = self.kv_bytes_per_token
       stats["serve_int8_weights"] = self.serve_int8_weights
       stats["scheduler"] = self.sched.Stats()
-      stats["kv_pages"] = self.alloc.Stats()
+      stats["kv_pages"] = (self._kind_pages or self.alloc).Stats()
       stats["mixers"] = dict(self.mixers)
       stats["prefix_cache"] = (
           self.prefix_cache.Stats() if self.prefix_cache is not None

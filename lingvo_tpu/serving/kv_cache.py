@@ -28,6 +28,19 @@ it does not exclusively own, the scheduler swaps in a fresh private page
 missed hazard — including a speculative-decoding rollback rewrite — a
 loud failure instead of silent cross-request corruption.
 
+Layers of two kinds (attention.MultiHeadedAttention.window): a stack that
+mixes full-attention layers with sliding-window ones keeps ONE pool of
+uniform pages that every layer of the scanned block draws from
+(transformer.StackedTransformerLayers: `kv_pool`). A page holds page_size
+tokens of ONE layer of the block (at every repeat of it), and each layer of
+the block has a block table of its own (`KindPages.tables`). A full layer's
+row holds every page it ever wrote. A window layer's row holds only the
+logical pages its future queries can still read, a run of at most `cap`
+consecutive ones that slides with the row's cursor; a page left behind goes
+to the row's own tail while the row still grows and back to the pool once it
+does not, where a request of either kind finds it. Nothing fixes a share of
+the pool for a kind: what the traffic holds of each is what it needs.
+
 O(1)-state mixers (core/ssm.py) need a second, much simpler resource:
 `StateSlotPool`. An SSM layer's decode state is a fixed [B, N, H, S]
 array — one constant-size matrix per batch row, no growth with sequence
@@ -41,6 +54,8 @@ HBM (the ISSUE's more-concurrent-requests-at-fixed-HBM criterion).
 from __future__ import annotations
 
 import heapq
+
+import numpy as np
 
 
 class OutOfPages(Exception):
@@ -284,6 +299,15 @@ class PageAllocator:
     else:
       self._ref[page] = r - 1
 
+  def FreePages(self, seq_id, pages: list[int]):
+    """Drops seq_id's reference on exactly `pages` (exclusive pages of its
+    own, in any logical slot): the window kind giving back what its row's
+    window has left behind while the row lives on."""
+    owned = self._owned[seq_id]
+    for pg in pages:
+      owned.remove(pg)
+      self._DecRef(pg)
+
   def Free(self, seq_id) -> int:
     """Drops seq_id's reference on every page it holds; returns the count
     of pages released (pages shared with other owners survive — they
@@ -299,6 +323,140 @@ class PageAllocator:
         self._DecRef(pg)
         n += 1
     return n
+
+
+class KindPages:
+  """The pages of a stack whose block has layers of two kinds, out of one
+  `PageAllocator` of uniform pages (module docstring).
+
+  windows: one entry a layer of the block, 0 = full attention, else the
+  keys a query sees counting its own. `tables[s]` is layer s's block table
+  `[slots, table_pages]`.
+
+  A query at position i of a window layer reads keys j with
+  i - window < j <= i. A row whose next token lands at `pos` (its cursor:
+  every later query is at `pos` or after, a prefill chunk's first query
+  included) can still read logical page p only if (p + 1) * page_size >
+  pos - window + 1. The layer's row therefore holds the logical pages
+  [first, end): `first` the page of slot pos - window + 1, `end` at most
+  `cap` pages on, where `cap` pages cover the window, the widest step a
+  row can take (`max_step_tokens`) and the page boundary: enough for any
+  one step's reads and writes together. Admission reserves, a layer,
+  min(pages of the whole request, cap), so a row once admitted never waits
+  for a page.
+
+  Advance() is called when a step has been DISPATCHED and the cursor has
+  passed its tokens: pages wholly behind the new cursor's window are
+  released. The step in flight may still read a page released here: the
+  device runs programs in dispatch order, and whoever gets the page writes
+  it in a later one. The row's table entries behind `first` go stale; the
+  kernel never visits them (ops/ragged_block_attend.py, `window`).
+
+  Host bookkeeping only, serialized by the engine's scheduler lock.
+  """
+
+  def __init__(self, allocator: PageAllocator, windows, max_step_tokens: int,
+               max_slots: int, table_pages: int):
+    assert max_step_tokens > 0 and any(windows) and not all(windows), windows
+    self.alloc = allocator
+    self.windows = tuple(int(w) for w in windows)
+    page = allocator.page_size
+    self.caps = tuple(
+        min(table_pages, (w + max_step_tokens - 2) // page + 2) if w
+        else table_pages for w in self.windows)
+    self.tables = np.zeros((len(self.windows), max_slots, table_pages),
+                           np.int32)
+    # seq -> [slot, logical pages the request ever writes,
+    #         a layer: [first logical page held, physical pages from it]]
+    self._rows: dict[object, list] = {}
+    self.in_use = {"full": 0, "window": 0}
+    self.peak_in_use = {"full": 0, "window": 0}
+    self.pages_allocated = 0    # window layers' logical pages ever backed
+    self.pages_released = 0     # of those, left behind by a live row
+
+  def Footprint(self, total_tokens: int) -> int:
+    """Pages admission reserves for a request of total_tokens slots."""
+    n = self.alloc.PagesFor(total_tokens)
+    return sum(min(n, cap) for cap in self.caps)
+
+  def CanAdmit(self, total_tokens: int) -> bool:
+    return self.alloc.CanAllocate(self.Footprint(total_tokens))
+
+  def _Count(self, kind: str, n: int):
+    self.in_use[kind] += n
+    self.peak_in_use[kind] = max(self.peak_in_use[kind], self.in_use[kind])
+
+  def Admit(self, seq_id, slot: int, total_tokens: int) -> None:
+    """Reserves the request's footprint and writes its rows of the tables."""
+    n = self.alloc.PagesFor(total_tokens)
+    pages = list(self.alloc.Allocate(seq_id, self.Footprint(total_tokens)))
+    layers = []
+    for s, cap in enumerate(self.caps):
+      mine, pages = pages[:min(n, cap)], pages[min(n, cap):]
+      self.tables[s, slot, :] = 0
+      self.tables[s, slot, :len(mine)] = mine
+      layers.append([0, mine])
+      self._Count("window" if self.windows[s] else "full", len(mine))
+      if self.windows[s]:
+        self.pages_allocated += len(mine)
+    self._rows[seq_id] = [slot, n, layers]
+
+  def Held(self, seq_id, layer: int) -> tuple[int, list[int]]:
+    """(first logical page, physical pages of the run from it) of seq_id's
+    row in the block's layer `layer`."""
+    first, pages = self._rows[seq_id][2][layer]
+    return first, list(pages)
+
+  def Advance(self, seq_id, pos: int) -> int:
+    """The row's cursor is now `pos`: its window layers let go of the pages
+    no query at or after it can read. Returns pages released."""
+    row = self._rows.get(seq_id)
+    if row is None:
+      return 0
+    slot, total, layers = row
+    released = 0
+    for s, (window, cap) in enumerate(zip(self.windows, self.caps)):
+      if not window:
+        continue
+      first, pages = layers[s]
+      new_first = max(0, pos - window + 1) // self.alloc.page_size
+      new_first = min(new_first, first + len(pages))
+      k = new_first - first
+      if k <= 0:
+        continue
+      left, pages = pages[:k], pages[k:]
+      end = first + k + len(pages)
+      grow = min(total, new_first + cap) - end
+      recycled, freed = left[:grow], left[grow:]
+      self.tables[s, slot, end:end + len(recycled)] = recycled
+      if freed:
+        self.alloc.FreePages(seq_id, freed)
+        self._Count("window", -len(freed))
+      layers[s] = [new_first, pages + recycled]
+      self.pages_allocated += len(recycled)
+      self.pages_released += k
+      released += k
+    return released
+
+  def Free(self, seq_id) -> int:
+    row = self._rows.pop(seq_id, None)
+    if row is not None:
+      for s, (_, pages) in enumerate(row[2]):
+        self._Count("window" if self.windows[s] else "full", -len(pages))
+    return self.alloc.Free(seq_id)
+
+  def Stats(self) -> dict:
+    """The pool's own section (`PageAllocator.Stats`: one pool, so its
+    peak is the peak) and, by kind, the pages held now and at most."""
+    out = self.alloc.Stats()
+    out["kinds"] = {kind: {"in_use": self.in_use[kind],
+                           "peak_in_use": self.peak_in_use[kind]}
+                    for kind in ("full", "window")}
+    out["window_pages_released"] = self.pages_released
+    out["window_pages_allocated"] = self.pages_allocated
+    out["window_cap_pages"] = max(
+        cap for cap, w in zip(self.caps, self.windows) if w)
+    return out
 
 
 class StateSlotPool:

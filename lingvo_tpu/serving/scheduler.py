@@ -288,7 +288,8 @@ class Scheduler:
                state_pool: Optional[kv_cache.StateSlotPool] = None,
                prefix_cache=None, scheduler_mode: str = "fifo",
                host_store: Optional[kv_cache.HostPageStore] = None,
-               tenant_quotas=None, tenant_weights=None, clock=None):
+               tenant_quotas=None, tenant_weights=None, clock=None,
+               kind_pages: Optional[kv_cache.KindPages] = None):
     """table_pages: block-table width (pages per sequence) — the static
     max_seq_len / page_size bound every compiled program carries.
     needs_kv_pages: False for pure-O(1)-mixer stacks (no attention layer
@@ -306,6 +307,13 @@ class Scheduler:
     quotas enforced at Submit. tenant_weights: {tenant: weight} for
     weighted-fair admission within a priority class (default 1.0).
     clock: injectable monotonic-seconds source for quota refill (tests).
+    kind_pages: the pages of a stack that mixes full and sliding-window
+    attention layers (kv_cache.KindPages, over `allocator`): it holds a
+    block table a layer of the block in place of `block_tables`, admission
+    reserves a request's whole footprint through it, and a row lets go of
+    the pages behind its window when its cursor moves (AdvanceRaggedStep).
+    FIFO admission only: the engine refuses the configurations whose
+    paths know one block table a sequence.
     """
     assert max_slots >= 1 and table_pages >= 1
     assert scheduler_mode in ("fifo", "priority"), scheduler_mode
@@ -339,6 +347,9 @@ class Scheduler:
     # block tables as one stable [B, table_pages] array, rewritten on
     # admit/evict only (steady-state decode steps reuse it as-is)
     self.block_tables = np.zeros((max_slots, table_pages), np.int32)
+    self.kind_pages = kind_pages
+    if kind_pages is not None:
+      assert scheduler_mode == "fifo" and prefix_cache is None
     # counters surfaced via engine Stats()
     self.admitted = 0
     self.finished = 0
@@ -424,7 +435,7 @@ class Scheduler:
     for i, seq in enumerate(self.slots):
       if seq is not None and seq.state is SeqState.CANCELLED:
         self.slots[i] = None
-        self.alloc.Free(seq.id)
+        self._FreePages(seq.id)
         if self.state_pool is not None:
           self.state_pool.Release(seq.id)
         self.cancelled += 1
@@ -528,7 +539,16 @@ class Scheduler:
     for i in range(self.max_slots):
       if self.slots[i] is not None or not self.waiting:
         continue
-      if self.needs_kv_pages:
+      if self.kind_pages is not None:
+        # a page a layer of the block, by its kind; strict FIFO
+        seq = self.waiting[0]
+        total = len(seq.req.prompt) + seq.req.max_new
+        if not self.kind_pages.CanAdmit(total):
+          break
+        self.waiting.popleft()
+        self.kind_pages.Admit(seq.id, i, total)
+        pages = []
+      elif self.needs_kv_pages:
         pick = self._NextWaiting()
         seq = self.waiting[pick]
         if not self._AdmitPages(seq):
@@ -910,6 +930,8 @@ class Scheduler:
       if seq.state is SeqState.PREFILL:
         seq.pos += n
         if seq.prompt_remaining > 0:
+          if self.kind_pages is not None:
+            self.kind_pages.Advance(seq.id, seq.pos)
           continue                       # more prompt tokens to go
         col = int(desc.row_cols[i, n - 1])
         seq.state = SeqState.DECODE
@@ -925,6 +947,8 @@ class Scheduler:
         continue
       batch.out_col[i] = seq.src_col = col
       seq.pending += 1
+      if self.kind_pages is not None:
+        self.kind_pages.Advance(seq.id, seq.pos)
       if seq.n_out >= seq.req.max_new:
         self.slots[i] = None
         self._Retire(seq, SeqState.FINISHED, "length")
@@ -999,10 +1023,16 @@ class Scheduler:
         events.append((seq.id, tok, False))
     return events
 
+  def _FreePages(self, seq_id):
+    if self.kind_pages is not None:
+      self.kind_pages.Free(seq_id)   # its bookkeeping, then the pool's
+    else:
+      self.alloc.Free(seq_id)
+
   def _Retire(self, seq: Sequence, state: SeqState, reason: str):
     seq.state = state
     seq.finish_reason = reason
-    self.alloc.Free(seq.id)   # idempotent
+    self._FreePages(seq.id)   # idempotent
     if self.state_pool is not None:
       self.state_pool.Release(seq.id)   # idempotent
 

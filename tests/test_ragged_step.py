@@ -212,7 +212,8 @@ class TestStepProgramCensus:
 
 _NP, _PS, _SLOTS, _TPAGES, _T, _WMAX = 12, 4, 4, 6, 16, 8
 # page-pool leaves of the decode state: K and V, and their int8 scale sidecars
-_POOL_LEAVES = {"f32": 2, "int8": 4, "hybrid": 2}
+# (the period body's four attention layers share ONE pool, K and V)
+_POOL_LEAVES = {"f32": 2, "int8": 4, "hybrid": 2, "period": 2}
 
 
 def _RepeatLm(kind):
@@ -221,6 +222,20 @@ def _RepeatLm(kind):
   [ssm, attention] block (DenseLmSsmHybrid's shape)."""
   if kind == "hybrid":
     p = _LmParams(every_n=2, use_repeat=True, num_layers=4)
+  elif kind == "period":
+    # 2 repeats of [full without rotary, window, window, window] over
+    # grouped KV heads, every feed-forward a dropless expert layer
+    # (SmallThinker's body, models/lm/params/smallthinker.py)
+    from lingvo_tpu.core import moe
+    from lingvo_tpu.core import attention
+    p = _LmParams(num_layers=8).Set(
+        use_repeat_layer=True, num_heads=4,
+        atten_tpl=attention.MultiHeadedAttention.Params().Set(
+            num_kv_heads=2, dim_per_head=4),
+        sliding_window_size=5, sliding_window_layout=[0, 1, 1, 1],
+        rope_layout=[0, 1, 1, 1],
+        expert_ffn_tpl=moe.DroplessMoELayer.Params().Set(
+            hidden_dim=16, num_experts=4, num_experts_per_token=2))
   else:
     p = _LmParams(num_layers=3).Set(use_repeat_layer=True)
   task, theta = _Instantiate(p, seed=5)
@@ -254,10 +269,13 @@ def _Pack(tree_row=False, all_padding=False):
   return ragged_lib.RaggedRows(*(jnp.asarray(x) for x in rows))
 
 
-def _Tables(stale=False):
+def _Tables(stale=False, layers=0):
   """Disjoint pages per row; dead entries 0, or (stale) other rows' pages,
   -5 and 99, with a LIVE entry of row 1 at _NP + 2: past this layer's
-  pages, inside the next layer's in a stack viewed as one pool."""
+  pages, inside the next layer's in a stack viewed as one pool. layers: a
+  block of two kinds of layer reads a table a layer, [layers, B, t_pages]
+  (the same one here: its layers then write the same pages of their one
+  pool one after the other, in the scan as in the loop)."""
   tables = np.zeros((_SLOTS, _TPAGES), np.int32)
   tables[0, :2] = [0, 1]
   tables[1, :3] = [2, 3, 4]
@@ -267,6 +285,8 @@ def _Tables(stale=False):
     tables[1, 2] = _NP + 2
     tables[1, 3:] = [0, 5, 99]
     tables[3] = [2, 7, -1, 99, 1, 4]
+  if layers:
+    tables = np.stack([tables] * layers)
   return jnp.asarray(tables)
 
 
@@ -317,14 +337,17 @@ class TestRepeatedStepCarriesThePool:
       ("f32", {}, True, False),
       ("f32", {}, False, True),
       ("int8", {}, True, True),
+      ("period", {}, False, False),
   ], ids=["bf16_pool", "int8_pool_with_scales", "hybrid", "hybrid_col_states",
-          "tree_row", "stale_and_out_of_range_tables", "int8_tree_stale"])
+          "tree_row", "stale_and_out_of_range_tables", "int8_tree_stale",
+          "period_of_window_and_expert_layers"])
   def test_step_is_bitwise_the_layer_loop(self, kind, kw, tree_row, stale):
     """Logits and the WHOLE returned state (trash pages, other layers'
     pages, SSM slots and `col_states`) equal the plain layer loop's."""
     task, theta, kv = _RepeatLm(kind)
     states = _RandomStates(task, theta, kv, seed=3)
-    rows, tables = _Pack(tree_row), _Tables(stale)
+    rows = _Pack(tree_row)
+    tables = _Tables(stale, layers=4 if kind == "period" else 0)
     ids = jnp.asarray(
         np.random.RandomState(4).randint(0, 64, (1, _T)), jnp.int32)
     logits, new_states = jax.jit(
@@ -338,7 +361,7 @@ class TestRepeatedStepCarriesThePool:
     assert not np.array_equal(np.asarray(new_states.body.Flatten()[0]),
                               np.asarray(states.body.Flatten()[0]))
 
-  @pytest.mark.parametrize("kind", ["f32", "int8", "hybrid"])
+  @pytest.mark.parametrize("kind", ["f32", "int8", "hybrid", "period"])
   def test_scan_carries_every_state_leaf(self, kind):
     """In the jaxpr of task.RaggedStep the scan over layers has every
     stacked state leaf among its CARRIES and nothing of a pool's shape
@@ -346,7 +369,7 @@ class TestRepeatedStepCarriesThePool:
     body (MoE, Mamba) has to keep for the step not to copy the pool."""
     task, theta, kv = _RepeatLm(kind)
     states = _RandomStates(task, theta, kv, seed=3)
-    rows, tables = _Pack(), _Tables()
+    rows, tables = _Pack(), _Tables(layers=4 if kind == "period" else 0)
     ids = jnp.zeros((1, _T), jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda th, st: task.RaggedStep(th, ids, st, tables, rows))(
