@@ -8,7 +8,10 @@ registered widths of `lm.synthetic_packed_input.DenseLm1B` (d=2048, 16 heads x
 
   device   what JAX sees, library versions, the compile-cache directory
   kernels  every Pallas kernel of the two paths, compiled (never interpret
-           mode), against its XLA twin on the same chip
+           mode), against its XLA twin on the same chip; the grouped ragged
+           kernel too, which DenseLm1B's plain heads do not reach: 28 query
+           heads over 4 KV heads of 128 on bf16 pages, a decode row, a short
+           row and a chunk in one pack, without and with a 4096-token window
   train    model_registry -> TrainProgram -> ExecutorTpu.Start(): two loops of
            tpu_steps_per_loop with flash attention and 'dots' remat, an async
            checkpoint (SaveAsync) and the final one, both restored
@@ -90,6 +93,13 @@ class Size:
   ragged_rows: int
   ragged_t: int
   ragged_chunk: int
+  # the grouped ragged kernel: (query heads, KV heads) of `grouped_h` (whole
+  # lanes, whatever `h` is), a row's table and the window of the windowed
+  # case (REAL: SmallThinker's 28 over 4 of 128 and its 4096-token layers)
+  grouped_heads: tuple[int, int]
+  grouped_h: int
+  grouped_table_pages: int
+  grouped_window: int
 
 
 REAL = Size(
@@ -99,7 +109,9 @@ REAL = Size(
     n=16, h=128, d=2048, vocab=32000, xent_block=1024, b=8, t=1024,
     pool_pages=512, table_pages=16, cache_len=2048,
     ssd_heads=8, ssd_state=128, ssd_chunk=64,
-    ragged_rows=32, ragged_t=544, ragged_chunk=300)
+    ragged_rows=32, ragged_t=544, ragged_chunk=300,
+    grouped_heads=(28, 4), grouped_h=128, grouped_table_pages=48,
+    grouped_window=4096)
 
 TINY = Size(
     model=TINY_MODEL, train_layers=None, steps_per_loop=2, interpret=True,
@@ -108,7 +120,9 @@ TINY = Size(
     n=2, h=16, d=32, vocab=96, xent_block=32, b=2, t=32,
     pool_pages=16, table_pages=4, cache_len=32,
     ssd_heads=2, ssd_state=8, ssd_chunk=8,
-    ragged_rows=4, ragged_t=24, ragged_chunk=14)
+    ragged_rows=4, ragged_t=24, ragged_chunk=14,
+    grouped_heads=(14, 2), grouped_h=128, grouped_table_pages=8,
+    grouped_window=20)
 
 
 # -- kernels -----------------------------------------------------------------
@@ -213,6 +227,26 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
   anc_hi = jnp.full((t,), -1, i32).at[live - 5:live].set(0)
   chain, tree = (None, None, None), (q_start, anc_lo, anc_hi)
 
+  # grouped ragged: a decode row, a short row and a chunk that spans query
+  # blocks, then padding, on bf16 pages of the KV heads alone; every row's
+  # context ends near the end of its table, past the windowed case's window
+  g_n, g_kv = s.grouped_heads
+  g_widths = (1, 5, s.ragged_chunk)
+  g_cap = s.grouped_table_pages * page
+  g_pool = (len(g_widths) * s.grouped_table_pages + 1, page, g_kv,
+            s.grouped_h)
+  g_row_of, g_end = [], []
+  for r, width in enumerate(g_widths):
+    g_row_of += [r] * width
+    g_end += list(range(g_cap - width - 3 * r, g_cap - 3 * r))
+  g_pad = [0] * (t - len(g_row_of))
+  grouped = (
+      _Normal((t, g_n, s.grouped_h), scale=1.0 / math.sqrt(s.grouped_h)),
+      _Normal(g_pool), _Normal(g_pool),
+      jax.random.permutation(next(keys), g_pool[0] - 1).reshape(
+          len(g_widths), -1).astype(i32),
+      jnp.asarray(g_row_of + g_pad, i32), jnp.asarray(g_end + g_pad, i32))
+
   # SSD scan: a cotangent, log-decay <= 0, write keys, read keys, values
   lead = (s.b, s.t, s.ssd_heads)
   ssd = (_Normal(lead + (s.h,), f32),
@@ -227,6 +261,7 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
       "ragged_plain": ragged[:1] + pools[False] + ragged[1:] + chain,
       "ragged_tree": ragged[:1] + pools[False] + ragged[1:] + tree,
       "ragged_int8": ragged[:1] + pools[True] + ragged[1:] + chain,
+      "ragged_grouped": grouped,
       "flash_decode": (
           _Normal((s.b, 1, s.n, s.h), scale=q_scale),
           _Normal((s.b, s.cache_len, s.n, s.h)),
@@ -294,6 +329,12 @@ def KernelCases(size: Size) -> list[KernelCase]:
             v_scale=vs, q_start=q_start, anc_lo=lo, anc_hi=hi,
             **_Lowering(pallas)))
 
+  def _RaggedGrouped(window):
+    return lambda pallas: lambda q, k, v, tables, row_of, q_end: (
+        ragged_block_attend.RaggedAttend(
+            q, k, v, tables, row_of, q_end, page_size=page, window=window,
+            **_Lowering(pallas)))
+
   def _FlashDecode(pallas):
     return lambda q, k, v, step: flash_decode.FlashDecode(
         q, k, v, step, page_size=page, **_Lowering(pallas))
@@ -332,6 +373,9 @@ def KernelCases(size: Size) -> list[KernelCase]:
       KernelCase("ragged_attend_plain", "ragged_plain", _Ragged),
       KernelCase("ragged_attend_tree", "ragged_tree", _Ragged),
       KernelCase("ragged_attend_int8", "ragged_int8", _Ragged),
+      KernelCase("ragged_attend_grouped", "ragged_grouped", _RaggedGrouped(0)),
+      KernelCase("ragged_attend_grouped_window", "ragged_grouped",
+                 _RaggedGrouped(s.grouped_window)),
       KernelCase("flash_decode", "flash_decode", _FlashDecode),
       KernelCase("fused_xent_fwd", "xent", _XentFwd),
       KernelCase("fused_xent_fwd_bwd", "xent", _XentFwdBwd),

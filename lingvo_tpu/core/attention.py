@@ -17,6 +17,7 @@ the reference's sharding-by-annotation design (§2.9 of SURVEY.md).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Sequence
 
@@ -702,6 +703,17 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if self.kv_group == 1:
       return 1, 1
     return ragged_block_attend.GroupLanes(self.kv_group), self.kv_group
+
+  def RaggedBlockRows(self, page_size: int, kv_cache_dtype=None):
+    """queries -> the rows of M the ragged kernel's products run for a block
+    of that many valid queries (ops/ragged_block_attend.BlockRows on this
+    layer's ladder): what the engine's `attend_block_rows` sums."""
+    from lingvo_tpu.ops import ragged_block_attend
+    return functools.partial(
+        ragged_block_attend.BlockRows,
+        rungs=ragged_block_attend.BlockRungs(
+            self.RaggedQueryBlock(page_size, kv_cache_dtype),
+            self.RaggedQueriesPerToken()[0]))
 
   def BlockDecodeEligible(self, page_size: int) -> bool:
     """Same gate family as PagedDecodeEligible, for the block-table kernel:

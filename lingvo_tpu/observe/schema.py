@@ -30,8 +30,11 @@ ENGINE_COUNTER_KEYS = (
     "spec_branches", "spec_width_clamps",
     "prefix_hit_tokens",
     # query blocks the ragged attend kernel ran, and the valid queries in
-    # them; their ratio over the block size is the block fill
-    "attend_query_blocks", "attend_block_queries",
+    # them; their ratio over the block size is the block fill. A block's
+    # products run the rows of M its queries need, not always the block's
+    # bound (ops/ragged_block_attend.BlockRungs): `attend_block_rows` sums
+    # them.
+    "attend_query_blocks", "attend_block_queries", "attend_block_rows",
     # the loop's pipeline: steps dispatched while the step before was still
     # undelivered (`steps` less the pipeline's fills), and rows computed
     # for a sequence that had ended by the time their tokens arrived

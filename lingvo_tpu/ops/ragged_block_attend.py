@@ -62,6 +62,11 @@ online softmax and accumulator), held to each other within rounding:
     key's head is the query's (nothing is re-laid out, and a decode row is
     bound by the page's bytes); a larger block runs head-batched
     `[Bq, N, H] x [P, N, H]`, the page re-laid out once for Bq queries.
+    Where a KV head serves a group of query heads the group rides the
+    packed axis and `_GroupedAttendKernel` runs plain products a KV head;
+    its blocks adapt the same way, by rows: a block's products, scratch
+    traffic and copies run over the first rung of `BlockRungs` that holds
+    its valid queries (a decode row's 8, or Bq).
 - `_XlaRaggedAttend` — the CPU serving path and the twin the kernel is
   held to: `fori_loop` with a dynamic trip count of `ceil(max(q_end) / P)`
   over per-token gathered pages through `flash_decode._PageAttend`. Tokens
@@ -84,6 +89,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -241,6 +247,31 @@ def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype,
   while bq > 8 and _WorkingSet(bq) > _VMEM_BUDGET:
     bq //= 2
   return bq
+
+
+def BlockRungs(bq: int, lanes: int = 1) -> tuple[int, ...]:
+  """The rows of M a query block may run, ascending: a block of n valid
+  queries runs the first rung that holds them (`BlockRows`), the kernel
+  choosing inside its one program from the count it is handed.
+
+  lanes: the queries one token lays on the packed axis (`GroupLanes` where
+  the grouped kernel runs, 1 where the head-batched one does). The lower
+  rung is one token's queries (a decode row: the head-batched kernel's
+  one-query path, the grouped kernel's 8 rows of a sublane tile), the upper
+  one is Bq. No rung between them: 128 rows halve a call over rows of 2 to
+  16 tokens (5.29 against 9.09 ms, 64 such rows), which no cell sends, and
+  every rung is traced again for every kernel of a step program, 0.4-0.6 s
+  each on the benchmark's host (PERF.md section 6, PR 36). A function of
+  shapes alone, like `QueryBlock`: the engine calls it for its counters,
+  nothing chooses it."""
+  return (lanes, bq) if lanes < bq else (bq,)
+
+
+def BlockRows(queries, rungs: tuple[int, ...]):
+  """Rows of M the kernel's products run for a block of `queries` valid
+  queries (an int or an integer array; 0 queries is no block: 0 rows)."""
+  ladder = np.asarray((0,) + tuple(rungs))
+  return ladder[np.searchsorted(ladder, queries)]
 
 
 def NumQueryBlocks(b: int, t: int, bq: int) -> int:
@@ -488,24 +519,31 @@ def _HeadPages(ref, heads: int):
 def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
                          first_ref, end0_ref, start0_ref, lo0_ref, hi0_ref,
                          *rest, page_size: int, t_pages: int, window: int,
-                         heads: int):
+                         heads: int, rungs: tuple[int, ...]):
   """The (query block, logical page) program where a KV head serves a GROUP
   of query heads: the group rides the packed axis (RaggedAttend), so a
-  block is Bq queries of which each has one vector per KV head, and no block
-  is one query. q and the output arrive with heads and head size MERGED on
-  the minor axis, `[T + Bq, Nkv * H]`: a head's queries are a 128-lane
-  column slice, whole tiles. A page arrives as the pool holds it,
-  `[P, Nkv, H]` seen as `[P * Nkv, H]` rows (token-major, head-minor): on
-  the chip that view is the SAME bytes (a `(Nkv, 128)`-tiled bf16 array
-  packs row pairs exactly as `[P * Nkv, 128]` does), where a view with the
-  heads on the lanes made XLA copy every pool every layer (25 of 98 ms a
-  step, PERF.md section 6, PR 35). `_HeadPages` takes a head's `[P, H]`
-  keys out of the rows by a strided load. The block then runs `heads` plain
-  `[Bq, H] x [H, P]` and `[Bq, P] x [P, H]` products a page. The packed
-  axis is the tiled one here, and a block starts at any token, a multiple
-  of 8 queries: q and the output cross HBM in f32, whose tile is 8 rows (a
-  16-bit tile is 16: half the rows would start mid-tile), and the block's
-  queries are cast once, at its first page. Window and masks as in
+  block is up to Bq queries of which each has one vector per KV head. q and
+  the output arrive with heads and head size MERGED on the minor axis,
+  `[T + Bq, Nkv * H]`: a head's queries are a 128-lane column slice, whole
+  tiles. A page arrives as the pool holds it, `[P, Nkv, H]` seen as
+  `[P * Nkv, H]` rows (token-major, head-minor): on the chip that view is
+  the SAME bytes (a `(Nkv, 128)`-tiled bf16 array packs row pairs exactly as
+  `[P * Nkv, 128]` does), where a view with the heads on the lanes made XLA
+  copy every pool every layer (25 of 98 ms a step, PERF.md section 6,
+  PR 35). `_HeadPages` takes a head's `[P, H]` keys out of the rows by a
+  strided load. The block then runs `heads` plain `[rows, H] x [H, P]` and
+  `[rows, P] x [P, H]` products a page, over the rows it HOLDS: its valid
+  queries lead its window, and their count (`n_ref`, which the program
+  already receives) picks the first of `rungs` (`BlockRungs`, static) that
+  holds them, inside the one program. The products, the softmax, the
+  scratch traffic and the copies of q and the output in and out of HBM all
+  run over that many leading rows of the scratch, so a decode row (one
+  token's laid group) pays for 8 rows and not for Bq, and rows past the rung
+  are never written: the output starts as zeros and padding reads them. The
+  packed axis is the tiled one here, and a block starts at any token, a
+  multiple of 8 queries: q and the output cross HBM in f32, whose tile is 8
+  rows (a 16-bit tile is 16: half the rows would start mid-tile), and the
+  block's queries are cast once, at its first page. Window and masks as in
   _RaggedAttendKernel."""
   i = pl.program_id(0)
   j = pl.program_id(1)
@@ -515,66 +553,141 @@ def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
     rest = rest[1:]
   q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
   h = qb.shape[1] // heads
+  nv = n_ref[i]
   # a token's group is padded to whole sublane tiles (RaggedAttend), so a
   # block starts on one: the packed axis is the tiled one here
   first = pl.multiple_of(first_ref[i], SUBLANES)
-  window_q = pl.ds(first, qb.shape[0])
 
   def _Copy(src, dst):
     cp = pltpu.make_async_copy(src, dst, sem)
     cp.start()
     cp.wait()
 
-  @pl.when(n_ref[i] > 0)
-  def _Block():
+  def _Block(rows):
+    """The block's life over its pages, on the `rows` leading rows of every
+    scratch (static): window in, accumulate, window out."""
+    held = pl.ds(0, rows)
+    window_q = pl.ds(first, rows)
+
     @pl.when(j == 0)
     def _Init():
-      _Copy(q_hbm.at[window_q], qb)
-      qh[...] = qb[...].astype(qh.dtype)
-      mb[...] = jnp.full_like(mb, NEG_INF)
-      lb[...] = jnp.zeros_like(lb)
-      accb[...] = jnp.zeros_like(accb)
+      _Copy(q_hbm.at[window_q], qb.at[held])
+      qh[held] = qb[held].astype(qh.dtype)
+      mb[:, held] = jnp.full((heads, rows, LANES), NEG_INF, mb.dtype)
+      lb[:, held] = jnp.zeros((heads, rows, LANES), lb.dtype)
+      accb[held] = jnp.zeros((rows, heads * h), accb.dtype)
 
     @pl.when(page <= last_ref[i])
     def _Accumulate():
       slot = page * page_size + jax.lax.broadcasted_iota(
           jnp.int32, (1, page_size), 1)                       # [1, P]
-      cols = cols_ref[0]                                      # [Bq, 4]
+      cols = cols_ref[0, held]                                # [rows, 4]
       keep = (slot < cols[:, 0:1]) & _AncestorOk(
-          slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [Bq, P]
+          slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [rows, P]
       if window:
         keep &= slot >= cols[:, 0:1] - window
       keys, values = _HeadPages(k_ref, heads), _HeadPages(v_ref, heads)
       for g in range(heads):
         lanes = pl.ds(g * h, h)
         m, l, acc = _BlockPageAttend(
-            qh[:, lanes], keys[g], values[g], keep,
-            mb[g, :, :1], lb[g, :, :1], accb[:, lanes],
+            qh[held, lanes], keys[g], values[g], keep,
+            mb[g, held, :1], lb[g, held, :1], accb[held, lanes],
             (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
-        mb[g] = jnp.broadcast_to(m, mb.shape[1:])
-        lb[g] = jnp.broadcast_to(l, lb.shape[1:])
-        accb[:, lanes] = acc
+        mb[g, held] = jnp.broadcast_to(m, (rows, LANES))
+        lb[g, held] = jnp.broadcast_to(l, (rows, LANES))
+        accb[held, lanes] = acc
 
     @pl.when(j == t_pages - 1)
     def _Emit():
-      # a query that is not this block's comes out an exact zero, as in
-      # _RaggedAttendKernel
+      # a query of the rung's rows that is not this block's comes out an
+      # exact zero, as in _RaggedAttendKernel
       for g in range(heads):
         lanes = pl.ds(g * h, h)
-        qb[:, lanes] = _Finish(lb[g, :, :1], accb[:, lanes], qb.dtype)
-      _Copy(qb, out_hbm.at[window_q])
+        qb[held, lanes] = _Finish(lb[g, held, :1], accb[held, lanes],
+                                  qb.dtype)
+      _Copy(qb.at[held], out_hbm.at[window_q])
+
+  below = 0
+  for rows in rungs:
+    pl.when((nv > below) & (nv <= rows))(functools.partial(_Block, rows))
+    below = rows
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "heads", "window", "grid", "rungs", "interpret"))
+def _GroupedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
+                 heads: int, window: int, grid: tuple[int, int],
+                 rungs: tuple[int, ...], interpret: bool):
+  """_GroupedAttendKernel over _PallasRaggedAttend's grid and descriptors.
+  q: [T + Bq, Nkv * H] f32; cols: [NB, Bq, 4]; pages as rows
+  [NP, P * Nkv, H] -> the output, [T + Bq, Nkv * H] f32, zeros where no
+  block wrote.
+
+  A `jit` of its own: a kernel's body is traced anew at every
+  `pallas_call`, a rung of this one costs 0.4-0.6 s on the benchmark's host,
+  and a period of four layers would pay that four times in set-up and again
+  in every later program of the process. The layers of a stack that call at
+  the same shapes share one trace. Only the call is inside: with the
+  descriptors inside too, XLA no longer shared them between the layers of a
+  step (0.66 ms a call; PERF.md section 6, PR 36). XLA names a kernel after
+  the innermost scope round its call, which `jit` would make this function's
+  name: the scope here keeps the name the callers' scope gives it. The page
+  index map is _PallasRaggedAttend's, dead-page clamp and all, less the
+  pages' fourth axis (a function the jit can key on cannot be passed in)."""
+  bq = cols.shape[1]
+  h = q.shape[1] // heads
+
+  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *more):
+    page = more[-1][i] + j if window else j
+    return (tables_ref[row_ref[i], jnp.minimum(page, last_ref[i])], 0, 0)
+
+  def _ColsIdx(i, j, row_ref, last_ref, src_ref, *_):
+    return (src_ref[i], 0, 0)
+
+  hbm = pl.BlockSpec(memory_space=pl.ANY)
+  with jax.named_scope("ragged_attend"):
+    return pl.pallas_call(
+        functools.partial(_GroupedAttendKernel, page_size=page_size,
+                          t_pages=grid[1], window=window, heads=heads,
+                          rungs=rungs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=[
+                hbm,
+                pl.BlockSpec((1, bq, 4), _ColsIdx),
+                pl.BlockSpec((1, page_size * heads, h), _PageIdx),
+                pl.BlockSpec((1, page_size * heads, h), _PageIdx),
+                hbm,
+            ],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((bq, heads * h), jnp.float32),
+                pltpu.VMEM((bq, heads * h), k_pages.dtype),
+                pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, heads * h), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        input_output_aliases={len(prefetch) + 4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, q, cols, k_pages, v_pages, jnp.zeros(q.shape, jnp.float32))
 
 
 def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
                         page_size: int, interpret: bool = False,
                         k_scale=None, v_scale=None,
                         q_start=None, anc_lo=None, anc_hi=None,
-                        window: int = 0, grouped: bool = False):
+                        window: int = 0, grouped: int = 0):
   """Pallas lowering of _XlaRaggedAttend. q: [T, N, H] -> [T, N, H].
 
-  grouped (static): the queries are a KV head's group laid beside the
-  tokens (RaggedAttend) and float pages: the same grid, descriptors and
-  page index map run _GroupedAttendKernel over pages seen as rows.
+  grouped (static): 0, or the queries a token lays on the packed axis where
+  they are a KV head's group laid beside the tokens (RaggedAttend) and the
+  pages float: the same grid, descriptors and page index map run
+  _GroupedAttendKernel over pages seen as rows.
 
   Grid `(NB, t_pages)`, both axes in order: block i + 1 starts where block
   i's queries end, so its window overwrites the zeros block i left past
@@ -621,40 +734,13 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
 
   hbm = pl.BlockSpec(memory_space=pl.ANY)
   if grouped:
-    out = pl.pallas_call(
-        functools.partial(_GroupedAttendKernel, page_size=page_size,
-                          t_pages=grid_pages, window=window, heads=n),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(nb, grid_pages),
-            in_specs=[
-                hbm,
-                pl.BlockSpec((1, bq, 4), _ColsIdx),
-                pl.BlockSpec((1, page_size * n, h),
-                             lambda *a: _PageIdx(*a)[:3]),
-                pl.BlockSpec((1, page_size * n, h),
-                             lambda *a: _PageIdx(*a)[:3]),
-                hbm,
-            ],
-            out_specs=hbm,
-            scratch_shapes=[
-                pltpu.VMEM((bq, n * h), jnp.float32),
-                pltpu.VMEM((bq, n * h), k_pool.dtype),
-                pltpu.VMEM((n, bq, LANES), jnp.float32),
-                pltpu.VMEM((n, bq, LANES), jnp.float32),
-                pltpu.VMEM((bq, n * h), jnp.float32),
-                pltpu.SemaphoreType.DMA(()),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((t + bq, n * h), jnp.float32),
-        input_output_aliases={len(prefetch) + 4: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(*prefetch,
-      jnp.pad(q.reshape(t, n * h).astype(jnp.float32), ((0, bq), (0, 0))),
-      blocks.cols, k_pool.reshape(np_total, page * n, h),
-      v_pool.reshape(np_total, page * n, h),
-      jnp.zeros((t + bq, n * h), jnp.float32))
+    out = _GroupedCall(
+        tuple(prefetch),
+        jnp.pad(q.reshape(t, n * h).astype(jnp.float32), ((0, bq), (0, 0))),
+        blocks.cols, k_pool.reshape(np_total, page * n, h),
+        v_pool.reshape(np_total, page * n, h), page_size=page_size, heads=n,
+        window=window, grid=(nb, grid_pages), rungs=BlockRungs(bq, grouped),
+        interpret=interpret)
     return out[:t].astype(q.dtype).reshape(t, n, h)
   in_specs = [
       hbm,
@@ -797,7 +883,7 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
       interpret = not on_tpu
     out = _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
                               page_size, interpret=interpret,
-                              **kw, **({"grouped": True} if grouped else {}))
+                              **kw, **({"grouped": lanes} if grouped else {}))
   if group > 1:
     out = out.reshape(t, lanes, n_kv, h)[:, :group]
     out = out.swapaxes(1, 2).reshape(t, n, h)

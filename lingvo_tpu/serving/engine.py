@@ -499,6 +499,9 @@ class ServingLoop:
     # a group of query heads (ops/ragged_block_attend.RaggedAttend)
     self._attend_laid, self._attend_own = (
         attens[0].RaggedQueriesPerToken() if attens else (1, 1))
+    # queries of a block -> the rows of M its products run
+    self._attend_rows = (attens[0].RaggedBlockRows(page_size, kv_cache_dtype)
+                         if attens else None)
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
@@ -1330,10 +1333,14 @@ class ServingLoop:
     self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
     if self._attend_bq:
       row_len = np.asarray(desc.row_len, np.int64)
+      # a row's queries fill whole blocks and then one that holds the rest
+      whole, rest = np.divmod(row_len * self._attend_laid, self._attend_bq)
       self._counters["attend_query_blocks"].Inc(
-          int(np.sum(-(-row_len * self._attend_laid // self._attend_bq))))
+          int(np.sum(whole + (rest > 0))))
       self._counters["attend_block_queries"].Inc(
           int(np.sum(row_len)) * self._attend_own)
+      self._counters["attend_block_rows"].Inc(int(np.sum(
+          whole * self._attend_bq + self._attend_rows(rest))))
     if self.paged_path == "dense":
       self._counters["dense_fallback_steps"].Inc()
     if self._kv_quantized:

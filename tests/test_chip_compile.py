@@ -40,6 +40,25 @@ _SERVING_CASES = {"bf16": "ragged_attend_plain", "int8": "ragged_attend_int8",
                   "tree": "ragged_attend_tree"}
 
 
+# The grouped kernel at the shapes `smallthinker21b_serve_mixed` runs it at:
+# 64 slots + a 1,024-token budget, 28 query heads over 4 KV heads of 128, the
+# block's one pool of 2 x 7,601 pages, 128 pages a row.
+_GROUPED_SERVING = dict(t=1088, n=28, n_kv=4, h=128, page=128,
+                        table_pages=128, pool_pages=15202, rows=64)
+_GROUPED_CASES = {"full": "ragged_attend_grouped",
+                  "window": "ragged_attend_grouped_window"}
+
+
+def _GroupedServingArgs():
+  import jax.numpy as jnp
+  d = _GROUPED_SERVING
+  sds = jax.ShapeDtypeStruct
+  pool = sds((d["pool_pages"], d["page"], d["n_kv"], d["h"]), jnp.bfloat16)
+  tok = sds((d["t"],), jnp.int32)
+  return (sds((d["t"], d["n"], d["h"]), jnp.bfloat16), pool, pool,
+          sds((d["rows"], d["table_pages"]), jnp.int32), tok, tok)
+
+
 def _ServingArgs(variant):
   """The operands of chip_smoke's `_Ragged` case at `_SERVING` shapes."""
   import jax.numpy as jnp
@@ -91,6 +110,10 @@ def compiles():
           f"serving_{variant}": pool.submit(
               _Compile, _CASES[name], _ServingArgs(variant))
           for variant, name in _SERVING_CASES.items()})
+      futures.update({
+          f"grouped_serving_{variant}": pool.submit(
+              _Compile, _CASES[name], _GroupedServingArgs())
+          for variant, name in _GROUPED_CASES.items()})
       yield futures
   finally:
     jax.config.update("jax_enable_compilation_cache", True)
@@ -106,6 +129,13 @@ def test_kernel_compiles_for_v5e(name, compiles):
 @pytest.mark.parametrize("variant", sorted(_SERVING_CASES))
 def test_ragged_attend_compiles_at_serving_shapes(variant, compiles):
   assert "tpu_custom_call" in compiles[f"serving_{variant}"].result(
+      timeout=300)
+
+
+@pytest.mark.parametrize("variant", sorted(_GROUPED_CASES))
+def test_grouped_attend_compiles_at_serving_shapes(variant, compiles):
+  # every rung of the ladder is a branch of the one program Mosaic lowers
+  assert "tpu_custom_call" in compiles[f"grouped_serving_{variant}"].result(
       timeout=300)
 
 

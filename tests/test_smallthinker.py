@@ -61,33 +61,102 @@ def _NumpyAttend(q, kp, vp, tables, row_of, q_end, page, window):
   return out
 
 
-@pytest.mark.parametrize("lowering", ["xla", "pallas"])
-@pytest.mark.parametrize("window", [0, 20])
-@pytest.mark.parametrize("heads,kv_heads", [(7, 1), (14, 2), (2, 2)],
-                         ids=["group_of_7", "two_groups_of_7", "mha"])
-def test_ragged_attend_groups_and_window(heads, kv_heads, window, lowering):
-  """A decode row, a 20-token chunk, a 5-token row and padding in one pack.
-  Pages wholly behind a row's window hold NaN: a lowering that read one,
-  even masked, would return NaN."""
+# (tokens of a row, its first token's q_end) and the padding tokens after it
+_THREE_ROWS = (((1, 51), 0), ((20, 31), 0), ((5, 1), 6))
+# every rung of the grouped kernel in one call (Bq 512, a token lays 8
+# queries): a decode row (8 rows), a 2-token row (16 queries: the next rung),
+# a row of exactly Bq / 8 = 64 tokens, and a chunk of 85 that spans two
+# blocks with a ragged last one of 21 tokens; padding between the rows
+_EVERY_RUNG = (((1, 51), 1), ((2, 30), 2), ((64, 17), 0), ((85, 11), 5))
+
+
+def _RungCases():
+  for heads in ((7, 1), (14, 2), (28, 4)):
+    for window in (0, 20):
+      for pages in ("f32", "bf16"):
+        yield pytest.param(
+            _EVERY_RUNG, *heads, window, pages, "pallas",
+            id=f"every_rung-{heads[0]}_over_{heads[1]}-w{window}-{pages}")
+  for window in (0, 20):
+    yield pytest.param(_EVERY_RUNG, 14, 2, window, "f32", "xla",
+                       id=f"every_rung-14_over_2-w{window}-f32-xla")
+    yield pytest.param(_EVERY_RUNG, 2, 2, window, "f32", "pallas",
+                       id=f"every_rung-mha-w{window}-f32")
+
+
+@pytest.mark.parametrize(
+    "pack,heads,kv_heads,window,pages,lowering",
+    [pytest.param(_THREE_ROWS, *heads, window, "f32", lowering,
+                  id=f"{name}-{window}-{lowering}")
+     for lowering in ("xla", "pallas") for window in (0, 20)
+     for name, heads in (("group_of_7", (7, 1)), ("two_groups_of_7", (14, 2)),
+                         ("mha", (2, 2)))] + list(_RungCases()))
+def test_ragged_attend_groups_and_window(pack, heads, kv_heads, window, pages,
+                                         lowering):
+  """Rows of several lengths and padding in one pack, against numpy and,
+  for the kernel, against the XLA twin. Pages wholly behind a row's window
+  hold NaN, and so do the queries of the padding tokens, which lie past a
+  narrow block's own rows: a lowering that read either into what it keeps,
+  even masked, would return NaN. A padding token's output is an exact 0."""
   rng = np.random.RandomState(heads + window)
-  page, h, rows, t_pages, pool = 8, 128, 3, 8, 40
+  page, h, rows = 8, 128, len(pack)
+  t_pages = -(-max(n + e for (n, e), _ in pack) // page)
+  pool = rows * t_pages + 1
   kp = rng.randn(pool, page, kv_heads, h).astype(np.float32)
   vp = rng.randn(pool, page, kv_heads, h).astype(np.float32)
+  tol = 3e-5
+  if pages == "bf16":
+    # the pool as bf16 holds it; the kernel rounds q and p to bf16 as well
+    kp, vp = (np.array(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+              for a in (kp, vp))
+    tol = 3e-2
   tables = rng.permutation(pool - 1)[:rows * t_pages].reshape(
       rows, t_pages).astype(np.int32)
-  row_of = np.array([0] + [1] * 20 + [2] * 5 + [0] * 6, np.int32)
-  q_end = np.array([51] + list(range(31, 51)) + list(range(1, 6)) + [0] * 6,
-                   np.int32)
+  row_of, q_end = [], []
+  for row, ((n, first_end), pad) in enumerate(pack):
+    row_of += [row] * n + [0] * pad
+    q_end += list(range(first_end, first_end + n)) + [0] * pad
+  row_of, q_end = np.array(row_of, np.int32), np.array(q_end, np.int32)
   q = rng.randn(len(row_of), heads, h).astype(np.float32) / np.sqrt(h)
   want = _NumpyAttend(q, kp, vp, tables, row_of, q_end, page, window)
+  q[q_end == 0] = np.nan
   if window:
-    for row, narrowest in ((0, 51), (1, 31)):
+    for row, ((_, narrowest), _) in enumerate(pack):
       for lp in range(max(0, narrowest - window) // page):
         kp[tables[row, lp]] = vp[tables[row, lp]] = np.nan
-  got = np.asarray(rba.RaggedAttend(
-      jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
-      row_of, q_end, page_size=page, window=window, lowering=lowering))
-  np.testing.assert_allclose(got, want, atol=3e-5)
+  dtype = jnp.bfloat16 if pages == "bf16" else jnp.float32
+
+  def _Run(lowering):
+    return np.asarray(rba.RaggedAttend(
+        jnp.asarray(q), jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+        jnp.asarray(tables), row_of, q_end, page_size=page, window=window,
+        lowering=lowering))
+
+  got = _Run(lowering)
+  np.testing.assert_allclose(got, want, atol=tol)
+  assert np.all(got[q_end == 0] == 0.0)
+  if lowering == "pallas":
+    np.testing.assert_allclose(got, _Run("xla"), atol=tol)
+
+
+def test_block_rungs_hold_a_blocks_queries():
+  """The ladder is a function of Bq and a token's laid queries; a block
+  runs the first rung that holds its valid queries, none where it has
+  none."""
+  bq = rba.QueryBlock(4, 128, 128, jnp.bfloat16, jnp.bfloat16, grouped=True)
+  rungs = rba.BlockRungs(bq, rba.GroupLanes(7))
+  assert rungs == (8, 512) and bq == 512
+  queries = np.array([0, 1, 8, 9, 16, 64, 128, 129, 511, 512])
+  rows = rba.BlockRows(queries, rungs)
+  assert rows[0] == 0 and rows[2] == 8 and rows[-1] == 512
+  for n, r in zip(queries[1:], rows[1:]):
+    assert r in rungs and r >= n
+    assert not any(n <= lower < r for lower in rungs)
+  assert int(rba.BlockRows(8, rungs)) == 8
+  # the head-batched kernel: one query alone, or Bq
+  assert rba.BlockRungs(128) == (1, 128)
+  assert rba.BlockRows(np.array([1, 2, 128]), (1, 128)).tolist() == [1, 128,
+                                                                     128]
 
 
 def test_window_pages_of_a_block():
@@ -395,6 +464,30 @@ def test_engine_counts_expert_load(tiny):
   records = [r for r in eng.trace.Steps() if r.counters]
   assert records and records[-1].counters["window_pages_allocated"] > 0
   assert "moe_tokens_routed" in records[-1].counters
+
+
+def test_engine_counts_the_rows_a_block_runs(tiny):
+  """`attend_block_rows` beside the block-fill counters: a request alone is
+  one block a step, a chunk of 16 or 14 tokens (128 or 112 laid queries)
+  runs the block's bound and a decode token its group's 8 rows, by the
+  ladder the kernel itself reads (`BlockRungs`)."""
+  task, theta = tiny
+  eng = engine_lib.ServingLoop(task, theta, page_size=8, num_pages=48,
+                               max_batch=2, max_seq_len=128,
+                               prefill_token_budget=16)
+  handle = eng.Submit(np.arange(1, 31, dtype=np.int32), 4)
+  while not handle.done:
+    eng.StepOnce()
+  st = eng.Stats()
+  laid, own = eng._attend_laid, eng._attend_own
+  bq = eng._attend_bq
+  assert (laid, bq) == (8, 512)
+  assert rba.BlockRungs(bq, laid) == (laid, bq)
+  assert st["attend_query_blocks"] == st["steps"] == 5
+  assert st["attend_block_queries"] == (30 + 3) * own
+  assert st["attend_block_rows"] == 2 * bq + 3 * laid
+  assert (st["attend_block_queries"] * laid // own
+          <= st["attend_block_rows"] < st["attend_query_blocks"] * bq)
 
 
 def test_layer_pattern_is_data():
