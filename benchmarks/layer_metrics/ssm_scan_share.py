@@ -1,0 +1,7 @@
+"""The selective scan's kernels (`hybrid_cost.SSM_SCAN`) over the first
+device's busy time in the traced steps."""
+from benchmarks.harness import hybrid_cost
+
+
+def Read(run):
+  return hybrid_cost.KernelShare(run, hybrid_cost.SSM_SCAN)

@@ -37,6 +37,7 @@ import argparse
 import contextlib
 import dataclasses
 import json
+import itertools
 import math
 import os
 import shutil
@@ -100,6 +101,19 @@ class Size:
   grouped_h: int
   grouped_table_pages: int
   grouped_window: int
+  # differential attention over pages: (query heads, K heads) of `diff_h`
+  # and the window of the windowed case (REAL: Phi-4-mini-flash's 40 over 20
+  # of 64 and its 512-token layers); it packs as the grouped case does
+  diff_heads: tuple[int, int]
+  diff_h: int
+  diff_window: int
+  # the selective scan on the packed axis: channels, state indices, one-token
+  # rows and one chunk after them (REAL: Phi-4-mini-flash's 5120 x 16 under
+  # 64 slots + a 512 budget)
+  scan_channels: int
+  scan_state: int
+  scan_rows: int
+  scan_chunk: int
 
 
 REAL = Size(
@@ -111,7 +125,9 @@ REAL = Size(
     ssd_heads=8, ssd_state=128, ssd_chunk=64,
     ragged_rows=32, ragged_t=544, ragged_chunk=300,
     grouped_heads=(28, 4), grouped_h=128, grouped_table_pages=48,
-    grouped_window=4096)
+    grouped_window=4096,
+    diff_heads=(40, 20), diff_h=64, diff_window=512,
+    scan_channels=5120, scan_state=16, scan_rows=64, scan_chunk=512)
 
 TINY = Size(
     model=TINY_MODEL, train_layers=None, steps_per_loop=2, interpret=True,
@@ -122,7 +138,9 @@ TINY = Size(
     ssd_heads=2, ssd_state=8, ssd_chunk=8,
     ragged_rows=4, ragged_t=24, ragged_chunk=14,
     grouped_heads=(14, 2), grouped_h=128, grouped_table_pages=8,
-    grouped_window=20)
+    grouped_window=20,
+    diff_heads=(8, 4), diff_h=8, diff_window=20,
+    scan_channels=128, scan_state=8, scan_rows=4, scan_chunk=14)
 
 
 # -- kernels -----------------------------------------------------------------
@@ -155,12 +173,16 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
   """Every kernel case's operands from one PRNG key (jit this: one program)."""
   import jax
   import jax.numpy as jnp
+  import numpy as np
+  from lingvo_tpu.core import ragged as ragged_lib
   from lingvo_tpu.quant import kv as kv_quant
 
   s = size
   bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
   page = s.page_size
-  keys = iter(jax.random.split(key, 32))
+  # the first 32 as they always were; the cases added since draw from more
+  keys = itertools.chain(jax.random.split(key, 32),
+                         jax.random.split(jax.random.fold_in(key, 1), 32))
 
   def _Normal(shape, dtype=bf16, scale=1.0):
     return (scale * jax.random.normal(next(keys), shape, f32)).astype(dtype)
@@ -247,6 +269,38 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
           len(g_widths), -1).astype(i32),
       jnp.asarray(g_row_of + g_pad, i32), jnp.asarray(g_end + g_pad, i32))
 
+  # differential attention: the grouped case's pack and tables over pools of
+  # the K heads, and the same tokens as rows to write (one row starts a page,
+  # the chunk crosses several)
+  d_n, d_kv = s.diff_heads
+  d_pool = (g_pool[0], page, d_kv, s.diff_h)
+  diff = (_Normal((t, d_n, s.diff_h), scale=1.0 / math.sqrt(s.diff_h)),
+          _Normal(d_pool), _Normal(d_pool)) + grouped[3:]
+  d_q_pos = [g_cap - width - 3 * r for r, width in enumerate(g_widths)]
+  d_rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in (
+      ragged_lib.BuildRaggedRows(np.asarray(g_widths), np.asarray(d_q_pos),
+                                 t, max(g_widths)))))
+  diff_write = diff[1:3] + (_Normal((t, d_kv, s.diff_h)),
+                            _Normal((t, d_kv, s.diff_h)), grouped[3], d_rows)
+
+  # selective scan: one-token rows (two of them a request's first token),
+  # then a chunk that carries on from its slot's state, then padding
+  sc_widths = (1,) * s.scan_rows + (s.scan_chunk,)
+  sc_t = -(-(sum(sc_widths) + 3) // 8) * 8
+  sc_rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in (
+      ragged_lib.BuildRaggedRows(
+          np.asarray(sc_widths),
+          np.asarray([0, 0] + [7 + 3 * r for r in range(s.scan_rows - 1)]),
+          sc_t, s.scan_chunk))))
+  e_, n_ = s.scan_channels, s.scan_state
+  scan = (0.001 + 0.1 * jax.nn.sigmoid(_Normal((sc_t, e_), f32)),
+          _Normal((sc_t, e_), f32), _Normal((sc_t, n_), f32),
+          _Normal((sc_t, n_), f32),
+          -jnp.broadcast_to(jnp.arange(1, n_ + 1, dtype=f32)[:, None],
+                            (n_, e_)),
+          _Normal((e_,), f32), _Normal((len(sc_widths), n_, e_), f32),
+          sc_rows)
+
   # SSD scan: a cotangent, log-decay <= 0, write keys, read keys, values
   lead = (s.b, s.t, s.ssd_heads)
   ssd = (_Normal(lead + (s.h,), f32),
@@ -262,6 +316,9 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
       "ragged_tree": ragged[:1] + pools[False] + ragged[1:] + tree,
       "ragged_int8": ragged[:1] + pools[True] + ragged[1:] + chain,
       "ragged_grouped": grouped,
+      "diff_attend": diff,
+      "diff_write": diff_write,
+      "selective_scan": scan,
       "flash_decode": (
           _Normal((s.b, 1, s.n, s.h), scale=q_scale),
           _Normal((s.b, s.cache_len, s.n, s.h)),
@@ -281,10 +338,12 @@ def KernelCases(size: Size) -> list[KernelCase]:
   import jax
   import jax.numpy as jnp
   from lingvo_tpu.ops import block_decode
+  from lingvo_tpu.ops import diff_attend
   from lingvo_tpu.ops import flash_attention
   from lingvo_tpu.ops import flash_decode
   from lingvo_tpu.ops import fused_xent
   from lingvo_tpu.ops import ragged_block_attend
+  from lingvo_tpu.ops import selective_scan
   from lingvo_tpu.ops import ssd_scan
 
   s = size
@@ -335,6 +394,23 @@ def KernelCases(size: Size) -> list[KernelCase]:
             q, k, v, tables, row_of, q_end, page_size=page, window=window,
             **_Lowering(pallas)))
 
+  def _DiffAttend(window):
+    return lambda pallas: lambda q, k, v, tables, row_of, q_end: (
+        diff_attend.DiffAttend(
+            q, k, v, tables, row_of, q_end, 0.35, page_size=page,
+            window=window, **_Lowering(pallas)))
+
+  def _DiffWrite(pallas):
+    # all pages but the last: only the scatter's padding writes the trash page
+    return lambda k, v, k_new, v_new, tables, rows: tuple(
+        pool[:-1] for pool in diff_attend.WritePages(
+            k, v, k_new, v_new, tables, rows, **_Lowering(pallas)))
+
+  def _SelectiveScan(pallas):
+    return lambda delta, x, b, c, a, d, state, rows: (
+        selective_scan.SelectiveScan(delta, x, b, c, a, d, state, rows,
+                                     **_Lowering(pallas)))
+
   def _FlashDecode(pallas):
     return lambda q, k, v, step: flash_decode.FlashDecode(
         q, k, v, step, page_size=page, **_Lowering(pallas))
@@ -376,6 +452,11 @@ def KernelCases(size: Size) -> list[KernelCase]:
       KernelCase("ragged_attend_grouped", "ragged_grouped", _RaggedGrouped(0)),
       KernelCase("ragged_attend_grouped_window", "ragged_grouped",
                  _RaggedGrouped(s.grouped_window)),
+      KernelCase("diff_attend_full", "diff_attend", _DiffAttend(0)),
+      KernelCase("diff_attend_window", "diff_attend",
+                 _DiffAttend(s.diff_window)),
+      KernelCase("diff_write_pages", "diff_write", _DiffWrite),
+      KernelCase("selective_scan_packed", "selective_scan", _SelectiveScan),
       KernelCase("flash_decode", "flash_decode", _FlashDecode),
       KernelCase("fused_xent_fwd", "xent", _XentFwd),
       KernelCase("fused_xent_fwd_bwd", "xent", _XentFwdBwd),

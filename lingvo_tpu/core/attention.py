@@ -1158,3 +1158,221 @@ class ChunkwiseSelfAttention(MultiHeadedAttention):
     if paddings is not None:
       out = py_utils.ApplyPadding(paddings, out)
     return out, probs
+
+
+_PAIR_NORM_EPSILON = 1e-5       # differential attention's RMSNorm a pair
+
+
+class DifferentialAttention(base_layer.BaseLayer):
+  """Differential attention (arXiv:2410.05258) for `transformer.
+  BlockSequence`, with K and V of its own or another layer's.
+
+  x [.., D]; `num_heads` query heads of H in pairs (2j, 2j + 1),
+  `num_kv_heads` K heads of H in pairs (2i, 2i + 1) with i = j // (query
+  pairs / K pairs), V read as one head of 2H a K pair, V_i = [v_2i; v_2i+1]:
+
+      a1 = softmax(q_2j k_2i^T / sqrt(H)),  a2 = softmax(q_2j+1 k_2i+1^T / sqrt(H))
+      o_j = (1 - lambda_init) * RMSNorm_2H((a1 - lambda * a2) V_i)
+      out = W_o concat_j o_j
+      lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init,
+      lambda_init = 0.8 - 0.6 * exp(-0.3 * depth)
+
+  both softmaxes causal, and within `window` keys (the query's own
+  included) where the layer has one. No bias, no position encoding.
+  `depth` is the layer's index in the whole stack, handed to every call (a
+  scanned block's layers differ in nothing else).
+
+  kv_owner False: the layer has no K and V projection, writes no page and
+  reads the pages of the owning layer before it, through that layer's block
+  table (`shared.kv_pool`, `table`); in a whole-sequence forward it reads
+  that layer's K and V (`shared.key`, `shared.value`), which an owner with
+  `export_kv` hands on.
+
+  Serving: one pool of pages for all layers of the stack (`shared.kv_pool`,
+  [pages, P, num_kv_heads, H]); an owner writes its tokens' K and V through
+  its own table before it reads (`kv_write`), and the read is
+  ops/diff_attend.DiffAttend.
+  """
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim (set by the wrapping layer).")
+    p.Define("num_heads", 0, "Query heads (an even number).")
+    p.Define("num_kv_heads", 0, "K heads of dim_per_head (an even number).")
+    p.Define("dim_per_head", 0, "H (0 = input_dim / num_heads).")
+    p.Define("window", 0, "Keys a query sees, its own included; 0 = all.")
+    p.Define("kv_owner", True, "The layer projects and caches K and V.")
+    p.Define("export_kv", False,
+             "An owner hands its K and V to the layers after it in a "
+             "whole-sequence forward (shared.key / shared.value).")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    assert p.input_dim > 0 and p.num_heads % 2 == 0 and p.num_kv_heads % 2 == 0
+    assert (p.num_heads // 2) % (p.num_kv_heads // 2) == 0, (
+        p.num_heads, p.num_kv_heads)
+    d, n, nk = p.input_dim, p.num_heads, p.num_kv_heads
+    self._h = h = p.dim_per_head or d // n
+    init = p.params_init
+    self.CreateVariable("w_query", WeightParams((d, n, h), init, p.dtype))
+    if p.kv_owner:
+      self.CreateVariable("w_key", WeightParams((d, nk, h), init, p.dtype))
+      self.CreateVariable("w_value", WeightParams((d, nk, h), init, p.dtype))
+    self.CreateVariable("w_post", WeightParams(
+        (d, n // 2, 2 * h), init, p.dtype))
+    for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+      self.CreateVariable(name, WeightParams(
+          (h,), WeightInit.Gaussian(0.1), p.dtype))
+    # (1 + scale), as layers.LayerNorm stores it
+    self.CreateVariable("subln_scale", WeightParams(
+        (2 * h,), WeightInit.Constant(0.0), p.dtype))
+
+  def KvBytesPerToken(self, kv_cache_dtype=None) -> int:
+    """Bytes one token adds to the pages this layer OWNS."""
+    assert kv_cache_dtype in (None, "bfloat16"), kv_cache_dtype
+    if not self.p.kv_owner:
+      return 0
+    return 2 * self.p.num_kv_heads * self._h * jnp.dtype(
+        self.fprop_dtype).itemsize
+
+  def KvCacheDtype(self, kv_cache_dtype=None) -> str:
+    del kv_cache_dtype
+    return str(jnp.dtype(self.fprop_dtype))
+
+  def _Grouped(self):
+    """The attend op's view (ops/diff_attend.py): queries of 2H over K pairs
+    as heads of 2H."""
+    return (self.p.num_heads, self.p.num_kv_heads // 2, 2 * self._h)
+
+  def RaggedQueryBlock(self, page_size: int, kv_cache_dtype=None) -> int:
+    from lingvo_tpu.ops import ragged_block_attend
+    n, n_kv, h = self._Grouped()
+    return ragged_block_attend.QueryBlock(
+        n_kv, h, page_size, self.fprop_dtype, self.fprop_dtype,
+        grouped=ragged_block_attend.Grouped(n, n_kv))
+
+  def RaggedQueriesPerToken(self) -> tuple[int, int]:
+    from lingvo_tpu.ops import ragged_block_attend
+    n, n_kv, _ = self._Grouped()
+    return ragged_block_attend.GroupLanes(n // n_kv), n // n_kv
+
+  def RaggedBlockRows(self, page_size: int, kv_cache_dtype=None):
+    from lingvo_tpu.ops import ragged_block_attend
+    return functools.partial(
+        ragged_block_attend.BlockRows,
+        rungs=ragged_block_attend.BlockRungs(
+            self.RaggedQueryBlock(page_size, kv_cache_dtype),
+            self.RaggedQueriesPerToken()[0]))
+
+  def BlockDecodeEligible(self, page_size: int) -> bool:
+    """Whether the Pallas kernel serves this layer on a TPU (else the XLA
+    twin does, and the engine says 'dense')."""
+    if jax.default_backend() != "tpu":
+      return page_size > 0
+    from lingvo_tpu.ops import diff_attend
+    return diff_attend.SupportedOnTpu(page_size, self._h)
+
+  # -- the layer's arithmetic ------------------------------------------------
+
+  def _Lambda(self, th, depth):
+    f32 = lambda v: v.astype(jnp.float32)
+    init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+    lam = (jnp.exp(jnp.sum(f32(th.lambda_q1) * f32(th.lambda_k1)))
+           - jnp.exp(jnp.sum(f32(th.lambda_q2) * f32(th.lambda_k2))) + init)
+    return lam, init
+
+  def _Query(self, th, x):
+    q = jnp.einsum("...d,dnh->...nh", x, th.w_query)
+    return q * (self._h ** -0.5)
+
+  def _KeyValue(self, th, x):
+    return (jnp.einsum("...d,dnh->...nh", x, th.w_key),
+            jnp.einsum("...d,dnh->...nh", x, th.w_value))
+
+  def _Finish(self, th, diff, lam_init):
+    """diff: (a1 - lambda a2) V, [.., pairs, 2H] -> [.., D]."""
+    o = diff.astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                          + _PAIR_NORM_EPSILON)
+    o = o * (1.0 + th.subln_scale.astype(jnp.float32)) * (1.0 - lam_init)
+    return jnp.einsum("...jh,djh->...d", o.astype(self.fprop_dtype), th.w_post)
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=0):
+    """x: [B, T, D] -> ([B, T, D], shared): the whole-sequence forward, with
+    the [T, T] scores in memory."""
+    p = self.p
+    th = self.CastTheta(theta)
+    b, t, _ = x.shape
+    n, nk, h = p.num_heads, p.num_kv_heads, self._h
+    q = self._Query(th, x)                                       # [B,T,N,H]
+    if p.kv_owner:
+      k, v = self._KeyValue(th, x)
+      if p.export_kv:
+        shared = shared.Copy()
+        shared.key, shared.value = k, v
+    else:
+      k, v = shared.key, shared.value
+    group = (n // 2) // (nk // 2)               # query pairs a K pair
+    head = jnp.arange(n)
+    k_of = 2 * (head // 2 // group) + head % 2  # a query head's K head
+    s = jnp.einsum("btnh,bsnh->bnts", q, k[:, :, k_of]).astype(jnp.float32)
+    i = jnp.arange(t)
+    seen = i[None, :] <= i[:, None]
+    if p.window:
+      seen &= i[None, :] > i[:, None] - p.window
+    seen = seen[None, None]
+    if segment_ids is not None:
+      seen = seen & (segment_ids[:, None, :, None]
+                     == segment_ids[:, None, None, :])
+    if paddings is not None:
+      seen = seen & (paddings[:, None, None, :] < 0.5)
+    a = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+    wide = v.reshape(b, t, nk // 2, 2 * h)[:, :, head // (2 * group)]
+    o = jnp.einsum("bnts,bsnh->btnh", a.astype(v.dtype), wide)
+    o = o.astype(jnp.float32).reshape(b, t, n // 2, 2, 2 * h)
+    lam, lam_init = self._Lambda(th, depth)
+    out = self._Finish(th, o[:, :, :, 0] - lam * o[:, :, :, 1], lam_init)
+    if paddings is not None:
+      out = py_utils.ApplyPadding(paddings, out)
+    return out, shared
+
+  def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
+    """Nothing a slot: its pages are the stack's one pool's."""
+    del theta, num_slots
+    return NestedMap()
+
+  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=0):
+    """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own
+    block table or, where it owns no pages, the owning layer's."""
+    from lingvo_tpu.ops import diff_attend
+    p = self.p
+    th = self.CastTheta(theta)
+    pool = shared.kv_pool
+    np_total, page_size = pool.key.shape[:2]
+    b = table.shape[0]
+    pos = rows.pos.astype(jnp.int32)
+    valid = rows.valid
+    row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
+    tables = jnp.clip(table.astype(jnp.int32), 0, np_total - 1)
+    q = self._Query(th, x[0])                                    # [T, N, H]
+    lowering = "auto" if self.BlockDecodeEligible(page_size) else "xla"
+    if p.kv_owner:
+      # a token's K and V land through its row's table before the read
+      k_new, v_new = self._KeyValue(th, x[0])
+      with jax.named_scope("kv_write"):
+        key, value = diff_attend.WritePages(
+            pool.key, pool.value, k_new, v_new, tables, rows,
+            lowering=lowering)
+      pool = NestedMap(key=key, value=value)
+      shared = shared.Copy()
+      shared.kv_pool = pool
+    lam, lam_init = self._Lambda(th, depth)
+    with jax.named_scope(diff_attend.SCOPE):
+      diff = diff_attend.DiffAttend(
+          q, pool.key, pool.value, tables, row, jnp.where(valid, pos + 1, 0),
+          lam, page_size=page_size, window=p.window, lowering=lowering)
+    return self._Finish(th, diff, lam_init)[None], states, shared

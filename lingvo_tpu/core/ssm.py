@@ -394,3 +394,252 @@ class GatedSSMLayer(base_layer.BaseLayer):
     row = jnp.clip(rows.row_of.astype(jnp.int32), 0, x_rows.shape[0] - 1)
     col = jnp.clip(rows.col_of.astype(jnp.int32), 0, wmax - 1)
     return out_rows[row, col][None], new_states
+
+
+_DT_MIN, _DT_MAX = 1e-3, 1e-1     # step sizes at initialisation (Mamba-1)
+
+
+def _InverseSoftplus(x):
+  return x + jnp.log(-jnp.expm1(-x))
+
+
+class Mamba1Layer(base_layer.BaseLayer):
+  """Mamba-1 mixer (arXiv:2312.00752) for `transformer.BlockSequence`.
+
+  x [.., D], E = expand * D channels, N state indices, R the step size's
+  rank, K the convolution's width:
+
+      [u; z] = x W_in                                       D -> 2E
+      c_t = silu(b_conv + sum_{k<K} w_conv[k] * u_{t-K+1+k})  depthwise, causal
+      [r; B; C] = c W_x                                     E -> R + 2N
+      delta = softplus(r W_dt + b_dt)                       [E]
+      s_t = exp(delta_t (x) A) * s_{t-1} + (delta_t * c_t) (x) B_t,
+            A = -exp(a_log)                                 [N, E], f32
+      y_t = s_t C_t + d_skip * c_t
+      out = (y_t * silu(z_t)) W_out
+
+  What a sequence carries from token to token is `s` and the convolution's
+  last K - 1 inputs `u`. Serving keeps both a slot (`InitPagedStates`:
+  `scan` [slots, N, E] and `conv` [slots, K - 1, E], f32), zeroes them where
+  a row starts a request (`rows.row_q_pos == 0`) and carries them from one
+  chunk of a prompt to the next; they are leaves of the engine's states, so
+  the engine's slot gather / scatter (spill, restore) moves them with the
+  rest. The scan runs on the packed token axis (ops/selective_scan.py).
+
+  export_memory: the layer also hands `y_t` (before the gate) to the layers
+  after it, as `shared.memory` (a gated memory unit multiplies by it).
+
+  The published initialisation, so that state neither dies in ten tokens
+  nor saturates: A = -(1 .. N) on every channel, step sizes log-uniform in
+  [0.001, 0.1] (`b_dt` their inverse softplus), d_skip = 1.
+  """
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim (set by the wrapping layer).")
+    p.Define("expand", 2, "Channels E over the model dim.")
+    p.Define("state_dim", 16, "State indices N a channel.")
+    p.Define("conv_width", 4, "Taps K of the causal depthwise convolution.")
+    p.Define("dt_rank", 0, "Rank R of the step size (0 = ceil(D / 16)).")
+    p.Define("export_memory", False,
+             "Hand y_t (before the gate) on as shared.memory.")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    assert p.input_dim > 0 and p.conv_width > 1
+    d = p.input_dim
+    self._e = e = p.expand * d
+    self._r = r = p.dt_rank or -(-d // 16)
+    n, k = p.state_dim, p.conv_width
+    init = p.params_init
+    self.CreateVariable("w_in", WeightParams((d, 2 * e), init, p.dtype))
+    self.CreateVariable("conv_w", WeightParams(
+        (k, e), WeightInit.Uniform(k ** -0.5), p.dtype))
+    self.CreateVariable("conv_b", WeightParams(
+        (e,), WeightInit.Constant(0.0), p.dtype))
+    self.CreateVariable("w_x", WeightParams((e, r + 2 * n), init, p.dtype))
+    self.CreateVariable("w_dt", WeightParams(
+        (r, e), WeightInit.Uniform(r ** -0.5), p.dtype))
+    # overwritten at instantiation with the published initialisation
+    self.CreateVariable("b_dt", WeightParams(
+        (e,), WeightInit.Uniform(1.0), p.dtype))
+    self.CreateVariable("a_log", WeightParams(
+        (n, e), WeightInit.Constant(0.0), p.dtype))
+    self.CreateVariable("d_skip", WeightParams(
+        (e,), WeightInit.Constant(1.0), p.dtype))
+    self.CreateVariable("w_out", WeightParams((e, d), init, p.dtype))
+
+  def InstantiateVariables(self, key):
+    p = self.p
+    theta = super().InstantiateVariables(key)
+    n = p.state_dim
+    theta.a_log = jnp.broadcast_to(
+        jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None],
+        (n, self._e)).astype(p.dtype)
+    # b_dt drew uniform in [-1, 1]: to a log-uniform step size, and its
+    # inverse softplus
+    u = 0.5 * (theta.b_dt.astype(jnp.float32) + 1.0)
+    dt = jnp.exp(u * (jnp.log(_DT_MAX) - jnp.log(_DT_MIN)) + jnp.log(_DT_MIN))
+    theta.b_dt = _InverseSoftplus(dt).astype(p.dtype)
+    return theta
+
+  def StateBytesPerSlot(self) -> int:
+    """Scan state and convolution tail of one sequence, f32."""
+    return 4 * self._e * (self.p.state_dim + self.p.conv_width - 1)
+
+  # -- the layer's arithmetic ------------------------------------------------
+
+  def _InProj(self, th, x):
+    uz = jnp.einsum("...d,de->...e", x, th.w_in)
+    return uz[..., :self._e], uz[..., self._e:]
+
+  def _ScanInputs(self, th, conv):
+    """conv: the convolution's sum [.., E] f32 (bias not yet added) ->
+    (c, delta [.., E], B, C [.., N]), f32."""
+    n, r = self.p.state_dim, self._r
+    c = jax.nn.silu(conv + th.conv_b.astype(jnp.float32))
+    proj = jnp.einsum("...e,ef->...f", c.astype(self.fprop_dtype), th.w_x,
+                      preferred_element_type=jnp.float32)
+    dt = jnp.einsum("...r,re->...e", proj[..., :r].astype(self.fprop_dtype),
+                    th.w_dt, preferred_element_type=jnp.float32)
+    delta = jax.nn.softplus(dt + th.b_dt.astype(jnp.float32))
+    return c, delta, proj[..., r:r + n], proj[..., r + n:]
+
+  def _Finish(self, th, y, z, shared):
+    if self.p.export_memory:
+      shared = shared.Copy()
+      shared.memory = y.astype(self.fprop_dtype)
+    gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(self.fprop_dtype)
+    return jnp.einsum("...e,ed->...d", gated, th.w_out), shared
+
+  # -- whole sequences -------------------------------------------------------
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=None):
+    """x: [B, T, D] -> ([B, T, D], shared). A plain scan over T: the
+    whole-sequence forward is what tests and a reference-sized forward
+    use, not a training path that was tuned."""
+    del depth
+    if segment_ids is not None:
+      raise NotImplementedError(
+          "Mamba1Layer.FProp does not reset state between packed segments")
+    th = self.CastTheta(theta)
+    k = self.p.conv_width
+    u, z = self._InProj(th, x)
+    u32 = u.astype(jnp.float32)
+    t = x.shape[1]
+    padded = jnp.pad(u32, ((0, 0), (k - 1, 0), (0, 0)))
+    w = th.conv_w.astype(jnp.float32)
+    conv = sum(w[i] * padded[:, i:i + t] for i in range(k))
+    c, delta, b_t, c_t = self._ScanInputs(th, conv)
+    if paddings is not None:
+      delta = delta * (1.0 - paddings.astype(jnp.float32))[..., None]
+    a = -jnp.exp(th.a_log.astype(jnp.float32))
+
+    def _Token(s, xs):
+      d, cc, bb, rd = xs
+      s = jnp.exp(d[:, None, :] * a) * s + (d * cc)[:, None, :] * bb[:, :, None]
+      return s, jnp.sum(s * rd[:, :, None], axis=1)
+
+    s0 = jnp.zeros((x.shape[0],) + a.shape, jnp.float32)
+    with jax.named_scope("ssm_scan"):
+      _, ys = jax.lax.scan(_Token, s0, tuple(
+          jnp.moveaxis(v, 1, 0) for v in (delta, c, b_t, c_t)))
+    y = jnp.moveaxis(ys, 0, 1) + th.d_skip.astype(jnp.float32) * c
+    out, shared = self._Finish(th, y, z, shared)
+    if paddings is not None:
+      out = py_utils.ApplyPadding(paddings, out)
+    return out, shared
+
+  # -- continuous-batching serving -------------------------------------------
+
+  def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
+    del theta
+    assert num_slots > 0, "Mamba1Layer keeps a state a slot"
+    p = self.p
+    return NestedMap(
+        scan=jnp.zeros((num_slots, p.state_dim, self._e), jnp.float32),
+        conv=jnp.zeros((num_slots, p.conv_width - 1, self._e), jnp.float32))
+
+  def RaggedStep(self, theta, x, states, shared, rows, table=None,
+                 depth=None):
+    """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
+    ([1, T, D], new states, shared)."""
+    del table, depth
+    from lingvo_tpu.ops import selective_scan
+    th = self.CastTheta(theta)
+    k = self.p.conv_width
+    t = x.shape[1]
+    u, z = self._InProj(th, x[0])                               # [T, E]
+    u32 = u.astype(jnp.float32)
+    slots = states.conv.shape[0]
+    row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
+    col = rows.col_of.astype(jnp.int32)
+    fresh = rows.row_q_pos == 0
+    tail = jnp.where(fresh[:, None, None], 0.0, states.conv)   # [B, K-1, E]
+    w = th.conv_w.astype(jnp.float32)
+    conv = w[k - 1] * u32
+    for back in range(1, k):
+      # the input `back` tokens before: of this step where the row has it,
+      # else of the slot's tail
+      here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
+      held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
+      conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here, held)
+    c, delta, b_t, c_t = self._ScanInputs(th, conv)
+    y, scan = selective_scan.SelectiveScan(
+        delta, c, b_t, c_t, -jnp.exp(th.a_log.astype(jnp.float32)),
+        th.d_skip, states.scan, rows)
+    # the row's last K - 1 inputs, old tail and this step's tokens together
+    n = rows.row_len.astype(jnp.int32)[:, None]                  # [B, 1]
+    i = jnp.arange(k - 1, dtype=jnp.int32)[None]                 # [1, K-1]
+    at = n - (k - 1) + i                                         # in the row
+    cols = jnp.take_along_axis(
+        rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
+    old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
+                              axis=1)
+    new_tail = jnp.where((at >= 0)[..., None],
+                         u32[jnp.clip(cols, 0, t - 1)], old)
+    out, shared = self._Finish(th, y[None], z[None], shared)
+    return out, NestedMap(scan=scan, conv=new_tail), shared
+
+
+class GatedMemoryUnit(base_layer.BaseLayer):
+  """out = W_2(silu(W_1 x) * m_t), `m_t` the memory an earlier layer exported
+  for the same token (`shared.memory`, Mamba1Layer.export_memory): the mixer
+  of the cross-decoder's odd layers in arXiv:2507.06607. It keeps no state."""
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim (set by the wrapping layer).")
+    p.Define("memory_dim", 0, "Width of the memory it gates.")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    assert p.input_dim > 0 and p.memory_dim > 0
+    self.CreateVariable("w_1", WeightParams(
+        (p.input_dim, p.memory_dim), p.params_init, p.dtype))
+    self.CreateVariable("w_2", WeightParams(
+        (p.memory_dim, p.input_dim), p.params_init, p.dtype))
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=None):
+    del paddings, segment_ids, depth
+    th = self.CastTheta(theta)
+    gate = jax.nn.silu(jnp.einsum("...d,de->...e", x, th.w_1))
+    return jnp.einsum("...e,ed->...d", gate * shared.memory, th.w_2), shared
+
+  def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
+    del theta, num_slots
+    return NestedMap()
+
+  def RaggedStep(self, theta, x, states, shared, rows, table=None,
+                 depth=None):
+    del rows, table
+    out, shared = self.FProp(theta, x, shared, depth=depth)
+    return out, states, shared

@@ -38,6 +38,7 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
     p.Define("relu_dropout_prob", 0.0, "Dropout after the inner activation.")
     p.Define("norm_tpl", layers_lib.LayerNorm.Params(), "Norm template.")
     p.Define("add_skip_connection", True, "Residual connection.")
+    p.Define("has_bias", True, "Biases on the projections.")
     return p
 
   def __init__(self, params):
@@ -51,18 +52,21 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
         "ffn_in",
         layers_lib.ProjectionLayer.Params().Set(
             input_dim=p.input_dim, output_dim=p.hidden_dim,
-            activation="NONE", weight_split_dims_mapping=wsdm_in))
+            activation="NONE", has_bias=p.has_bias,
+            weight_split_dims_mapping=wsdm_in))
     if p.use_gated_activation:
       self.CreateChild(
           "ffn_gate",
           layers_lib.ProjectionLayer.Params().Set(
               input_dim=p.input_dim, output_dim=p.hidden_dim,
-              activation="NONE", weight_split_dims_mapping=wsdm_in))
+              activation="NONE", has_bias=p.has_bias,
+              weight_split_dims_mapping=wsdm_in))
     self.CreateChild(
         "ffn_out",
         layers_lib.ProjectionLayer.Params().Set(
             input_dim=p.hidden_dim, output_dim=p.input_dim,
-            activation="NONE", weight_split_dims_mapping=wsdm_out))
+            activation="NONE", has_bias=p.has_bias,
+            weight_split_dims_mapping=wsdm_out))
     self.CreateChild("dropout", layers_lib.DeterministicDropoutLayer.Params())
 
   def FProp(self, theta, inputs, paddings=None):
@@ -776,3 +780,283 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
         lambda new, old: jnp.concatenate([new, old[reps:]], axis=0),
         new_prefix, cached_states.body)
     return out, NestedMap(body=new_body)
+
+
+class SharedStateLayer(base_layer.BaseLayer):
+  """h += Mixer(LN(h)), then the feed-forward block, for a mixer of
+  `BlockSequence`: one that reads or writes what the stack's layers share
+  (`shared`: the one pool of pages, a memory an earlier layer exported, in a
+  whole-sequence forward an earlier layer's K and V). The mixer's contract
+  (ssm.Mamba1Layer, ssm.GatedMemoryUnit, attention.DifferentialAttention):
+  `FProp(theta, x, shared, paddings, segment_ids, depth) -> (out, shared)`,
+  `InitPagedStates(theta, num_slots)`, and `RaggedStep(theta, x, states,
+  shared, rows, table, depth) -> (out, states, shared)`."""
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim.")
+    p.Define("mixer_tpl", None, "The mixer's template.")
+    p.Define("norm_tpl", layers_lib.LayerNorm.Params(), "The mixer's norm.")
+    p.Define("tr_fflayer_tpl", TransformerFeedForwardLayer.Params(),
+             "Feed-forward block (with its own norm and residual).")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=p.input_dim))
+    self.CreateChild("atten", p.mixer_tpl.Copy().Set(input_dim=p.input_dim))
+    self.CreateChild("fflayer",
+                     p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim))
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=0):
+    with jax.named_scope("norm"):
+      normed = self.ln.FProp(theta.ln, x)
+    with jax.named_scope("atten"):
+      out, shared = self.atten.FProp(theta.atten, normed, shared,
+                                     paddings=paddings,
+                                     segment_ids=segment_ids, depth=depth)
+      x = x + out
+    return self.fflayer.FProp(theta.fflayer, x, paddings), shared
+
+  def InitPagedStates(self, theta, num_slots):
+    return self.atten.InitPagedStates(theta.atten, num_slots)
+
+  def RaggedStep(self, theta, x, states, shared, rows, table, depth):
+    with jax.named_scope("norm"):
+      normed = self.ln.FProp(theta.ln, x)
+    with jax.named_scope("atten"):
+      out, states, shared = self.atten.RaggedStep(
+          theta.atten, normed, states, shared, rows, table=table, depth=depth)
+      x = x + out
+    return self.fflayer.FProp(theta.fflayer, x), states, shared
+
+
+class BlockSequence(base_layer.BaseLayer):
+  """A stack told as data: blocks in sequence, each a short list of layers
+  repeated some number of times and run as one scan over its stacked
+  weights (a block of one repeat is a scan of one trip). A depth with no
+  period is a sequence of blocks that each have one; what is left over
+  between two periodic stretches is a block of its own.
+
+  The layers are `SharedStateLayer`s and share, from the first block to the
+  last, a `shared` map that every mixer may read and replace:
+
+  - serving (`RaggedStep`): `kv_pool`, ONE pool of uniform pages
+    `[pages, P, KV heads, H]` for every attention layer that owns pages. An
+    owning layer writes and reads through its own block table
+    (`block_tables[k]` for the k-th owner of the stack, in order); a layer
+    that owns none reads the pages and the table of the nearest owner
+    before it, and allocates and writes nothing. `memory`, `[1, T, E]`,
+    what a layer exported for the same tokens (zeros until one has).
+  - whole sequences (`FProp`): `memory`, and `key` / `value` of the layer
+    that exports them.
+
+  Slot state (`InitPagedStates`): every layer's own leaves, stacked over its
+  block's repeats, `blocks[b].x_layers[j].<leaf>` `[repeats, slots, ...]`;
+  they are the scan's inputs and outputs, the pool its carry.
+
+  Serves through `RaggedStep` only (the engine's one packed program); the
+  dense decode contracts (`ExtendStep`, `Prefill`, `PagedStep`) and
+  speculative column states are not built for it.
+  """
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim (propagated to the layers).")
+    p.Define("blocks", None,
+             "[(list of SharedStateLayer Params, repeats)], in stack order.")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    assert p.blocks
+    self._repeats = [int(reps) for _, reps in p.blocks]
+    for b, (tpls, _) in enumerate(p.blocks):
+      self.CreateChild(f"block_{b}", StackedTransformerLayers.Params().Set(
+          num_layers=len(tpls), input_dim=p.input_dim, final_ln=False,
+          layer_tpls=[t.Copy() for t in tpls]))
+    self._bodies = [getattr(self, f"block_{b}").x_layers
+                    for b in range(len(p.blocks))]
+    # a layer's place: its depth in the stack, and which block table it
+    # uses: its own (the k-th owner's) or the nearest owner's before it
+    self._first_depth, self._table_of, depth, owners = [], [], 0, 0
+    for layers, reps in zip(self._bodies, self._repeats):
+      self._first_depth.append(depth)
+      own = [getattr(l.atten.p, "kv_owner", None) for l in layers]
+      per = sum(bool(o) for o in own)
+      tables, k = [], 0
+      for o in own:
+        if o:
+          tables.append(("own", owners, k))
+          k += 1
+        elif o is None:
+          tables.append(None)
+        else:
+          assert owners + k > 0 and (reps == 1 or per == 0), (
+              "a layer that owns no pages reads the nearest owner before "
+              "it, which a repeated block cannot hold beside it")
+          tables.append(("read", owners + k - 1, None))
+      self._table_of.append(tables)
+      owners += per * reps
+      depth += len(layers) * reps
+    self._num_owners = owners
+    # width of the memory a layer exports (0: none does)
+    self._memory_dim = max(
+        [l.atten._e for layers in self._bodies for l in layers
+         if getattr(l.atten.p, "export_memory", False)], default=0)
+
+  def InstantiateVariables(self, key):
+    if self._path is None:
+      self.FinalizePaths()
+    return NestedMap({
+        f"block_{b}": base_layer.StackedInstantiateVariables(
+            getattr(self, f"block_{b}"), key, reps)
+        for b, reps in enumerate(self._repeats)})
+
+  def VariableSpecs(self):
+    return NestedMap({
+        f"block_{b}": base_layer.StackedVariableSpecs(
+            getattr(self, f"block_{b}"), reps)
+        for b, reps in enumerate(self._repeats)})
+
+  def MixerLayers(self):
+    """[(mixer, how many layers of the stack are it)] for the mixers that
+    keep decode state, pages or a slot's (serving/spec_decode.MixerLayers)."""
+    return [(l.atten, reps) for layers, reps in zip(self._bodies,
+                                                    self._repeats)
+            for l in layers if hasattr(l.atten, "StateBytesPerSlot")
+            or hasattr(l.atten, "KvBytesPerToken")]
+
+  def PageWindows(self):
+    """The window of every layer that OWNS pages (0 = full), in stack
+    order: one block table each, all out of one pool of uniform pages
+    (serving/kv_cache.KindPages)."""
+    return [int(l.atten.p.window) for layers, reps in zip(
+        self._bodies, self._repeats) for _ in range(reps) for l in layers
+            if getattr(l.atten.p, "kv_owner", False)]
+
+  def SharedKvReadLayers(self) -> int:
+    """Layers that read pages they do not own."""
+    return sum(reps for layers, reps in zip(self._bodies, self._repeats)
+               for l in layers if getattr(l.atten.p, "kv_owner", None) is False)
+
+  def _Scan(self, b, theta, x, shared, per_repeat, call):
+    """Block b as one scan over its repeats: `call(layer, theta_j, x,
+    shared, j-th entry of every per-repeat tree, depth) -> (x, out_j,
+    shared)`; returns (x, shared, [out_j stacked over repeats])."""
+    layers = self._bodies[b]
+    first = self._first_depth[b]
+
+    def _Body(carry, per):
+      x, shared = carry
+      theta_i, idx, extra = per
+      outs = []
+      for j, layer in enumerate(layers):
+        depth = first + idx * len(layers) + j
+        x, out, shared = call(layer, theta_i.x_layers[j], x, shared, j,
+                              extra, depth)
+        outs.append(out)
+      return (x, shared), outs
+
+    (x, shared), outs = jax.lax.scan(
+        _Body, (x, shared),
+        (theta[f"block_{b}"], jnp.arange(self._repeats[b]),
+         per_repeat))
+    return x, shared, outs
+
+  def FProp(self, theta, inputs, paddings=None, aux_vecs=None,
+            aux_paddings=None, segment_ids=None, token_ids=None):
+    del aux_vecs, aux_paddings, token_ids
+    bsz, t = inputs.shape[:2]
+    shared = NestedMap()
+    if self._memory_dim:
+      shared.memory = jnp.zeros((bsz, t, self._memory_dim), inputs.dtype)
+    kv = [l.atten for layers in self._bodies for l in layers
+          if getattr(l.atten.p, "export_kv", False)]
+    if kv:
+      shape = (bsz, t, kv[0].p.num_kv_heads, kv[0]._h)
+      shared.key = shared.value = jnp.zeros(shape, inputs.dtype)
+
+    def _Call(layer, theta_j, x, shared, j, extra, depth):
+      del j, extra
+      x, shared = layer.FProp(theta_j, x, shared, paddings=paddings,
+                              segment_ids=segment_ids, depth=depth)
+      return x, None, shared
+
+    x = inputs
+    for b in range(len(self._bodies)):
+      x, shared, _ = self._Scan(b, theta, x, shared, None, _Call)
+    return x
+
+  def InitPagedStates(self, theta, num_pages, page_size, num_slots=0,
+                      kv_cache_dtype=None):
+    if kv_cache_dtype not in (None, "bfloat16"):
+      raise NotImplementedError(
+          f"kv_cache_dtype {kv_cache_dtype!r}: the differential attend "
+          "kernel reads float pages")
+    owners = [l.atten for layers in self._bodies for l in layers
+              if getattr(l.atten.p, "kv_owner", False)]
+    assert owners, "BlockSequence serves a stack in which some layer owns pages"
+    shapes = {(a.p.num_kv_heads, a._h) for a in owners}
+    assert len(shapes) == 1, f"one pool, one page shape: {shapes}"
+    (nk, h), = shapes
+    dtype = owners[0].fprop_dtype
+    states = NestedMap(kv_pool=NestedMap(
+        key=jnp.zeros((num_pages, page_size, nk, h), dtype),
+        value=jnp.zeros((num_pages, page_size, nk, h), dtype)))
+    states.blocks = []
+    for b, layers in enumerate(self._bodies):
+      def _One(theta_i, layers=layers):
+        return [l.InitPagedStates(theta_i.x_layers[j], num_slots)
+                for j, l in enumerate(layers)]
+      states.blocks.append(jax.vmap(_One)(theta[f"block_{b}"]))
+    return states
+
+  def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
+                 ssm_col_states: bool = False):
+    """block_tables: [owners, B, t_pages], one table an owning layer in
+    stack order (KindPages.tables)."""
+    if ssm_col_states:
+      raise NotImplementedError(
+          "BlockSequence keeps no per-column states: no draft source")
+    assert block_tables.shape[0] == self._num_owners, (
+        block_tables.shape, self._num_owners)
+    shared = NestedMap(kv_pool=cached_states.kv_pool)
+    if self._memory_dim:
+      shared.memory = jnp.zeros(inputs.shape[:2] + (self._memory_dim,),
+                                inputs.dtype)
+    x = inputs
+    new_states = NestedMap(blocks=[])
+    for b, layers in enumerate(self._bodies):
+      tables_of = self._table_of[b]
+      reps = self._repeats[b]
+      own = [t for t in tables_of if t and t[0] == "own"]
+      # the block's own tables by repeat, [repeats, owners a repeat, B, tp]
+      mine = None
+      if own:
+        first = own[0][1]
+        mine = block_tables[first:first + reps * len(own)].reshape(
+            (reps, len(own)) + block_tables.shape[1:])
+
+      def _Call(layer, theta_j, x, shared, j, extra, depth,
+                tables_of=tables_of):
+        states_i, mine_i = extra
+        place = tables_of[j]
+        table = None
+        if place is not None:
+          table = (mine_i[place[2]] if place[0] == "own"
+                   else block_tables[place[1]])
+        x, ns, shared = layer.RaggedStep(theta_j, x, states_i[j], shared,
+                                         rows, table, depth)
+        return x, ns, shared
+
+      x, shared, outs = self._Scan(
+          b, theta, x, shared, (cached_states.blocks[b], mine), _Call)
+      new_states.blocks.append(outs)
+    new_states.kv_pool = shared.kv_pool
+    return x, new_states

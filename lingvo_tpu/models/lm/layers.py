@@ -18,6 +18,25 @@ from lingvo_tpu.core import transformer as transformer_lib
 from lingvo_tpu.core.nested_map import NestedMap
 
 
+def KindBlocks(kinds) -> list[tuple[list, int]]:
+  """A list of layer kinds as blocks in sequence, [(a block's kinds,
+  repeats)]: from each position the shortest stretch that repeats at least
+  twice, taken as often as it repeats (the longest run wins, the shorter
+  period among equals); where nothing repeats, the one layer alone."""
+  kinds, out, i = list(kinds), [], 0
+  while i < len(kinds):
+    best = (1, 1)
+    for k in range(1, (len(kinds) - i) // 2 + 1):
+      reps = 1
+      while kinds[i + reps * k:i + (reps + 1) * k] == kinds[i:i + k]:
+        reps += 1
+      if reps > 1 and k * reps > best[0] * best[1]:
+        best = (k, reps)
+    out.append((kinds[i:i + best[0]], best[1]))
+    i += best[0] * best[1]
+  return out
+
+
 class TransformerLm(base_model.BaseTask):
   """Decoder-only transformer LM.
 
@@ -57,6 +76,18 @@ class TransformerLm(base_model.BaseTask):
         "the mixer (pure-SSM stack, pageless serving). Under "
         "use_repeat_layer, num_layers must divide by n (the block is the "
         "scanned repeat body).")
+    p.Define(
+        "layer_kinds", None,
+        "The stack as data, one name a layer: 'mamba' / 'mamba_export' "
+        "(mixer_tpl, an ssm.Mamba1Layer; the second also exports its scan "
+        "output as the stack's memory), 'window' / 'full' / 'cross' "
+        "(atten_tpl, an attention.DifferentialAttention: within "
+        "sliding_window_size, over everything, or over everything through "
+        "the pages of the 'full' layer before it, with no K and V of its "
+        "own), 'gmu' (ssm.GatedMemoryUnit over that memory). Stretches "
+        "that repeat are scanned, what lies between them is a block of "
+        "its own (transformer.BlockSequence); no layer carries a position. "
+        "None = the layouts below.")
     p.Define("use_rotary", True, "RoPE instead of absolute positions.")
     p.Define("rope_theta", 1e4,
              "RoPE base where a layer rotates. KV heads and a head size "
@@ -158,6 +189,60 @@ class TransformerLm(base_model.BaseTask):
           layers_lib.PositionalEmbeddingLayer.Params().Set(
               embedding_dim=p.model_dim))
 
+    if p.layer_kinds is not None:
+      self.CreateChild("stack", self._KindStack())
+    else:
+      self._CreateLayoutStack()
+    if p.softmax_num_sampled > 0:
+      assert p.xent_block_size == 0, (
+          "sampled softmax and the fused blockwise xent are both "
+          "no-[B,T,V]-logits training paths; pick one")
+      assert p.label_smoothing == 0.0, (
+          "label_smoothing is not supported with the sampled softmax "
+          "(the sampled xent has no smoothing term)")
+      self.CreateChild(
+          "sampled_softmax",
+          layers_lib.SampledSoftmax.Params().Set(
+              input_dim=p.model_dim, num_classes=p.vocab_size,
+              num_sampled=p.softmax_num_sampled))
+    self.CreateChild(
+        "final_ln",
+        (p.norm_tpl or layers_lib.LayerNorm.Params()).Copy().Set(
+            input_dim=p.model_dim))
+
+  def _KindStack(self):
+    """Params of the stack `layer_kinds` describes."""
+    from lingvo_tpu.core import ssm as ssm_lib
+    p = self.p
+    assert len(p.layer_kinds) == p.num_layers, (p.layer_kinds, p.num_layers)
+    assert p.mixer_tpl is not None and p.atten_tpl is not None
+    assert p.num_experts == 0 and p.expert_ffn_tpl is None
+    assert not p.bidirectional
+    atten = p.atten_tpl.Copy().Set(num_heads=p.num_heads)
+    mixers = {
+        "mamba": p.mixer_tpl.Copy().Set(export_memory=False),
+        "mamba_export": p.mixer_tpl.Copy().Set(export_memory=True),
+        "window": atten.Copy().Set(window=p.sliding_window_size),
+        "full": atten.Copy().Set(window=0, export_kv=True),
+        "cross": atten.Copy().Set(window=0, kv_owner=False),
+        "gmu": ssm_lib.GatedMemoryUnit.Params().Set(
+            memory_dim=p.mixer_tpl.expand * p.model_dim),
+    }
+    assert p.sliding_window_size > 0 or "window" not in p.layer_kinds
+    layer = transformer_lib.SharedStateLayer.Params()
+    layer.tr_fflayer_tpl.Set(
+        hidden_dim=p.hidden_dim, activation="SILU", use_gated_activation=True,
+        has_bias=False, residual_dropout_prob=p.residual_dropout_prob)
+    if p.norm_tpl is not None:
+      layer.norm_tpl = p.norm_tpl.Copy()
+      layer.tr_fflayer_tpl.norm_tpl = p.norm_tpl.Copy()
+    blocks = [([layer.Copy().Set(mixer_tpl=mixers[kind]) for kind in kinds],
+               reps) for kinds, reps in KindBlocks(p.layer_kinds)]
+    return transformer_lib.BlockSequence.Params().Set(
+        input_dim=p.model_dim, blocks=blocks)
+
+  def _CreateLayoutStack(self):
+    p = self.p
     layer_body = transformer_lib.TransformerLayer.Params().Set(
         input_dim=p.model_dim, num_heads=p.num_heads,
         hidden_dim=p.hidden_dim, mask_self_atten=not p.bidirectional)
@@ -295,22 +380,6 @@ class TransformerLm(base_model.BaseTask):
               num_layers=p.num_layers, input_dim=p.model_dim,
               transformer_layer_params_tpl=ssm_body or layer_body,
               final_ln=False))
-    if p.softmax_num_sampled > 0:
-      assert p.xent_block_size == 0, (
-          "sampled softmax and the fused blockwise xent are both "
-          "no-[B,T,V]-logits training paths; pick one")
-      assert p.label_smoothing == 0.0, (
-          "label_smoothing is not supported with the sampled softmax "
-          "(the sampled xent has no smoothing term)")
-      self.CreateChild(
-          "sampled_softmax",
-          layers_lib.SampledSoftmax.Params().Set(
-              input_dim=p.model_dim, num_classes=p.vocab_size,
-              num_sampled=p.softmax_num_sampled))
-    self.CreateChild(
-        "final_ln",
-        (p.norm_tpl or layers_lib.LayerNorm.Params()).Copy().Set(
-            input_dim=p.model_dim))
 
   def _PatternPeriod(self):
     """[(windowed, rotates)] over the shortest period of the two layouts;

@@ -46,6 +46,16 @@ ENGINE_COUNTER_KEYS = (
     # got any token. All zero on a stack without expert layers.
     "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
     "moe_experts_active",
+    # a stack with slot-state mixers (core/ssm.Mamba1Layer) and layers that
+    # read pages they do not own (transformer.BlockSequence): tokens that
+    # went through the scan of every such mixer and rows whose state
+    # advanced, counted when a step is dispatched; packed tokens that are
+    # neither a row's decode token nor the last token of its prompt, for
+    # which the layers past the last page-owning one compute what nothing
+    # reads. All zero on a stack without them. Beside them in Stats() and in
+    # a step's record, not counters: `state_slots_in_use` and
+    # `shared_kv_read_layers`.
+    "ssm_tokens", "ssm_rows", "cross_tokens_unread",
 )
 
 # Static engine configuration facts (set once at construction).
@@ -66,13 +76,15 @@ ENGINE_STATS_REQUIRED = frozenset(
     + ("accepted_len_hist", "accepted_depth_hist"))
 
 # Keys present only under specific configurations:
-#   state_slots — stacks with O(1)-state mixers
+#   state_slots, state_slots_in_use, shared_kv_read_layers — stacks with
+#                 O(1)-state mixers
 #   spec        — engines with a draft source
 #   trace       — engines with tracing enabled (the default)
 #   compile     — per-compiled-program records (observe/profile.py)
 #   watchdog    — engines with a stall watchdog (observe/watchdog.py)
 ENGINE_STATS_OPTIONAL = frozenset(
-    {"state_slots", "spec", "trace", "compile", "watchdog"})
+    {"state_slots", "state_slots_in_use", "shared_kv_read_layers", "spec",
+     "trace", "compile", "watchdog"})
 
 
 def ValidateEngineStats(stats: dict) -> dict:

@@ -1,0 +1,386 @@
+"""Differential attention over the serving engine's pages (arXiv:2410.05258).
+
+Query heads come in pairs `(2j, 2j + 1)`, K heads in pairs `(2i, 2i + 1)`
+with `i = j // (pairs / K pairs)`, and V is read as one head of twice the
+head size a K pair, `V_i = [v_2i; v_2i+1]`:
+
+    a1 = softmax(q_2j   k_2i^T),   a2 = softmax(q_2j+1 k_2i+1^T)
+    o_j = (a1 - lam * a2) V_i = a1 V_i - lam * (a2 V_i)
+
+Both softmaxes are causal, and windowed where the layer is. q arrives
+pre-scaled.
+
+As grouped-query attention: a K pair `[k_2i; k_2i+1]` is one vector of 2H
+numbers a token, and so is `V_i`. A query head padded with zeros to 2H (its
+own half kept: the first for `q_2j`, the second for `q_2j+1`) has with that
+vector exactly its score against its own K head. So the 2 * pairs padded
+queries over the Nk / 2 wide heads are plain grouped-query attention with two
+softmaxes a pair, each with its own output `a V_i`; the difference of a
+pair's two outputs is taken after it. Both lowerings run it so:
+
+- `_XlaDiffAttend`, the CPU serving path and the twin: ops/
+  ragged_block_attend's XLA lowering over the pool seen `[NP, P, Nk / 2,
+  2H]`.
+- `_PallasDiffAttend`, the chip's. A pool `[NP, P, Nk, H]` with H under the
+  lane width lies on the chip with the TOKENS on the lanes (the compiler's
+  layout of that shape: a page is `[Nk, H, P]` there, and any view with H or
+  2H on the lanes is a copy of the whole pool, every layer). So the kernel
+  takes a page as it lies, `[Nk * H, P]`: rows `[2H * i, 2H * (i + 1))` are
+  `[k_2i; k_2i+1]^T`, the K pair transposed, whole tiles. A block's scores
+  are then the plain product `[rows, 2H] x [2H, P]` and its output `[rows,
+  P] x [2H, P]^T`, with no strided load and nothing re-laid out. Block
+  descriptors, the page walk (dead pages clamp, a window's first page), the
+  block rungs and the `jit` round the call are ops/ragged_block_attend's
+  grouped kernel's; the kernel's name in a trace is `diff_attend`.
+
+What the padded form costs beside a kernel that kept H-wide queries and
+subtracted inside: the zero half of every score product, and twice the
+output rows across HBM (PERF.md section 7 sizes both).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from lingvo_tpu.ops import ragged_block_attend as rba
+from lingvo_tpu.ops.flash_attention import LANES, NEG_INF, SUBLANES
+from lingvo_tpu.ops.flash_decode import _Finish
+
+SCOPE = "diff_attend"
+
+
+def PaddedQueries(q):
+  """[T, Nq, H] -> [T, Nq, 2H]: an even head in the first half, an odd one
+  in the second, zeros in the other."""
+  even = (jnp.arange(q.shape[1]) % 2 == 0)[None, :, None]
+  zero = jnp.zeros_like(q)
+  return jnp.concatenate([jnp.where(even, q, zero),
+                          jnp.where(even, zero, q)], axis=-1)
+
+
+def WidePages(pool):
+  """[NP, P, Nk, H] -> [NP, P, Nk / 2, 2H]: a pair of heads as one."""
+  np_total, page, nk, h = pool.shape
+  assert nk % 2 == 0, pool.shape
+  return pool.reshape(np_total, page, nk // 2, 2 * h)
+
+
+def SupportedOnTpu(page_size: int, h: int) -> bool:
+  """Mosaic's tiling: a page's tokens on whole lanes, a K pair's 2H rows on
+  whole 16-bit sublane tiles."""
+  return page_size % LANES == 0 and (2 * h) % (2 * SUBLANES) == 0
+
+
+def _XlaDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
+                   page_size: int, window: int):
+  return rba.RaggedAttend(
+      q2, WidePages(k_pool), WidePages(v_pool), block_tables, row_of, q_end,
+      page_size=page_size, window=window, lowering="xla")
+
+
+def _TransposedPagesKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
+                           first_ref, *rest, page_size: int, t_pages: int,
+                           window: int, heads: int, rungs: tuple[int, ...]):
+  """The (query block, logical page) program of rba._GroupedAttendKernel over
+  pages that lie transposed, `[heads * h, P]`: head g's keys are the rows
+  `[g * h, (g + 1) * h)`, whole. q and the output `[T' + Bq, heads * h]`
+  f32 in HBM, a head's queries a lane slice; a block runs the first of
+  `rungs` that holds its valid queries."""
+  i = pl.program_id(0)
+  j = pl.program_id(1)
+  page = j
+  if window:
+    page = rest[0][i] + j
+    rest = rest[1:]
+  q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
+  h = qb.shape[1] // heads
+  nv = n_ref[i]
+  first = pl.multiple_of(first_ref[i], SUBLANES)
+
+  def _Copy(src, dst):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+  def _Block(rows):
+    held = pl.ds(0, rows)
+    window_q = pl.ds(first, rows)
+
+    @pl.when(j == 0)
+    def _Init():
+      _Copy(q_hbm.at[window_q], qb.at[held])
+      qh[held] = qb[held].astype(qh.dtype)
+      mb[:, held] = jnp.full((heads, rows, LANES), NEG_INF, mb.dtype)
+      lb[:, held] = jnp.zeros((heads, rows, LANES), lb.dtype)
+      accb[held] = jnp.zeros((rows, heads * h), accb.dtype)
+
+    @pl.when(page <= last_ref[i])
+    def _Accumulate():
+      slot = page * page_size + jax.lax.broadcasted_iota(
+          jnp.int32, (1, page_size), 1)                       # [1, P]
+      ends = cols_ref[0, held][:, 0:1]                        # [rows, 1]
+      keep = slot < ends
+      if window:
+        keep &= slot >= ends - window
+      for g in range(heads):
+        lanes = pl.ds(g * h, h)
+        m, l, acc = rba._BlockPageAttend(
+            qh[held, lanes], k_ref[0, lanes, :], v_ref[0, lanes, :], keep,
+            mb[g, held, :1], lb[g, held, :1], accb[held, lanes],
+            (((1,), (0,)), ((), ())), (((1,), (1,)), ((), ())))
+        mb[g, held] = jnp.broadcast_to(m, (rows, LANES))
+        lb[g, held] = jnp.broadcast_to(l, (rows, LANES))
+        accb[held, lanes] = acc
+
+    @pl.when(j == t_pages - 1)
+    def _Emit():
+      for g in range(heads):
+        lanes = pl.ds(g * h, h)
+        qb[held, lanes] = _Finish(lb[g, held, :1], accb[held, lanes],
+                                  qb.dtype)
+      _Copy(qb.at[held], out_hbm.at[window_q])
+
+  below = 0
+  for rows in rungs:
+    pl.when((nv > below) & (nv <= rows))(functools.partial(_Block, rows))
+    below = rows
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "heads", "window", "grid", "rungs", "interpret"))
+def _TransposedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
+                    heads: int, window: int, grid: tuple[int, int],
+                    rungs: tuple[int, ...], interpret: bool):
+  """_TransposedPagesKernel over its grid. q: [T' + Bq, heads * h] f32;
+  cols: [NB, Bq, 4]; pages [NP, heads * h, P]. A `jit` of its own and the
+  scope inside it, as rba._GroupedCall and for its reasons."""
+  bq = cols.shape[1]
+  h = q.shape[1] // heads
+
+  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *more):
+    page = more[-1][i] + j if window else j
+    return (tables_ref[row_ref[i], jnp.minimum(page, last_ref[i])], 0, 0)
+
+  def _ColsIdx(i, j, row_ref, last_ref, src_ref, *_):
+    return (src_ref[i], 0, 0)
+
+  hbm = pl.BlockSpec(memory_space=pl.ANY)
+  with jax.named_scope(SCOPE):
+    return pl.pallas_call(
+        functools.partial(_TransposedPagesKernel, page_size=page_size,
+                          t_pages=grid[1], window=window, heads=heads,
+                          rungs=rungs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=[
+                hbm,
+                pl.BlockSpec((1, bq, 4), _ColsIdx),
+                pl.BlockSpec((1, heads * h, page_size), _PageIdx),
+                pl.BlockSpec((1, heads * h, page_size), _PageIdx),
+                hbm,
+            ],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((bq, heads * h), jnp.float32),
+                pltpu.VMEM((bq, heads * h), k_pages.dtype),
+                pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, heads * h), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        input_output_aliases={len(prefetch) + 4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, q, cols, k_pages, v_pages, jnp.zeros(q.shape, jnp.float32))
+
+
+def AsItLies(pool):
+  """[NP, P, Nk, H] -> [NP, Nk * H, P]: on the chip the same bytes."""
+  np_total, page, nk, h = pool.shape
+  return pool.transpose(0, 2, 3, 1).reshape(np_total, nk * h, page)
+
+
+def _FromAsItLies(pages, nk: int):
+  np_total, rows, page = pages.shape
+  return pages.reshape(np_total, nk, rows // nk, page).transpose(0, 3, 1, 2)
+
+
+def _WriteKernel(page_ref, k_old, v_old, k_new, v_new, mask_ref, k_out,
+                 v_out):
+  del page_ref
+  keep = mask_ref[0] != 0                                     # [1, P]
+  k_out[0] = jnp.where(keep, k_new[0], k_old[0])
+  v_out[0] = jnp.where(keep, v_new[0], v_old[0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _WriteCall(page_ids, k_pages, v_pages, k_new, v_new, mask, *,
+               interpret: bool):
+  """A grid of (row, page) writes: program i rewrites page `page_ids[i]` of
+  both pools with `new[i]` where `mask[i]` says so. Pages and new pages
+  `[.., Nk * H, P]`, as the pool lies."""
+  nw, rows, page = k_new.shape
+  by_page = lambda i, ids: (ids[i], 0, 0)
+  by_write = lambda i, ids: (i, 0, 0)
+  block = (1, rows, page)
+  with jax.named_scope("kv_write"):     # inside the jit: the kernel's name
+    return pl.pallas_call(
+        _WriteKernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nw,),
+            in_specs=[pl.BlockSpec(block, by_page),
+                      pl.BlockSpec(block, by_page),
+                      pl.BlockSpec(block, by_write),
+                      pl.BlockSpec(block, by_write),
+                      pl.BlockSpec((1, 1, page), by_write)],
+            out_specs=[pl.BlockSpec(block, by_page),
+                       pl.BlockSpec(block, by_page)]),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        input_output_aliases={1: 0, 2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_ids, k_pages, v_pages, k_new, v_new, mask)
+
+
+def PageWrites(b: int, t: int, page_size: int) -> int:
+  """Static bound on the (row, page) pairs a step writes: a row of `len`
+  tokens touches one page and one more for every page boundary it crosses,
+  under 2 + len / P, and a row of one token one."""
+  return min(t, 2 * b + t // page_size)
+
+
+def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
+               lowering: str = "auto", interpret: bool | None = None):
+  """Every valid token's K and V `[T, Nk, H]` into its row's page at its
+  slot (`rows`: the step's core/ragged.RaggedRows; block_tables [B,
+  t_pages]); padding tokens write nothing, or the pool's last page. ->
+  (k_pool, v_pool).
+
+  The XLA lowering scatters rows of `[Nk, H]`. On the chip a token is a
+  LANE of its page (module docstring), and a scatter there re-lays the whole
+  pool out, so the kernel rewrites whole pages: a program a (row, page) pair
+  the step touches (`PageWrites` of them, the dead ones on the trash page
+  with nothing to write), the page's new lanes gathered beforehand."""
+  assert lowering in ("auto", "pallas", "xla"), lowering
+  on_tpu = jax.default_backend() == "tpu"
+  if lowering == "auto":
+    lowering = "pallas" if on_tpu else "xla"
+  np_total, page, nk, h = k_pool.shape
+  b, t_pages = block_tables.shape
+  t = k_new.shape[0]
+  pos = rows.pos.astype(jnp.int32)
+  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
+  tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
+  if lowering == "xla":
+    logical = jnp.clip(pos // page, 0, t_pages - 1)
+    phys = jnp.where(rows.valid, tables[row, logical], np_total - 1)
+    off = jnp.where(rows.valid, pos % page,
+                    jnp.arange(t, dtype=jnp.int32) % page)
+    return (k_pool.at[phys, off].set(k_new.astype(k_pool.dtype)),
+            v_pool.at[phys, off].set(v_new.astype(v_pool.dtype)))
+  # the (row, logical page) pairs of the step, rows in slot order
+  p0 = rows.row_q_pos.astype(jnp.int32)
+  n = rows.row_len.astype(jnp.int32)
+  first_page = p0 // page
+  n_pages = jnp.where(n > 0, (p0 + n - 1) // page - first_page + 1, 0)
+  cum = jnp.cumsum(n_pages)
+  nw = PageWrites(b, t, page)
+  i = jnp.arange(nw, dtype=jnp.int32)
+  r = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1)
+  lp = first_page[r] + i - (cum[r] - n_pages[r])
+  live = i < cum[-1]
+  slot = lp[:, None] * page + jnp.arange(page, dtype=jnp.int32)[None]
+  mask = live[:, None] & (slot >= p0[r][:, None]) & (
+      slot < (p0 + n)[r][:, None])
+  tok = jnp.clip(rows.row_cols[r, 0][:, None] + slot - p0[r][:, None],
+                 0, t - 1)                                    # [NW, P]
+  page_ids = jnp.where(live, tables[r, jnp.clip(lp, 0, t_pages - 1)],
+                       np_total - 1)
+
+  def _NewPages(new):
+    lanes = new.reshape(t, nk * h).astype(k_pool.dtype)[tok]  # [NW, P, rows]
+    return lanes.swapaxes(1, 2)
+
+  if interpret is None:
+    interpret = not on_tpu
+  k_pages, v_pages = _WriteCall(
+      page_ids, AsItLies(k_pool), AsItLies(v_pool), _NewPages(k_new),
+      _NewPages(v_new), mask.astype(jnp.int32)[:, None, :],
+      interpret=interpret)
+  return _FromAsItLies(k_pages, nk), _FromAsItLies(v_pages, nk)
+
+
+def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
+                      page_size: int, window: int, interpret: bool):
+  """q2: [T, N, 2H] padded queries -> [T, N, 2H], a softmax's `a V` each."""
+  t, n, h2 = q2.shape
+  np_total, page, nk, h = k_pool.shape
+  assert page == page_size and h2 == 2 * h, (k_pool.shape, q2.shape)
+  heads = nk // 2
+  group = n // heads
+  lanes = rba.GroupLanes(group)
+  b, t_pages = block_tables.shape
+  # the group beside the tokens, padded to whole sublane tiles (RaggedAttend)
+  q = q2.reshape(t, heads, group, h2).swapaxes(1, 2)
+  q = jnp.pad(q, ((0, 0), (0, lanes - group), (0, 0), (0, 0)))
+  q = q.reshape(t * lanes, heads * h2).astype(jnp.float32)
+  rows = jnp.repeat(jnp.clip(row_of.astype(jnp.int32), 0, b - 1), lanes)
+  ends = jnp.repeat(q_end.astype(jnp.int32), lanes)
+  tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
+  bq = rba.QueryBlock(heads, h2, page_size, q2.dtype, k_pool.dtype,
+                      grouped=True)
+  nb = rba.NumQueryBlocks(b, t * lanes, bq)
+  zeros = jnp.zeros_like(ends)
+  blocks = rba._BuildQueryBlocks(
+      rows, ends, zeros, zeros - 1, zeros - 1, bq=bq, nb=nb,
+      page_size=page_size, t_pages=t_pages, window=window)
+  grid_pages = rba.WindowPages(window, bq, page_size, t_pages)
+  prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
+              blocks.first]
+  if window:
+    prefetch.append(blocks.page0)
+
+  out = _TransposedCall(
+      tuple(prefetch), jnp.pad(q, ((0, bq), (0, 0))), blocks.cols,
+      AsItLies(k_pool), AsItLies(v_pool), page_size=page_size, heads=heads,
+      window=window, grid=(nb, grid_pages), rungs=rba.BlockRungs(bq, lanes),
+      interpret=interpret)
+  out = out[:t * lanes].reshape(t, lanes, heads, h2)[:, :group]
+  return out.swapaxes(1, 2).reshape(t, n, h2)
+
+
+def DiffAttend(q, k_pool, v_pool, block_tables, row_of, q_end, lam, *,
+               page_size: int, window: int = 0, lowering: str = "auto",
+               interpret: bool | None = None):
+  """q: [T, 2 * pairs, H] packed queries, scaled; pools [NP, P, Nk, H];
+  block_tables [B, t_pages]; row_of / q_end [T] as RaggedAttend's (a row's
+  tokens contiguous, q_end 0 = padding); lam: the layer's scalar.
+  lowering: 'auto' (the kernel on a TPU, the twin elsewhere) | 'pallas' |
+  'xla'. -> [T, pairs, 2H] in q's dtype, zeros at padding tokens."""
+  assert lowering in ("auto", "pallas", "xla"), lowering
+  t, nq, h = q.shape
+  on_tpu = jax.default_backend() == "tpu"
+  if lowering == "auto":
+    lowering = "pallas" if on_tpu else "xla"
+  q2 = PaddedQueries(q)
+  if lowering == "xla":
+    out = _XlaDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
+                         page_size, int(window))
+  else:
+    out = _PallasDiffAttend(
+        q2, k_pool, v_pool, block_tables, jnp.asarray(row_of),
+        jnp.asarray(q_end), page_size, int(window),
+        interpret=(not on_tpu) if interpret is None else interpret)
+  out = out.astype(jnp.float32).reshape(t, nq // 2, 2, 2 * h)
+  return (out[:, :, 0] - lam * out[:, :, 1]).astype(q.dtype)

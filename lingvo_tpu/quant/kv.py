@@ -96,6 +96,10 @@ def StackKvCensus(task, kv_cache_dtype=None):
   stack = getattr(task, "stack", None)
   if stack is None:
     return None
+  if hasattr(stack, "MixerLayers"):
+    # a stack that lists its own mixers (transformer.BlockSequence)
+    return _Census([(m, reps) for m, reps in stack.MixerLayers()
+                    if hasattr(m, "KvBytesPerToken")], kv_cache_dtype)
   layers = []
   if hasattr(stack, "x_layers"):
     layers = [(l, 1) for l in stack.x_layers]
@@ -111,6 +115,11 @@ def StackKvCensus(task, kv_cache_dtype=None):
     atten = getattr(getattr(layer, "self_atten", None), "atten", None)
     if atten is not None and hasattr(atten, "KvBytesPerToken"):
       attens.append((atten, reps))
+  return _Census(attens, kv_cache_dtype)
+
+
+def _Census(attens, kv_cache_dtype):
+  """StackKvCensus's dict from [(attention layer, repeats)]."""
   if not attens:
     return {"kv_cache_dtype": None, "kv_bytes_per_token": 0,
             "attention_layers": 0}

@@ -505,6 +505,9 @@ class ServingLoop:
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
+    # layers that read pages another layer owns (0: the stack has none)
+    self._shared_kv_read_layers = getattr(
+        task.stack, "SharedKvReadLayers", lambda: 0)()
     self._handles: dict = {}
     # counters live in the registry under serving/* (schema is the single
     # source of the key set); Stats() maps them back to the plain keys.
@@ -1311,6 +1314,11 @@ class ServingLoop:
     if self._kind_pages is not None:
       out["window_pages_released"] = self._kind_pages.pages_released
       out["window_pages_allocated"] = self._kind_pages.pages_allocated
+    if self.state_pool is not None:
+      out.update((k, self._counters[k].value) for k in (
+          "ssm_tokens", "ssm_rows", "cross_tokens_unread"))
+      out["state_slots_in_use"] = self.state_pool.num_in_use
+      out["shared_kv_read_layers"] = self._shared_kv_read_layers
     return out or None
 
   def _NoteDispatch(self, batch):
@@ -1318,6 +1326,17 @@ class ServingLoop:
     the scheduler's cursors pass it, and the counters that need no token
     count it."""
     desc = batch.rows_desc
+    row_len = np.asarray(desc.row_len, np.int64)
+    if self.state_pool is not None:
+      self._counters["ssm_tokens"].Inc(int(row_len.sum()))
+      self._counters["ssm_rows"].Inc(int((row_len > 0).sum()))
+      # of a prefill row's tokens only the prompt's last one is sampled from
+      # (before the cursors advance: prompt_remaining is as the step finds it)
+      self._counters["cross_tokens_unread"].Inc(sum(
+          int(n) - (seq.prompt_remaining <= n)
+          for seq, n in zip(batch.rows, row_len)
+          if seq is not None and n > 0
+          and seq.state is scheduler_lib.SeqState.PREFILL))
     if self.trace is not None and batch.mixed:
       # emit prefill-chunk spans BEFORE the cursors advance
       for i, seq in enumerate(batch.rows):
@@ -1332,7 +1351,6 @@ class ServingLoop:
     self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
     self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
     if self._attend_bq:
-      row_len = np.asarray(desc.row_len, np.int64)
       # a row's queries fill whole blocks and then one that holds the rest
       whole, rest = np.divmod(row_len * self._attend_laid, self._attend_bq)
       self._counters["attend_query_blocks"].Inc(
@@ -1583,6 +1601,8 @@ class ServingLoop:
           else observe_schema.DisabledPrefixCacheStats())
       if self.state_pool is not None:
         stats["state_slots"] = self.state_pool.Stats()
+        stats["state_slots_in_use"] = self.state_pool.num_in_use
+        stats["shared_kv_read_layers"] = self._shared_kv_read_layers
       # acceptance telemetry: hist[m] = verify rows whose accepted draft
       # prefix had length m ([] for engines without a draft source).
       # accepted_depth_hist is the tree-speculation reading of the SAME

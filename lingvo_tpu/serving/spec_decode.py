@@ -85,6 +85,8 @@ def MixerLayers(task):
   whose body is itself a StackedTransformerLayers block (body.x_layers,
   each xN)."""
   stack = task.stack
+  if hasattr(stack, "MixerLayers"):
+    return stack.MixerLayers()     # transformer.BlockSequence lists its own
   body = getattr(stack, "body", None)
   if body is not None:
     reps = stack.p.num_layers
