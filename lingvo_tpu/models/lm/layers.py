@@ -644,7 +644,7 @@ class TransformerLm(base_model.BaseTask):
     return logits, new_states
 
   def RaggedStep(self, theta, ids, states, block_tables, rows,
-                 ssm_col_states: bool = False):
+                 ssm_col_states: bool = False, head_cols=None):
     """Packed-token continuous-batching step: ids [1, T] ->
     (logits [1, T, vocab], states).
 
@@ -657,6 +657,11 @@ class TransformerLm(base_model.BaseTask):
     the global slot indices, no absolute pos_emb (serve rotary models).
     ssm_col_states as in PagedStep (per-column state trajectories for
     spec-verify rollback, shaped [B, wmax, ...] here).
+    head_cols: [n] int32 indices into the packed token axis, the columns
+    whose logits something reads. The stack runs over all T tokens (every
+    one writes its K/V or advances its row's state); the final norm and the
+    head run over these n alone, and the logits are [1, n, vocab], column
+    head_cols[i]'s at i. None: all T, in packed order.
     """
     with jax.named_scope("embed"):
       x = self.emb.EmbLookup(theta.emb, ids)
@@ -664,6 +669,8 @@ class TransformerLm(base_model.BaseTask):
                                           block_tables, rows,
                                           ssm_col_states=ssm_col_states)
     with jax.named_scope("norm"):
+      if head_cols is not None:
+        x = jnp.take(x, head_cols, axis=1)
       x = self.final_ln.FProp(theta.final_ln, x)
     with jax.named_scope("head_sample"):
       if self.p.softmax_num_sampled > 0:

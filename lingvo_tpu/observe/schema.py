@@ -58,10 +58,13 @@ ENGINE_COUNTER_KEYS = (
     "ssm_tokens", "ssm_rows", "cross_tokens_unread",
 )
 
-# Static engine configuration facts (set once at construction).
+# Static engine configuration facts (set once at construction). `head_rows`:
+# the token columns the step program's final norm and head run over (a draw
+# a slot, and a draft source's verify lane), of the max_batch * row width +
+# prefill_token_budget it packs; also in every step's record.
 ENGINE_INFO_KEYS = (
     "paged_path", "kv_cache_dtype", "kv_bytes_per_token",
-    "serve_int8_weights",
+    "serve_int8_weights", "head_rows",
 )
 
 # Nested sub-dict sections always present in Stats().

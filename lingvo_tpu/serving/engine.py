@@ -473,6 +473,13 @@ class ServingLoop:
                   if self.spec is not None else 1)
     self._ragged_t = max_batch * spec_width + self.prefill_token_budget
     self._ragged_wmax = max(spec_width, self.prefill_token_budget)
+    # the token columns whose logits something reads: a draw a slot, and
+    # under a draft source the slot's verify columns. The step program's
+    # head runs over them alone, unless they are no fewer than the packed
+    # tokens (a wide tree over a tiny budget)
+    self.head_rows = min(
+        max_batch * (1 + (spec_width if self.spec is not None else 0)),
+        self._ragged_t)
     # tree KV repair needs each paged leaf's (page, token-offset) axes;
     # chain engines never repair (accepted prefixes are already in place)
     self._kv_leaf_axes = None
@@ -527,6 +534,7 @@ class ServingLoop:
         self.kv_bytes_per_token)
     self.metrics.Gauge("serving/serve_int8_weights").Set(
         self.serve_int8_weights)
+    self.metrics.Gauge("serving/head_rows").Set(self.head_rows)
     self.metrics.SectionFn("scheduler", self.sched.Stats)
     self.metrics.SectionFn("kv_pages", (self._kind_pages or self.alloc).Stats)
     self.metrics.SectionFn(
@@ -612,11 +620,14 @@ class ServingLoop:
 
     One program covers every iteration shape: prefill chunks, plain
     decode rows, and spec-verify rows are just rows of different length
-    on the same [T] token axis (core/ragged.py). Sampling is per TOKEN
-    with each token broadcasting its row's (seed, output-position)
-    stream; the commit consumes one column per row (a decode row's only
-    column, a finishing prefill's last prompt column), so identical draws
-    across a row's columns are never double-consumed. When a draft
+    on the same [T] token axis (core/ragged.py). The commit consumes one
+    column per row (a decode row's only column, a finishing prefill's
+    last prompt column), so the final norm, the head and the draw run
+    over those columns and the verify lane's alone (`head_rows` of them,
+    gathered before the final norm: _HeadStep), each row drawing from its
+    own (seed, output-position) stream; `sampled` stays [T], the draws
+    scattered to their columns, because the next step's feed, the commit
+    and whoever stands in for this program index it by column. When a draft
     source is configured the verify lane is always computed (static
     structure): rows with row_k == 0 flow through SpecVerifyTokens as
     all-invalid and their column-0 output is exactly the plain draw, so
@@ -640,17 +651,44 @@ class ServingLoop:
     spec_w = self.spec.w if self.spec is not None else 1
     collect = self.spec is not None and self.mixers["num_ssm"] > 0
 
+    t = self._ragged_t
+    narrow = self.head_rows < t   # else the full head, gathered after
+
+    def _HeadStep(theta, states, tok_ids, rows, tables, seeds, pos,
+                  lane_cols=None):
+      """The forward pass, with the head over the columns something reads:
+      each slot's draw column (its row's last token: a decode row's only
+      one, a finishing prompt's last; of a mid-prompt chunk nothing is
+      read) and after them `lane_cols` [m], the verify lane's. A draw is a
+      pure function of (engine seed, row seed, output position), so it is
+      the one its column had when every column drew. Returns (sampled [T]
+      int32, slot b's draw at its column and 0 elsewhere; the lane's logits
+      [m, V]; new_states)."""
+      with jax.named_scope("head_sample"):
+        draw_cols = jnp.take_along_axis(
+            rows.row_cols, jnp.maximum(rows.row_len - 1, 0)[:, None],
+            axis=1)[:, 0]                                      # [B]
+        cols = (draw_cols if lane_cols is None
+                else jnp.concatenate([draw_cols, lane_cols]))
+      logits, new_states = task.RaggedStep(
+          theta, tok_ids[None], states, tables, rows, ssm_col_states=collect,
+          head_cols=cols if narrow else None)
+      with jax.named_scope("head_sample"):
+        logits = logits[0] if narrow else logits[0][cols]      # [n, V]
+        draws = sampling.SampleFromLogits(
+            logits[:b], jax.random.PRNGKey(base_key), temperature=temp,
+            top_k=topk, row_seeds=seeds, positions=pos)
+        # an empty slot's row_cols point at column 0, which is a live
+        # row's: its draw is sent out of range and dropped
+        sampled = jnp.zeros((t,), jnp.int32).at[
+            jnp.where(rows.row_len > 0, draw_cols, t)].set(draws,
+                                                           mode="drop")
+      return sampled, logits[b:], new_states
+
     if spec_k == 0:
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos):
-        logits, new_states = task.RaggedStep(theta, tok_ids[None], states,
-                                             tables, rows)
-        logits = logits[0]                                     # [T, V]
-        key = jax.random.PRNGKey(base_key)
-        row = jnp.clip(rows.row_of, 0, b - 1)
-        with jax.named_scope("head_sample"):
-          sampled = sampling.SampleFromLogits(
-              logits, key, temperature=temp, top_k=topk,
-              row_seeds=seeds[row], positions=pos[row])
+        sampled, _, new_states = _HeadStep(theta, states, tok_ids, rows,
+                                           tables, seeds, pos)
         routed = _MoeCountLeaves(new_states)
         if routed is None:
           return sampled, new_states
@@ -660,21 +698,15 @@ class ServingLoop:
     elif spec_w == 1:
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos,
                       row_k, q_logits):
-        logits, new_states = task.RaggedStep(theta, tok_ids[None], states,
-                                             tables, rows,
-                                             ssm_col_states=collect)
-        logits = logits[0]                                     # [T, V]
-        key = jax.random.PRNGKey(base_key)
-        row = jnp.clip(rows.row_of, 0, b - 1)
-        with jax.named_scope("head_sample"):
-          sampled = sampling.SampleFromLogits(
-              logits, key, temperature=temp, top_k=topk,
-              row_seeds=seeds[row], positions=pos[row])
         # verify lane: each row's first spec_k+1 token columns, gathered
         # back to [B, k+1] — prefill/no-draft rows gather garbage that
         # draft_valid masks out of acceptance entirely
+        key = jax.random.PRNGKey(base_key)
         vcols = rows.row_cols[:, :spec_k + 1]
-        v_logits = logits[vcols]
+        sampled, v_logits, new_states = _HeadStep(
+            theta, states, tok_ids, rows, tables, seeds, pos,
+            vcols.reshape(-1))
+        v_logits = v_logits.reshape(b, spec_k + 1, -1)
         d_toks = tok_ids[vcols[:, 1:]]
         draft_valid = (jnp.arange(spec_k, dtype=jnp.int32)[None]
                        < row_k[:, None])
@@ -740,16 +772,6 @@ class ServingLoop:
 
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos,
                       row_k, row_w, q_logits):
-        logits, new_states = task.RaggedStep(theta, tok_ids[None], states,
-                                             tables, rows,
-                                             ssm_col_states=collect)
-        logits = logits[0]                                     # [T, V]
-        key = jax.random.PRNGKey(base_key)
-        row = jnp.clip(rows.row_of, 0, b - 1)
-        with jax.named_scope("head_sample"):
-          sampled = sampling.SampleFromLogits(
-              logits, key, temperature=temp, top_k=topk,
-              row_seeds=seeds[row], positions=pos[row])
         # tree verify lane: draft node j = bi*k + d (the branch-major
         # draft layout) sits at packed column 1 + bi*row_k + d; rows
         # with clamped width/depth leave the tail invalid, so the
@@ -764,8 +786,12 @@ class ServingLoop:
         node_col = jnp.where(
             nvalid, 1 + bi_j[None] * row_k[:, None] + d_j[None], 0)
         ntok = jnp.take_along_axis(rows.row_cols, node_col, axis=1)
-        v_logits = jnp.concatenate(
-            [logits[rows.row_cols[:, :1]], logits[ntok]], axis=1)
+        key = jax.random.PRNGKey(base_key)
+        vcols = jnp.concatenate([rows.row_cols[:, :1], ntok], axis=1)
+        sampled, v_logits, new_states = _HeadStep(
+            theta, states, tok_ids, rows, tables, seeds, pos,
+            vcols.reshape(-1))
+        v_logits = v_logits.reshape(b, r + 1, -1)
         d_toks = tok_ids[ntok]
         branches = jnp.broadcast_to(
             jnp.arange(r, dtype=jnp.int32).reshape(1, spec_w, spec_k),
@@ -1305,8 +1331,9 @@ class ServingLoop:
     """What a step's record carries of the cumulative counters whose
     readers want them between two steps: expert load as of the newest
     RETIRED step (one behind the record's own), window pages as of this
-    step's dispatch. None on a stack with neither."""
-    out = {}
+    step's dispatch; and, a constant of the step program, the token columns
+    its head ran over."""
+    out = {"head_rows": self.head_rows}
     if self._moe_layers is not None:
       out.update((k, self._counters[k].value) for k in (
           "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
@@ -1319,7 +1346,7 @@ class ServingLoop:
           "ssm_tokens", "ssm_rows", "cross_tokens_unread"))
       out["state_slots_in_use"] = self.state_pool.num_in_use
       out["shared_kv_read_layers"] = self._shared_kv_read_layers
-    return out or None
+    return out
 
   def _NoteDispatch(self, batch):
     """What is known of a step when it is built (caller holds the lock):
@@ -1593,6 +1620,7 @@ class ServingLoop:
       stats["kv_cache_dtype"] = self.kv_cache_dtype
       stats["kv_bytes_per_token"] = self.kv_bytes_per_token
       stats["serve_int8_weights"] = self.serve_int8_weights
+      stats["head_rows"] = self.head_rows
       stats["scheduler"] = self.sched.Stats()
       stats["kv_pages"] = (self._kind_pages or self.alloc).Stats()
       stats["mixers"] = dict(self.mixers)
