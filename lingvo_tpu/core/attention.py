@@ -24,6 +24,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
 from lingvo_tpu.core import py_utils
@@ -371,14 +372,16 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         query_vec.shape[1])
     key_vec = query_vec if key_vec is None else key_vec
     value_vec = key_vec if value_vec is None else value_vec
-    q = self._HeadsProj(theta, "query", query_vec)
-    k = self._HeadsProj(theta, "key", key_vec)
-    v = self._HeadsProj(theta, "value", value_vec)
-    if self.p.use_rotary_position_emb:
-      rt = self.ChildTheta(theta, "rotary")
-      q = self.rotary.FProp(rt, q)
-      k = self.rotary.FProp(rt, k)
-    q = self._ScaleQuery(theta, q)
+    with observe.Scope("qkv_proj"):
+      q = self._HeadsProj(theta, "query", query_vec)
+      k = self._HeadsProj(theta, "key", key_vec)
+      v = self._HeadsProj(theta, "value", value_vec)
+    with observe.Scope("rope"):
+      if self.p.use_rotary_position_emb:
+        rt = self.ChildTheta(theta, "rotary")
+        q = self.rotary.FProp(rt, q)
+        k = self.rotary.FProp(rt, k)
+      q = self._ScaleQuery(theta, q)
     if use_flash:
       # paddings/segment_ids both become the kernel's segment mask: padding
       # gets segment 0 (packed inputs already carry 0 there; enforce it so
@@ -398,7 +401,8 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         # downstream consumer mixing across time without re-masking would
         # see different numerics depending on the engaged path. Zero them.
         ctx = py_utils.ApplyPadding(paddings, ctx)
-      return self._PostProj(theta, ctx), None
+      with observe.Scope("out_proj"):
+        return self._PostProj(theta, ctx), None
     mask = atten_mask
     if causal:
       cm = CausalMask(query_vec.shape[1])
@@ -417,7 +421,8 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       mask = sm if mask is None else mask + sm
     ctx, probs = self._Atten(theta, q, self._RepeatKv(k), self._RepeatKv(v),
                              mask)
-    return self._PostProj(theta, ctx), probs
+    with observe.Scope("out_proj"):
+      return self._PostProj(theta, ctx), probs
 
   # -- chunk streaming (ref conformer streaming / stream_step_test_base) -----
 
@@ -804,10 +809,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       # the front, so the update shape is [B, C, N] == the scale shape.
       k_new, k_s = kv_quant.QuantizeKv(k_new)              # int8, [B,C,N]
       v_new, v_s = kv_quant.QuantizeKv(v_new)
-      with jax.named_scope("kv_write"):
+      with observe.Scope("kv_write"):
         k_scale = cached_states.key_scale.at[phys, :, off].set(k_s)
         v_scale = cached_states.value_scale.at[phys, :, off].set(v_s)
-    with jax.named_scope("kv_write"):
+    with observe.Scope("kv_write"):
       k_pool = k_pool.at[phys, off].set(k_new.astype(k_pool.dtype))
       v_pool = v_pool.at[phys, off].set(v_new.astype(v_pool.dtype))
     new_states = NestedMap(key=k_pool, value=v_pool)
@@ -892,15 +897,17 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     # at; on chain rows pos_ids == pos bitwise.
     rot_pos = rows.pos_ids.astype(jnp.int32)
     q_start = rows.row_q_pos.astype(jnp.int32)[row]                # [T]
-    q = self._HeadsProj(theta, "query", query_vec)                 # [1,T,N,H]
-    k_new = self._HeadsProj(theta, "key", query_vec)
-    v_new = self._HeadsProj(theta, "value", query_vec)
-    if p.use_rotary_position_emb:
-      rt = self.ChildTheta(theta, "rotary")
-      posf = rot_pos[None].astype(jnp.float32)
-      q = self.rotary.FProp(rt, q, position=posf)
-      k_new = self.rotary.FProp(rt, k_new, position=posf)
-    q = self._ScaleQuery(theta, q)
+    with observe.Scope("qkv_proj"):
+      q = self._HeadsProj(theta, "query", query_vec)               # [1,T,N,H]
+      k_new = self._HeadsProj(theta, "key", query_vec)
+      v_new = self._HeadsProj(theta, "value", query_vec)
+    with observe.Scope("rope"):
+      if p.use_rotary_position_emb:
+        rt = self.ChildTheta(theta, "rotary")
+        posf = rot_pos[None].astype(jnp.float32)
+        q = self.rotary.FProp(rt, q, position=posf)
+        k_new = self.rotary.FProp(rt, k_new, position=posf)
+      q = self._ScaleQuery(theta, q)
     # scatter each token's K/V through ITS row's block table before the
     # read (later tokens of the same prefill chunk attend to earlier ones);
     # padding tokens write to the trash page (this layer's page np_total - 1).
@@ -917,10 +924,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if quantized:
       k_new, k_s = kv_quant.QuantizeKv(k_new)              # int8, [1,T,N]
       v_new, v_s = kv_quant.QuantizeKv(v_new)
-      with jax.named_scope("kv_write"):
+      with observe.Scope("kv_write"):
         k_scale = cached_states.key_scale.at[phys, :, off].set(k_s[0])
         v_scale = cached_states.value_scale.at[phys, :, off].set(v_s[0])
-    with jax.named_scope("kv_write"):
+    with observe.Scope("kv_write"):
       k_pool = k_pool.at[phys, off].set(k_new[0].astype(k_pool.dtype))
       v_pool = v_pool.at[phys, off].set(v_new[0].astype(v_pool.dtype))
     new_states = NestedMap(key=k_pool, value=v_pool)
@@ -936,7 +943,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     # padding (the ragged op emits exact zeros there)
     q_end = jnp.where(valid, pos + 1, 0)
     if eligible:
-      with jax.named_scope("ragged_attend"):
+      with observe.Scope("ragged_attend"):
         ctx = ragged_block_attend.RaggedAttend(
             q[0], k_pool, v_pool, tables, row, q_end,
             page_size=page_size, k_scale=k_scale, v_scale=v_scale,
@@ -968,7 +975,8 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       ctx, _ = self._Atten(theta, q[0][:, None], k_dense[row],
                            v_dense[row], mask)
       ctx = ctx[:, 0][None]
-    return self._PostProj(theta, ctx), new_states
+    with observe.Scope("out_proj"):
+      return self._PostProj(theta, ctx), new_states
 
 
 class LocalSelfAttention(MultiHeadedAttention):
@@ -1308,9 +1316,11 @@ class DifferentialAttention(base_layer.BaseLayer):
     th = self.CastTheta(theta)
     b, t, _ = x.shape
     n, nk, h = p.num_heads, p.num_kv_heads, self._h
-    q = self._Query(th, x)                                       # [B,T,N,H]
+    with observe.Scope("qkv_proj"):
+      q = self._Query(th, x)                                     # [B,T,N,H]
     if p.kv_owner:
-      k, v = self._KeyValue(th, x)
+      with observe.Scope("qkv_proj"):
+        k, v = self._KeyValue(th, x)
       if p.export_kv:
         shared = shared.Copy()
         shared.key, shared.value = k, v
@@ -1335,7 +1345,8 @@ class DifferentialAttention(base_layer.BaseLayer):
     o = jnp.einsum("bnts,bsnh->btnh", a.astype(v.dtype), wide)
     o = o.astype(jnp.float32).reshape(b, t, n // 2, 2, 2 * h)
     lam, lam_init = self._Lambda(th, depth)
-    out = self._Finish(th, o[:, :, :, 0] - lam * o[:, :, :, 1], lam_init)
+    with observe.Scope("out_proj"):
+      out = self._Finish(th, o[:, :, :, 0] - lam * o[:, :, :, 1], lam_init)
     if paddings is not None:
       out = py_utils.ApplyPadding(paddings, out)
     return out, shared
@@ -1358,12 +1369,14 @@ class DifferentialAttention(base_layer.BaseLayer):
     valid = rows.valid
     row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
     tables = jnp.clip(table.astype(jnp.int32), 0, np_total - 1)
-    q = self._Query(th, x[0])                                    # [T, N, H]
+    with observe.Scope("qkv_proj"):
+      q = self._Query(th, x[0])                                  # [T, N, H]
     lowering = "auto" if self.BlockDecodeEligible(page_size) else "xla"
     if p.kv_owner:
       # a token's K and V land through its row's table before the read
-      k_new, v_new = self._KeyValue(th, x[0])
-      with jax.named_scope("kv_write"):
+      with observe.Scope("qkv_proj"):
+        k_new, v_new = self._KeyValue(th, x[0])
+      with observe.Scope("kv_write"):
         key, value = diff_attend.WritePages(
             pool.key, pool.value, k_new, v_new, tables, rows,
             lowering=lowering)
@@ -1371,8 +1384,9 @@ class DifferentialAttention(base_layer.BaseLayer):
       shared = shared.Copy()
       shared.kv_pool = pool
     lam, lam_init = self._Lambda(th, depth)
-    with jax.named_scope(diff_attend.SCOPE):
+    with observe.Scope("diff_attend"):
       diff = diff_attend.DiffAttend(
           q, pool.key, pool.value, tables, row, jnp.where(valid, pos + 1, 0),
           lam, page_size=page_size, window=p.window, lowering=lowering)
-    return self._Finish(th, diff, lam_init)[None], states, shared
+    with observe.Scope("out_proj"):
+      return self._Finish(th, diff, lam_init)[None], states, shared

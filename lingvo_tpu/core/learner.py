@@ -17,6 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import optimizer as optimizer_lib
 from lingvo_tpu.core import py_utils
@@ -102,7 +103,7 @@ class Learner(base_layer.BaseLayer):
   def Apply(self, theta: NestedMap, grads: NestedMap, step,
             opt_state: NestedMap) -> tuple[NestedMap, NestedMap, NestedMap]:
     """Returns (new_theta, new_opt_state, stats NestedMap)."""
-    with jax.named_scope("optimizer_update"):
+    with observe.Scope("optimizer_update"):
       return self._Apply(theta, grads, step, opt_state)
 
   def _Apply(self, theta, grads, step, opt_state):

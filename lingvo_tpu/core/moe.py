@@ -33,6 +33,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
 from lingvo_tpu.core.nested_map import NestedMap
@@ -129,13 +130,13 @@ class DroplessMoELayer(base_layer.BaseLayer):
     th = self.CastTheta(theta)
     t, d = x.shape
     e, k = p.num_experts, p.num_experts_per_token
-    with jax.named_scope("moe_route"):
+    with observe.Scope("moe_route"):
       top_logits, top_idx = jax.lax.top_k(logits, k)               # [T, k]
       weights = jax.nn.softmax(top_logits, axis=-1)
       if valid is not None:
         # a padding token's pairs sort behind every expert's run
         top_idx = jnp.where(valid[:, None], top_idx, e)
-    with jax.named_scope("moe_dispatch"):
+    with observe.Scope("moe_dispatch"):
       flat = top_idx.reshape(-1)                                    # [T * k]
       order = jnp.argsort(flat, stable=True)
       counts = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
@@ -147,11 +148,11 @@ class DroplessMoELayer(base_layer.BaseLayer):
           jnp.zeros((layers * e,), jnp.int32), counts,
           (jnp.asarray(layer, jnp.int32) * e,))
       flat = lambda w: w.reshape((-1,) + w.shape[2:])
-    with jax.named_scope("moe_experts"):
+    with observe.Scope("moe_experts"):
       h = jax.nn.relu(GroupedMatmul(xs, flat(th.w_gate), sizes))
       h = h * GroupedMatmul(xs, flat(th.w_up), sizes)
       ys = GroupedMatmul(h.astype(xs.dtype), flat(th.w_down), sizes)
-    with jax.named_scope("moe_combine"):
+    with observe.Scope("moe_combine"):
       w_sorted = weights.reshape(-1)[order]
       live = jnp.arange(t * k) < jnp.sum(counts)
       ys = jnp.where(live[:, None], ys.astype(jnp.float32)
@@ -166,9 +167,9 @@ class DroplessMoELayer(base_layer.BaseLayer):
     transformer layer's input); paddings [...] (1 = padding) or None.
     Returns (inputs + experts [..., D], tokens by expert [E] int32)."""
     p = self.p
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, inputs)
-    with jax.named_scope("ffn"):
+    with observe.Scope("ffn"):
       d = x.shape[-1]
       valid = None if paddings is None else paddings.reshape(-1) < 0.5
       out, counts = self._Experts(

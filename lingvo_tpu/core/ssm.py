@@ -39,6 +39,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import py_utils
 from lingvo_tpu.core.nested_map import NestedMap
@@ -528,13 +529,16 @@ class Mamba1Layer(base_layer.BaseLayer):
           "Mamba1Layer.FProp does not reset state between packed segments")
     th = self.CastTheta(theta)
     k = self.p.conv_width
-    u, z = self._InProj(th, x)
-    u32 = u.astype(jnp.float32)
-    t = x.shape[1]
-    padded = jnp.pad(u32, ((0, 0), (k - 1, 0), (0, 0)))
-    w = th.conv_w.astype(jnp.float32)
-    conv = sum(w[i] * padded[:, i:i + t] for i in range(k))
-    c, delta, b_t, c_t = self._ScanInputs(th, conv)
+    with observe.Scope("ssm_in_proj"):
+      u, z = self._InProj(th, x)
+    with observe.Scope("ssm_conv"):
+      u32 = u.astype(jnp.float32)
+      t = x.shape[1]
+      padded = jnp.pad(u32, ((0, 0), (k - 1, 0), (0, 0)))
+      w = th.conv_w.astype(jnp.float32)
+      conv = sum(w[i] * padded[:, i:i + t] for i in range(k))
+    with observe.Scope("ssm_params"):
+      c, delta, b_t, c_t = self._ScanInputs(th, conv)
     if paddings is not None:
       delta = delta * (1.0 - paddings.astype(jnp.float32))[..., None]
     a = -jnp.exp(th.a_log.astype(jnp.float32))
@@ -545,11 +549,12 @@ class Mamba1Layer(base_layer.BaseLayer):
       return s, jnp.sum(s * rd[:, :, None], axis=1)
 
     s0 = jnp.zeros((x.shape[0],) + a.shape, jnp.float32)
-    with jax.named_scope("ssm_scan"):
+    with observe.Scope("ssm_scan"):
       _, ys = jax.lax.scan(_Token, s0, tuple(
           jnp.moveaxis(v, 1, 0) for v in (delta, c, b_t, c_t)))
     y = jnp.moveaxis(ys, 0, 1) + th.d_skip.astype(jnp.float32) * c
-    out, shared = self._Finish(th, y, z, shared)
+    with observe.Scope("ssm_out_proj"):
+      out, shared = self._Finish(th, y, z, shared)
     if paddings is not None:
       out = py_utils.ApplyPadding(paddings, out)
     return out, shared
@@ -573,36 +578,42 @@ class Mamba1Layer(base_layer.BaseLayer):
     th = self.CastTheta(theta)
     k = self.p.conv_width
     t = x.shape[1]
-    u, z = self._InProj(th, x[0])                               # [T, E]
-    u32 = u.astype(jnp.float32)
-    slots = states.conv.shape[0]
-    row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
-    col = rows.col_of.astype(jnp.int32)
-    fresh = rows.row_q_pos == 0
-    tail = jnp.where(fresh[:, None, None], 0.0, states.conv)   # [B, K-1, E]
-    w = th.conv_w.astype(jnp.float32)
-    conv = w[k - 1] * u32
-    for back in range(1, k):
-      # the input `back` tokens before: of this step where the row has it,
-      # else of the slot's tail
-      here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
-      held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
-      conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here, held)
-    c, delta, b_t, c_t = self._ScanInputs(th, conv)
+    with observe.Scope("ssm_in_proj"):
+      u, z = self._InProj(th, x[0])                             # [T, E]
+    with observe.Scope("ssm_conv"):
+      u32 = u.astype(jnp.float32)
+      slots = states.conv.shape[0]
+      row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
+      col = rows.col_of.astype(jnp.int32)
+      fresh = rows.row_q_pos == 0
+      tail = jnp.where(fresh[:, None, None], 0.0, states.conv)  # [B, K-1, E]
+      w = th.conv_w.astype(jnp.float32)
+      conv = w[k - 1] * u32
+      for back in range(1, k):
+        # the input `back` tokens before: of this step where the row has
+        # it, else of the slot's tail
+        here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
+        held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
+        conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here,
+                                            held)
+    with observe.Scope("ssm_params"):
+      c, delta, b_t, c_t = self._ScanInputs(th, conv)
     y, scan = selective_scan.SelectiveScan(
         delta, c, b_t, c_t, -jnp.exp(th.a_log.astype(jnp.float32)),
         th.d_skip, states.scan, rows)
-    # the row's last K - 1 inputs, old tail and this step's tokens together
-    n = rows.row_len.astype(jnp.int32)[:, None]                  # [B, 1]
-    i = jnp.arange(k - 1, dtype=jnp.int32)[None]                 # [1, K-1]
-    at = n - (k - 1) + i                                         # in the row
-    cols = jnp.take_along_axis(
-        rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
-    old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
-                              axis=1)
-    new_tail = jnp.where((at >= 0)[..., None],
-                         u32[jnp.clip(cols, 0, t - 1)], old)
-    out, shared = self._Finish(th, y[None], z[None], shared)
+    with observe.Scope("ssm_conv"):
+      # the row's last K - 1 inputs, old tail and this step's tokens together
+      n = rows.row_len.astype(jnp.int32)[:, None]                # [B, 1]
+      i = jnp.arange(k - 1, dtype=jnp.int32)[None]               # [1, K-1]
+      at = n - (k - 1) + i                                       # in the row
+      cols = jnp.take_along_axis(
+          rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
+      old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
+                                axis=1)
+      new_tail = jnp.where((at >= 0)[..., None],
+                           u32[jnp.clip(cols, 0, t - 1)], old)
+    with observe.Scope("ssm_out_proj"):
+      out, shared = self._Finish(th, y[None], z[None], shared)
     return out, NestedMap(scan=scan, conv=new_tail), shared
 
 
@@ -631,8 +642,9 @@ class GatedMemoryUnit(base_layer.BaseLayer):
             depth=None):
     del paddings, segment_ids, depth
     th = self.CastTheta(theta)
-    gate = jax.nn.silu(jnp.einsum("...d,de->...e", x, th.w_1))
-    return jnp.einsum("...e,ed->...d", gate * shared.memory, th.w_2), shared
+    with observe.Scope("gmu"):
+      gate = jax.nn.silu(jnp.einsum("...d,de->...e", x, th.w_1))
+      return jnp.einsum("...e,ed->...d", gate * shared.memory, th.w_2), shared
 
   def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
     del theta, num_slots

@@ -17,6 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from lingvo_tpu import observe
 from lingvo_tpu.core import attention as attention_lib
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
@@ -74,9 +75,9 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
     from lingvo_tpu.core import activations
     # named scopes: each device op's op_name in a profiler trace says which
     # block it belongs to (metadata only; docs/observability.md)
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, inputs)
-    with jax.named_scope("ffn"):
+    with observe.Scope("ffn"):
       h = self.ffn_in.FProp(theta.ffn_in, x)
       act = activations.GetFn(p.activation)
       if p.use_gated_activation:
@@ -129,9 +130,9 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
             atten_mask=None, segment_ids=None):
     """Self-attention when source_vecs is None; else cross-attention."""
     p = self.p
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
-    with jax.named_scope("atten"):
+    with observe.Scope("atten"):
       if source_vecs is None:
         # causality is passed as a flag (not a materialized mask) so the
         # fused flash kernel can take over when eligible.
@@ -163,9 +164,9 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
 
   def _Step(self, method, theta, query_vec, cached_states, cache_paddings,
             **kw):
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
-    with jax.named_scope("atten"):
+    with observe.Scope("atten"):
       out, new_states = getattr(self.atten, method)(
           theta.atten, x, cached_states, paddings=cache_paddings, **kw)
       return query_vec + out, new_states
@@ -185,9 +186,9 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
     (ssm.GatedSSMLayer.PagedStep); attention mixers ignore it (KV-page
     rollback is free — the write cursor is host-side and reads never
     pass q_pos + in_len)."""
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
-    with jax.named_scope("atten"):
+    with observe.Scope("atten"):
       if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
         out, new_states = self.atten.PagedStep(
             theta.atten, x, cached_states, block_tables, q_pos, in_len,
@@ -207,9 +208,9 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
     kw = {} if layer is None else {"layer": layer}
     if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
       kw["collect_col_states"] = True
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
-    with jax.named_scope("atten"):
+    with observe.Scope("atten"):
       out, new_states = self.atten.RaggedStep(
           theta.atten, x, cached_states, block_tables, rows, **kw)
       return query_vec + out, new_states
@@ -267,7 +268,7 @@ class TransformerLayer(base_layer.BaseLayer):
     attention block, and ride past it as a keyword of the feed-forward's
     call. Nothing otherwise."""
     if hasattr(self.fflayer, "RouterLogits"):
-      with jax.named_scope("ffn"), jax.named_scope("moe_route"):
+      with observe.Scope("ffn"), observe.Scope("moe_route"):
         return {"router_logits": self.fflayer.RouterLogits(theta.fflayer,
                                                            inputs)}
     return {}
@@ -637,8 +638,9 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
         body_fn = jax.checkpoint(_Body, policy=policy)
       else:
         body_fn = jax.checkpoint(_Body)
-    out, aux_per_layer = jax.lax.scan(body_fn, inputs,
-                                      (theta.body, jnp.arange(p.num_layers)))
+    with observe.Scope("layer_scan"):
+      out, aux_per_layer = jax.lax.scan(
+          body_fn, inputs, (theta.body, jnp.arange(p.num_layers)))
     if aux_flag.emitted:
       py_utils.AddAuxLoss(f"{self.path}/aux_loss", jnp.sum(aux_per_layer))
     return out
@@ -741,9 +743,10 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
           lambda path, leaf: None if path in carried else leaf, new_states)
       return (x, states), added
 
-    (out, states), added = jax.lax.scan(
-        _Body, (inputs, cached_states.body),
-        (scanned_theta, jnp.arange(self.p.num_layers)))
+    with observe.Scope("layer_scan"):
+      (out, states), added = jax.lax.scan(
+          _Body, (inputs, cached_states.body),
+          (scanned_theta, jnp.arange(self.p.num_layers)))
     final = _ByPath(states)
     new_states = jax.tree_util.tree_map_with_path(
         lambda path, leaf: final[path] if leaf is None else leaf, added,
@@ -812,9 +815,9 @@ class SharedStateLayer(base_layer.BaseLayer):
 
   def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
             depth=0):
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       normed = self.ln.FProp(theta.ln, x)
-    with jax.named_scope("atten"):
+    with observe.Scope("atten"):
       out, shared = self.atten.FProp(theta.atten, normed, shared,
                                      paddings=paddings,
                                      segment_ids=segment_ids, depth=depth)
@@ -825,9 +828,9 @@ class SharedStateLayer(base_layer.BaseLayer):
     return self.atten.InitPagedStates(theta.atten, num_slots)
 
   def RaggedStep(self, theta, x, states, shared, rows, table, depth):
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       normed = self.ln.FProp(theta.ln, x)
-    with jax.named_scope("atten"):
+    with observe.Scope("atten"):
       out, states, shared = self.atten.RaggedStep(
           theta.atten, normed, states, shared, rows, table=table, depth=depth)
       x = x + out
@@ -963,10 +966,11 @@ class BlockSequence(base_layer.BaseLayer):
         outs.append(out)
       return (x, shared), outs
 
-    (x, shared), outs = jax.lax.scan(
-        _Body, (x, shared),
-        (theta[f"block_{b}"], jnp.arange(self._repeats[b]),
-         per_repeat))
+    with observe.Scope("layer_scan"):
+      (x, shared), outs = jax.lax.scan(
+          _Body, (x, shared),
+          (theta[f"block_{b}"], jnp.arange(self._repeats[b]),
+           per_repeat))
     return x, shared, outs
 
   def FProp(self, theta, inputs, paddings=None, aux_vecs=None,

@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from lingvo_tpu import observe
 from lingvo_tpu.core import base_model
 from lingvo_tpu.core import layers as layers_lib
 from lingvo_tpu.core import py_utils
@@ -442,7 +443,7 @@ class TransformerLm(base_model.BaseTask):
     p = self.p
     ids = input_batch.ids
     # named scopes at the block boundaries: op_name in a profiler trace
-    with jax.named_scope("embed"):
+    with observe.Scope("embed"):
       x = self.emb.EmbLookup(theta.emb, ids)
       if not p.use_rotary:
         pos = input_batch.Get("segment_pos")
@@ -455,7 +456,7 @@ class TransformerLm(base_model.BaseTask):
     seg_ids = input_batch.Get("segment_ids")
     x = self.stack.FProp(theta.stack, x, paddings=input_batch.paddings,
                          segment_ids=seg_ids, token_ids=ids)
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       x = self.final_ln.FProp(theta.final_ln, x)
     if p.softmax_num_sampled > 0 and not py_utils.DoEval() and \
         py_utils.HasStepSeed():
@@ -467,14 +468,14 @@ class TransformerLm(base_model.BaseTask):
       # vocab; only full-distribution consumers (_FullLogits) pay for
       # dense logits
       return NestedMap(hidden=x)
-    with jax.named_scope("head_loss"):
+    with observe.Scope("head_loss"):
       logits = self._Head(theta, x) if p.softmax_num_sampled == 0 \
           else self.sampled_softmax.Logits(
               self.ChildTheta(theta, "sampled_softmax"), x)
     return NestedMap(logits=logits)
 
   def ComputeLoss(self, theta, predictions, input_batch):
-    with jax.named_scope("head_loss"):
+    with observe.Scope("head_loss"):
       return self._ComputeLoss(theta, predictions, input_batch)
 
   def _ComputeLoss(self, theta, predictions, input_batch):
@@ -663,16 +664,16 @@ class TransformerLm(base_model.BaseTask):
     head run over these n alone, and the logits are [1, n, vocab], column
     head_cols[i]'s at i. None: all T, in packed order.
     """
-    with jax.named_scope("embed"):
+    with observe.Scope("embed"):
       x = self.emb.EmbLookup(theta.emb, ids)
     x, new_states = self.stack.RaggedStep(theta.stack, x, states,
                                           block_tables, rows,
                                           ssm_col_states=ssm_col_states)
-    with jax.named_scope("norm"):
+    with observe.Scope("norm"):
       if head_cols is not None:
         x = jnp.take(x, head_cols, axis=1)
       x = self.final_ln.FProp(theta.final_ln, x)
-    with jax.named_scope("head_sample"):
+    with observe.Scope("head_sample"):
       if self.p.softmax_num_sampled > 0:
         logits = self.sampled_softmax.Logits(
             self.ChildTheta(theta, "sampled_softmax"), x)

@@ -26,7 +26,9 @@ And the fleet-facing layer (ISSUE 13) on top:
 
 `observe.schema` declares every telemetry key set once — engine `Stats()`,
 GShardDecode telemetry, endpoint paths, /statusz keys, goodput buckets and
-watchdog stats are views generated from it.
+watchdog stats are views generated from it, and its `DEVICE_SCOPES` is the
+one tree of `jax.named_scope` names the jitted programs enter, each through
+`observe.Scope(name)`.
 """
 
 from lingvo_tpu.observe import aggregate  # noqa: F401
@@ -39,6 +41,7 @@ from lingvo_tpu.observe.metrics import (  # noqa: F401
     DEFAULT_BOUNDS, Default, HistogramQuantiles, MetricsRegistry)
 from lingvo_tpu.observe.profile import (  # noqa: F401
     CompileInfo, CompileLog, ProfileWindow, ProfilerSupported)
+from lingvo_tpu.observe.schema import Scope  # noqa: F401
 from lingvo_tpu.observe.trace import (  # noqa: F401
     RequestTrace, TraceRecorder)
 from lingvo_tpu.observe.watchdog import StallWatchdog  # noqa: F401

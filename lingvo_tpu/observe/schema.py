@@ -18,6 +18,8 @@ Conventions:
 
 from __future__ import annotations
 
+import jax
+
 # -- serving engine Stats() --------------------------------------------------
 
 # Monotonic counters the engine increments per step/commit; Stats() carries
@@ -48,20 +50,19 @@ ENGINE_COUNTER_KEYS = (
     "moe_experts_active",
     # a stack with slot-state mixers (core/ssm.Mamba1Layer) and layers that
     # read pages they do not own (transformer.BlockSequence): tokens that
-    # went through the scan of every such mixer and rows whose state
-    # advanced, counted when a step is dispatched; packed tokens that are
-    # neither a row's decode token nor the last token of its prompt, for
-    # which the layers past the last page-owning one compute what nothing
-    # reads. All zero on a stack without them. Beside them in Stats() and in
-    # a step's record, not counters: `state_slots_in_use` and
+    # went through the scan of every such mixer, counted when a step is
+    # dispatched; packed tokens that are neither a row's decode token nor
+    # the last token of its prompt, for which the layers past the last
+    # page-owning one compute what nothing reads. All zero on a stack
+    # without them. Beside them in Stats(), not a counter:
     # `shared_kv_read_layers`.
-    "ssm_tokens", "ssm_rows", "cross_tokens_unread",
+    "ssm_tokens", "cross_tokens_unread",
 )
 
 # Static engine configuration facts (set once at construction). `head_rows`:
 # the token columns the step program's final norm and head run over (a draw
 # a slot, and a draft source's verify lane), of the max_batch * row width +
-# prefill_token_budget it packs; also in every step's record.
+# prefill_token_budget it packs.
 ENGINE_INFO_KEYS = (
     "paged_path", "kv_cache_dtype", "kv_bytes_per_token",
     "serve_int8_weights", "head_rows",
@@ -79,15 +80,14 @@ ENGINE_STATS_REQUIRED = frozenset(
     + ("accepted_len_hist", "accepted_depth_hist"))
 
 # Keys present only under specific configurations:
-#   state_slots, state_slots_in_use, shared_kv_read_layers — stacks with
-#                 O(1)-state mixers
+#   state_slots, shared_kv_read_layers — stacks with O(1)-state mixers
 #   spec        — engines with a draft source
 #   trace       — engines with tracing enabled (the default)
 #   compile     — per-compiled-program records (observe/profile.py)
 #   watchdog    — engines with a stall watchdog (observe/watchdog.py)
 ENGINE_STATS_OPTIONAL = frozenset(
-    {"state_slots", "state_slots_in_use", "shared_kv_read_layers", "spec",
-     "trace", "compile", "watchdog"})
+    {"state_slots", "shared_kv_read_layers", "spec", "trace", "compile",
+     "watchdog"})
 
 
 def ValidateEngineStats(stats: dict) -> dict:
@@ -295,3 +295,83 @@ WATCHDOG_STATS_KEYS = frozenset({
     "healthy", "beats", "trips", "tripped", "last_beat_age_s",
     "step_ema_s", "capture_armed",
 })
+
+
+# -- device scopes -----------------------------------------------------------
+
+# Every `jax.named_scope` the jitted programs enter, as one tree: name ->
+# (parent, what it holds). A scope is a string in an op's metadata (its
+# `op_name` in a profiler trace says which block it belongs to); no shape,
+# number or compiled instruction depends on it. A block (parent None) is a
+# stretch of the step program; a child names a lump inside its block. XLA
+# names a Pallas kernel's op after the innermost scope round its call, and
+# the benchmark's readers find kernels by that name, so no scope that is
+# new goes directly round a `pallas_call` (docs/observability.md).
+# `benchmarks/harness/scope_ms.py` reads this tree: milliseconds a step by
+# block. Names are path segments of `op_name`: none may be a JAX primitive's
+# or a jitted function's.
+DEVICE_SCOPES = {
+    "embed": (None, "token embedding lookup (and the absolute position "
+              "embedding where the model has one)"),
+    "norm": (None, "every layer norm: a block's pre-norm, the final norm, "
+             "and in the serving step the gather of the head's columns"),
+    "atten": (None, "a layer's sequence mixer with its residual add: "
+              "attention, a Mamba-1 layer or a gated memory unit"),
+    "qkv_proj": ("atten", "the query, key and value projections"),
+    "rope": ("atten", "rotary position embedding of q and k, and the "
+             "query's scale"),
+    "out_proj": ("atten", "the output projection (a differential layer's "
+                 "with its pair norm)"),
+    "kv_write": ("atten", "new tokens' K and V into the page pool: the "
+                 "scatter, or the page-write kernel (named after it)"),
+    "kv_layout": ("kv_write", "the gathers and re-layouts that lay new "
+                  "tokens out for the page-write kernel "
+                  "(ops/diff_attend.WritePages), not the write itself"),
+    "ragged_attend": ("atten", "ops/ragged_block_attend.RaggedAttend: the "
+                      "ragged attend kernels (named after it) and the "
+                      "group's re-layout round them"),
+    "attend_descriptors": ("ragged_attend", "the query-block descriptors "
+                           "the ragged kernels are prefetched with"),
+    "diff_attend": ("atten", "ops/diff_attend.DiffAttend: the differential "
+                    "attend kernels (named after it)"),
+    "diff_layout": ("diff_attend", "round those kernels: the padded queries, "
+                    "the group's re-layout in and out, the pools as they "
+                    "lie, and the pairs' difference"),
+    "diff_descriptors": ("diff_attend", "the query-block descriptors the "
+                         "differential kernels are prefetched with"),
+    "ssm_in_proj": ("atten", "a Mamba-1 layer's input projection to u, z"),
+    "ssm_conv": ("atten", "its causal depthwise convolution: the taps, the "
+                 "slot tail's gather and its write-back"),
+    "ssm_params": ("atten", "the scan's inputs from the convolution: silu, "
+                   "w_x, w_dt, softplus"),
+    "ssm_scan": ("atten", "the selective scan (ops/selective_scan.py; its "
+                 "kernel is named after it)"),
+    "ssm_out_proj": ("atten", "the gate by z and the output projection"),
+    "gmu": ("atten", "a gated memory unit, whole"),
+    "ffn": (None, "a layer's feed-forward with its residual add: dense, or "
+            "the expert layer"),
+    "moe_route": ("ffn", "router logits, top-k and the softmax over them"),
+    "moe_dispatch": ("ffn", "sort of the (token, expert) pairs, counts, and "
+                     "the gather of tokens into expert order"),
+    "moe_experts": ("ffn", "the three grouped matmuls (megablox names its "
+                    "kernels `gmm` inside it)"),
+    "moe_combine": ("ffn", "weighting, un-sort and sum of a token's experts"),
+    "layer_scan": (None, "a scan over stacked layers, less what its layers "
+                   "name: the slices of the stacked weights and states a "
+                   "trip reads and the stacking of what it hands back"),
+    "head_loss": (None, "the training head: logits and cross-entropy"),
+    "head_sample": (None, "the serving head: the draw columns, logits over "
+                    "them and the draw"),
+    "optimizer_update": (None, "Learner.Apply: gradient norm and clip, the "
+                         "optimizer's update, the new weights"),
+}
+
+
+def Scope(name: str):
+  """`jax.named_scope(name)` for a name DEVICE_SCOPES declares: the one way
+  the program enters a device scope. The check is a dict lookup while
+  Python traces; nothing of it reaches the compiled program."""
+  if name not in DEVICE_SCOPES:
+    raise KeyError(f"device scope {name!r} is not declared in "
+                   "observe.schema.DEVICE_SCOPES")
+  return jax.named_scope(name)

@@ -47,11 +47,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from lingvo_tpu import observe
 from lingvo_tpu.ops import ragged_block_attend as rba
 from lingvo_tpu.ops.flash_attention import LANES, NEG_INF, SUBLANES
 from lingvo_tpu.ops.flash_decode import _Finish
-
-SCOPE = "diff_attend"
 
 
 def PaddedQueries(q):
@@ -170,7 +169,7 @@ def _TransposedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
     return (src_ref[i], 0, 0)
 
   hbm = pl.BlockSpec(memory_space=pl.ANY)
-  with jax.named_scope(SCOPE):
+  with observe.Scope("diff_attend"):
     return pl.pallas_call(
         functools.partial(_TransposedPagesKernel, page_size=page_size,
                           t_pages=grid[1], window=window, heads=heads,
@@ -231,7 +230,7 @@ def _WriteCall(page_ids, k_pages, v_pages, k_new, v_new, mask, *,
   by_page = lambda i, ids: (ids[i], 0, 0)
   by_write = lambda i, ids: (i, 0, 0)
   block = (1, rows, page)
-  with jax.named_scope("kv_write"):     # inside the jit: the kernel's name
+  with observe.Scope("kv_write"):     # inside the jit: the kernel's name
     return pl.pallas_call(
         _WriteKernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -283,42 +282,46 @@ def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
   row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
   if lowering == "xla":
-    logical = jnp.clip(pos // page, 0, t_pages - 1)
-    phys = jnp.where(rows.valid, tables[row, logical], np_total - 1)
-    off = jnp.where(rows.valid, pos % page,
-                    jnp.arange(t, dtype=jnp.int32) % page)
+    with observe.Scope("kv_layout"):
+      logical = jnp.clip(pos // page, 0, t_pages - 1)
+      phys = jnp.where(rows.valid, tables[row, logical], np_total - 1)
+      off = jnp.where(rows.valid, pos % page,
+                      jnp.arange(t, dtype=jnp.int32) % page)
     return (k_pool.at[phys, off].set(k_new.astype(k_pool.dtype)),
             v_pool.at[phys, off].set(v_new.astype(v_pool.dtype)))
-  # the (row, logical page) pairs of the step, rows in slot order
-  p0 = rows.row_q_pos.astype(jnp.int32)
-  n = rows.row_len.astype(jnp.int32)
-  first_page = p0 // page
-  n_pages = jnp.where(n > 0, (p0 + n - 1) // page - first_page + 1, 0)
-  cum = jnp.cumsum(n_pages)
-  nw = PageWrites(b, t, page)
-  i = jnp.arange(nw, dtype=jnp.int32)
-  r = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1)
-  lp = first_page[r] + i - (cum[r] - n_pages[r])
-  live = i < cum[-1]
-  slot = lp[:, None] * page + jnp.arange(page, dtype=jnp.int32)[None]
-  mask = live[:, None] & (slot >= p0[r][:, None]) & (
-      slot < (p0 + n)[r][:, None])
-  tok = jnp.clip(rows.row_cols[r, 0][:, None] + slot - p0[r][:, None],
-                 0, t - 1)                                    # [NW, P]
-  page_ids = jnp.where(live, tables[r, jnp.clip(lp, 0, t_pages - 1)],
-                       np_total - 1)
+  with observe.Scope("kv_layout"):
+    # the (row, logical page) pairs of the step, rows in slot order
+    p0 = rows.row_q_pos.astype(jnp.int32)
+    n = rows.row_len.astype(jnp.int32)
+    first_page = p0 // page
+    n_pages = jnp.where(n > 0, (p0 + n - 1) // page - first_page + 1, 0)
+    cum = jnp.cumsum(n_pages)
+    nw = PageWrites(b, t, page)
+    i = jnp.arange(nw, dtype=jnp.int32)
+    r = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1)
+    lp = first_page[r] + i - (cum[r] - n_pages[r])
+    live = i < cum[-1]
+    slot = lp[:, None] * page + jnp.arange(page, dtype=jnp.int32)[None]
+    mask = live[:, None] & (slot >= p0[r][:, None]) & (
+        slot < (p0 + n)[r][:, None])
+    tok = jnp.clip(rows.row_cols[r, 0][:, None] + slot - p0[r][:, None],
+                   0, t - 1)                                  # [NW, P]
+    page_ids = jnp.where(live, tables[r, jnp.clip(lp, 0, t_pages - 1)],
+                         np_total - 1)
 
-  def _NewPages(new):
-    lanes = new.reshape(t, nk * h).astype(k_pool.dtype)[tok]  # [NW, P, rows]
-    return lanes.swapaxes(1, 2)
+    def _NewPages(new):
+      lanes = new.reshape(t, nk * h).astype(k_pool.dtype)[tok]  # [NW,P,rows]
+      return lanes.swapaxes(1, 2)
 
+    operands = (page_ids, AsItLies(k_pool), AsItLies(v_pool),
+                _NewPages(k_new), _NewPages(v_new),
+                mask.astype(jnp.int32)[:, None, :])
   if interpret is None:
     interpret = not on_tpu
-  k_pages, v_pages = _WriteCall(
-      page_ids, AsItLies(k_pool), AsItLies(v_pool), _NewPages(k_new),
-      _NewPages(v_new), mask.astype(jnp.int32)[:, None, :],
-      interpret=interpret)
-  return _FromAsItLies(k_pages, nk), _FromAsItLies(v_pages, nk)
+  # the write itself stays outside `kv_layout`: its kernel is `kv_write`
+  k_pages, v_pages = _WriteCall(*operands, interpret=interpret)
+  with observe.Scope("kv_layout"):
+    return _FromAsItLies(k_pages, nk), _FromAsItLies(v_pages, nk)
 
 
 def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
@@ -331,33 +334,39 @@ def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
   group = n // heads
   lanes = rba.GroupLanes(group)
   b, t_pages = block_tables.shape
-  # the group beside the tokens, padded to whole sublane tiles (RaggedAttend)
-  q = q2.reshape(t, heads, group, h2).swapaxes(1, 2)
-  q = jnp.pad(q, ((0, 0), (0, lanes - group), (0, 0), (0, 0)))
-  q = q.reshape(t * lanes, heads * h2).astype(jnp.float32)
-  rows = jnp.repeat(jnp.clip(row_of.astype(jnp.int32), 0, b - 1), lanes)
-  ends = jnp.repeat(q_end.astype(jnp.int32), lanes)
-  tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-  bq = rba.QueryBlock(heads, h2, page_size, q2.dtype, k_pool.dtype,
-                      grouped=True)
-  nb = rba.NumQueryBlocks(b, t * lanes, bq)
-  zeros = jnp.zeros_like(ends)
-  blocks = rba._BuildQueryBlocks(
-      rows, ends, zeros, zeros - 1, zeros - 1, bq=bq, nb=nb,
-      page_size=page_size, t_pages=t_pages, window=window)
-  grid_pages = rba.WindowPages(window, bq, page_size, t_pages)
-  prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
-              blocks.first]
-  if window:
-    prefetch.append(blocks.page0)
-
+  with observe.Scope("diff_layout"):
+    # the group beside the tokens, padded to whole sublane tiles
+    # (RaggedAttend)
+    q = q2.reshape(t, heads, group, h2).swapaxes(1, 2)
+    q = jnp.pad(q, ((0, 0), (0, lanes - group), (0, 0), (0, 0)))
+    q = q.reshape(t * lanes, heads * h2).astype(jnp.float32)
+  with observe.Scope("diff_descriptors"):
+    rows = jnp.repeat(jnp.clip(row_of.astype(jnp.int32), 0, b - 1), lanes)
+    ends = jnp.repeat(q_end.astype(jnp.int32), lanes)
+    tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
+    bq = rba.QueryBlock(heads, h2, page_size, q2.dtype, k_pool.dtype,
+                        grouped=True)
+    nb = rba.NumQueryBlocks(b, t * lanes, bq)
+    zeros = jnp.zeros_like(ends)
+    blocks = rba._BuildQueryBlocks(
+        rows, ends, zeros, zeros - 1, zeros - 1, bq=bq, nb=nb,
+        page_size=page_size, t_pages=t_pages, window=window)
+    grid_pages = rba.WindowPages(window, bq, page_size, t_pages)
+    prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
+                blocks.first]
+    if window:
+      prefetch.append(blocks.page0)
+  with observe.Scope("diff_layout"):
+    operands = (jnp.pad(q, ((0, bq), (0, 0))), blocks.cols, AsItLies(k_pool),
+                AsItLies(v_pool))
+  # the call stays outside both: its kernel is `diff_attend`
   out = _TransposedCall(
-      tuple(prefetch), jnp.pad(q, ((0, bq), (0, 0))), blocks.cols,
-      AsItLies(k_pool), AsItLies(v_pool), page_size=page_size, heads=heads,
+      tuple(prefetch), *operands, page_size=page_size, heads=heads,
       window=window, grid=(nb, grid_pages), rungs=rba.BlockRungs(bq, lanes),
       interpret=interpret)
-  out = out[:t * lanes].reshape(t, lanes, heads, h2)[:, :group]
-  return out.swapaxes(1, 2).reshape(t, n, h2)
+  with observe.Scope("diff_layout"):
+    out = out[:t * lanes].reshape(t, lanes, heads, h2)[:, :group]
+    return out.swapaxes(1, 2).reshape(t, n, h2)
 
 
 def DiffAttend(q, k_pool, v_pool, block_tables, row_of, q_end, lam, *,
@@ -373,7 +382,8 @@ def DiffAttend(q, k_pool, v_pool, block_tables, row_of, q_end, lam, *,
   on_tpu = jax.default_backend() == "tpu"
   if lowering == "auto":
     lowering = "pallas" if on_tpu else "xla"
-  q2 = PaddedQueries(q)
+  with observe.Scope("diff_layout"):
+    q2 = PaddedQueries(q)
   if lowering == "xla":
     out = _XlaDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
                          page_size, int(window))
@@ -382,5 +392,6 @@ def DiffAttend(q, k_pool, v_pool, block_tables, row_of, q_end, lam, *,
         q2, k_pool, v_pool, block_tables, jnp.asarray(row_of),
         jnp.asarray(q_end), page_size, int(window),
         interpret=(not on_tpu) if interpret is None else interpret)
-  out = out.astype(jnp.float32).reshape(t, nq // 2, 2, 2 * h)
-  return (out[:, :, 0] - lam * out[:, :, 1]).astype(q.dtype)
+  with observe.Scope("diff_layout"):
+    out = out.astype(jnp.float32).reshape(t, nq // 2, 2, 2 * h)
+    return (out[:, :, 0] - lam * out[:, :, 1]).astype(q.dtype)

@@ -93,6 +93,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from lingvo_tpu import observe
 from lingvo_tpu.ops.flash_attention import (  # single source of truth
     LANES, NEG_INF, SUBLANES)
 from lingvo_tpu.ops.flash_decode import _DotF32, _Finish, _PageAttend
@@ -645,7 +646,7 @@ def _GroupedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
     return (src_ref[i], 0, 0)
 
   hbm = pl.BlockSpec(memory_space=pl.ANY)
-  with jax.named_scope("ragged_attend"):
+  with observe.Scope("ragged_attend"):
     return pl.pallas_call(
         functools.partial(_GroupedAttendKernel, page_size=page_size,
                           t_pages=grid[1], window=window, heads=heads,
@@ -706,11 +707,12 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
     anc_lo = anc_hi = jnp.full((t,), -1, jnp.int32)
   bq = QueryBlock(n, h, page_size, q.dtype, k_pool.dtype, grouped=grouped)
   nb = NumQueryBlocks(b, t, bq)
-  blocks = _BuildQueryBlocks(
-      rows, ends, q_start.astype(jnp.int32), anc_lo.astype(jnp.int32),
-      anc_hi.astype(jnp.int32), bq=bq, nb=nb, page_size=page_size,
-      t_pages=t_pages, window=window)
-  col0 = blocks.cols[:, 0]                                  # [NB, 4]
+  with observe.Scope("attend_descriptors"):
+    blocks = _BuildQueryBlocks(
+        rows, ends, q_start.astype(jnp.int32), anc_lo.astype(jnp.int32),
+        anc_hi.astype(jnp.int32), bq=bq, nb=nb, page_size=page_size,
+        t_pages=t_pages, window=window)
+    col0 = blocks.cols[:, 0]                                # [NB, 4]
   grid_pages = WindowPages(window, bq, page_size, t_pages)
   prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
               blocks.first, col0[:, 0], col0[:, 1], col0[:, 2], col0[:, 3]]

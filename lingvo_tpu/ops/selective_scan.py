@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from lingvo_tpu import observe
 from lingvo_tpu.ops.flash_attention import LANES, SUBLANES
 
 _FIRST, _LAST, _FRESH = 1, 2, 4     # bits of a token's flags
@@ -133,7 +134,7 @@ def _ScanCall(row_of, flags, n_valid, delta, x, b8, c8, a, d_skip, state, *,
   eb = ChannelBlock(e)
   by_block = lambda i, *_: (0, i)
   whole = lambda i, *_: (0, 0, 0)
-  with jax.named_scope("ssm_scan"):
+  with observe.Scope("ssm_scan"):
     return pl.pallas_call(
         _ScanKernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -212,7 +213,7 @@ def SelectiveScan(delta, x, b, c, a, d_skip, state, rows, *,
   row_len = rows.row_len.astype(jnp.int32)
   fresh = rows.row_q_pos == 0
   if lowering == "xla":
-    with jax.named_scope("ssm_scan"):
+    with observe.Scope("ssm_scan"):
       return _XlaSelectiveScan(delta, x, b, c, a, d_skip, state, row_of,
                                col_of, rows.valid, row_len, fresh,
                                rows.row_cols)

@@ -664,7 +664,7 @@ class ServingLoop:
       the one its column had when every column drew. Returns (sampled [T]
       int32, slot b's draw at its column and 0 elsewhere; the lane's logits
       [m, V]; new_states)."""
-      with jax.named_scope("head_sample"):
+      with observe.Scope("head_sample"):
         draw_cols = jnp.take_along_axis(
             rows.row_cols, jnp.maximum(rows.row_len - 1, 0)[:, None],
             axis=1)[:, 0]                                      # [B]
@@ -673,7 +673,7 @@ class ServingLoop:
       logits, new_states = task.RaggedStep(
           theta, tok_ids[None], states, tables, rows, ssm_col_states=collect,
           head_cols=cols if narrow else None)
-      with jax.named_scope("head_sample"):
+      with observe.Scope("head_sample"):
         logits = logits[0] if narrow else logits[0][cols]      # [n, V]
         draws = sampling.SampleFromLogits(
             logits[:b], jax.random.PRNGKey(base_key), temperature=temp,
@@ -1330,10 +1330,10 @@ class ServingLoop:
   def _StepCounters(self):
     """What a step's record carries of the cumulative counters whose
     readers want them between two steps: expert load as of the newest
-    RETIRED step (one behind the record's own), window pages as of this
-    step's dispatch; and, a constant of the step program, the token columns
-    its head ran over."""
-    out = {"head_rows": self.head_rows}
+    RETIRED step (one behind the record's own), window pages and the
+    hybrid stack's token counts as of this step's dispatch. None where the
+    stack has none of them."""
+    out = {}
     if self._moe_layers is not None:
       out.update((k, self._counters[k].value) for k in (
           "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
@@ -1343,10 +1343,8 @@ class ServingLoop:
       out["window_pages_allocated"] = self._kind_pages.pages_allocated
     if self.state_pool is not None:
       out.update((k, self._counters[k].value) for k in (
-          "ssm_tokens", "ssm_rows", "cross_tokens_unread"))
-      out["state_slots_in_use"] = self.state_pool.num_in_use
-      out["shared_kv_read_layers"] = self._shared_kv_read_layers
-    return out
+          "ssm_tokens", "cross_tokens_unread"))
+    return out or None
 
   def _NoteDispatch(self, batch):
     """What is known of a step when it is built (caller holds the lock):
@@ -1356,7 +1354,6 @@ class ServingLoop:
     row_len = np.asarray(desc.row_len, np.int64)
     if self.state_pool is not None:
       self._counters["ssm_tokens"].Inc(int(row_len.sum()))
-      self._counters["ssm_rows"].Inc(int((row_len > 0).sum()))
       # of a prefill row's tokens only the prompt's last one is sampled from
       # (before the cursors advance: prompt_remaining is as the step finds it)
       self._counters["cross_tokens_unread"].Inc(sum(
@@ -1629,7 +1626,6 @@ class ServingLoop:
           else observe_schema.DisabledPrefixCacheStats())
       if self.state_pool is not None:
         stats["state_slots"] = self.state_pool.Stats()
-        stats["state_slots_in_use"] = self.state_pool.num_in_use
         stats["shared_kv_read_layers"] = self._shared_kv_read_layers
       # acceptance telemetry: hist[m] = verify rows whose accepted draft
       # prefix had length m ([] for engines without a draft source).

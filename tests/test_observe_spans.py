@@ -10,7 +10,13 @@
 - engine steps and train loops under `jax.profiler.trace` leave their
   `lingvo/` spans, with their arguments, on the host plane of the trace;
 - a compiled train step and ragged serving step carry every scope name in
-  their HLO metadata, and `host_overhead_s` is a perf_counter duration.
+  their HLO metadata, and `host_overhead_s` is a perf_counter duration;
+- the one declared tree (`observe.schema.DEVICE_SCOPES`): every scope is on
+  some op of a compiled tiny program (dense train, dense / SmallThinker /
+  Phi-4-flash ragged steps, the Pallas lowerings), an undeclared name raises,
+  `observe.Scope` is the program's one way into `jax.named_scope`, the docs'
+  table is the tree, and each program's lowered text is byte for byte the
+  same with every scope a null context.
 """
 
 import gc
@@ -20,9 +26,12 @@ import os
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lingvo_tpu import observe
+from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as trace_lib
 
 sys.path.insert(0, os.path.join(
@@ -33,10 +42,13 @@ from tests.test_observe import _CheckChromeTrace, _FakeClock, _TinyLmParams
 from tests.test_serving_engine import _GreedyRef
 
 TRAIN_MODEL = "lm.synthetic_packed_input.DenseLmTiny"
-TRAIN_SCOPES = ("atten", "ffn", "norm", "embed", "head_loss",
-                "optimizer_update")
-SERVE_SCOPES = ("atten", "ffn", "norm", "embed", "ragged_attend", "kv_write",
-                "head_sample")
+# the blocks of the tiny dense train and ragged steps, and what
+# MultiHeadedAttention names inside `atten` in both: read from the one tree
+_DENSE = ("atten", "qkv_proj", "rope", "out_proj", "ffn", "norm", "embed")
+TRAIN_SCOPES = tuple(s for s in observe_schema.DEVICE_SCOPES if s in _DENSE + (
+    "head_loss", "optimizer_update"))
+SERVE_SCOPES = tuple(s for s in observe_schema.DEVICE_SCOPES if s in _DENSE + (
+    "ragged_attend", "kv_write", "head_sample"))
 SERVE_SPANS = ("lingvo/serve/step",) + tuple(
     "lingvo/serve/" + p for p in trace_lib.STEP_PHASES if p != "draft")
 TRAIN_SPANS = ("lingvo/train/loop", "lingvo/train/infeed_get",
@@ -448,3 +460,155 @@ class TestScopeNames:
                                     np.array([4, 3]), 4)
     for row, n in zip(out, (4, 3)):
       assert list(row) == _GreedyRef(task, theta, [1] * n, 4)
+
+
+# -- the one declared tree (observe.schema.DEVICE_SCOPES) ---------------------
+
+_PROGRAMS = ("dense_train", "dense", "smallthinker", "phi4flash",
+             "pallas_attend")
+
+
+def _Avals(tree):
+  """Shapes for `lower`: a dispatched step's states were donated."""
+  return jax.tree_util.tree_map(
+      lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+      if hasattr(x, "shape") else x, tree)
+
+
+def _Lowered(program, tmp):
+  """The program's jitted step, lowered on what it is really called with:
+  the tiny dense train step, the ragged step of a tiny engine of each family
+  (tests/test_head_cols), and the Pallas lowerings of the ragged attend, the
+  differential attend and the page write in interpret mode (a CPU engine
+  takes their XLA twins)."""
+  if program == "dense_train":
+    task, prog = _TrainProgram(tmp)
+    state = task.CreateTrainState(jax.random.PRNGKey(0))
+    batch = prog._PutBatch(prog.input_generator.GetPreprocessedInputBatch())
+    return prog._GetStepFn(state).lower(state, batch)
+  if program == "pallas_attend":
+    from lingvo_tpu.core import ragged as ragged_lib
+    from lingvo_tpu.ops import diff_attend
+    from lingvo_tpu.ops import ragged_block_attend
+    q = jnp.zeros((8, 1, 8), jnp.float32)
+    pool = jnp.zeros((7, 8, 1, 8), jnp.float32)
+    tables = jnp.arange(6, dtype=jnp.int32).reshape(3, 2)
+    row_of = jnp.asarray([0, 1, 1, 1, 2, 2, 2, 0], jnp.int32)
+    q_end = jnp.asarray([9, 5, 6, 7, 12, 13, 14, 0], jnp.int32)
+    rows = jax.tree_util.tree_map(
+        jnp.asarray, ragged_lib.BuildRaggedRows([1, 3, 3], [8, 4, 11], 8, 4))
+    wide = jnp.zeros((7, 8, 2, 64), jnp.float32)
+    new = jnp.zeros((8, 2, 64), jnp.float32)
+
+    dq = jnp.zeros((8, 8, 8), jnp.float32)
+    dpool = jnp.zeros((7, 8, 4, 8), jnp.float32)
+
+    def _All(q, pool, wide, new, dq, dpool):
+      with observe.Scope("atten"):
+        with observe.Scope("ragged_attend"):
+          ctx = ragged_block_attend.RaggedAttend(
+              q, pool, pool, tables, row_of, q_end, page_size=8,
+              lowering="pallas", interpret=True)
+        with observe.Scope("diff_attend"):
+          diff = diff_attend.DiffAttend(
+              dq, dpool, dpool, tables, row_of, q_end, 0.3, page_size=8,
+              lowering="pallas", interpret=True)
+        with observe.Scope("kv_write"):
+          return ctx, diff, diff_attend.WritePages(
+              wide, wide, new, new, tables, rows, lowering="pallas",
+              interpret=True)
+
+    return jax.jit(_All).lower(q, pool, wide, new, dq, dpool)
+  from lingvo_tpu.serving import engine as engine_lib
+  from tests.test_head_cols import _FAMILIES
+  task, theta = _FAMILIES[program](jnp.float32)
+  eng = engine_lib.ServingLoop(
+      task, theta, page_size=8, num_pages=48, max_batch=4, max_seq_len=128,
+      prefill_token_budget=8)
+  seen = []
+  inner = eng._compile_log.Call
+
+  def _Call(name, fn, *args):
+    if name == "ragged":
+      seen.append((fn, _Avals(args)))
+    return inner(name, fn, *args)
+
+  eng._compile_log.Call = _Call
+  eng.Submit([5, 9, 2], 6, eos_id=None, seed=11)
+  eng.StepOnce()
+  fn, args = seen[-1]
+  return fn.lower(*args)
+
+
+@pytest.fixture(scope="module")
+def scoped_programs(tmp_path_factory):
+  """{program: (lowered text, op_names of the compiled HLO)}, scopes on."""
+  out = {}
+  for program in _PROGRAMS:
+    lowered = _Lowered(program, str(tmp_path_factory.mktemp(program)))
+    out[program] = (lowered.as_text(), _OpNames(lowered.compile().as_text()))
+  return out
+
+
+class TestDeclaredTree:
+
+  @pytest.mark.parametrize("scope", list(observe_schema.DEVICE_SCOPES))
+  def test_every_declared_scope_is_on_some_compiled_op(self, scoped_programs,
+                                                       scope):
+    names = [n for _, ns in scoped_programs.values() for n in ns]
+    assert any(f"/{scope}/" in n or f"({scope})" in n for n in names), scope
+
+  @pytest.mark.parametrize("scope", list(observe_schema.DEVICE_SCOPES))
+  def test_a_scopes_parent_is_declared(self, scope):
+    parent, holds = observe_schema.DEVICE_SCOPES[scope]
+    assert parent is None or parent in observe_schema.DEVICE_SCOPES
+    assert holds and parent != scope
+
+  @pytest.mark.parametrize("scope", list(observe_schema.DEVICE_SCOPES))
+  def test_the_docs_table_is_the_tree(self, scope):
+    """docs/observability.md, "Device scopes": one row a scope, with the
+    tree's parent and a reader."""
+    docs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "observability.md")
+    with open(docs) as f:
+      rows = [ln.split("|") for ln in f if ln.startswith(f"| `{scope}` ")]
+    assert len(rows) == 1, scope
+    parent = observe_schema.DEVICE_SCOPES[scope][0]
+    assert rows[0][2].strip() == (f"`{parent}`" if parent else "")
+    assert rows[0][3].strip() and rows[0][5].strip()
+
+  def test_an_undeclared_scope_raises(self):
+    with pytest.raises(KeyError, match="nope"):
+      observe.Scope("nope")
+    with observe.Scope("atten"):
+      pass
+
+  def test_the_program_enters_scopes_through_the_tree_alone(self):
+    """No op can carry a scope of the program's that is not declared: the
+    one `jax.named_scope` call under lingvo_tpu/ is observe.Scope's."""
+    root = os.path.dirname(os.path.abspath(observe.__file__))
+    pkg = os.path.dirname(root)
+    hits = []
+    for d, _, files in os.walk(pkg):
+      for f in files:
+        if f.endswith(".py"):
+          path = os.path.join(d, f)
+          with open(path) as fh:
+            if "named_scope(" in fh.read():
+              hits.append(os.path.relpath(path, pkg))
+    assert hits == [os.path.join("observe", "schema.py")], hits
+
+  @pytest.mark.parametrize("program", _PROGRAMS)
+  def test_scopes_are_metadata_only(self, scoped_programs, program,
+                                    monkeypatch, tmp_path):
+    """The lowered program without its metadata (`as_text()` prints no
+    locations) is byte for byte what it is with every scope a null context:
+    no shape, number, operand or instruction depends on a scope."""
+    import contextlib
+    monkeypatch.setattr(observe, "Scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _Lowered(program, str(tmp_path))
+    assert not any(
+        s in n for n in _OpNames(bare.as_text(debug_info=True))
+        for s in ("qkv_proj", "kv_layout", "ssm_conv", "moe_dispatch"))
+    assert bare.as_text() == scoped_programs[program][0]
