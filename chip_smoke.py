@@ -11,7 +11,10 @@ registered widths of `lm.synthetic_packed_input.DenseLm1B` (d=2048, 16 heads x
            mode), against its XLA twin on the same chip; the grouped ragged
            kernel too, which DenseLm1B's plain heads do not reach: 28 query
            heads over 4 KV heads of 128 on bf16 pages, a decode row, a short
-           row and a chunk in one pack, without and with a 4096-token window
+           row and a chunk in one pack, without and with a 4096-token window;
+           and a kernel of each family handed the step's plan (its query-block
+           descriptors, the page write's pairs) against the same kernel
+           building its own
   train    model_registry -> TrainProgram -> ExecutorTpu.Start(): two loops of
            tpu_steps_per_loop with flash attention and 'dots' remat, an async
            checkpoint (SaveAsync) and the final one, both restored
@@ -150,7 +153,8 @@ class KernelCase(NamedTuple):
   """One Pallas kernel variant and its XLA twin.
 
   inputs: the entry of `KernelInputs` it runs on; fn(pallas) -> callable over
-  that tuple, the Pallas lowering when `pallas` else the twin.
+  that tuple, the Pallas lowering when `pallas` else the twin (a `_plan`
+  case: the kernel both times, handed the step's plan when `pallas`).
   `tests/test_chip_compile.py` compiles fn(True) of every case for a
   described v5e from the shapes of its inputs (jax.eval_shape), so this table
   is the single list of what must lower.
@@ -382,29 +386,75 @@ def KernelCases(size: Size) -> list[KernelCase]:
         **_Lowering(pallas))
 
   def _Ragged(pallas):
-    return lambda q, k, v, ks, vs, tables, row_of, q_end, q_start, lo, hi: (
-        ragged_block_attend.RaggedAttend(
-            q, k, v, tables, row_of, q_end, page_size=page, k_scale=ks,
-            v_scale=vs, q_start=q_start, anc_lo=lo, anc_hi=hi,
-            **_Lowering(pallas)))
+    def _Run(q, k, v, ks, vs, tables, row_of, q_end, q_start, lo, hi, **kw):
+      return ragged_block_attend.RaggedAttend(
+          q, k, v, tables, row_of, q_end, page_size=page, k_scale=ks,
+          v_scale=vs, q_start=q_start, anc_lo=lo, anc_hi=hi,
+          **_Lowering(pallas), **kw)
+    return _Run
 
   def _RaggedGrouped(window):
-    return lambda pallas: lambda q, k, v, tables, row_of, q_end: (
+    return lambda pallas: lambda q, k, v, tables, row_of, q_end, **kw: (
         ragged_block_attend.RaggedAttend(
             q, k, v, tables, row_of, q_end, page_size=page, window=window,
-            **_Lowering(pallas)))
+            **_Lowering(pallas), **kw))
 
   def _DiffAttend(window):
-    return lambda pallas: lambda q, k, v, tables, row_of, q_end: (
+    return lambda pallas: lambda q, k, v, tables, row_of, q_end, **kw: (
         diff_attend.DiffAttend(
             q, k, v, tables, row_of, q_end, 0.35, page_size=page,
-            window=window, **_Lowering(pallas)))
+            window=window, **_Lowering(pallas), **kw))
 
   def _DiffWrite(pallas):
     # all pages but the last: only the scatter's padding writes the trash page
-    return lambda k, v, k_new, v_new, tables, rows: tuple(
+    return lambda k, v, k_new, v_new, tables, rows, **kw: tuple(
         pool[:-1] for pool in diff_attend.WritePages(
-            k, v, k_new, v_new, tables, rows, **_Lowering(pallas)))
+            k, v, k_new, v_new, tables, rows, **_Lowering(pallas), **kw))
+
+  # The step's plan (core/attention.BuildRaggedPlan) against the call that
+  # builds its own: the kernel on BOTH sides, handed its descriptors
+  # (`planned`) or not, which must agree to the bit.
+  def _Blocks(key, tables, row_of, q_end, *tree):
+    return {key: ragged_block_attend.BuildAttendPlan(
+        key, row_of, q_end, *tree, b=tables.shape[0],
+        t_pages=tables.shape[1])}
+
+  def _RaggedPlan(planned):
+    def _Run(q, k, v, ks, vs, tables, row_of, q_end, q_start, lo, hi):
+      key = ragged_block_attend.AttendPlanKey(
+          q.shape[1], k.shape[2], q.shape[2], page, q.dtype, k.dtype,
+          lowering="pallas")
+      return _Ragged(True)(
+          q, k, v, ks, vs, tables, row_of, q_end, q_start, lo, hi,
+          plan=_Blocks(key, tables, row_of, q_end, q_start, lo, hi)
+          if planned else None)
+    return _Run
+
+  def _GroupedPlan(planned):
+    def _Run(q, k, v, tables, row_of, q_end):
+      key = ragged_block_attend.AttendPlanKey(
+          q.shape[1], k.shape[2], q.shape[2], page, q.dtype, k.dtype,
+          window=s.grouped_window, tree=False, lowering="pallas")
+      return _RaggedGrouped(s.grouped_window)(True)(
+          q, k, v, tables, row_of, q_end,
+          plan=_Blocks(key, tables, row_of, q_end) if planned else None)
+    return _Run
+
+  def _DiffPlan(planned):
+    def _Run(q, k, v, tables, row_of, q_end):
+      key = diff_attend.DiffPlanKey(
+          q.shape[1], k.shape[2], q.shape[2], page, q.dtype, k.dtype,
+          window=s.diff_window, lowering="pallas")
+      return _DiffAttend(s.diff_window)(True)(
+          q, k, v, tables, row_of, q_end,
+          plan=_Blocks(key, tables, row_of, q_end) if planned else None)
+    return _Run
+
+  def _DiffWritePlan(planned):
+    return lambda k, v, k_new, v_new, tables, rows: _DiffWrite(True)(
+        k, v, k_new, v_new, tables, rows,
+        plan=diff_attend.BuildWritePlan(rows, *tables.shape, page)
+        if planned else None)
 
   def _SelectiveScan(pallas):
     return lambda delta, x, b, c, a, d, state, rows: (
@@ -456,6 +506,11 @@ def KernelCases(size: Size) -> list[KernelCase]:
       KernelCase("diff_attend_window", "diff_attend",
                  _DiffAttend(s.diff_window)),
       KernelCase("diff_write_pages", "diff_write", _DiffWrite),
+      KernelCase("ragged_attend_tree_plan", "ragged_tree", _RaggedPlan),
+      KernelCase("ragged_attend_grouped_window_plan", "ragged_grouped",
+                 _GroupedPlan),
+      KernelCase("diff_attend_window_plan", "diff_attend", _DiffPlan),
+      KernelCase("diff_write_pages_plan", "diff_write", _DiffWritePlan),
       KernelCase("selective_scan_packed", "selective_scan", _SelectiveScan),
       KernelCase("flash_decode", "flash_decode", _FlashDecode),
       KernelCase("fused_xent_fwd", "xent", _XentFwd),

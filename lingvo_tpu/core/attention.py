@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,7 @@ from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
 from lingvo_tpu.core import py_utils
 from lingvo_tpu.core import quant_utils
+from lingvo_tpu.core import ragged
 from lingvo_tpu.core.nested_map import NestedMap
 from lingvo_tpu.core.py_utils import WeightInit, WeightParams
 from lingvo_tpu.parallel import mesh as mesh_lib
@@ -94,6 +95,41 @@ def _FlashUnderMesh(q, k, v, segment_ids, causal: bool):
   # over (the setting ring_attention's and ulysses' shard_maps use)
   return mesh_lib.ShardMap(_Kernel, mesh, in_specs=specs, out_specs=spec,
                            check_vma=False)(*args)
+
+
+class RaggedPlan(NamedTuple):
+  """What a serving step's attention derives from its rows, static shapes
+  and a layer's window alone, and so the same in every layer: a stack builds
+  it once a step, before its scans over layers (`BuildRaggedPlan`), and hands
+  it to each layer's RaggedStep beside `rows`."""
+  tokens: ragged.TokenView
+  blocks: dict    # {ops/ragged_block_attend.PlanKey: AttendPlan}, one for
+  #                 every distinct key a kernel of the stack is called at
+  writes: object  # ops/diff_attend.WritePlan where a layer of the stack
+  #                 writes whole pages, else None
+
+
+def BuildRaggedPlan(keys, rows, b: int, t_pages: int,
+                    page_writes: bool = False) -> RaggedPlan:
+  """keys: the PlanKey of every attention call of the step (what the
+  stack's layers declare, `RaggedPlanKey`); rows: its RaggedRows; block
+  tables [b, t_pages]. page_writes: some layer writes through
+  ops/diff_attend.WritePages' kernel. None where no layer attends."""
+  from lingvo_tpu.ops import diff_attend
+  from lingvo_tpu.ops import ragged_block_attend
+  if not keys:
+    return None     # no layer of the stack attends
+  (page_size,) = {k.page_size for k in keys}
+  with observe.Scope("attend_plan"):
+    tokens = ragged.BuildTokenView(rows, b, t_pages, page_size)
+    blocks = {}
+    for key in sorted({k for k in keys if k.kernel}):
+      tree = (tokens.q_start, rows.anc_lo, rows.anc_hi) if key.tree else ()
+      blocks[key] = ragged_block_attend.BuildAttendPlan(
+          key, tokens.row, tokens.q_end, *tree, b=b, t_pages=t_pages)
+    writes = (diff_attend.BuildWritePlan(rows, b, t_pages, page_size)
+              if page_writes else None)
+  return RaggedPlan(tokens, blocks, writes)
 
 
 class PerDimScaleLayer(base_layer.BaseLayer):
@@ -720,6 +756,26 @@ class MultiHeadedAttention(base_layer.BaseLayer):
             self.RaggedQueryBlock(page_size, kv_cache_dtype),
             self.RaggedQueriesPerToken()[0]))
 
+  def _RaggedEligible(self, cached_states) -> bool:
+    """Whether RaggedStep over these paged states calls RaggedAttend (else
+    its gather-dense fallback)."""
+    page_size = cached_states.key.shape[-3]
+    if "key_scale" in cached_states:
+      return self.QuantizedDecodeEligible(page_size)
+    return self.BlockDecodeEligible(page_size)
+
+  def RaggedPlanKey(self, cached_states):
+    """The ops/ragged_block_attend.PlanKey of this layer's RaggedStep over
+    these paged states (its own, or stacked over a repeat axis): what a
+    stack hands `BuildRaggedPlan`, and what RaggedAttend looks its
+    descriptors up by."""
+    from lingvo_tpu.ops import ragged_block_attend
+    return ragged_block_attend.AttendPlanKey(
+        self.p.num_heads, self._num_kv_heads, self._dim_per_head,
+        cached_states.key.shape[-3], self.fprop_dtype, cached_states.key.dtype,
+        window=self.p.window,
+        lowering="auto" if self._RaggedEligible(cached_states) else "xla")
+
   def BlockDecodeEligible(self, page_size: int) -> bool:
     """Same gate family as PagedDecodeEligible, for the block-table kernel:
     plain masked-softmax attention only. Ineligible configs run PagedStep's
@@ -848,7 +904,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     return self._PostProj(theta, ctx), new_states
 
   def RaggedStep(self, theta, query_vec, cached_states: NestedMap,
-                 block_tables, rows, layer=None):
+                 block_tables, rows, layer=None, plan=None):
     """One PACKED continuous-batching step (core/ragged.py RaggedRows).
 
     query_vec: [1, T, D] — all rows' tokens flattened on one token axis;
@@ -870,6 +926,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     ([L, NP, ...], RepeatedTransformerLayer's scan carry). The stack is
     then read and written as ONE pool of L * NP pages with this layer's at
     page base layer * NP, so no op slices or re-assembles a layer's pool.
+
+    plan: the step's RaggedPlan (a stack builds it once, before its scan
+    over layers); None: the layer derives its token view here and the
+    kernel's call its descriptors.
     """
     from lingvo_tpu.ops import block_decode
     from lingvo_tpu.ops import ragged_block_attend
@@ -887,16 +947,14 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       cached_states = cached_states.Transform(
           lambda x: x.reshape((-1,) + x.shape[2:]))
     k_pool, v_pool = cached_states.key, cached_states.value
-    b, t_pages = block_tables.shape
-    t = query_vec.shape[1]
-    pos = rows.pos.astype(jnp.int32)                               # [T]
     valid = rows.valid
-    row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
+    tokens = (plan.tokens if plan is not None else ragged.BuildTokenView(
+        rows, *block_tables.shape, page_size))
+    row, q_start = tokens.row, tokens.q_start                      # [T]
     # Tree rows decouple the KV SLOT (pos, DFS-ordered, collision-free)
     # from the LOGICAL position (pos_ids = q_pos + depth) a token embeds
     # at; on chain rows pos_ids == pos bitwise.
     rot_pos = rows.pos_ids.astype(jnp.int32)
-    q_start = rows.row_q_pos.astype(jnp.int32)[row]                # [T]
     with observe.Scope("qkv_proj"):
       q = self._HeadsProj(theta, "query", query_vec)               # [1,T,N,H]
       k_new = self._HeadsProj(theta, "key", query_vec)
@@ -913,12 +971,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     # padding tokens write to the trash page (this layer's page np_total - 1).
     # A table entry is clipped to the layer's range BEFORE the base is
     # added, so no write and no read can reach another layer's pages
-    logical = jnp.clip(pos // page_size, 0, t_pages - 1)
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-    phys = jnp.where(valid, tables[row, logical], np_total - 1) + base  # [T]
+    phys = jnp.where(valid, tables[row, tokens.logical],
+                     np_total - 1) + base                          # [T]
     tables = tables + base
-    off = jnp.where(valid, pos % page_size,
-                    jnp.arange(t, dtype=jnp.int32) % page_size)
+    off = tokens.off
+    eligible = self._RaggedEligible(cached_states)
     quantized = "key_scale" in cached_states
     k_scale = v_scale = None
     if quantized:
@@ -937,18 +995,16 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if layer is not None:
       new_states = new_states.Transform(
           lambda x: x.reshape((num_layers, -1) + x.shape[1:]))
-    eligible = (self.QuantizedDecodeEligible(page_size) if quantized
-                else self.BlockDecodeEligible(page_size))
-    # token t attends over its row's slots [0, pos[t]]; q_end = 0 marks
-    # padding (the ragged op emits exact zeros there)
-    q_end = jnp.where(valid, pos + 1, 0)
     if eligible:
+      # token t attends over its row's slots [0, pos[t]]; q_end = 0 marks
+      # padding (the ragged op emits exact zeros there)
       with observe.Scope("ragged_attend"):
         ctx = ragged_block_attend.RaggedAttend(
-            q[0], k_pool, v_pool, tables, row, q_end,
+            q[0], k_pool, v_pool, tables, row, tokens.q_end,
             page_size=page_size, k_scale=k_scale, v_scale=v_scale,
             q_start=q_start, anc_lo=rows.anc_lo, anc_hi=rows.anc_hi,
-            window=p.window)[None]
+            window=p.window,
+            plan=None if plan is None else plan.blocks)[None]
     else:
       self._RequirePlainKv("RaggedStep's gather-dense fallback")
       # gather-dense fallback at token granularity: each token is a batch
@@ -961,10 +1017,11 @@ class MultiHeadedAttention(base_layer.BaseLayer):
             k_dense, block_decode.GatherScales(k_scale, tables))
         v_dense = kv_quant.DequantKv(
             v_dense, block_decode.GatherScales(v_scale, tables))
-      slot = jnp.arange(t_pages * page_size)[None, None, None, :]
+      slot = jnp.arange(
+          block_tables.shape[1] * page_size)[None, None, None, :]
       # padding tokens see slot 0 only (garbage, but never an all-masked
       # softmax row)
-      horizon = jnp.where(valid, pos, 0)
+      horizon = jnp.where(valid, rows.pos.astype(jnp.int32), 0)
       ok = ragged_block_attend._AncestorOk(
           slot, slot - q_start[:, None, None, None],
           rows.anc_lo[:, None, None, None], rows.anc_hi[:, None, None, None])
@@ -1356,18 +1413,28 @@ class DifferentialAttention(base_layer.BaseLayer):
     del theta, num_slots
     return NestedMap()
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=0):
+  def RaggedPlanKey(self, pool):
+    """The ops/ragged_block_attend.PlanKey of this layer's RaggedStep over
+    the stack's pool (`shared.kv_pool`)."""
+    from lingvo_tpu.ops import diff_attend
+    page_size = pool.key.shape[1]
+    return diff_attend.DiffPlanKey(
+        self.p.num_heads, self.p.num_kv_heads, self._h, page_size,
+        self.fprop_dtype, pool.key.dtype, window=self.p.window,
+        lowering="auto" if self.BlockDecodeEligible(page_size) else "xla")
+
+  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=0,
+                 plan=None):
     """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own
-    block table or, where it owns no pages, the owning layer's."""
+    block table or, where it owns no pages, the owning layer's; plan: the
+    step's RaggedPlan, or None (MultiHeadedAttention.RaggedStep)."""
     from lingvo_tpu.ops import diff_attend
     p = self.p
     th = self.CastTheta(theta)
     pool = shared.kv_pool
     np_total, page_size = pool.key.shape[:2]
-    b = table.shape[0]
-    pos = rows.pos.astype(jnp.int32)
-    valid = rows.valid
-    row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
+    tokens = (plan.tokens if plan is not None else ragged.BuildTokenView(
+        rows, *table.shape, page_size))
     tables = jnp.clip(table.astype(jnp.int32), 0, np_total - 1)
     with observe.Scope("qkv_proj"):
       q = self._Query(th, x[0])                                  # [T, N, H]
@@ -1379,14 +1446,15 @@ class DifferentialAttention(base_layer.BaseLayer):
       with observe.Scope("kv_write"):
         key, value = diff_attend.WritePages(
             pool.key, pool.value, k_new, v_new, tables, rows,
-            lowering=lowering)
+            lowering=lowering, plan=None if plan is None else plan.writes)
       pool = NestedMap(key=key, value=value)
       shared = shared.Copy()
       shared.kv_pool = pool
     lam, lam_init = self._Lambda(th, depth)
     with observe.Scope("diff_attend"):
       diff = diff_attend.DiffAttend(
-          q, pool.key, pool.value, tables, row, jnp.where(valid, pos + 1, 0),
-          lam, page_size=page_size, window=p.window, lowering=lowering)
+          q, pool.key, pool.value, tables, tokens.row, tokens.q_end, lam,
+          page_size=page_size, window=p.window, lowering=lowering,
+          plan=None if plan is None else plan.blocks)
     with observe.Scope("out_proj"):
       return self._Finish(th, diff, lam_init)[None], states, shared

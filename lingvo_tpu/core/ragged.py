@@ -84,6 +84,35 @@ class RaggedRows(NamedTuple):
 MAX_TREE_COLS = 64  # anc_lo/anc_hi bit budget; scheduler clamps width first.
 
 
+class TokenView(NamedTuple):
+  """Where each packed token's K/V lands and what it may attend, as every
+  attention layer of a step derives it from RaggedRows and the shape of the
+  block tables ([b, t_pages], pages of page_size slots). All [T] int32; a
+  layer adds what is its own: the table's lookup and its pool's page base."""
+  row: jnp.ndarray      # the token's block-table row, inside the table
+  logical: jnp.ndarray  # the logical page of its slot, inside the table
+  off: jnp.ndarray      # its offset in that page (a padding token's: its
+  #                       column's, so padding writes spread over the trash
+  #                       page)
+  q_end: jnp.ndarray    # one past its highest attendable slot; 0 = padding
+  q_start: jnp.ndarray  # its row's first slot of this step (tree masks)
+
+
+def BuildTokenView(rows: RaggedRows, b: int, t_pages: int,
+                   page_size: int) -> TokenView:
+  """The device-side token view of `rows` (jnp arrays) against block tables
+  [b, t_pages]."""
+  pos = rows.pos.astype(jnp.int32)
+  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
+  return TokenView(
+      row=row,
+      logical=jnp.clip(pos // page_size, 0, t_pages - 1),
+      off=jnp.where(rows.valid, pos % page_size,
+                    jnp.arange(pos.shape[0], dtype=jnp.int32) % page_size),
+      q_end=jnp.where(rows.valid, pos + 1, 0),
+      q_start=rows.row_q_pos.astype(jnp.int32)[row])
+
+
 def TreeDepths(parents) -> np.ndarray:
   """Draft-node depths from DFS parent pointers.
 

@@ -570,10 +570,10 @@ class Mamba1Layer(base_layer.BaseLayer):
         conv=jnp.zeros((num_slots, p.conv_width - 1, self._e), jnp.float32))
 
   def RaggedStep(self, theta, x, states, shared, rows, table=None,
-                 depth=None):
+                 depth=None, plan=None):
     """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
     ([1, T, D], new states, shared)."""
-    del table, depth
+    del table, depth, plan
     from lingvo_tpu.ops import selective_scan
     th = self.CastTheta(theta)
     k = self.p.conv_width
@@ -651,7 +651,7 @@ class GatedMemoryUnit(base_layer.BaseLayer):
     return NestedMap()
 
   def RaggedStep(self, theta, x, states, shared, rows, table=None,
-                 depth=None):
-    del rows, table
+                 depth=None, plan=None):
+    del rows, table, plan
     out, shared = self.FProp(theta, x, shared, depth=depth)
     return out, states, shared

@@ -198,16 +198,27 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
             theta.atten, x, cached_states, block_tables, q_pos, in_len)
       return query_vec + out, new_states
 
+  def RaggedPlanKeys(self, cached_states) -> list:
+    """The mixer's attention call over these paged states, as the key its
+    part of the step's plan is built for (MultiHeadedAttention.
+    RaggedPlanKey); a mixer that is no attention declares none."""
+    if not hasattr(self.atten, "RaggedPlanKey"):
+      return []
+    return [self.atten.RaggedPlanKey(cached_states)]
+
   def RaggedStep(self, theta, query_vec, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False, layer=None):
+                 ssm_col_states: bool = False, layer=None, plan=None):
     """Packed-token continuous-batching step (core/ragged.py RaggedRows);
     query_vec [1, T, D]. Same pre-LN/residual wrapper and spec-verify
     dispatch as PagedStep — only the inner mixer contract changes.
     layer: the mixer's state is stacked over a repeat axis and this is
-    its index there (MultiHeadedAttention.RaggedStep); None = its own."""
+    its index there (MultiHeadedAttention.RaggedStep); None = its own.
+    plan: the step's attention.RaggedPlan, for a mixer that is attention."""
     kw = {} if layer is None else {"layer": layer}
     if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
       kw["collect_col_states"] = True
+    if plan is not None and hasattr(self.atten, "RaggedPlanKey"):
+      kw["plan"] = plan
     with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
     with observe.Scope("atten"):
@@ -351,12 +362,15 @@ class TransformerLayer(base_layer.BaseLayer):
     new_states.self_atten = new_sa
     return out, new_states
 
+  def RaggedPlanKeys(self, cached_states) -> list:
+    return self.self_atten.RaggedPlanKeys(cached_states.self_atten)
+
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False, layer=None):
+                 ssm_col_states: bool = False, layer=None, plan=None):
     routed = self._RouterLogits(theta, inputs)
     x, new_sa = self.self_atten.RaggedStep(
         theta.self_atten, inputs, cached_states.self_atten, block_tables,
-        rows, ssm_col_states=ssm_col_states, layer=layer)
+        rows, ssm_col_states=ssm_col_states, layer=layer, plan=plan)
     if "fflayer" not in cached_states:
       out = self.fflayer.FProp(theta.fflayer, x, **routed)
       return out, NestedMap(self_atten=new_sa)
@@ -506,26 +520,45 @@ class StackedTransformerLayers(base_layer.BaseLayer):
       x = self.final_ln.FProp(theta.final_ln, x)
     return x, new_states
 
+  def _LayerStates(self, cached_states, i: int, pool):
+    """Layer i's paged states: its own, or (a block of two kinds of layer,
+    PageWindows) the block's one pool as its `self_atten`."""
+    states_i = cached_states.x_layers[i]
+    if pool is not None:
+      states_i = states_i.Copy()
+      states_i.self_atten = pool
+    return states_i
+
+  def RaggedPlanKeys(self, cached_states) -> list:
+    """Every layer's (TransformerLayer.RaggedPlanKeys), in stack order."""
+    pool = cached_states.get("kv_pool")
+    return [key for i, layer in enumerate(self.x_layers)
+            for key in layer.RaggedPlanKeys(
+                self._LayerStates(cached_states, i, pool))]
+
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False, layer=None):
+                 ssm_col_states: bool = False, layer=None, plan=None):
     """layer: None here (distinct layers, each with a pool of its own); an
     index when this stack is the body of a RepeatedTransformerLayer, whose
     stacked states every x_layer then addresses by it. A block of two
     kinds of layer (PageWindows) hands its one pool from layer to layer
-    and each layer its own table of `block_tables` [layers, B, t_pages]."""
+    and each layer its own table of `block_tables` [layers, B, t_pages].
+    plan: the step's attention.RaggedPlan from the stack this one is the
+    body of; None: built here, once for all the layers."""
     kw = {"ssm_col_states": True} if ssm_col_states else {}
     if layer is not None:
       kw["layer"] = layer
+    if plan is None:
+      plan = attention_lib.BuildRaggedPlan(
+          self.RaggedPlanKeys(cached_states), rows, *block_tables.shape[-2:])
     x = inputs
     new_states = NestedMap(x_layers=[])
     pool = cached_states.get("kv_pool")
     for i, x_layer in enumerate(self.x_layers):
-      states_i, tables_i = cached_states.x_layers[i], block_tables
-      if pool is not None:
-        states_i, tables_i = states_i.Copy(), block_tables[i]
-        states_i.self_atten = pool
+      states_i = self._LayerStates(cached_states, i, pool)
+      tables_i = block_tables if pool is None else block_tables[i]
       x, ns = x_layer.RaggedStep(theta.x_layers[i], x, states_i, tables_i,
-                                 rows, **kw)
+                                 rows, plan=plan, **kw)
       if pool is not None:
         pool, ns.self_atten = ns.self_atten, NestedMap()
       new_states.x_layers.append(ns)
@@ -701,6 +734,10 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
                                    (theta.body, cached_states.body))
     return out, NestedMap(body=new_states)
 
+  def RaggedPlanKeys(self, cached_states) -> list:
+    """The body's, once a repeat: one key an attention call of the step."""
+    return self.body.RaggedPlanKeys(cached_states.body) * self.p.num_layers
+
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
                  ssm_col_states: bool = False):
     """The stacked states are the scan's CARRY, one buffer from the caller's
@@ -709,6 +746,11 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
     for this step only (`col_states`) is its layer's own: a scanned output,
     which comes back stacked."""
     kw = {"ssm_col_states": True} if ssm_col_states else {}
+    # what the rows alone decide is built here, once, and reaches every
+    # trip as an invariant of the loop
+    plan = attention_lib.BuildRaggedPlan(
+        self.body.RaggedPlanKeys(cached_states.body), rows,
+        *block_tables.shape[-2:])
 
     def _ByPath(tree):
       return dict(jax.tree_util.tree_flatten_with_path(tree)[0])
@@ -735,7 +777,7 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
           lambda path, mine, stack: stack if _Whole(path, stack) else mine,
           theta_i, theta.body)
       x, new_states = self.body.RaggedStep(theta_i, x, states, block_tables,
-                                           rows, layer=idx, **kw)
+                                           rows, layer=idx, plan=plan, **kw)
       new = _ByPath(new_states)
       states = jax.tree_util.tree_map_with_path(
           lambda path, _: new[path], states)
@@ -793,7 +835,8 @@ class SharedStateLayer(base_layer.BaseLayer):
   (ssm.Mamba1Layer, ssm.GatedMemoryUnit, attention.DifferentialAttention):
   `FProp(theta, x, shared, paddings, segment_ids, depth) -> (out, shared)`,
   `InitPagedStates(theta, num_slots)`, and `RaggedStep(theta, x, states,
-  shared, rows, table, depth) -> (out, states, shared)`."""
+  shared, rows, table, depth, plan) -> (out, states, shared)` (`plan`: the
+  step's attention.RaggedPlan, which a mixer that is no attention drops)."""
 
   @classmethod
   def Params(cls):
@@ -827,12 +870,13 @@ class SharedStateLayer(base_layer.BaseLayer):
   def InitPagedStates(self, theta, num_slots):
     return self.atten.InitPagedStates(theta.atten, num_slots)
 
-  def RaggedStep(self, theta, x, states, shared, rows, table, depth):
+  def RaggedStep(self, theta, x, states, shared, rows, table, depth, plan):
     with observe.Scope("norm"):
       normed = self.ln.FProp(theta.ln, x)
     with observe.Scope("atten"):
       out, states, shared = self.atten.RaggedStep(
-          theta.atten, normed, states, shared, rows, table=table, depth=depth)
+          theta.atten, normed, states, shared, rows, table=table, depth=depth,
+          plan=plan)
       x = x + out
     return self.fflayer.FProp(theta.fflayer, x), states, shared
 
@@ -943,6 +987,19 @@ class BlockSequence(base_layer.BaseLayer):
         self._bodies, self._repeats) for _ in range(reps) for l in layers
             if getattr(l.atten.p, "kv_owner", False)]
 
+  def _AttentionMixers(self) -> list:
+    """The mixer of every layer of the stack that attends over pages, in
+    stack order (a repeated block's once a repeat)."""
+    return [l.atten for layers, reps in zip(self._bodies, self._repeats)
+            for _ in range(reps) for l in layers
+            if hasattr(l.atten, "RaggedPlanKey")]
+
+  def RaggedPlanKeys(self, cached_states) -> list:
+    """One key an attention call of the step (DifferentialAttention.
+    RaggedPlanKey): what `RaggedStep` builds the step's plan for."""
+    return [a.RaggedPlanKey(cached_states.kv_pool)
+            for a in self._AttentionMixers()]
+
   def SharedKvReadLayers(self) -> int:
     """Layers that read pages they do not own."""
     return sum(reps for layers, reps in zip(self._bodies, self._repeats)
@@ -1034,6 +1091,12 @@ class BlockSequence(base_layer.BaseLayer):
     if self._memory_dim:
       shared.memory = jnp.zeros(inputs.shape[:2] + (self._memory_dim,),
                                 inputs.dtype)
+    # built once, before the blocks' scans, and an invariant of each
+    keys = self.RaggedPlanKeys(cached_states)
+    plan = attention_lib.BuildRaggedPlan(
+        keys, rows, *block_tables.shape[-2:],
+        page_writes=any(a.p.kv_owner and key.kernel for a, key in zip(
+            self._AttentionMixers(), keys)))
     x = inputs
     new_states = NestedMap(blocks=[])
     for b, layers in enumerate(self._bodies):
@@ -1056,7 +1119,7 @@ class BlockSequence(base_layer.BaseLayer):
           table = (mine_i[place[2]] if place[0] == "own"
                    else block_tables[place[1]])
         x, ns, shared = layer.RaggedStep(theta_j, x, states_i[j], shared,
-                                         rows, table, depth)
+                                         rows, table, depth, plan)
         return x, ns, shared
 
       x, shared, outs = self._Scan(

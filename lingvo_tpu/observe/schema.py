@@ -62,10 +62,15 @@ ENGINE_COUNTER_KEYS = (
 # Static engine configuration facts (set once at construction). `head_rows`:
 # the token columns the step program's final norm and head run over (a draw
 # a slot, and a draft source's verify lane), of the max_batch * row width +
-# prefill_token_budget it packs.
+# prefill_token_budget it packs. `attend_calls`, `attend_plans`: the attend
+# kernels the step program calls, and the sets of query-block descriptors it
+# builds for them, one for every distinct ops/ragged_block_attend.PlanKey
+# among the calls (core/attention.BuildRaggedPlan); both 0 where the twins
+# run. The counters `attend_query_blocks` ... above count what the kernels
+# are given; these two count the program.
 ENGINE_INFO_KEYS = (
     "paged_path", "kv_cache_dtype", "kv_bytes_per_token",
-    "serve_int8_weights", "head_rows",
+    "serve_int8_weights", "head_rows", "attend_calls", "attend_plans",
 )
 
 # Nested sub-dict sections always present in Stats().
@@ -313,6 +318,11 @@ WATCHDOG_STATS_KEYS = frozenset({
 DEVICE_SCOPES = {
     "embed": (None, "token embedding lookup (and the absolute position "
               "embedding where the model has one)"),
+    "attend_plan": (None, "what a serving step's attention derives from its "
+                    "rows alone, built once before the scans over layers "
+                    "(core/attention.BuildRaggedPlan): the token view, the "
+                    "kernels' query-block descriptors, the page write's "
+                    "pairs"),
     "norm": (None, "every layer norm: a block's pre-norm, the final norm, "
              "and in the serving step the gather of the head's columns"),
     "atten": (None, "a layer's sequence mixer with its residual add: "
@@ -331,14 +341,16 @@ DEVICE_SCOPES = {
                       "ragged attend kernels (named after it) and the "
                       "group's re-layout round them"),
     "attend_descriptors": ("ragged_attend", "the query-block descriptors "
-                           "the ragged kernels are prefetched with"),
+                           "of a ragged kernel called without the step's "
+                           "plan (with it: `attend_plan`)"),
     "diff_attend": ("atten", "ops/diff_attend.DiffAttend: the differential "
                     "attend kernels (named after it)"),
     "diff_layout": ("diff_attend", "round those kernels: the padded queries, "
                     "the group's re-layout in and out, the pools as they "
                     "lie, and the pairs' difference"),
-    "diff_descriptors": ("diff_attend", "the query-block descriptors the "
-                         "differential kernels are prefetched with"),
+    "diff_descriptors": ("diff_attend", "the query-block descriptors of a "
+                         "differential kernel called without the step's "
+                         "plan (with it: `attend_plan`)"),
     "ssm_in_proj": ("atten", "a Mamba-1 layer's input projection to u, z"),
     "ssm_conv": ("atten", "its causal depthwise convolution: the taps, the "
                  "slot tail's gather and its write-back"),

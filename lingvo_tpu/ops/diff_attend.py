@@ -29,9 +29,11 @@ pair's two outputs is taken after it. Both lowerings run it so:
   `[k_2i; k_2i+1]^T`, the K pair transposed, whole tiles. A block's scores
   are then the plain product `[rows, 2H] x [2H, P]` and its output `[rows,
   P] x [2H, P]^T`, with no strided load and nothing re-laid out. Block
-  descriptors, the page walk (dead pages clamp, a window's first page), the
-  block rungs and the `jit` round the call are ops/ragged_block_attend's
-  grouped kernel's; the kernel's name in a trace is `diff_attend`.
+  descriptors (one `rba.AttendPlan` a step for the layers of one window,
+  handed in as `plan`), the page walk (dead pages clamp, a window's first
+  page), the block rungs and the `jit` round the call are
+  ops/ragged_block_attend's grouped kernel's; the kernel's name in a trace
+  is `diff_attend`.
 
 What the padded form costs beside a kernel that kept H-wide queries and
 subtracted inside: the zero half of every score product, and twice the
@@ -41,6 +43,7 @@ output rows across HBM (PERF.md section 7 sizes both).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -259,8 +262,42 @@ def PageWrites(b: int, t: int, page_size: int) -> int:
   return min(t, 2 * b + t // page_size)
 
 
+class WritePlan(NamedTuple):
+  """The (row, logical page) pairs a step writes, rows in slot order (NW =
+  `PageWrites` of them, static), and what lays the new tokens out for them:
+  all of it the step's rows' alone, none of it an owner's."""
+  r: jnp.ndarray      # [NW] int32 the pair's row
+  lp: jnp.ndarray     # [NW] int32 its logical page, inside the table
+  live: jnp.ndarray   # [NW] bool; a dead pair writes the trash page, nothing
+  mask: jnp.ndarray   # [NW, 1, P] int32 the page's lanes the step writes
+  tok: jnp.ndarray    # [NW, P] int32 the packed token a lane is taken from
+
+
+def BuildWritePlan(rows, b: int, t_pages: int, page: int) -> WritePlan:
+  """rows: the step's core/ragged.RaggedRows; block tables [b, t_pages] of
+  pages of `page` tokens."""
+  t = rows.row_of.shape[0]
+  p0 = rows.row_q_pos.astype(jnp.int32)
+  n = rows.row_len.astype(jnp.int32)
+  first_page = p0 // page
+  n_pages = jnp.where(n > 0, (p0 + n - 1) // page - first_page + 1, 0)
+  cum = jnp.cumsum(n_pages)
+  i = jnp.arange(PageWrites(b, t, page), dtype=jnp.int32)
+  r = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1)
+  lp = first_page[r] + i - (cum[r] - n_pages[r])
+  live = i < cum[-1]
+  slot = lp[:, None] * page + jnp.arange(page, dtype=jnp.int32)[None]
+  mask = live[:, None] & (slot >= p0[r][:, None]) & (
+      slot < (p0 + n)[r][:, None])
+  tok = jnp.clip(rows.row_cols[r, 0][:, None] + slot - p0[r][:, None],
+                 0, t - 1)                                    # [NW, P]
+  return WritePlan(r=r, lp=jnp.clip(lp, 0, t_pages - 1), live=live,
+                   mask=mask.astype(jnp.int32)[:, None, :], tok=tok)
+
+
 def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
-               lowering: str = "auto", interpret: bool | None = None):
+               lowering: str = "auto", interpret: bool | None = None,
+               plan: WritePlan | None = None):
   """Every valid token's K and V `[T, Nk, H]` into its row's page at its
   slot (`rows`: the step's core/ragged.RaggedRows; block_tables [B,
   t_pages]); padding tokens write nothing, or the pool's last page. ->
@@ -270,19 +307,19 @@ def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
   LANE of its page (module docstring), and a scatter there re-lays the whole
   pool out, so the kernel rewrites whole pages: a program a (row, page) pair
   the step touches (`PageWrites` of them, the dead ones on the trash page
-  with nothing to write), the page's new lanes gathered beforehand."""
-  assert lowering in ("auto", "pallas", "xla"), lowering
-  on_tpu = jax.default_backend() == "tpu"
-  if lowering == "auto":
-    lowering = "pallas" if on_tpu else "xla"
+  with nothing to write), the page's new lanes gathered beforehand.
+  plan: the step's BuildWritePlan over these rows and this table's shape (a
+  stack builds it once for all its owners); the kernel's call builds its own
+  when handed none. The scatter takes none."""
+  lowering = rba.Lowering(lowering)
   np_total, page, nk, h = k_pool.shape
   b, t_pages = block_tables.shape
   t = k_new.shape[0]
-  pos = rows.pos.astype(jnp.int32)
-  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
   if lowering == "xla":
     with observe.Scope("kv_layout"):
+      pos = rows.pos.astype(jnp.int32)
+      row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
       logical = jnp.clip(pos // page, 0, t_pages - 1)
       phys = jnp.where(rows.valid, tables[row, logical], np_total - 1)
       off = jnp.where(rows.valid, pos % page,
@@ -290,43 +327,37 @@ def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
     return (k_pool.at[phys, off].set(k_new.astype(k_pool.dtype)),
             v_pool.at[phys, off].set(v_new.astype(v_pool.dtype)))
   with observe.Scope("kv_layout"):
-    # the (row, logical page) pairs of the step, rows in slot order
-    p0 = rows.row_q_pos.astype(jnp.int32)
-    n = rows.row_len.astype(jnp.int32)
-    first_page = p0 // page
-    n_pages = jnp.where(n > 0, (p0 + n - 1) // page - first_page + 1, 0)
-    cum = jnp.cumsum(n_pages)
-    nw = PageWrites(b, t, page)
-    i = jnp.arange(nw, dtype=jnp.int32)
-    r = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1)
-    lp = first_page[r] + i - (cum[r] - n_pages[r])
-    live = i < cum[-1]
-    slot = lp[:, None] * page + jnp.arange(page, dtype=jnp.int32)[None]
-    mask = live[:, None] & (slot >= p0[r][:, None]) & (
-        slot < (p0 + n)[r][:, None])
-    tok = jnp.clip(rows.row_cols[r, 0][:, None] + slot - p0[r][:, None],
-                   0, t - 1)                                  # [NW, P]
-    page_ids = jnp.where(live, tables[r, jnp.clip(lp, 0, t_pages - 1)],
-                         np_total - 1)
+    if plan is None:
+      plan = BuildWritePlan(rows, b, t_pages, page)
+    page_ids = jnp.where(plan.live, tables[plan.r, plan.lp], np_total - 1)
 
     def _NewPages(new):
-      lanes = new.reshape(t, nk * h).astype(k_pool.dtype)[tok]  # [NW,P,rows]
-      return lanes.swapaxes(1, 2)
+      lanes = new.reshape(t, nk * h).astype(k_pool.dtype)[plan.tok]
+      return lanes.swapaxes(1, 2)                          # [NW, rows, P]
 
     operands = (page_ids, AsItLies(k_pool), AsItLies(v_pool),
-                _NewPages(k_new), _NewPages(v_new),
-                mask.astype(jnp.int32)[:, None, :])
+                _NewPages(k_new), _NewPages(v_new), plan.mask)
   if interpret is None:
-    interpret = not on_tpu
+    interpret = jax.default_backend() != "tpu"
   # the write itself stays outside `kv_layout`: its kernel is `kv_write`
   k_pages, v_pages = _WriteCall(*operands, interpret=interpret)
   with observe.Scope("kv_layout"):
     return _FromAsItLies(k_pages, nk), _FromAsItLies(v_pages, nk)
 
 
-def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
+def DiffPlanKey(nq: int, nk: int, h: int, page_size: int, q_dtype, kv_dtype,
+                *, window: int = 0, lowering: str = "auto") -> rba.PlanKey:
+  """The rba.PlanKey of DiffAttend called with `nq` query heads over `nk` K
+  heads of size `h`: grouped-query attention of 2H-wide queries over Nk / 2
+  wide heads, chains only."""
+  return rba.AttendPlanKey(nq, nk // 2, 2 * h, page_size, q_dtype, kv_dtype,
+                           window=window, tree=False, lowering=lowering)
+
+
+def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, blocks: rba.AttendPlan,
                       page_size: int, window: int, interpret: bool):
-  """q2: [T, N, 2H] padded queries -> [T, N, 2H], a softmax's `a V` each."""
+  """q2: [T, N, 2H] padded queries -> [T, N, 2H], a softmax's `a V` each.
+  blocks: the call's descriptors (rba.BuildAttendPlan at DiffPlanKey)."""
   t, n, h2 = q2.shape
   np_total, page, nk, h = k_pool.shape
   assert page == page_size and h2 == 2 * h, (k_pool.shape, q2.shape)
@@ -334,36 +365,27 @@ def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
   group = n // heads
   lanes = rba.GroupLanes(group)
   b, t_pages = block_tables.shape
+  nb, bq, _ = blocks.cols.shape
+  assert nb == rba.NumQueryBlocks(b, t * lanes, bq), (
+      "descriptors of another pack", blocks.cols.shape, (b, t, lanes))
+  tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
+  prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
+              blocks.first]
+  if window:
+    prefetch.append(blocks.page0)
   with observe.Scope("diff_layout"):
     # the group beside the tokens, padded to whole sublane tiles
     # (RaggedAttend)
     q = q2.reshape(t, heads, group, h2).swapaxes(1, 2)
     q = jnp.pad(q, ((0, 0), (0, lanes - group), (0, 0), (0, 0)))
     q = q.reshape(t * lanes, heads * h2).astype(jnp.float32)
-  with observe.Scope("diff_descriptors"):
-    rows = jnp.repeat(jnp.clip(row_of.astype(jnp.int32), 0, b - 1), lanes)
-    ends = jnp.repeat(q_end.astype(jnp.int32), lanes)
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-    bq = rba.QueryBlock(heads, h2, page_size, q2.dtype, k_pool.dtype,
-                        grouped=True)
-    nb = rba.NumQueryBlocks(b, t * lanes, bq)
-    zeros = jnp.zeros_like(ends)
-    blocks = rba._BuildQueryBlocks(
-        rows, ends, zeros, zeros - 1, zeros - 1, bq=bq, nb=nb,
-        page_size=page_size, t_pages=t_pages, window=window)
-    grid_pages = rba.WindowPages(window, bq, page_size, t_pages)
-    prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
-                blocks.first]
-    if window:
-      prefetch.append(blocks.page0)
-  with observe.Scope("diff_layout"):
     operands = (jnp.pad(q, ((0, bq), (0, 0))), blocks.cols, AsItLies(k_pool),
                 AsItLies(v_pool))
-  # the call stays outside both: its kernel is `diff_attend`
+  # the call stays outside: its kernel is `diff_attend`
   out = _TransposedCall(
       tuple(prefetch), *operands, page_size=page_size, heads=heads,
-      window=window, grid=(nb, grid_pages), rungs=rba.BlockRungs(bq, lanes),
-      interpret=interpret)
+      window=window, grid=(nb, rba.WindowPages(window, bq, page_size, t_pages)),
+      rungs=rba.BlockRungs(bq, lanes), interpret=interpret)
   with observe.Scope("diff_layout"):
     out = out[:t * lanes].reshape(t, lanes, heads, h2)[:, :group]
     return out.swapaxes(1, 2).reshape(t, n, h2)
@@ -371,27 +393,35 @@ def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
 
 def DiffAttend(q, k_pool, v_pool, block_tables, row_of, q_end, lam, *,
                page_size: int, window: int = 0, lowering: str = "auto",
-               interpret: bool | None = None):
+               interpret: bool | None = None, plan=None):
   """q: [T, 2 * pairs, H] packed queries, scaled; pools [NP, P, Nk, H];
   block_tables [B, t_pages]; row_of / q_end [T] as RaggedAttend's (a row's
   tokens contiguous, q_end 0 = padding); lam: the layer's scalar.
   lowering: 'auto' (the kernel on a TPU, the twin elsewhere) | 'pallas' |
-  'xla'. -> [T, pairs, 2H] in q's dtype, zeros at padding tokens."""
-  assert lowering in ("auto", "pallas", "xla"), lowering
+  'xla'. plan: as RaggedAttend's, {rba.PlanKey: rba.AttendPlan} over these
+  row_of / q_end and this table's shape, from which the kernel's call takes
+  the descriptors of its DiffPlanKey; it builds them itself when handed
+  none. -> [T, pairs, 2H] in q's dtype, zeros at padding tokens."""
   t, nq, h = q.shape
-  on_tpu = jax.default_backend() == "tpu"
-  if lowering == "auto":
-    lowering = "pallas" if on_tpu else "xla"
+  key = DiffPlanKey(nq, k_pool.shape[2], h, page_size, q.dtype, k_pool.dtype,
+                    window=window, lowering=lowering)
   with observe.Scope("diff_layout"):
     q2 = PaddedQueries(q)
-  if lowering == "xla":
+  if not key.kernel:
     out = _XlaDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
-                         page_size, int(window))
+                         page_size, key.window)
   else:
+    if plan is None:
+      with observe.Scope("diff_descriptors"):
+        blocks = rba.BuildAttendPlan(
+            key, row_of, q_end, b=block_tables.shape[0],
+            t_pages=block_tables.shape[1])
+    else:
+      blocks = plan[key]
     out = _PallasDiffAttend(
-        q2, k_pool, v_pool, block_tables, jnp.asarray(row_of),
-        jnp.asarray(q_end), page_size, int(window),
-        interpret=(not on_tpu) if interpret is None else interpret)
+        q2, k_pool, v_pool, block_tables, blocks, page_size, key.window,
+        interpret=(jax.default_backend() != "tpu") if interpret is None
+        else interpret)
   with observe.Scope("diff_layout"):
     out = out.astype(jnp.float32).reshape(t, nq // 2, 2, 2 * h)
     return (out[:, :, 0] - lam * out[:, :, 1]).astype(q.dtype)

@@ -42,7 +42,11 @@ online softmax and accumulator), held to each other within rounding:
     queries, last live page of the widest horizon, per-query mask columns)
     are a few integer ops on `row_of`/`q_end`, computed in the jitted step
     and shipped from nowhere; they ride scalar prefetch, so the page index
-    map resolves `block_tables[row[i], j]` before the DMA is issued.
+    map resolves `block_tables[row[i], j]` before the DMA is issued. They
+    depend on the step's rows, on shapes and on the window, not on the
+    layer: a stack builds them once a step for every `PlanKey` its layers
+    declare (`BuildAttendPlan`, core/attention.BuildRaggedPlan) and hands
+    them to each call as `plan`; a call without one builds its own.
   - A row starts at any packed offset, so q and the output stay in HBM and
     each block copies its own `[Bq, N, H]` window in at its first page and
     out at its last (`Bq` rows of slack past T). A query that is not the
@@ -215,6 +219,14 @@ def Grouped(n: int, n_kv: int) -> bool:
   return n != n_kv
 
 
+def Lowering(lowering: str) -> str:
+  """'auto' resolved: the Pallas kernel on a TPU, the XLA twin elsewhere."""
+  assert lowering in ("auto", "pallas", "xla"), lowering
+  if lowering == "auto":
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+  return lowering
+
+
 def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype,
                grouped: bool = False) -> int:
   """Bq, the most queries of one row that meet a page together.
@@ -291,7 +303,35 @@ def WindowPages(window: int, bq: int, page_size: int, t_pages: int) -> int:
   return min(t_pages, (window + bq - 2) // page_size + 2)
 
 
-class _QueryBlocks(NamedTuple):
+class PlanKey(NamedTuple):
+  """What decides a call's query-block descriptors beside the step's rows
+  and its tables' shape. All static: a function of a layer's shapes, dtypes
+  and window (`AttendPlanKey`), so the layers of a stack that agree in it
+  share one `AttendPlan` a step."""
+  page_size: int
+  window: int
+  bq: int        # queries a block holds (QueryBlock)
+  lanes: int     # queries a token lays on the packed axis (its group)
+  tree: bool     # the rows' tree operands ride the descriptors; else the
+  #                chain sentinels (q_start 0, every ancestor bit set)
+  kernel: bool   # the Pallas lowering serves the call: a twin's call takes
+  #                no descriptors and none are built for it
+
+
+def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
+                  kv_dtype, *, window: int = 0, tree: bool = True,
+                  lowering: str = "auto") -> PlanKey:
+  """The PlanKey of RaggedAttend called with `n` query heads of size `h`
+  over `n_kv` KV heads at these dtypes."""
+  kernel = Lowering(lowering) == "pallas"
+  grouped = kernel and Grouped(n, n_kv)
+  lanes = GroupLanes(n // n_kv) if grouped else n // n_kv
+  return PlanKey(page_size, int(window),
+                 QueryBlock(n_kv, h, page_size, q_dtype, kv_dtype,
+                            grouped=grouped), lanes, tree, kernel)
+
+
+class AttendPlan(NamedTuple):
   """Descriptors of the step's query blocks (all int32; NB static).
 
   Block i holds up to Bq consecutive queries of ONE row, starting at
@@ -307,18 +347,21 @@ class _QueryBlocks(NamedTuple):
   src: jnp.ndarray    # [NB] the `cols` block its programs map
   cols: jnp.ndarray   # [NB, Bq, 4] per query: q_end (0 = not of this
   #                     block), q_start, anc_lo, anc_hi
+  col0: tuple         # cols[:, 0]'s four columns, [NB] each: what a
+  #                     one-query block's program reads as scalars
 
 
 def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                       page_size: int, t_pages: int,
-                      window: int = 0) -> _QueryBlocks:
+                      window: int = 0) -> AttendPlan:
   """Cuts each row's run of tokens into blocks of Bq queries.
 
   A few [T]- and [NB, Bq]-sized integer ops on what the step already has
-  on the device. They depend on nothing a layer computes, so under the
-  scan over layers they are loop-invariant: XLA hoists them, then sinks
-  the cheap ones back beside their consumers (PERF.md section 6, PR 25:
-  with q's padding and the output's zeros, 29 us a call)."""
+  on the device. They depend on nothing a layer computes, yet under a scan
+  over layers XLA lifts out of the loop only a part of them (the lookups;
+  the `[NB, Bq, 4]` stack and its copies, 0.12 ms a layer at 73 blocks of
+  512, stayed; PERF.md section 6, PR 43): `BuildAttendPlan` is called once
+  a step, before the scan."""
   t = row_of.shape[0]
   idx = jnp.arange(t, dtype=jnp.int32)
   valid = ends > 0
@@ -335,12 +378,21 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
   # block k starts at the first token whose running count reaches k + 1
   first = jnp.sum((csum[None, :] <= src[:, None]).astype(jnp.int32), axis=1)
   first = jnp.minimum(first, t - 1)
-  tok = first[:, None] + jnp.arange(bq, dtype=jnp.int32)[None, :]
-  in_range = tok < t
-  tok = jnp.minimum(tok, t - 1)
-  member = in_range & valid[tok] & (blk[tok] == src[:, None])
-  blk_ends = jnp.where(member, ends[tok], 0)                # [NB, Bq]
-  cols = jnp.stack([blk_ends, starts[tok], lo[tok], hi[tok]], axis=-1)
+  in_range = first[:, None] + jnp.arange(bq, dtype=jnp.int32)[None, :] < t
+  # What a block's queries carry, x[first[k] + j] (past the end: x[T - 1]).
+  # A block's tokens are consecutive, so it is one slice a block of the
+  # tokens' values laid side by side, not a lookup a query and value: the
+  # chip runs a lookup an index at a time (six of 73 x 512: 1.5 ms; the
+  # slices 0.07; PERF.md section 6, PR 43)
+  per_token = jnp.stack([ends, starts, lo, hi, blk, valid.astype(jnp.int32)],
+                        axis=-1)                            # [T, 6]
+  per_token = jnp.pad(per_token, ((0, bq), (0, 0)), mode="edge")
+  of_blocks = jax.vmap(
+      lambda f: jax.lax.dynamic_slice_in_dim(per_token, f, bq))(first)
+  member = in_range & (of_blocks[..., 5] != 0) & (
+      of_blocks[..., 4] == src[:, None])
+  blk_ends = jnp.where(member, of_blocks[..., 0], 0)        # [NB, Bq]
+  cols = jnp.concatenate([blk_ends[..., None], of_blocks[..., 1:4]], axis=-1)
   last = jnp.clip((jnp.max(blk_ends, axis=1) + page_size - 1) // page_size
                   - 1, 0, t_pages - 1)
   n = jnp.where(k < n_live, jnp.sum(member.astype(jnp.int32), axis=1), 0)
@@ -351,8 +403,40 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
     low = jnp.min(jnp.where(member, blk_ends, jnp.iinfo(jnp.int32).max),
                   axis=1)
     page0 = jnp.minimum(jnp.maximum(low - window, 0) // page_size, last)
-  return _QueryBlocks(row=row_of[first], last=last, page0=page0, n=n,
-                      first=first, src=src, cols=cols)
+  col0 = cols[:, 0]                                         # [NB, 4]
+  return AttendPlan(row=row_of[first], last=last, page0=page0, n=n,
+                    first=first, src=src, cols=cols,
+                    col0=tuple(col0[:, c] for c in range(4)))
+
+
+def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
+                    anc_hi=None, *, b: int, t_pages: int) -> AttendPlan:
+  """The descriptors of every call of `key` in a step over these tokens.
+
+  row_of / q_end [T] and the tree operands as RaggedAttend takes them (a
+  token each, before its group is laid beside it; `key.tree` says whether
+  the tree operands ride); block tables [b, t_pages]. The one way the
+  descriptors are built: by a stack once a step, or by a call that was
+  handed none."""
+  assert key.kernel, key
+  assert (q_start is not None) == key.tree, (key, q_start is None)
+  rows = jnp.clip(jnp.asarray(row_of).astype(jnp.int32), 0, b - 1)
+  ends = jnp.asarray(q_end).astype(jnp.int32)
+  if q_start is None:
+    starts = jnp.zeros_like(ends)
+    lo = hi = jnp.full_like(ends, -1)
+  else:
+    starts, lo, hi = (jnp.asarray(x).astype(jnp.int32)
+                      for x in (q_start, anc_lo, anc_hi))
+  if key.lanes > 1:
+    # a token's group rides the packed axis as consecutive queries of its
+    # row with its horizon (RaggedAttend)
+    rows, ends, starts, lo, hi = (jnp.repeat(x, key.lanes)
+                                  for x in (rows, ends, starts, lo, hi))
+  return _BuildQueryBlocks(
+      rows, ends, starts, lo, hi, bq=key.bq,
+      nb=NumQueryBlocks(b, rows.shape[0], key.bq), page_size=key.page_size,
+      t_pages=t_pages, window=key.window)
 
 
 def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
@@ -678,13 +762,14 @@ def _GroupedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
     )(*prefetch, q, cols, k_pages, v_pages, jnp.zeros(q.shape, jnp.float32))
 
 
-def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
+def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
                         page_size: int, interpret: bool = False,
-                        k_scale=None, v_scale=None,
-                        q_start=None, anc_lo=None, anc_hi=None,
-                        window: int = 0, grouped: int = 0):
+                        k_scale=None, v_scale=None, window: int = 0,
+                        grouped: int = 0):
   """Pallas lowering of _XlaRaggedAttend. q: [T, N, H] -> [T, N, H].
 
+  blocks: the call's descriptors (BuildAttendPlan over the packed tokens
+  and this table's shape, at this window).
   grouped (static): 0, or the queries a token lays on the packed axis where
   they are a KV head's group laid beside the tokens (RaggedAttend) and the
   pages float: the same grid, descriptors and page index map run
@@ -700,22 +785,12 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
   assert page == page_size, (page, page_size)
   b, t_pages = block_tables.shape
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-  rows = jnp.clip(row_of.astype(jnp.int32), 0, b - 1)
-  ends = q_end.astype(jnp.int32)
-  if q_start is None:
-    q_start = jnp.zeros((t,), jnp.int32)
-    anc_lo = anc_hi = jnp.full((t,), -1, jnp.int32)
-  bq = QueryBlock(n, h, page_size, q.dtype, k_pool.dtype, grouped=grouped)
-  nb = NumQueryBlocks(b, t, bq)
-  with observe.Scope("attend_descriptors"):
-    blocks = _BuildQueryBlocks(
-        rows, ends, q_start.astype(jnp.int32), anc_lo.astype(jnp.int32),
-        anc_hi.astype(jnp.int32), bq=bq, nb=nb, page_size=page_size,
-        t_pages=t_pages, window=window)
-    col0 = blocks.cols[:, 0]                                # [NB, 4]
+  nb, bq, _ = blocks.cols.shape
+  assert nb == NumQueryBlocks(b, t, bq), (
+      "descriptors of another pack", blocks.cols.shape, (b, t))
   grid_pages = WindowPages(window, bq, page_size, t_pages)
   prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
-              blocks.first, col0[:, 0], col0[:, 1], col0[:, 2], col0[:, 3]]
+              blocks.first, *blocks.col0]
   if window:
     prefetch.append(blocks.page0)
 
@@ -801,7 +876,8 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
 def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
                  page_size: int, k_scale=None, v_scale=None,
                  q_start=None, anc_lo=None, anc_hi=None, window: int = 0,
-                 lowering: str = "auto", interpret: bool | None = None):
+                 lowering: str = "auto", interpret: bool | None = None,
+                 plan=None):
   """Packed-token ragged paged attention — decode, prefill, and verify
   rows in one call.
 
@@ -830,32 +906,31 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
   64-bit ancestor-column bitmask; all three or none. None keeps chain
   semantics bitwise (every in-step predecessor visible).
   lowering: 'auto' (Pallas on real TPU, XLA twin elsewhere) | 'pallas' |
-  'xla'. Returns [T, N, H].
+  'xla'.
+  plan: None, or {PlanKey: AttendPlan} built over these same row_of / q_end
+  / tree operands and this table's shape (BuildAttendPlan; a stack builds
+  it once a step): the Pallas lowering takes the descriptors of its own
+  key from it, and builds them itself when handed none. The twin takes
+  none. Returns [T, N, H].
   """
   assert q.ndim == 3, q.shape
-  assert lowering in ("auto", "pallas", "xla"), lowering
   assert (k_scale is None) == (v_scale is None), "pass both scales or neither"
   tree_args = (q_start is not None, anc_lo is not None, anc_hi is not None)
   assert all(tree_args) or not any(tree_args), \
       "pass q_start+anc_lo+anc_hi together or none"
   if k_scale is not None:
     assert k_pool.dtype == jnp.int8, k_pool.dtype
-  if q_start is not None:
-    q_start = jnp.asarray(q_start)
-    anc_lo = jnp.asarray(anc_lo)
-    anc_hi = jnp.asarray(anc_hi)
-  on_tpu = jax.default_backend() == "tpu"
-  if lowering == "auto":
-    lowering = "pallas" if on_tpu else "xla"
-  row_of, q_end = jnp.asarray(row_of), jnp.asarray(q_end)
   t, n, h = q.shape
   n_kv = k_pool.shape[2]
   assert n % n_kv == 0, (n, n_kv)
   group = n // n_kv
+  key = AttendPlanKey(n, n_kv, h, page_size, q.dtype, k_pool.dtype,
+                      window=window, tree=q_start is not None,
+                      lowering=lowering)
   # the grouped kernel wants every token's group to start on a sublane
   # tile: the group is padded with zero queries of the token's own horizon
   # (computed, dropped)
-  grouped = lowering == "pallas" and Grouped(n, n_kv)
+  grouped = key.kernel and Grouped(n, n_kv)
   if grouped and (k_scale is not None or h % LANES or (
       k_pool.dtype.itemsize == 2 and n_kv > 1 and n_kv % 2)):
     raise NotImplementedError(
@@ -863,29 +938,36 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
         "from f32 pages, or bf16 pages of one or an even number of KV "
         f"heads, whose heads tile the lanes; got {k_pool.dtype} pages, head "
         f"size {h}" + (", int8 scales" if k_scale is not None else ""))
-  lanes = GroupLanes(group) if grouped else group
+  lanes = key.lanes
   if group > 1:
     # [T, Nkv, G, H] -> [T * G', Nkv, H]: the group beside the tokens
     q = q.reshape(t, n_kv, group, h).swapaxes(1, 2)
     q = jnp.pad(q, ((0, 0), (0, lanes - group), (0, 0), (0, 0)))
     q = q.reshape(-1, n_kv, h)
-    row_of, q_end = jnp.repeat(row_of, lanes), jnp.repeat(q_end, lanes)
+  if not key.kernel:
+    tokens = [row_of, q_end]
     if q_start is not None:
-      q_start, anc_lo, anc_hi = (jnp.repeat(x, lanes)
-                                 for x in (q_start, anc_lo, anc_hi))
-  kw = dict(k_scale=k_scale, v_scale=v_scale, q_start=q_start,
-            anc_lo=anc_lo, anc_hi=anc_hi)
-  if window:
-    kw["window"] = int(window)
-  if lowering == "xla":
-    out = _XlaRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
-                           page_size, **kw)
+      tokens += [q_start, anc_lo, anc_hi]
+    tokens = [jnp.asarray(x) for x in tokens]
+    if group > 1:
+      tokens = [jnp.repeat(x, lanes) for x in tokens]
+    out = _XlaRaggedAttend(q, k_pool, v_pool, block_tables, *tokens[:2],
+                           page_size, k_scale, v_scale, *tokens[2:],
+                           window=key.window)
   else:
+    if plan is None:
+      with observe.Scope("attend_descriptors"):
+        blocks = BuildAttendPlan(
+            key, row_of, q_end, q_start, anc_lo, anc_hi,
+            b=block_tables.shape[0], t_pages=block_tables.shape[1])
+    else:
+      blocks = plan[key]
     if interpret is None:
-      interpret = not on_tpu
-    out = _PallasRaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end,
-                              page_size, interpret=interpret,
-                              **kw, **({"grouped": lanes} if grouped else {}))
+      interpret = jax.default_backend() != "tpu"
+    out = _PallasRaggedAttend(
+        q, k_pool, v_pool, block_tables, blocks, page_size,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        window=key.window, grouped=lanes if grouped else 0)
   if group > 1:
     out = out.reshape(t, lanes, n_kv, h)[:, :group]
     out = out.swapaxes(1, 2).reshape(t, n, h)

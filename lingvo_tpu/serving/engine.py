@@ -509,6 +509,14 @@ class ServingLoop:
     # queries of a block -> the rows of M its products run
     self._attend_rows = (attens[0].RaggedBlockRows(page_size, kv_cache_dtype)
                          if attens else None)
+    # the attend kernels the step program calls, and the plans (sets of
+    # query-block descriptors) it builds for them once a step: a count of
+    # the program, from what the stack declares over these states
+    kernel_keys = [k for k in getattr(
+        task.stack, "RaggedPlanKeys", lambda states: [])(self._states)
+                   if k.kernel]
+    self.attend_calls = len(kernel_keys)
+    self.attend_plans = len(set(kernel_keys))
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
@@ -535,6 +543,8 @@ class ServingLoop:
     self.metrics.Gauge("serving/serve_int8_weights").Set(
         self.serve_int8_weights)
     self.metrics.Gauge("serving/head_rows").Set(self.head_rows)
+    self.metrics.Gauge("serving/attend_calls").Set(self.attend_calls)
+    self.metrics.Gauge("serving/attend_plans").Set(self.attend_plans)
     self.metrics.SectionFn("scheduler", self.sched.Stats)
     self.metrics.SectionFn("kv_pages", (self._kind_pages or self.alloc).Stats)
     self.metrics.SectionFn(
@@ -1618,6 +1628,8 @@ class ServingLoop:
       stats["kv_bytes_per_token"] = self.kv_bytes_per_token
       stats["serve_int8_weights"] = self.serve_int8_weights
       stats["head_rows"] = self.head_rows
+      stats["attend_calls"] = self.attend_calls
+      stats["attend_plans"] = self.attend_plans
       stats["scheduler"] = self.sched.Stats()
       stats["kv_pages"] = (self._kind_pages or self.alloc).Stats()
       stats["mixers"] = dict(self.mixers)
