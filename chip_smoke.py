@@ -22,6 +22,16 @@ registered widths of `lm.synthetic_packed_input.DenseLm1B` (d=2048, 16 heads x
            64-600 tokens, 32 new tokens each, first tokens checked against a
            plain unpaged forward of the same task and theta
 
+  hybrid   the tiny sibling of `lm.nemotron_h` (Mamba-2 mixers with slot
+           state, sigmoid-routed experts beside a shared one, one
+           grouped-query attention layer; one branch a layer) through
+           ServingLoop in bf16 at the smallest widths the chip's kernels
+           take: one prompt over two chunk boundaries, a few decode steps,
+           every streamed token against the plain reference's
+           (benchmarks/references/nemotron_h.py) f32 forward of the same
+           weights: ten seconds that say the packed Mamba-2 scan still
+           compiles and computes on the chip
+
 `--multichip` runs, and only runs, the GSPMD trainer on a {"data": 2,
 "model": 2} mesh against the same two steps on one device. `--tiny` runs the
 same phases at DenseLmTiny size with the kernels in interpret mode: the CPU
@@ -792,6 +802,77 @@ def ServePhase(size: Size, seed: int) -> dict:
   }
 
 
+def HybridPhase(seed: int) -> dict:
+  """The tiny Nemotron-H sibling's layers at the smallest widths the chip's
+  kernels take (heads and state indices of 128, pages of 128, a step of 128
+  packed tokens), served on whatever device is there."""
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+  from benchmarks.references import nemotron_h as reference
+  from lingvo_tpu import model_registry
+  from lingvo_tpu.serving import engine as engine_lib
+  import lingvo_tpu.models.all_params  # noqa: F401  (fills the registry)
+
+  mp = model_registry.GetParams("lm.nemotron_h.Nemotron3NanoTiny", "Train")
+  tp = mp.task
+  tp.input = mp.input
+  tp.Set(fprop_dtype=jnp.bfloat16, model_dim=256, vocab_size=512)
+  tp.atten_tpl.Set(dim_per_head=128)
+  tp.mixer_tpl.Set(head_dim=64, state_dim=128)
+  tp.expert_ffn_tpl.Set(hidden_dim=128, shared_hidden_dim=256)
+  task = _Instantiate(tp)
+  theta = jax.tree_util.tree_map(
+      lambda x: x.astype(jnp.bfloat16), reference.SeededWeights(
+          task.InstantiateVariables(jax.random.PRNGKey(seed)),
+          router_reads_share=0.25, router_scale=32.0,
+          groups=tp.mixer_tpl.num_groups, state_dim=tp.mixer_tpl.state_dim,
+          experts_per_token=tp.expert_ffn_tpl.num_experts_per_token))
+  prompt = np.random.RandomState(seed).randint(1, tp.vocab_size, 300).astype(
+      np.int32)
+  new_tokens = 6
+  engine = engine_lib.ServingLoop(task, theta, page_size=128, num_pages=8,
+                                  max_batch=2, max_seq_len=512,
+                                  prefill_token_budget=126)
+  on_chip = jax.default_backend() == "tpu"
+  if engine.paged_path != ("pallas" if on_chip else "xla"):
+    raise AssertionError(f"paged_path {engine.paged_path!r}")
+  handle = engine.Submit(prompt, new_tokens)
+  while not handle.done:
+    engine.StepOnce()
+  stream = np.asarray(handle.Result(), np.int32)
+  seq = np.concatenate([prompt, stream])
+  ids = np.zeros((new_tokens, 512), np.int32)
+  ids[:, :len(seq)] = seq
+  at = len(prompt) - 1 + np.arange(new_tokens, dtype=np.int32)
+  with jax.default_matmul_precision("highest"):
+    want = np.asarray(jax.jit(lambda th, i, a: reference.LogitsAt(
+        th, i, a, 0.0))(theta, jnp.asarray(ids), jnp.asarray(at)))
+  checked = 0
+  for i, tok in enumerate(stream):
+    best, second = np.sort(want[i])[::-1][:2]
+    if best - second <= 0.1:     # inside bf16's rounding: no stable argmax
+      continue
+    if tok != want[i].argmax():
+      raise AssertionError(
+          f"streamed token {i} is {tok}, the reference's {want[i].argmax()} "
+          f"(its two best logits {best}, {second})")
+    checked += 1
+  if not checked:
+    raise AssertionError("every token was skipped: nothing was compared")
+  stats = engine.Stats()
+  records = stats["compile"]
+  if on_chip and not records.get("ragged", {}).get("tpu_custom_calls"):
+    raise AssertionError(f"want a Pallas step program: {records}")
+  return {"layer_kinds": stats["layer_kinds"], "steps": stats["steps"],
+          "paged_path": engine.paged_path,
+          "tpu_custom_calls": records.get("ragged", {}).get(
+              "tpu_custom_calls"),
+          "ssm_tokens": stats["ssm_tokens"],
+          "moe_tokens_routed": stats["moe_tokens_routed"],
+          "tokens_checked": checked, "tokens_out": len(stream)}
+
+
 # -- four chips --------------------------------------------------------------
 
 
@@ -931,6 +1012,7 @@ def main(argv=None) -> int:
         ("kernels", lambda: KernelsPhase(size, args.seed)),
         ("train", lambda: TrainPhase(size, args.seed)),
         ("serve", lambda: ServePhase(size, args.seed)),
+        ("hybrid", lambda: HybridPhase(args.seed)),
     ]
   for name, run in phases:
     before = dict(cache)

@@ -1228,6 +1228,49 @@ class ChunkwiseSelfAttention(MultiHeadedAttention):
 _PAIR_NORM_EPSILON = 1e-5       # differential attention's RMSNorm a pair
 
 
+class PooledAttention(MultiHeadedAttention):
+  """MultiHeadedAttention (grouped-query or not) as a page-owning mixer of
+  `transformer.BlockSequence`: the layer's arithmetic, weights and kernels
+  are the base class's; what changes is whose pages it reads and writes.
+  Its K and V live in the stack's ONE pool (`shared.kv_pool`, [pages, P,
+  num_kv_heads, H]) through its own block table, and the step's RaggedPlan
+  carries its query-block descriptors, as for DifferentialAttention. It
+  speaks the mixer contract (transformer.SharedStateLayer), causal, with no
+  state of a slot's own."""
+
+  # what BlockSequence asks a mixer: it projects and caches K and V in pages
+  # of its own table, through the base class's own `kv_write` (the step's
+  # plan carries no page write for it)
+  kv_owner = True
+  writes_by_plan = False
+
+  @property
+  def _h(self) -> int:
+    return self._dim_per_head
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=None):
+    del depth
+    out, _ = super().FProp(theta, x, paddings=paddings,
+                           segment_ids=segment_ids, causal=True)
+    return out, shared
+
+  def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
+    """Nothing a slot: its pages are the stack's one pool's."""
+    del theta, num_slots
+    return NestedMap()
+
+  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=0,
+                 plan=None):
+    """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own."""
+    del depth
+    out, pool = super().RaggedStep(theta, x, shared.kv_pool, table, rows,
+                                   plan=plan)
+    shared = shared.Copy()
+    shared.kv_pool = pool
+    return out, states, shared
+
+
 class DifferentialAttention(base_layer.BaseLayer):
   """Differential attention (arXiv:2410.05258) for `transformer.
   BlockSequence`, with K and V of its own or another layer's.
@@ -1294,6 +1337,16 @@ class DifferentialAttention(base_layer.BaseLayer):
     # (1 + scale), as layers.LayerNorm stores it
     self.CreateVariable("subln_scale", WeightParams(
         (2 * h,), WeightInit.Constant(0.0), p.dtype))
+
+  @property
+  def kv_owner(self) -> bool:
+    return self.p.kv_owner
+
+  @property
+  def writes_by_plan(self) -> bool:
+    """An owner writes its pages through ops/diff_attend.WritePages, which
+    takes the step's `RaggedPlan.writes`."""
+    return self.p.kv_owner
 
   def KvBytesPerToken(self, kv_cache_dtype=None) -> int:
     """Bytes one token adds to the pages this layer OWNS."""

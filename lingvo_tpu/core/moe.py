@@ -6,14 +6,21 @@ expert full is dropped. A served token cannot be dropped, and on one chip
 there is no exchange to shape the dispatch for, so this layer sorts instead:
 
   route     router logits `[T, E]` from the LAYER's input, handed in by
-            `TransformerLayer` ("router before attention"), top-k,
-            softmax over the chosen logits (softmax then top-k then
-            renormalise gives the same numbers);
+            `TransformerLayer` ("router before attention"), or from the
+            layer's own normed input (`router_reads`); top-k; `scoring`
+            'softmax': softmax over the chosen logits (softmax then top-k
+            then renormalise gives the same numbers); 'sigmoid': scores
+            sigmoid(logits), chosen by score + `router_bias` (a bias that
+            chooses and does not weigh), weights the chosen scores over
+            their sum times `routed_scale`;
   dispatch  the `T x k` (token, expert) pairs sorted by expert: a gather of
             `[T * k, D]` rows and the experts' run lengths `[E]`;
-  experts   three grouped matmuls over the runs, ReGLU:
-            (relu(x W_gate) * (x W_up)) W_down, widths D -> F -> D;
-  combine   each row weighted, unsorted, and a token's k rows summed.
+  experts   grouped matmuls over the runs, widths D -> F -> D; `activation`
+            'reglu': three, (relu(x W_gate) * (x W_up)) W_down; 'relu2':
+            two, relu(x W_up)^2 W_down, and no W_gate exists;
+  combine   each row weighted, unsorted, and a token's k rows summed;
+  shared    where `shared_hidden_dim` > 0, one more expert of that width
+            that every token goes through, added to the routed sum.
 
 No capacity, no `[T, E, C]` tensor, no dropped token; an expert with no token
 is a run of length 0. The layer holds all its experts and runs no collective:
@@ -41,6 +48,19 @@ from lingvo_tpu.core.py_utils import WeightInit, WeightParams
 
 _GMM_TILE = 128   # megablox tiles m, k and n; a shape it cannot tile takes
 #                   ragged_dot
+
+
+def _StoredWidth(d: int, f: int) -> int:
+  """The width the experts' [d, f] / [f, d] matrices are STORED at. Where the
+  kernel tiles the model dim and not the experts' width (1856 = 14.5 x 128),
+  the width is padded up to whole tiles: zero columns of W_up (and W_gate),
+  zero rows of W_down, so every product is the same to the bit (relu(0) = 0)
+  and the kernel runs (PERF.md section 6, PR 45: 4.9 ms against ragged_dot's
+  43). A layout, like the page pool's: `hidden_dim` stays the model's width.
+  A model dim the kernel cannot tile takes ragged_dot whatever the width, and
+  nothing is padded."""
+  return -(-f // _GMM_TILE) * _GMM_TILE if d % _GMM_TILE == 0 else f
+
 
 def GroupedMatmul(lhs, rhs, group_sizes):
   """lhs [M, K] rows in runs by group, rhs [G, K, N], group_sizes [G] int32
@@ -82,6 +102,22 @@ class DroplessMoELayer(base_layer.BaseLayer):
     p.Define("num_experts", 0, "Experts held (all of them).")
     p.Define("num_experts_per_token", 2, "k: experts a token reaches.")
     p.Define("norm_tpl", layers_lib.RmsNorm.Params(), "Norm template.")
+    p.Define("scoring", "softmax",
+             "'softmax': weights are the softmax over the chosen logits. "
+             "'sigmoid': scores sigmoid(logits) in f32, the k chosen by "
+             "score + router_bias, weights routed_scale * score / (sum of "
+             "the chosen scores).")
+    p.Define("routed_scale", 1.0, "Factor on the weights ('sigmoid').")
+    p.Define("activation", "reglu",
+             "'reglu': relu(x W_gate) * (x W_up). 'relu2': relu(x W_up)^2, "
+             "two matrices an expert.")
+    p.Define("shared_hidden_dim", 0,
+             "Width of the shared expert every token goes through (same "
+             "activation); 0 = none.")
+    p.Define("router_reads", "layer_input",
+             "'layer_input': the logits are handed in (RouterLogits of the "
+             "transformer layer's un-normed input). 'normed_input': the "
+             "router reads this layer's own normed input.")
     return p
 
   def __init__(self, params):
@@ -89,18 +125,52 @@ class DroplessMoELayer(base_layer.BaseLayer):
     p = self.p
     assert p.input_dim > 0 and p.hidden_dim > 0
     assert 0 < p.num_experts_per_token <= p.num_experts
-    d, f, e = p.input_dim, p.hidden_dim, p.num_experts
+    assert p.scoring in ("softmax", "sigmoid"), p.scoring
+    assert p.activation in ("reglu", "relu2"), p.activation
+    assert p.router_reads in ("layer_input", "normed_input"), p.router_reads
+    d, e = p.input_dim, p.num_experts
+    f = _StoredWidth(d, p.hidden_dim)
     self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=d))
     self.CreateVariable(
         "w_router", WeightParams((d, e), WeightInit.Gaussian(
             1.0 / math.sqrt(d)), p.dtype))
+    if p.scoring == "sigmoid":
+      self.CreateVariable("router_bias", WeightParams(
+          (e,), WeightInit.Constant(0.0), p.dtype))
     # fans are an expert's own, not the stack's
-    for name, shape, fan_in in (("w_gate", (e, d, f), d),
-                                ("w_up", (e, d, f), d),
-                                ("w_down", (e, f, d), f)):
+    fs = p.shared_hidden_dim
+    gated = p.activation == "reglu"
+    for name, shape, fan_in in (
+        [("w_gate", (e, d, f), d)] * gated
+        + [("w_up", (e, d, f), d), ("w_down", (e, f, d), f)]
+        + ([("w_shared_gate", (d, fs), d)] * gated
+           + [("w_shared_up", (d, fs), d), ("w_shared_down", (fs, d), fs)]
+           ) * (fs > 0)):
       self.CreateVariable(
           name, WeightParams(shape, WeightInit.Gaussian(
               1.0 / math.sqrt(fan_in)), p.dtype))
+
+  def InstantiateVariables(self, key):
+    theta = super().InstantiateVariables(key)
+    p = self.p
+    if _StoredWidth(p.input_dim, p.hidden_dim) != p.hidden_dim:
+      # the padded layout is made an expert at a time, each with its zeros
+      # where it is made: a second pass over a scanned block's matrices
+      # ([repeats, 128, 2688, 1920]) would hold them twice
+      for i, name in enumerate(self.StackAddressed()):
+        e, rows, cols = theta[name].shape
+        down = name == "w_down"
+        live = jnp.arange(rows if down else cols) < p.hidden_dim
+        live = live[:, None] if down else live[None, :]
+        scale = (p.hidden_dim if down else rows) ** -0.5
+
+        def _One(k, live=live, scale=scale, shape=(rows, cols)):
+          return jnp.where(live, scale * jax.random.normal(k, shape, p.dtype),
+                           0).astype(p.dtype)
+
+        theta[name] = jax.lax.map(_One, jax.random.split(
+            jax.random.fold_in(key, i + 1), e))
+    return theta
 
   def StackAddressed(self) -> tuple[str, ...]:
     """The variables a scan over layers hands this layer WHOLE,
@@ -110,15 +180,31 @@ class DroplessMoELayer(base_layer.BaseLayer):
     a buffer: 0.25 GB a matrix, 2 ms each on a v5e, PERF.md section 6,
     PR 35), so the layer addresses its run of groups in the stack, as an
     attention layer addresses its pages."""
-    return ("w_gate", "w_up", "w_down")
+    return (("w_gate",) if self.p.activation == "reglu" else ()) + (
+        "w_up", "w_down")
 
   def RouterLogits(self, theta, x):
     """x [..., D], the transformer layer's un-normed input -> f32 [..., E].
     `TransformerLayer` calls it before its attention block and hands the
-    logits to FProp / RaggedStep."""
+    logits to FProp / RaggedStep ('layer_input'; a layer whose router reads
+    its own normed input takes its logits itself, in f32)."""
     th = self.CastTheta(theta)
     return jnp.einsum("...d,de->...e", self.ToFPropDtype(x), th.w_router,
                       preferred_element_type=jnp.float32)
+
+  def _Route(self, th, logits):
+    """logits f32 [T, E] -> (the k experts a token reaches [T, k] int32,
+    their weights f32 [T, k])."""
+    p = self.p
+    k = p.num_experts_per_token
+    if p.scoring == "softmax":
+      top_logits, top_idx = jax.lax.top_k(logits, k)
+      return top_idx, jax.nn.softmax(top_logits, axis=-1)
+    scores = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(scores + th.router_bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, top_idx, axis=-1)
+    return top_idx, p.routed_scale * chosen / jnp.sum(chosen, -1,
+                                                      keepdims=True)
 
   def _Experts(self, theta, x, logits, valid, layer=None):
     """x [T, D] normed tokens, logits f32 [T, E], valid bool [T] or None ->
@@ -131,8 +217,7 @@ class DroplessMoELayer(base_layer.BaseLayer):
     t, d = x.shape
     e, k = p.num_experts, p.num_experts_per_token
     with observe.Scope("moe_route"):
-      top_logits, top_idx = jax.lax.top_k(logits, k)               # [T, k]
-      weights = jax.nn.softmax(top_logits, axis=-1)
+      top_idx, weights = self._Route(th, logits)                   # [T, k]
       if valid is not None:
         # a padding token's pairs sort behind every expert's run
         top_idx = jnp.where(valid[:, None], top_idx, e)
@@ -142,15 +227,18 @@ class DroplessMoELayer(base_layer.BaseLayer):
       counts = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
       xs = x[order // k]                                            # [T*k, D]
     sizes, flat = counts, lambda w: w
-    if th.w_gate.ndim == 4:
-      layers = th.w_gate.shape[0]
+    if th.w_up.ndim == 4:
+      layers = th.w_up.shape[0]
       sizes = jax.lax.dynamic_update_slice(
           jnp.zeros((layers * e,), jnp.int32), counts,
           (jnp.asarray(layer, jnp.int32) * e,))
       flat = lambda w: w.reshape((-1,) + w.shape[2:])
     with observe.Scope("moe_experts"):
-      h = jax.nn.relu(GroupedMatmul(xs, flat(th.w_gate), sizes))
-      h = h * GroupedMatmul(xs, flat(th.w_up), sizes)
+      if p.activation == "reglu":
+        h = jax.nn.relu(GroupedMatmul(xs, flat(th.w_gate), sizes))
+        h = h * GroupedMatmul(xs, flat(th.w_up), sizes)
+      else:
+        h = jnp.square(jax.nn.relu(GroupedMatmul(xs, flat(th.w_up), sizes)))
       ys = GroupedMatmul(h.astype(xs.dtype), flat(th.w_down), sizes)
     with observe.Scope("moe_combine"):
       w_sorted = weights.reshape(-1)[order]
@@ -159,18 +247,37 @@ class DroplessMoELayer(base_layer.BaseLayer):
                      * w_sorted[:, None], 0.0)
       # unsort by a gather through the inverse permutation
       out = ys[jnp.argsort(order)].reshape(t, k, d).sum(axis=1)
+    if p.shared_hidden_dim:
+      with observe.Scope("moe_shared"):
+        up = jnp.einsum("td,df->tf", x, th.w_shared_up)
+        if p.activation == "reglu":
+          h = jax.nn.relu(jnp.einsum("td,df->tf", x, th.w_shared_gate)) * up
+        else:
+          h = jnp.square(jax.nn.relu(up))
+        out = out + jnp.einsum("tf,fd->td", h, th.w_shared_down)
     return out.astype(x.dtype), counts
 
-  def FPropWithCounts(self, theta, inputs, router_logits, paddings=None,
+  def FPropWithCounts(self, theta, inputs, router_logits=None, paddings=None,
                       layer=None):
     """inputs [..., D]; router_logits f32 [..., E] (RouterLogits of the
-    transformer layer's input); paddings [...] (1 = padding) or None.
+    transformer layer's input; None where the router reads this layer's own
+    normed input); paddings [...] (1 = padding) or None.
     Returns (inputs + experts [..., D], tokens by expert [E] int32)."""
     p = self.p
     with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, inputs)
     with observe.Scope("ffn"):
       d = x.shape[-1]
+      if p.router_reads == "normed_input":
+        assert router_logits is None
+        with observe.Scope("moe_route"):
+          # the norm and the product in f32: a router is a discrete cut, and
+          # a score rounded to the stream's precision moves it
+          router_logits = jnp.einsum(
+              "...d,de->...e",
+              self.ln.FProp(theta.ln, inputs.astype(jnp.float32)),
+              theta.w_router.astype(jnp.float32),
+              precision=jax.lax.Precision.HIGHEST)
       valid = None if paddings is None else paddings.reshape(-1) < 0.5
       out, counts = self._Experts(
           theta, x.reshape(-1, d),
@@ -178,7 +285,7 @@ class DroplessMoELayer(base_layer.BaseLayer):
       out = inputs + out.reshape(inputs.shape)
     return out, counts
 
-  def FProp(self, theta, inputs, paddings=None, *, router_logits):
+  def FProp(self, theta, inputs, paddings=None, *, router_logits=None):
     return self.FPropWithCounts(theta, inputs, router_logits, paddings)[0]
 
   # -- the serving step ------------------------------------------------------
@@ -189,8 +296,8 @@ class DroplessMoELayer(base_layer.BaseLayer):
     del theta
     return NestedMap(routed=jnp.zeros((self.p.num_experts,), jnp.int32))
 
-  def RaggedStep(self, theta, inputs, cached_states, rows, *, router_logits,
-                 layer=None):
+  def RaggedStep(self, theta, inputs, cached_states, rows, *,
+                 router_logits=None, layer=None):
     """inputs [1, T, D] packed tokens (core/ragged.py RaggedRows); the
     step's padding tokens are routed nowhere. layer: as in
     MultiHeadedAttention.RaggedStep, the index of this layer's `routed` in
@@ -198,7 +305,7 @@ class DroplessMoELayer(base_layer.BaseLayer):
     paddings = 1.0 - rows.valid.astype(jnp.float32)[None]
     out, counts = self.FPropWithCounts(
         theta, inputs, router_logits, paddings,
-        layer=layer if theta.w_gate.ndim == 4 else None)
+        layer=layer if theta.w_up.ndim == 4 else None)
     if layer is None:
       return out, NestedMap(routed=counts)
     return out, NestedMap(

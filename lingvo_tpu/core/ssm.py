@@ -404,6 +404,46 @@ def _InverseSoftplus(x):
   return x + jnp.log(-jnp.expm1(-x))
 
 
+def _PackedConv(u32, held_tail, conv_w, rows):
+  """The causal depthwise convolution's sum over the packed token axis
+  (bias and activation are the caller's). u32: [T, C] f32, this step's
+  inputs; held_tail: [B, K - 1, C], every slot's last K - 1 inputs; conv_w:
+  [K, C]. -> (sum [T, C] f32, the tails as the step reads them [B, K-1, C]:
+  zero where a row starts a request)."""
+  k = conv_w.shape[0]
+  t = u32.shape[0]
+  slots = held_tail.shape[0]
+  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
+  col = rows.col_of.astype(jnp.int32)
+  fresh = rows.row_q_pos == 0
+  tail = jnp.where(fresh[:, None, None], 0.0, held_tail)        # [B, K-1, C]
+  w = conv_w.astype(jnp.float32)
+  conv = w[k - 1] * u32
+  for back in range(1, k):
+    # the input `back` tokens before: of this step where the row has
+    # it, else of the slot's tail
+    here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
+    held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
+    conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here,
+                                        held)
+  return conv, tail
+
+
+def _PackedConvTail(u32, tail, rows):
+  """Every row's last K - 1 inputs after the step: the tail `_PackedConv`
+  read and this step's tokens together. -> [B, K - 1, C]."""
+  k = tail.shape[1] + 1
+  t = u32.shape[0]
+  n = rows.row_len.astype(jnp.int32)[:, None]                    # [B, 1]
+  i = jnp.arange(k - 1, dtype=jnp.int32)[None]                   # [1, K-1]
+  at = n - (k - 1) + i                                           # in the row
+  cols = jnp.take_along_axis(
+      rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
+  old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
+                            axis=1)
+  return jnp.where((at >= 0)[..., None], u32[jnp.clip(cols, 0, t - 1)], old)
+
+
 class Mamba1Layer(base_layer.BaseLayer):
   """Mamba-1 mixer (arXiv:2312.00752) for `transformer.BlockSequence`.
 
@@ -576,42 +616,18 @@ class Mamba1Layer(base_layer.BaseLayer):
     del table, depth, plan
     from lingvo_tpu.ops import selective_scan
     th = self.CastTheta(theta)
-    k = self.p.conv_width
-    t = x.shape[1]
     with observe.Scope("ssm_in_proj"):
       u, z = self._InProj(th, x[0])                             # [T, E]
     with observe.Scope("ssm_conv"):
       u32 = u.astype(jnp.float32)
-      slots = states.conv.shape[0]
-      row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
-      col = rows.col_of.astype(jnp.int32)
-      fresh = rows.row_q_pos == 0
-      tail = jnp.where(fresh[:, None, None], 0.0, states.conv)  # [B, K-1, E]
-      w = th.conv_w.astype(jnp.float32)
-      conv = w[k - 1] * u32
-      for back in range(1, k):
-        # the input `back` tokens before: of this step where the row has
-        # it, else of the slot's tail
-        here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
-        held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
-        conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here,
-                                            held)
+      conv, tail = _PackedConv(u32, states.conv, th.conv_w, rows)
     with observe.Scope("ssm_params"):
       c, delta, b_t, c_t = self._ScanInputs(th, conv)
     y, scan = selective_scan.SelectiveScan(
         delta, c, b_t, c_t, -jnp.exp(th.a_log.astype(jnp.float32)),
         th.d_skip, states.scan, rows)
     with observe.Scope("ssm_conv"):
-      # the row's last K - 1 inputs, old tail and this step's tokens together
-      n = rows.row_len.astype(jnp.int32)[:, None]                # [B, 1]
-      i = jnp.arange(k - 1, dtype=jnp.int32)[None]               # [1, K-1]
-      at = n - (k - 1) + i                                       # in the row
-      cols = jnp.take_along_axis(
-          rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
-      old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
-                                axis=1)
-      new_tail = jnp.where((at >= 0)[..., None],
-                           u32[jnp.clip(cols, 0, t - 1)], old)
+      new_tail = _PackedConvTail(u32, tail, rows)
     with observe.Scope("ssm_out_proj"):
       out, shared = self._Finish(th, y[None], z[None], shared)
     return out, NestedMap(scan=scan, conv=new_tail), shared
@@ -655,3 +671,211 @@ class GatedMemoryUnit(base_layer.BaseLayer):
     del rows, table, plan
     out, shared = self.FProp(theta, x, shared, depth=depth)
     return out, states, shared
+
+
+class Mamba2Layer(base_layer.BaseLayer):
+  """Mamba-2 mixer (arXiv:2405.21060, the scalar-decay SSD form with its
+  convolution and gated norm as published) for `transformer.BlockSequence`.
+
+  x [.., D]; Hm heads of P channels, E = Hm * P; G groups of N state
+  indices, a group's B and C shared by its Hm / G heads; K taps;
+  C = E + 2 G N channels through the convolution (x, B and C together):
+
+      [z; xBC; dt] = x W_in                                D -> E + C + Hm
+      xBC_t = silu(b_conv + sum_{k<K} w_conv[k] * xBC_{t-K+1+k})  depthwise
+      [u; B; C] = xBC            u [Hm, P], B and C [G, N]
+      delta_t = softplus(dt_t + dt_bias)                   [Hm]
+      S_t[h] = exp(delta_t[h] A[h]) S_{t-1}[h] + delta_t[h] u_t[h] (x) B_t[g(h)]
+               A = -exp(a_log), ONE decay a head           [Hm, P, N], f32
+      y_t[h] = S_t[h] C_t[g(h)] + d_skip[h] u_t[h]
+      y = RMSNorm_groups(y * silu(z)) * (1 + norm_scale)   the gate BEFORE the
+               norm; the mean square over each of the G groups of E / G
+               channels
+      out = y W_out                                        E -> D
+
+  What a sequence carries from token to token is S and the last K - 1 rows
+  of the un-convolved xBC. Serving keeps both a slot (`InitPagedStates`:
+  `scan` [slots, Hm, P, N] and `conv` [slots, K - 1, C], f32), zeroes them
+  where a row starts a request (`rows.row_q_pos == 0`) and carries them from
+  one chunk of a prompt to the next; they are leaves of the engine's states,
+  so the engine's slot gather / scatter (spill, restore) moves them with the
+  rest. The scan runs on the packed token axis (ops/packed_ssd_scan.py).
+
+  The published initialisation, so that state neither dies nor saturates:
+  A uniform in [1, 16] a head, step sizes log-uniform in [0.001, 0.1]
+  (`dt_bias` their inverse softplus), d_skip = 1.
+  """
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim (set by the wrapping layer).")
+    p.Define("num_heads", 0, "Heads Hm, one decay each.")
+    p.Define("head_dim", 64, "Channels P a head (E = num_heads * head_dim).")
+    p.Define("num_groups", 1, "Groups G that share B and C; divides Hm.")
+    p.Define("state_dim", 128, "State indices N.")
+    p.Define("conv_width", 4, "Taps K of the causal depthwise convolution.")
+    p.Define("norm_epsilon", 1e-5, "The gated RMSNorm's epsilon.")
+    p.Define("chunk_size", 64, "Packed tokens a chunk of the serving scan.")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    assert p.input_dim > 0 and p.conv_width > 1 and p.num_heads > 0
+    assert p.num_heads % p.num_groups == 0, (p.num_heads, p.num_groups)
+    d, hm = p.input_dim, p.num_heads
+    self._e = e = hm * p.head_dim
+    self._c = c = e + 2 * p.num_groups * p.state_dim
+    k = p.conv_width
+    init = p.params_init
+    self.CreateVariable("w_in", WeightParams((d, e + c + hm), init, p.dtype))
+    self.CreateVariable("conv_w", WeightParams(
+        (k, c), WeightInit.Uniform(k ** -0.5), p.dtype))
+    self.CreateVariable("conv_b", WeightParams(
+        (c,), WeightInit.Constant(0.0), p.dtype))
+    # both overwritten at instantiation with the published initialisation
+    self.CreateVariable("dt_bias", WeightParams(
+        (hm,), WeightInit.Uniform(1.0), p.dtype))
+    self.CreateVariable("a_log", WeightParams(
+        (hm,), WeightInit.Uniform(1.0), p.dtype))
+    self.CreateVariable("d_skip", WeightParams(
+        (hm,), WeightInit.Constant(1.0), p.dtype))
+    # (1 + scale), as layers.RmsNorm stores it
+    self.CreateVariable("norm_scale", WeightParams(
+        (e,), WeightInit.Constant(0.0), p.dtype))
+    self.CreateVariable("w_out", WeightParams((e, d), init, p.dtype))
+
+  def InstantiateVariables(self, key):
+    p = self.p
+    theta = super().InstantiateVariables(key)
+    # both drew uniform in [-1, 1]: to A uniform in [1, 16], and to a
+    # log-uniform step size and its inverse softplus
+    u = 0.5 * (theta.a_log.astype(jnp.float32) + 1.0)
+    theta.a_log = jnp.log(1.0 + 15.0 * u).astype(p.dtype)
+    u = 0.5 * (theta.dt_bias.astype(jnp.float32) + 1.0)
+    dt = jnp.exp(u * (jnp.log(_DT_MAX) - jnp.log(_DT_MIN)) + jnp.log(_DT_MIN))
+    theta.dt_bias = _InverseSoftplus(dt).astype(p.dtype)
+    return theta
+
+  def StateBytesPerSlot(self) -> int:
+    """Scan state and convolution tail of one sequence, f32."""
+    p = self.p
+    return 4 * (self._e * p.state_dim + (p.conv_width - 1) * self._c)
+
+  # -- the layer's arithmetic ------------------------------------------------
+
+  def _InProj(self, th, x):
+    """x [.., D] -> (z [.., E], xBC [.., C], dt [.., Hm])."""
+    e, c = self._e, self._c
+    proj = jnp.einsum("...d,df->...f", x, th.w_in)
+    return proj[..., :e], proj[..., e:e + c], proj[..., e + c:]
+
+  def _ScanInputs(self, th, conv, dt):
+    """conv: the convolution's sum [.., C] f32 (bias not yet added); dt
+    [.., Hm] -> (u [.., Hm, P], delta [.., Hm], B, C [.., G, N]), f32."""
+    p = self.p
+    e, gn = self._e, p.num_groups * p.state_dim
+    xbc = jax.nn.silu(conv + th.conv_b.astype(jnp.float32))
+    lead = xbc.shape[:-1]
+    u = xbc[..., :e].reshape(lead + (p.num_heads, p.head_dim))
+    b = xbc[..., e:e + gn].reshape(lead + (p.num_groups, p.state_dim))
+    c = xbc[..., e + gn:].reshape(lead + (p.num_groups, p.state_dim))
+    delta = jax.nn.softplus(dt.astype(jnp.float32)
+                            + th.dt_bias.astype(jnp.float32))
+    return u, delta, b, c
+
+  def _GateNorm(self, th, y, z):
+    """y [.., Hm, P] f32, z [.., E] -> [.., E] in the fprop dtype."""
+    p = self.p
+    groups = p.num_groups
+    lead = z.shape[:-1]
+    gated = y.reshape(lead + (self._e,)) * jax.nn.silu(z.astype(jnp.float32))
+    by_group = gated.reshape(lead + (groups, self._e // groups))
+    ms = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
+    normed = (by_group * jax.lax.rsqrt(ms + p.norm_epsilon)).reshape(
+        lead + (self._e,))
+    return (normed * (1.0 + th.norm_scale.astype(jnp.float32))).astype(
+        self.fprop_dtype)
+
+  # -- whole sequences -------------------------------------------------------
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=None):
+    """x: [B, T, D] -> ([B, T, D], shared). A plain scan over T: what tests
+    and a reference-sized forward use, not a training path that was tuned."""
+    del depth
+    if segment_ids is not None:
+      raise NotImplementedError(
+          "Mamba2Layer.FProp does not reset state between packed segments")
+    p = self.p
+    th = self.CastTheta(theta)
+    k = p.conv_width
+    t = x.shape[1]
+    with observe.Scope("ssd_in_proj"):
+      z, xbc, dt = self._InProj(th, x)
+    with observe.Scope("ssd_conv"):
+      padded = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+      w = th.conv_w.astype(jnp.float32)
+      conv = sum(w[i] * padded[:, i:i + t] for i in range(k))
+      u, delta, b_t, c_t = self._ScanInputs(th, conv, dt)
+    if paddings is not None:
+      delta = delta * (1.0 - paddings.astype(jnp.float32))[..., None]
+    a = -jnp.exp(th.a_log.astype(jnp.float32))
+    r = p.num_heads // p.num_groups
+
+    def _Token(s, xs):
+      d, uu, bb, cc = xs                    # [B,Hm] [B,Hm,P] [B,G,N] [B,G,N]
+      bb, cc = jnp.repeat(bb, r, axis=1), jnp.repeat(cc, r, axis=1)
+      s = (jnp.exp(d * a)[..., None, None] * s
+           + (d[..., None] * uu)[..., None] * bb[:, :, None, :])
+      return s, jnp.sum(s * cc[:, :, None, :], axis=-1)
+
+    s0 = jnp.zeros((x.shape[0], p.num_heads, p.head_dim, p.state_dim),
+                   jnp.float32)
+    with observe.Scope("ssd_scan"):
+      _, ys = jax.lax.scan(_Token, s0, tuple(
+          jnp.moveaxis(v, 1, 0) for v in (delta, u, b_t, c_t)))
+      y = (jnp.moveaxis(ys, 0, 1)
+           + th.d_skip.astype(jnp.float32)[:, None] * u)
+    with observe.Scope("ssd_gate_norm"):
+      gated = self._GateNorm(th, y, z)
+    with observe.Scope("ssd_out_proj"):
+      out = jnp.einsum("...e,ed->...d", gated, th.w_out)
+    if paddings is not None:
+      out = py_utils.ApplyPadding(paddings, out)
+    return out, shared
+
+  # -- continuous-batching serving -------------------------------------------
+
+  def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
+    del theta
+    assert num_slots > 0, "Mamba2Layer keeps a state a slot"
+    p = self.p
+    return NestedMap(
+        scan=jnp.zeros((num_slots, p.num_heads, p.head_dim, p.state_dim),
+                       jnp.float32),
+        conv=jnp.zeros((num_slots, p.conv_width - 1, self._c), jnp.float32))
+
+  def RaggedStep(self, theta, x, states, shared, rows, table=None,
+                 depth=None, plan=None):
+    """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
+    ([1, T, D], new states, shared)."""
+    del table, depth, plan
+    from lingvo_tpu.ops import packed_ssd_scan
+    th = self.CastTheta(theta)
+    with observe.Scope("ssd_in_proj"):
+      z, xbc, dt = self._InProj(th, x[0])
+    with observe.Scope("ssd_conv"):
+      xbc32 = xbc.astype(jnp.float32)
+      conv, tail = _PackedConv(xbc32, states.conv, th.conv_w, rows)
+      new_tail = _PackedConvTail(xbc32, tail, rows)
+      u, delta, b_t, c_t = self._ScanInputs(th, conv, dt)
+    y, scan = packed_ssd_scan.PackedSsdScan(
+        u, delta, -jnp.exp(th.a_log.astype(jnp.float32)), b_t, c_t,
+        th.d_skip, states.scan, rows, chunk_size=self.p.chunk_size)
+    with observe.Scope("ssd_gate_norm"):
+      gated = self._GateNorm(th, y, z)
+    with observe.Scope("ssd_out_proj"):
+      out = jnp.einsum("...e,ed->...d", gated, th.w_out)
+    return out[None], NestedMap(scan=scan, conv=new_tail), shared

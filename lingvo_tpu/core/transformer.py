@@ -278,7 +278,8 @@ class TransformerLayer(base_layer.BaseLayer):
     reads the layer's INPUT, so its logits are taken here, before the
     attention block, and ride past it as a keyword of the feed-forward's
     call. Nothing otherwise."""
-    if hasattr(self.fflayer, "RouterLogits"):
+    if (hasattr(self.fflayer, "RouterLogits")
+        and self.fflayer.p.router_reads == "layer_input"):
       with observe.Scope("ffn"), observe.Scope("moe_route"):
         return {"router_logits": self.fflayer.RouterLogits(theta.fflayer,
                                                            inputs)}
@@ -832,53 +833,105 @@ class SharedStateLayer(base_layer.BaseLayer):
   `BlockSequence`: one that reads or writes what the stack's layers share
   (`shared`: the one pool of pages, a memory an earlier layer exported, in a
   whole-sequence forward an earlier layer's K and V). The mixer's contract
-  (ssm.Mamba1Layer, ssm.GatedMemoryUnit, attention.DifferentialAttention):
+  (ssm.Mamba1Layer, ssm.Mamba2Layer, ssm.GatedMemoryUnit,
+  attention.DifferentialAttention, attention.PooledAttention):
   `FProp(theta, x, shared, paddings, segment_ids, depth) -> (out, shared)`,
   `InitPagedStates(theta, num_slots)`, and `RaggedStep(theta, x, states,
   shared, rows, table, depth, plan) -> (out, states, shared)` (`plan`: the
-  step's attention.RaggedPlan, which a mixer that is no attention drops)."""
+  step's attention.RaggedPlan, which a mixer that is no attention drops).
+
+  Either branch may be absent: `mixer_tpl` None is a layer that is its
+  feed-forward alone, `tr_fflayer_tpl` None one that is its mixer alone. The
+  absent branch has no norm, no weights and no op. The feed-forward may be
+  an expert layer (core/moe.DroplessMoELayer whose router reads its own
+  normed input): its tokens by expert are the layer's `routed` state leaf,
+  and a scanned block hands it the experts' matrices whole with the repeat's
+  index (`StackAddressed`, `repeat`)."""
 
   @classmethod
   def Params(cls):
     p = super().Params()
     p.Define("input_dim", 0, "Model dim.")
-    p.Define("mixer_tpl", None, "The mixer's template.")
+    p.Define("mixer_tpl", None, "The mixer's template; None: no mixer.")
     p.Define("norm_tpl", layers_lib.LayerNorm.Params(), "The mixer's norm.")
     p.Define("tr_fflayer_tpl", TransformerFeedForwardLayer.Params(),
-             "Feed-forward block (with its own norm and residual).")
+             "Feed-forward block (with its own norm and residual); None: "
+             "no feed-forward.")
     return p
 
   def __init__(self, params):
     super().__init__(params)
     p = self.p
-    self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=p.input_dim))
-    self.CreateChild("atten", p.mixer_tpl.Copy().Set(input_dim=p.input_dim))
-    self.CreateChild("fflayer",
-                     p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim))
+    assert p.mixer_tpl is not None or p.tr_fflayer_tpl is not None
+    self.mixer = None
+    if p.mixer_tpl is not None:
+      self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=p.input_dim))
+      self.CreateChild("atten", p.mixer_tpl.Copy().Set(input_dim=p.input_dim))
+      self.mixer = self.atten
+    self._experts = False
+    if p.tr_fflayer_tpl is not None:
+      self.CreateChild("fflayer",
+                       p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim))
+      self._experts = hasattr(self.fflayer, "FPropWithCounts")
+      assert not self._experts or (
+          self.fflayer.p.router_reads == "normed_input"), (
+              "an expert layer of a BlockSequence routes from its own input")
+
+  def StackAddressed(self) -> set:
+    """As TransformerLayer.StackAddressed: the experts' matrices."""
+    if not self._experts:
+      return set()
+    return {("fflayer", name) for name in self.fflayer.StackAddressed()}
+
+  def _FeedForward(self, theta, x, paddings, repeat):
+    """-> (x, tokens by expert or None)."""
+    if self.p.tr_fflayer_tpl is None:
+      return x, None
+    if not self._experts:
+      return self.fflayer.FProp(theta.fflayer, x, paddings), None
+    return self.fflayer.FPropWithCounts(
+        theta.fflayer, x, None, paddings,
+        layer=repeat if theta.fflayer.w_up.ndim == 4 else None)
 
   def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
-            depth=0):
-    with observe.Scope("norm"):
-      normed = self.ln.FProp(theta.ln, x)
-    with observe.Scope("atten"):
-      out, shared = self.atten.FProp(theta.atten, normed, shared,
-                                     paddings=paddings,
-                                     segment_ids=segment_ids, depth=depth)
-      x = x + out
-    return self.fflayer.FProp(theta.fflayer, x, paddings), shared
+            depth=0, repeat=None):
+    if self.mixer is not None:
+      with observe.Scope("norm"):
+        normed = self.ln.FProp(theta.ln, x)
+      with observe.Scope("atten"):
+        out, shared = self.atten.FProp(theta.atten, normed, shared,
+                                       paddings=paddings,
+                                       segment_ids=segment_ids, depth=depth)
+        x = x + out
+    return self._FeedForward(theta, x, paddings, repeat)[0], shared
 
   def InitPagedStates(self, theta, num_slots):
-    return self.atten.InitPagedStates(theta.atten, num_slots)
+    states = (self.atten.InitPagedStates(theta.atten, num_slots)
+              if self.mixer is not None else NestedMap())
+    if self._experts:
+      states.routed = self.fflayer.InitPagedStates(theta.fflayer).routed
+    return states
 
-  def RaggedStep(self, theta, x, states, shared, rows, table, depth, plan):
-    with observe.Scope("norm"):
-      normed = self.ln.FProp(theta.ln, x)
-    with observe.Scope("atten"):
-      out, states, shared = self.atten.RaggedStep(
-          theta.atten, normed, states, shared, rows, table=table, depth=depth,
-          plan=plan)
-      x = x + out
-    return self.fflayer.FProp(theta.fflayer, x), states, shared
+  def RaggedStep(self, theta, x, states, shared, rows, table, depth, plan,
+                 repeat=None):
+    if self.mixer is not None:
+      if self._experts:
+        states = NestedMap({k: v for k, v in states.items() if k != "routed"})
+      with observe.Scope("norm"):
+        normed = self.ln.FProp(theta.ln, x)
+      with observe.Scope("atten"):
+        out, states, shared = self.atten.RaggedStep(
+            theta.atten, normed, states, shared, rows, table=table,
+            depth=depth, plan=plan)
+        x = x + out
+    if self._experts:
+      # the step's padding tokens are routed nowhere
+      x, counts = self._FeedForward(
+          theta, x, 1.0 - rows.valid.astype(jnp.float32)[None], repeat)
+      states = states.Copy()
+      states.routed = counts
+      return x, states, shared
+    return self._FeedForward(theta, x, None, repeat)[0], states, shared
 
 
 class BlockSequence(base_layer.BaseLayer):
@@ -934,7 +987,7 @@ class BlockSequence(base_layer.BaseLayer):
     self._first_depth, self._table_of, depth, owners = [], [], 0, 0
     for layers, reps in zip(self._bodies, self._repeats):
       self._first_depth.append(depth)
-      own = [getattr(l.atten.p, "kv_owner", None) for l in layers]
+      own = [getattr(l.mixer, "kv_owner", None) for l in layers]
       per = sum(bool(o) for o in own)
       tables, k = [], 0
       for o in own:
@@ -954,8 +1007,12 @@ class BlockSequence(base_layer.BaseLayer):
     self._num_owners = owners
     # width of the memory a layer exports (0: none does)
     self._memory_dim = max(
-        [l.atten._e for layers in self._bodies for l in layers
-         if getattr(l.atten.p, "export_memory", False)], default=0)
+        [m._e for m, _ in self._Mixers()
+         if getattr(m.p, "export_memory", False)], default=0)
+    # a scanned block hands these to its layers whole (StackAddressed)
+    self._whole = [
+        {("x_layers", str(j)) + path for j, l in enumerate(layers)
+         for path in l.StackAddressed()} for layers in self._bodies]
 
   def InstantiateVariables(self, key):
     if self._path is None:
@@ -971,28 +1028,44 @@ class BlockSequence(base_layer.BaseLayer):
             getattr(self, f"block_{b}"), reps)
         for b, reps in enumerate(self._repeats)})
 
+  def _Mixers(self):
+    """[(mixer, repeats of its block)] for every layer that has one, a
+    repeated block's layers once."""
+    return [(l.mixer, reps) for layers, reps in zip(self._bodies,
+                                                    self._repeats)
+            for l in layers if l.mixer is not None]
+
   def MixerLayers(self):
     """[(mixer, how many layers of the stack are it)] for the mixers that
     keep decode state, pages or a slot's (serving/spec_decode.MixerLayers)."""
-    return [(l.atten, reps) for layers, reps in zip(self._bodies,
-                                                    self._repeats)
-            for l in layers if hasattr(l.atten, "StateBytesPerSlot")
-            or hasattr(l.atten, "KvBytesPerToken")]
+    return [(m, reps) for m, reps in self._Mixers()
+            if hasattr(m, "StateBytesPerSlot")
+            or hasattr(m, "KvBytesPerToken")]
+
+  def LayerKinds(self) -> dict:
+    """{what a layer is: how many layers of the stack are it}: a layer is
+    its mixer's class, its feed-forward's, or both joined by '+'."""
+    kinds: dict = {}
+    for layers, reps in zip(self._bodies, self._repeats):
+      for l in layers:
+        name = "+".join(type(c).__name__ for c in (
+            l.mixer, getattr(l, "fflayer", None)) if c is not None)
+        kinds[name] = kinds.get(name, 0) + reps
+    return kinds
 
   def PageWindows(self):
     """The window of every layer that OWNS pages (0 = full), in stack
     order: one block table each, all out of one pool of uniform pages
     (serving/kv_cache.KindPages)."""
-    return [int(l.atten.p.window) for layers, reps in zip(
-        self._bodies, self._repeats) for _ in range(reps) for l in layers
-            if getattr(l.atten.p, "kv_owner", False)]
+    return [int(m.p.window) for m, reps in self._Mixers()
+            for _ in range(reps) if getattr(m, "kv_owner", False)]
 
   def _AttentionMixers(self) -> list:
     """The mixer of every layer of the stack that attends over pages, in
     stack order (a repeated block's once a repeat)."""
-    return [l.atten for layers, reps in zip(self._bodies, self._repeats)
+    return [l.mixer for layers, reps in zip(self._bodies, self._repeats)
             for _ in range(reps) for l in layers
-            if hasattr(l.atten, "RaggedPlanKey")]
+            if hasattr(l.mixer, "RaggedPlanKey")]
 
   def RaggedPlanKeys(self, cached_states) -> list:
     """One key an attention call of the step (DifferentialAttention.
@@ -1002,32 +1075,50 @@ class BlockSequence(base_layer.BaseLayer):
 
   def SharedKvReadLayers(self) -> int:
     """Layers that read pages they do not own."""
-    return sum(reps for layers, reps in zip(self._bodies, self._repeats)
-               for l in layers if getattr(l.atten.p, "kv_owner", None) is False)
+    return sum(reps for m, reps in self._Mixers()
+               if getattr(m, "kv_owner", None) is False)
 
   def _Scan(self, b, theta, x, shared, per_repeat, call):
     """Block b as one scan over its repeats: `call(layer, theta_j, x,
-    shared, j-th entry of every per-repeat tree, depth) -> (x, out_j,
-    shared)`; returns (x, shared, [out_j stacked over repeats])."""
+    shared, j-th entry of every per-repeat tree, depth, repeat) -> (x,
+    out_j, shared)`; returns (x, shared, [out_j stacked over repeats]).
+    The variables a layer addresses in the stack by `repeat`
+    (`StackAddressed`: an expert layer's matrices) are not scanned: a slice
+    of them a trip would be a copy of them a trip."""
     layers = self._bodies[b]
     first = self._first_depth[b]
+    whole = self._whole[b]
+    block = theta[f"block_{b}"]
+
+    def _Whole(path):
+      return tuple(str(getattr(k, "key", getattr(k, "name", getattr(
+          k, "idx", "")))) for k in path) in whole
+
+    scanned = block
+    if whole:
+      scanned = jax.tree_util.tree_map_with_path(
+          lambda path, leaf: jnp.zeros(leaf.shape[:1], leaf.dtype)
+          if _Whole(path) else leaf, block)
 
     def _Body(carry, per):
       x, shared = carry
       theta_i, idx, extra = per
+      if whole:
+        theta_i = jax.tree_util.tree_map_with_path(
+            lambda path, mine, stack: stack if _Whole(path) else mine,
+            theta_i, block)
       outs = []
       for j, layer in enumerate(layers):
         depth = first + idx * len(layers) + j
         x, out, shared = call(layer, theta_i.x_layers[j], x, shared, j,
-                              extra, depth)
+                              extra, depth, idx)
         outs.append(out)
       return (x, shared), outs
 
     with observe.Scope("layer_scan"):
       (x, shared), outs = jax.lax.scan(
           _Body, (x, shared),
-          (theta[f"block_{b}"], jnp.arange(self._repeats[b]),
-           per_repeat))
+          (scanned, jnp.arange(self._repeats[b]), per_repeat))
     return x, shared, outs
 
   def FProp(self, theta, inputs, paddings=None, aux_vecs=None,
@@ -1037,16 +1128,16 @@ class BlockSequence(base_layer.BaseLayer):
     shared = NestedMap()
     if self._memory_dim:
       shared.memory = jnp.zeros((bsz, t, self._memory_dim), inputs.dtype)
-    kv = [l.atten for layers in self._bodies for l in layers
-          if getattr(l.atten.p, "export_kv", False)]
+    kv = [m for m, _ in self._Mixers() if getattr(m.p, "export_kv", False)]
     if kv:
       shape = (bsz, t, kv[0].p.num_kv_heads, kv[0]._h)
       shared.key = shared.value = jnp.zeros(shape, inputs.dtype)
 
-    def _Call(layer, theta_j, x, shared, j, extra, depth):
+    def _Call(layer, theta_j, x, shared, j, extra, depth, repeat):
       del j, extra
       x, shared = layer.FProp(theta_j, x, shared, paddings=paddings,
-                              segment_ids=segment_ids, depth=depth)
+                              segment_ids=segment_ids, depth=depth,
+                              repeat=repeat)
       return x, None, shared
 
     x = inputs
@@ -1060,8 +1151,7 @@ class BlockSequence(base_layer.BaseLayer):
       raise NotImplementedError(
           f"kv_cache_dtype {kv_cache_dtype!r}: the differential attend "
           "kernel reads float pages")
-    owners = [l.atten for layers in self._bodies for l in layers
-              if getattr(l.atten.p, "kv_owner", False)]
+    owners = [m for m, _ in self._Mixers() if getattr(m, "kv_owner", False)]
     assert owners, "BlockSequence serves a stack in which some layer owns pages"
     shapes = {(a.p.num_kv_heads, a._h) for a in owners}
     assert len(shapes) == 1, f"one pool, one page shape: {shapes}"
@@ -1095,7 +1185,7 @@ class BlockSequence(base_layer.BaseLayer):
     keys = self.RaggedPlanKeys(cached_states)
     plan = attention_lib.BuildRaggedPlan(
         keys, rows, *block_tables.shape[-2:],
-        page_writes=any(a.p.kv_owner and key.kernel for a, key in zip(
+        page_writes=any(a.writes_by_plan and key.kernel for a, key in zip(
             self._AttentionMixers(), keys)))
     x = inputs
     new_states = NestedMap(blocks=[])
@@ -1110,7 +1200,7 @@ class BlockSequence(base_layer.BaseLayer):
         mine = block_tables[first:first + reps * len(own)].reshape(
             (reps, len(own)) + block_tables.shape[1:])
 
-      def _Call(layer, theta_j, x, shared, j, extra, depth,
+      def _Call(layer, theta_j, x, shared, j, extra, depth, repeat,
                 tables_of=tables_of):
         states_i, mine_i = extra
         place = tables_of[j]
@@ -1119,7 +1209,8 @@ class BlockSequence(base_layer.BaseLayer):
           table = (mine_i[place[2]] if place[0] == "own"
                    else block_tables[place[1]])
         x, ns, shared = layer.RaggedStep(theta_j, x, states_i[j], shared,
-                                         rows, table, depth, plan)
+                                         rows, table, depth, plan,
+                                         repeat=repeat)
         return x, ns, shared
 
       x, shared, outs = self._Scan(

@@ -19,6 +19,10 @@ from lingvo_tpu.core import transformer as transformer_lib
 from lingvo_tpu.core.nested_map import NestedMap
 
 
+# hybrid_override_pattern's letters
+PATTERN_KINDS = {"M": "mamba2", "E": "experts", "*": "gqa"}
+
+
 def KindBlocks(kinds) -> list[tuple[list, int]]:
   """A list of layer kinds as blocks in sequence, [(a block's kinds,
   repeats)]: from each position the shortest stretch that repeats at least
@@ -85,10 +89,22 @@ class TransformerLm(base_model.BaseTask):
         "(atten_tpl, an attention.DifferentialAttention: within "
         "sliding_window_size, over everything, or over everything through "
         "the pages of the 'full' layer before it, with no K and V of its "
-        "own), 'gmu' (ssm.GatedMemoryUnit over that memory). Stretches "
+        "own), 'gmu' (ssm.GatedMemoryUnit over that memory): each of "
+        "these a mixer and the dense feed-forward. A layer that is ONE "
+        "branch alone: 'mamba2' (mixer_tpl, an ssm.Mamba2Layer, and no "
+        "feed-forward), 'gqa' (atten_tpl, an attention.PooledAttention "
+        "over everything that owns its pages, and no feed-forward), "
+        "'experts' (expert_ffn_tpl, a core/moe.DroplessMoELayer whose "
+        "router reads its own normed input, and no mixer). Stretches "
         "that repeat are scanned, what lies between them is a block of "
         "its own (transformer.BlockSequence); no layer carries a position. "
         "None = the layouts below.")
+    p.Define(
+        "hybrid_override_pattern", None,
+        "layer_kinds as one letter a layer, for a stack of single-branch "
+        "layers: 'M' = 'mamba2', 'E' = 'experts', '*' = 'gqa'. The stack "
+        "is its first num_layers letters, so a cut of a published depth "
+        "keeps the pattern's start. None = layer_kinds as given.")
     p.Define("use_rotary", True, "RoPE instead of absolute positions.")
     p.Define("rope_theta", 1e4,
              "RoPE base where a layer rotates. KV heads and a head size "
@@ -190,8 +206,14 @@ class TransformerLm(base_model.BaseTask):
           layers_lib.PositionalEmbeddingLayer.Params().Set(
               embedding_dim=p.model_dim))
 
-    if p.layer_kinds is not None:
-      self.CreateChild("stack", self._KindStack())
+    if p.hybrid_override_pattern is not None:
+      assert p.layer_kinds is None
+      assert p.num_layers <= len(p.hybrid_override_pattern), p.num_layers
+      self.CreateChild("stack", self._KindStack(
+          [PATTERN_KINDS[c]
+           for c in p.hybrid_override_pattern[:p.num_layers]]))
+    elif p.layer_kinds is not None:
+      self.CreateChild("stack", self._KindStack(p.layer_kinds))
     else:
       self._CreateLayoutStack()
     if p.softmax_num_sampled > 0:
@@ -211,25 +233,32 @@ class TransformerLm(base_model.BaseTask):
         (p.norm_tpl or layers_lib.LayerNorm.Params()).Copy().Set(
             input_dim=p.model_dim))
 
-  def _KindStack(self):
+  def _KindStack(self, layer_kinds):
     """Params of the stack `layer_kinds` describes."""
     from lingvo_tpu.core import ssm as ssm_lib
     p = self.p
-    assert len(p.layer_kinds) == p.num_layers, (p.layer_kinds, p.num_layers)
+    assert len(layer_kinds) == p.num_layers, (layer_kinds, p.num_layers)
     assert p.mixer_tpl is not None and p.atten_tpl is not None
-    assert p.num_experts == 0 and p.expert_ffn_tpl is None
+    assert p.num_experts == 0
+    assert (p.expert_ffn_tpl is not None) == ("experts" in layer_kinds)
     assert not p.bidirectional
     atten = p.atten_tpl.Copy().Set(num_heads=p.num_heads)
+    # a mixer with the dense feed-forward after it
     mixers = {
-        "mamba": p.mixer_tpl.Copy().Set(export_memory=False),
-        "mamba_export": p.mixer_tpl.Copy().Set(export_memory=True),
-        "window": atten.Copy().Set(window=p.sliding_window_size),
-        "full": atten.Copy().Set(window=0, export_kv=True),
-        "cross": atten.Copy().Set(window=0, kv_owner=False),
-        "gmu": ssm_lib.GatedMemoryUnit.Params().Set(
+        "mamba": lambda: p.mixer_tpl.Copy().Set(export_memory=False),
+        "mamba_export": lambda: p.mixer_tpl.Copy().Set(export_memory=True),
+        "window": lambda: atten.Copy().Set(window=p.sliding_window_size),
+        "full": lambda: atten.Copy().Set(window=0, export_kv=True),
+        "cross": lambda: atten.Copy().Set(window=0, kv_owner=False),
+        "gmu": lambda: ssm_lib.GatedMemoryUnit.Params().Set(
             memory_dim=p.mixer_tpl.expand * p.model_dim),
     }
-    assert p.sliding_window_size > 0 or "window" not in p.layer_kinds
+    # one branch alone
+    alone = {
+        "mamba2": lambda: p.mixer_tpl.Copy(),
+        "gqa": lambda: atten.Copy().Set(window=0),
+    }
+    assert p.sliding_window_size > 0 or "window" not in layer_kinds
     layer = transformer_lib.SharedStateLayer.Params()
     layer.tr_fflayer_tpl.Set(
         hidden_dim=p.hidden_dim, activation="SILU", use_gated_activation=True,
@@ -237,8 +266,20 @@ class TransformerLm(base_model.BaseTask):
     if p.norm_tpl is not None:
       layer.norm_tpl = p.norm_tpl.Copy()
       layer.tr_fflayer_tpl.norm_tpl = p.norm_tpl.Copy()
-    blocks = [([layer.Copy().Set(mixer_tpl=mixers[kind]) for kind in kinds],
-               reps) for kinds, reps in KindBlocks(p.layer_kinds)]
+
+    def _Layer(kind):
+      if kind in mixers:
+        return layer.Copy().Set(mixer_tpl=mixers[kind]())
+      if kind in alone:
+        return layer.Copy().Set(mixer_tpl=alone[kind](), tr_fflayer_tpl=None)
+      assert kind == "experts", kind
+      experts = p.expert_ffn_tpl.Copy()
+      if p.norm_tpl is not None:
+        experts.norm_tpl = p.norm_tpl.Copy()
+      return layer.Copy().Set(mixer_tpl=None, tr_fflayer_tpl=experts)
+
+    blocks = [([_Layer(kind) for kind in kinds], reps)
+              for kinds, reps in KindBlocks(layer_kinds)]
     return transformer_lib.BlockSequence.Params().Set(
         input_dim=p.model_dim, blocks=blocks)
 

@@ -86,13 +86,15 @@ ENGINE_STATS_REQUIRED = frozenset(
 
 # Keys present only under specific configurations:
 #   state_slots, shared_kv_read_layers — stacks with O(1)-state mixers
+#   layer_kinds — stacks told as data (transformer.BlockSequence): how many
+#                 layers are a mixer of which class, a feed-forward of which
 #   spec        — engines with a draft source
 #   trace       — engines with tracing enabled (the default)
 #   compile     — per-compiled-program records (observe/profile.py)
 #   watchdog    — engines with a stall watchdog (observe/watchdog.py)
 ENGINE_STATS_OPTIONAL = frozenset(
-    {"state_slots", "shared_kv_read_layers", "spec", "trace", "compile",
-     "watchdog"})
+    {"state_slots", "shared_kv_read_layers", "layer_kinds", "spec", "trace",
+     "compile", "watchdog"})
 
 
 def ValidateEngineStats(stats: dict) -> dict:
@@ -326,7 +328,7 @@ DEVICE_SCOPES = {
     "norm": (None, "every layer norm: a block's pre-norm, the final norm, "
              "and in the serving step the gather of the head's columns"),
     "atten": (None, "a layer's sequence mixer with its residual add: "
-              "attention, a Mamba-1 layer or a gated memory unit"),
+              "attention, a Mamba-1 or Mamba-2 layer or a gated memory unit"),
     "qkv_proj": ("atten", "the query, key and value projections"),
     "rope": ("atten", "rotary position embedding of q and k, and the "
              "query's scale"),
@@ -360,14 +362,29 @@ DEVICE_SCOPES = {
                  "kernel is named after it)"),
     "ssm_out_proj": ("atten", "the gate by z and the output projection"),
     "gmu": ("atten", "a gated memory unit, whole"),
+    "ssd_in_proj": ("atten", "a Mamba-2 layer's input projection to z, xBC "
+                    "and dt"),
+    "ssd_conv": ("atten", "its causal depthwise convolution over x, B and C "
+                 "together with the silu, the slot tail's gather and its "
+                 "write-back, and the step size's softplus"),
+    "ssd_scan": ("ssm_scan", "the scalar-decay scan over the packed tokens "
+                 "(ops/packed_ssd_scan.py), slot state in and out: the "
+                 "chunked form's operations and the pass over the slots' "
+                 "states (its kernel is named after it)"),
+    "ssd_gate_norm": ("atten", "the gate by z and the RMSNorm over groups of "
+                      "channels after it"),
+    "ssd_out_proj": ("atten", "the output projection"),
     "ffn": (None, "a layer's feed-forward with its residual add: dense, or "
             "the expert layer"),
     "moe_route": ("ffn", "router logits, top-k and the softmax over them"),
     "moe_dispatch": ("ffn", "sort of the (token, expert) pairs, counts, and "
                      "the gather of tokens into expert order"),
-    "moe_experts": ("ffn", "the three grouped matmuls (megablox names its "
+    "moe_experts": ("ffn", "the grouped matmuls, three of a gated expert or two "
+                    "of an ungated one (megablox names its "
                     "kernels `gmm` inside it)"),
     "moe_combine": ("ffn", "weighting, un-sort and sum of a token's experts"),
+    "moe_shared": ("ffn", "the shared expert every token goes through, "
+                   "beside the routed ones"),
     "layer_scan": (None, "a scan over stacked layers, less what its layers "
                    "name: the slices of the stacked weights and states a "
                    "trip reads and the stacking of what it hands back"),

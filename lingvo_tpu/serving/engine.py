@@ -1633,6 +1633,8 @@ class ServingLoop:
       stats["scheduler"] = self.sched.Stats()
       stats["kv_pages"] = (self._kind_pages or self.alloc).Stats()
       stats["mixers"] = dict(self.mixers)
+      if hasattr(self._task.stack, "LayerKinds"):
+        stats["layer_kinds"] = self._task.stack.LayerKinds()
       stats["prefix_cache"] = (
           self.prefix_cache.Stats() if self.prefix_cache is not None
           else observe_schema.DisabledPrefixCacheStats())

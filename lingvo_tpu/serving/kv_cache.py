@@ -357,7 +357,8 @@ class KindPages:
 
   def __init__(self, allocator: PageAllocator, windows, max_step_tokens: int,
                max_slots: int, table_pages: int):
-    assert max_step_tokens > 0 and any(windows) and not all(windows), windows
+    # a stack with no window layer still keeps a table an owning layer
+    assert max_step_tokens > 0 and windows and not all(windows), windows
     self.alloc = allocator
     self.windows = tuple(int(w) for w in windows)
     page = allocator.page_size
@@ -455,7 +456,7 @@ class KindPages:
     out["window_pages_released"] = self.pages_released
     out["window_pages_allocated"] = self.pages_allocated
     out["window_cap_pages"] = max(
-        cap for cap, w in zip(self.caps, self.windows) if w)
+        (cap for cap, w in zip(self.caps, self.windows) if w), default=0)
     return out
 
 

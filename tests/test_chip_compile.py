@@ -146,7 +146,8 @@ def tiny_lines(tiny_smoke):
   return [json.loads(line) for line in out.strip().splitlines()]
 
 
-@pytest.mark.parametrize("phase", ["device", "kernels", "train", "serve"])
+@pytest.mark.parametrize("phase", ["device", "kernels", "train", "serve",
+                                   "hybrid"])
 def test_tiny_smoke_phase(phase, tiny_lines):
   rows = [row for row in tiny_lines[:-1] if row["phase"] == phase]
   assert len(rows) == 1 and rows[0]["ok"], rows
@@ -163,7 +164,7 @@ def test_tiny_smoke_phase(phase, tiny_lines):
 
 def test_tiny_smoke_last_line(tiny_lines):
   assert [row["phase"] for row in tiny_lines[:-1]] == [
-      "device", "kernels", "train", "serve"]
+      "device", "kernels", "train", "serve", "hybrid"]
   last = tiny_lines[-1]
   assert set(last) == {"ok", "device"} and last["ok"] is True
   # the platform it really saw: a rehearsal never reads as a chip run

@@ -67,6 +67,8 @@ _FAMILIES = {
         "lm.smallthinker.SmallThinkerTiny", dtype),
     "phi4flash": lambda dtype: _Registered(
         "lm.phi4flash.Phi4MiniFlashTiny", dtype, depth=8),
+    "nemotron_h": lambda dtype: _Registered(
+        "lm.nemotron_h.Nemotron3NanoTiny", dtype),
 }
 
 
