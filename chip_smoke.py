@@ -403,6 +403,13 @@ def KernelCases(size: Size) -> list[KernelCase]:
           **_Lowering(pallas), **kw)
     return _Run
 
+  def _RaggedNoRows(pallas):
+    # a step that holds no row: the kernel's grid is its live (block, page)
+    # pairs, here none, and the output is the zeros it starts from
+    return lambda q, k, v, ks, vs, tables, row_of, q_end, *tree: (
+        _Ragged(pallas)(q, k, v, ks, vs, tables, row_of,
+                        jnp.zeros_like(q_end), *tree))
+
   def _RaggedGrouped(window):
     return lambda pallas: lambda q, k, v, tables, row_of, q_end, **kw: (
         ragged_block_attend.RaggedAttend(
@@ -509,6 +516,7 @@ def KernelCases(size: Size) -> list[KernelCase]:
       KernelCase("ragged_attend_plain", "ragged_plain", _Ragged),
       KernelCase("ragged_attend_tree", "ragged_tree", _Ragged),
       KernelCase("ragged_attend_int8", "ragged_int8", _Ragged),
+      KernelCase("ragged_attend_no_rows", "ragged_plain", _RaggedNoRows),
       KernelCase("ragged_attend_grouped", "ragged_grouped", _RaggedGrouped(0)),
       KernelCase("ragged_attend_grouped_window", "ragged_grouped",
                  _RaggedGrouped(s.grouped_window)),

@@ -37,6 +37,11 @@ ENGINE_COUNTER_KEYS = (
     # bound (ops/ragged_block_attend.BlockRungs): `attend_block_rows` sums
     # them.
     "attend_query_blocks", "attend_block_queries", "attend_block_rows",
+    # the (block, page) pairs the attend kernels' grids ran, summed over the
+    # step's plans (one a PlanKey), and the pairs their lists have room for,
+    # which is the grid every call ran before PR 46: their ratio is the share
+    # of that grid that held work. Both 0 where the twins run.
+    "attend_live_pairs", "attend_grid_pairs",
     # the loop's pipeline: steps dispatched while the step before was still
     # undelivered (`steps` less the pipeline's fills), and rows computed
     # for a sequence that had ended by the time their tokens arrived

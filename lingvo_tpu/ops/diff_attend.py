@@ -30,10 +30,10 @@ pair's two outputs is taken after it. Both lowerings run it so:
   are then the plain product `[rows, 2H] x [2H, P]` and its output `[rows,
   P] x [2H, P]^T`, with no strided load and nothing re-laid out. Block
   descriptors (one `rba.AttendPlan` a step for the layers of one window,
-  handed in as `plan`), the page walk (dead pages clamp, a window's first
-  page), the block rungs and the `jit` round the call are
-  ops/ragged_block_attend's grouped kernel's; the kernel's name in a trace
-  is `diff_attend`.
+  handed in as `plan`), the grid (one program a live (block, page) pair of
+  the plan's list, as many as the step holds), the block rungs and the `jit`
+  round the call are ops/ragged_block_attend's grouped kernel's; the
+  kernel's name in a trace is `diff_attend`.
 
 What the padded form costs beside a kernel that kept H-wide queries and
 subtracted inside: the zero half of every score product, and twice the
@@ -85,20 +85,17 @@ def _XlaDiffAttend(q2, k_pool, v_pool, block_tables, row_of, q_end,
       page_size=page_size, window=window, lowering="xla")
 
 
-def _TransposedPagesKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
-                           first_ref, *rest, page_size: int, t_pages: int,
-                           window: int, heads: int, rungs: tuple[int, ...]):
-  """The (query block, logical page) program of rba._GroupedAttendKernel over
-  pages that lie transposed, `[heads * h, P]`: head g's keys are the rows
-  `[g * h, (g + 1) * h)`, whole. q and the output `[T' + Bq, heads * h]`
-  f32 in HBM, a head's queries a lane slice; a block runs the first of
-  `rungs` that holds its valid queries."""
-  i = pl.program_id(0)
-  j = pl.program_id(1)
-  page = j
-  if window:
-    page = rest[0][i] + j
-    rest = rest[1:]
+def _TransposedPagesKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
+                           tables_ref, n_ref, first_ref, *rest,
+                           page_size: int, window: int, heads: int,
+                           rungs: tuple[int, ...]):
+  """The live (query block, logical page) pair's program of
+  rba._GroupedAttendKernel over pages that lie transposed, `[heads * h, P]`:
+  head g's keys are the rows `[g * h, (g + 1) * h)`, whole. q and the output
+  `[T' + Bq, heads * h]` f32 in HBM, a head's queries a lane slice; a block
+  runs the first of `rungs` that holds its valid queries."""
+  pair = pl.program_id(0)
+  i, page = blk_ref[pair], page_ref[pair]
   q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
   h = qb.shape[1] // heads
   nv = n_ref[i]
@@ -113,7 +110,7 @@ def _TransposedPagesKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
     held = pl.ds(0, rows)
     window_q = pl.ds(first, rows)
 
-    @pl.when(j == 0)
+    @pl.when(page == page0_ref[i])
     def _Init():
       _Copy(q_hbm.at[window_q], qb.at[held])
       qh[held] = qb[held].astype(qh.dtype)
@@ -121,7 +118,6 @@ def _TransposedPagesKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
       lb[:, held] = jnp.zeros((heads, rows, LANES), lb.dtype)
       accb[held] = jnp.zeros((rows, heads * h), accb.dtype)
 
-    @pl.when(page <= last_ref[i])
     def _Accumulate():
       slot = page * page_size + jax.lax.broadcasted_iota(
           jnp.int32, (1, page_size), 1)                       # [1, P]
@@ -139,7 +135,9 @@ def _TransposedPagesKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
         lb[g, held] = jnp.broadcast_to(l, (rows, LANES))
         accb[held, lanes] = acc
 
-    @pl.when(j == t_pages - 1)
+    _Accumulate()
+
+    @pl.when(page == last_ref[i])
     def _Emit():
       for g in range(heads):
         lanes = pl.ds(g * h, h)
@@ -147,44 +145,39 @@ def _TransposedPagesKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
                                   qb.dtype)
       _Copy(qb.at[held], out_hbm.at[window_q])
 
+  # nested, not `&`: rba._GroupedAttendKernel says why
   below = 0
   for rows in rungs:
-    pl.when((nv > below) & (nv <= rows))(functools.partial(_Block, rows))
+    pl.when(nv > below)(functools.partial(
+        pl.when(nv <= rows), functools.partial(_Block, rows)))
     below = rows
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "page_size", "heads", "window", "grid", "rungs", "interpret"))
-def _TransposedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
-                    heads: int, window: int, grid: tuple[int, int],
+    "page_size", "heads", "window", "rungs", "interpret"))
+def _TransposedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
+                    page_size: int, heads: int, window: int,
                     rungs: tuple[int, ...], interpret: bool):
-  """_TransposedPagesKernel over its grid. q: [T' + Bq, heads * h] f32;
-  cols: [NB, Bq, 4]; pages [NP, heads * h, P]. A `jit` of its own and the
-  scope inside it, as rba._GroupedCall and for its reasons."""
+  """_TransposedPagesKernel over the plan's `pairs` live pairs. q:
+  [T' + Bq, heads * h] f32; cols: [NB, Bq, 4]; pages [NP, heads * h, P]. A
+  `jit` of its own and the scope inside it, as rba._GroupedCall and for its
+  reasons."""
   bq = cols.shape[1]
   h = q.shape[1] // heads
-
-  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *more):
-    page = more[-1][i] + j if window else j
-    return (tables_ref[row_ref[i], jnp.minimum(page, last_ref[i])], 0, 0)
-
-  def _ColsIdx(i, j, row_ref, last_ref, src_ref, *_):
-    return (src_ref[i], 0, 0)
-
+  page_idx, cols_idx = rba._PairIndexMaps(2)
   hbm = pl.BlockSpec(memory_space=pl.ANY)
   with observe.Scope("diff_attend"):
     return pl.pallas_call(
         functools.partial(_TransposedPagesKernel, page_size=page_size,
-                          t_pages=grid[1], window=window, heads=heads,
-                          rungs=rungs),
+                          window=window, heads=heads, rungs=rungs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=grid,
+            grid=(pairs,),
             in_specs=[
                 hbm,
-                pl.BlockSpec((1, bq, 4), _ColsIdx),
-                pl.BlockSpec((1, heads * h, page_size), _PageIdx),
-                pl.BlockSpec((1, heads * h, page_size), _PageIdx),
+                pl.BlockSpec((1, bq, 4), cols_idx),
+                pl.BlockSpec((1, heads * h, page_size), page_idx),
+                pl.BlockSpec((1, heads * h, page_size), page_idx),
                 hbm,
             ],
             out_specs=hbm,
@@ -199,7 +192,7 @@ def _TransposedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         input_output_aliases={len(prefetch) + 4: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, q, cols, k_pages, v_pages, jnp.zeros(q.shape, jnp.float32))
 
@@ -364,15 +357,11 @@ def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, blocks: rba.AttendPlan,
   heads = nk // 2
   group = n // heads
   lanes = rba.GroupLanes(group)
-  b, t_pages = block_tables.shape
+  b = block_tables.shape[0]
   nb, bq, _ = blocks.cols.shape
   assert nb == rba.NumQueryBlocks(b, t * lanes, bq), (
       "descriptors of another pack", blocks.cols.shape, (b, t, lanes))
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-  prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
-              blocks.first]
-  if window:
-    prefetch.append(blocks.page0)
   with observe.Scope("diff_layout"):
     # the group beside the tokens, padded to whole sublane tiles
     # (RaggedAttend)
@@ -383,8 +372,8 @@ def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, blocks: rba.AttendPlan,
                 AsItLies(v_pool))
   # the call stays outside: its kernel is `diff_attend`
   out = _TransposedCall(
-      tuple(prefetch), *operands, page_size=page_size, heads=heads,
-      window=window, grid=(nb, rba.WindowPages(window, bq, page_size, t_pages)),
+      blocks.pairs, rba._Prefetch(blocks, tables), *operands,
+      page_size=page_size, heads=heads, window=window,
       rungs=rba.BlockRungs(bq, lanes), interpret=interpret)
   with observe.Scope("diff_layout"):
     out = out[:t * lanes].reshape(t, lanes, heads, h2)[:, :group]

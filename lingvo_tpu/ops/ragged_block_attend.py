@@ -33,17 +33,21 @@ Two lowerings of the same arithmetic (bf16 or int8 pages, f32 scores, f32
 online softmax and accumulator), held to each other within rounding:
 
 - `_PallasRaggedAttend` — the kernel's unit of work is (a block of up to
-  `Bq` consecutive queries of ONE row, one logical page): grid
-  `(NB, t_pages)`, `NB = B + T // Bq` the static bound on blocks. A page
-  comes into VMEM once per query block, not once per token, and padding
-  costs the blocks past the live ones (no DMA, no compute), not sixteen
-  programs a token.
+  `Bq` consecutive queries of ONE row, one logical page), and its grid is
+  the list of such pairs the step HOLDS: one axis of `pairs` programs, a
+  traced length (the plan's count of live pairs), blocks in packed order and
+  a block's pages ascending. A page comes into VMEM once per query block,
+  not once per token; a block the step does not hold, a page past a block's
+  widest horizon or behind its window is no program at all, and a step
+  without rows runs none.
   - Block descriptors (`_BuildQueryBlocks`: row, first packed token, valid
-    queries, last live page of the widest horizon, per-query mask columns)
-    are a few integer ops on `row_of`/`q_end`, computed in the jitted step
-    and shipped from nowhere; they ride scalar prefetch, so the page index
-    map resolves `block_tables[row[i], j]` before the DMA is issued. They
-    depend on the step's rows, on shapes and on the window, not on the
+    queries, first and last live page, per-query mask columns) and the list
+    of pairs (`_LivePairs`: a pair's block and page, `NB * grid_pages`
+    entries of room, `NB = B + T // Bq` the static bound on blocks) are a
+    few integer ops on `row_of`/`q_end`, computed in the jitted step and
+    shipped from nowhere; they ride scalar prefetch, so the page index map
+    resolves `block_tables[row[blk[k]], page[k]]` before the DMA is issued.
+    They depend on the step's rows, on shapes and on the window, not on the
     layer: a stack builds them once a step for every `PlanKey` its layers
     declare (`BuildAttendPlan`, core/attention.BuildRaggedPlan) and hands
     them to each call as `plan`; a call without one builds its own.
@@ -55,9 +59,8 @@ online softmax and accumulator), held to each other within rounding:
     and what is left over padding is the zeros padding must read.
   - Masks are per query: the causal horizon and the tree ancestor bits are
     `[Bq, 1]` columns against the `[1, P]` slot iota.
-  - Dead pages clamp to the block's last live page and blocks past the
-    live ones to the last live block (DMA elided, `pl.when` skips
-    compute): a stale table entry never reaches VMEM.
+  - Only a live pair is ever named, so a stale table entry never reaches
+    VMEM: by construction, not by a clamp.
   - `Bq` is `QueryBlock(shapes, dtypes)`: one page of queries, halved
     while the working set passes the scoped-VMEM budget. The arithmetic
     adapts per block to what the kernel sees: a one-query block (a decode
@@ -332,23 +335,55 @@ def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
 
 
 class AttendPlan(NamedTuple):
-  """Descriptors of the step's query blocks (all int32; NB static).
+  """Descriptors of the step's query blocks (all int32; NB static) and the
+  list of the (block, page) pairs its kernels' grid runs.
 
   Block i holds up to Bq consecutive queries of ONE row, starting at
-  packed token `first[i]`. Entries past the live blocks repeat the last
-  live block with `n == 0`, so their programs ask for blocks already in
-  VMEM and compute nothing."""
+  packed token `first[i]`; its pages are `page0[i] .. last[i]`. Entries
+  past the live blocks repeat the last live block with `n == 0`: no pair
+  names them. Pair k is block `blk[k]` at logical page `page[k]`, blocks in
+  packed order and a block's pages ascending; `pairs` of them are live, and
+  the entries past those repeat the last live pair."""
   row: jnp.ndarray    # [NB] block-table row
   last: jnp.ndarray   # [NB] last live logical page (of the widest horizon)
   page0: jnp.ndarray  # [NB] first logical page a query's window reaches
   #                     (0 without a window)
   n: jnp.ndarray      # [NB] valid queries; 0 = no such block this step
   first: jnp.ndarray  # [NB] packed index of the block's first query
-  src: jnp.ndarray    # [NB] the `cols` block its programs map
   cols: jnp.ndarray   # [NB, Bq, 4] per query: q_end (0 = not of this
   #                     block), q_start, anc_lo, anc_hi
   col0: tuple         # cols[:, 0]'s four columns, [NB] each: what a
   #                     one-query block's program reads as scalars
+  blk: jnp.ndarray    # [NB * grid_pages] a pair's block
+  page: jnp.ndarray   # [NB * grid_pages] a pair's logical page
+  pairs: jnp.ndarray  # [] the live pairs: the grid's length
+
+
+def _LivePairs(n, page0, last, size: int):
+  """(blk, page, pairs) of AttendPlan from its blocks' `n`, `page0`, `last`.
+
+  Block i's pairs are the `last[i] - page0[i] + 1` entries that follow those
+  of the blocks before it. So pair k belongs to the last live block with at
+  most k pairs before it, and its page is `k + page0[i] - (pairs before i)`
+  for that block i. Both are sums over the blocks that have started by k (of
+  ones, and of each block's step over the block before it in what it adds to
+  k), not lookups by `blk`: the chip runs a lookup an index at a time, and a
+  `[NB, size]` compare with two sums is what `first` already costs. size:
+  `NB * grid_pages`, which holds any step's pairs whatever pages its rows
+  share."""
+  live = n > 0
+  count = jnp.where(live, last - page0 + 1, 0)
+  upto = jnp.cumsum(count)
+  pairs = upto[-1]
+  before = upto - count
+  k = jnp.minimum(jnp.arange(size, dtype=jnp.int32), pairs - 1)
+  started = live[:, None] & (before[:, None] <= k[None, :])     # [NB, size]
+  offset = page0 - before
+  step = offset - jnp.concatenate([jnp.zeros((1,), jnp.int32), offset[:-1]])
+  blk = jnp.sum(started.astype(jnp.int32), axis=0) - 1
+  page = k + jnp.sum(jnp.where(started, step[:, None], 0), axis=0)
+  # a step with no live block: nothing runs, and the entries name block 0
+  return jnp.maximum(blk, 0), jnp.maximum(page, 0), pairs
 
 
 def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
@@ -404,9 +439,42 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                   axis=1)
     page0 = jnp.minimum(jnp.maximum(low - window, 0) // page_size, last)
   col0 = cols[:, 0]                                         # [NB, 4]
+  blk, page, pairs = _LivePairs(
+      n, page0, last, nb * WindowPages(window, bq, page_size, t_pages))
   return AttendPlan(row=row_of[first], last=last, page0=page0, n=n,
-                    first=first, src=src, cols=cols,
-                    col0=tuple(col0[:, c] for c in range(4)))
+                    first=first, cols=cols,
+                    col0=tuple(col0[:, c] for c in range(4)),
+                    blk=blk, page=page, pairs=pairs)
+
+
+def GridPairs(key: PlanKey, b: int, t: int, t_pages: int) -> int:
+  """The pairs a plan's list has room for, `NB * grid_pages`: every block
+  any pack of `t` tokens over `b` rows could hold, times every page a
+  block's queries could reach. The grid a call ran before it ran the live
+  pairs alone."""
+  return NumQueryBlocks(b, t * key.lanes, key.bq) * WindowPages(
+      key.window, key.bq, key.page_size, t_pages)
+
+
+def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
+  """`AttendPlan.pairs` of a step from the host's own view of its rows
+  (numpy; `_LivePairs`' twin, as `BlockRows` is `BlockRungs`'): row r brings
+  `row_len[r]` tokens at positions `row_q_pos[r] ...`, each `key.lanes`
+  queries of its own horizon, cut into blocks of `key.bq`."""
+  start = np.asarray(row_q_pos, np.int64)[:, None]
+  queries = np.asarray(row_len, np.int64)[:, None] * key.lanes
+  lo = np.arange(-(-int(queries.max(initial=0)) // key.bq))[None] * key.bq
+  live = lo < queries                                       # [B, blocks]
+  hi = np.minimum(lo + key.bq, queries) - 1                 # its last query
+  # a query's horizon is its token's slot + 1
+  widest = start + hi // key.lanes + 1
+  last = np.clip(-(-widest // key.page_size) - 1, 0, t_pages - 1)
+  page0 = 0
+  if key.window:
+    narrowest = start + lo // key.lanes + 1
+    page0 = np.minimum(
+        np.maximum(narrowest - key.window, 0) // key.page_size, last)
+  return int(np.sum(np.where(live, last - page0 + 1, 0)))
 
 
 def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
@@ -454,16 +522,16 @@ def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
   return m_new, l_new, acc * alpha + pv
 
 
-def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
-                        first_ref, end0_ref, start0_ref, lo0_ref, hi0_ref,
-                        *rest, page_size: int, t_pages: int, window: int):
-  """One (query block, logical page) program; scratch carried over pages.
+def _RaggedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
+                        tables_ref, n_ref, first_ref, end0_ref, start0_ref,
+                        lo0_ref, hi0_ref, *rest, page_size: int, window: int):
+  """One live (query block, logical page) pair of the plan's list; scratch
+  carried over a block's pages.
 
-  With a window (static) an eleventh prefetched array, the block's first
-  page, leads `rest`: program j of a block runs logical page page0 + j,
-  `t_pages` is then the most pages a block's queries can reach, and a query
-  sees slots [q_end - window, q_end) only. Without one the program is the
-  windowless one, operand for operand.
+  Program k runs block `blk_ref[k]` at logical page `page_ref[k]`: the
+  block's first page (`page0`) brings its queries in, its last one takes the
+  output out, and every program accumulates. With a window (static) a query
+  sees slots [q_end - window, q_end) only.
 
   q_hbm/out_hbm: [T + Bq, N, H], left in HBM: a block copies its own
   window in at its first page and out at its last, at its row's packed
@@ -481,12 +549,8 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
 
   Float and int8 pools share the body (int8 threads two scale blocks,
   dequantized via the shared `_DequantPages`)."""
-  i = pl.program_id(0)
-  j = pl.program_id(1)
-  page = j
-  if window:
-    page = rest[0][i] + j
-    rest = rest[1:]
+  pair = pl.program_id(0)
+  i, page = blk_ref[pair], page_ref[pair]
   q_hbm, cols_ref, k_ref, v_ref, *rest = rest
   if len(rest) == 13:
     ks_ref, vs_ref = rest[:2]
@@ -511,14 +575,13 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
     finished block in q_scr's layout (which doubles as the way out)."""
     window = pl.ds(first, q_scr.shape[0])
 
-    @pl.when(j == 0)
+    @pl.when(page == page0_ref[i])
     def _Init():
       _Copy(q_hbm.at[window], q_scr)
       m_scr[...] = jnp.full_like(m_scr, NEG_INF)
       l_scr[...] = jnp.zeros_like(l_scr)
       acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(page <= last_ref[i])
     def _Accumulate():
       k_page, v_page = k_ref[0], v_ref[0]
       if ks_ref is not None:
@@ -530,7 +593,9 @@ def _RaggedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
       l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
       acc_scr[...] = acc
 
-    @pl.when(j == t_pages - 1)
+    _Accumulate()
+
+    @pl.when(page == last_ref[i])
     def _Emit():
       # a query that is not this block's (q_end 0 in cols) comes out an
       # exact zero: the rows after the block's own are the next block's to
@@ -601,9 +666,9 @@ def _HeadPages(ref, heads: int):
   return out
 
 
-def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
-                         first_ref, end0_ref, start0_ref, lo0_ref, hi0_ref,
-                         *rest, page_size: int, t_pages: int, window: int,
+def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
+                         tables_ref, n_ref, first_ref, end0_ref, start0_ref,
+                         lo0_ref, hi0_ref, *rest, page_size: int, window: int,
                          heads: int, rungs: tuple[int, ...]):
   """The (query block, logical page) program where a KV head serves a GROUP
   of query heads: the group rides the packed axis (RaggedAttend), so a
@@ -630,12 +695,8 @@ def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
   rows (a 16-bit tile is 16: half the rows would start mid-tile), and the
   block's queries are cast once, at its first page. Window and masks as in
   _RaggedAttendKernel."""
-  i = pl.program_id(0)
-  j = pl.program_id(1)
-  page = j
-  if window:
-    page = rest[0][i] + j
-    rest = rest[1:]
+  pair = pl.program_id(0)
+  i, page = blk_ref[pair], page_ref[pair]
   q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
   h = qb.shape[1] // heads
   nv = n_ref[i]
@@ -654,7 +715,7 @@ def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
     held = pl.ds(0, rows)
     window_q = pl.ds(first, rows)
 
-    @pl.when(j == 0)
+    @pl.when(page == page0_ref[i])
     def _Init():
       _Copy(q_hbm.at[window_q], qb.at[held])
       qh[held] = qb[held].astype(qh.dtype)
@@ -662,7 +723,6 @@ def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
       lb[:, held] = jnp.zeros((heads, rows, LANES), lb.dtype)
       accb[held] = jnp.zeros((rows, heads * h), accb.dtype)
 
-    @pl.when(page <= last_ref[i])
     def _Accumulate():
       slot = page * page_size + jax.lax.broadcasted_iota(
           jnp.int32, (1, page_size), 1)                       # [1, P]
@@ -682,7 +742,9 @@ def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
         lb[g, held] = jnp.broadcast_to(l, (rows, LANES))
         accb[held, lanes] = acc
 
-    @pl.when(j == t_pages - 1)
+    _Accumulate()
+
+    @pl.when(page == last_ref[i])
     def _Emit():
       # a query of the rung's rows that is not this block's comes out an
       # exact zero, as in _RaggedAttendKernel
@@ -692,21 +754,52 @@ def _GroupedAttendKernel(row_ref, last_ref, src_ref, tables_ref, n_ref,
                                   qb.dtype)
       _Copy(qb.at[held], out_hbm.at[window_q])
 
+  # A rung's two bounds are two nested branches, not one `&`: what the block
+  # does at every page (its accumulate) then stands two branches deep, as it
+  # did under the dead-page guard, and the benchmark's host traces it there
+  # in a third of the time it takes one branch deep (0.36-0.43 s a call of
+  # this kernel against 1.1-1.3, 1.2 s of `setup_s`; PERF.md section 6,
+  # PR 46). The program is the same.
   below = 0
   for rows in rungs:
-    pl.when((nv > below) & (nv <= rows))(functools.partial(_Block, rows))
+    pl.when(nv > below)(functools.partial(
+        pl.when(nv <= rows), functools.partial(_Block, rows)))
     below = rows
 
 
+def _PairIndexMaps(minor: int):
+  """(a page's index map, the `cols` one) of a grid over the plan's pairs.
+  Program k names its own live page, `tables[row[blk[k]], page[k]]`, and
+  nothing else: a stale table entry past a block's widest horizon, or behind
+  its window, never reaches VMEM (the page-reuse-after-eviction guarantee).
+  minor: the axes of a page's block after the first."""
+  zeros = (0,) * minor
+
+  def _PageIdx(k, blk_ref, page_ref, row_ref, last_ref, page0_ref, tables_ref,
+               *_):
+    return (tables_ref[row_ref[blk_ref[k]], page_ref[k]],) + zeros
+
+  def _ColsIdx(k, blk_ref, *_):
+    return (blk_ref[k], 0, 0)
+
+  return _PageIdx, _ColsIdx
+
+
+def _Prefetch(blocks: AttendPlan, tables) -> tuple:
+  """What rides scalar prefetch into the attend kernels, in their order."""
+  return (blocks.blk, blocks.page, blocks.row, blocks.last, blocks.page0,
+          tables, blocks.n, blocks.first)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "page_size", "heads", "window", "grid", "rungs", "interpret"))
-def _GroupedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
-                 heads: int, window: int, grid: tuple[int, int],
+    "page_size", "heads", "window", "rungs", "interpret"))
+def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
+                 page_size: int, heads: int, window: int,
                  rungs: tuple[int, ...], interpret: bool):
   """_GroupedAttendKernel over _PallasRaggedAttend's grid and descriptors.
-  q: [T + Bq, Nkv * H] f32; cols: [NB, Bq, 4]; pages as rows
-  [NP, P * Nkv, H] -> the output, [T + Bq, Nkv * H] f32, zeros where no
-  block wrote.
+  pairs: [] the grid's length; q: [T + Bq, Nkv * H] f32; cols: [NB, Bq, 4];
+  pages as rows [NP, P * Nkv, H] -> the output, [T + Bq, Nkv * H] f32, zeros
+  where no block wrote.
 
   A `jit` of its own: a kernel's body is traced anew at every
   `pallas_call`, a rung of this one costs 0.4-0.6 s on the benchmark's host,
@@ -716,33 +809,23 @@ def _GroupedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
   descriptors inside too, XLA no longer shared them between the layers of a
   step (0.66 ms a call; PERF.md section 6, PR 36). XLA names a kernel after
   the innermost scope round its call, which `jit` would make this function's
-  name: the scope here keeps the name the callers' scope gives it. The page
-  index map is _PallasRaggedAttend's, dead-page clamp and all, less the
-  pages' fourth axis (a function the jit can key on cannot be passed in)."""
+  name: the scope here keeps the name the callers' scope gives it."""
   bq = cols.shape[1]
   h = q.shape[1] // heads
-
-  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *more):
-    page = more[-1][i] + j if window else j
-    return (tables_ref[row_ref[i], jnp.minimum(page, last_ref[i])], 0, 0)
-
-  def _ColsIdx(i, j, row_ref, last_ref, src_ref, *_):
-    return (src_ref[i], 0, 0)
-
+  page_idx, cols_idx = _PairIndexMaps(2)
   hbm = pl.BlockSpec(memory_space=pl.ANY)
   with observe.Scope("ragged_attend"):
     return pl.pallas_call(
         functools.partial(_GroupedAttendKernel, page_size=page_size,
-                          t_pages=grid[1], window=window, heads=heads,
-                          rungs=rungs),
+                          window=window, heads=heads, rungs=rungs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=grid,
+            grid=(pairs,),
             in_specs=[
                 hbm,
-                pl.BlockSpec((1, bq, 4), _ColsIdx),
-                pl.BlockSpec((1, page_size * heads, h), _PageIdx),
-                pl.BlockSpec((1, page_size * heads, h), _PageIdx),
+                pl.BlockSpec((1, bq, 4), cols_idx),
+                pl.BlockSpec((1, page_size * heads, h), page_idx),
+                pl.BlockSpec((1, page_size * heads, h), page_idx),
                 hbm,
             ],
             out_specs=hbm,
@@ -757,7 +840,7 @@ def _GroupedCall(prefetch, q, cols, k_pages, v_pages, *, page_size: int,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         input_output_aliases={len(prefetch) + 4: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, q, cols, k_pages, v_pages, jnp.zeros(q.shape, jnp.float32))
 
@@ -775,63 +858,43 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
   pages float: the same grid, descriptors and page index map run
   _GroupedAttendKernel over pages seen as rows.
 
-  Grid `(NB, t_pages)`, both axes in order: block i + 1 starts where block
-  i's queries end, so its window overwrites the zeros block i left past
-  its own. With a window the grid's second axis is the pages a block's
-  queries can reach, counted from the block's first one (`WindowPages`),
-  not the row's whole table."""
+  Grid `(blocks.pairs,)`, the step's live (block, page) pairs in order (a
+  traced length: a step runs the programs it has work for, and none when it
+  holds no block): block i + 1 starts where block i's queries end, so its
+  window overwrites the zeros block i left past its own."""
   t, n, h = q.shape
   np_total, page, _, _ = k_pool.shape
   assert page == page_size, (page, page_size)
-  b, t_pages = block_tables.shape
+  b = block_tables.shape[0]
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
   nb, bq, _ = blocks.cols.shape
   assert nb == NumQueryBlocks(b, t, bq), (
       "descriptors of another pack", blocks.cols.shape, (b, t))
-  grid_pages = WindowPages(window, bq, page_size, t_pages)
-  prefetch = [blocks.row, blocks.last, blocks.src, tables, blocks.n,
-              blocks.first, *blocks.col0]
-  if window:
-    prefetch.append(blocks.page0)
-
-  # A dead logical page clamps to the BLOCK's last live page and a block
-  # past the live ones to the last live block: Pallas asks for the block it
-  # already holds and elides the DMA, pl.when skips compute. A stale table
-  # entry past a block's widest horizon never reaches VMEM, which is the
-  # page-reuse-after-eviction guarantee.
-  def _PageIdx(i, j, row_ref, last_ref, src_ref, tables_ref, *more):
-    page = more[-1][i] + j if window else j
-    return (tables_ref[row_ref[i], jnp.minimum(page, last_ref[i])], 0, 0, 0)
-
-  def _ScaleIdx(i, j, *refs):
-    return _PageIdx(i, j, *refs)[:3]
-
-  def _ColsIdx(i, j, row_ref, last_ref, src_ref, *_):
-    return (src_ref[i], 0, 0)
-
-  hbm = pl.BlockSpec(memory_space=pl.ANY)
+  prefetch = _Prefetch(blocks, tables) + blocks.col0
   if grouped:
     out = _GroupedCall(
-        tuple(prefetch),
+        blocks.pairs, prefetch,
         jnp.pad(q.reshape(t, n * h).astype(jnp.float32), ((0, bq), (0, 0))),
         blocks.cols, k_pool.reshape(np_total, page * n, h),
         v_pool.reshape(np_total, page * n, h), page_size=page_size, heads=n,
-        window=window, grid=(nb, grid_pages), rungs=BlockRungs(bq, grouped),
-        interpret=interpret)
+        window=window, rungs=BlockRungs(bq, grouped), interpret=interpret)
     return out[:t].astype(q.dtype).reshape(t, n, h)
+  page_idx, cols_idx = _PairIndexMaps(3)
+  hbm = pl.BlockSpec(memory_space=pl.ANY)
   in_specs = [
       hbm,
-      pl.BlockSpec((1, bq, 4), _ColsIdx),
-      pl.BlockSpec((1, page_size, n, h), _PageIdx),
-      pl.BlockSpec((1, page_size, n, h), _PageIdx),
+      pl.BlockSpec((1, bq, 4), cols_idx),
+      pl.BlockSpec((1, page_size, n, h), page_idx),
+      pl.BlockSpec((1, page_size, n, h), page_idx),
   ]
   # Bq rows of slack: the last block's window may run past T
   slack = ((0, bq), (0, 0), (0, 0))
-  operands = prefetch + [jnp.pad(q, slack), blocks.cols, k_pool, v_pool]
+  operands = [*prefetch, jnp.pad(q, slack), blocks.cols, k_pool, v_pool]
   if k_scale is not None:
+    scale_idx = lambda k, *refs: page_idx(k, *refs)[:3]
     in_specs += [
-        pl.BlockSpec((1, n, page_size), _ScaleIdx),
-        pl.BlockSpec((1, n, page_size), _ScaleIdx),
+        pl.BlockSpec((1, n, page_size), scale_idx),
+        pl.BlockSpec((1, n, page_size), scale_idx),
     ]
     operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
   # the output starts as zeros and is written in place: a padding token is
@@ -841,7 +904,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
 
   grid_spec = pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=len(prefetch),
-      grid=(nb, grid_pages),
+      grid=(blocks.pairs,),
       in_specs=in_specs,
       out_specs=hbm,
       scratch_shapes=[
@@ -857,14 +920,14 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
       ],
   )
   kernel = functools.partial(_RaggedAttendKernel, page_size=page_size,
-                             t_pages=grid_pages, window=window)
+                             window=window)
   out = pl.pallas_call(
       kernel,
       grid_spec=grid_spec,
       out_shape=jax.ShapeDtypeStruct((t + bq, n, h), q.dtype),
       input_output_aliases={len(operands) - 1: 0},
       compiler_params=pltpu.CompilerParams(
-          dimension_semantics=("arbitrary", "arbitrary")),
+          dimension_semantics=("arbitrary",)),
       interpret=interpret,
   )(*operands)
   return out[:t]

@@ -93,6 +93,7 @@ from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.core import sampling
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as observe_trace
+from lingvo_tpu.ops import ragged_block_attend
 from lingvo_tpu.quant import kv as kv_quant
 from lingvo_tpu.quant import weights as quant_weights
 from lingvo_tpu.serving import kv_cache
@@ -517,6 +518,15 @@ class ServingLoop:
                    if k.kernel]
     self.attend_calls = len(kernel_keys)
     self.attend_plans = len(set(kernel_keys))
+    # the plans' keys, and the pairs their lists have room for together: the
+    # (block, page) grids the kernels ran before they ran the step's live
+    # pairs alone
+    self._attend_plan_keys = set(kernel_keys)
+    self._attend_grid_pairs = sum(
+        ragged_block_attend.GridPairs(key, max_batch, self._ragged_t,
+                                      table_pages)
+        for key in self._attend_plan_keys)
+    self._table_pages = table_pages
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
@@ -1393,6 +1403,12 @@ class ServingLoop:
           int(np.sum(row_len)) * self._attend_own)
       self._counters["attend_block_rows"].Inc(int(np.sum(
           whole * self._attend_bq + self._attend_rows(rest))))
+    if self._attend_plan_keys:
+      self._counters["attend_live_pairs"].Inc(sum(
+          ragged_block_attend.LivePairs(key, desc.row_q_pos, row_len,
+                                        self._table_pages)
+          for key in self._attend_plan_keys))
+      self._counters["attend_grid_pairs"].Inc(self._attend_grid_pairs)
     if self.paged_path == "dense":
       self._counters["dense_fallback_steps"].Inc()
     if self._kv_quantized:

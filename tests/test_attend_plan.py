@@ -192,7 +192,7 @@ def _LookupDescriptors(row_of, ends, starts, lo, hi, *, bq, nb, page_size,
   cols = np.stack([blk_ends, starts[tok], lo[tok], hi[tok]], axis=-1)
   return dict(row=row_of[first], last=last, page0=page0,
               n=np.where(k < n_live, member.sum(axis=1), 0), first=first,
-              src=src, cols=cols, col0=tuple(cols[:, 0].T))
+              cols=cols, col0=tuple(cols[:, 0].T))
 
 
 @pytest.mark.parametrize("pack", list(PACKS))
@@ -219,6 +219,131 @@ def test_a_slice_a_block_gives_the_descriptors_of_a_lookup_a_query(
           else getattr(got, name), np.stack(value) if name == "col0" else value)
   assert int(np.max(got.n)) == min(bq, 30 * lanes if pack == "chain"
                                    else 13 * lanes)
+
+
+# -- the list of live pairs ----------------------------------------------------
+
+
+def _PairPack(kind, seed):
+  """(tokens a slot, first position a slot, block tables) of a seeded random
+  pack of `kind` over 4 slots, T packed tokens and tables of T_PAGES pages."""
+  rng = np.random.RandomState(seed)
+  slots = T_PAGES * PAGE
+  b = 4
+  tables = rng.permutation(b * T_PAGES).reshape(b, T_PAGES).astype(np.int32)
+  decode = lambda: int(rng.randint(0, slots - 1))
+  if kind == "decode_only":
+    lens, q_pos = [1, 1, 0, 1], [decode(), decode(), 7, decode()]
+  elif kind == "chunk_and_decode":
+    n = int(rng.randint(2, 14))
+    lens, q_pos = [1, n, 1, 0], [decode(), int(rng.randint(0, slots - n)),
+                                  decode(), 3]
+  elif kind == "row_cut_into_blocks":
+    n = int(rng.randint(18, 33))      # over two blocks at either key below
+    lens, q_pos = [1, 0, n, 1], [decode(), 5, int(rng.randint(0, slots - n)),
+                                  decode()]
+  elif kind == "shared_prefix":
+    lens, q_pos = [1, 9, 1, 1], [40 + int(rng.randint(0, 50)), 33, 70, 64]
+    tables[1:, :2] = tables[0, :2]    # the rows' first two pages are one
+  elif kind == "full_pool":
+    # every page of every table is live: the list holds what the grid held
+    lens, q_pos = [12] * 4, [slots - 12] * 4
+  else:
+    assert kind == "empty", kind
+    lens, q_pos = [0] * 4, [1] * 4
+  return lens, q_pos, tables
+
+
+PAIR_KINDS = ["decode_only", "chunk_and_decode", "row_cut_into_blocks",
+              "shared_prefix", "full_pool", "empty"]
+
+
+def _PairRows(kind, seed):
+  lens, q_pos, tables = _PairPack(kind, seed)
+  rows = ragged_lib.BuildRaggedRows(np.array(lens), np.array(q_pos), T, WMAX)
+  return ragged_lib.RaggedRows(*(jnp.asarray(m) for m in rows)), tables
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("kind", PAIR_KINDS)
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("lanes,bq", [(1, 16), (8, 64)])
+def test_the_list_is_the_old_grids_live_programs_in_order(
+    lanes, bq, window, kind, seed):
+  """`blk`, `page`, `pairs` against the enumeration of the `(NB, grid_pages)`
+  grid's programs that held work (`n[i] > 0`, `page0[i] <= page <= last[i]`;
+  blocks in packed order, a block's pages ascending), and the host's count
+  (`LivePairs`) against the plan's."""
+  rows, tables = _PairRows(kind, seed)
+  b = tables.shape[0]
+  tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
+  key = rba.PlanKey(PAGE, window, bq, lanes, tree=False, kernel=True)
+  plan = rba.BuildAttendPlan(key, tok.row, tok.q_end, b=b, t_pages=T_PAGES)
+  nb = rba.NumQueryBlocks(b, T * lanes, bq)
+  grid_pages = rba.WindowPages(window, bq, PAGE, T_PAGES)
+  n, page0, last = (np.asarray(x) for x in (plan.n, plan.page0, plan.last))
+  want = [(i, page0[i] + j) for i in range(nb) for j in range(grid_pages)
+          if n[i] > 0 and page0[i] + j <= last[i]]
+  pairs = int(plan.pairs)
+  assert plan.blk.shape == plan.page.shape == (nb * grid_pages,)
+  assert rba.GridPairs(key, b, T, T_PAGES) == nb * grid_pages
+  assert pairs == len(want)
+  got = list(zip(np.asarray(plan.blk).tolist(), np.asarray(plan.page).tolist()))
+  assert got[:pairs] == want
+  # past the live pairs the list repeats the last one: a name the grid never
+  # reaches is still a live page's
+  assert set(got[pairs:]) <= {want[-1] if want else (0, 0)}
+  assert (pairs == 0) == (kind == "empty")
+  if kind == "full_pool" and not window:
+    assert pairs == int(np.sum(n > 0)) * T_PAGES
+  assert rba.LivePairs(key, rows.row_q_pos, rows.row_len, T_PAGES) == pairs
+
+
+@pytest.mark.parametrize("kind", PAIR_KINDS)
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("kernel", ["heads", "grouped", "diff"])
+def test_a_grid_over_the_live_pairs_gives_the_twins_output(kernel, window,
+                                                           kind):
+  """Each of the three kernels over the pack's list (a grid of `pairs`
+  programs, none for the empty step) against its XLA twin, with every pool
+  page no query may see poisoned: a page the tables name past a row's
+  horizon or behind its window, and every page no table names."""
+  rows, tables = _PairRows(kind, 3)
+  lens, q_pos = np.asarray(rows.row_len), np.asarray(rows.row_q_pos)
+  b = tables.shape[0]
+  tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
+  nq, nk, h = {"heads": (2, 2, 8), "grouped": (4, 2, 128),
+               "diff": (8, 4, 8)}[kernel]
+  rng = np.random.RandomState(4)
+  np_total = b * T_PAGES + 1
+  seen = np.zeros((np_total,), bool)
+  for r in range(b):
+    if lens[r]:
+      lo = max(q_pos[r] + 1 - window, 0) // PAGE if window else 0
+      seen[tables[r, lo:(q_pos[r] + lens[r] - 1) // PAGE + 1]] = True
+  pools = [np.where(seen[:, None, None, None],
+                    rng.randn(np_total, PAGE, nk, h), np.nan)
+           for _ in range(2)]
+  kp, vp = (jnp.asarray(x, jnp.float32) for x in pools)
+  q = jnp.asarray(rng.randn(T, nq, h) * h ** -0.5, jnp.float32)
+  args = (q, kp, vp, jnp.asarray(tables), tok.row, tok.q_end)
+  if kernel == "diff":
+    call = lambda lowering, **kw: diff_attend.DiffAttend(
+        *args, 0.3, page_size=PAGE, window=window, lowering=lowering, **kw)
+  else:
+    call = lambda lowering, **kw: rba.RaggedAttend(
+        *args, page_size=PAGE, window=window, lowering=lowering, **kw)
+  out = np.asarray(call("pallas", interpret=True))
+  assert np.all(np.isfinite(out))
+  _Same(out[np.asarray(tok.q_end) == 0], 0.0)
+  if kind == "empty":
+    _Same(out, 0.0)
+    return
+  # the twin masks what it gathers, and a masked NaN is still one: it reads
+  # the pools with the poison taken out
+  clean = [jnp.nan_to_num(x) for x in (kp, vp)]
+  args = (q, *clean, jnp.asarray(tables), tok.row, tok.q_end)
+  np.testing.assert_allclose(out, np.asarray(call("xla")), atol=2e-5)
 
 
 def test_a_plan_of_another_key_or_pack_is_refused():
@@ -248,12 +373,13 @@ def test_a_plan_of_another_key_or_pack_is_refused():
 # -- the step programs ---------------------------------------------------------
 
 
-def _Task(family):
-  name, depth = {
+def _Task(family, depth=None):
+  name, served = {
       "dense": ("lm.synthetic_packed_input.DenseLmTiny", 24),
       "smallthinker": ("lm.smallthinker.SmallThinkerTiny", 8),
       "phi4flash": ("lm.phi4flash.Phi4MiniFlashTiny", 32),
   }[family]
+  depth = depth or served
   mp = model_registry.GetParams(name, "Train")
   tp = mp.task
   tp.input = mp.input
@@ -278,8 +404,8 @@ DECLARED = {"dense": (24, 1, 1), "smallthinker": (8, 2, 4),
 
 def _StepArgs(task, theta):
   """An engine driven to a step that holds a decode row, a finishing prompt,
-  a mid-prompt chunk and an empty slot: (engine, that step's theta, states,
-  tok_ids, rows, tables)."""
+  a mid-prompt chunk and an empty slot: (engine, every step's theta, states,
+  tok_ids, rows, tables; that step's last)."""
   eng = engine_lib.ServingLoop(
       task, theta, page_size=PAGE, num_pages=48, max_batch=4,
       max_seq_len=128, prefill_token_budget=8)
@@ -299,7 +425,7 @@ def _StepArgs(task, theta):
   eng.Submit(list(range(1, 31)), 6, eos_id=None, seed=13)
   eng.StepOnce()
   assert np.asarray(seen[-1][3].row_len).tolist() == [1, 3, 8, 0]
-  return eng, seen[-1]
+  return eng, seen
 
 
 def _Census(jaxpr, in_scan=False, out=None):
@@ -323,7 +449,8 @@ def programs(request):
   kernels' step's census, the two programs' logits on one step's arguments)."""
   family = request.param
   task, theta = _Task(family)
-  twin_eng, args = _StepArgs(task, theta)
+  twin_eng, seen = _StepArgs(task, theta)
+  args = seen[-1]
 
   def _Step():
     # a function object a program: JAX keeps traces by function, and the
@@ -363,6 +490,34 @@ def test_stats_count_what_the_stack_declares(programs):
   assert (stats["attend_calls"], stats["attend_plans"]) == (calls, plans)
   # where the twins run no kernel is called and no descriptor built
   assert (twin_stats["attend_calls"], twin_stats["attend_plans"]) == (0, 0)
+
+
+@pytest.mark.parametrize("family,depth", [("dense", 2), ("smallthinker", 4)])
+def test_stats_count_the_pairs_the_steps_plans_hold(family, depth):
+  """`attend_live_pairs` is the sum of `AttendPlan.pairs` over the steps an
+  engine dispatched and the plans of each (the host counts from its own rows
+  what the device lists from the same), `attend_grid_pairs` the room of those
+  lists; both stay 0 where the twins run."""
+  task, theta = _Task(family, depth)
+  with pytest.MonkeyPatch.context() as mp:
+    mp.setattr(rba, "Lowering", lambda lowering: (
+        "pallas" if lowering == "auto" else lowering))
+    eng, seen = _StepArgs(task, theta)
+    keys = sorted(set(task.stack.RaggedPlanKeys(eng._states)))
+  stats = eng.Stats()
+  assert len(keys) == stats["attend_plans"] == DECLARED[family][1]
+  live = grid = 0
+  for _, _, _, rows, tables in seen:
+    plan = attention_lib.BuildRaggedPlan(keys, rows, *tables.shape[-2:])
+    live += sum(int(blocks.pairs) for blocks in plan.blocks.values())
+    grid += sum(blocks.blk.shape[0] for blocks in plan.blocks.values())
+  assert stats["steps"] == len(seen) == 2
+  assert (stats["attend_live_pairs"], stats["attend_grid_pairs"]) == (
+      live, grid)
+  assert 0 < live < grid
+  twin_stats = _StepArgs(task, theta)[0].Stats()
+  assert (twin_stats["attend_live_pairs"],
+          twin_stats["attend_grid_pairs"]) == (0, 0)
 
 
 def test_the_kernels_program_is_the_twins_within_rounding(programs):

@@ -49,9 +49,15 @@ _GROUPED_CASES = {"full": "ragged_attend_grouped",
                   "window": "ragged_attend_grouped_window"}
 
 
-def _GroupedServingArgs():
+# The longest lists of (block, page) pairs any cell's plan rides scalar
+# prefetch with: `nemotron3nano_serve_agent`'s one attention layer, 32 query
+# heads over 2 KV heads, 98 blocks x 128 pages = 12,544 entries twice.
+_AGENT_SERVING = dict(t=1088, n=32, n_kv=2, h=128, page=128, table_pages=128,
+                      pool_pages=2049, rows=64)
+
+
+def _GroupedServingArgs(d=_GROUPED_SERVING):
   import jax.numpy as jnp
-  d = _GROUPED_SERVING
   sds = jax.ShapeDtypeStruct
   pool = sds((d["pool_pages"], d["page"], d["n_kv"], d["h"]), jnp.bfloat16)
   tok = sds((d["t"],), jnp.int32)
@@ -114,6 +120,9 @@ def compiles():
           f"grouped_serving_{variant}": pool.submit(
               _Compile, _CASES[name], _GroupedServingArgs())
           for variant, name in _GROUPED_CASES.items()})
+      futures["grouped_serving_agent"] = pool.submit(
+          _Compile, _CASES["ragged_attend_grouped"],
+          _GroupedServingArgs(_AGENT_SERVING))
       yield futures
   finally:
     jax.config.update("jax_enable_compilation_cache", True)
@@ -132,7 +141,7 @@ def test_ragged_attend_compiles_at_serving_shapes(variant, compiles):
       timeout=300)
 
 
-@pytest.mark.parametrize("variant", sorted(_GROUPED_CASES))
+@pytest.mark.parametrize("variant", sorted(_GROUPED_CASES) + ["agent"])
 def test_grouped_attend_compiles_at_serving_shapes(variant, compiles):
   # every rung of the ladder is a branch of the one program Mosaic lowers
   assert "tpu_custom_call" in compiles[f"grouped_serving_{variant}"].result(
