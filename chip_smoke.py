@@ -127,6 +127,14 @@ class Size:
   scan_state: int
   scan_rows: int
   scan_chunk: int
+  # power retention on the packed axis: (query heads, KV heads) of `ret_h`,
+  # one-token rows and one chunk after them that crosses a page boundary
+  # (REAL: Brumby-14B's 40 over 8 of 128, pages of 128; a few rows only: the
+  # XLA twin gathers a state of 34 MB a token)
+  ret_heads: tuple[int, int]
+  ret_h: int
+  ret_rows: int
+  ret_chunk: int
 
 
 REAL = Size(
@@ -140,7 +148,8 @@ REAL = Size(
     grouped_heads=(28, 4), grouped_h=128, grouped_table_pages=48,
     grouped_window=4096,
     diff_heads=(40, 20), diff_h=64, diff_window=512,
-    scan_channels=5120, scan_state=16, scan_rows=64, scan_chunk=512)
+    scan_channels=5120, scan_state=16, scan_rows=64, scan_chunk=512,
+    ret_heads=(40, 8), ret_h=128, ret_rows=3, ret_chunk=140)
 
 TINY = Size(
     model=TINY_MODEL, train_layers=None, steps_per_loop=2, interpret=True,
@@ -153,7 +162,8 @@ TINY = Size(
     grouped_heads=(14, 2), grouped_h=128, grouped_table_pages=8,
     grouped_window=20,
     diff_heads=(8, 4), diff_h=8, diff_window=20,
-    scan_channels=128, scan_state=8, scan_rows=4, scan_chunk=14)
+    scan_channels=128, scan_state=8, scan_rows=4, scan_chunk=14,
+    ret_heads=(4, 2), ret_h=16, ret_rows=3, ret_chunk=14)
 
 
 # -- kernels -----------------------------------------------------------------
@@ -315,6 +325,37 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
           _Normal((e_,), f32), _Normal((len(sc_widths), n_, e_), f32),
           sc_rows)
 
+  # power retention: one-token rows deep in their open chunks (one of them a
+  # request's first token), then a chunk that starts inside a page, carries
+  # on from its slot's state and completes a page or two; slot states that
+  # are not zero, gates near one
+  r_n, r_kv = s.ret_heads
+  r_h = s.ret_h
+  r_widths = (1,) * s.ret_rows + (s.ret_chunk,)
+  r_t = -(-(sum(r_widths) + 3) // 8) * 8
+  r_q_pos = [0] + [page + 5 + 3 * r for r in range(s.ret_rows - 1)] + [
+      2 * page + page // 2]
+  r_rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in (
+      ragged_lib.BuildRaggedRows(np.asarray(r_widths), np.asarray(r_q_pos),
+                                 r_t, s.ret_chunk))))
+  r_slots = len(r_widths)
+  r_span = 3 + (s.ret_chunk + page // 2) // page
+  r_pages = r_slots * r_span + 1
+  r_pool = (r_pages, page, r_kv, r_h)
+  from lingvo_tpu.ops import power_retention as retention_op
+  r_d = retention_op.StoredDim(r_h)
+  retention = (
+      _Normal((r_t, r_n, r_h), f32, 1.0 / math.sqrt(r_h)),
+      _Normal((r_t, r_kv, r_h), f32), _Normal((r_t, r_kv, r_h)),
+      -0.01 * jax.nn.softplus(_Normal((r_t, r_kv), f32)),
+      _Normal((r_slots, r_kv, r_h, r_d), f32),
+      1.0 + jnp.abs(_Normal((r_slots, r_kv, r_d // r_h, r_h), f32)),
+      _Normal(r_pool), _Normal(r_pool),
+      jnp.cumsum(-0.01 * jax.nn.softplus(_Normal((r_pages, r_kv, page), f32)),
+                 axis=-1),
+      jnp.arange(r_slots * r_span, dtype=i32).reshape(r_slots, r_span),
+      r_rows)
+
   # SSD scan: a cotangent, log-decay <= 0, write keys, read keys, values
   lead = (s.b, s.t, s.ssd_heads)
   ssd = (_Normal(lead + (s.h,), f32),
@@ -333,6 +374,7 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
       "diff_attend": diff,
       "diff_write": diff_write,
       "selective_scan": scan,
+      "retention": retention,
       "flash_decode": (
           _Normal((s.b, 1, s.n, s.h), scale=q_scale),
           _Normal((s.b, s.cache_len, s.n, s.h)),
@@ -508,6 +550,19 @@ def KernelCases(size: Size) -> list[KernelCase]:
       return y + jnp.mean(s_fin)
     return lambda w, dl, b, c, v: _Weighted(_Y, (0, 1, 2, 3))(w, dl, b, c, v)
 
+  def _Retention(pallas):
+    from lingvo_tpu.core.nested_map import NestedMap
+    from lingvo_tpu.ops import power_retention
+
+    def _Run(q, k, v, log_g, state, norm, k_pool, v_pool, gates, tables, rows):
+      y, state, norm, pool = power_retention.PackedRetention(
+          q, k, v, log_g, state, norm,
+          NestedMap(key=k_pool, value=v_pool, gate=gates), tables, rows,
+          eps=1e-6, **_Lowering(pallas))
+      return y, state, norm, pool.key, pool.gate
+
+    return _Run
+
   return [
       KernelCase("flash_fwd", "flash", _FlashFwd),
       KernelCase("flash_fwd_bwd_segments", "flash", _FlashFwdBwdSeg),
@@ -530,6 +585,7 @@ def KernelCases(size: Size) -> list[KernelCase]:
       KernelCase("diff_attend_window_plan", "diff_attend", _DiffPlan),
       KernelCase("diff_write_pages_plan", "diff_write", _DiffWritePlan),
       KernelCase("selective_scan_packed", "selective_scan", _SelectiveScan),
+      KernelCase("power_retention_packed", "retention", _Retention),
       KernelCase("flash_decode", "flash_decode", _FlashDecode),
       KernelCase("fused_xent_fwd", "xent", _XentFwd),
       KernelCase("fused_xent_fwd_bwd", "xent", _XentFwdBwd),

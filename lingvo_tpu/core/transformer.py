@@ -883,6 +883,13 @@ class SharedStateLayer(base_layer.BaseLayer):
       return set()
     return {("fflayer", name) for name in self.fflayer.StackAddressed()}
 
+  def StackStates(self) -> tuple:
+    """Names of the slot-state leaves the mixer reads and writes IN the
+    block's stack, by the repeat's index: a scan that sliced them a trip and
+    stacked them again would copy them whole (a retention layer's are 0.6
+    GB a layer)."""
+    return tuple(getattr(self.mixer, "stack_states", ()))
+
   def _FeedForward(self, theta, x, paddings, repeat):
     """-> (x, tokens by expert or None)."""
     if self.p.tr_fflayer_tpl is None:
@@ -920,9 +927,12 @@ class SharedStateLayer(base_layer.BaseLayer):
       with observe.Scope("norm"):
         normed = self.ln.FProp(theta.ln, x)
       with observe.Scope("atten"):
+        # a mixer whose state is too large to slice a trip is handed the
+        # whole stack's and the repeat's index (StackStates)
+        extra = {"layer": repeat} if self.StackStates() else {}
         out, states, shared = self.atten.RaggedStep(
             theta.atten, normed, states, shared, rows, table=table,
-            depth=depth, plan=plan)
+            depth=depth, plan=plan, **extra)
         x = x + out
     if self._experts:
       # the step's padding tokens are routed nowhere
@@ -1160,6 +1170,11 @@ class BlockSequence(base_layer.BaseLayer):
     states = NestedMap(kv_pool=NestedMap(
         key=jnp.zeros((num_pages, page_size, nk, h), dtype),
         value=jnp.zeros((num_pages, page_size, nk, h), dtype)))
+    if any(getattr(a, "gated_pages", False) for a in owners):
+      # a retention layer's pages hold a cumulated log-gate a (KV head,
+      # token) beside K and V (core/retention.PowerRetention)
+      states.kv_pool.gate = jnp.zeros((num_pages, nk, page_size),
+                                      jnp.float32)
     states.blocks = []
     for b, layers in enumerate(self._bodies):
       def _One(theta_i, layers=layers):
@@ -1187,6 +1202,13 @@ class BlockSequence(base_layer.BaseLayer):
         keys, rows, *block_tables.shape[-2:],
         page_writes=any(a.writes_by_plan and key.kernel for a, key in zip(
             self._AttentionMixers(), keys)))
+    if plan is None:
+      # a stack of retention layers: what their step derives from the rows
+      planners = [m for m, _ in self._Mixers() if hasattr(m, "StepPlan")]
+      if planners:
+        plan = planners[0].StepPlan(
+            rows, *block_tables.shape[-2:],
+            page_size=cached_states.kv_pool.key.shape[1])
     x = inputs
     new_states = NestedMap(blocks=[])
     for b, layers in enumerate(self._bodies):
@@ -1200,21 +1222,46 @@ class BlockSequence(base_layer.BaseLayer):
         mine = block_tables[first:first + reps * len(own)].reshape(
             (reps, len(own)) + block_tables.shape[1:])
 
+      # the leaves a layer addresses in the stack ride the scan's carry whole
+      # (SharedStateLayer.StackStates); the others are its inputs and outputs
+      whole = [l.StackStates() for l in layers]
+      scanned = [NestedMap({k: v for k, v in st.items() if k not in names})
+                 for st, names in zip(cached_states.blocks[b], whole)]
+      if any(whole):
+        shared = shared.Copy()
+        shared.stack_states = [
+            NestedMap({k: st[k] for k in names})
+            for st, names in zip(cached_states.blocks[b], whole)]
+
       def _Call(layer, theta_j, x, shared, j, extra, depth, repeat,
-                tables_of=tables_of):
+                tables_of=tables_of, whole=whole):
         states_i, mine_i = extra
         place = tables_of[j]
         table = None
         if place is not None:
           table = (mine_i[place[2]] if place[0] == "own"
                    else block_tables[place[1]])
-        x, ns, shared = layer.RaggedStep(theta_j, x, states_i[j], shared,
+        states_j = states_i[j]
+        if whole[j]:
+          states_j = states_j.Copy()
+          states_j.update(shared.stack_states[j])
+        x, ns, shared = layer.RaggedStep(theta_j, x, states_j, shared,
                                          rows, table, depth, plan,
                                          repeat=repeat)
+        if whole[j]:
+          shared = shared.Copy()
+          shared.stack_states = list(shared.stack_states)
+          shared.stack_states[j] = NestedMap({k: ns[k] for k in whole[j]})
+          ns = NestedMap({k: v for k, v in ns.items() if k not in whole[j]})
         return x, ns, shared
 
       x, shared, outs = self._Scan(
-          b, theta, x, shared, (cached_states.blocks[b], mine), _Call)
+          b, theta, x, shared, (scanned, mine), _Call)
+      if any(whole):
+        for out, carried in zip(outs, shared.stack_states):
+          out.update(carried)
+        shared = NestedMap({k: v for k, v in shared.items()
+                            if k != "stack_states"})
       new_states.blocks.append(outs)
     new_states.kv_pool = shared.kv_pool
     return x, new_states

@@ -12,6 +12,7 @@ except ImportError:
   pass
 try:
   from lingvo_tpu.models.lm.params import nemotron_h  # noqa: F401
+  from lingvo_tpu.models.lm.params import brumby  # noqa: F401
   from lingvo_tpu.models.lm.params import phi4flash  # noqa: F401
   from lingvo_tpu.models.lm.params import smallthinker  # noqa: F401
 except ImportError:

@@ -20,7 +20,7 @@ from lingvo_tpu.core.nested_map import NestedMap
 
 
 # hybrid_override_pattern's letters
-PATTERN_KINDS = {"M": "mamba2", "E": "experts", "*": "gqa"}
+PATTERN_KINDS = {"M": "mamba2", "E": "experts", "*": "gqa", "R": "retention"}
 
 
 def KindBlocks(kinds) -> list[tuple[list, int]]:
@@ -89,7 +89,10 @@ class TransformerLm(base_model.BaseTask):
         "(atten_tpl, an attention.DifferentialAttention: within "
         "sliding_window_size, over everything, or over everything through "
         "the pages of the 'full' layer before it, with no K and V of its "
-        "own), 'gmu' (ssm.GatedMemoryUnit over that memory): each of "
+        "own), 'gmu' (ssm.GatedMemoryUnit over that memory), 'retention' "
+        "(mixer_tpl, a retention.PowerRetention: pages of its own for its "
+        "open chunk and a slot state, rotated by rope_theta inside the "
+        "mixer; atten_tpl may then be None): each of "
         "these a mixer and the dense feed-forward. A layer that is ONE "
         "branch alone: 'mamba2' (mixer_tpl, an ssm.Mamba2Layer, and no "
         "feed-forward), 'gqa' (atten_tpl, an attention.PooledAttention "
@@ -97,12 +100,14 @@ class TransformerLm(base_model.BaseTask):
         "'experts' (expert_ffn_tpl, a core/moe.DroplessMoELayer whose "
         "router reads its own normed input, and no mixer). Stretches "
         "that repeat are scanned, what lies between them is a block of "
-        "its own (transformer.BlockSequence); no layer carries a position. "
+        "its own (transformer.BlockSequence); no layer but a 'retention' "
+        "one carries a position. "
         "None = the layouts below.")
     p.Define(
         "hybrid_override_pattern", None,
         "layer_kinds as one letter a layer, for a stack of single-branch "
-        "layers: 'M' = 'mamba2', 'E' = 'experts', '*' = 'gqa'. The stack "
+        "layers: 'M' = 'mamba2', 'E' = 'experts', '*' = 'gqa'; and 'R' = "
+        "'retention', a mixer WITH the dense feed-forward. The stack "
         "is its first num_layers letters, so a cut of a published depth "
         "keeps the pattern's start. None = layer_kinds as given.")
     p.Define("use_rotary", True, "RoPE instead of absolute positions.")
@@ -238,13 +243,19 @@ class TransformerLm(base_model.BaseTask):
     from lingvo_tpu.core import ssm as ssm_lib
     p = self.p
     assert len(layer_kinds) == p.num_layers, (layer_kinds, p.num_layers)
-    assert p.mixer_tpl is not None and p.atten_tpl is not None
+    kinds = set(layer_kinds)
+    # the templates a kind reads: both, unless every layer is a retention one
+    assert p.mixer_tpl is not None
+    assert p.atten_tpl is not None or kinds <= {"retention"}, kinds
     assert p.num_experts == 0
     assert (p.expert_ffn_tpl is not None) == ("experts" in layer_kinds)
     assert not p.bidirectional
-    atten = p.atten_tpl.Copy().Set(num_heads=p.num_heads)
+    atten = (p.atten_tpl.Copy().Set(num_heads=p.num_heads)
+             if p.atten_tpl is not None else None)
     # a mixer with the dense feed-forward after it
     mixers = {
+        "retention": lambda: p.mixer_tpl.Copy().Set(
+            num_heads=p.num_heads, rope_theta=p.rope_theta),
         "mamba": lambda: p.mixer_tpl.Copy().Set(export_memory=False),
         "mamba_export": lambda: p.mixer_tpl.Copy().Set(export_memory=True),
         "window": lambda: atten.Copy().Set(window=p.sliding_window_size),

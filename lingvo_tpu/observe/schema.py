@@ -53,6 +53,11 @@ ENGINE_COUNTER_KEYS = (
     # got any token. All zero on a stack without expert layers.
     "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
     "moe_experts_active",
+    # power-retention layers (core/retention.PowerRetention), counted when a
+    # step is dispatched: live rows that read or write a slot state, pages
+    # folded into one (a layer: times the layers for the stack's), and the
+    # keys the step's tokens attended in open chunks (a layer, a query head)
+    "retention_rows", "retention_folds", "retention_chunk_tokens",
     # a stack with slot-state mixers (core/ssm.Mamba1Layer) and layers that
     # read pages they do not own (transformer.BlockSequence): tokens that
     # went through the scan of every such mixer, counted when a step is
@@ -379,6 +384,24 @@ DEVICE_SCOPES = {
     "ssd_gate_norm": ("atten", "the gate by z and the RMSNorm over groups of "
                       "channels after it"),
     "ssd_out_proj": ("atten", "the output projection"),
+    "qk_norm": ("atten", "the RMSNorm over each head of q and of k (a "
+                "power-retention layer's, before the rotation)"),
+    "retention_gate": ("atten", "a power-retention layer's gates: the "
+                       "log-sigmoid, the log-gates cumulated over a row's "
+                       "open chunk and the step's tokens, the page each "
+                       "token's count starts at, and the query blocks"),
+    "retention_chunk": ("atten", "the attention form over a row's open "
+                        "chunk and the step's own tokens "
+                        "(ops/power_retention.py; its kernel is named after "
+                        "it) with the descriptors and gathers round it"),
+    "retention_state": ("atten", "the slot state's query and fold "
+                        "(ops/power_retention.py; its kernels are named "
+                        "after it): a row of one token on the VPU, the "
+                        "blocks of longer rows on the MXU, the pages a step "
+                        "completes folded in place, and the operands "
+                        "gathered for them"),
+    "retention_out": ("atten", "the two parts joined: the state's through "
+                      "the decay since the chunk's start, the normaliser"),
     "ffn": (None, "a layer's feed-forward with its residual add: dense, or "
             "the expert layer"),
     "moe_route": ("ffn", "router logits, top-k and the softmax over them"),

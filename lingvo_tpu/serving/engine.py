@@ -93,6 +93,7 @@ from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.core import sampling
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as observe_trace
+from lingvo_tpu.ops import power_retention
 from lingvo_tpu.ops import ragged_block_attend
 from lingvo_tpu.quant import kv as kv_quant
 from lingvo_tpu.quant import weights as quant_weights
@@ -500,7 +501,10 @@ class ServingLoop:
     self.paged_path = self._ClassifyPath()
     # block size of the ragged attend kernel at this stack's shapes (0: no
     # attention layer); only the block-fill counters read it
-    attens = self._AttentionLayers()
+    # (the ragged attend kernels': a mixer that reads its pages through
+    # kernels of its own, core/retention.PowerRetention, has none)
+    attens = [a for a in self._AttentionLayers()
+              if hasattr(a, "RaggedQueryBlock")]
     self._attend_bq = (attens[0].RaggedQueryBlock(page_size, kv_cache_dtype)
                        if attens else 0)
     # a token is (laid, own) of the kernel's queries where a KV head serves
@@ -530,6 +534,10 @@ class ServingLoop:
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
+    # power-retention layers: mixers that hold pages AND a slot state
+    self._retention_layers = sum(
+        reps for m, reps in self._MixerLayers()
+        if hasattr(m, "StateBytesPerSlot") and hasattr(m, "KvBytesPerToken"))
     # layers that read pages another layer owns (0: the stack has none)
     self._shared_kv_read_layers = getattr(
         task.stack, "SharedKvReadLayers", lambda: 0)()
@@ -602,8 +610,7 @@ class ServingLoop:
 
   def _AttentionLayers(self) -> list:
     """The stack's attention mixers (those that read the page pool)."""
-    return [m for m, _ in self._MixerLayers()
-            if not hasattr(m, "StateBytesPerSlot")]
+    return [m for m, _ in self._MixerLayers() if spec_decode.ReadsPages(m)]
 
   def _MixerCensus(self) -> dict:
     """Attention vs O(1)-state census — see spec_decode.MixerCensus."""
@@ -1364,6 +1371,9 @@ class ServingLoop:
     if self.state_pool is not None:
       out.update((k, self._counters[k].value) for k in (
           "ssm_tokens", "cross_tokens_unread"))
+    if self._retention_layers:
+      out.update((k, self._counters[k].value) for k in (
+          "retention_rows", "retention_folds", "retention_chunk_tokens"))
     return out or None
 
   def _NoteDispatch(self, batch):
@@ -1381,6 +1391,14 @@ class ServingLoop:
           for seq, n in zip(batch.rows, row_len)
           if seq is not None and n > 0
           and seq.state is scheduler_lib.SeqState.PREFILL))
+    if self._retention_layers:
+      # power-retention layers: rows with a state, pages folded into one and
+      # keys attended in open chunks, a layer (before the cursors advance)
+      live, folds, attended = power_retention.StepCounts(
+          desc.row_q_pos, row_len, self.page_size)
+      self._counters["retention_rows"].Inc(live)
+      self._counters["retention_folds"].Inc(folds)
+      self._counters["retention_chunk_tokens"].Inc(attended)
     if self.trace is not None and batch.mixed:
       # emit prefill-chunk spans BEFORE the cursors advance
       for i, seq in enumerate(batch.rows):

@@ -357,8 +357,10 @@ class KindPages:
 
   def __init__(self, allocator: PageAllocator, windows, max_step_tokens: int,
                max_slots: int, table_pages: int):
-    # a stack with no window layer still keeps a table an owning layer
-    assert max_step_tokens > 0 and windows and not all(windows), windows
+    # a stack with no window layer still keeps a table an owning layer; one
+    # with no full layer (every layer holds only what lies behind its cursor:
+    # power-retention layers, their open chunk) keeps window tables alone
+    assert max_step_tokens > 0 and windows, windows
     self.alloc = allocator
     self.windows = tuple(int(w) for w in windows)
     page = allocator.page_size

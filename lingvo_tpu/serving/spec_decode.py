@@ -95,18 +95,28 @@ def MixerLayers(task):
   return [(l.self_atten.atten, 1) for l in stack.x_layers]
 
 
+def ReadsPages(mixer) -> bool:
+  """Whether a mixer reads the page pool: every mixer but one that keeps a
+  slot state and nothing else."""
+  return (hasattr(mixer, "KvBytesPerToken")
+          or not hasattr(mixer, "StateBytesPerSlot"))
+
+
 def MixerCensus(task) -> dict:
   """Counts attention vs O(1)-state mixers; prices the per-slot state.
 
-  A mixer is 'O(1)-state' iff it exposes StateBytesPerSlot (the
-  core/ssm.py contract); everything else is a paged-KV attention layer.
+  A mixer keeps an 'O(1) state' iff it exposes StateBytesPerSlot (the
+  core/ssm.py contract) and pages iff it exposes KvBytesPerToken; one that
+  exposes neither is a paged-KV attention layer too. A mixer may hold both
+  (core/retention.PowerRetention: a slot state and its open chunk's pages)
+  and is then counted under both.
   """
   num_attention = num_ssm = state_bytes = 0
   for mixer, reps in MixerLayers(task):
     if hasattr(mixer, "StateBytesPerSlot"):
       num_ssm += reps
       state_bytes += reps * mixer.StateBytesPerSlot()
-    else:
+    if ReadsPages(mixer):
       num_attention += reps
   return {
       "num_attention": num_attention,

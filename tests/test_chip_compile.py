@@ -56,6 +56,36 @@ _AGENT_SERVING = dict(t=1088, n=32, n_kv=2, h=128, page=128, table_pages=128,
                       pool_pages=2049, rows=64)
 
 
+# The four power-retention kernels at the shapes `brumby14b_serve_longwrite`
+# runs them at: 16 slots + a 512-token budget, 40 query heads over 8 KV heads
+# of 128, states of 8,320 features, the one pool of 8 x 104 pages and the
+# trash page, 128 pages a row.
+_RETENTION_SERVING = dict(t=528, n=40, n_kv=8, h=128, page=128,
+                          table_pages=128, pool_pages=833, rows=16, wmax=512)
+
+
+def _RetentionServingArgs(d=_RETENTION_SERVING):
+  import jax.numpy as jnp
+  from lingvo_tpu.core import ragged
+  from lingvo_tpu.ops import power_retention
+  sds = jax.ShapeDtypeStruct
+  f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+  t, b, nk, h = d["t"], d["rows"], d["n_kv"], d["h"]
+  pool = sds((d["pool_pages"], d["page"], nk, h), bf16)
+  tok, row = sds((t,), i32), sds((b,), i32)
+  cols = sds((b, d["wmax"]), i32)
+  rows = ragged.RaggedRows(
+      row_of=tok, col_of=tok, pos=tok, valid=sds((t,), jnp.bool_),
+      row_q_pos=row, row_len=row, row_cols=cols, pos_ids=tok, anc_lo=tok,
+      anc_hi=tok, col_parent=cols)
+  return (sds((t, d["n"], h), f32), sds((t, nk, h), f32), sds((t, nk, h), bf16),
+          sds((t, nk), f32),
+          sds((b, nk, h, power_retention.StoredDim(h)), f32),
+          sds((b, nk, power_retention.Offsets(h), h), f32), pool, pool,
+          sds((d["pool_pages"], nk, d["page"]), f32),
+          sds((b, d["table_pages"]), i32), rows)
+
+
 def _GroupedServingArgs(d=_GROUPED_SERVING):
   import jax.numpy as jnp
   sds = jax.ShapeDtypeStruct
@@ -120,6 +150,8 @@ def compiles():
           f"grouped_serving_{variant}": pool.submit(
               _Compile, _CASES[name], _GroupedServingArgs())
           for variant, name in _GROUPED_CASES.items()})
+      futures["retention_serving"] = pool.submit(
+          _Compile, _CASES["power_retention_packed"], _RetentionServingArgs())
       futures["grouped_serving_agent"] = pool.submit(
           _Compile, _CASES["ragged_attend_grouped"],
           _GroupedServingArgs(_AGENT_SERVING))
@@ -146,6 +178,12 @@ def test_grouped_attend_compiles_at_serving_shapes(variant, compiles):
   # every rung of the ladder is a branch of the one program Mosaic lowers
   assert "tpu_custom_call" in compiles[f"grouped_serving_{variant}"].result(
       timeout=300)
+
+
+def test_power_retention_compiles_at_serving_shapes(compiles):
+  # the open chunk's kernel, the state's two queries and the fold
+  assert compiles["retention_serving"].result(timeout=300).count(
+      "tpu_custom_call") >= 4
 
 
 @pytest.fixture(scope="module")

@@ -69,6 +69,7 @@ _FAMILIES = {
         "lm.phi4flash.Phi4MiniFlashTiny", dtype, depth=8),
     "nemotron_h": lambda dtype: _Registered(
         "lm.nemotron_h.Nemotron3NanoTiny", dtype),
+    "brumby": lambda dtype: _Registered("lm.brumby.BrumbyTiny", dtype),
 }
 
 

@@ -465,7 +465,7 @@ class TestScopeNames:
 # -- the one declared tree (observe.schema.DEVICE_SCOPES) ---------------------
 
 _PROGRAMS = ("dense_train", "dense", "smallthinker", "phi4flash",
-             "nemotron_h", "pallas_attend")
+             "nemotron_h", "brumby", "pallas_attend")
 
 
 def _Avals(tree):
