@@ -306,6 +306,11 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
                                  t, max(g_widths)))))
   diff_write = diff[1:3] + (_Normal((t, d_kv, s.diff_h)),
                             _Normal((t, d_kv, s.diff_h)), grouped[3], d_rows)
+  # the page write by runs: the same rows' tokens into the grouped case's
+  # pools (a run of one token, one inside a page, a chunk over several)
+  run_write = grouped[1:3] + (_Normal((t, g_kv, s.grouped_h)),
+                              _Normal((t, g_kv, s.grouped_h)), grouped[3],
+                              d_rows)
 
   # selective scan: one-token rows (two of them a request's first token),
   # then a chunk that carries on from its slot's state, then padding
@@ -373,6 +378,7 @@ def KernelInputs(size: Size, key) -> dict[str, tuple]:
       "ragged_grouped": grouped,
       "diff_attend": diff,
       "diff_write": diff_write,
+      "run_write": run_write,
       "selective_scan": scan,
       "retention": retention,
       "flash_decode": (
@@ -399,6 +405,7 @@ def KernelCases(size: Size) -> list[KernelCase]:
   from lingvo_tpu.ops import flash_decode
   from lingvo_tpu.ops import fused_xent
   from lingvo_tpu.ops import ragged_block_attend
+  from lingvo_tpu.ops import run_write
   from lingvo_tpu.ops import selective_scan
   from lingvo_tpu.ops import ssd_scan
 
@@ -469,6 +476,15 @@ def KernelCases(size: Size) -> list[KernelCase]:
     return lambda k, v, k_new, v_new, tables, rows, **kw: tuple(
         pool[:-1] for pool in diff_attend.WritePages(
             k, v, k_new, v_new, tables, rows, **_Lowering(pallas), **kw))
+
+  def _RunWrite(pallas):
+    # all pages but the last: nothing writes the trash page
+    def _Run(k, v, k_new, v_new, tables, rows):
+      runs = run_write.BuildWriteRuns(rows, *tables.shape, page)
+      return tuple(pool[:-1] for pool in run_write.WriteRuns(
+          k, v, k_new, v_new, tables[runs.row, runs.logical], runs,
+          **_Lowering(pallas)))
+    return _Run
 
   # The step's plan (core/attention.BuildRaggedPlan) against the call that
   # builds its own: the kernel on BOTH sides, handed its descriptors
@@ -579,6 +595,7 @@ def KernelCases(size: Size) -> list[KernelCase]:
       KernelCase("diff_attend_window", "diff_attend",
                  _DiffAttend(s.diff_window)),
       KernelCase("diff_write_pages", "diff_write", _DiffWrite),
+      KernelCase("run_write", "run_write", _RunWrite),
       KernelCase("ragged_attend_tree_plan", "ragged_tree", _RaggedPlan),
       KernelCase("ragged_attend_grouped_window_plan", "ragged_grouped",
                  _GroupedPlan),

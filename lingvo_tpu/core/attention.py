@@ -101,12 +101,16 @@ class RaggedPlan(NamedTuple):
   """What a serving step's attention derives from its rows, static shapes
   and a layer's window alone, and so the same in every layer: a stack builds
   it once a step, before its scans over layers (`BuildRaggedPlan`), and hands
-  it to each layer's RaggedStep beside `rows`."""
+  it to each layer's RaggedStep beside `rows`. What a layer adds is its own:
+  its table's lookup, its pool's page base."""
   tokens: ragged.TokenView
   blocks: dict    # {ops/ragged_block_attend.PlanKey: AttendPlan}, one for
   #                 every distinct key a kernel of the stack is called at
   writes: object  # ops/diff_attend.WritePlan where a layer of the stack
   #                 writes whole pages, else None
+  runs: object    # ops/run_write.Runs: the runs of tokens the step's rows
+  #                 add to their pages (MultiHeadedAttention.RaggedStep's
+  #                 page write; a layer adds its table's lookup)
 
 
 def BuildRaggedPlan(keys, rows, b: int, t_pages: int,
@@ -117,6 +121,7 @@ def BuildRaggedPlan(keys, rows, b: int, t_pages: int,
   ops/diff_attend.WritePages' kernel. None where no layer attends."""
   from lingvo_tpu.ops import diff_attend
   from lingvo_tpu.ops import ragged_block_attend
+  from lingvo_tpu.ops import run_write
   if not keys:
     return None     # no layer of the stack attends
   (page_size,) = {k.page_size for k in keys}
@@ -129,7 +134,10 @@ def BuildRaggedPlan(keys, rows, b: int, t_pages: int,
           key, tokens.row, tokens.q_end, *tree, b=b, t_pages=t_pages)
     writes = (diff_attend.BuildWritePlan(rows, b, t_pages, page_size)
               if page_writes else None)
-  return RaggedPlan(tokens, blocks, writes)
+    # a stack whose layers all write another way leaves the list unread,
+    # and the compiler drops it
+    runs = run_write.BuildWriteRuns(rows, b, t_pages, page_size)
+  return RaggedPlan(tokens, blocks, writes, runs)
 
 
 class PerDimScaleLayer(base_layer.BaseLayer):
@@ -161,6 +169,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
   FProp computes full attention; ExtendStep does one-token incremental decode
   against a KV cache (the Step-API equivalent, all-static shapes for jit).
   """
+
+  # RaggedStep writes its pages by the step's runs (ops/run_write.py): what
+  # the serving engine's `kv_write_runs` / `kv_write_tokens` count
+  writes_by_runs = True
 
   @classmethod
   def Params(cls):
@@ -912,11 +924,19 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     rows.pos[t] through that row's block table. Decode rows contribute one
     token, prefill chunks and spec-verify windows several — the single
     program the engine compiles instead of three (decode / mixed /
-    verify). Padding tokens (rows.valid == False) scatter to the trash
-    page and emit garbage the engine discards. Returns ([1, T, D],
-    updated states). Same numerics per token as PagedStep — the ragged
-    op twins (ops/ragged_block_attend.py) carry the bitwise proof at the
-    op level.
+    verify). Padding tokens (rows.valid == False) write no K or V (an int8
+    pool's scales alone scatter a token, padding's to the trash page) and
+    emit garbage the engine discards. Returns ([1, T, D], updated states).
+    Same numerics per token as PagedStep — the ragged op twins
+    (ops/ragged_block_attend.py) carry the bitwise proof at the op level.
+
+    The page write is by RUNS (ops/run_write.py): a row's tokens of the
+    step are one contiguous span of the packed axis bound for consecutive
+    slots, so they move a page they touch at a time, as a few copies of
+    static widths (a decode row one token, a whole page one copy), K and V
+    in one call whose program does not depend on T or on the count of runs.
+    The step's list is the plan's (`plan.runs`); the layer adds its table's
+    lookup and its pool's page base.
 
     block_tables: [B, t_pages]. A window layer's table may hold stale
     entries behind a row's window, which no query block reaches.
@@ -928,11 +948,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     page base layer * NP, so no op slices or re-assembles a layer's pool.
 
     plan: the step's RaggedPlan (a stack builds it once, before its scan
-    over layers); None: the layer derives its token view here and the
-    kernel's call its descriptors.
+    over layers); None: the layer derives its token view and its runs here
+    and the kernel's call its descriptors.
     """
     from lingvo_tpu.ops import block_decode
     from lingvo_tpu.ops import ragged_block_attend
+    from lingvo_tpu.ops import run_write
     p = self.p
     assert p.rel_pos_emb_dim <= 0, (
         "RaggedStep computes positions from rows.pos; the T5 relative "
@@ -966,28 +987,38 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         q = self.rotary.FProp(rt, q, position=posf)
         k_new = self.rotary.FProp(rt, k_new, position=posf)
       q = self._ScaleQuery(theta, q)
-    # scatter each token's K/V through ITS row's block table before the
-    # read (later tokens of the same prefill chunk attend to earlier ones);
-    # padding tokens write to the trash page (this layer's page np_total - 1).
+    # each row's new K/V land through ITS row's block table before the read
+    # (later tokens of the same prefill chunk attend to earlier ones), by
+    # RUNS (ops/run_write.py): a row's tokens of the step are one span of
+    # the packed axis bound for consecutive slots, cut where a page ends, and
+    # a run moves as a few copies. Padding tokens are in no run and land
+    # nowhere.
     # A table entry is clipped to the layer's range BEFORE the base is
     # added, so no write and no read can reach another layer's pages
     tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
-    phys = jnp.where(valid, tables[row, tokens.logical],
-                     np_total - 1) + base                          # [T]
-    tables = tables + base
-    off = tokens.off
+    runs = (plan.runs if plan is not None else run_write.BuildWriteRuns(
+        rows, *block_tables.shape, page_size))
     eligible = self._RaggedEligible(cached_states)
     quantized = "key_scale" in cached_states
     k_scale = v_scale = None
     if quantized:
       k_new, k_s = kv_quant.QuantizeKv(k_new)              # int8, [1,T,N]
       v_new, v_s = kv_quant.QuantizeKv(v_new)
+      # the scales' sidecar [NP, N, P] has a token on its lanes, where no
+      # run is contiguous: a scatter a token, padding to the trash page
+      # (this layer's page np_total - 1)
+      phys = jnp.where(valid, tables[row, tokens.logical],
+                       np_total - 1) + base                        # [T]
       with observe.Scope("kv_write"):
-        k_scale = cached_states.key_scale.at[phys, :, off].set(k_s[0])
-        v_scale = cached_states.value_scale.at[phys, :, off].set(v_s[0])
+        k_scale = cached_states.key_scale.at[phys, :, tokens.off].set(k_s[0])
+        v_scale = cached_states.value_scale.at[phys, :, tokens.off].set(
+            v_s[0])
     with observe.Scope("kv_write"):
-      k_pool = k_pool.at[phys, off].set(k_new[0].astype(k_pool.dtype))
-      v_pool = v_pool.at[phys, off].set(v_new[0].astype(v_pool.dtype))
+      k_pool, v_pool = run_write.WriteRuns(
+          k_pool, v_pool, k_new[0].astype(k_pool.dtype),
+          v_new[0].astype(v_pool.dtype),
+          tables[runs.row, runs.logical] + base, runs)
+    tables = tables + base
     new_states = NestedMap(key=k_pool, value=v_pool)
     if quantized:
       new_states.key_scale = k_scale
@@ -1240,7 +1271,7 @@ class PooledAttention(MultiHeadedAttention):
 
   # what BlockSequence asks a mixer: it projects and caches K and V in pages
   # of its own table, through the base class's own `kv_write` (the step's
-  # plan carries no page write for it)
+  # runs; the plan carries no whole-page write for it)
   kv_owner = True
   writes_by_plan = False
 

@@ -12,10 +12,11 @@ members, so one jit signature covers every admit/decode/spec/retire mix).
 Two views of the same pack:
 
 - the TOKEN view (`row_of`, `col_of`, `pos`, `valid`, all [T]): what
-  attention needs — each token scatters its K/V through its row's block
-  table at global slot `pos` and attends over its own prefix. Padding
-  tokens (`valid == False`) write to the trash page and produce garbage
-  outputs the engine discards.
+  attention needs — each token's K/V lands through its row's block table
+  at global slot `pos` (a row's tokens as runs, ops/run_write.py) and the
+  token attends over its own prefix. Padding tokens (`valid == False`)
+  are in no run (a scatter a token, where one remains, sends them to the
+  trash page) and produce garbage outputs the engine discards.
 - the ROW view (`row_q_pos`, `row_len` [B]; `row_cols` [B, wmax]): what
   O(1)-state mixers need — ssm.GatedSSMLayer gathers its [B, wmax, D]
   per-row chunk via `row_cols`, runs the existing PagedStep recurrence

@@ -42,6 +42,13 @@ ENGINE_COUNTER_KEYS = (
     # which is the grid every call ran before PR 46: their ratio is the share
     # of that grid that held work. Both 0 where the twins run.
     "attend_live_pairs", "attend_grid_pairs",
+    # the page write by runs (ops/run_write.py), counted when a step is
+    # dispatched from the host's own rows: the runs of tokens the step's list
+    # holds (a layer moves each as a few copies) and the tokens in them. Their
+    # ratio is the tokens a run; `kv_write_tokens` over the packed width a
+    # step is the share of the pack that was written at all. Both 0 where no
+    # layer of the stack writes by runs.
+    "kv_write_runs", "kv_write_tokens",
     # the loop's pipeline: steps dispatched while the step before was still
     # undelivered (`steps` less the pipeline's fills), and rows computed
     # for a sequence that had ended by the time their tokens arrived
@@ -345,7 +352,9 @@ DEVICE_SCOPES = {
     "out_proj": ("atten", "the output projection (a differential layer's "
                  "with its pair norm)"),
     "kv_write": ("atten", "new tokens' K and V into the page pool: the "
-                 "scatter, or the page-write kernel (named after it)"),
+                 "runs' copies (ops/run_write.py) or the whole-page write "
+                 "(ops/diff_attend.WritePages), kernels named after it, and "
+                 "an int8 pool's scales' scatter"),
     "kv_layout": ("kv_write", "the gathers and re-layouts that lay new "
                   "tokens out for the page-write kernel "
                   "(ops/diff_attend.WritePages), not the write itself"),

@@ -95,6 +95,7 @@ from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as observe_trace
 from lingvo_tpu.ops import power_retention
 from lingvo_tpu.ops import ragged_block_attend
+from lingvo_tpu.ops import run_write
 from lingvo_tpu.quant import kv as kv_quant
 from lingvo_tpu.quant import weights as quant_weights
 from lingvo_tpu.serving import kv_cache
@@ -531,6 +532,9 @@ class ServingLoop:
                                       table_pages)
         for key in self._attend_plan_keys)
     self._table_pages = table_pages
+    # some layer writes its pages by the step's runs (ops/run_write.py)
+    self._kv_write_by_runs = any(
+        getattr(m, "writes_by_runs", False) for m, _ in self._MixerLayers())
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
@@ -1427,6 +1431,11 @@ class ServingLoop:
                                         self._table_pages)
           for key in self._attend_plan_keys))
       self._counters["attend_grid_pairs"].Inc(self._attend_grid_pairs)
+    if self._kv_write_by_runs:
+      runs, tokens = run_write.RunCounts(desc.row_q_pos, row_len,
+                                         self.page_size)
+      self._counters["kv_write_runs"].Inc(runs)
+      self._counters["kv_write_tokens"].Inc(tokens)
     if self.paged_path == "dense":
       self._counters["dense_fallback_steps"].Inc()
     if self._kv_quantized:

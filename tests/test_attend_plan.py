@@ -395,10 +395,11 @@ def _Task(family, depth=None):
 
 
 # family -> (attend kernels a step calls, plans it builds for them, Pallas
-# calls in its scans' bodies: dense one attend; SmallThinker a period of four;
-# Phi-4-flash a write and an attend in the window block and in the full
-# layer's, an attend in the cross block)
-DECLARED = {"dense": (24, 1, 1), "smallthinker": (8, 2, 4),
+# calls in its scans' bodies: dense one attend (its heads of 16 tile no lanes,
+# so its page write is the twin's); SmallThinker a period of four, each
+# layer the write of its runs and an attend; Phi-4-flash a write and an attend
+# in the window block and in the full layer's, an attend in the cross block)
+DECLARED = {"dense": (24, 1, 1), "smallthinker": (8, 2, 8),
             "phi4flash": (16, 2, 5)}
 
 

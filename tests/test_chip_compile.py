@@ -86,6 +86,26 @@ def _RetentionServingArgs(d=_RETENTION_SERVING):
           sds((b, d["table_pages"]), i32), rows)
 
 
+def _RunWriteServingArgs(d, kv_dtype=None):
+  """The operands of chip_smoke's `_RunWrite` case at a cell's shapes: its
+  pool, a step's packed K and V, its block tables and rows."""
+  import jax.numpy as jnp
+  from lingvo_tpu.core import ragged
+  sds = jax.ShapeDtypeStruct
+  i32 = jnp.int32
+  n = d.get("n_kv", d["n"])
+  dtype = kv_dtype or jnp.bfloat16
+  pool = sds((d["pool_pages"], d["page"], n, d["h"]), dtype)
+  new = sds((d["t"], n, d["h"]), dtype)
+  tok, row = sds((d["t"],), i32), sds((d["rows"],), i32)
+  cols = sds((d["rows"], d["t"] - d["rows"] + 1), i32)
+  rows = ragged.RaggedRows(
+      row_of=tok, col_of=tok, pos=tok, valid=sds((d["t"],), jnp.bool_),
+      row_q_pos=row, row_len=row, row_cols=cols, pos_ids=tok, anc_lo=tok,
+      anc_hi=tok, col_parent=cols)
+  return (pool, pool, new, new, sds((d["rows"], d["table_pages"]), i32), rows)
+
+
 def _GroupedServingArgs(d=_GROUPED_SERVING):
   import jax.numpy as jnp
   sds = jax.ShapeDtypeStruct
@@ -152,6 +172,14 @@ def compiles():
           for variant, name in _GROUPED_CASES.items()})
       futures["retention_serving"] = pool.submit(
           _Compile, _CASES["power_retention_packed"], _RetentionServingArgs())
+      import jax.numpy as jnp
+      futures.update({
+          f"run_write_serving_{cell}": pool.submit(
+              _Compile, _CASES["run_write"], _RunWriteServingArgs(*args))
+          for cell, args in {
+              "docs": (_SERVING,), "docs_int8": (_SERVING, jnp.int8),
+              "mixed": (_GROUPED_SERVING,), "agent": (_AGENT_SERVING,),
+          }.items()})
       futures["grouped_serving_agent"] = pool.submit(
           _Compile, _CASES["ragged_attend_grouped"],
           _GroupedServingArgs(_AGENT_SERVING))
@@ -177,6 +205,14 @@ def test_ragged_attend_compiles_at_serving_shapes(variant, compiles):
 def test_grouped_attend_compiles_at_serving_shapes(variant, compiles):
   # every rung of the ladder is a branch of the one program Mosaic lowers
   assert "tpu_custom_call" in compiles[f"grouped_serving_{variant}"].result(
+      timeout=300)
+
+
+@pytest.mark.parametrize("cell", ["docs", "docs_int8", "mixed", "agent"])
+def test_run_write_compiles_at_serving_shapes(cell, compiles):
+  # token rows of 16, 4 and 2 KV heads (4 KB, 1 KB and 512 B of bf16) and of
+  # int8: each a whole number of the tiles Mosaic lays that pool out in
+  assert "tpu_custom_call" in compiles[f"run_write_serving_{cell}"].result(
       timeout=300)
 
 

@@ -399,9 +399,11 @@ class TestRepeatedStepCarriesThePool:
 
   @pytest.mark.parametrize("kind", ["f32", "int8", "hybrid"])
   def test_padding_writes_only_its_own_layers_trash_page(self, kind):
-    """A pack of nothing but padding tokens changes page NP - 1 of every
-    layer and not one element besides: no layer writes outside
-    [i * NP, (i + 1) * NP)."""
+    """A pack of nothing but padding tokens changes at most page NP - 1 of
+    every layer and not one element besides: no layer writes outside
+    [i * NP, (i + 1) * NP). K and V move by runs (ops/run_write.py) and
+    padding is in no run, so their pools come back whole; an int8 pool's
+    scales still scatter a token, padding to the layer's own trash page."""
     task, theta, kv = _RepeatLm(kind)
     states = _RandomStates(task, theta, kv, seed=3)
     rows, tables = _Pack(all_padding=True), _Tables(stale=True)
@@ -423,7 +425,8 @@ class TestRepeatedStepCarriesThePool:
       np.testing.assert_array_equal(new[:, :_NP - 1], old[:, :_NP - 1],
                                     err_msg=name)
       for i in range(new.shape[0]):
-        assert not np.array_equal(new[i, _NP - 1], old[i, _NP - 1]), (name, i)
+        assert np.array_equal(new[i, _NP - 1], old[i, _NP - 1]) == (
+            "scale" not in name), (name, i)
     assert pools == _POOL_LEAVES[kind]
 
 
