@@ -111,6 +111,9 @@ class RaggedPlan(NamedTuple):
   runs: object    # ops/run_write.Runs: the runs of tokens the step's rows
   #                 add to their pages (MultiHeadedAttention.RaggedStep's
   #                 page write; a layer adds its table's lookup)
+  narrow: object = None  # ragged.LiveWidth: whether the step's live tokens
+  #                 fit the decode width, for the stack's row-wise blocks
+  #                 (ragged.OverLiveRows); None: the pack has one width
 
 
 def BuildRaggedPlan(keys, rows, b: int, t_pages: int,
@@ -137,7 +140,8 @@ def BuildRaggedPlan(keys, rows, b: int, t_pages: int,
     # a stack whose layers all write another way leaves the list unread,
     # and the compiler drops it
     runs = run_write.BuildWriteRuns(rows, b, t_pages, page_size)
-  return RaggedPlan(tokens, blocks, writes, runs)
+    narrow = ragged.BuildLiveWidth(rows)
+  return RaggedPlan(tokens, blocks, writes, runs, narrow)
 
 
 class PerDimScaleLayer(base_layer.BaseLayer):
@@ -173,6 +177,16 @@ class MultiHeadedAttention(base_layer.BaseLayer):
   # RaggedStep writes its pages by the step's runs (ops/run_write.py): what
   # the serving engine's `kv_write_runs` / `kv_write_tokens` count
   writes_by_runs = True
+  # the [D, N, H] projections (heads of 128) are re-laid for the MXU a layer
+  # at a time, a copy as large as the weights, whichever width the product
+  # runs: narrowed to the rows a step holds (ragged.OverLiveRows) the product
+  # costs what the copy costs, and as a conditional's operand the weight is
+  # written out twice (brumby14b: `atten` + `layer_scan` 2.3 -> 3.8 ms a step,
+  # a chunk step 43.2 -> 47.1 ms; PERF.md section 6, PR 50). So the serving
+  # step keeps this layer's projections OUTSIDE its conditionals, over the
+  # whole pack as ever, on variables the wrapping layer takes from their
+  # stacks as a scan's slice was (transformer._MixThenRows).
+  relaid_weights = True
 
   @classmethod
   def Params(cls):
@@ -917,7 +931,22 @@ class MultiHeadedAttention(base_layer.BaseLayer):
 
   def RaggedStep(self, theta, query_vec, cached_states: NestedMap,
                  block_tables, rows, layer=None, plan=None):
-    """One PACKED continuous-batching step (core/ragged.py RaggedRows).
+    """`RaggedMix` and then `RaggedOut`: the whole layer (a wrapping layer
+    calls the two itself: TransformerAttentionLayer)."""
+    ctx, new_states = self.RaggedMix(theta, query_vec, cached_states,
+                                     block_tables, rows, layer=layer,
+                                     plan=plan)
+    return self.RaggedOut(theta, ctx), new_states
+
+  def RaggedOut(self, theta, ctx):
+    """What follows the attend, row by row: ctx [1, n, N, H] -> [1, n, D]."""
+    with observe.Scope("out_proj"):
+      return self._PostProj(theta, ctx)
+
+  def RaggedMix(self, theta, query_vec, cached_states: NestedMap,
+                block_tables, rows, layer=None, plan=None):
+    """One PACKED continuous-batching step (core/ragged.py RaggedRows), up to
+    the attend's output: -> (ctx [1, T, N, H], updated states).
 
     query_vec: [1, T, D] — all rows' tokens flattened on one token axis;
     token t belongs to slot rows.row_of[t] and lands at global kv slot
@@ -926,8 +955,8 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     program the engine compiles instead of three (decode / mixed /
     verify). Padding tokens (rows.valid == False) write no K or V (an int8
     pool's scales alone scatter a token, padding's to the trash page) and
-    emit garbage the engine discards. Returns ([1, T, D], updated states).
-    Same numerics per token as PagedStep — the ragged op twins
+    emit garbage the engine discards. Same numerics per token as PagedStep:
+    the ragged op twins
     (ops/ragged_block_attend.py) carry the bitwise proof at the op level.
 
     The page write is by RUNS (ops/run_write.py): a row's tokens of the
@@ -950,6 +979,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     plan: the step's RaggedPlan (a stack builds it once, before its scan
     over layers); None: the layer derives its token view and its runs here
     and the kernel's call its descriptors.
+
+    The projections run over every row of the pack, whatever the step
+    holds (`relaid_weights`); the write and the attend run the step's runs
+    and pairs.
     """
     from lingvo_tpu.ops import block_decode
     from lingvo_tpu.ops import ragged_block_attend
@@ -1063,8 +1096,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       ctx, _ = self._Atten(theta, q[0][:, None], k_dense[row],
                            v_dense[row], mask)
       ctx = ctx[:, 0][None]
-    with observe.Scope("out_proj"):
-      return self._PostProj(theta, ctx), new_states
+    return ctx, new_states
 
 
 class LocalSelfAttention(MultiHeadedAttention):
@@ -1291,15 +1323,20 @@ class PooledAttention(MultiHeadedAttention):
     del theta, num_slots
     return NestedMap()
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=0,
-                 plan=None):
-    """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own."""
+  def RaggedMix(self, theta, x, states, shared, rows, table=None, depth=0,
+                plan=None):
+    """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own
+    -> ((ctx,), states, shared): the mixer contract's first half."""
     del depth
-    out, pool = super().RaggedStep(theta, x, shared.kv_pool, table, rows,
-                                   plan=plan)
+    ctx, pool = super().RaggedMix(theta, x, shared.kv_pool, table, rows,
+                                  plan=plan)
     shared = shared.Copy()
     shared.kv_pool = pool
-    return out, states, shared
+    return (ctx,), states, shared
+
+  def RaggedOut(self, theta, ctx, depth=None):
+    del depth
+    return super().RaggedOut(theta, ctx)
 
 
 class DifferentialAttention(base_layer.BaseLayer):
@@ -1507,26 +1544,32 @@ class DifferentialAttention(base_layer.BaseLayer):
         self.fprop_dtype, pool.key.dtype, window=self.p.window,
         lowering="auto" if self.BlockDecodeEligible(page_size) else "xla")
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=0,
-                 plan=None):
+  def RaggedMix(self, theta, x, states, shared, rows, table=None, depth=0,
+                plan=None):
     """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own
     block table or, where it owns no pages, the owning layer's; plan: the
-    step's RaggedPlan, or None (MultiHeadedAttention.RaggedStep)."""
+    step's RaggedPlan, or None (MultiHeadedAttention.RaggedMix)
+    -> (((a1 - lambda a2) V [1, T, pairs, 2H],), states, shared). The
+    projections run over the rows the step holds (ragged.OverLiveRows)."""
     from lingvo_tpu.ops import diff_attend
     p = self.p
-    th = self.CastTheta(theta)
     pool = shared.kv_pool
     np_total, page_size = pool.key.shape[:2]
     tokens = (plan.tokens if plan is not None else ragged.BuildTokenView(
         rows, *table.shape, page_size))
     tables = jnp.clip(table.astype(jnp.int32), 0, np_total - 1)
-    with observe.Scope("qkv_proj"):
-      q = self._Query(th, x[0])                                  # [T, N, H]
+
+    def _Project(x):
+      th = self.CastTheta(theta)
+      with observe.Scope("qkv_proj"):
+        q = self._Query(th, x)                                   # [n, N, H]
+        return (q,) + (self._KeyValue(th, x) if p.kv_owner else ())
+
+    q, *kv = ragged.OverLiveRows(_Project, plan, x[0], axis=0)
     lowering = "auto" if self.BlockDecodeEligible(page_size) else "xla"
     if p.kv_owner:
       # a token's K and V land through its row's table before the read
-      with observe.Scope("qkv_proj"):
-        k_new, v_new = self._KeyValue(th, x[0])
+      k_new, v_new = kv
       with observe.Scope("kv_write"):
         key, value = diff_attend.WritePages(
             pool.key, pool.value, k_new, v_new, tables, rows,
@@ -1534,11 +1577,17 @@ class DifferentialAttention(base_layer.BaseLayer):
       pool = NestedMap(key=key, value=value)
       shared = shared.Copy()
       shared.kv_pool = pool
-    lam, lam_init = self._Lambda(th, depth)
+    lam, _ = self._Lambda(self.CastTheta(theta), depth)
     with observe.Scope("diff_attend"):
       diff = diff_attend.DiffAttend(
           q, pool.key, pool.value, tables, tokens.row, tokens.q_end, lam,
           page_size=page_size, window=p.window, lowering=lowering,
           plan=None if plan is None else plan.blocks)
+    return (diff[None],), states, shared
+
+  def RaggedOut(self, theta, diff, depth=0):
+    """What follows the attend, row by row: the pair norm and the output
+    projection, [1, n, pairs, 2H] -> [1, n, D]."""
+    th = self.CastTheta(theta)
     with observe.Scope("out_proj"):
-      return self._Finish(th, diff, lam_init)[None], states, shared
+      return self._Finish(th, diff, self._Lambda(th, depth)[1])

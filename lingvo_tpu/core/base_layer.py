@@ -21,6 +21,7 @@ from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from lingvo_tpu.core import hyperparams
 from lingvo_tpu.core import py_utils
@@ -60,6 +61,52 @@ def StackedInstantiateVariables(body: "BaseLayer", key: jax.Array,
     return body.InstantiateVariables(jax.random.fold_in(key, i))
 
   return jax.vmap(_One)(jnp.arange(n))
+
+
+class StackSlice:
+  """Layer `index` of a variable stacked over a scan's trips, NOT YET TAKEN:
+  the dynamic slice is made where the variable is used (`TakeSlices`, which
+  `BaseLayer.CastTheta` calls), not where the scan hands it out. A serving
+  scan over layers hands its body such leaves (`SlicedTheta`), so that a use
+  inside a `lax.cond` branch (core/ragged.OverLiveRows) slices the stack
+  THERE, fused into the product that reads it: a slice made outside is an
+  operand of the conditional, which the compiler writes out, the layer's
+  weights copied once a step (+40% on a wide step, my chip run, PR 50)."""
+
+  def __init__(self, stack, index):
+    self.stack, self.index = stack, index
+
+  def Take(self):
+    # `index` is a scan's own counter, never negative: without
+    # `allow_negative_indices` the slice wraps it in no `jnp` comparison,
+    # each a trace of its own a leaf a use (582 more trace events in the
+    # tiny Phi-4-flash sibling's step: tests/test_step_trace.py)
+    stack = self.stack
+    row = jax.lax.dynamic_slice(
+        stack, (self.index,) + (np.int32(0),) * (stack.ndim - 1),
+        (1,) + stack.shape[1:], allow_negative_indices=False)
+    return jax.lax.squeeze(row, (0,))
+
+
+def PathKeys(path) -> tuple:
+  """A `tree_util` key path as the tuple of its keys' names."""
+  return tuple(str(getattr(k, "key", getattr(k, "name", getattr(k, "idx", ""))))
+               for k in path)
+
+
+def SlicedTheta(stacked: NestedMap, index, whole=()) -> NestedMap:
+  """`stacked` with every leaf a `StackSlice` at `index`, but those whose
+  path (`PathKeys`) is in `whole`: a layer addresses them in the stack
+  itself (`StackAddressed`)."""
+  return jax.tree_util.tree_map_with_path(
+      lambda path, stack: stack if PathKeys(path) in whole
+      else StackSlice(stack, index), stacked)
+
+
+def TakeSlices(theta):
+  """`theta` with every `StackSlice` leaf taken; other leaves as they are."""
+  return jax.tree_util.tree_map(
+      lambda x: x.Take() if isinstance(x, StackSlice) else x, theta)
 
 
 class BaseLayer:
@@ -283,7 +330,9 @@ class BaseLayer:
     return py_utils.MaybeBfloat16(x, self.fprop_dtype)
 
   def CastTheta(self, theta: NestedMap) -> NestedMap:
-    """Casts floating theta leaves to fprop dtype (bf16 activations policy)."""
+    """Casts floating theta leaves to fprop dtype (bf16 activations policy);
+    a leaf a serving scan has not sliced yet (`StackSlice`) is taken here."""
+    theta = TakeSlices(theta)
     dtype = self.fprop_dtype
     if dtype == self.p.dtype:
       return theta

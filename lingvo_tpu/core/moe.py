@@ -264,6 +264,7 @@ class DroplessMoELayer(base_layer.BaseLayer):
     normed input); paddings [...] (1 = padding) or None.
     Returns (inputs + experts [..., D], tokens by expert [E] int32)."""
     p = self.p
+    theta = base_layer.TakeSlices(theta)
     with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, inputs)
     with observe.Scope("ffn"):

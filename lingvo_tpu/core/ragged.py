@@ -53,12 +53,23 @@ bitwise-identical to the pre-tree kernel. `col_parent` is the ROW-view
 twin of the same structure: the parent COLUMN of each packed column
 (-1 = no in-step parent, i.e. the row's incoming recurrent state), which
 is what the SSM tree scan gathers its per-column initial state from.
+
+The width a step runs (PR 50). `BuildRaggedRows` packs the rows from column 0
+on with no gap, so a step's live tokens are the PREFIX [0, sum(row_len)) of
+the packed axis and everything behind it is padding that nothing reads. The
+pack is sized for a decode token (or verify window) a slot plus the widest row
+it admits (the prefill budget's), so a step that carries no such row, most
+steps of most flows, holds its tokens in the first W = T - wmax columns.
+`LiveWidth` is that fact once a step (a step's plan carries it) and
+`OverLiveRows` runs a row-wise block over W rows or over T by it, inside the
+one step program.
 """
 
 from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -102,16 +113,81 @@ class TokenView(NamedTuple):
 def BuildTokenView(rows: RaggedRows, b: int, t_pages: int,
                    page_size: int) -> TokenView:
   """The device-side token view of `rows` (jnp arrays) against block tables
-  [b, t_pages]."""
-  pos = rows.pos.astype(jnp.int32)
-  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, b - 1)
+  [b, t_pages]. In `jax.lax` over constants of numpy, as every list a step's
+  rows alone decide: the step program is traced in every process, and a
+  `jnp` call on a tracer is a trace of its own (PERF.md section 6, PRs 49,
+  51)."""
+  lax, i32 = jax.lax, np.int32
+  t = rows.pos.shape[0]
+  pos = lax.convert_element_type(rows.pos, i32)
+  row = lax.clamp(i32(0), lax.convert_element_type(rows.row_of, i32),
+                  i32(b - 1))
+  valid = lax.convert_element_type(rows.valid, np.bool_)
+  q_start = lax.gather(
+      lax.convert_element_type(rows.row_q_pos, i32), lax.reshape(row, (t, 1)),
+      lax.GatherDimensionNumbers(offset_dims=(), collapsed_slice_dims=(0,),
+                                 start_index_map=(0,)),
+      slice_sizes=(1,), mode="clip")
   return TokenView(
       row=row,
-      logical=jnp.clip(pos // page_size, 0, t_pages - 1),
-      off=jnp.where(rows.valid, pos % page_size,
-                    jnp.arange(pos.shape[0], dtype=jnp.int32) % page_size),
-      q_end=jnp.where(rows.valid, pos + 1, 0),
-      q_start=rows.row_q_pos.astype(jnp.int32)[row])
+      logical=lax.clamp(i32(0), lax.div(pos, i32(page_size)),
+                        i32(t_pages - 1)),
+      off=lax.select(valid, lax.rem(pos, i32(page_size)),
+                     np.arange(t, dtype=i32) % page_size),
+      q_end=lax.select(valid, lax.add(pos, i32(1)), np.zeros((t,), i32)),
+      q_start=q_start)
+
+
+class LiveWidth(NamedTuple):
+  """Whether a step's live tokens fit the pack's decode width."""
+  fits: jnp.ndarray   # [] bool: sum(row_len) <= rows
+  rows: int           # W, static: the pack less the widest row it admits
+
+
+def DecodeWidth(t: int, wmax: int) -> int:
+  """W of a pack of t columns whose widest row is wmax: what is left for the
+  slots' decode tokens (or verify windows) when that row is out; 0: the pack
+  has no narrower width."""
+  return max(t - wmax, 0)
+
+
+def BuildLiveWidth(rows: RaggedRows) -> LiveWidth | None:
+  """The step's `LiveWidth`, from the shapes the pack was sized with and the
+  row lengths on the device; None where the pack has no narrower width (a
+  caller that sized `row_cols` as wide as the pack)."""
+  w = DecodeWidth(rows.pos.shape[0], rows.row_cols.shape[1])
+  if not w:
+    return None
+  live = jax.lax.reduce(jax.lax.convert_element_type(rows.row_len, np.int32),
+                        np.int32(0), jax.lax.add, (0,))
+  return LiveWidth(fits=jax.lax.le(live, np.int32(w)), rows=w)
+
+
+def OverLiveRows(fn, plan, *xs, axis: int = 1):
+  """fn(*xs) for a ROW-WISE fn (row i of every output depends on row i of
+  every operand alone) over the rows a step holds: where the step's plan
+  says its live tokens fit W rows (`plan.narrow`), `fn` runs over the first W
+  rows of each operand and the outputs are laid back at full width with zeros
+  behind (rows nothing reads); else over all of them. One `lax.cond`, both
+  branches the same `fn`; what `fn` closes over (weights) enters both. xs:
+  arrays whose axis `axis` is the packed one; fn returns a pytree of such
+  arrays. No plan, or a plan without the width (every caller that is not the
+  serving step's stack): `fn(*xs)` and nothing else."""
+  narrow = getattr(plan, "narrow", None)
+  if narrow is None:
+    return fn(*xs)
+  t, w = xs[0].shape[axis], narrow.rows
+
+  def _Pad(y):
+    config = [(0, 0, 0)] * y.ndim
+    config[axis] = (0, t - w, 0)
+    return jax.lax.pad(y, np.zeros((), y.dtype), config)
+
+  def _Narrow(*xs):
+    out = fn(*(jax.lax.slice_in_dim(x, 0, w, axis=axis) for x in xs))
+    return jax.tree_util.tree_map(_Pad, out)
+
+  return jax.lax.cond(narrow.fits, _Narrow, fn, *xs)
 
 
 def TreeDepths(parents) -> np.ndarray:

@@ -29,6 +29,7 @@ cursor on, which is the open chunk's).
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +37,16 @@ import jax.numpy as jnp
 from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
+from lingvo_tpu.core import ragged
 from lingvo_tpu.core.nested_map import NestedMap
 from lingvo_tpu.core.py_utils import WeightInit, WeightParams
 from lingvo_tpu.ops import power_retention as op
+
+
+class RetentionPlan(NamedTuple):
+  """What a stack of retention layers builds once a step."""
+  lists: op.StepPlan    # the kernels' lists (ops/power_retention.py)
+  narrow: object        # ragged.LiveWidth (or None), for the row-wise blocks
 
 
 class PowerRetention(base_layer.BaseLayer):
@@ -51,6 +59,7 @@ class PowerRetention(base_layer.BaseLayer):
   kv_owner = True
   writes_by_plan = False
   gated_pages = True      # the pool keeps a `gate` leaf beside K and V
+  relaid_weights = True   # [D, N, H] projections: as MultiHeadedAttention's
   # the slot-state leaves a scanned block hands over whole, with the repeat's
   # index (`layer`): sliced a trip they would be copied whole a trip
   stack_states = ("state", "norm")
@@ -128,7 +137,8 @@ class PowerRetention(base_layer.BaseLayer):
     t_pages: the block tables' shape; the width decides nothing here)."""
     del t_pages
     with observe.Scope("attend_plan"):
-      return op.BuildStepPlan(rows, b, page_size)
+      return RetentionPlan(op.BuildStepPlan(rows, b, page_size),
+                           ragged.BuildLiveWidth(rows))
 
   # -- the layer's arithmetic ------------------------------------------------
 
@@ -191,24 +201,31 @@ class PowerRetention(base_layer.BaseLayer):
     state, norm = op.InitState(num_slots, self._nk, self._h)
     return NestedMap(state=state, norm=norm)
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None, depth=None,
-                 plan=None, layer=None):
+  def RaggedMix(self, theta, x, states, shared, rows, table=None, depth=None,
+                plan=None, layer=None):
     """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only);
-    table: [B, t_pages], this layer's own -> ([1, T, D], states, shared).
+    table: [B, t_pages], this layer's own -> ((y [1, T, N, H] f32,), states,
+    shared). The projections run over every row of the pack
+    (`relaid_weights`).
     layer: None where `states` are this layer's own ([slots, ...]); the
     repeat's index where they are its block's, stacked ([repeats, slots,
     ...]): read and written in place, a slot of this layer at `layer * slots
     + slot` of the flat stack."""
     del depth
-    th = self.CastTheta(theta)
-    q, k, v, log_g = self._Project(th, x[0], rows.pos_ids.astype(jnp.int32))
+    q, k, v, log_g = self._Project(self.CastTheta(theta), x[0],
+                                   rows.pos_ids.astype(jnp.int32))
     y, state, norm, pool = op.PackedRetention(
         q, k, v, log_g, states.state, states.norm, shared.kv_pool, table,
         rows, eps=self.p.normalizer_epsilon,
-        plan=plan if isinstance(plan, op.StepPlan) else None,
+        plan=plan.lists if isinstance(plan, RetentionPlan) else None,
         lowering=self.p.lowering, layer=layer)
-    with observe.Scope("out_proj"):
-      out = jnp.einsum("tnh,dnh->td", y.astype(self.fprop_dtype), th.w_post)
     shared = shared.Copy()
     shared.kv_pool = pool
-    return out[None], NestedMap(state=state, norm=norm), shared
+    return (y[None],), NestedMap(state=state, norm=norm), shared
+
+  def RaggedOut(self, theta, y, depth=None):
+    """What follows the kernels, row by row: [1, n, N, H] -> [1, n, D]."""
+    del depth
+    with observe.Scope("out_proj"):
+      return jnp.einsum("...nh,dnh->...d", y.astype(self.fprop_dtype),
+                        self.CastTheta(theta).w_post)

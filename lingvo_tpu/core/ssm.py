@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import py_utils
+from lingvo_tpu.core import ragged
 from lingvo_tpu.core.nested_map import NestedMap
 from lingvo_tpu.core.py_utils import WeightInit, WeightParams
 from lingvo_tpu.ops import ssd_scan
@@ -408,15 +409,15 @@ def _PackedConv(u32, held_tail, conv_w, rows):
   """The causal depthwise convolution's sum over the packed token axis
   (bias and activation are the caller's). u32: [T, C] f32, this step's
   inputs; held_tail: [B, K - 1, C], every slot's last K - 1 inputs; conv_w:
-  [K, C]. -> (sum [T, C] f32, the tails as the step reads them [B, K-1, C]:
-  zero where a row starts a request)."""
+  [K, C]. -> sum [T, C] f32. A token reads tokens BEFORE it on the packed
+  axis and its row's tail alone, so the sum over a prefix of the axis (u32,
+  `rows.row_of` and `rows.col_of` cut to it) is that prefix of the sum."""
   k = conv_w.shape[0]
   t = u32.shape[0]
   slots = held_tail.shape[0]
   row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
   col = rows.col_of.astype(jnp.int32)
-  fresh = rows.row_q_pos == 0
-  tail = jnp.where(fresh[:, None, None], 0.0, held_tail)        # [B, K-1, C]
+  tail = _FreshTail(held_tail, rows)                            # [B, K-1, C]
   w = conv_w.astype(jnp.float32)
   conv = w[k - 1] * u32
   for back in range(1, k):
@@ -426,14 +427,20 @@ def _PackedConv(u32, held_tail, conv_w, rows):
     held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
     conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here,
                                         held)
-  return conv, tail
+  return conv
 
 
-def _PackedConvTail(u32, tail, rows):
+def _FreshTail(held_tail, rows):
+  """Every slot's last K - 1 inputs as the step reads them: zero where a row
+  starts a request."""
+  return jnp.where((rows.row_q_pos == 0)[:, None, None], 0.0, held_tail)
+
+
+def _PackedConvTail(u, tail, rows):
   """Every row's last K - 1 inputs after the step: the tail `_PackedConv`
-  read and this step's tokens together. -> [B, K - 1, C]."""
+  read and this step's tokens u [T, C] together. -> [B, K - 1, C] f32."""
   k = tail.shape[1] + 1
-  t = u32.shape[0]
+  t = u.shape[0]
   n = rows.row_len.astype(jnp.int32)[:, None]                    # [B, 1]
   i = jnp.arange(k - 1, dtype=jnp.int32)[None]                   # [1, K-1]
   at = n - (k - 1) + i                                           # in the row
@@ -441,7 +448,8 @@ def _PackedConvTail(u32, tail, rows):
       rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
   old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
                             axis=1)
-  return jnp.where((at >= 0)[..., None], u32[jnp.clip(cols, 0, t - 1)], old)
+  return jnp.where((at >= 0)[..., None],
+                   u[jnp.clip(cols, 0, t - 1)].astype(jnp.float32), old)
 
 
 class Mamba1Layer(base_layer.BaseLayer):
@@ -549,12 +557,18 @@ class Mamba1Layer(base_layer.BaseLayer):
     delta = jax.nn.softplus(dt + th.b_dt.astype(jnp.float32))
     return c, delta, proj[..., r:r + n], proj[..., r + n:]
 
-  def _Finish(self, th, y, z, shared):
-    if self.p.export_memory:
-      shared = shared.Copy()
-      shared.memory = y.astype(self.fprop_dtype)
+  def _Export(self, y, shared):
+    """`shared` with y (before the gate) as its memory, where the layer
+    exports one."""
+    if not self.p.export_memory:
+      return shared
+    shared = shared.Copy()
+    shared.memory = y.astype(self.fprop_dtype)
+    return shared
+
+  def _Finish(self, th, y, z):
     gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(self.fprop_dtype)
-    return jnp.einsum("...e,ed->...d", gated, th.w_out), shared
+    return jnp.einsum("...e,ed->...d", gated, th.w_out)
 
   # -- whole sequences -------------------------------------------------------
 
@@ -593,8 +607,9 @@ class Mamba1Layer(base_layer.BaseLayer):
       _, ys = jax.lax.scan(_Token, s0, tuple(
           jnp.moveaxis(v, 1, 0) for v in (delta, c, b_t, c_t)))
     y = jnp.moveaxis(ys, 0, 1) + th.d_skip.astype(jnp.float32) * c
+    shared = self._Export(y, shared)
     with observe.Scope("ssm_out_proj"):
-      out, shared = self._Finish(th, y, z, shared)
+      out = self._Finish(th, y, z)
     if paddings is not None:
       out = py_utils.ApplyPadding(paddings, out)
     return out, shared
@@ -609,28 +624,42 @@ class Mamba1Layer(base_layer.BaseLayer):
         scan=jnp.zeros((num_slots, p.state_dim, self._e), jnp.float32),
         conv=jnp.zeros((num_slots, p.conv_width - 1, self._e), jnp.float32))
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None,
-                 depth=None, plan=None):
+  def RaggedMix(self, theta, x, states, shared, rows, table=None,
+                depth=None, plan=None):
     """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
-    ([1, T, D], new states, shared)."""
-    del table, depth, plan
+    ((y, z [1, T, E]), new states, shared). What precedes the scan is a
+    token's own or reads the tokens before it (`_PackedConv`), and runs over
+    the rows the step holds (ragged.OverLiveRows, one branch)."""
+    del table, depth
     from lingvo_tpu.ops import selective_scan
+
+    def _ScanOperands(x, row_of, col_of):
+      th = self.CastTheta(theta)
+      with observe.Scope("ssm_in_proj"):
+        u, z = self._InProj(th, x)                              # [n, E]
+      with observe.Scope("ssm_conv"):
+        conv = _PackedConv(u.astype(jnp.float32), states.conv, th.conv_w,
+                           rows._replace(row_of=row_of, col_of=col_of))
+      with observe.Scope("ssm_params"):
+        return (u, z) + self._ScanInputs(th, conv)
+
+    u, z, c, delta, b_t, c_t = ragged.OverLiveRows(
+        _ScanOperands, plan, x[0], rows.row_of, rows.col_of, axis=0)
     th = self.CastTheta(theta)
-    with observe.Scope("ssm_in_proj"):
-      u, z = self._InProj(th, x[0])                             # [T, E]
-    with observe.Scope("ssm_conv"):
-      u32 = u.astype(jnp.float32)
-      conv, tail = _PackedConv(u32, states.conv, th.conv_w, rows)
-    with observe.Scope("ssm_params"):
-      c, delta, b_t, c_t = self._ScanInputs(th, conv)
     y, scan = selective_scan.SelectiveScan(
         delta, c, b_t, c_t, -jnp.exp(th.a_log.astype(jnp.float32)),
         th.d_skip, states.scan, rows)
     with observe.Scope("ssm_conv"):
-      new_tail = _PackedConvTail(u32, tail, rows)
+      new_tail = _PackedConvTail(u, _FreshTail(states.conv, rows), rows)
+    return ((y[None], z[None]), NestedMap(scan=scan, conv=new_tail),
+            self._Export(y[None], shared))
+
+  def RaggedOut(self, theta, y, z, depth=None):
+    """What follows the scan, row by row: the gate and the output
+    projection, [1, n, E] -> [1, n, D]."""
+    del depth
     with observe.Scope("ssm_out_proj"):
-      out, shared = self._Finish(th, y[None], z[None], shared)
-    return out, NestedMap(scan=scan, conv=new_tail), shared
+      return self._Finish(self.CastTheta(theta), y, z)
 
 
 class GatedMemoryUnit(base_layer.BaseLayer):
@@ -666,11 +695,14 @@ class GatedMemoryUnit(base_layer.BaseLayer):
     del theta, num_slots
     return NestedMap()
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None,
-                 depth=None, plan=None):
-    del rows, table, plan
-    out, shared = self.FProp(theta, x, shared, depth=depth)
-    return out, states, shared
+  def RaggedMix(self, theta, x, states, shared, rows, table=None,
+                depth=None, plan=None):
+    """Nothing mixes tokens here: the whole layer is `RaggedOut`'s."""
+    del theta, rows, table, depth, plan
+    return (x, shared.memory), states, shared
+
+  def RaggedOut(self, theta, x, memory, depth=None):
+    return self.FProp(theta, x, NestedMap(memory=memory), depth=depth)[0]
 
 
 class Mamba2Layer(base_layer.BaseLayer):
@@ -857,25 +889,39 @@ class Mamba2Layer(base_layer.BaseLayer):
                        jnp.float32),
         conv=jnp.zeros((num_slots, p.conv_width - 1, self._c), jnp.float32))
 
-  def RaggedStep(self, theta, x, states, shared, rows, table=None,
-                 depth=None, plan=None):
+  def RaggedMix(self, theta, x, states, shared, rows, table=None,
+                depth=None, plan=None):
     """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
-    ([1, T, D], new states, shared)."""
-    del table, depth, plan
+    ((y [1, T, Hm, P] f32, z [1, T, E]), new states, shared). What precedes
+    the scan runs over the rows the step holds, as Mamba1Layer's does."""
+    del table, depth
     from lingvo_tpu.ops import packed_ssd_scan
-    th = self.CastTheta(theta)
-    with observe.Scope("ssd_in_proj"):
-      z, xbc, dt = self._InProj(th, x[0])
+
+    def _ScanOperands(x, row_of, col_of):
+      th = self.CastTheta(theta)
+      with observe.Scope("ssd_in_proj"):
+        z, xbc, dt = self._InProj(th, x)
+      with observe.Scope("ssd_conv"):
+        conv = _PackedConv(xbc.astype(jnp.float32), states.conv, th.conv_w,
+                           rows._replace(row_of=row_of, col_of=col_of))
+        return (z, xbc) + self._ScanInputs(th, conv, dt)
+
+    z, xbc, u, delta, b_t, c_t = ragged.OverLiveRows(
+        _ScanOperands, plan, x[0], rows.row_of, rows.col_of, axis=0)
     with observe.Scope("ssd_conv"):
-      xbc32 = xbc.astype(jnp.float32)
-      conv, tail = _PackedConv(xbc32, states.conv, th.conv_w, rows)
-      new_tail = _PackedConvTail(xbc32, tail, rows)
-      u, delta, b_t, c_t = self._ScanInputs(th, conv, dt)
+      new_tail = _PackedConvTail(xbc, _FreshTail(states.conv, rows), rows)
+    th = self.CastTheta(theta)
     y, scan = packed_ssd_scan.PackedSsdScan(
         u, delta, -jnp.exp(th.a_log.astype(jnp.float32)), b_t, c_t,
         th.d_skip, states.scan, rows, chunk_size=self.p.chunk_size)
+    return (y[None], z[None]), NestedMap(scan=scan, conv=new_tail), shared
+
+  def RaggedOut(self, theta, y, z, depth=None):
+    """What follows the scan, row by row: the gated norm and the output
+    projection, -> [1, n, D]."""
+    del depth
+    th = self.CastTheta(theta)
     with observe.Scope("ssd_gate_norm"):
       gated = self._GateNorm(th, y, z)
     with observe.Scope("ssd_out_proj"):
-      out = jnp.einsum("...e,ed->...d", gated, th.w_out)
-    return out[None], NestedMap(scan=scan, conv=new_tail), shared
+      return jnp.einsum("...e,ed->...d", gated, th.w_out)

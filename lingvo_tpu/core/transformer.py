@@ -22,6 +22,7 @@ from lingvo_tpu.core import attention as attention_lib
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
 from lingvo_tpu.core import py_utils
+from lingvo_tpu.core import ragged
 from lingvo_tpu.core.nested_map import NestedMap
 
 
@@ -98,6 +99,45 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
       if p.add_skip_connection:
         out = inputs + out
     return out
+
+
+def _MixThenRows(mixer, theta, plan, mix, out, residual, then):
+  """`then(residual + Mixer(.))` in a serving step, the row-wise part over the
+  rows the step holds. mix(theta) -> (ctx, *rest) is the mixer's
+  `RaggedMix` (ctx: a tuple of `[1, T, ...]` arrays; its own projections
+  branch inside it); out(theta, *ctx) -> [1, n, D] its `RaggedOut`; then: what
+  the caller does next row by row (a dense feed-forward) or None. The output
+  projection, the residual and `then` share ONE conditional
+  (ragged.OverLiveRows). -> (x [1, T, D], *rest).
+
+  With no `then` (a layer that is its mixer alone) nothing branches here: an
+  output projection and a residual alone do not repay a conditional
+  (nemotron3nano: `serve_tok_s` +10.1% with it, +9.4 to +10.2% without, and
+  a Mamba-2 body's second conditional is 0.1 s of every process's set-up;
+  PERF.md section 6, PR 51).
+
+  A mixer whose `[D, N, H]` projections are re-laid for the MXU a layer at a
+  time (`relaid_weights`: MultiHeadedAttention says why) keeps them outside
+  the step's conditionals, its output projection too: its variables are
+  taken from their stacks here (base_layer.StackSlice), as a scan's slice
+  was."""
+  relaid = getattr(mixer, "relaid_weights", False)
+  if relaid:
+    # (outside `atten`: a scan's slices count under `layer_scan`, as ever)
+    theta = base_layer.TakeSlices(theta)
+  with observe.Scope("atten"):
+    ctx, *rest = mix(theta)
+    if relaid:
+      ctx, out = (out(theta, *ctx),), lambda theta, y: y
+
+  def _Finish(residual, *ctx):
+    with observe.Scope("atten"):
+      x = residual + out(theta, *ctx)
+    return x if then is None else then(x)
+
+  if then is None:
+    return (_Finish(residual, *ctx), *rest)
+  return (ragged.OverLiveRows(_Finish, plan, residual, *ctx), *rest)
 
 
 class TransformerAttentionLayer(base_layer.BaseLayer):
@@ -207,13 +247,18 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
     return [self.atten.RaggedPlanKey(cached_states)]
 
   def RaggedStep(self, theta, query_vec, cached_states, block_tables, rows,
-                 ssm_col_states: bool = False, layer=None, plan=None):
+                 ssm_col_states: bool = False, layer=None, plan=None,
+                 then=None):
     """Packed-token continuous-batching step (core/ragged.py RaggedRows);
     query_vec [1, T, D]. Same pre-LN/residual wrapper and spec-verify
     dispatch as PagedStep — only the inner mixer contract changes.
     layer: the mixer's state is stacked over a repeat axis and this is
     its index there (MultiHeadedAttention.RaggedStep); None = its own.
-    plan: the step's attention.RaggedPlan, for a mixer that is attention."""
+    plan: the step's attention.RaggedPlan, for a mixer that is attention.
+    then: what the caller does next with the block's output, row by row (a
+    layer's feed-forward): it runs in the same branch as the mixer's output
+    projection and the residual, over the rows the step holds
+    (ragged.OverLiveRows: one conditional for the three)."""
     kw = {} if layer is None else {"layer": layer}
     if ssm_col_states and hasattr(self.atten, "StateBytesPerSlot"):
       kw["collect_col_states"] = True
@@ -221,10 +266,20 @@ class TransformerAttentionLayer(base_layer.BaseLayer):
       kw["plan"] = plan
     with observe.Scope("norm"):
       x = self.ln.FProp(theta.ln, query_vec)
-    with observe.Scope("atten"):
-      out, new_states = self.atten.RaggedStep(
-          theta.atten, x, cached_states, block_tables, rows, **kw)
-      return query_vec + out, new_states
+    ragged_out = hasattr(self.atten, "RaggedOut")
+
+    def _Mix(theta):
+      # a mixer that works by rows of slots, not of the pack
+      # (ssm.GatedSSMLayer), has one call, and its output is whole already
+      step = self.atten.RaggedMix if ragged_out else self.atten.RaggedStep
+      ctx, new_states = step(theta, x, cached_states, block_tables, rows,
+                             **kw)
+      return (ctx,), new_states
+
+    return _MixThenRows(
+        self.atten, theta.atten, plan, _Mix,
+        self.atten.RaggedOut if ragged_out else (lambda theta, y: y),
+        query_vec, then)
 
 
 class TransformerLayer(base_layer.BaseLayer):
@@ -369,9 +424,16 @@ class TransformerLayer(base_layer.BaseLayer):
   def RaggedStep(self, theta, inputs, cached_states, block_tables, rows,
                  ssm_col_states: bool = False, layer=None, plan=None):
     routed = self._RouterLogits(theta, inputs)
+    # a dense feed-forward is row-wise: it runs in the branch of the
+    # attention block's output (TransformerAttentionLayer.RaggedStep)
+    dense = "fflayer" not in cached_states and not routed
     x, new_sa = self.self_atten.RaggedStep(
         theta.self_atten, inputs, cached_states.self_atten, block_tables,
-        rows, ssm_col_states=ssm_col_states, layer=layer, plan=plan)
+        rows, ssm_col_states=ssm_col_states, layer=layer, plan=plan,
+        then=(lambda x: self.fflayer.FProp(theta.fflayer, x))
+        if dense else None)
+    if dense:
+      return x, NestedMap(self_atten=new_sa)
     if "fflayer" not in cached_states:
       out = self.fflayer.FProp(theta.fflayer, x, **routed)
       return out, NestedMap(self_atten=new_sa)
@@ -758,25 +820,17 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
 
     carried = _ByPath(cached_states.body)
 
-    # variables the body addresses in the stack by `layer` are not
-    # scanned: the body gets the stack whole (StackAddressed)
+    # variables the body addresses in the stack by `layer` stay whole
+    # (StackAddressed)
     whole = (self.body.StackAddressed()
              if hasattr(self.body, "StackAddressed") else set())
-    def _Whole(path, leaf):
-      del leaf
-      keys = tuple(str(getattr(k, "key", getattr(k, "name", getattr(
-          k, "idx", "")))) for k in path)
-      return keys in whole
-    scanned_theta = jax.tree_util.tree_map_with_path(
-        lambda path, leaf: jnp.zeros(leaf.shape[:1], leaf.dtype)
-        if _Whole(path, leaf) else leaf, theta.body)
 
-    def _Body(carry, per_layer):
+    def _Body(carry, idx):
       x, states = carry
-      theta_i, idx = per_layer
-      theta_i = jax.tree_util.tree_map_with_path(
-          lambda path, mine, stack: stack if _Whole(path, stack) else mine,
-          theta_i, theta.body)
+      # no variable is scanned: layer idx's stays in its stack until a layer
+      # uses it (base_layer.StackSlice), so that a use inside a conditional's
+      # branch (ragged.OverLiveRows) slices the stack there
+      theta_i = base_layer.SlicedTheta(theta.body, idx, whole)
       x, new_states = self.body.RaggedStep(theta_i, x, states, block_tables,
                                            rows, layer=idx, plan=plan, **kw)
       new = _ByPath(new_states)
@@ -789,7 +843,7 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
     with observe.Scope("layer_scan"):
       (out, states), added = jax.lax.scan(
           _Body, (inputs, cached_states.body),
-          (scanned_theta, jnp.arange(self.p.num_layers)))
+          jnp.arange(self.p.num_layers))
     final = _ByPath(states)
     new_states = jax.tree_util.tree_map_with_path(
         lambda path, leaf: final[path] if leaf is None else leaf, added,
@@ -836,9 +890,14 @@ class SharedStateLayer(base_layer.BaseLayer):
   (ssm.Mamba1Layer, ssm.Mamba2Layer, ssm.GatedMemoryUnit,
   attention.DifferentialAttention, attention.PooledAttention):
   `FProp(theta, x, shared, paddings, segment_ids, depth) -> (out, shared)`,
-  `InitPagedStates(theta, num_slots)`, and `RaggedStep(theta, x, states,
-  shared, rows, table, depth, plan) -> (out, states, shared)` (`plan`: the
-  step's attention.RaggedPlan, which a mixer that is no attention drops).
+  `InitPagedStates(theta, num_slots)`, and the serving step in two halves:
+  `RaggedMix(theta, x, states, shared, rows, table, depth, plan) -> (ctx,
+  states, shared)`, what mixes tokens (its projections over the rows the step
+  holds, its kernels over the step's pairs and runs), and `RaggedOut(theta,
+  *ctx, depth) -> out`, what follows row by row ([1, n, ...] -> [1, n, D]),
+  which this layer runs with the residual and the feed-forward in ONE branch
+  over the rows the step holds (ragged.OverLiveRows). `plan`: the step's
+  attention.RaggedPlan or the retention layers' own.
 
   Either branch may be absent: `mixer_tpl` None is a layer that is its
   feed-forward alone, `tr_fflayer_tpl` None one that is its mixer alone. The
@@ -921,27 +980,34 @@ class SharedStateLayer(base_layer.BaseLayer):
 
   def RaggedStep(self, theta, x, states, shared, rows, table, depth, plan,
                  repeat=None):
+    dense = self.p.tr_fflayer_tpl is not None and not self._experts
+    feed_forward = (lambda x: self._FeedForward(theta, x, None, repeat)[0]
+                    ) if dense else None
     if self.mixer is not None:
       if self._experts:
         states = NestedMap({k: v for k, v in states.items() if k != "routed"})
       with observe.Scope("norm"):
         normed = self.ln.FProp(theta.ln, x)
-      with observe.Scope("atten"):
-        # a mixer whose state is too large to slice a trip is handed the
-        # whole stack's and the repeat's index (StackStates)
-        extra = {"layer": repeat} if self.StackStates() else {}
-        out, states, shared = self.atten.RaggedStep(
-            theta.atten, normed, states, shared, rows, table=table,
-            depth=depth, plan=plan, **extra)
-        x = x + out
+      # a mixer whose state is too large to slice a trip is handed the
+      # whole stack's and the repeat's index (StackStates)
+      extra = {"layer": repeat} if self.StackStates() else {}
+      x, states, shared = _MixThenRows(
+          self.atten, theta.atten, plan,
+          lambda theta: self.atten.RaggedMix(
+              theta, normed, states, shared, rows, table=table, depth=depth,
+              plan=plan, **extra),
+          lambda theta, *ctx: self.atten.RaggedOut(theta, *ctx, depth=depth),
+          x, feed_forward)
+    elif dense:
+      # a layer that is its dense feed-forward alone
+      x = ragged.OverLiveRows(feed_forward, plan, x)
     if self._experts:
       # the step's padding tokens are routed nowhere
       x, counts = self._FeedForward(
           theta, x, 1.0 - rows.valid.astype(jnp.float32)[None], repeat)
       states = states.Copy()
       states.routed = counts
-      return x, states, shared
-    return self._FeedForward(theta, x, None, repeat)[0], states, shared
+    return x, states, shared
 
 
 class BlockSequence(base_layer.BaseLayer):
@@ -1088,24 +1154,27 @@ class BlockSequence(base_layer.BaseLayer):
     return sum(reps for m, reps in self._Mixers()
                if getattr(m, "kv_owner", None) is False)
 
-  def _Scan(self, b, theta, x, shared, per_repeat, call):
+  def _Scan(self, b, theta, x, shared, per_repeat, call, sliced=True):
     """Block b as one scan over its repeats: `call(layer, theta_j, x,
     shared, j-th entry of every per-repeat tree, depth, repeat) -> (x,
     out_j, shared)`; returns (x, shared, [out_j stacked over repeats]).
     The variables a layer addresses in the stack by `repeat`
     (`StackAddressed`: an expert layer's matrices) are not scanned: a slice
-    of them a trip would be a copy of them a trip."""
+    of them a trip would be a copy of them a trip. sliced False (the serving
+    step): no variable is; a repeat's stays in its stack until a layer uses
+    it (base_layer.StackSlice, RepeatedTransformerLayer.RaggedStep)."""
     layers = self._bodies[b]
     first = self._first_depth[b]
     whole = self._whole[b]
     block = theta[f"block_{b}"]
 
     def _Whole(path):
-      return tuple(str(getattr(k, "key", getattr(k, "name", getattr(
-          k, "idx", "")))) for k in path) in whole
+      return base_layer.PathKeys(path) in whole
 
     scanned = block
-    if whole:
+    if not sliced:
+      scanned = None
+    elif whole:
       scanned = jax.tree_util.tree_map_with_path(
           lambda path, leaf: jnp.zeros(leaf.shape[:1], leaf.dtype)
           if _Whole(path) else leaf, block)
@@ -1113,7 +1182,9 @@ class BlockSequence(base_layer.BaseLayer):
     def _Body(carry, per):
       x, shared = carry
       theta_i, idx, extra = per
-      if whole:
+      if not sliced:
+        theta_i = base_layer.SlicedTheta(block, idx, whole)
+      elif whole:
         theta_i = jax.tree_util.tree_map_with_path(
             lambda path, mine, stack: stack if _Whole(path) else mine,
             theta_i, block)
@@ -1256,7 +1327,7 @@ class BlockSequence(base_layer.BaseLayer):
         return x, ns, shared
 
       x, shared, outs = self._Scan(
-          b, theta, x, shared, (scanned, mine), _Call)
+          b, theta, x, shared, (scanned, mine), _Call, sliced=False)
       if any(whole):
         for out, carried in zip(outs, shared.stack_states):
           out.update(carried)

@@ -158,6 +158,13 @@ class CompileLog:
       rec["fallback"] = f"dispatch: {type(e).__name__}: {e}"
       return fn(*args)
 
+  def Compile(self, name: str, fn, *args) -> None:
+    """Builds `name` now, in the caller's thread, from arguments of the
+    shapes and dtypes `Call` will be given; a later `Call` finds it. A
+    no-op where `name` is already there."""
+    if name not in self._programs:
+      self._Compile(name, fn, args)
+
   def _Compile(self, name: str, fn, args):
     rec = {"name": name, "donated_argnums": list(self._donate)}
     compiled = None

@@ -26,6 +26,12 @@ import jax
 # them under these exact plain keys, the registry under "serving/<key>".
 ENGINE_COUNTER_KEYS = (
     "steps", "decode_steps", "mixed_steps",
+    # steps whose live tokens fit the pack's decode width (the pack less the
+    # widest row it admits: core/ragged.LiveWidth), counted at dispatch from
+    # the host's own row lengths: the steps whose row-wise blocks the step
+    # program runs over that width and not the pack's (ragged.OverLiveRows).
+    # Not `decode_steps`: a short chunk beside few live rows fits too.
+    "narrow_steps",
     "tokens_emitted", "prompt_tokens",
     "dense_fallback_steps", "quantized_steps",
     "spec_cycles", "draft_tokens", "accepted_tokens",

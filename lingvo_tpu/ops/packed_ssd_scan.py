@@ -354,12 +354,24 @@ def PackedSsdScan(x, dt, a, b, c, d_skip, state, rows, *, chunk_size: int = 64,
   if lowering == "sequential":
     with observe.Scope("ssd_scan"):
       return _SequentialPackedScan(x, dt, a, b, c, d_skip, state, rows)
-  if lowering == "xla":
-    row_pass = functools.partial(_XlaRowPass, g=g)
-  else:
-    row_pass = functools.partial(
-        _PallasRowPass, g=g,
-        interpret=(not on_tpu) if interpret is None else interpret)
+  return _ChunkedCall(
+      x, dt, a, b, c, d_skip, state, rows, q=int(chunk_size),
+      kernel=lowering != "xla",
+      interpret=(not on_tpu) if interpret is None else interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("q", "kernel", "interpret"),
+                   inline=True)
+def _ChunkedCall(x, dt, a, b, c, d_skip, state, rows, *, q: int, kernel: bool,
+                 interpret: bool):
+  """`_ChunkedPackedScan` as a `jit` of its own, inlined where it is called:
+  the Mamba-2 layers of a stack (and a probe's program) share ONE trace of
+  its hundred and fifty `jnp` calls, which the step program would else
+  repeat a traced layer body (PERF.md section 6, PR 51); what is lowered is
+  what was."""
+  g = b.shape[1]
+  row_pass = (functools.partial(_PallasRowPass, g=g, interpret=interpret)
+              if kernel else functools.partial(_XlaRowPass, g=g))
   with observe.Scope("ssd_scan"):
-    return _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows,
-                              int(chunk_size), row_pass)
+    return _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q,
+                              row_pass)

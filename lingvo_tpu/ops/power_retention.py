@@ -61,6 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from lingvo_tpu import observe
 from lingvo_tpu.core.nested_map import NestedMap
+from lingvo_tpu.ops import run_write
 from lingvo_tpu.ops.flash_attention import LANES, SUBLANES
 from lingvo_tpu.ops.ragged_block_attend import Lowering, _HeadPages
 
@@ -285,63 +286,101 @@ def BuildStepPlan(rows, b: int, page: int, bq: int = QUERY_BLOCK,
                   reset: bool = True) -> StepPlan:
   """b: rows of the block tables. reset: False leaves a slot as its last
   occupant left it where a row starts a request (what a control and a test
-  break, nothing else)."""
-  i32 = jnp.int32
+  break, nothing else).
+
+  Written in `jax.lax` over constants of numpy, as ops/run_write.
+  BuildWriteRuns is and for its reason: the step program is traced in every
+  process and each `jnp` call on a tracer is a trace of its own (these lists
+  through `jnp` were 182 of them, a quarter of a second on an idle host:
+  PERF.md section 6, PR 51). A list's owner (`searchsorted`) is a comparison
+  against the cumulated counts, summed (run_write.Owner), and what a list
+  reads of its owners is one gather a list (run_write.TakeRows)."""
+  lax, i32 = jax.lax, np.int32
   t = rows.row_of.shape[0]
   nb, span, ne = PlanSizes(b, t, page, rows.row_cols.shape[1], bq)
-  p0 = rows.row_q_pos.astype(i32)
-  ln = rows.row_len.astype(i32)
-  live = ln > 0
-  fresh = live & (p0 == 0) & reset
-  j0 = p0 // page
-  start = jnp.clip(rows.row_cols[:, 0].astype(i32), 0, t - 1)
-  row = jnp.clip(rows.row_of.astype(i32), 0, b - 1)
-  pos = rows.pos.astype(i32)
-  jj = jnp.clip(pos // page - j0[row], 0, span - 1)
-  off = jnp.where(rows.valid, pos % page, jnp.arange(t, dtype=i32) % page)
-  col = jnp.clip(rows.col_of.astype(i32), 0, None)
-  folds = jnp.where(live, (p0 + ln) // page - j0, 0)
+  to_i32 = lambda x: lax.convert_element_type(x, i32)
+  zeros = lambda n: np.zeros((n,), np.int32)
+  cols = lambda *leaves: lax.concatenate(
+      [lax.reshape(to_i32(x), x.shape + (1,)) for x in leaves], 1)
+  col = lambda table, j: lax.index_in_dim(table, j, 1, keepdims=False)
+  last = lambda x: lax.index_in_dim(x, x.shape[0] - 1, 0, keepdims=False)
+  both = lax.bitwise_and
+  p0 = to_i32(rows.row_q_pos)
+  ln = to_i32(rows.row_len)
+  live = lax.gt(ln, i32(0))
+  fresh = (both(live, lax.eq(p0, i32(0))) if reset
+           else lax.full_like(live, False))
+  j0 = lax.div(p0, i32(page))
+  start = lax.clamp(i32(0), to_i32(col(rows.row_cols, 0)), i32(t - 1))
+  row = lax.clamp(i32(0), to_i32(rows.row_of), i32(b - 1))
+  pos = to_i32(rows.pos)
+  column = lax.max(to_i32(rows.col_of), i32(0))
+  folds = lax.select(live, lax.sub(lax.div(lax.add(p0, ln), i32(page)), j0),
+                     zeros(b))
   # blocks
-  nblk = (ln + bq - 1) // bq
-  cum = jnp.cumsum(nblk)
-  i = jnp.arange(nb, dtype=i32)
-  blk_row = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1
-                     ).astype(i32)
-  k = i - (cum - nblk)[blk_row]
-  blk_live = i < cum[-1]
-  blk_n = jnp.where(blk_live, jnp.clip(ln[blk_row] - k * bq, 0, bq), 0)
-  blk_first = jnp.where(blk_live, start[blk_row] + k * bq, 0)
-  blk_pages = jnp.where(
-      blk_live, (p0[blk_row] + k * bq + blk_n - 1) // page - j0[blk_row] + 1,
-      0)
-  tok_at = ((cum - nblk)[row] + col // bq) * bq + col % bq
-  tok_at = jnp.where(rows.valid, tok_at, 0)
+  nblk = lax.div(lax.add(ln, i32(bq - 1)), i32(bq))
+  cum = lax.cumsum(nblk)
+  before = lax.sub(cum, nblk)
+  reads = both(live, lax.bitwise_not(fresh))
+  owner, i = run_write.Owner(cum, nb)
+  blk_row = lax.min(owner, i32(b - 1))
+  mine = run_write.TakeRows(
+      cols(before, ln, start, p0, j0, both(reads, lax.gt(ln, i32(1)))),
+      blk_row, nb)
+  into = lax.mul(lax.sub(i, col(mine, 0)), i32(bq))   # tokens of the row before
+  blk_live = lax.lt(i, lax.broadcast(last(cum), (nb,)))
+  blk_n = lax.select(
+      blk_live, lax.clamp(i32(0), lax.sub(col(mine, 1), into), i32(bq)),
+      zeros(nb))
+  blk_first = lax.select(blk_live, lax.add(col(mine, 2), into), zeros(nb))
+  blk_pages = lax.select(
+      blk_live,
+      lax.add(lax.sub(lax.div(lax.sub(lax.add(lax.add(col(mine, 3), into),
+                                              blk_n), i32(1)), i32(page)),
+                      col(mine, 4)), i32(1)),
+      zeros(nb))
+  wide = both(blk_live, lax.ne(col(mine, 5), i32(0)))
+  # tokens
+  theirs = run_write.TakeRows(cols(j0, before), row, t)
+  jj = lax.clamp(i32(0), lax.sub(lax.div(pos, i32(page)), col(theirs, 0)),
+                 i32(span - 1))
+  valid = lax.convert_element_type(rows.valid, np.bool_)
+  off = lax.select(valid, lax.rem(pos, i32(page)),
+                   np.arange(t, dtype=np.int32) % page)
+  tok_at = lax.select(
+      valid,
+      lax.add(lax.mul(lax.add(col(theirs, 1), lax.div(column, i32(bq))),
+                      i32(bq)), lax.rem(column, i32(bq))),
+      zeros(t))
   # pairs
-  pcum = jnp.cumsum(blk_pages)
-  m = jnp.arange(nb * span, dtype=i32)
-  pair_blk = jnp.clip(jnp.searchsorted(pcum, m, side="right"), 0, nb - 1
-                      ).astype(i32)
-  pair_jj = jnp.clip(m - (pcum - blk_pages)[pair_blk], 0, span - 1)
-  # blocks that read a state on the MXU, rows that read one on the VPU
-  reads = live & ~fresh
-  wide = blk_live & (reads & (ln > 1))[blk_row]
-  sblk = jnp.nonzero(wide, size=nb, fill_value=0)[0].astype(i32)
+  pcum = lax.cumsum(blk_pages)
+  owner, m = run_write.Owner(pcum, nb * span)
+  pair_blk = lax.min(owner, i32(nb - 1))
+  held = run_write.TakeRows(cols(lax.sub(pcum, blk_pages)), pair_blk,
+                            nb * span)
+  pair_jj = lax.clamp(i32(0), lax.sub(m, col(held, 0)), i32(span - 1))
+  # blocks that read a state on the MXU (the wide ones, ascending: the j-th
+  # is the block as many of the cumulated count do not pass j), rows that
+  # read one on the VPU
+  wcum = lax.cumsum(to_i32(wide))
+  owner, j = run_write.Owner(wcum, nb)
+  sblk = lax.select(lax.lt(j, lax.broadcast(last(wcum), (nb,))), owner,
+                    zeros(nb))
   # fold entries
-  cnt = folds + (fresh & (folds == 0))
-  ecum = jnp.cumsum(cnt)
-  e = jnp.arange(ne, dtype=i32)
-  e_row = jnp.clip(jnp.searchsorted(ecum, e, side="right"), 0, b - 1
-                   ).astype(i32)
-  e_jj = jnp.clip(e - (ecum - cnt)[e_row], 0, span - 1)
+  cnt = lax.add(folds, to_i32(both(fresh, lax.eq(folds, i32(0)))))
+  ecum = lax.cumsum(cnt)
+  owner, e = run_write.Owner(ecum, ne)
+  e_row = lax.min(owner, i32(b - 1))
+  mine = run_write.TakeRows(cols(lax.sub(ecum, cnt), fresh, folds), e_row, ne)
+  e_jj = lax.clamp(i32(0), lax.sub(e, col(mine, 0)), i32(span - 1))
   return StepPlan(
-      row=row, jj=jj, off=off, j0=j0, off0=p0 % page, start=start, live=live,
-      fresh=fresh, folds=folds, blk_row=blk_row, blk_first=blk_first,
-      blk_n=blk_n, tok_at=tok_at, pair_blk=pair_blk, pair_jj=pair_jj,
-      pairs=pcum[-1].astype(i32), sblk=sblk,
-      sblks=jnp.sum(wide).astype(i32), decode=reads & (ln == 1),
-      e_row=e_row, e_jj=e_jj, e_zero=fresh[e_row] & (e_jj == 0),
-      e_add=folds[e_row] > e_jj, e_cnt=cnt.astype(i32),
-      entries=ecum[-1].astype(i32))
+      row=row, jj=jj, off=off, j0=j0, off0=lax.rem(p0, i32(page)),
+      start=start, live=live, fresh=fresh, folds=folds, blk_row=blk_row,
+      blk_first=blk_first, blk_n=blk_n, tok_at=tok_at, pair_blk=pair_blk,
+      pair_jj=pair_jj, pairs=last(pcum), sblk=sblk, sblks=last(wcum),
+      decode=both(reads, lax.eq(ln, i32(1))), e_row=e_row, e_jj=e_jj,
+      e_zero=both(lax.ne(col(mine, 1), i32(0)), lax.eq(e_jj, i32(0))),
+      e_add=lax.gt(col(mine, 2), e_jj), e_cnt=cnt, entries=last(ecum))
 
 
 def StepCounts(row_q_pos, row_len, page: int) -> tuple[int, int, int]:

@@ -370,20 +370,29 @@ def _LivePairs(n, page0, last, size: int):
   k), not lookups by `blk`: the chip runs a lookup an index at a time, and a
   `[NB, size]` compare with two sums is what `first` already costs. size:
   `NB * grid_pages`, which holds any step's pairs whatever pages its rows
-  share."""
-  live = n > 0
-  count = jnp.where(live, last - page0 + 1, 0)
-  upto = jnp.cumsum(count)
-  pairs = upto[-1]
-  before = upto - count
-  k = jnp.minimum(jnp.arange(size, dtype=jnp.int32), pairs - 1)
-  started = live[:, None] & (before[:, None] <= k[None, :])     # [NB, size]
-  offset = page0 - before
-  step = offset - jnp.concatenate([jnp.zeros((1,), jnp.int32), offset[:-1]])
-  blk = jnp.sum(started.astype(jnp.int32), axis=0) - 1
-  page = k + jnp.sum(jnp.where(started, step[:, None], 0), axis=0)
+  share. (`jax.lax` over constants of numpy, as `_BuildQueryBlocks`.)"""
+  lax, i32 = jax.lax, np.int32
+  nb = n.shape[0]
+  live = lax.gt(n, i32(0))
+  count = lax.select(live, lax.add(lax.sub(last, page0), i32(1)),
+                     np.zeros((nb,), i32))
+  upto = lax.cumsum(count)
+  pairs = lax.index_in_dim(upto, nb - 1, 0, keepdims=False)
+  before = lax.sub(upto, count)
+  k = lax.min(np.arange(size, dtype=i32),
+              lax.broadcast(lax.sub(pairs, i32(1)), (size,)))
+  over = lambda x: lax.broadcast_in_dim(x, (nb, size), (0,))     # a block's
+  started = lax.bitwise_and(
+      over(live), lax.le(over(before), lax.broadcast_in_dim(k, (nb, size),
+                                                            (1,))))
+  offset = lax.sub(page0, before)
+  step = lax.sub(offset, lax.pad(offset, i32(0), ((1, -1, 0),)))
+  blk = lax.sub(lax.reduce_sum(lax.convert_element_type(started, i32), (0,)),
+                i32(1))
+  page = lax.add(k, lax.reduce_sum(
+      lax.select(started, over(step), np.zeros((nb, size), i32)), (0,)))
   # a step with no live block: nothing runs, and the entries name block 0
-  return jnp.maximum(blk, 0), jnp.maximum(page, 0), pairs
+  return lax.max(blk, i32(0)), lax.max(page, i32(0)), pairs
 
 
 def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
@@ -396,54 +405,91 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
   over layers XLA lifts out of the loop only a part of them (the lookups;
   the `[NB, Bq, 4]` stack and its copies, 0.12 ms a layer at 73 blocks of
   512, stayed; PERF.md section 6, PR 43): `BuildAttendPlan` is called once
-  a step, before the scan."""
+  a step, before the scan.
+
+  Written in `jax.lax` over constants of numpy, as ops/run_write.
+  BuildWriteRuns is and for its reason: every serving process traces it, and
+  through `jnp` it was 71 traces a plan (PERF.md section 6, PR 51)."""
+  lax, i32 = jax.lax, np.int32
   t = row_of.shape[0]
-  idx = jnp.arange(t, dtype=jnp.int32)
-  valid = ends > 0
-  prev_valid = jnp.concatenate([jnp.zeros((1,), bool), valid[:-1]])
-  prev_row = jnp.concatenate([row_of[:1], row_of[:-1]])
-  run_start = valid & (~prev_valid | (row_of != prev_row))
-  run_first = jax.lax.cummax(jnp.where(run_start, idx, 0))
-  blk_start = valid & ((idx - run_first) % bq == 0)
-  csum = jnp.cumsum(blk_start.astype(jnp.int32))
-  blk = csum - 1                                            # [T] block id
-  n_live = csum[-1]
-  k = jnp.arange(nb, dtype=jnp.int32)
-  src = jnp.minimum(k, jnp.maximum(n_live - 1, 0))
+  both = lax.bitwise_and
+  last_of = lambda x: lax.index_in_dim(x, x.shape[0] - 1, 0, keepdims=False)
+  idx = np.arange(t, dtype=i32)
+  valid = lax.gt(ends, i32(0))
+  prev_valid = lax.pad(valid, np.bool_(False), ((1, -1, 0),))
+  prev_row = lax.concatenate(
+      [lax.slice(row_of, (0,), (1,)), lax.slice(row_of, (0,), (t - 1,))], 0)
+  run_start = both(valid, lax.bitwise_or(lax.bitwise_not(prev_valid),
+                                         lax.ne(row_of, prev_row)))
+  run_first = lax.cummax(lax.select(run_start, idx, np.zeros((t,), i32)))
+  blk_start = both(valid, lax.eq(lax.rem(lax.sub(idx, run_first), i32(bq)),
+                                 i32(0)))
+  csum = lax.cumsum(lax.convert_element_type(blk_start, i32))
+  blk = lax.sub(csum, i32(1))                               # [T] block id
+  n_live = last_of(csum)
+  k = np.arange(nb, dtype=i32)
+  src = lax.min(k, lax.broadcast(lax.max(lax.sub(n_live, i32(1)), i32(0)),
+                                 (nb,)))
   # block k starts at the first token whose running count reaches k + 1
-  first = jnp.sum((csum[None, :] <= src[:, None]).astype(jnp.int32), axis=1)
-  first = jnp.minimum(first, t - 1)
-  in_range = first[:, None] + jnp.arange(bq, dtype=jnp.int32)[None, :] < t
+  first = lax.reduce_sum(lax.convert_element_type(
+      lax.le(lax.broadcast_in_dim(csum, (nb, t), (1,)),
+             lax.broadcast_in_dim(src, (nb, t), (0,))), i32), (1,))
+  first = lax.min(first, i32(t - 1))
+  each = lambda x: lax.broadcast_in_dim(x, (nb, bq), (0,))       # a block's
+  in_range = lax.lt(
+      lax.add(each(first), np.broadcast_to(np.arange(bq, dtype=i32),
+                                           (nb, bq))), i32(t))
   # What a block's queries carry, x[first[k] + j] (past the end: x[T - 1]).
   # A block's tokens are consecutive, so it is one slice a block of the
   # tokens' values laid side by side, not a lookup a query and value: the
   # chip runs a lookup an index at a time (six of 73 x 512: 1.5 ms; the
   # slices 0.07; PERF.md section 6, PR 43)
-  per_token = jnp.stack([ends, starts, lo, hi, blk, valid.astype(jnp.int32)],
-                        axis=-1)                            # [T, 6]
-  per_token = jnp.pad(per_token, ((0, bq), (0, 0)), mode="edge")
-  of_blocks = jax.vmap(
-      lambda f: jax.lax.dynamic_slice_in_dim(per_token, f, bq))(first)
-  member = in_range & (of_blocks[..., 5] != 0) & (
-      of_blocks[..., 4] == src[:, None])
-  blk_ends = jnp.where(member, of_blocks[..., 0], 0)        # [NB, Bq]
-  cols = jnp.concatenate([blk_ends[..., None], of_blocks[..., 1:4]], axis=-1)
-  last = jnp.clip((jnp.max(blk_ends, axis=1) + page_size - 1) // page_size
-                  - 1, 0, t_pages - 1)
-  n = jnp.where(k < n_live, jnp.sum(member.astype(jnp.int32), axis=1), 0)
-  page0 = jnp.zeros_like(last)
+  per_token = lax.concatenate(
+      [lax.reshape(x, (t, 1)) for x in (
+          ends, starts, lo, hi, blk, lax.convert_element_type(valid, i32))],
+      1)                                                    # [T, 6]
+  per_token = lax.concatenate(
+      [per_token, lax.broadcast_in_dim(last_of(per_token), (bq, 6), (1,))], 0)
+  of_blocks = lax.gather(
+      per_token, lax.reshape(first, (nb, 1)),
+      lax.GatherDimensionNumbers(offset_dims=(1, 2), collapsed_slice_dims=(),
+                                 start_index_map=(0,)),
+      slice_sizes=(bq, 6), mode="clip")                     # [NB, Bq, 6]
+  part = lambda j: lax.index_in_dim(of_blocks, j, 2, keepdims=False)
+  member = both(both(in_range, lax.ne(part(5), i32(0))),
+                lax.eq(part(4), each(src)))
+  blk_ends = lax.select(member, part(0), np.zeros((nb, bq), i32))
+  cols = lax.concatenate([lax.reshape(blk_ends, (nb, bq, 1)),
+                          lax.slice_in_dim(of_blocks, 1, 4, axis=2)], 2)
+  last = lax.clamp(
+      i32(0),
+      lax.sub(lax.div(lax.add(lax.reduce_max(blk_ends, (1,)),
+                              i32(page_size - 1)), i32(page_size)), i32(1)),
+      i32(t_pages - 1))
+  n = lax.select(lax.lt(k, lax.broadcast(n_live, (nb,))),
+                 lax.reduce_sum(lax.convert_element_type(member, i32), (1,)),
+                 np.zeros((nb,), i32))
+  page0 = lax.full_like(last, 0)
   if window:
     # the block's narrowest horizon less the window: no query of the block
     # sees a slot before it
-    low = jnp.min(jnp.where(member, blk_ends, jnp.iinfo(jnp.int32).max),
-                  axis=1)
-    page0 = jnp.minimum(jnp.maximum(low - window, 0) // page_size, last)
-  col0 = cols[:, 0]                                         # [NB, 4]
+    low = lax.reduce_min(
+        lax.select(member, blk_ends,
+                   np.full((nb, bq), np.iinfo(np.int32).max, i32)), (1,))
+    page0 = lax.min(lax.div(lax.max(lax.sub(low, i32(window)), i32(0)),
+                            i32(page_size)), last)
+  col0 = lax.index_in_dim(cols, 0, 1, keepdims=False)       # [NB, 4]
   blk, page, pairs = _LivePairs(
       n, page0, last, nb * WindowPages(window, bq, page_size, t_pages))
-  return AttendPlan(row=row_of[first], last=last, page0=page0, n=n,
+  row = lax.gather(
+      row_of, lax.reshape(first, (nb, 1)),
+      lax.GatherDimensionNumbers(offset_dims=(), collapsed_slice_dims=(0,),
+                                 start_index_map=(0,)),
+      slice_sizes=(1,), mode="clip")
+  return AttendPlan(row=row, last=last, page0=page0, n=n,
                     first=first, cols=cols,
-                    col0=tuple(col0[:, c] for c in range(4)),
+                    col0=tuple(lax.index_in_dim(col0, c, 1, keepdims=False)
+                               for c in range(4)),
                     blk=blk, page=page, pairs=pairs)
 
 
@@ -488,19 +534,22 @@ def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
   handed none."""
   assert key.kernel, key
   assert (q_start is not None) == key.tree, (key, q_start is None)
-  rows = jnp.clip(jnp.asarray(row_of).astype(jnp.int32), 0, b - 1)
-  ends = jnp.asarray(q_end).astype(jnp.int32)
+  lax, i32 = jax.lax, np.int32
+  to_i32 = lambda x: lax.convert_element_type(x, i32)
+  rows = lax.clamp(i32(0), to_i32(row_of), i32(b - 1))
+  ends = to_i32(q_end)
   if q_start is None:
-    starts = jnp.zeros_like(ends)
-    lo = hi = jnp.full_like(ends, -1)
+    starts = np.zeros(ends.shape, i32)
+    lo = hi = np.full(ends.shape, -1, i32)
   else:
-    starts, lo, hi = (jnp.asarray(x).astype(jnp.int32)
-                      for x in (q_start, anc_lo, anc_hi))
+    starts, lo, hi = (to_i32(x) for x in (q_start, anc_lo, anc_hi))
   if key.lanes > 1:
     # a token's group rides the packed axis as consecutive queries of its
     # row with its horizon (RaggedAttend)
-    rows, ends, starts, lo, hi = (jnp.repeat(x, key.lanes)
-                                  for x in (rows, ends, starts, lo, hi))
+    t = ends.shape[0]
+    rows, ends, starts, lo, hi = (
+        lax.reshape(lax.broadcast_in_dim(x, (t, key.lanes), (0,)),
+                    (t * key.lanes,)) for x in (rows, ends, starts, lo, hi))
   return _BuildQueryBlocks(
       rows, ends, starts, lo, hi, bq=key.bq,
       nb=NumQueryBlocks(b, rows.shape[0], key.bq), page_size=key.page_size,

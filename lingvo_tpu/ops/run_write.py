@@ -102,7 +102,7 @@ class Runs(NamedTuple):
   runs: jnp.ndarray     # [] int32 the runs they move
 
 
-def _Take(table, index, room: int):
+def TakeRows(table, index, room: int):
   """table[index] over the leading axis, index [room] -> [room, K], an index
   past the end read as the last."""
   return jax.lax.gather(
@@ -113,7 +113,7 @@ def _Take(table, index, room: int):
       slice_sizes=(1, table.shape[1]), mode="clip")
 
 
-def _Owner(ends, room: int):
+def Owner(ends, room: int):
   """For k in [0, room): how many of the ascending `ends` are <= k, which is
   the entry that owns place k of the list the `ends` cut up; and k."""
   k = np.arange(room, dtype=np.int32)
@@ -152,10 +152,10 @@ def BuildWriteRuns(rows, b: int, t_pages: int, page_size: int) -> Runs:
                       first_page), i32(1)),
       lax.full_like(n, 0))
   cum = lax.cumsum(n_pages)
-  r, i = _Owner(cum, size)
+  r, i = Owner(cum, size)
   r = lax.min(r, i32(b - 1))
   runs = lax.min(last(cum), i32(size))
-  mine = _Take(cols(first_page, lax.sub(cum, n_pages), p0, end,
+  mine = TakeRows(cols(first_page, lax.sub(cum, n_pages), p0, end,
                     lax.convert_element_type(col(rows.row_cols, 0), i32)), r,
                size)
   first_page, before, p0, end, col0 = (col(mine, j) for j in range(5))
@@ -177,8 +177,8 @@ def BuildWriteRuns(rows, b: int, t_pages: int, page_size: int) -> Runs:
         lax.div(lax.add(length, i32(w - 1)), i32(w)),
         np.zeros((size,), np.int32))
     cum = lax.cumsum(pieces)
-    at, k = _Owner(cum, room)
-    mine = _Take(cols(r, logical, tok, off, length, lax.sub(cum, pieces)),
+    at, k = Owner(cum, room)
+    mine = TakeRows(cols(r, logical, tok, off, length, lax.sub(cum, pieces)),
                  lax.min(at, i32(size - 1)), room)
     # the run's j-th piece; the last ends where the run ends
     into = lax.min(lax.mul(lax.sub(k, col(mine, 5)), i32(w)),

@@ -476,6 +476,11 @@ class ServingLoop:
                   if self.spec is not None else 1)
     self._ragged_t = max_batch * spec_width + self.prefill_token_budget
     self._ragged_wmax = max(spec_width, self.prefill_token_budget)
+    # the width the step program's row-wise blocks run when the step's live
+    # tokens fit it (core/ragged.BuildLiveWidth); `narrow_steps` counts
+    # those steps
+    self._narrow_rows = ragged_lib.DecodeWidth(self._ragged_t,
+                                               self._ragged_wmax)
     # the token columns whose logits something reads: a draw a slot, and
     # under a draft source the slot's verify columns. The step program's
     # head runs over them alone, unless they are no fewer than the packed
@@ -1152,10 +1157,39 @@ class ServingLoop:
 
   # -- async API -------------------------------------------------------------
 
+  def _CompileStep(self):
+    """Builds THE step program here, in the thread that starts the engine,
+    from an idle step's arguments placed as _Dispatch places a real one's
+    (the program has one shape whatever a step holds). Left to its first
+    dispatch the loop's own thread would build it, beside the thread that
+    waits for it: on the benchmark's host the compile cache's fetch of it
+    took 2.0-2.4 s there against 0.4-0.5 s here (`brumby14b`), 3.6 against
+    0.9 (`nemotron3nano`): PERF.md section 6, PR 51.
+    A draft source's step takes arguments of a draft pass: built at its
+    first dispatch, as before."""
+    if self.spec is not None:
+      return
+    b, t = self.max_batch, self._ragged_t
+    desc = ragged_lib.BuildRaggedRows(
+        np.zeros((b,), np.int32), np.ones((b,), np.int32), t,
+        self._ragged_wmax, None)
+    tables = (self._kind_pages.tables if self._kind_pages is not None
+              else self.sched.block_tables)
+    # placed from the host's arrays, as _Dispatch places a step's: a
+    # `jnp.zeros` here would be one more program to compile and to fetch
+    zeros = jnp.asarray(np.zeros((b,), np.int32))
+    tok_ids = jnp.asarray(np.zeros((t,), np.int32))
+    self._compile_log.Compile(
+        "ragged", self._ragged_fn, self._theta, self._states, tok_ids,
+        ragged_lib.RaggedRows(*(jnp.asarray(m) for m in desc)),
+        jnp.asarray(np.array(tables)), zeros, zeros)
+    self._compile_log.Compile("feed", self._feed_fn, tok_ids, tok_ids)
+
   def Start(self):
     with self._lock:
       if self._running:
         return self
+      self._CompileStep()
       self._running = True
       self._thread = threading.Thread(target=self._Loop, daemon=True,
                                       name="serving-loop")
@@ -1415,6 +1449,8 @@ class ServingLoop:
     if self._in_flight:
       self._counters["steps_overlapped"].Inc()
     self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
+    if 0 < self._narrow_rows and int(row_len.sum()) <= self._narrow_rows:
+      self._counters["narrow_steps"].Inc()
     self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
     if self._attend_bq:
       # a row's queries fill whole blocks and then one that holds the rest

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.core.nested_map import NestedMap
 
@@ -583,3 +584,242 @@ class TestPrefixOrderedAdmission:
     live = [s.id for s in sched.slots if s is not None]
     assert live == ["head"]
     assert sched.prefix_ordered_admissions == 0
+
+
+# -- the width a step runs (docs/ragged_step.md, "The width a step runs") -----
+
+from tests.test_head_cols import _Calls, _FAMILIES  # noqa: E402
+
+
+def _WidthEngineCalls(family):
+  """Every "ragged" call of a small engine of `family` (f32) over a prompt
+  of 3, a prompt of 2 beside its decode row, a prompt of 30 in chunks of the
+  budget beside two decode rows, and decode rows alone: (engine, [(theta,
+  states, tok_ids, rows, tables)])."""
+  task, theta = _FAMILIES[family](jnp.float32)
+  eng = engine_lib.ServingLoop(
+      task, theta, page_size=8, num_pages=48, max_batch=4, max_seq_len=128,
+      prefill_token_budget=8)
+  calls = _Calls(eng)
+  eng.Submit([5, 9, 2], 8, eos_id=None, seed=11)
+  eng.StepOnce()
+  eng.Submit([7, 1], 8, eos_id=None, seed=12)
+  eng.StepOnce()
+  eng.Submit(list(range(1, 31)), 4, eos_id=None, seed=13)
+  for _ in range(6):
+    eng.StepOnce()
+  return task, eng, [args[:5] for args, _ in calls.calls]
+
+
+_WIDTH_CALLS = {}
+
+
+def _OnePackWidth(rows, t):
+  """The same rows in a pack that has no narrower width (`row_cols` as wide
+  as the pack, ragged.BuildLiveWidth): the step program without a
+  conditional, which is the one every step ran before."""
+  pad = t - rows.row_cols.shape[1]
+  return rows._replace(
+      row_cols=jnp.pad(rows.row_cols, ((0, 0), (0, pad))),
+      col_parent=jnp.pad(rows.col_parent, ((0, 0), (0, pad)),
+                         constant_values=-1))
+
+
+def _SlotStates(states):
+  """The leaves that are a slot's or a step's (scan and convolution states,
+  a retention state, tokens by expert), not pages of a pool: a page holds
+  slots no token of the step wrote (a whole-page write lays a padding token's
+  K and V behind a row's last one, zeros from a narrow block, the token's own
+  from a wide), which nothing reads before the row's next tokens overwrite
+  them. The pools are held to what the NEXT step reads of them."""
+  return [np.asarray(leaf.astype(jnp.float32))
+          for path, leaf in jax.tree_util.tree_flatten_with_path(states)[0]
+          if not {"key", "value", "gate"} & set(base_layer.PathKeys(path))]
+
+
+@pytest.mark.parametrize("branch", ["narrow", "wide"])
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_a_step_is_the_same_at_the_width_it_holds(family, branch):
+  """Steps an engine really dispatched, through the step program with its
+  conditionals (the engine's rows: W = max_batch, so a prompt of 3 and a
+  prompt of 2 beside a decode row take the narrow branch, a chunk of 8 the
+  wide one) and through the program without any (`_OnePackWidth`): the same
+  logits on every live column, the same new slot states, and pages from
+  which the engine's next step computes the same logits."""
+  if family not in _WIDTH_CALLS:
+    _WIDTH_CALLS[family] = _WidthEngineCalls(family)
+  task, eng, calls = _WIDTH_CALLS[family]
+  t, w = eng._ragged_t, eng._narrow_rows
+  assert w == eng.max_batch == 4
+  live = [int(np.asarray(c[3].row_len).sum()) for c in calls]
+  picked = [i for i, n in enumerate(live[:-1])
+            if (n <= w) == (branch == "narrow")]
+  # narrow: a chunk alone, a chunk beside a decode row, decode rows alone
+  assert len({tuple(np.asarray(calls[i][3].row_len).tolist())
+              for i in picked}) >= (3 if branch == "narrow" else 2), live
+  step = jax.jit(lambda th, st, ids, rows, tables: task.RaggedStep(
+      th, ids[None], st, tables, rows))
+
+  def _Live(logits, rows):
+    return np.asarray(logits[0])[np.flatnonzero(np.asarray(rows.valid))]
+
+  for i in picked:
+    theta, states, tok_ids, rows, tables = calls[i]
+    assert ragged_lib.BuildLiveWidth(rows).rows == w
+    assert ragged_lib.BuildLiveWidth(_OnePackWidth(rows, t)) is None
+    got, got_states = step(theta, states, tok_ids, rows, tables)
+    want, want_states = step(theta, states, tok_ids, _OnePackWidth(rows, t),
+                             tables)
+    np.testing.assert_allclose(_Live(got, rows), _Live(want, rows),
+                               atol=1e-5, rtol=1e-5)
+    for a, b in zip(_SlotStates(got_states), _SlotStates(want_states)):
+      np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+    _, _, next_ids, next_rows, next_tables = calls[i + 1]
+    np.testing.assert_allclose(
+        _Live(step(theta, got_states, next_ids, next_rows, next_tables)[0],
+              next_rows),
+        _Live(step(theta, want_states, next_ids, next_rows, next_tables)[0],
+              next_rows), atol=1e-5, rtol=1e-5)
+
+
+def _Conds(jaxpr, out=None):
+  """Every `cond` equation of a jaxpr and of the jaxprs inside it."""
+  out = [] if out is None else out
+  for eqn in jaxpr.eqns:
+    if eqn.primitive.name == "cond":
+      out.append(eqn)
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+      _Conds(sub, out)
+  return out
+
+
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_a_whole_sequence_forward_traces_no_conditional(family):
+  """`FProp` hands no plan down, so `ragged.OverLiveRows` is its function and
+  nothing else: training traces what it traced."""
+  task, theta = _FAMILIES[family](jnp.float32)
+  x = jnp.zeros((2, 8, task.p.model_dim), jnp.float32)
+  jaxpr = jax.make_jaxpr(lambda th, x: task.stack.FProp(th, x))(
+      theta.stack, x)
+  assert not _Conds(jaxpr.jaxpr)
+
+
+# The whole-sequence forward of each family's stack (2 x 8 tokens, f32; the
+# dense family's, which the train cells run, with its gradient) as the PARENT
+# of PR 51 (`6246f70`) traces it under JAX 0.9.0: equations, sub-jaxprs
+# included, and the first 16 hex digits of the sha256 of the jaxpr's text.
+# The test below is the recipe: run it on a parent's tree to take a number
+# again.
+_PARENT_FORWARD = {
+    "dense": (453, "0127a45b397c9af1"),
+    "smallthinker": (716, "de7d6e176f8724fe"),
+    "phi4flash": (831, "e7e44788e2b4d76b"),
+    "nemotron_h": (795, "a25d30d52123d314"),
+    "brumby": (153, "c3fea7069c9406d6"),
+}
+
+
+def _Equations(jaxpr) -> int:
+  return sum(1 + sum(_Equations(sub)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+             for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_training_traces_the_parents_jaxpr(family):
+  """What a train step traces of the stack is what the parent traced, to the
+  letter: the serving step's halves (`RaggedMix` / `RaggedOut`, `_Export` /
+  `_Finish`), the stack slices `CastTheta` takes and the plans' rewrites
+  leave `FProp` and its gradient alone."""
+  import hashlib
+  task, theta = _FAMILIES[family](jnp.float32)
+  x = jnp.zeros((2, 8, task.p.model_dim), jnp.float32)
+  forward = lambda th, x: task.stack.FProp(th, x)
+  fn = (jax.value_and_grad(lambda th, x: jnp.sum(forward(th, x)))
+        if family == "dense" else forward)
+  jaxpr = jax.make_jaxpr(fn)(theta.stack, x)
+  equations, digest = _PARENT_FORWARD[family]
+  assert _Equations(jaxpr.jaxpr) == equations
+  if jax.__version__ == "0.9.0":      # the text is that version's
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] == digest
+
+
+def test_a_paged_step_traces_no_conditional(tiny_lm):
+  task, theta = tiny_lm
+  states = task.InitPagedDecodeState(theta, 9, 4, 3)
+  jaxpr = jax.make_jaxpr(lambda th, st: task.PagedStep(
+      th, jnp.zeros((3, 1), jnp.int32), st, jnp.zeros((3, 4), jnp.int32),
+      jnp.zeros((3,), jnp.int32), jnp.ones((3,), jnp.int32)))(theta, states)
+  assert not _Conds(jaxpr.jaxpr)
+
+
+def test_the_serving_step_branches_once_a_layer_on_whole_stacks():
+  """A scanned dense stack's step: ONE conditional in the traced body (the
+  residual and the feed-forward), and it takes no layer's SLICE of a weight
+  as an operand: the feed-forward's stacks enter whole and are sliced inside
+  the branch (base_layer.StackSlice), where the slice fuses into the product
+  that reads it. The attention's own `[D, N, H]` projections are re-laid a
+  layer at a time (`relaid_weights`): they run outside the conditional, on
+  slices taken as a scan takes them, and no conditional sees them."""
+  from tests.conftest import InstantiateLm, TinyLmParams
+  task, theta = InstantiateLm(TinyLmParams(use_repeat_layer=True,
+                                           num_layers=3))
+  states = task.InitPagedDecodeState(theta, 9, 4, 3)
+  rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in
+                                 ragged_lib.BuildRaggedRows(
+                                     [1, 2, 0], [4, 0, 1], 11, 8)))
+  jaxpr = jax.make_jaxpr(lambda th, st: task.RaggedStep(
+      th, jnp.zeros((1, 11), jnp.int32), st, jnp.zeros((3, 8), jnp.int32),
+      rows))(theta, states)
+  (cond,) = _Conds(jaxpr.jaxpr)
+  body = theta.stack.body
+  ff_stacks = {leaf.shape for leaf in jax.tree_util.tree_leaves(body.fflayer)
+               if leaf.ndim >= 3}
+  atten = {leaf.shape[1:] for leaf in jax.tree_util.tree_leaves(
+      body.self_atten.atten) if leaf.ndim >= 3}
+  operands = {v.aval.shape for v in cond.invars}
+  assert ff_stacks and ff_stacks <= operands
+  assert not {shape[1:] for shape in ff_stacks} & operands
+  assert atten and not atten & operands
+
+
+# conditionals a layer (docs/ragged_step.md's table): what precedes a mixer's
+# kernel where that is row-wise and its weights are not re-laid, and one more
+# for what follows it (the output projection and the residual) WITH a dense
+# feed-forward; a layer that is its mixer alone branches nothing behind it
+_MIXER_CONDS = {"Mamba1Layer": 1, "Mamba2Layer": 1,
+                "DifferentialAttention": 1, "GatedMemoryUnit": 0,
+                "PooledAttention": 0, "PowerRetention": 0,
+                "MultiHeadedAttention": 0}
+
+
+def _LayerConds(mixer, dense: bool) -> int:
+  before = _MIXER_CONDS[type(mixer).__name__] if mixer else 0
+  return before + (1 if dense else 0)
+
+
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_conditionals_a_traced_step_by_stack(family):
+  """The step's conditionals are what its traced layer bodies ask for, by
+  the table: 1 in the tiny dense stack's one body, none beside experts, the
+  Phi-4-flash and Nemotron siblings' by their blocks' lists, 1 in a retention
+  layer's."""
+  task, _, calls = (_WIDTH_CALLS.get(family)
+                    or _WIDTH_CALLS.setdefault(family,
+                                               _WidthEngineCalls(family)))
+  theta, states, tok_ids, rows, tables = calls[0]
+  jaxpr = jax.make_jaxpr(lambda th, st: task.RaggedStep(
+      th, tok_ids[None], st, tables, rows))(theta, states)
+  stack = task.stack
+  if hasattr(stack, "_bodies"):
+    want = sum(_LayerConds(l.mixer, hasattr(l, "fflayer")
+                           and not l._experts)
+               for layers in stack._bodies for l in layers)
+  else:
+    layers = getattr(stack.body, "x_layers", [stack.body])
+    want = sum(_LayerConds(l.self_atten.atten,
+                           not hasattr(l.fflayer, "RouterLogits"))
+               for l in layers)
+  assert want == {"dense": 1, "smallthinker": 0, "brumby": 1}.get(family,
+                                                                  want)
+  assert len(_Conds(jaxpr.jaxpr)) == want

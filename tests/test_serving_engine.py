@@ -483,6 +483,103 @@ class TestServingEngine:
         "pallas" if jax.default_backend() == "tpu" else "xla")
     assert stats["dense_fallback_steps"] == 0
 
+  @pytest.mark.parametrize("family", ["dense", "nemotron_h", "brumby"])
+  def test_start_builds_the_step_program_in_its_callers_thread(self, family):
+    """`Start()` builds the step program and the feed before the loop's
+    thread exists (the loop's own thread built them at its first dispatch,
+    beside the thread that waits for it: PERF.md section 6, PR 51), from an
+    idle step's arguments; the steps that follow call that executable: no
+    second build, no fall-back to the jitted function."""
+    import threading
+    import time
+    from tests.test_head_cols import _FAMILIES
+    task, theta = _FAMILIES[family](jnp.float32)
+    eng = engine_lib.ServingLoop(
+        task, theta, page_size=8, num_pages=48, max_batch=4, max_seq_len=128,
+        prefill_token_budget=8)
+    built = []
+    inner = eng._compile_log._Compile
+    eng._compile_log._Compile = lambda name, fn, args: (
+        built.append((name, threading.current_thread().name)),
+        inner(name, fn, args))[1]
+    assert eng.Stats()["compile"]["step_programs"] == 0
+    eng.Start()
+    try:
+      me = threading.current_thread().name
+      assert built == [("ragged", me), ("feed", me)]
+      assert eng.Stats()["compile"]["step_programs"] == 1
+      handle = eng.Submit(list(range(1, 12)), 6, eos_id=None, seed=3)
+      deadline = time.monotonic() + 120
+      while not handle.done and time.monotonic() < deadline:
+        time.sleep(0.005)
+      assert handle.done
+    finally:
+      eng.Stop()
+    records = eng.Stats()["compile"]
+    assert built == [("ragged", me), ("feed", me)]
+    assert records["step_programs"] == 1
+    for name in ("ragged", "feed"):
+      assert "fallback" not in records[name], records[name]
+      assert records[name]["calls"] > 0
+
+  def test_narrow_steps_counts_the_steps_whose_tokens_fit(self, tiny_lm):
+    """`narrow_steps` (docs/observability.md) is the count of dispatched steps
+    with sum(row_len) <= W = max_batch, whatever kind of step: here
+    decode-only steps, a prompt of 2 beside a decode row (a chunk that fits)
+    and a prompt of 30 in chunks of 8 (which do not). One step program, and
+    what it costs to trace stays under a stated ceiling."""
+    from jax._src import monitoring
+    task, theta = tiny_lm
+    events = []
+
+    def _OnDuration(event, secs, **_):
+      del secs
+      if event.startswith("/jax/core/compile/"):
+        events.append(event)
+
+    eng = engine_lib.ServingLoop(
+        task, theta, page_size=8, num_pages=48, max_batch=4, max_seq_len=128,
+        prefill_token_budget=8)
+    mixes = []
+    inner = eng._compile_log.Call
+
+    def _Call(name, fn, *args):
+      if name == "ragged":
+        mixes.append(tuple(np.asarray(args[3].row_len).tolist()))
+      return inner(name, fn, *args)
+
+    eng._compile_log.Call = _Call
+    monitoring.register_event_duration_secs_listener(_OnDuration)
+    try:
+      eng.Submit([5, 9, 2], 12, eos_id=None, seed=11)
+      eng.StepOnce()                    # the step program is built here
+    finally:
+      monitoring.unregister_event_duration_listener(_OnDuration)
+    eng.StepOnce()
+    eng.Submit([7, 1], 8, eos_id=None, seed=12)
+    eng.StepOnce()
+    eng.Submit(list(range(1, 31)), 4, eos_id=None, seed=13)
+    while eng.sched.HasWork():
+      eng.StepOnce()
+    stats = observe_schema.ValidateEngineStats(eng.Stats())
+    w = 4
+    assert (1, 2, 0, 0) in mixes                       # a chunk that fits
+    assert any(max(m) == 8 for m in mixes)             # one that does not
+    assert any(set(m) <= {0, 1} and sum(m) for m in mixes)  # decode only
+    narrow = sum(sum(m) <= w for m in mixes)
+    assert 0 < narrow < len(mixes) == stats["steps"]
+    assert stats["narrow_steps"] == narrow
+    assert eng.metrics.Snapshot()["serving/narrow_steps"] == narrow
+    # `decode_steps` asks another question: the chunk that fits is mixed
+    assert stats["narrow_steps"] > stats["decode_steps"]
+    assert stats["compile"]["step_programs"] == 1
+    # every trace, lowering and compile of the first step (a nested `jnp`
+    # call at new shapes is an event): the conditionals trace the row-wise
+    # blocks at two widths, which this count sees. 299 at PR 50 (246 before
+    # it; 475 while a stack's slice was taken through `jnp` operators): a PR
+    # that passes the ceiling has made every serve cell's set-up longer.
+    assert 0 < len(events) <= 320, len(events)
+
   def test_page_reuse_across_batches_stays_identical(self, tiny_lm):
     """A second RunBatch on the same engine decodes into recycled pages;
     outputs must not change."""
