@@ -167,6 +167,30 @@ class PerDimScaleLayer(base_layer.BaseLayer):
         inputs.dtype)
 
 
+def KvPagePool(num_pages: int, page_size: int, num_kv_heads: int,
+               dim_per_head: int, dtype, quantized: bool = False) -> NestedMap:
+  """The leaves of a pool of K/V pages: `key` and `value` [num_pages,
+  page_size, KV heads, H] and, quantized, their f32 scale sidecars
+  [num_pages, KV heads, page_size] (transposed so that the Pallas scale
+  block's minor dimension is page_size: lingvo_tpu/quant/kv.py). Every mixer
+  that keeps K and V in pages declares them here."""
+  n, h = num_kv_heads, dim_per_head
+  pool = NestedMap(key=jnp.zeros((num_pages, page_size, n, h), dtype),
+                   value=jnp.zeros((num_pages, page_size, n, h), dtype))
+  if quantized:
+    pool.key_scale = jnp.zeros((num_pages, n, page_size), jnp.float32)
+    pool.value_scale = jnp.zeros((num_pages, n, page_size), jnp.float32)
+  return pool
+
+
+def RequireFloatPages(kv_cache_dtype, reader: str) -> None:
+  """A page-owning mixer of `transformer.BlockSequence` whose `reader` takes
+  float pages refuses any other: its pool is in the fprop dtype."""
+  if kv_cache_dtype not in (None, "bfloat16"):
+    raise NotImplementedError(
+        f"kv_cache_dtype {kv_cache_dtype!r}: {reader} reads float pages")
+
+
 class MultiHeadedAttention(base_layer.BaseLayer):
   """Dot-product multi-headed attention (ref `batch_major_attention.py:481`).
 
@@ -742,13 +766,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       raise NotImplementedError(
           f"int8 KV pages under num_kv_heads={n} of {self.p.num_heads} "
           "heads: the grouped ragged kernel reads float pages")
-    states = NestedMap(
-        key=jnp.zeros((num_pages, page_size, n, h), dtype),
-        value=jnp.zeros((num_pages, page_size, n, h), dtype))
-    if quantized:
-      states.key_scale = jnp.zeros((num_pages, n, page_size), jnp.float32)
-      states.value_scale = jnp.zeros((num_pages, n, page_size), jnp.float32)
-    return states
+    return KvPagePool(num_pages, page_size, n, h, dtype, quantized)
 
   def RaggedQueryBlock(self, page_size: int, kv_cache_dtype=None) -> int:
     """Queries of one row that the ragged kernel runs against a page
@@ -1323,6 +1341,15 @@ class PooledAttention(MultiHeadedAttention):
     del theta, num_slots
     return NestedMap()
 
+  def PagePool(self, num_pages: int, page_size: int,
+               kv_cache_dtype=None) -> NestedMap:
+    """The leaves of the pool this layer's pages are of (what
+    `transformer.BlockSequence` asks of a mixer that owns pages): the base
+    class's K and V, in the fprop dtype."""
+    RequireFloatPages(kv_cache_dtype, "PooledAttention in a BlockSequence")
+    return KvPagePool(num_pages, page_size, self._num_kv_heads,
+                      self._dim_per_head, self.fprop_dtype)
+
   def RaggedMix(self, theta, x, states, shared, rows, table=None, depth=0,
                 plan=None):
     """x: [1, T, D] packed tokens; table: [B, t_pages], this layer's own
@@ -1533,6 +1560,14 @@ class DifferentialAttention(base_layer.BaseLayer):
     """Nothing a slot: its pages are the stack's one pool's."""
     del theta, num_slots
     return NestedMap()
+
+  def PagePool(self, num_pages: int, page_size: int,
+               kv_cache_dtype=None) -> NestedMap:
+    """The leaves of the pool an owner's pages are of
+    (PooledAttention.PagePool): K and V heads of H, as projected."""
+    RequireFloatPages(kv_cache_dtype, "the differential attend kernel")
+    return KvPagePool(num_pages, page_size, self.p.num_kv_heads, self._h,
+                      self.fprop_dtype)
 
   def RaggedPlanKey(self, pool):
     """The ops/ragged_block_attend.PlanKey of this layer's RaggedStep over

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from lingvo_tpu import observe
+from lingvo_tpu.core import attention as attention_lib
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import layers as layers_lib
 from lingvo_tpu.core import ragged
@@ -58,7 +59,6 @@ class PowerRetention(base_layer.BaseLayer):
   # table, and writes them itself (the step's plan carries no page write)
   kv_owner = True
   writes_by_plan = False
-  gated_pages = True      # the pool keeps a `gate` leaf beside K and V
   relaid_weights = True   # [D, N, H] projections: as MultiHeadedAttention's
   # the slot-state leaves a scanned block hands over whole, with the repeat's
   # index (`layer`): sliced a trip they would be copied whole a trip
@@ -200,6 +200,17 @@ class PowerRetention(base_layer.BaseLayer):
     assert num_slots > 0, "PowerRetention keeps a state a slot"
     state, norm = op.InitState(num_slots, self._nk, self._h)
     return NestedMap(state=state, norm=norm)
+
+  def PagePool(self, num_pages: int, page_size: int,
+               kv_cache_dtype=None) -> NestedMap:
+    """The leaves of the pool this layer's pages are of (what
+    `transformer.BlockSequence` asks of a mixer that owns pages): K and V,
+    and beside them the cumulated log-gate a (KV head, token)."""
+    attention_lib.RequireFloatPages(kv_cache_dtype, "the retention kernels")
+    pool = attention_lib.KvPagePool(num_pages, page_size, self._nk, self._h,
+                                    self.fprop_dtype)
+    pool.gate = jnp.zeros((num_pages, self._nk, page_size), jnp.float32)
+    return pool
 
   def RaggedMix(self, theta, x, states, shared, rows, table=None, depth=None,
                 plan=None, layer=None):

@@ -531,6 +531,11 @@ class StackedTransformerLayers(base_layer.BaseLayer):
       x = self.final_ln.FProp(theta.final_ln, x)
     return x, new_states
 
+  def MixerLayers(self):
+    """[(mixer, how many layers of the stack are it)], in stack order: what
+    the serving census counts and prices (serving/kv_cache.StackCensus)."""
+    return [(l.self_atten.atten, 1) for l in self.x_layers]
+
   def PageWindows(self):
     """None, or where this block's layers are attention layers of two
     kinds, full and sliding-window: each layer's window (0 = full). Such a
@@ -770,6 +775,18 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
                                    (theta.body, cached_states.body))
     return out, NestedMap(body=new_states)
 
+  def MixerLayers(self):
+    """The body's mixers, each once a repeat (StackedTransformerLayers.
+    MixerLayers). A body that serves nothing (gshard.DenseMoEBlock, which
+    trains only) has none."""
+    if isinstance(self.body, StackedTransformerLayers):
+      inner = self.body.MixerLayers()
+    elif isinstance(self.body, TransformerLayer):
+      inner = [(self.body.self_atten.atten, 1)]
+    else:
+      inner = []
+    return [(m, reps * self.p.num_layers) for m, reps in inner]
+
   def PageWindows(self):
     """The body's (StackedTransformerLayers.PageWindows), or None."""
     return getattr(self.body, "PageWindows", lambda: None)()
@@ -861,7 +878,9 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
     through untouched (pytree matches PagedStep's)."""
     body_depth = (len(self.body.x_layers)
                   if hasattr(self.body, "x_layers") else 1)
-    assert num_layers % body_depth == 0, (num_layers, body_depth)
+    assert num_layers % body_depth == 0, (
+        f"an early-exit draft of num_layers={num_layers} layers: not a "
+        f"multiple of the scanned repeat body's depth ({body_depth})")
     reps = num_layers // body_depth
     assert 1 <= reps <= self.p.num_layers, (reps, self.p.num_layers)
     prefix_theta = jax.tree_util.tree_map(lambda t: t[:reps], theta.body)
@@ -1113,7 +1132,7 @@ class BlockSequence(base_layer.BaseLayer):
 
   def MixerLayers(self):
     """[(mixer, how many layers of the stack are it)] for the mixers that
-    keep decode state, pages or a slot's (serving/spec_decode.MixerLayers)."""
+    keep decode state, pages or a slot's (serving/kv_cache.StackCensus)."""
     return [(m, reps) for m, reps in self._Mixers()
             if hasattr(m, "StateBytesPerSlot")
             or hasattr(m, "KvBytesPerToken")]
@@ -1228,24 +1247,18 @@ class BlockSequence(base_layer.BaseLayer):
 
   def InitPagedStates(self, theta, num_pages, page_size, num_slots=0,
                       kv_cache_dtype=None):
-    if kv_cache_dtype not in (None, "bfloat16"):
-      raise NotImplementedError(
-          f"kv_cache_dtype {kv_cache_dtype!r}: the differential attend "
-          "kernel reads float pages")
+    # the pool's leaves are the owning mixers' to declare (`PagePool`); the
+    # stack keeps ONE pool, so they have to declare the same
     owners = [m for m, _ in self._Mixers() if getattr(m, "kv_owner", False)]
     assert owners, "BlockSequence serves a stack in which some layer owns pages"
-    shapes = {(a.p.num_kv_heads, a._h) for a in owners}
-    assert len(shapes) == 1, f"one pool, one page shape: {shapes}"
-    (nk, h), = shapes
-    dtype = owners[0].fprop_dtype
-    states = NestedMap(kv_pool=NestedMap(
-        key=jnp.zeros((num_pages, page_size, nk, h), dtype),
-        value=jnp.zeros((num_pages, page_size, nk, h), dtype)))
-    if any(getattr(a, "gated_pages", False) for a in owners):
-      # a retention layer's pages hold a cumulated log-gate a (KV head,
-      # token) beside K and V (core/retention.PowerRetention)
-      states.kv_pool.gate = jnp.zeros((num_pages, nk, page_size),
-                                      jnp.float32)
+    pool = owners[0].PagePool(num_pages, page_size, kv_cache_dtype)
+    declared = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), pool)
+    for m in owners[1:]:
+      other = jax.eval_shape(
+          lambda m=m: m.PagePool(num_pages, page_size, kv_cache_dtype))
+      assert other == declared, f"one pool, one page: {other} / {declared}"
+    states = NestedMap(kv_pool=pool)
     states.blocks = []
     for b, layers in enumerate(self._bodies):
       def _One(theta_i, layers=layers):

@@ -81,51 +81,3 @@ def KvBytesPerToken(num_heads: int, dim_per_head: int, kv_cache_dtype,
   if quantized:
     per += 2 * num_heads * 4
   return per
-
-
-def StackKvCensus(task, kv_cache_dtype=None):
-  """Walk a TransformerLm-shaped task's stack -> KV telemetry dict.
-
-  Duck-types the same three stack shapes the serving engine walks
-  (Stacked x_layers / Repeated body / Repeated-of-Stacked) and sums
-  repetitions x per-layer `KvBytesPerToken()`. SSM mixers keep O(1) state
-  slots, not KV, so they contribute zero here (int8 state slots are a
-  documented follow-on). Returns None when the task has no recognizable
-  stack (e.g. non-LM tasks in GShardDecode).
-  """
-  stack = getattr(task, "stack", None)
-  if stack is None:
-    return None
-  if hasattr(stack, "MixerLayers"):
-    # a stack that lists its own mixers (transformer.BlockSequence)
-    return _Census([(m, reps) for m, reps in stack.MixerLayers()
-                    if hasattr(m, "KvBytesPerToken")], kv_cache_dtype)
-  layers = []
-  if hasattr(stack, "x_layers"):
-    layers = [(l, 1) for l in stack.x_layers]
-  elif hasattr(stack, "body"):
-    reps = int(getattr(stack.p, "num_layers", 1) or 1)
-    body = stack.body
-    if hasattr(body, "x_layers"):
-      layers = [(l, reps) for l in body.x_layers]
-    else:
-      layers = [(body, reps)]
-  attens = []
-  for layer, reps in layers:
-    atten = getattr(getattr(layer, "self_atten", None), "atten", None)
-    if atten is not None and hasattr(atten, "KvBytesPerToken"):
-      attens.append((atten, reps))
-  return _Census(attens, kv_cache_dtype)
-
-
-def _Census(attens, kv_cache_dtype):
-  """StackKvCensus's dict from [(attention layer, repeats)]."""
-  if not attens:
-    return {"kv_cache_dtype": None, "kv_bytes_per_token": 0,
-            "attention_layers": 0}
-  total = sum(reps * a.KvBytesPerToken(kv_cache_dtype) for a, reps in attens)
-  return {
-      "kv_cache_dtype": attens[0][0].KvCacheDtype(kv_cache_dtype),
-      "kv_bytes_per_token": int(total),
-      "attention_layers": int(sum(reps for _, reps in attens)),
-  }

@@ -43,7 +43,7 @@ from lingvo_tpu.core import py_utils
 from lingvo_tpu.core import sampling
 from lingvo_tpu.core.nested_map import NestedMap
 from lingvo_tpu.observe import schema as observe_schema
-from lingvo_tpu.quant import kv as kv_quant
+from lingvo_tpu.serving import kv_cache
 
 # Decode-program shape buckets (slots, ascending). Lengths beyond the last
 # bucket run at their exact size (a compile per distinct length).
@@ -280,7 +280,7 @@ class GShardDecode:
     # KV-cache telemetry: the same visibility contract the serving engine's
     # Stats() carries — a quantized (or non-default-dtype) cache is never
     # silent. Non-LM tasks without a recognizable stack report None/0.
-    census = kv_quant.StackKvCensus(self._task) or {}
+    census = kv_cache.StackCensus(self._task) or {}
     observe_schema.PublishTelemetry(self.metrics, observe_schema.GShardTelemetry(
         prefill_s=t1 - t0,
         decode_s=decode_s,

@@ -1,12 +1,17 @@
 """ServingLoop: the continuous-batching serving engine driver.
 
-Glues the four layers below into a running service:
+Glues the layers below into a running service:
 
     ops/ragged_block_attend.py   the packed-token paged attention kernel
-    ops/block_decode.py          the [B, C]-shape paged attention kernels
-                                 (draft sources and GatedSSMLayer only)
-    serving/kv_cache.py          host-side page ownership
+    serving/kv_cache.py          host-side page and slot ownership, and the
+                                 census of the stack that prices them
+    serving/state_layout.py      which leaves of the decode state are pages
+                                 or a slot's; the gather / scatter over them
     serving/scheduler.py         admission / step building / retirement
+
+(ops/block_decode.py, the [B, C]-shape paged attention kernels, is not among
+them: no engine step calls it. The draft sources' PagedStep and GatedSSMLayer
+do: serving/spec_decode.py.)
 
 Device-side there is ONE compiled step program: every serving iteration
 packs its work onto a single static [T] token axis (core/ragged.py) — a
@@ -60,7 +65,7 @@ are replayable no matter which slot or batch neighbors the scheduler
 picked.
 
 O(1)-state mixers (core/ssm.py): stacks whose mixers carry fixed-size
-recurrent state instead of KV pages plug in unchanged — their PagedStep
+recurrent state instead of KV pages plug in unchanged — their decode
 state is a [max_batch, ...] per-slot array reset device-side on each
 sequence's first chunk (q_pos == 0). The engine takes a mixer census at
 construction: hybrid stacks price both resources, and pure-SSM stacks
@@ -96,12 +101,12 @@ from lingvo_tpu.observe import trace as observe_trace
 from lingvo_tpu.ops import power_retention
 from lingvo_tpu.ops import ragged_block_attend
 from lingvo_tpu.ops import run_write
-from lingvo_tpu.quant import kv as kv_quant
 from lingvo_tpu.quant import weights as quant_weights
 from lingvo_tpu.serving import kv_cache
 from lingvo_tpu.serving import prefix_cache as prefix_cache_lib
 from lingvo_tpu.serving import scheduler as scheduler_lib
 from lingvo_tpu.serving import spec_decode
+from lingvo_tpu.serving import state_layout
 
 _END = object()   # stream sentinel
 
@@ -284,6 +289,8 @@ class ServingLoop:
                scheduler_mode: str = "fifo",
                tenant_quotas=None, tenant_weights=None):
     """task: a TransformerLm-style task exposing InitPagedDecodeState /
+    RaggedStep, whose `stack` answers MixerLayers() (docs/serving_engine.md,
+    "What the engine asks of a stack"); a draft source also runs its
     PagedStep. num_pages: allocator-owned pages (the device pool gets one
     extra trash page). max_seq_len: static per-sequence capacity bound
     (block-table width = ceil(max_seq_len / page_size)).
@@ -332,10 +339,10 @@ class ServingLoop:
     path) or 'priority' — SLO classes, per-tenant quotas, weighted-fair
     admission, and preemption by KV page spill to a host tier
     (serving/scheduler.py module docstring). The engine supplies the
-    device halves: jitted whole-page gather/scatter over every paged
-    leaf (spilled KV round-trips bitwise, int8 scale sidecars ride
-    along) and slot-row gather/scatter over every O(1)-mixer state
-    leaf. tenant_quotas: {tenant: (rate, burst) | TokenBucket} token-
+    device halves: serving/state_layout.py's jitted gather/scatter over
+    every paged leaf by whole pages (spilled KV round-trips bitwise, int8
+    scale sidecars ride along) and over every O(1)-mixer state leaf by
+    slot. tenant_quotas: {tenant: (rate, burst) | TokenBucket} token-
     rate quotas enforced at Submit (QuotaExceeded before a handle is
     created). tenant_weights: {tenant: weight} for weighted-fair
     admission within a priority class.
@@ -356,14 +363,18 @@ class ServingLoop:
     self.temperature = float(temperature)
     self.top_k = int(top_k)
     self.sample_seed = int(sample_seed)
-    # KV census BEFORE allocating: the effective cache dtype prices a page
-    kv_census = kv_quant.StackKvCensus(task, kv_cache_dtype) or {}
-    self.kv_cache_dtype = kv_census.get("kv_cache_dtype")
-    self.kv_bytes_per_token = kv_census.get("kv_bytes_per_token", 0)
+    # census BEFORE allocating: which resource(s) this stack's decode state
+    # occupies, and the effective cache dtype, which prices a page
+    census = kv_cache.StackCensus(task, kv_cache_dtype)
+    self._mixer_layers = task.stack.MixerLayers()
+    self._page_readers = [m for m, _ in self._mixer_layers
+                          if kv_cache.ReadsPages(m)]
+    self.kv_cache_dtype = census["kv_cache_dtype"]
+    self.kv_bytes_per_token = census["kv_bytes_per_token"]
     self._kv_quantized = self.kv_cache_dtype == "int8"
     self._kv_override = kv_cache_dtype
-    # mixer census: which resource(s) this stack's decode state occupies
-    self.mixers = self._MixerCensus()
+    self.mixers = {k: census[k] for k in (
+        "num_attention", "num_ssm", "decode_state_bytes_per_slot")}
     # unified ragged step geometry (the widest row a step admits also sizes
     # what a row of the window kind holds): see below
     self.prefill_token_budget = int(prefill_token_budget or prefill_chunk)
@@ -434,7 +445,10 @@ class ServingLoop:
       if self.state_pool is not None:
         self.sched.state_spill_fn = self._SpillStateRow
         self.sched.state_restore_fn = self._RestoreStateRow
-    self._slot_io_fns = None   # lazy (gather, scatter) jits over slot leaves
+    # which leaves of the decode state are pages or a slot's, and the
+    # gather / scatter over them: built on first use (_Layout); an engine
+    # with no prefix cache, no draft tree and FIFO admission never is
+    self._layout = None
     # pool page num_pages (the +1) is the trash page padding writes hit;
     # num_slots sizes the per-slot O(1) mixer states (attention ignores it);
     # the kv dtype override is a static string arg (hashable)
@@ -443,13 +457,6 @@ class ServingLoop:
                            max_batch, kv_cache_dtype)
     # donate the pool into each step off-cpu (XLA:CPU can't alias + warns)
     donate = (1,) if jax.default_backend() != "cpu" else ()
-    # copy-on-write executor: one jitted page copy across every page-pool
-    # leaf of the decode state (compiled once; src/dst are traced scalars)
-    self._cow_fn = (self._BuildCowFn(task, theta, kv_cache_dtype)
-                    if self.prefix_cache is not None else None)
-    # fleet page handoff (AdoptPrefix): jitted page gather/scatter pair,
-    # built lazily — most engines never donate or adopt a prefix
-    self._page_io_fns = None
     # observability (observe/): per-engine metrics registry, per-request
     # lifecycle trace, and one-shot compile records for the step programs
     self.metrics = (metrics_registry if metrics_registry is not None
@@ -488,12 +495,6 @@ class ServingLoop:
     self.head_rows = min(
         max_batch * (1 + (spec_width if self.spec is not None else 0)),
         self._ragged_t)
-    # tree KV repair needs each paged leaf's (page, token-offset) axes;
-    # chain engines never repair (accepted prefixes are already in place)
-    self._kv_leaf_axes = None
-    if (self.spec is not None and self.spec.w > 1
-        and self.mixers["num_attention"] > 0):
-      self._kv_leaf_axes = self._PagedLeafAxes(task, theta, kv_cache_dtype)
     self._ragged_fn = self._BuildRaggedFn(task, donate)
     self._feed_fn = jax.jit(_FeedTokens)
     # dispatched steps whose tokens are still on the device, oldest first:
@@ -509,8 +510,8 @@ class ServingLoop:
     # attention layer); only the block-fill counters read it
     # (the ragged attend kernels': a mixer that reads its pages through
     # kernels of its own, core/retention.PowerRetention, has none)
-    attens = [a for a in self._AttentionLayers()
-              if hasattr(a, "RaggedQueryBlock")]
+    attens = [m for m in self._page_readers
+              if hasattr(m, "RaggedQueryBlock")]
     self._attend_bq = (attens[0].RaggedQueryBlock(page_size, kv_cache_dtype)
                        if attens else 0)
     # a token is (laid, own) of the kernel's queries where a KV head serves
@@ -539,13 +540,13 @@ class ServingLoop:
     self._table_pages = table_pages
     # some layer writes its pages by the step's runs (ops/run_write.py)
     self._kv_write_by_runs = any(
-        getattr(m, "writes_by_runs", False) for m, _ in self._MixerLayers())
+        getattr(m, "writes_by_runs", False) for m, _ in self._mixer_layers)
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
     # power-retention layers: mixers that hold pages AND a slot state
     self._retention_layers = sum(
-        reps for m, reps in self._MixerLayers()
+        reps for m, reps in self._mixer_layers
         if hasattr(m, "StateBytesPerSlot") and hasattr(m, "KvBytesPerToken"))
     # layers that read pages another layer owns (0: the stack has none)
     self._shared_kv_read_layers = getattr(
@@ -613,21 +614,18 @@ class ServingLoop:
 
   # -- path classification ---------------------------------------------------
 
-  def _MixerLayers(self):
-    """[(mixer_layer, multiplicity)] — see spec_decode.MixerLayers."""
-    return spec_decode.MixerLayers(self._task)
-
-  def _AttentionLayers(self) -> list:
-    """The stack's attention mixers (those that read the page pool)."""
-    return [m for m, _ in self._MixerLayers() if spec_decode.ReadsPages(m)]
-
-  def _MixerCensus(self) -> dict:
-    """Attention vs O(1)-state census — see spec_decode.MixerCensus."""
-    return spec_decode.MixerCensus(self._task)
+  def _Layout(self) -> state_layout.StateLayout:
+    """The decode state's layout (serving/state_layout.py), detected the
+    first time something moves state by page, by token or by slot."""
+    if self._layout is None:
+      self._layout = state_layout.Detect(
+          self._task, self._theta, self.num_pages + 1, self.page_size,
+          self.max_batch, self._kv_override)
+    return self._layout
 
   def _ClassifyPath(self) -> str:
-    """'pallas[-int8]' | 'xla[-int8]' | 'dense' | 'ssm' — what PagedStep
-    lowers to.
+    """'pallas[-int8]' | 'xla[-int8]' | 'dense' | 'ssm' — what the step
+    program's reads of the page pool lower to.
 
     A dense fallback (ineligible attention config) is CORRECT but not
     paged-fast; it must be visible, never silent (ISSUE satellite). With
@@ -635,7 +633,7 @@ class ServingLoop:
     dequantize), but loses the in-kernel dequant — equally worth
     surfacing. 'ssm' = no attention layer at all: the page pool is never
     read and classification is about the recurrent-state path instead."""
-    attens = self._AttentionLayers()
+    attens = self._page_readers
     if not attens:
       return "ssm"
     if self._kv_quantized:
@@ -762,13 +760,9 @@ class ServingLoop:
       r = spec_w * spec_k
       ps = self.page_size
       trash_page = self.num_pages        # the pool's padding-write page
-      kv_axes = self._kv_leaf_axes
-
-      def _IdxTuple(ndim, pa, oa, pi, oi):
-        idx = [slice(None)] * ndim
-        idx[pa] = pi
-        idx[oa] = oi
-        return tuple(idx)
+      # tree KV repair moves tokens by each paged leaf's (page, offset)
+      # axes; a stack with no attention layer has nothing to repair
+      layout = self._Layout() if self.mixers["num_attention"] > 0 else None
 
       def _RepairKv(states, tables, rows, row_k, alen, wbr):
         # Moves the accepted path's K/V (and int8 scale sidecars) from
@@ -793,18 +787,7 @@ class ServingLoop:
         so = jnp.where(active, src_slot % ps, 0)
         dp = jnp.where(active, tables[bb, dst_slot // ps], trash_page)
         do = jnp.where(active, dst_slot % ps, 0)
-        leaves, treedef = jax.tree_util.tree_flatten(states)
-        assert len(leaves) == len(kv_axes), (len(leaves), len(kv_axes))
-        out = []
-        for leaf, ax in zip(leaves, kv_axes):
-          if ax is None:
-            out.append(leaf)
-            continue
-          pa, oa = ax
-          vals = leaf[_IdxTuple(leaf.ndim, pa, oa, sp, so)]
-          out.append(
-              leaf.at[_IdxTuple(leaf.ndim, pa, oa, dp, do)].set(vals))
-        return jax.tree_util.tree_unflatten(treedef, out)
+        return layout.Copy(states, "token", (sp, so), (dp, do))
 
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos,
                       row_k, row_w, q_logits):
@@ -843,7 +826,7 @@ class ServingLoop:
           restore = jnp.where(row_k > 0, leaf_col,
                               jnp.clip(rows.row_len - 1, 0, None))
           new_states = spec_decode._SelectAcceptedCols(new_states, restore)
-        if kv_axes is not None:
+        if layout is not None:
           new_states = _RepairKv(new_states, tables, rows, row_k, alen,
                                  wbr)
         return sampled, out, alen, new_states
@@ -861,211 +844,48 @@ class ServingLoop:
            self._task.p.vocab_size), jnp.float32)
     return self._zero_qlogits
 
-  def _PagedLeafAxes(self, task, theta, kv_cache_dtype):
-    """(page_axis, offset_axis) per decode-state leaf, None for unpaged.
-
-    The same structural detection as _BuildCowFn, run along BOTH pool
-    geometry parameters: the leaf axis that grows with the pool size is
-    the page axis, the one that grows with page_size is the token-offset
-    axis. Detecting the offset axis independently matters because int8
-    scale sidecars keep it on a different axis ([P, N, page_size]) than
-    the K/V pools ([P, page_size, N, H]) — adjacency can't be assumed."""
-    def _Shapes(np_total, ps):
-      return jax.eval_shape(
-          lambda th: task.InitPagedDecodeState(
-              th, np_total, ps, self.max_batch, kv_cache_dtype), theta)
-
-    base = jax.tree_util.tree_leaves(
-        _Shapes(self.num_pages + 1, self.page_size))
-    bigger = jax.tree_util.tree_leaves(
-        _Shapes(self.num_pages + 2, self.page_size))
-    wider = jax.tree_util.tree_leaves(
-        _Shapes(self.num_pages + 1, self.page_size + 1))
-    axes = []
-    for la, lb, lc in zip(base, bigger, wider):
-      dp = [i for i, (x, y) in enumerate(zip(la.shape, lb.shape))
-            if x != y]
-      do = [i for i, (x, y) in enumerate(zip(la.shape, lc.shape))
-            if x != y]
-      assert len(dp) <= 1 and len(do) <= 1, (la.shape, lb.shape, lc.shape)
-      assert bool(dp) == bool(do), (la.shape, dp, do)
-      axes.append((dp[0], do[0]) if dp else None)
-    return axes
-
   # -- prefix-cache support --------------------------------------------------
-
-  def _BuildCowFn(self, task, theta, kv_cache_dtype):
-    """Jits a whole-page device copy `states, src, dst -> states`.
-
-    Which decode-state leaves are page pools (and which axis pages them)
-    is detected STRUCTURALLY: abstract-eval InitPagedDecodeState at two
-    pool sizes and diff the leaf shapes — the axis that grew is the page
-    axis. That handles every layout uniformly: flat stacks page axis 0,
-    repeat-stacked layers page axis 1 (leaves carry a leading reps axis),
-    int8 K/V plus their f32 scale sidecars each get their own leaf, and
-    O(1)-mixer state leaves (shape-independent of the pool) are left
-    untouched."""
-    def _Shapes(np_total):
-      return jax.eval_shape(
-          lambda th: task.InitPagedDecodeState(
-              th, np_total, self.page_size, self.max_batch, kv_cache_dtype),
-          theta)
-
-    a = jax.tree_util.tree_leaves(_Shapes(self.num_pages + 1))
-    b = jax.tree_util.tree_leaves(_Shapes(self.num_pages + 2))
-    axes = []
-    for la, lb in zip(a, b):
-      diff = [i for i, (x, y) in enumerate(zip(la.shape, lb.shape))
-              if x != y]
-      assert len(diff) <= 1, (la.shape, lb.shape)
-      axes.append(diff[0] if diff else None)
-
-    def _CopyPage(states, src, dst):
-      leaves, treedef = jax.tree_util.tree_flatten(states)
-      assert len(leaves) == len(axes), (len(leaves), len(axes))
-      out = []
-      for leaf, ax in zip(leaves, axes):
-        if ax is None:
-          out.append(leaf)
-        else:
-          row = jnp.take(leaf, src, axis=ax)
-          out.append(leaf.at[(slice(None),) * ax + (dst,)].set(row))
-      return jax.tree_util.tree_unflatten(treedef, out)
-
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(_CopyPage, donate_argnums=donate)
 
   def _RunCow(self, admitted):
     """Executes pending copy-on-write page splits for freshly admitted
     sequences (caller holds the lock; the loop thread owns _states)."""
     for seq in admitted:
       for src, dst in seq.cow_pairs:
-        self._states = self._cow_fn(self._states,
-                                    jnp.asarray(src, jnp.int32),
-                                    jnp.asarray(dst, jnp.int32))
+        self._states = self._Layout().copy(
+            self._states, "page", jnp.asarray(src, jnp.int32),
+            jnp.asarray(dst, jnp.int32))
       seq.cow_pairs = []
 
-  def _PageIoFns(self):
-    """Jitted whole-page (gather, scatter) across the page-pool leaves —
-    the device half of the fleet page handoff (serving/fleet.py):
-    gather(states, idx) pulls the [n]-page blocks of one pool out as a
-    flat leaf list, scatter(states, idx, blocks) lands them in another
-    pool of the same stack. Which leaves are paged (and on which axis)
-    reuses the _PagedLeafAxes structural detection, so int8 K/V scale
-    sidecars are just more paged leaves and ride along."""
-    if self._page_io_fns is None:
-      axes = [ax[0] if ax is not None else None
-              for ax in self._PagedLeafAxes(self._task, self._theta,
-                                            self._kv_override)]
-
-      def _Gather(states, idx):
-        leaves = jax.tree_util.tree_leaves(states)
-        assert len(leaves) == len(axes), (len(leaves), len(axes))
-        return [jnp.take(leaf, idx, axis=ax)
-                for leaf, ax in zip(leaves, axes) if ax is not None]
-
-      def _Scatter(states, idx, blocks):
-        leaves, treedef = jax.tree_util.tree_flatten(states)
-        assert len(leaves) == len(axes), (len(leaves), len(axes))
-        out, j = [], 0
-        for leaf, ax in zip(leaves, axes):
-          if ax is None:
-            out.append(leaf)
-          else:
-            out.append(leaf.at[(slice(None),) * ax + (idx,)].set(blocks[j]))
-            j += 1
-        return jax.tree_util.tree_unflatten(treedef, out)
-
-      donate = (0,) if jax.default_backend() != "cpu" else ()
-      self._page_io_fns = (jax.jit(_Gather),
-                           jax.jit(_Scatter, donate_argnums=donate))
-    return self._page_io_fns
-
   # -- preemption spill/restore (scheduler_mode='priority') ------------------
-
-  def _SlotLeafAxes(self):
-    """Slot axis per decode-state leaf, None for slot-independent leaves.
-
-    The same structural trick as _PagedLeafAxes, diffed along num_slots
-    instead of the pool geometry: abstract-eval InitPagedDecodeState at
-    max_batch and max_batch + 1 — the leaf axis that grew is the slot
-    axis. Exactly the O(1)-mixer state leaves move (paged KV leaves are
-    slot-independent; block tables route them), so this is the complete
-    per-slot recurrent state a preemption must carry to the host."""
-    def _Shapes(num_slots):
-      return jax.eval_shape(
-          lambda th: self._task.InitPagedDecodeState(
-              th, self.num_pages + 1, self.page_size, num_slots,
-              self._kv_override), self._theta)
-
-    a = jax.tree_util.tree_leaves(_Shapes(self.max_batch))
-    b = jax.tree_util.tree_leaves(_Shapes(self.max_batch + 1))
-    axes = []
-    for la, lb in zip(a, b):
-      diff = [i for i, (x, y) in enumerate(zip(la.shape, lb.shape))
-              if x != y]
-      assert len(diff) <= 1, (la.shape, lb.shape)
-      axes.append(diff[0] if diff else None)
-    return axes
-
-  def _SlotIoFns(self):
-    """Jitted (gather, scatter) of ONE slot's row across every slot-axis
-    leaf — the state half of preemption spill/restore."""
-    if self._slot_io_fns is None:
-      axes = self._SlotLeafAxes()
-
-      def _Gather(states, slot):
-        leaves = jax.tree_util.tree_leaves(states)
-        assert len(leaves) == len(axes), (len(leaves), len(axes))
-        return [jnp.take(leaf, slot, axis=ax)
-                for leaf, ax in zip(leaves, axes) if ax is not None]
-
-      def _Scatter(states, slot, rows):
-        leaves, treedef = jax.tree_util.tree_flatten(states)
-        assert len(leaves) == len(axes), (len(leaves), len(axes))
-        out, j = [], 0
-        for leaf, ax in zip(leaves, axes):
-          if ax is None:
-            out.append(leaf)
-          else:
-            out.append(leaf.at[(slice(None),) * ax + (slot,)].set(rows[j]))
-            j += 1
-        return jax.tree_util.tree_unflatten(treedef, out)
-
-      donate = (0,) if jax.default_backend() != "cpu" else ()
-      self._slot_io_fns = (jax.jit(_Gather),
-                           jax.jit(_Scatter, donate_argnums=donate))
-    return self._slot_io_fns
 
   def _SpillPages(self, pages):
     """Scheduler spill callback: device→host copies of whole pages
     across every paged leaf. Copies to host memory are FORCED before
     returning — the scheduler frees the device pages right after, so a
     lazy device view would read reallocated garbage."""
-    gather, _ = self._PageIoFns()
-    blocks = gather(self._states, jnp.asarray(pages, jnp.int32))
+    blocks = self._Layout().gather(self._states, "page",
+                                   jnp.asarray(pages, jnp.int32))
     return [np.asarray(b) for b in jax.block_until_ready(blocks)]
 
   def _RestorePages(self, pages, blocks):
     """Scheduler restore callback: scatters spilled host blocks into the
     freshly allocated device pages (same logical slots, new physical)."""
-    _, scatter = self._PageIoFns()
-    self._states = scatter(self._states, jnp.asarray(pages, jnp.int32),
-                           [jnp.asarray(b) for b in blocks])
+    self._states = self._Layout().scatter(
+        self._states, "page", jnp.asarray(pages, jnp.int32),
+        [jnp.asarray(b) for b in blocks])
 
   def _SpillStateRow(self, slot: int):
     """Scheduler state-spill callback: one slot's O(1)-mixer state rows
     (every slot-axis leaf), forced to host."""
-    gather, _ = self._SlotIoFns()
-    rows = gather(self._states, jnp.int32(slot))
+    rows = self._Layout().gather(self._states, "slot", jnp.int32(slot))
     return [np.asarray(r) for r in jax.block_until_ready(rows)]
 
   def _RestoreStateRow(self, slot: int, rows):
     """Scheduler state-restore callback: lands a spilled state row in
     the (possibly different) slot the sequence resumes in."""
-    _, scatter = self._SlotIoFns()
-    self._states = scatter(self._states, jnp.int32(slot),
-                           [jnp.asarray(r) for r in rows])
+    self._states = self._Layout().scatter(
+        self._states, "slot", jnp.int32(slot),
+        [jnp.asarray(r) for r in rows])
 
   def ExportPrefixBlocks(self, prompt):
     """Donor half of the fleet page handoff: gathers this engine's
@@ -1083,8 +903,8 @@ class ServingLoop:
       for pg in pages:
         self.alloc.Retain(pg)
       try:
-        gather, _ = self._PageIoFns()
-        blocks = gather(self._states, jnp.asarray(pages, jnp.int32))
+        blocks = self._Layout().gather(self._states, "page",
+                                       jnp.asarray(pages, jnp.int32))
         # materialize before unpinning: the gather must read the pages
         # while our Retain still guarantees nobody rewrites them
         blocks = list(jax.block_until_ready(blocks))
@@ -1122,9 +942,8 @@ class ServingLoop:
       self._adopt_counter += 1
       owner = ("_adopt", self._adopt_counter)
       pages = self.alloc.Allocate(owner, n)
-      _, scatter = self._PageIoFns()
-      self._states = scatter(self._states, jnp.asarray(pages, jnp.int32),
-                             blocks)
+      self._states = self._Layout().scatter(
+          self._states, "page", jnp.asarray(pages, jnp.int32), blocks)
       # Insert retains what it keeps; Free drops our allocation ref, so
       # unadopted pages (a racing insert won) go straight back to the pool
       self.prefix_cache.Insert(prompt, pages)

@@ -525,6 +525,45 @@ class StateSlotPool:
     }
 
 
+def ReadsPages(mixer) -> bool:
+  """Whether a mixer reads the page pool: every mixer but one that keeps a
+  slot state and nothing else."""
+  return (hasattr(mixer, "KvBytesPerToken")
+          or not hasattr(mixer, "StateBytesPerSlot"))
+
+
+def StackCensus(task, kv_cache_dtype=None):
+  """What `task.stack` keeps of a sequence while it decodes, counted and
+  priced from the stack's own `MixerLayers()`: what `PageAllocator(
+  page_bytes=)` and `StateSlotPool(bytes_per_slot)` above are given. None for
+  a task with no stack (a non-LM task under GShardDecode).
+
+  A mixer keeps a slot state iff it exposes StateBytesPerSlot (core/ssm.py)
+  and prices its pages iff it exposes KvBytesPerToken; one that exposes
+  neither is a paged-KV attention layer too. A mixer may hold both
+  (core/retention.PowerRetention) and is then counted under both.
+  kv_cache_dtype: the engine's override of the layers' own (quant/kv.py)."""
+  stack = getattr(task, "stack", None)
+  if stack is None:
+    return None
+  census = {"num_attention": 0, "num_ssm": 0,
+            "decode_state_bytes_per_slot": 0, "kv_cache_dtype": None,
+            "kv_bytes_per_token": 0, "attention_layers": 0}
+  for mixer, reps in stack.MixerLayers():
+    if hasattr(mixer, "StateBytesPerSlot"):
+      census["num_ssm"] += reps
+      census["decode_state_bytes_per_slot"] += reps * mixer.StateBytesPerSlot()
+    if ReadsPages(mixer):
+      census["num_attention"] += reps
+    if hasattr(mixer, "KvBytesPerToken"):
+      census["attention_layers"] += reps
+      census["kv_bytes_per_token"] += int(
+          reps * mixer.KvBytesPerToken(kv_cache_dtype))
+      if census["kv_cache_dtype"] is None:
+        census["kv_cache_dtype"] = mixer.KvCacheDtype(kv_cache_dtype)
+  return census
+
+
 class SpillEntry:
   """One preempted sequence's host-tier state.
 

@@ -67,62 +67,12 @@ import numpy as np
 
 from lingvo_tpu.core import sampling
 from lingvo_tpu.core.nested_map import NestedMap
+from lingvo_tpu.serving import kv_cache
 from lingvo_tpu.serving import scheduler as scheduler_lib
 
 # key salt separating the draft model's sampling streams from the target's
 # acceptance/bonus streams (both are per-request replayable)
 _DRAFT_KEY_SALT = 0x5BEC
-
-
-# -- stack census (shared with serving/engine.py) -----------------------------
-
-
-def MixerLayers(task):
-  """[(mixer_layer, multiplicity)] over the whole stack.
-
-  Handles all four stack shapes: plain Stacked (x_layers), plain
-  Repeated (body = one TransformerLayer, xN), and the hybrid Repeated
-  whose body is itself a StackedTransformerLayers block (body.x_layers,
-  each xN)."""
-  stack = task.stack
-  if hasattr(stack, "MixerLayers"):
-    return stack.MixerLayers()     # transformer.BlockSequence lists its own
-  body = getattr(stack, "body", None)
-  if body is not None:
-    reps = stack.p.num_layers
-    inner = body.x_layers if hasattr(body, "x_layers") else [body]
-    return [(l.self_atten.atten, reps) for l in inner]
-  return [(l.self_atten.atten, 1) for l in stack.x_layers]
-
-
-def ReadsPages(mixer) -> bool:
-  """Whether a mixer reads the page pool: every mixer but one that keeps a
-  slot state and nothing else."""
-  return (hasattr(mixer, "KvBytesPerToken")
-          or not hasattr(mixer, "StateBytesPerSlot"))
-
-
-def MixerCensus(task) -> dict:
-  """Counts attention vs O(1)-state mixers; prices the per-slot state.
-
-  A mixer keeps an 'O(1) state' iff it exposes StateBytesPerSlot (the
-  core/ssm.py contract) and pages iff it exposes KvBytesPerToken; one that
-  exposes neither is a paged-KV attention layer too. A mixer may hold both
-  (core/retention.PowerRetention: a slot state and its open chunk's pages)
-  and is then counted under both.
-  """
-  num_attention = num_ssm = state_bytes = 0
-  for mixer, reps in MixerLayers(task):
-    if hasattr(mixer, "StateBytesPerSlot"):
-      num_ssm += reps
-      state_bytes += reps * mixer.StateBytesPerSlot()
-    if ReadsPages(mixer):
-      num_attention += reps
-  return {
-      "num_attention": num_attention,
-      "num_ssm": num_ssm,
-      "decode_state_bytes_per_slot": state_bytes,
-  }
 
 
 # -- draft-source configs -----------------------------------------------------
@@ -233,21 +183,11 @@ class SpecRunner:
     if self.is_self:
       depth = task.p.num_layers
       assert config.num_layers <= depth, (config.num_layers, depth)
-      body = getattr(task.stack, "body", None)
-      if body is not None:
-        # repeat stack: the early-exit prefix slices whole scanned repeats,
-        # so the draft depth must cover an integral number of them — fail
-        # here rather than as a shape assert inside the first spec cycle
-        body_depth = len(body.x_layers) if hasattr(body, "x_layers") else 1
-        assert config.num_layers % body_depth == 0, (
-            f"SelfDraft num_layers={config.num_layers} must be a multiple "
-            f"of the scanned repeat body depth ({body_depth}) for this "
-            "target stack")
       self.draft_task = None
       self.draft_theta = None
       self.draft_states = None
     else:
-      census = MixerCensus(config.task)
+      census = kv_cache.StackCensus(config.task)
       assert census["num_attention"] == 0, (
           "ModelDraft requires a pageless draft (pure O(1)-state mixer "
           f"stack); draft has {census['num_attention']} attention layers "

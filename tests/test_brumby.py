@@ -144,10 +144,12 @@ def test_published_model_counts_its_layers_and_parameters():
                    + 2 * 128 + 2 * d) == 330_352_896
   total = count(shapes)
   assert total == 40 * layer + 2 * 151936 * d + d == 14_769_945_600
-  census = spec_decode.MixerCensus(task)
+  census = kv_cache.StackCensus(task)
   # a retention layer holds pages AND a slot state: counted under both
   assert census == {"num_attention": 40, "num_ssm": 40,
-                    "decode_state_bytes_per_slot": 40 * 34_344_960}
+                    "decode_state_bytes_per_slot": 40 * 34_344_960,
+                    "attention_layers": 40, "kv_cache_dtype": "float32",
+                    "kv_bytes_per_token": 40 * 8 * (2 * 128 * 4 + 4)}
   assert stack.PageWindows() == [1] * 40
   mixer = stack._bodies[0][0].mixer
   assert mixer.StoredFeatureDim() == 8320

@@ -18,6 +18,7 @@ from lingvo_tpu.core.nested_map import NestedMap
 from lingvo_tpu.models.lm import layers as lm_layers
 from lingvo_tpu.ops import packed_ssd_scan
 from lingvo_tpu.serving import engine as engine_lib
+from lingvo_tpu.serving import kv_cache
 from lingvo_tpu.serving import spec_decode
 
 import lingvo_tpu.models.all_params  # noqa: F401  (fills the registry)
@@ -108,7 +109,7 @@ def test_published_model_counts_its_layers_and_parameters():
   routed = 23 * 122 * 2 * d * 1856                 # experts a token skips
   assert round((total - routed) / 1e9, 1) == 3.6   # with embedding and head
   assert round((total - routed - 131072 * d) / 1e9, 1) == 3.2   # A3.2B
-  census = spec_decode.MixerCensus(task)
+  census = kv_cache.StackCensus(task)
   assert census["num_ssm"] == 23 and census["num_attention"] == 6
   assert census["decode_state_bytes_per_slot"] == 23 * 4 * (
       64 * 64 * 128 + 3 * 6144)

@@ -19,6 +19,7 @@ from lingvo_tpu.models.lm.params import phi4flash
 from lingvo_tpu.ops import diff_attend
 from lingvo_tpu.ops import selective_scan
 from lingvo_tpu.serving import engine as engine_lib
+from lingvo_tpu.serving import kv_cache
 from lingvo_tpu.serving import spec_decode
 
 import lingvo_tpu.models.all_params  # noqa: F401  (fills the registry)
@@ -90,7 +91,7 @@ def test_published_model_counts_its_layers_and_parameters():
   # nine layers own pages (eight of the window, one full), seven read
   assert stack.PageWindows() == [512] * 8 + [0]
   assert stack.SharedKvReadLayers() == 7
-  census = spec_decode.MixerCensus(task)
+  census = kv_cache.StackCensus(task)
   assert census["num_ssm"] == 9
   assert census["decode_state_bytes_per_slot"] == 9 * 4 * 5120 * (16 + 3)
 

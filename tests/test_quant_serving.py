@@ -200,11 +200,13 @@ class TestKvQuant:
 
   def test_stack_census_counts_repeated_layers(self, tiny_lm):
     task, _ = tiny_lm
-    census = kv_quant.StackKvCensus(task)
+    census = kv_cache.StackCensus(task)
     # 2 repeated layers x (2 heads * 16 dim * 2(K,V) * 4B) = 512 B/token
     assert census == {"kv_cache_dtype": "float32",
-                      "kv_bytes_per_token": 512, "attention_layers": 2}
-    census8 = kv_quant.StackKvCensus(task, "int8")
+                      "kv_bytes_per_token": 512, "attention_layers": 2,
+                      "num_attention": 2, "num_ssm": 0,
+                      "decode_state_bytes_per_slot": 0}
+    census8 = kv_cache.StackCensus(task, "int8")
     assert census8["kv_cache_dtype"] == "int8"
     # per layer: 2*2*16*1 + 2*2*4 = 80 -> 160 total
     assert census8["kv_bytes_per_token"] == 160

@@ -192,7 +192,7 @@ class TestStepProgramCensus:
     request's first output rides its last prefill chunk."""
     task, theta = tiny_lm
     eng = _Engine(task, theta)                    # pages of 4: Bq is 8
-    bq = eng._AttentionLayers()[0].RaggedQueryBlock(4)
+    bq = task.stack.MixerLayers()[0][0].RaggedQueryBlock(4)
     assert eng._attend_bq == bq == 8
     reqs = [([5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17], 5),
             ([3, 1], 6), ([2, 2, 2], 4)]
