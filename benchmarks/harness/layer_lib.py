@@ -5,10 +5,10 @@ trace reduction (`trace`) and the step program's executions (`trace_step`)."""
 
 from __future__ import annotations
 
-import json
 import statistics
 
 from benchmarks.harness import flops
+from benchmarks.harness import hybrid_cost
 from benchmarks.harness import readings
 from benchmarks.harness import xplane
 
@@ -59,28 +59,20 @@ def RaggedShare(run):
 
 
 def RaggedRoofline(run):
-  """Required operations and bytes of the traced steps (from each live row's
-  tokens and context) against the kernel's device time in the same steps.
+  """Required operations and bytes of the traced steps (each live row's
+  tokens and context in the steps the trace holds: `hybrid_cost.
+  TracedStepRows`, not the last steps the recorder saw, which lie after the
+  trace) against the kernel's device time in the same steps.
   K and V bytes by the configuration's `num_kv_heads` and each layer's
   context by its `attention_windows` (one entry a layer, 0 = full), where
   the file states them; absent, every query head has its own K and V and
   every layer is full. None where the trace holds no such kernel."""
-  n = run["trace_step"]["count"]
   s = run["sizes"]
-  kernel_s = xplane.KernelSeconds(run["trace"], RAGGED_KERNEL)
-  if kernel_s is None:
-    return None
-  ops = nbytes = 0.0
-  for rows in run["step_rows"][-n:]:
-    o, b = flops.RaggedAttendStepCost(
-        rows, run["packed_t"], s["num_heads"], s["dim_per_head"],
-        s["num_layers"], num_kv_heads=s.get("num_kv_heads"),
-        windows=s.get("attention_windows"))
-    ops, nbytes = ops + o, nbytes + b
-  share, bound = flops.RooflineShare(ops, nbytes, kernel_s, run["peak"])
-  print(json.dumps({"note": "ragged_attend_roofline", "value": {
-      "bound": bound, "ops": ops, "bytes": nbytes, "steps": n}}), flush=True)
-  return share
+  return hybrid_cost.KernelRoofline(
+      run, RAGGED_KERNEL, lambda rows: flops.RaggedAttendStepCost(
+          rows, run["packed_t"], s["num_heads"], s["dim_per_head"],
+          s["num_layers"], num_kv_heads=s.get("num_kv_heads"),
+          windows=s.get("attention_windows")))
 
 
 def Pct(run, key, q):

@@ -256,8 +256,10 @@ def Run(ctx) -> dict:
   if engine.paged_path != want_path:
     raise RuntimeError(f"paged_path {engine.paged_path!r}, want {want_path!r}")
   # the roofline readers count with the file's KV heads and head size: they
-  # are the program's only if the engine's own page pool ends in them
-  ctx.Note("read_back", model_lib.ReadBackKvPool(sizes, engine._states))
+  # are the program's only if the engine's own page pools (the leaves the
+  # program's layout says are pools) end in the rows the file states
+  ctx.Note("read_back", model_lib.ReadBackPools(sizes, task, theta,
+                                                engine._states))
   packed_t = geo["max_batch"] + geo["prefill_token_budget"]
 
   scale = float(sizes.get("length_scale", 1.0)) if ctx.rehearse else 1.0

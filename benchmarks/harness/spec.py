@@ -8,7 +8,15 @@ A configuration of another family than the dense one is added the same way
 (PERF.md section 4, "Adding a configuration of another family"). Files: the
 configuration (the sizes every file has, `task_params` for what only its
 family has, `num_kv_heads` and `attention_windows` where the roofline
-readers need them, `reduced` and `assumed`, a `correct` group with a
+readers need them, `pool_rows` where the decode state's page pools hold
+more than K and V by heads: `{a pool leaf's last path key: the dimensions it
+ends in behind its page and token-offset axes}`, `{"gate": [8]}` for a
+retention layer's gates, `{"latent": [320]}` for a latent row a token; the
+read-back (`model.ReadBackPools`) asks the program which leaves are pools
+and holds each to its row, `key` and `value` to `[num_kv_heads,
+dim_per_head]` with no entry, and fails the run on a pool leaf the file does
+not cover and on an entry the program has no leaf for; the `rehearsal` group
+carries its own), `reduced` and `assumed`, a `correct` group with a
 `train_reason` where a train cell uses it and a `serve_reason` where a serve
 cell does, a `rehearsal` group), its plain reference under
 benchmarks/references/ (`SeededWeights`, `LogitsAt`, and `Logits` for a

@@ -259,10 +259,10 @@ def _ProgramIgnoresItsHeadSizeKey(monkeypatch):
 @pytest.mark.parametrize("cell,fault,says", [
     ("dense1b_serve_docs",
      lambda mp: _FileSays(mp, num_kv_heads=2),
-     r"K/V pool leaf body/self_atten/key has shape \(2, 34, 8, 4, 16\); the "
+     r"pool leaf body/self_atten/key has shape \(2, 34, 8, 4, 16\); the "
      r"configuration file states \(2, 16\)"),
     ("dense1b_serve_docs", _ProgramIgnoresItsHeadSizeKey,
-     r"K/V pool leaf .* the configuration file states \(4, 32\)"),
+     r"pool leaf .* the configuration file states \(4, 32\)"),
     ("dense1b_train_packed", _ProgramIgnoresItsHeadSizeKey,
      r"query projection stack/body/self_atten/atten/w_query has shape "
      r"\(2, 64, 4, 16\); the configuration file states \(4, 32\)"),

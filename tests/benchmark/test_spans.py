@@ -438,29 +438,72 @@ def test_every_new_metric_has_its_reader_and_its_entry():
   assert entries["step_host_share.tput"]["moves"] == "serve_tok_s"
 
 
+# A reader of the engine, of the step or of the device finds something to
+# read in every cell that reports the metric it moves, and lists them all. A
+# reader of ONE kernel, or of what exists only across chips, finds it only in
+# the cells whose program has that kernel or that mesh: it lists some of
+# them, and the driver refuses a traced line that lacks a metric which lists
+# its cell, so it may not list the rest. Which sort a reader is says the
+# `layer` of its entry in BENCHMARK.json.
+_LAYERS_OF_SOME_CELLS = ("kernels", "sharding")
+
+
+def _ListsItsCells(bench, m) -> bool:
+  """`m`'s list against the cells of the metric it moves, by its layer."""
+  cells = _CellsOf(bench, m["moves"])
+  if m["layer"] in _LAYERS_OF_SOME_CELLS:
+    # some of them, each once, in their order there
+    return bool(m["workloads"]) and m["workloads"] == [
+        c for c in cells if c in m["workloads"]]
+  return m["workloads"] == cells
+
+
 def test_every_reader_lists_the_cells_of_the_metric_it_moves():
-  """Rules, not names: every `.lat` metric lists exactly the cells of
-  `itl_p95_ms`, every `.tput` metric those of `serve_tok_s`, every train
-  reader those of `train_tok_s`, and `collective_exposed_share` the
-  four-chip ones among them."""
+  """Rules, not names: every `.lat` metric moves `itl_p95_ms` and every
+  `.tput` metric `serve_tok_s`; each of them, and every train reader, lists
+  exactly the cells of the metric it moves, unless its layer is one whose
+  readers find something in some cells only (`_LAYERS_OF_SOME_CELLS`): those
+  list a part of them. `collective_exposed_share` is such a reader, and its
+  cells are the four-chip ones."""
   bench = spec.LoadBenchmark()
   chips = {w["name"]: w["chips"] for w in bench["workloads"]}
   suffix = {".lat": "itl_p95_ms", ".tput": "serve_tok_s"}
   seen = {"itl_p95_ms": 0, "serve_tok_s": 0, "train_tok_s": 0}
+  some = 0
   for m in bench["per_layer"]:
     ext = os.path.splitext(m["name"])[1]
     if ext in suffix:
       assert m["moves"] == suffix[ext], m["name"]
     elif m["moves"] != "train_tok_s":
-      continue            # compile_s; a later metric of another kind
-    cells = _CellsOf(bench, m["moves"])
+      continue            # compile_s; a family's own metric of another kind
+    assert _ListsItsCells(bench, m), (m["name"], m["layer"], m["workloads"])
+    some += m["workloads"] != _CellsOf(bench, m["moves"])
     if m["name"] == "collective_exposed_share":
-      cells = [c for c in cells if chips[c] == 4]
-      assert cells
-    assert m["workloads"] == cells, m["name"]
+      assert m["workloads"] and all(chips[c] == 4 for c in m["workloads"])
     seen[m["moves"]] += 1
   assert all(seen[k] >= n for k, n in (
       ("itl_p95_ms", 17), ("serve_tok_s", 14), ("train_tok_s", 10))), seen
+  # the rule is not idle: some kernel's reader lists a part of its cells
+  assert some >= 1
+
+
+def test_a_reader_of_the_engine_that_leaves_a_cell_out_is_caught():
+  """The rule on a benchmark with one entry changed: a `serving engine`
+  reader that drops a cell fails, a `kernels` reader that drops one holds,
+  and one that lists a cell which does not report its metric fails."""
+  bench = spec.LoadBenchmark()
+  entries = {m["name"]: m for m in bench["per_layer"]}
+  cells = _CellsOf(bench, "serve_tok_s")
+  assert len(cells) >= 2
+  engine = dict(entries["step_span_ms.tput"], workloads=cells[1:])
+  assert engine["layer"] == "serving engine"
+  assert not _ListsItsCells(bench, engine)
+  kernel = dict(entries["ragged_attend_share.tput"], workloads=cells[1:])
+  assert kernel["layer"] == "kernels"
+  assert _ListsItsCells(bench, kernel)
+  assert not _ListsItsCells(bench, dict(kernel, workloads=[]))
+  assert not _ListsItsCells(bench, dict(
+      kernel, workloads=cells[:1] + _CellsOf(bench, "itl_p95_ms")))
 
 
 # -- where a window's seconds went (step_stall_share, step_h2d_ms) -------------
@@ -644,5 +687,7 @@ def test_stall_metrics_have_their_entries():
     assert lat["layer"] == tput["layer"] == layer
     assert lat["unit"] == tput["unit"] == unit
     assert (lat["moves"], tput["moves"]) == ("itl_p95_ms", "serve_tok_s")
-    assert lat["workloads"] == _CellsOf(bench, "itl_p95_ms")
-    assert tput["workloads"] == _CellsOf(bench, "serve_tok_s")
+    # all the cells of the metric it moves, or for a kernel's reader those
+    # whose program runs the kernel (`brumby14b_serve_longwrite` runs no
+    # ragged attend: `attend_block_fill.tput` finds nothing to read there)
+    assert _ListsItsCells(bench, lat) and _ListsItsCells(bench, tput)

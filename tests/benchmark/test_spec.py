@@ -315,7 +315,9 @@ def test_a_cell_of_another_family_is_added_from_new_files_alone(
     if m["name"] in ("serve_tok_s", "compile_s") or m["name"].endswith(".tput"):
       m["workloads"] = m["workloads"] + [name]
       joined.append(m["name"])
-  assert sum(n.endswith(".tput") for n in joined) == 14
+  # every `.tput` metric the file under test has, however many that is
+  assert sum(n.endswith(".tput") for n in joined) == sum(
+      m["name"].endswith(".tput") for m in bench["per_layer"]) >= 14
 
   _CheckConfigs(grown, root), _CheckWorkloads(grown, root)
   _CheckMetrics(grown, root)
@@ -345,6 +347,148 @@ def test_a_cell_of_another_family_is_added_from_new_files_alone(
     assert tp.num_layers == want["num_layers"]
     assert tp.softmax_logits_soft_max == 0.0
     assert tp.hidden_dim == 128, "the registered model's: the file has none"
+  for p, data in before.items():
+    assert open(p, "rb").read() == data, f"{p} was edited"
+
+
+# -- a configuration whose cache is a latent row a token -----------------------
+
+_MLA = {"kv_lora_rank": 256, "qk_nope_head_dim": 64, "qk_rope_head_dim": 64,
+        "v_head_dim": 128, "q_lora_rank": 1024}
+_LATENT_CFG = {
+    "family": "latent_lm", "reference": "latent_lm",
+    "registry_model": "lm.synthetic_packed_input.MoELmTiny",
+    # one chip's share of a model of 32 heads on 4096: the query-key head
+    # size (64 + 64) is model_dim / num_heads, so no head size is written
+    "model_dim": 4096, "num_heads": 32, "dim_per_head": 128,
+    "vocab_size": 32768, "seq_len": 64, "batch_size": 4, "num_layers": 6,
+    "logit_cap": 0.0, **_MLA,
+    "task_params": {"atten_tpl." + k: v for k, v in _MLA.items()},
+    # what the pool keeps a token: kv_lora_rank + qk_rope_head_dim, not heads
+    "pool_rows": {"latent": [320]},
+    "weights": {}, "assumed": [], "reduced_notes": "num_layers 6 of 36",
+    "serving": {"page_size": 128, "num_pages": 96, "max_batch": 128,
+                "max_seq_len": 8192, "prefill_token_budget": 512},
+    "correct": {"serve_logit_tol": 0.2, "serve_sample_rows": 4,
+                "serve_reason": "a test"},
+    "rehearsal": {"model_dim": 64, "num_heads": 4, "dim_per_head": 16,
+                  "num_layers": 2, "pool_rows": {"latent": [24]},
+                  "serving": {"page_size": 8, "num_pages": 12, "max_batch": 8,
+                              "max_seq_len": 64, "prefill_token_budget": 16}},
+}
+_LATENT_READER = '''"""A latent attend kernel against its roofline over the traced steps: a
+live row's latent rows read once a layer, 2 x heads x (rank + rope + rank)
+operations an attended token."""
+from benchmarks.harness import hybrid_cost
+
+
+def _Cost(s):
+  row = s["pool_rows"]["latent"][0]
+
+  def _StepCost(rows):
+    attended = sum(new * (ctx - (new - 1) / 2.0) for new, ctx in rows)
+    kept = sum(ctx for new, ctx in rows if new > 0)
+    return (s["num_layers"] * 2.0 * s["num_heads"]
+            * (row + s["kv_lora_rank"]) * attended,
+            s["num_layers"] * 2.0 * row * kept)
+  return _StepCost
+
+
+def Read(run):
+  return hybrid_cost.KernelRoofline(run, "mla_attend", _Cost(run["sizes"]))
+'''
+
+
+def test_a_cell_with_a_latent_pool_is_added_from_new_files_alone(
+    bench, tmp_path, capsys):
+  """The sibling of the test above for a stack whose cache is not K and V by
+  heads: the file states `pool_rows: {"latent": [kv_lora_rank +
+  qk_rope_head_dim]}`, the cell joins the lists of the readers that are not
+  one kernel's, and brings its own kernel's roofline reader over the traced
+  steps' rows. New files and appended entries only; every rule of this file
+  holds; the read-back takes the program's latent leaf at the full and at
+  the rehearsal sizes, never its slot state, and the same file with 576
+  stated against 320 kept fails and names the leaf."""
+  root, before = _Copy(tmp_path)
+  with open(os.path.join(root, "benchmarks/configs/latent.json"), "w") as f:
+    json.dump(_LATENT_CFG, f)
+  with open(os.path.join(root, "benchmarks/references/latent_lm.py"),
+            "w") as f:
+    f.write("def SeededWeights(theta):\n  return theta\n\n\n"
+            "def LogitsAt(theta, ids, at, logit_cap):\n  raise "
+            "NotImplementedError\n")
+  mix = dict(_Json(ROOT, "benchmarks/traffic/docs.json"), notes="a test")
+  with open(os.path.join(root, "benchmarks/traffic/docs_long.json"),
+            "w") as f:
+    json.dump(mix, f)
+  with open(os.path.join(
+      root, "benchmarks/layer_metrics/mla_attend_roofline.py"), "w") as f:
+    f.write(_LATENT_READER)
+
+  name = "latent_serve_docs_long"
+  grown = json.loads(json.dumps(bench))
+  grown["configs"].append({"name": "latent", "source": "a test",
+                           "file": "benchmarks/configs/latent.json",
+                           "reduced": ["num_layers"], "why": "a test"})
+  grown["workloads"].append({"name": name, "config": "latent",
+                             "traffic": "docs_long", "chips": 1,
+                             "why": "a test"})
+  joined = []
+  for m in grown["end_to_end"] + grown["per_layer"]:
+    # the readers of the engine, the step and the device; not another
+    # kernel's (the driver refuses a traced line that lacks a listed metric)
+    if m["name"] in ("serve_tok_s", "compile_s") or (
+        m["name"].endswith(".tput") and m["layer"] != "kernels"):
+      m["workloads"] = m["workloads"] + [name]
+      joined.append(m["name"])
+  grown["per_layer"].append({
+      "name": "mla_attend_roofline", "unit": "%", "better": "higher",
+      "source": "device_trace", "layer": "kernels", "moves": "serve_tok_s",
+      "workloads": [name]})
+
+  _CheckConfigs(grown, root), _CheckWorkloads(grown, root)
+  _CheckMetrics(grown, root)
+  cell = spec.Cell(grown, name, root=root)
+  assert [m["name"] for m in cell["end_to_end"]] == ["serve_tok_s", "setup_s"]
+  assert sorted(m["name"] for m in cell["per_layer"]) == sorted(
+      [n for n in joined if n != "serve_tok_s"] + ["mla_attend_roofline"])
+  assert not any(m["name"].startswith("ragged_attend")
+                 for m in cell["per_layer"])
+
+  # the program's word and the file's: a latent leaf a layer stack, a slot
+  # state with as many slots as a page has tokens
+  def _Program(row):
+    return lambda pages, page, slots: {"stack": {
+        "mla": {"latent": (6, pages, page, row)},
+        "conv": {"tail": (6, slots, 3, 4096)}}}
+
+  for rehearse, row in ((False, 320), (True, 24)):
+    sizes = model_lib.Sizes(cell["config"], rehearse)
+    model_lib.CheckHeads(sizes)
+    geo = sizes["serving"]
+    assert _ReadBackPools(sizes, _Program(row)) == {
+        "stack/mla/latent": [6, geo["num_pages"] + 1, geo["page_size"], row]}
+  with pytest.raises(ValueError, match=r"pool leaf stack/mla/latent has "
+                     r"shape \(6, 97, 128, 320\); the configuration file "
+                     r"states \(576,\)"):
+    _ReadBackPools(dict(cell["config"], pool_rows={"latent": [576]}),
+                   _Program(320))
+
+  # its own reader, found by name, over the rows of the steps the trace holds
+  from benchmarks.harness import peaks
+  run = {"sizes": cell["config"], "peak": peaks.PeakOf("TPU v5 lite"),
+         "trace": {"kernel_s_by_scope": {"mla_attend": 0.004}},
+         "trace_step": {"count": 2}, "window": (0.0, 10.25),
+         "step_records": [(10.0 + 0.1 * i, 0.09, i + 1, 0) for i in range(6)],
+         "step_rows": [[(1, 4000)] * 64] * 3 + [[(1, 9)]] * 3}
+  got = spec.LayerMetricReader("mla_attend_roofline", root)(spec.RunData(run))
+  note = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+  assert note["note"] == "mla_attend_roofline" and note["value"]["steps"] == 2
+  nbytes = 2 * 6 * 2.0 * 320 * 64 * 4000     # steps 2 and 3, not 5 and 6
+  assert got == pytest.approx(100.0 * nbytes / 819e9 / 0.004)
+  # a run whose trace holds no such kernel: nothing to read, not 0
+  assert spec.LayerMetricReader("mla_attend_roofline", root)(spec.RunData(
+      dict(run, trace={"kernel_s_by_scope": {"ragged_attend": 0.1}}))) is None
   for p, data in before.items():
     assert open(p, "rb").read() == data, f"{p} was edited"
 
@@ -432,25 +576,145 @@ def _Leaf(*shape):
   return np.zeros(shape, np.float32)
 
 
+class _StubTask:
+  """A program as the read-back sees it: `InitPagedDecodeState` alone, over
+  `leaves(num_pages, page_size, num_slots) -> {path: shape}` (nested)."""
+
+  def __init__(self, leaves):
+    self._leaves = leaves
+
+  def InitPagedDecodeState(self, theta, num_pages, page_size, num_slots,
+                           kv_cache_dtype=None):
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(
+        lambda shape: jnp.zeros(shape, jnp.float32),
+        self._leaves(num_pages, page_size, num_slots),
+        is_leaf=lambda x: isinstance(x, tuple))
+
+
+def _ReadBackPools(sizes, leaves):
+  """The read-back as a serve cell calls it: the engine's states (abstract
+  here: shapes are all it reads) hold one page more than the file's
+  `num_pages`, as `ServingLoop`'s do."""
+  import jax
+  task, geo = _StubTask(leaves), sizes["serving"]
+  states = jax.eval_shape(lambda: task.InitPagedDecodeState(
+      None, geo["num_pages"] + 1, geo["page_size"], geo["max_batch"]))
+  return model_lib.ReadBackPools(sizes, task, None, states)
+
+
 def test_read_back_holds_the_pool_to_the_files_kv_heads_and_head_size():
   sizes = {"num_heads": 16, "dim_per_head": 128,
-           "serving": {"page_size": 128}}
-  states = {"body": {"self_atten": {"key": _Leaf(24, 3, 128, 16, 128),
-                                    "value": _Leaf(24, 3, 128, 16, 128)},
-                     "ssm": {"state": _Leaf(24, 4, 16)}}}
-  assert model_lib.ReadBackKvPool(sizes, states) == {
+           "serving": {"page_size": 128, "num_pages": 2, "max_batch": 4}}
+
+  def _Dense(pages, page, slots, heads=16):
+    return {"body": {"self_atten": {"key": (24, pages, page, heads, 128),
+                                    "value": (24, pages, page, heads, 128)},
+                     "ssm": {"state": (24, slots, 16)}}}
+
+  assert _ReadBackPools(sizes, _Dense) == {
       "body/self_atten/key": [24, 3, 128, 16, 128],
       "body/self_atten/value": [24, 3, 128, 16, 128]}
   # grouped heads: the pool is as wide as the KV heads
-  grouped = dict(sizes, num_heads=28, num_kv_heads=4)
-  pool = {"key": _Leaf(36, 128, 4, 128), "value": _Leaf(36, 128, 4, 128)}
-  assert len(model_lib.ReadBackKvPool(grouped, pool)) == 2
+  grouped = dict(sizes, num_heads=28, num_kv_heads=4,
+                 serving=dict(sizes["serving"], num_pages=35))
+  flat = lambda heads: lambda pages, page, slots: {
+      "key": (pages, page, heads, 128), "value": (pages, page, heads, 128)}
+  assert len(_ReadBackPools(grouped, flat(4))) == 2
   # the file says 4 KV heads, the program keeps 28: the roofline would count
   # a seventh of the bytes the kernel reads
-  with pytest.raises(ValueError, match=r"key has shape \(36, 128, 28, 128\)"):
-    model_lib.ReadBackKvPool(grouped, {"key": _Leaf(36, 128, 28, 128)})
-  with pytest.raises(ValueError, match="no K/V pool leaf"):
-    model_lib.ReadBackKvPool(sizes, {"ssm": {"state": _Leaf(24, 4, 16)}})
+  with pytest.raises(ValueError, match=r"key has shape \(36, 128, 28, 128\); "
+                     r"the configuration file states \(4, 128\)"):
+    _ReadBackPools(grouped, flat(28))
+  with pytest.raises(ValueError, match="no pool leaf"):
+    _ReadBackPools(sizes, lambda pages, page, slots: {
+        "ssm": {"state": (24, slots, 16)}})
+
+
+# a stack whose cache is not K and V by heads: a latent row a token, a gate a
+# (head, token) with its offsets on the LAST axis, and a slot state whose
+# slots are as many as a page has tokens (`max_batch` == `page_size`)
+_LATENT = {"num_heads": 32, "dim_per_head": 128, "pool_rows": {
+    "latent": [320], "gate": [8]},
+           "serving": {"page_size": 128, "num_pages": 40, "max_batch": 128}}
+
+
+def _LatentLeaves(latent=320, more=None):
+  def _Leaves(pages, page, slots):
+    return {"stack": {"mla": {"latent": (pages, page, latent)},
+                      "retention": {"gate": (pages, 8, page)},
+                      "ssm": {"state": (2, slots, 16, 5120)},
+                      **(more(pages, page, slots) if more else {})}}
+  return _Leaves
+
+
+def test_read_back_holds_a_latent_pool_to_the_row_the_file_states():
+  """Which leaves are pools is the program's word, what each keeps a token
+  the file's: the latent leaf ends in `[320]`, the gate leaf keeps `[8]`
+  behind a page axis and an offset axis that are not neighbours, and the
+  slot state `[2, 128, 16, 5120]`, which the rule by shape took for a pool
+  (third from last = page size), is not read."""
+  assert _ReadBackPools(_LATENT, _LatentLeaves()) == {
+      "stack/mla/latent": [41, 128, 320],
+      "stack/retention/gate": [41, 8, 128]}
+  # a file with K and V by heads beside them states nothing for those two
+  both = _LatentLeaves(more=lambda pages, page, slots: {
+      "atten": {"key": (pages, page, 32, 128), "value": (pages, page, 32, 128)}})
+  assert sorted(_ReadBackPools(_LATENT, both)) == [
+      "stack/atten/key", "stack/atten/value", "stack/mla/latent",
+      "stack/retention/gate"]
+
+
+@pytest.mark.parametrize("sizes, leaves, says", [
+    # the file states kv_lora_rank + qk_rope_head_dim of another model
+    (dict(_LATENT, pool_rows={"latent": [576], "gate": [8]}), _LatentLeaves(),
+     r"pool leaf stack/mla/latent has shape \(41, 128, 320\); the "
+     r"configuration file states \(576,\) for the last 1 of the \(320,\)"),
+    # the program keeps another row than the file's
+    (_LATENT, _LatentLeaves(latent=576),
+     r"stack/mla/latent has shape \(41, 128, 576\); .* states \(320,\)"),
+    # a pool leaf the file does not cover
+    (dict(_LATENT, pool_rows={"latent": [320]}), _LatentLeaves(),
+     r"pool leaf stack/retention/gate has shape \(41, 8, 128\) and the "
+     r"configuration file states no row for 'gate': pool_rows covers "
+     r"\['latent'\]"),
+    # a row stated for a leaf the program does not have
+    (dict(_LATENT, pool_rows={"latent": [320], "gate": [8], "scale": [1]}),
+     _LatentLeaves(),
+     r"pool_rows states \{'scale': \[1\]\} and the program declares no such "
+     r"pool leaf"),
+    # ... and for one that is a slot state, not a pool
+    (dict(_LATENT, pool_rows={"latent": [320], "gate": [8],
+                              "state": [16, 5120]}), _LatentLeaves(),
+     r"pool_rows states \{'state': \[16, 5120\]\}"),
+    # a row that is no row
+    (dict(_LATENT, pool_rows={"latent": [], "gate": [8]}), _LatentLeaves(),
+     r"pool_rows 'latent' is \[\]"),
+    # K and V restated by the file, and wrongly
+    (dict(_LATENT, pool_rows={"latent": [320], "gate": [8], "key": [320]}),
+     _LatentLeaves(more=lambda pages, page, slots: {
+         "atten": {"key": (pages, page, 32, 128)}}),
+     r"stack/atten/key has shape \(41, 128, 32, 128\); .* states \(320,\)"),
+    # no pool at all, whatever the slot state's shape
+    (dict(_LATENT, pool_rows={}), lambda pages, page, slots: {
+        "ssm": {"state": (2, slots, 16, 5120)}}, "no pool leaf"),
+])
+def test_read_back_fails_and_names_the_leaf(sizes, leaves, says):
+  with pytest.raises(ValueError, match=says):
+    _ReadBackPools(sizes, leaves)
+
+
+def test_read_back_never_takes_a_slot_state_for_a_pool():
+  """`slots == page_size` and the shape is a pool's by the old rule: the
+  layout's word decides, so a stack of K, V and such a state reads K and V
+  alone, and the state's last dimensions are held to nothing."""
+  sizes = {"num_heads": 40, "num_kv_heads": 20, "dim_per_head": 64,
+           "serving": {"page_size": 128, "num_pages": 8, "max_batch": 128}}
+  got = _ReadBackPools(sizes, lambda pages, page, slots: {
+      "pool": {"key": (pages, page, 20, 64), "value": (pages, page, 20, 64)},
+      "mamba": {"state": (9, slots, 16, 5120), "tail": (9, slots, 3, 5120)}})
+  assert got == {"pool/key": [9, 128, 20, 64], "pool/value": [9, 128, 20, 64]}
 
 
 def test_read_back_holds_the_query_projection_to_the_files_heads():

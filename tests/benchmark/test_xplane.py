@@ -9,6 +9,7 @@ import os
 import pytest
 
 from benchmarks.harness import flops
+from benchmarks.harness import hybrid_cost
 from benchmarks.harness import layer_lib
 from benchmarks.harness import peaks
 from benchmarks.harness import spec
@@ -227,7 +228,43 @@ def test_ragged_readers_give_what_the_parents_formula_gives(serve_trace, sfx,
   assert _Read("ragged_attend_roofline" + sfx, run) == want
   note = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
   assert note["value"] == {"bound": bound, "ops": ops, "bytes": nbytes,
-                           "steps": n}
+                           "steps": n, "kernel_s": red["kernel_s"]}
+
+
+@pytest.mark.parametrize("sfx", [".lat", ".tput"])
+def test_ragged_roofline_counts_the_steps_the_trace_holds(serve_trace, sfx,
+                                                          capsys):
+  """The trace stops where the window closes and the engine runs on while
+  the probe waits: a closed loop then drains, and the last steps the
+  recorder saw hold a few decode rows and no chunk. The reader counts the n
+  steps that were done when the window closed (`hybrid_cost.TracedStepRows`,
+  the one copy), not the last n recorded."""
+  run = _ServeRun(serve_trace)
+  n = run["trace_step"]["count"]
+  in_trace = run["step_rows"][:n]
+  drain = [[(1, 900 + i) for i in range(3)] for _ in range(4 * n)]
+  # a step every 0.1 s from 100.0; the window closes behind the n-th
+  run["step_rows"] = in_trace + drain
+  run["step_records"] = [(100.0 + 0.1 * i, 0.09, i + 1, 0)
+                         for i in range(len(run["step_rows"]))]
+  run["window"] = (99.95, 100.0 + 0.1 * (n - 1) + 0.01)
+  assert hybrid_cost.TracedStepRows(run, n) == in_trace
+
+  def _Share(step_rows):
+    ops = nbytes = 0.0
+    for rows in step_rows:
+      o, b = flops.RaggedAttendStepCost(rows, 544, 16, 128, 24)
+      ops, nbytes = ops + o, nbytes + b
+    return flops.RooflineShare(ops, nbytes, run["trace"]["kernel_s"],
+                               run["peak"])[0]
+
+  got = _Read("ragged_attend_roofline" + sfx, run)
+  assert got == _Share(in_trace)
+  assert got > 2 * _Share(run["step_rows"][-n:])   # what the parent read
+  note = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+  assert note["note"] == "ragged_attend_roofline"
+  assert note["value"]["steps"] == n
+  assert note["value"]["kernel_s"] == run["trace"]["kernel_s"]
 
 
 def test_ragged_roofline_counts_kv_heads_and_windows_from_the_file(
