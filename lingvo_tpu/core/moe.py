@@ -16,15 +16,23 @@ there is no exchange to shape the dispatch for, so this layer sorts instead:
   dispatch  the `T x k` (token, expert) pairs sorted by expert: a gather of
             `[T * k, D]` rows and the experts' run lengths `[E]`;
   experts   grouped matmuls over the runs, widths D -> F -> D; `activation`
-            'reglu': three, (relu(x W_gate) * (x W_up)) W_down; 'relu2':
-            two, relu(x W_up)^2 W_down, and no W_gate exists;
+            'reglu': three, (relu(x W_gate) * (x W_up)) W_down; 'swiglu':
+            three, (silu(x W_gate) * (x W_up)) W_down; 'relu2': two,
+            relu(x W_up)^2 W_down, and no W_gate exists;
   combine   each row weighted, unsorted, and a token's k rows summed;
   shared    where `shared_hidden_dim` > 0, one more expert of that width
             that every token goes through, added to the routed sum.
 
 No capacity, no `[T, E, C]` tensor, no dropped token; an expert with no token
-is a run of length 0. The layer holds all its experts and runs no collective:
-an expert-parallel form would be told which experts it holds (ROADMAP R1).
+is a run of length 0. The layer runs no collective. By default it holds all
+the experts its router scores; as one of several chips that share the layer
+it is TOLD which it holds (`first_expert`, `num_experts_held`: a contiguous
+run): the router keeps its whole width and chooses among all `num_experts`,
+the weights of a token's k are normalised over all k, and a (token, expert)
+pair whose expert lives elsewhere sorts behind every run as a padding token's
+does: it costs the grouped matmuls nothing and adds nothing here. The shared
+expert is computed by every share. Nothing stands in for the other chips or
+for the exchange that would sum their parts (ROADMAP R1).
 
 The grouped matmul is `GroupedMatmul`: megablox's Pallas kernel on a TPU
 where the shapes tile, `jax.lax.ragged_dot` elsewhere (PERF.md section 6,
@@ -48,6 +56,8 @@ from lingvo_tpu.core.py_utils import WeightInit, WeightParams
 
 _GMM_TILE = 128   # megablox tiles m, k and n; a shape it cannot tile takes
 #                   ragged_dot
+# `activation`s with a gate matrix -> what the gate's product goes through
+_GATE_ACTIVATION = {"reglu": jax.nn.relu, "swiglu": jax.nn.silu}
 
 
 def _StoredWidth(d: int, f: int) -> int:
@@ -99,7 +109,15 @@ class DroplessMoELayer(base_layer.BaseLayer):
     p = super().Params()
     p.Define("input_dim", 0, "Model dim.")
     p.Define("hidden_dim", 0, "Width of one expert.")
-    p.Define("num_experts", 0, "Experts held (all of them).")
+    p.Define("num_experts", 0,
+             "Experts the router scores and chooses among; all of them are "
+             "held here unless num_experts_held says fewer.")
+    p.Define("first_expert", 0,
+             "The first expert of the contiguous run this layer holds.")
+    p.Define("num_experts_held", 0,
+             "Experts held: [first_expert, first_expert + num_experts_held) "
+             "of num_experts; 0 = all of them. The experts' matrices have "
+             "this many, the router num_experts.")
     p.Define("num_experts_per_token", 2, "k: experts a token reaches.")
     p.Define("norm_tpl", layers_lib.RmsNorm.Params(), "Norm template.")
     p.Define("scoring", "softmax",
@@ -109,8 +127,8 @@ class DroplessMoELayer(base_layer.BaseLayer):
              "the chosen scores).")
     p.Define("routed_scale", 1.0, "Factor on the weights ('sigmoid').")
     p.Define("activation", "reglu",
-             "'reglu': relu(x W_gate) * (x W_up). 'relu2': relu(x W_up)^2, "
-             "two matrices an expert.")
+             "'reglu': relu(x W_gate) * (x W_up). 'swiglu': silu(x W_gate) "
+             "* (x W_up). 'relu2': relu(x W_up)^2, two matrices an expert.")
     p.Define("shared_hidden_dim", 0,
              "Width of the shared expert every token goes through (same "
              "activation); 0 = none.")
@@ -126,9 +144,13 @@ class DroplessMoELayer(base_layer.BaseLayer):
     assert p.input_dim > 0 and p.hidden_dim > 0
     assert 0 < p.num_experts_per_token <= p.num_experts
     assert p.scoring in ("softmax", "sigmoid"), p.scoring
-    assert p.activation in ("reglu", "relu2"), p.activation
+    assert p.activation in _GATE_ACTIVATION or p.activation == "relu2", (
+        p.activation)
     assert p.router_reads in ("layer_input", "normed_input"), p.router_reads
     d, e = p.input_dim, p.num_experts
+    held = self.num_held
+    assert 0 <= p.first_expert and p.first_expert + held <= e, (
+        p.first_expert, held, e)
     f = _StoredWidth(d, p.hidden_dim)
     self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=d))
     self.CreateVariable(
@@ -139,16 +161,22 @@ class DroplessMoELayer(base_layer.BaseLayer):
           (e,), WeightInit.Constant(0.0), p.dtype))
     # fans are an expert's own, not the stack's
     fs = p.shared_hidden_dim
-    gated = p.activation == "reglu"
+    gated = p.activation in _GATE_ACTIVATION
     for name, shape, fan_in in (
-        [("w_gate", (e, d, f), d)] * gated
-        + [("w_up", (e, d, f), d), ("w_down", (e, f, d), f)]
+        [("w_gate", (held, d, f), d)] * gated
+        + [("w_up", (held, d, f), d), ("w_down", (held, f, d), f)]
         + ([("w_shared_gate", (d, fs), d)] * gated
            + [("w_shared_up", (d, fs), d), ("w_shared_down", (fs, d), fs)]
            ) * (fs > 0)):
       self.CreateVariable(
           name, WeightParams(shape, WeightInit.Gaussian(
               1.0 / math.sqrt(fan_in)), p.dtype))
+
+  @property
+  def num_held(self) -> int:
+    """Experts this layer holds: the length of `routed` and of the experts'
+    matrices."""
+    return self.p.num_experts_held or self.p.num_experts
 
   def InstantiateVariables(self, key):
     theta = super().InstantiateVariables(key)
@@ -180,7 +208,7 @@ class DroplessMoELayer(base_layer.BaseLayer):
     a buffer: 0.25 GB a matrix, 2 ms each on a v5e, PERF.md section 6,
     PR 35), so the layer addresses its run of groups in the stack, as an
     attention layer addresses its pages."""
-    return (("w_gate",) if self.p.activation == "reglu" else ()) + (
+    return (("w_gate",) if self.p.activation in _GATE_ACTIVATION else ()) + (
         "w_up", "w_down")
 
   def RouterLogits(self, theta, x):
@@ -208,16 +236,22 @@ class DroplessMoELayer(base_layer.BaseLayer):
 
   def _Experts(self, theta, x, logits, valid, layer=None):
     """x [T, D] normed tokens, logits f32 [T, E], valid bool [T] or None ->
-    (f32-weighted sum of each token's k experts [T, D], tokens by expert
-    [E] int32). layer: set where the experts' matrices arrive stacked over
-    layers (StackAddressed): this layer's experts are groups
-    [layer * E, (layer + 1) * E) of the stack seen as one run of groups."""
+    (f32-weighted sum of each token's experts held here [T, D], tokens by
+    held expert [Eh] int32). layer: set where the experts' matrices arrive
+    stacked over layers (StackAddressed): this layer's experts are groups
+    [layer * Eh, (layer + 1) * Eh) of the stack seen as one run of groups."""
     p = self.p
     th = self.CastTheta(theta)
     t, d = x.shape
-    e, k = p.num_experts, p.num_experts_per_token
+    e, k = self.num_held, p.num_experts_per_token
     with observe.Scope("moe_route"):
       top_idx, weights = self._Route(th, logits)                   # [T, k]
+      if e != p.num_experts:
+        # a pair whose expert lives elsewhere sorts behind every run, as a
+        # padding token's; its weight stays in the sum the k were
+        # normalised over
+        top_idx = top_idx - p.first_expert
+        top_idx = jnp.where((top_idx >= 0) & (top_idx < e), top_idx, e)
       if valid is not None:
         # a padding token's pairs sort behind every expert's run
         top_idx = jnp.where(valid[:, None], top_idx, e)
@@ -233,9 +267,10 @@ class DroplessMoELayer(base_layer.BaseLayer):
           jnp.zeros((layers * e,), jnp.int32), counts,
           (jnp.asarray(layer, jnp.int32) * e,))
       flat = lambda w: w.reshape((-1,) + w.shape[2:])
+    gate = _GATE_ACTIVATION.get(p.activation)   # None: 'relu2', no gate matrix
     with observe.Scope("moe_experts"):
-      if p.activation == "reglu":
-        h = jax.nn.relu(GroupedMatmul(xs, flat(th.w_gate), sizes))
+      if gate is not None:
+        h = gate(GroupedMatmul(xs, flat(th.w_gate), sizes))
         h = h * GroupedMatmul(xs, flat(th.w_up), sizes)
       else:
         h = jnp.square(jax.nn.relu(GroupedMatmul(xs, flat(th.w_up), sizes)))
@@ -250,8 +285,8 @@ class DroplessMoELayer(base_layer.BaseLayer):
     if p.shared_hidden_dim:
       with observe.Scope("moe_shared"):
         up = jnp.einsum("td,df->tf", x, th.w_shared_up)
-        if p.activation == "reglu":
-          h = jax.nn.relu(jnp.einsum("td,df->tf", x, th.w_shared_gate)) * up
+        if gate is not None:
+          h = gate(jnp.einsum("td,df->tf", x, th.w_shared_gate)) * up
         else:
           h = jnp.square(jax.nn.relu(up))
         out = out + jnp.einsum("tf,fd->td", h, th.w_shared_down)
@@ -262,7 +297,8 @@ class DroplessMoELayer(base_layer.BaseLayer):
     """inputs [..., D]; router_logits f32 [..., E] (RouterLogits of the
     transformer layer's input; None where the router reads this layer's own
     normed input); paddings [...] (1 = padding) or None.
-    Returns (inputs + experts [..., D], tokens by expert [E] int32)."""
+    Returns (inputs + experts held here [..., D], tokens by held expert
+    [Eh] int32)."""
     p = self.p
     theta = base_layer.TakeSlices(theta)
     with observe.Scope("norm"):
@@ -292,10 +328,16 @@ class DroplessMoELayer(base_layer.BaseLayer):
   # -- the serving step ------------------------------------------------------
 
   def InitPagedStates(self, theta) -> NestedMap:
-    """`routed` [E] int32: tokens each expert got in the newest step. It is
-    the engine's to read (Stats: moe_*), overwritten every step."""
+    """`routed` [Eh] int32: tokens each held expert got in the newest step.
+    It is the engine's to read (Stats: moe_*), overwritten every step. A
+    layer that holds a share of its experts adds `elsewhere` [] int32: the
+    step's (token, expert) pairs whose expert lives on another chip
+    (moe_pairs_elsewhere)."""
     del theta
-    return NestedMap(routed=jnp.zeros((self.p.num_experts,), jnp.int32))
+    states = NestedMap(routed=jnp.zeros((self.num_held,), jnp.int32))
+    if self.num_held != self.p.num_experts:
+      states.elsewhere = jnp.zeros((), jnp.int32)
+    return states
 
   def RaggedStep(self, theta, inputs, cached_states, rows, *,
                  router_logits=None, layer=None):
@@ -307,7 +349,12 @@ class DroplessMoELayer(base_layer.BaseLayer):
     out, counts = self.FPropWithCounts(
         theta, inputs, router_logits, paddings,
         layer=layer if theta.w_up.ndim == 4 else None)
+    new_states = NestedMap(routed=counts)
+    if "elsewhere" in cached_states:
+      new_states.elsewhere = (
+          self.p.num_experts_per_token * jnp.sum(rows.valid.astype(jnp.int32))
+          - jnp.sum(counts))
     if layer is None:
-      return out, NestedMap(routed=counts)
-    return out, NestedMap(
-        routed=cached_states.routed.at[layer].set(counts))
+      return out, new_states
+    return out, jax.tree_util.tree_map(
+        lambda old, new: old.at[layer].set(new), cached_states, new_states)

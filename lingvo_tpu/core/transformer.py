@@ -954,6 +954,10 @@ class SharedStateLayer(base_layer.BaseLayer):
       assert not self._experts or (
           self.fflayer.p.router_reads == "normed_input"), (
               "an expert layer of a BlockSequence routes from its own input")
+      assert not self._experts or (
+          self.fflayer.num_held == self.fflayer.p.num_experts), (
+              "an expert layer of a BlockSequence holds every expert it "
+              "routes over: its step keeps no count of the pairs elsewhere")
 
   def StackAddressed(self) -> set:
     """As TransformerLayer.StackAddressed: the experts' matrices."""

@@ -13,6 +13,7 @@ except ImportError:
 try:
   from lingvo_tpu.models.lm.params import nemotron_h  # noqa: F401
   from lingvo_tpu.models.lm.params import brumby  # noqa: F401
+  from lingvo_tpu.models.lm.params import mistral4  # noqa: F401
   from lingvo_tpu.models.lm.params import phi4flash  # noqa: F401
   from lingvo_tpu.models.lm.params import smallthinker  # noqa: F401
 except ImportError:

@@ -63,9 +63,13 @@ ENGINE_COUNTER_KEYS = (
     # a step returns beside its tokens: (token, expert) pairs routed; summed
     # over steps and layers, the tokens of the fullest expert, the tokens
     # of the mean expert (routed / experts, a float), and the experts that
-    # got any token. All zero on a stack without expert layers.
+    # got any token. All zero on a stack without expert layers. Where a
+    # layer holds a share of the experts its router scores (`first_expert`,
+    # `num_experts_held`), these four count the HELD experts' pairs, and
+    # `moe_pairs_elsewhere` the pairs whose expert lives on another chip
+    # (0 where every layer holds all its experts).
     "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
-    "moe_experts_active",
+    "moe_experts_active", "moe_pairs_elsewhere",
     # power-retention layers (core/retention.PowerRetention), counted when a
     # step is dispatched: live rows that read or write a slot state, pages
     # folded into one (a layer: times the layers for the stack's), and the
@@ -370,6 +374,14 @@ DEVICE_SCOPES = {
     "attend_descriptors": ("ragged_attend", "the query-block descriptors "
                            "of a ragged kernel called without the step's "
                            "plan (with it: `attend_plan`)"),
+    "mla_attend": ("atten", "ops/latent_attend.LatentAttend: the latent "
+                   "attend kernels of the absorbed form (named after it), "
+                   "32 query heads over one latent row a token, with the "
+                   "heads' re-layout round them"),
+    "mla_absorb": ("atten", "latent attention's absorption: q_nope through "
+                   "W_kvb's key half into the latent space before the "
+                   "attend (with the query's scale), the context through its "
+                   "value half after it"),
     "diff_attend": ("atten", "ops/diff_attend.DiffAttend: the differential "
                     "attend kernels (named after it)"),
     "diff_layout": ("diff_attend", "round those kernels: the padded queries, "

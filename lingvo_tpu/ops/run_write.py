@@ -216,16 +216,18 @@ def SupportedOnTpu(h: int) -> bool:
 # -- XLA twin (the CPU serving path) -----------------------------------------
 
 
-def _XlaWriteRuns(k_pool, v_pool, k_new, v_new, pages, runs: Runs):
-  pools = (k_pool, v_pool)
-  for c, w in enumerate(Widths(k_pool.shape[1], k_new.shape[0])):
+def _XlaWriteRuns(pools, news, pages, runs: Runs):
+  """pools: `[NP, P, ...]` each, news: `[T, ...]` each (a pool's token rows)
+  -> the pools, every live piece written."""
+  pools = tuple(pools)
+  for c, w in enumerate(Widths(pools[0].shape[1], news[0].shape[0])):
     def _Piece(i, pools, w=w, first=runs.first[c]):
       j = first + i
       return tuple(
           jax.lax.dynamic_update_slice(
               pool, jax.lax.dynamic_slice_in_dim(new, runs.tok[j], w)[None],
-              (pages[j], runs.off[j], 0, 0))
-          for pool, new in zip(pools, (k_new, v_new)))
+              (pages[j], runs.off[j]) + (0,) * (pool.ndim - 2))
+          for pool, new in zip(pools, news))
     pools = jax.lax.fori_loop(0, runs.counts[c], _Piece, pools)
   return pools
 
@@ -307,9 +309,20 @@ def WriteRuns(k_pool, v_pool, k_new, v_new, pages, runs: Runs, *,
   if lowering == "auto" and not SupportedOnTpu(k_pool.shape[-1]):
     lowering = "xla"
   if rba.Lowering(lowering) == "xla":
-    return _XlaWriteRuns(k_pool, v_pool, k_new, v_new, pages, runs)
+    return _XlaWriteRuns((k_pool, v_pool), (k_new, v_new), pages, runs)
   if interpret is None:
     interpret = jax.default_backend() != "tpu"
   return tuple(_RunWriteCall(
       runs.first, runs.counts, pages.astype(jnp.int32), runs.tok, runs.off,
       k_new, v_new, k_pool, v_pool, interpret=interpret))
+
+
+def WriteRowRuns(pool, new, pages, runs: Runs):
+  """`WriteRuns` for ONE pool whose token is a single row of its page,
+  `[NP, P, W]` with `new` `[T, W]` (core/mla.py's latent rows): the same
+  pieces through the XLA lowering on every backend. The chip tiles such a
+  page over (P, W), a token's row is a sixteenth of a bf16 tile, and the
+  kernel's copies move whole tiles; a `dynamic_update_slice` a piece writes
+  in place under the loop. -> pool."""
+  assert new.dtype == pool.dtype and pool.ndim == 3, (new.dtype, pool.shape)
+  return _XlaWriteRuns((pool,), (new,), pages.astype(jnp.int32), runs)[0]

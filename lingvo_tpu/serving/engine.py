@@ -201,14 +201,16 @@ class _StepSpans:
                               valid_tokens, prefill_tokens, rows, counters)
 
 
-def _MoeCountLeaves(states):
+def _MoeCountLeaves(states, name="routed", width=None):
   """The `routed` leaves of a decode state (core/moe.DroplessMoELayer:
-  tokens by expert of the newest step), each as [layers of it, experts];
-  None where the stack has no expert layer."""
-  leaves = [leaf.reshape(-1, leaf.shape[-1]) for path, leaf in
+  tokens by held expert of the newest step), each as [layers of it,
+  experts]; None where the stack has no expert layer. name='elsewhere',
+  width=1: the pairs a layer that holds a share of its experts sent nowhere
+  here, [layers of it, 1]; None where every layer holds all it routes over."""
+  leaves = [leaf.reshape(-1, width or leaf.shape[-1]) for path, leaf in
             jax.tree_util.tree_flatten_with_path(states)[0]
             if str(getattr(path[-1], "key", getattr(path[-1], "name", "")))
-            == "routed"]
+            == name]
   return leaves or None
 
 
@@ -406,6 +408,14 @@ class ServingLoop:
       self._kind_pages = kv_cache.KindPages(
           self.alloc, windows, max(1, self.prefill_token_budget), max_batch,
           table_pages)
+    ragged_only = sorted({type(m).__name__ for m, _ in self._mixer_layers
+                          if getattr(m, "ragged_only", False)})
+    if spec is not None and ragged_only:
+      raise ValueError(
+          f"spec (a draft source): {', '.join(ragged_only)} serves through "
+          "the packed step alone; a draft pass and its rollback run the dense "
+          "decode contracts (InitStates, ExtendStep, Prefill, PagedStep), "
+          "which it does not have")
     self.state_pool = None
     if self.mixers["num_ssm"] > 0:
       self.state_pool = kv_cache.StateSlotPool(
@@ -544,6 +554,7 @@ class ServingLoop:
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
+    self._moe_shares = _MoeCountLeaves(self._states, "elsewhere", 1) is not None
     # power-retention layers: mixers that hold pages AND a slot state
     self._retention_layers = sum(
         reps for m, reps in self._mixer_layers
@@ -728,7 +739,14 @@ class ServingLoop:
           return sampled, new_states
         # [layers, experts] tokens by expert of this step: a copy (the
         # states are donated to the next step before this one is fetched)
-        return sampled, jnp.concatenate(routed, axis=0), new_states
+        counts = jnp.concatenate(routed, axis=0)
+        elsewhere = _MoeCountLeaves(new_states, "elsewhere", 1)
+        if elsewhere is not None:
+          # layers that hold a share of their experts: one more column, the
+          # pairs whose expert lives on another chip
+          counts = jnp.concatenate(
+              [counts, jnp.concatenate(elsewhere, axis=0)], axis=1)
+        return sampled, counts, new_states
     elif spec_w == 1:
       def _RaggedStep(theta, states, tok_ids, rows, tables, seeds, pos,
                       row_k, q_logits):
@@ -1221,7 +1239,7 @@ class ServingLoop:
     if self._moe_layers is not None:
       out.update((k, self._counters[k].value) for k in (
           "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
-          "moe_experts_active"))
+          "moe_experts_active", "moe_pairs_elsewhere"))
     if self._kind_pages is not None:
       out["window_pages_released"] = self._kind_pages.pages_released
       out["window_pages_allocated"] = self._kind_pages.pages_allocated
@@ -1370,6 +1388,9 @@ class ServingLoop:
       spans.To("commit")
       events = self.sched.CommitRaggedStep(batch, sampled, out, alen)
       if routed is not None:
+        if self._moe_shares:
+          routed, elsewhere = routed[:, :-1], routed[:, -1]
+          self._counters["moe_pairs_elsewhere"].Inc(int(elsewhere.sum()))
         self._counters["moe_tokens_routed"].Inc(int(routed.sum()))
         self._counters["moe_expert_load_max"].Inc(int(routed.max(-1).sum()))
         self._counters["moe_expert_load_mean"].Inc(

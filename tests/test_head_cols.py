@@ -71,6 +71,12 @@ _FAMILIES = {
         "lm.nemotron_h.Nemotron3NanoTiny", dtype),
     "brumby": lambda dtype: _Registered("lm.brumby.BrumbyTiny", dtype),
 }
+# families newer than the tables that other test files keep a family
+# (tests/test_step_trace.py, tests/test_ragged_step.py: the parent's counts)
+_NEWER_FAMILIES = {
+    "mistral4": lambda dtype: _Registered(
+        "lm.mistral4.MistralSmall4Tiny", dtype),
+}
 
 
 class _Calls:
@@ -110,9 +116,9 @@ def _MixedStepEngine(task, theta, **kw):
 
 
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
-@pytest.mark.parametrize("family", list(_FAMILIES))
+@pytest.mark.parametrize("family", list(_FAMILIES) + list(_NEWER_FAMILIES))
 def test_head_cols_are_columns_of_the_full_logits(family, dtype):
-  task, theta = _FAMILIES[family](
+  task, theta = {**_FAMILIES, **_NEWER_FAMILIES}[family](
       jnp.float32 if dtype == "f32" else jnp.bfloat16)
   _, calls, _ = _MixedStepEngine(task, theta)
   theta_, states, tok_ids, rows, tables = calls.calls[-1][0][:5]

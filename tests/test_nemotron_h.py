@@ -627,3 +627,50 @@ def test_an_expert_layer_runs_no_mixer_and_a_mixer_layer_no_feed_forward(tiny):
                    ).lower(th, x, st, shared).as_text(debug_info=True)
     scopes = set(re.findall(r"[/\"](atten|ffn)[/\"]", text))
     assert scopes == {"atten", "ffn"} - {absent}, ((b, j), scopes)
+
+
+# -- a layer that holds a share of the experts its router scores ---------------
+
+
+@pytest.mark.parametrize("shares", [2, 4, 8])
+def test_the_shares_of_the_tiny_presets_experts_add_up_to_the_reference(
+    tiny, shares, monkeypatch):
+  """Nemotron3NanoTiny's expert layer (8 relu2 experts top-3, sigmoid scores
+  over all, a selection bias, a shared expert, the router on the layer's own
+  normed input) cut into `shares` contiguous runs: the routed parts of all the
+  shares plus the shared expert ONCE are the uncut layer's output as the
+  reference computes it. Every share computes the shared expert; its weights
+  stay the chosen scores over the sum of all three wherever they live."""
+  task, theta = tiny[9]
+  block = next(i for i, (layers, _) in enumerate(task.stack.p.blocks)
+               if any(l.tr_fflayer_tpl is not None and l.mixer_tpl is None
+                      for l in layers))
+  body = getattr(task.stack, f"block_{block}")
+  j = next(i for i, l in enumerate(body.x_layers) if l.mixer is None)
+  tpl = body.x_layers[j].fflayer.p.Copy()
+  th = jax.tree_util.tree_map(
+      lambda a: a[0], theta.stack[f"block_{block}"].x_layers[j].fflayer)
+  e, d = tpl.num_experts, tpl.input_dim
+  monkeypatch.setattr(ref, "_PIECE", 4)
+  ref._ARCH.update(ref._Arch(d))
+  x = jnp.asarray(np.random.RandomState(shares).randn(19, d), jnp.float32)
+  layer_ff = {"fflayer": jax.tree_util.tree_map(lambda a: a[None], dict(th))}
+  want = ref._Experts(layer_ff, 0, x, 1)
+  u = ref._RmsNorm(x, th.ln.scale)
+  shared = jnp.square(jax.nn.relu(u @ th.w_shared_up)) @ th.w_shared_down
+  held = e // shares
+  total, counted = jnp.zeros_like(x), 0
+  for s in range(shares):
+    layer = tpl.Copy().Set(name="moe", first_expert=s * held,
+                           num_experts_held=held).Instantiate()
+    layer.FinalizePaths()
+    mine = th.Copy()
+    for name in layer.StackAddressed():
+      mine[name] = th[name][s * held:(s + 1) * held]
+    out, counts = layer.FPropWithCounts(mine, x)
+    assert counts.shape == (held,)
+    counted += int(counts.sum())
+    total = total + (out - x - shared)
+  assert counted == x.shape[0] * tpl.num_experts_per_token
+  np.testing.assert_allclose(np.asarray(x + total + shared), np.asarray(want),
+                             atol=2e-5)

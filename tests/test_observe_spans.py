@@ -465,7 +465,7 @@ class TestScopeNames:
 # -- the one declared tree (observe.schema.DEVICE_SCOPES) ---------------------
 
 _PROGRAMS = ("dense_train", "dense", "smallthinker", "phi4flash",
-             "nemotron_h", "brumby", "pallas_attend")
+             "nemotron_h", "brumby", "mistral4", "pallas_attend")
 
 
 def _Avals(tree):
@@ -520,8 +520,8 @@ def _Lowered(program, tmp):
 
     return jax.jit(_All).lower(q, pool, wide, new, dq, dpool)
   from lingvo_tpu.serving import engine as engine_lib
-  from tests.test_head_cols import _FAMILIES
-  task, theta = _FAMILIES[program](jnp.float32)
+  from tests.test_head_cols import _FAMILIES, _NEWER_FAMILIES
+  task, theta = {**_FAMILIES, **_NEWER_FAMILIES}[program](jnp.float32)
   eng = engine_lib.ServingLoop(
       task, theta, page_size=8, num_pages=48, max_batch=4, max_seq_len=128,
       prefill_token_budget=8)

@@ -590,3 +590,64 @@ def test_int8_pages_under_kv_groups_are_refused(tiny):
         jnp.zeros((4,), jnp.int32), jnp.ones((4,), jnp.int32), page_size=8,
         k_scale=jnp.ones((3, 2, 8)), v_scale=jnp.ones((3, 2, 8)),
         lowering="pallas")
+
+
+# -- a layer that holds a share of the experts its router scores ---------------
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_the_shares_of_the_tiny_presets_experts_add_up_to_the_reference(
+    tiny, shares, monkeypatch):
+  """One of `shares` chips that share a layer holds a contiguous run of its
+  experts and the router's whole width (SmallThinkerTiny's expert layer: 8
+  ReGLU experts top-2, logits handed in): what each share adds to the stream,
+  summed over the shares, is the uncut layer's routed sum as the reference
+  computes it, and the weights of a token's two stay normalised over both
+  wherever the two live. Padding tokens count nowhere; a share's `routed` and
+  `elsewhere` are the numpy counts."""
+  task, _ = tiny
+  tpl = task.stack.body.x_layers[0].fflayer.p.Copy()
+  e, k, d = tpl.num_experts, tpl.num_experts_per_token, tpl.input_dim
+  whole = tpl.Copy().Set(name="moe").Instantiate()
+  whole.FinalizePaths()
+  theta = whole.InstantiateVariables(jax.random.PRNGKey(5))
+  rng = np.random.RandomState(shares)
+  t = 21
+  x = jnp.asarray(rng.randn(t, d), jnp.float32)
+  logits = jnp.asarray(rng.randn(t, e), jnp.float32)
+  valid = np.ones(t, bool)
+  valid[[3, 20]] = False
+  rows = ragged_lib.RaggedRows(*(jnp.asarray(a) for a in
+                                 ragged_lib.BuildRaggedRows([t], [0], t, t)))
+  rows = rows._replace(valid=jnp.asarray(valid))
+  monkeypatch.setattr(ref, "_PIECE", 4)
+  monkeypatch.setitem(ref._ARCH, "experts_per_token", k)
+  monkeypatch.setitem(ref._ARCH, "eps", float(tpl.norm_tpl.epsilon))
+  ff = jax.tree_util.tree_map(lambda a: a[None], dict(theta))
+  want = ref._Experts(ff, 0, ref._RmsNorm(x, theta.ln.scale), logits)
+  top = np.argsort(-np.asarray(logits), -1)[:, :k][valid]
+  held = e // shares
+  total = jnp.zeros_like(x)
+  for s in range(shares):
+    layer = tpl.Copy().Set(name="moe", first_expert=s * held,
+                           num_experts_held=held).Instantiate()
+    layer.FinalizePaths()
+    mine = theta.Copy()
+    for name in layer.StackAddressed():
+      mine[name] = theta[name][s * held:(s + 1) * held]
+    assert jax.tree_util.tree_map(lambda a: a.shape, dict(mine)) == (
+        jax.tree_util.tree_map(lambda a: a.shape, dict(
+            layer.InstantiateVariables(jax.random.PRNGKey(0)))))
+    out, states = jax.jit(lambda th, x, r, layer=layer: layer.RaggedStep(
+        th, x[None], layer.InitPagedStates(th), rows,
+        router_logits=r[None]))(mine, x, logits)
+    total = total + (out[0] - x)
+    here = (top >= s * held) & (top < (s + 1) * held)
+    np.testing.assert_array_equal(
+        np.asarray(states.routed),
+        np.bincount(top[here] - s * held, minlength=held))
+    assert int(states.elsewhere) == int((~here).sum())
+  np.testing.assert_allclose(np.asarray(total)[valid], np.asarray(want)[valid],
+                             atol=1e-5)
+  # the layer that holds all of them keeps the state it had
+  assert "elsewhere" not in whole.InitPagedStates(theta)
