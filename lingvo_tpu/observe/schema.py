@@ -48,6 +48,12 @@ ENGINE_COUNTER_KEYS = (
     # which is the grid every call ran before PR 46: their ratio is the share
     # of that grid that held work. Both 0 where the twins run.
     "attend_live_pairs", "attend_grid_pairs",
+    # the live pairs whose program ran no mask (ops/latent_attend.py: a block
+    # of the widest rung at a page that lies whole under every query's
+    # horizon), by ops/ragged_block_attend.ClearPairs from the host's rows:
+    # over `attend_live_pairs`, the share of programs that ran unmasked. 0
+    # where no kernel of the step reads the plan's `clear`.
+    "attend_clear_pairs",
     # the page write by runs (ops/run_write.py), counted when a step is
     # dispatched from the host's own rows: the runs of tokens the step's list
     # holds (a layer moves each as a few copies) and the tokens in them. Their

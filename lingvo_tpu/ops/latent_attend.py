@@ -78,7 +78,8 @@ def PlanKey(num_heads: int, page_size: int, *, tree: bool = True,
   heads: no window, a token's heads on the packed axis."""
   kernel = rba.Lowering(lowering) == "pallas"
   return rba.PlanKey(page_size, 0, QueryBlock(num_heads),
-                     Lanes(num_heads) if kernel else num_heads, tree, kernel)
+                     Lanes(num_heads) if kernel else num_heads, tree, kernel,
+                     clear=kernel)
 
 
 def SupportedOnTpu(page_size: int, value_dim: int) -> bool:
@@ -129,16 +130,33 @@ def _XlaLatentAttend(q, pool, block_tables, row_of, q_end, page_size: int,
 
 
 def _LatentAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
-                        tables_ref, n_ref, first_ref, q_hbm, cols_ref,
-                        rows_ref, _, out_hbm, qb, mb, lb, accb, ob, sem, *,
-                        page_size: int, value_dim: int, lanes: int,
+                        tables_ref, n_ref, first_ref, clear_ref, q_hbm,
+                        cols_ref, rows_ref, _, out_hbm, qb, mb, lb, accb, ob,
+                        sem, *, page_size: int, value_dim: int, lanes: int,
                         rungs: tuple[int, ...]):
   """The (query block, logical page) program: ops/ragged_block_attend.
   _GroupedAttendKernel's for one KV head whose value is its key's first
   `value_dim` columns. q_hbm `[T * lanes + Bq, W]`, out_hbm `[T * lanes + Bq,
   V]`, rows_ref one page `[1, P, W]`. A block's life runs over the leading
   rows of every scratch that hold its valid queries (the first of `rungs`);
-  rows past the rung are never written, and the output starts as zeros."""
+  rows past the rung are never written, and the output starts as zeros.
+
+  A program does the vector work its page needs. The widest rung has two
+  bodies. A page under `clear_ref[i]` lies whole under the horizon of every
+  query of the block (`AttendPlan.clear`): its body reads no mask column,
+  builds no mask and selects nothing, carries the softmax's statistics as the
+  lane-replicated `[rows, 128]` the scratch holds (no slice in, no broadcast
+  out; a page of 128 slots), and runs the rung's rows as two halves, two
+  chains of products and vector passes with nothing between them, so one
+  half's products run beside the other's passes. Any other page (the one or
+  two the block's own tokens sit in) takes the masked body, over `[rows, 1]`
+  statistics as it was. Both give the same bits: the same float ops in the
+  same order a (query, slot). A masked page left the rung's rows that are not
+  the block's at an exact zero by itself; a clear one does not, so `_Emit`
+  zeroes them, once a block. A lower rung (a decode row) keeps the one masked
+  body: its program is its fixed cost and its page's copy, and a second body
+  is a second trace of the kernel in set-up. (PERF.md section 6, PR 55: what
+  each of these bought, and what was tried and lost.)"""
   pair = pl.program_id(0)
   i, page = blk_ref[pair], page_ref[pair]
   nv = n_ref[i]
@@ -160,24 +178,43 @@ def _LatentAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
       lb[held] = jnp.zeros((rows, LANES), lb.dtype)
       accb[held] = jnp.zeros((rows, value_dim), accb.dtype)
 
-    slot = page * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                         # [1, P]
-    cols = cols_ref[0, held]                                  # [rows, 4]
-    keep = (slot < cols[:, 0:1]) & rba._AncestorOk(
-        slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [rows, P]
-    m, l, acc = rba._BlockPageAttend(
-        qb[held], rows_ref[0], rows_ref[0, :, :value_dim], keep,
-        mb[held, :1], lb[held, :1], accb[held],
-        (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
-    mb[held] = jnp.broadcast_to(m, (rows, LANES))
-    lb[held] = jnp.broadcast_to(l, (rows, LANES))
-    accb[held] = acc
+    def _Page(masked: bool):
+      # the statistics as the scratch holds them, where a page is as wide
+      wide = (not masked and page_size == LANES and value_dim % LANES == 0
+              and rows % (2 * lanes) == 0)
+      for r in ((pl.ds(0, rows // 2), pl.ds(rows // 2, rows // 2)) if wide
+                else (held,)):
+        keep = None
+        if masked:
+          slot = page * page_size + jax.lax.broadcasted_iota(
+              jnp.int32, (1, page_size), 1)                   # [1, P]
+          cols = cols_ref[0, r]                               # [rows, 4]
+          keep = (slot < cols[:, 0:1]) & rba._AncestorOk(
+              slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])
+        stat = (lambda ref: ref[r]) if wide else (lambda ref: ref[r, :1])
+        m, l, acc = rba._BlockPageAttend(
+            qb[r], rows_ref[0], rows_ref[0, :, :value_dim], keep,
+            stat(mb), stat(lb), accb[r],
+            (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+        mb[r] = jnp.broadcast_to(m, (r.size, LANES))
+        lb[r] = jnp.broadcast_to(l, (r.size, LANES))
+        accb[r] = acc
+
+    if rows > rba.ClearRung(rungs):
+      is_clear = page < clear_ref[i]
+      pl.when(is_clear)(functools.partial(_Page, False))
+      pl.when(jnp.logical_not(is_clear))(functools.partial(_Page, True))
+    else:
+      _Page(True)
 
     @pl.when(page == last_ref[i])
     def _Emit():
       # a query of the rung's rows that is not this block's comes out an
-      # exact zero
-      ob[held] = _Finish(lb[held, :1], accb[held], ob.dtype)
+      # exact zero (the block's own lead its window)
+      mine = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < nv
+      ob[held] = jnp.where(
+          mine, _Finish(lb[held, :1], accb[held], ob.dtype),
+          jnp.zeros((), ob.dtype))
       _Copy(ob.at[held], out_hbm.at[window_q])
 
   # two nested branches a rung, as _GroupedAttendKernel's (and for its
@@ -280,7 +317,7 @@ def LatentAttend(q, pool, block_tables, row_of, q_end, *, page_size: int,
   # rows of slack past T for the last block's window
   laid = jnp.pad(q, ((0, 0), (0, lanes - n), (0, 0))).reshape(t * lanes, w)
   out = _LatentCall(
-      blocks.pairs, rba._Prefetch(blocks, tables),
+      blocks.pairs, rba._Prefetch(blocks, tables) + (blocks.clear,),
       jnp.pad(laid, ((0, bq), (0, 0))), blocks.cols, pool,
       page_size=page_size, value_dim=value_dim, lanes=lanes,
       rungs=rba.BlockRungs(bq, lanes), interpret=interpret)

@@ -319,6 +319,8 @@ class PlanKey(NamedTuple):
   #                chain sentinels (q_start 0, every ancestor bit set)
   kernel: bool   # the Pallas lowering serves the call: a twin's call takes
   #                no descriptors and none are built for it
+  clear: bool = False  # the call's kernel reads `AttendPlan.clear` (ops/
+  #                latent_attend.py's does); built for no other key
 
 
 def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
@@ -357,6 +359,9 @@ class AttendPlan(NamedTuple):
   blk: jnp.ndarray    # [NB * grid_pages] a pair's block
   page: jnp.ndarray   # [NB * grid_pages] a pair's logical page
   pairs: jnp.ndarray  # [] the live pairs: the grid's length
+  clear: object = None  # [NB] the leading logical pages every query of the
+  #                     block sees WHOLE (no mask changes a score there);
+  #                     None unless the key asks for it (`PlanKey.clear`)
 
 
 def _LivePairs(n, page0, last, size: int):
@@ -396,8 +401,8 @@ def _LivePairs(n, page0, last, size: int):
 
 
 def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
-                      page_size: int, t_pages: int,
-                      window: int = 0) -> AttendPlan:
+                      page_size: int, t_pages: int, window: int = 0,
+                      clear: bool = False) -> AttendPlan:
   """Cuts each row's run of tokens into blocks of Bq queries.
 
   A few [T]- and [NB, Bq]-sized integer ops on what the step already has
@@ -469,15 +474,31 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
   n = lax.select(lax.lt(k, lax.broadcast(n_live, (nb,))),
                  lax.reduce_sum(lax.convert_element_type(member, i32), (1,)),
                  np.zeros((nb,), i32))
+  # the least of a per-query value over the block's own queries
+  narrowest = lambda x: lax.reduce_min(
+      lax.select(member, x, np.full((nb, bq), np.iinfo(np.int32).max, i32)),
+      (1,))
   page0 = lax.full_like(last, 0)
   if window:
     # the block's narrowest horizon less the window: no query of the block
     # sees a slot before it
-    low = lax.reduce_min(
-        lax.select(member, blk_ends,
-                   np.full((nb, bq), np.iinfo(np.int32).max, i32)), (1,))
-    page0 = lax.min(lax.div(lax.max(lax.sub(low, i32(window)), i32(0)),
-                            i32(page_size)), last)
+    page0 = lax.min(lax.div(lax.max(lax.sub(narrowest(blk_ends), i32(window)),
+                                    i32(0)), i32(page_size)), last)
+  cleared = None
+  if clear:
+    # A query sees every slot under its horizon `q_end` if it is a chain's
+    # (every ancestor bit set); a tree's sees them under `q_start + 1` too
+    # (slots at or below `q_start` clip to bit 0 of `_AncestorOk`, where that
+    # is set) and none for sure otherwise. The block's narrowest such reach,
+    # in whole pages.
+    assert not window, "a window's far edge is not counted here"
+    one = np.ones((nb, bq), i32)
+    reach = lax.select(
+        lax.eq(both(part(2), part(3)), np.negative(one)), blk_ends,
+        lax.select(lax.eq(both(part(2), one), one),
+                   lax.min(blk_ends, lax.add(part(1), i32(1))),
+                   np.zeros((nb, bq), i32)))
+    cleared = lax.min(lax.div(narrowest(reach), i32(page_size)), i32(t_pages))
   col0 = lax.index_in_dim(cols, 0, 1, keepdims=False)       # [NB, 4]
   blk, page, pairs = _LivePairs(
       n, page0, last, nb * WindowPages(window, bq, page_size, t_pages))
@@ -490,7 +511,7 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                     first=first, cols=cols,
                     col0=tuple(lax.index_in_dim(col0, c, 1, keepdims=False)
                                for c in range(4)),
-                    blk=blk, page=page, pairs=pairs)
+                    blk=blk, page=page, pairs=pairs, clear=cleared)
 
 
 def GridPairs(key: PlanKey, b: int, t: int, t_pages: int) -> int:
@@ -502,11 +523,12 @@ def GridPairs(key: PlanKey, b: int, t: int, t_pages: int) -> int:
       key.window, key.bq, key.page_size, t_pages)
 
 
-def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
-  """`AttendPlan.pairs` of a step from the host's own view of its rows
-  (numpy; `_LivePairs`' twin, as `BlockRows` is `BlockRungs`'): row r brings
-  `row_len[r]` tokens at positions `row_q_pos[r] ...`, each `key.lanes`
-  queries of its own horizon, cut into blocks of `key.bq`."""
+def _HostBlocks(key: PlanKey, row_q_pos, row_len, t_pages: int):
+  """(live, queries, narrowest horizon, page0, last), `[B, blocks]` each, of
+  the blocks a step's rows are cut into, from the host's own view of them
+  (numpy): row r brings `row_len[r]` tokens at positions `row_q_pos[r] ...`,
+  each `key.lanes` queries of its own horizon, cut into blocks of
+  `key.bq`."""
   start = np.asarray(row_q_pos, np.int64)[:, None]
   queries = np.asarray(row_len, np.int64)[:, None] * key.lanes
   lo = np.arange(-(-int(queries.max(initial=0)) // key.bq))[None] * key.bq
@@ -514,13 +536,41 @@ def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
   hi = np.minimum(lo + key.bq, queries) - 1                 # its last query
   # a query's horizon is its token's slot + 1
   widest = start + hi // key.lanes + 1
+  narrowest = start + lo // key.lanes + 1
   last = np.clip(-(-widest // key.page_size) - 1, 0, t_pages - 1)
-  page0 = 0
+  page0 = np.zeros_like(last)
   if key.window:
-    narrowest = start + lo // key.lanes + 1
     page0 = np.minimum(
         np.maximum(narrowest - key.window, 0) // key.page_size, last)
+  return live, hi - lo + 1, narrowest, page0, last
+
+
+def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
+  """`AttendPlan.pairs` of a step from the host's own view of its rows
+  (numpy; `_LivePairs`' twin, as `BlockRows` is `BlockRungs`')."""
+  live, _, _, page0, last = _HostBlocks(key, row_q_pos, row_len, t_pages)
   return int(np.sum(np.where(live, last - page0 + 1, 0)))
+
+
+def ClearRung(rungs: tuple[int, ...]) -> int:
+  """The queries a block must pass to run the rung whose programs tell a
+  clear page from another (the widest; a lower rung runs one body: its
+  program is its fixed cost and its page's copy)."""
+  return rungs[-2] if len(rungs) > 1 else 0
+
+
+def ClearPairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
+  """The pairs of `LivePairs` whose program ran no mask (numpy; what the
+  plan's `clear` gives the kernel, counted over CHAIN rows, which is what the
+  host knows it sent): a block of the widest rung at a page that lies whole
+  under its narrowest horizon. 0 for a key whose kernel reads no `clear`."""
+  if not key.clear:
+    return 0
+  live, queries, narrowest, _, last = _HostBlocks(key, row_q_pos, row_len,
+                                                  t_pages)
+  wide = live & (queries > ClearRung(BlockRungs(key.bq, key.lanes)))
+  return int(np.sum(np.where(
+      wide, np.minimum(narrowest // key.page_size, last + 1), 0)))
 
 
 def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
@@ -553,21 +603,35 @@ def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
   return _BuildQueryBlocks(
       rows, ends, starts, lo, hi, bq=key.bq,
       nb=NumQueryBlocks(b, rows.shape[0], key.bq), page_size=key.page_size,
-      t_pages=t_pages, window=key.window)
+      t_pages=t_pages, window=key.window, clear=key.clear)
 
 
 def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
   """`_PageAttend` with a free query dimension: the same float ops in the
   same order per (query, head, slot). keep is boolean and broadcasts
-  against the scores; m/l keep a trailing unit dim."""
-  s = jnp.where(keep, _DotF32(q, k, dims_qk), NEG_INF)
+  against the scores; m/l keep a trailing unit dim, or ride lane-replicated
+  as wide as the scores (every column a copy: the statistics come back as
+  wide, and the accumulator, a whole number of times as wide, is scaled by
+  the copies laid side by side). keep None (static): the caller knows every
+  query sees every slot of the page, and the passes that only a masked slot
+  needs are not traced: the select over the scores, and the guard of a row
+  that has seen no slot yet (its maximum is a score's here, never a masked
+  slot's -1e30). The result is bitwise that of an all-true `keep`."""
+  s = _DotF32(q, k, dims_qk)
+  if keep is not None:
+    s = jnp.where(keep, s, NEG_INF)
   m_cur = jnp.max(s, axis=-1, keepdims=True)
   m_new = jnp.maximum(m, m_cur)
-  m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+  m_safe = m_new
+  if keep is not None:
+    m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
   p = jnp.exp(s - m_safe)
   alpha = jnp.exp(m - m_new)
   l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
   pv = _DotF32(p.astype(v.dtype), v, dims_pv)
+  if alpha.shape[-1] > 1:
+    alpha = jnp.concatenate(
+        [alpha] * (acc.shape[-1] // alpha.shape[-1]), axis=-1)
   return m_new, l_new, acc * alpha + pv
 
 

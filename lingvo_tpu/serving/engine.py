@@ -548,6 +548,9 @@ class ServingLoop:
                                       table_pages)
         for key in self._attend_plan_keys)
     self._table_pages = table_pages
+    # those whose kernel runs no mask at a page under every query's horizon
+    # (ops/latent_attend.py), counted as `attend_clear_pairs`
+    self._attend_clear_keys = {k for k in self._attend_plan_keys if k.clear}
     # some layer writes its pages by the step's runs (ops/run_write.py)
     self._kv_write_by_runs = any(
         getattr(m, "writes_by_runs", False) for m, _ in self._mixer_layers)
@@ -1232,9 +1235,9 @@ class ServingLoop:
   def _StepCounters(self):
     """What a step's record carries of the cumulative counters whose
     readers want them between two steps: expert load as of the newest
-    RETIRED step (one behind the record's own), window pages and the
-    hybrid stack's token counts as of this step's dispatch. None where the
-    stack has none of them."""
+    RETIRED step (one behind the record's own), window pages, the hybrid
+    stack's token counts and the attend kernels' pairs as of this step's
+    dispatch. None where the stack has none of them."""
     out = {}
     if self._moe_layers is not None:
       out.update((k, self._counters[k].value) for k in (
@@ -1249,6 +1252,9 @@ class ServingLoop:
     if self._retention_layers:
       out.update((k, self._counters[k].value) for k in (
           "retention_rows", "retention_folds", "retention_chunk_tokens"))
+    if self._attend_clear_keys:
+      out.update((k, self._counters[k].value) for k in (
+          "attend_live_pairs", "attend_clear_pairs"))
     return out or None
 
   def _NoteDispatch(self, batch):
@@ -1304,6 +1310,10 @@ class ServingLoop:
                                         self._table_pages)
           for key in self._attend_plan_keys))
       self._counters["attend_grid_pairs"].Inc(self._attend_grid_pairs)
+      self._counters["attend_clear_pairs"].Inc(sum(
+          ragged_block_attend.ClearPairs(key, desc.row_q_pos, row_len,
+                                         self._table_pages)
+          for key in self._attend_clear_keys))
     if self._kv_write_by_runs:
       runs, tokens = run_write.RunCounts(desc.row_q_pos, row_len,
                                          self.page_size)

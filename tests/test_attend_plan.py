@@ -299,6 +299,66 @@ def test_the_list_is_the_old_grids_live_programs_in_order(
   assert rba.LivePairs(key, rows.row_q_pos, rows.row_len, T_PAGES) == pairs
 
 
+@pytest.mark.parametrize("pack", list(PACKS))
+@pytest.mark.parametrize("lanes,bq", [(1, 16), (8, 64), (16, 128)])
+def test_clear_is_the_pages_under_every_members_reach(lanes, bq, pack):
+  """`AttendPlan.clear` of a key that asks for it, against a numpy reading of
+  the same rows a query at a time: a chain's query reaches its horizon, a
+  tree's the slot its window starts at; a block clears the whole pages under
+  its narrowest member. Every other field is the plan's without it, and a
+  key that does not ask gets none."""
+  rows = _Rows(pack)
+  b = len(PACKS[pack][0])
+  tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
+  args = (tok.row, tok.q_end, tok.q_start, rows.anc_lo, rows.anc_hi)
+  key = rba.PlanKey(PAGE, 0, bq, lanes, tree=True, kernel=True, clear=True)
+  got = rba.BuildAttendPlan(key, *args, b=b, t_pages=T_PAGES)
+  plain = rba.BuildAttendPlan(key._replace(clear=False), *args, b=b,
+                              t_pages=T_PAGES)
+  assert plain.clear is None
+  for a, b_ in zip(jax.tree.leaves(got._replace(clear=None)),
+                   jax.tree.leaves(plain)):
+    _Same(a, b_)
+  ends, starts, lo, hi = (np.repeat(np.asarray(x, np.int64), lanes)
+                          for x in args[1:])
+  chain = (lo == -1) & (hi == -1)
+  assert chain.all() == (pack == "chain")
+  reach = np.where(chain, ends, np.where(lo & 1, np.minimum(ends, starts + 1),
+                                         0))
+  n, first = np.asarray(got.n), np.asarray(got.first)
+  live = int(np.sum(n > 0))
+  want = [reach[first[i]:first[i] + n[i]].min() // PAGE for i in range(live)]
+  assert np.asarray(got.clear)[:live].tolist() == want
+  assert max(want) > 0
+  with pytest.raises(AssertionError, match="window"):
+    rba.BuildAttendPlan(key._replace(window=24), *args, b=b, t_pages=T_PAGES)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("kind", PAIR_KINDS)
+@pytest.mark.parametrize("lanes,bq", [(1, 16), (8, 64), (16, 16)])
+def test_the_host_counts_the_clear_pairs_the_plan_holds(lanes, bq, kind, seed):
+  """`ClearPairs` (the host's rows) against the plan's own count: the pairs
+  of a block of the widest rung whose page lies under its `clear`. Never more
+  than the live pairs, and none under a key that does not ask."""
+  rows, tables = _PairRows(kind, seed)
+  b = tables.shape[0]
+  tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
+  key = rba.PlanKey(PAGE, 0, bq, lanes, tree=False, kernel=True, clear=True)
+  plan = rba.BuildAttendPlan(key, tok.row, tok.q_end, b=b, t_pages=T_PAGES)
+  n, last, clear = (np.asarray(x) for x in (plan.n, plan.last, plan.clear))
+  wide = n > rba.ClearRung(rba.BlockRungs(bq, lanes))
+  want = int(np.sum(np.where(wide, np.minimum(clear, last + 1), 0)))
+  got = rba.ClearPairs(key, rows.row_q_pos, rows.row_len, T_PAGES)
+  assert got == want <= int(plan.pairs)
+  if kind in ("decode_only", "empty") and lanes < bq:
+    assert got == 0          # a decode row's rung runs one body
+  if kind == "full_pool":
+    assert got > 0
+  assert rba.ClearPairs(key._replace(clear=False), rows.row_q_pos,
+                        rows.row_len, T_PAGES) == 0
+
+
 @pytest.mark.parametrize("kind", PAIR_KINDS)
 @pytest.mark.parametrize("window", [0, 24])
 @pytest.mark.parametrize("kernel", ["heads", "grouped", "diff"])

@@ -19,6 +19,7 @@ import pytest
 from benchmarks.references import mistral4 as ref
 from lingvo_tpu import model_registry
 from lingvo_tpu.core import mla as mla_lib
+from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.ops import latent_attend
 from lingvo_tpu.ops import ragged_block_attend as rba
 from lingvo_tpu.serving import engine as engine_lib
@@ -256,15 +257,121 @@ def test_the_latent_kernel_runs_the_steps_plan_and_its_rungs():
   op's: a token lays its heads padded to 16 on the packed axis, a block
   holds whole tokens, and a decode row runs the lower rung."""
   key = latent_attend.PlanKey(32, 128, lowering="pallas")
-  assert key == rba.PlanKey(128, 0, 1024, 32, True, True)
+  assert key == rba.PlanKey(128, 0, 1024, 32, True, True, clear=True)
   assert latent_attend.PlanKey(4, 8, lowering="pallas").lanes == 16
   assert latent_attend.QueryBlock(4) == 1024
   assert latent_attend.QueryBlock(48) == 21 * 48
   assert not latent_attend.PlanKey(32, 128, lowering="xla").kernel
   assert rba.BlockRungs(key.bq, key.lanes) == (32, 1024)
   assert rba.LivePairs(key, [5000, 300], [1, 40], 196) == 40 + 2 * 3
+  # the chunk's two blocks start at tokens 300 and 332: two whole pages lie
+  # under either's first horizon; the decode row's rung runs one body
+  assert rba.ClearPairs(key, [5000, 300], [1, 40], 196) == 2 * 2
+  assert rba.ClearRung(rba.BlockRungs(key.bq, key.lanes)) == 32
   assert latent_attend.SupportedOnTpu(128, 256)
   assert not latent_attend.SupportedOnTpu(8, 16)
+
+
+# a pack of every kind of row the clear pages must leave alone (heads 4:
+# 16 lanes; Bq 8 tokens; pages of 8): (tokens, first q_end), padding after
+_CLEAR_PACK = (((16, 41), 0),    # a full chunk, two blocks, five clear pages
+               ((11, 30), 3),    # a partial last block beside padding tokens
+               ((1, 51), 0),     # a decode row
+               ((1, 6), 1),      # a decode row shorter than a page
+               ((3, 5), 2),      # a row shorter than a page, in the wide rung
+               ((12, 27), 1))    # (the tree row, where the case asks for one)
+
+
+def _ClearCase(tree, dtype=jnp.float32, page=8, seed=0):
+  """(args of LatentAttend, its keywords, q_end, the plan's `clear` by
+  numpy) of `_CLEAR_PACK` with its positions scaled to pages of `page`, and
+  with `tree` the last row's tokens as a root and an 11-node tree."""
+  rng = np.random.RandomState(seed)
+  heads, scale = 4, page // 8
+  row, value = (24, 16) if page == 8 else (page + 8, page)
+  t_pages = -(-max(n + e * scale for (n, e), _ in _CLEAR_PACK) // page)
+  rows = len(_CLEAR_PACK)
+  pool = rng.randn(rows * t_pages + 2, page, row).astype(np.float32)
+  pool[-2:] = np.nan                        # pages no table names
+  tables = rng.permutation(rows * t_pages).reshape(rows, t_pages)
+  row_of, q_end, q_start, lo, hi, clear = [], [], [], [], [], []
+  for r, ((n, first_end), pad) in enumerate(_CLEAR_PACK):
+    first_end *= scale
+    row_of += [r] * n + [0] * pad
+    q_end += list(range(first_end, first_end + n)) + [0] * pad
+    q_start += [first_end - 1] * n + [0] * pad
+    lo += [-1] * (n + pad)
+    hi += [-1] * (n + pad)
+    # a block is 8 tokens; a chain's narrowest reach is its first token's
+    # horizon, the tree's the slot its window starts at, counting it
+    clear += [(first_end if tree and r == rows - 1 else first_end + j)
+              // page for j in range(0, n, 8)]
+  if tree:
+    n, pad = _CLEAR_PACK[-1][0][0], _CLEAR_PACK[-1][1]
+    at = len(q_end) - n - pad
+    lo[at:at + n], hi[at:at + n] = ragged_lib.TreeAncestorMasks(
+        [-1, 0, 0, 2, -1, 4, 4, 6, -1, 8, 9])
+  q = rng.randn(len(row_of), heads, row).astype(np.float32) / np.sqrt(row)
+  q[np.array(q_end) == 0] = np.nan          # a padding token's query
+  i32 = lambda x: jnp.asarray(np.array(x, np.int32))
+  args = (jnp.asarray(q, dtype), jnp.asarray(pool, dtype), i32(tables),
+          i32(row_of), i32(q_end))
+  kw = dict(page_size=page, value_dim=value, q_start=i32(q_start),
+            anc_lo=i32(lo), anc_hi=i32(hi))
+  return args, kw, np.array(q_end), clear
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("page", [8, 128])
+@pytest.mark.parametrize("tree", [False, True], ids=["chains", "a_tree_row"])
+def test_clear_pages_are_bitwise_the_masked_pages(tree, page, dtype,
+                                                  monkeypatch):
+  """The kernel with the plan's `clear` against the same kernel with `clear`
+  forced to 0 (every page masked: the kernel as it was), bit for bit, and
+  against the twin within rounding; padding tokens an exact zero and the
+  tokens after them what they were. Pages of 128 run the clear body the cell
+  runs: the statistics lane-replicated, the rows as two halves."""
+  monkeypatch.setattr(latent_attend, "_BQ", 8 * latent_attend.Lanes(4))
+  args, kw, q_end, want = _ClearCase(tree, dtype, page)
+  key = latent_attend.PlanKey(4, page, lowering="pallas")
+  assert key.clear and (key.bq, key.lanes) == (128, 16)
+  blocks = rba.BuildAttendPlan(key, args[3], args[4], kw["q_start"],
+                               kw["anc_lo"], kw["anc_hi"], b=len(_CLEAR_PACK),
+                               t_pages=args[2].shape[1])
+  clear = np.asarray(blocks.clear)[:int(np.sum(np.asarray(blocks.n) > 0))]
+  assert clear.tolist() == want and (want[0], want[2], want[5]) == (5, 3, 0)
+  call = lambda plan: np.asarray(latent_attend.LatentAttend(
+      *args, **kw, lowering="pallas", interpret=True,
+      plan={key: plan}).astype(jnp.float32))
+  got = call(blocks)
+  masked = call(blocks._replace(clear=jnp.zeros_like(blocks.clear)))
+  np.testing.assert_array_equal(got, masked)
+  # and the comparison sees a page that is run unmasked and is not clear
+  wrong = call(blocks._replace(clear=blocks.clear + 1))
+  assert not np.array_equal(wrong[q_end > 0], got[q_end > 0])
+  assert np.all(np.isfinite(got))
+  assert (got[q_end == 0] == 0).all()
+  assert (got[q_end > 0] != 0).any(axis=(1, 2)).all()
+  clean = (args[0], jnp.nan_to_num(args[1])) + args[2:]
+  twin = np.asarray(latent_attend.LatentAttend(
+      *clean, **kw, lowering="xla").astype(jnp.float32))
+  np.testing.assert_allclose(got[q_end > 0], twin[q_end > 0],
+                             atol=3e-5 if dtype == jnp.float32 else 3e-2)
+
+
+def test_a_tree_mask_without_the_root_bit_clears_no_page(monkeypatch):
+  """`clear` trusts no convention: a query whose mask leaves bit 0 unset
+  (no tree the engine builds) keeps every page of its block masked."""
+  monkeypatch.setattr(latent_attend, "_BQ", 8 * latent_attend.Lanes(4))
+  args, kw, _, want = _ClearCase(True)
+  kw["anc_lo"] = kw["anc_lo"].at[-3].set(2)
+  key = latent_attend.PlanKey(4, 8, lowering="pallas")
+  blocks = rba.BuildAttendPlan(key, args[3], args[4], kw["q_start"],
+                               kw["anc_lo"], kw["anc_hi"], b=len(_CLEAR_PACK),
+                               t_pages=args[2].shape[1])
+  assert want[7:] == [27 // 8, 27 // 8]
+  assert np.asarray(blocks.clear)[7:9].tolist() == [27 // 8, 0]
 
 
 def test_the_latent_kernel_compiles_for_a_v5e_at_the_cells_shapes():
@@ -614,6 +721,46 @@ def test_the_engine_counts_held_pairs_and_pairs_elsewhere(tiny):
     whole.StepOnce()
   assert whole.Stats()["moe_pairs_elsewhere"] == 0
   assert whole.Stats()["moe_tokens_routed"] == tokens * 2 * 2
+
+
+def test_the_engine_counts_the_pairs_that_ran_no_mask(tiny, monkeypatch):
+  """`attend_clear_pairs` is the plans' own count of (block of the widest
+  rung, page under its `clear`) pairs over the steps dispatched, in `Stats()`
+  and in the step records beside `attend_live_pairs`, which it never
+  passes; 0 where the twin runs and no plan is built."""
+  task, theta, _ = tiny["whole"]
+  twin = _Engine(task, theta, 2)
+  assert twin.Stats()["attend_plans"] == 0
+  monkeypatch.setattr(rba, "Lowering", lambda lowering: (
+      "pallas" if lowering == "auto" else lowering))
+  monkeypatch.setattr(mla_lib.MultiHeadLatentAttention, "_Lowering",
+                      lambda self, page_size: "pallas")
+  eng = _Engine(task, theta, 2)
+  (key,) = eng._attend_clear_keys
+  assert key.clear and eng.Stats()["attend_plans"] == 1
+  seen, note = [], eng._NoteDispatch
+
+  def _Note(batch):
+    seen.append((np.array(batch.rows_desc.row_q_pos),
+                 np.array(batch.rows_desc.row_len)))
+    return note(batch)
+
+  monkeypatch.setattr(eng, "_NoteDispatch", _Note)
+  long = eng.Submit(np.arange(1, 40, dtype=np.int32), 3)
+  eng.Submit(np.arange(1, 9, dtype=np.int32), 3)
+  while not long.done:
+    eng.StepOnce()
+  st = eng.Stats()
+  pages = eng._table_pages
+  assert st["attend_clear_pairs"] == sum(
+      rba.ClearPairs(key, q_pos, n, pages) for q_pos, n in seen)
+  assert st["attend_live_pairs"] == sum(
+      rba.LivePairs(key, q_pos, n, pages) for q_pos, n in seen)
+  assert 0 < st["attend_clear_pairs"] < st["attend_live_pairs"]
+  last = [r for r in eng.trace.Steps() if r.counters][-1].counters
+  assert (last["attend_clear_pairs"], last["attend_live_pairs"]) == (
+      st["attend_clear_pairs"], st["attend_live_pairs"])
+  assert twin.Stats()["attend_clear_pairs"] == 0
 
 
 # -- the published depth, from shapes ------------------------------------------
