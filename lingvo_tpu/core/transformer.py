@@ -1172,6 +1172,12 @@ class BlockSequence(base_layer.BaseLayer):
     return [a.RaggedPlanKey(cached_states.kv_pool)
             for a in self._AttentionMixers()]
 
+  def WritesWholePages(self, cached_states) -> bool:
+    """Some layer of the stack writes its pages through
+    ops/diff_attend.WritePages' kernel, which takes the step's WritePlan."""
+    return any(a.writes_by_plan and key.kernel for a, key in zip(
+        self._AttentionMixers(), self.RaggedPlanKeys(cached_states)))
+
   def SharedKvReadLayers(self) -> int:
     """Layers that read pages they do not own."""
     return sum(reps for m, reps in self._Mixers()
@@ -1285,11 +1291,9 @@ class BlockSequence(base_layer.BaseLayer):
       shared.memory = jnp.zeros(inputs.shape[:2] + (self._memory_dim,),
                                 inputs.dtype)
     # built once, before the blocks' scans, and an invariant of each
-    keys = self.RaggedPlanKeys(cached_states)
     plan = attention_lib.BuildRaggedPlan(
-        keys, rows, *block_tables.shape[-2:],
-        page_writes=any(a.writes_by_plan and key.kernel for a, key in zip(
-            self._AttentionMixers(), keys)))
+        self.RaggedPlanKeys(cached_states), rows, *block_tables.shape[-2:],
+        page_writes=self.WritesWholePages(cached_states))
     if plan is None:
       # a stack of retention layers: what their step derives from the rows
       planners = [m for m, _ in self._Mixers() if hasattr(m, "StepPlan")]

@@ -61,6 +61,13 @@ ENGINE_COUNTER_KEYS = (
     # step is the share of the pack that was written at all. Both 0 where no
     # layer of the stack writes by runs.
     "kv_write_runs", "kv_write_tokens",
+    # the whole-page write (ops/diff_attend.WritePages' kernel), counted
+    # beside them: the live (row, page) pairs a step's write runs, a program
+    # each in every owning layer, and the static bound on them, which is the
+    # grid every such write ran before PR 56 (`PageWrites`): their ratio is
+    # the share of that grid that was ever live. Both 0 where no layer of the
+    # stack writes whole pages.
+    "kv_page_writes", "kv_page_write_bound",
     # the loop's pipeline: steps dispatched while the step before was still
     # undelivered (`steps` less the pipeline's fills), and rows computed
     # for a sequence that had ended by the time their tokens arrived
@@ -371,9 +378,11 @@ DEVICE_SCOPES = {
                  "runs' copies (ops/run_write.py) or the whole-page write "
                  "(ops/diff_attend.WritePages), kernels named after it, and "
                  "an int8 pool's scales' scatter"),
-    "kv_layout": ("kv_write", "the gathers and re-layouts that lay new "
-                  "tokens out for the page-write kernel "
-                  "(ops/diff_attend.WritePages), not the write itself"),
+    "kv_layout": ("kv_write", "round the page-write kernel "
+                  "(ops/diff_attend.WritePages), not the write itself: the "
+                  "pairs' pages looked up in the layer's table and the new "
+                  "tokens padded to whole tiles of P (the kernel lays them "
+                  "out on the lanes itself)"),
     "ragged_attend": ("atten", "ops/ragged_block_attend.RaggedAttend: the "
                       "ragged attend kernels (named after it) and the "
                       "group's re-layout round them"),

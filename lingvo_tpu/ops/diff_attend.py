@@ -208,44 +208,79 @@ def _FromAsItLies(pages, nk: int):
   return pages.reshape(np_total, nk, rows // nk, page).transpose(0, 3, 1, 2)
 
 
-def _WriteKernel(page_ref, k_old, v_old, k_new, v_new, mask_ref, k_out,
-                 v_out):
-  del page_ref
-  keep = mask_ref[0] != 0                                     # [1, P]
-  k_out[0] = jnp.where(keep, k_new[0], k_old[0])
-  v_out[0] = jnp.where(keep, v_new[0], v_old[0])
+def _Words(x):
+  """[rows, P] -> the same bytes as 32-bit words (Mosaic rolls no other): a
+  16-bit page's sublane pairs, its lanes where they were."""
+  return x if x.dtype.itemsize == 4 else pltpu.bitcast(x, jnp.uint32)
+
+
+def _WriteKernel(page_ref, tok0_ref, lane0_ref, lane1_ref, tiles_ref, k_old,
+                 v_old, k_new, v_new, k_out, v_out, k_lanes, v_lanes):
+  """Pair i's program: page `page_ref[i]` of both pools with the lanes
+  `[lane0, lane1)` taken from the packed tokens `tok0 ..`, every other lane
+  the old page's. The step's new K and V arrive as they are, `[T, Nk * H]`
+  whole in VMEM; the first program lays the tokens the step holds out on the
+  lanes once, a tile of P tokens a transpose (`k_lanes`, `v_lanes`: `[Nk * H,
+  P + T + P]`, token t at lane P + t), and a pair's page is a window of that
+  rolled to its first lane. Moves and selects only: a bit pattern lands as
+  it left."""
+  i = pl.program_id(0)
+  page = k_old.shape[2]
+
+  @pl.when(i == 0)
+  def _LayOut():
+    def _Tile(g, _):
+      at = pl.multiple_of(g * page, page)
+      for new, lanes in ((k_new, k_lanes), (v_new, v_lanes)):
+        lanes[:, pl.ds(at + page, page)] = new[pl.ds(at, page), :].T
+
+    jax.lax.fori_loop(0, tiles_ref[0], _Tile, None)
+
+  lane0, lane1 = lane0_ref[i], lane1_ref[i]
+  # lane j of the page is lane `at + j` of the laid-out tokens: two aligned
+  # windows, each lane from the one that holds it, rolled down by `at % P`
+  at = tok0_ref[i] - lane0 + page
+  first = pl.multiple_of((at // page) * page, page)
+  shift = at - first
+  lane = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+  keep = (lane >= lane0) & (lane < lane1)
+  for old, lanes, out in ((k_old, k_lanes, k_out), (v_old, v_lanes, v_out)):
+    window = jnp.where(lane >= shift, _Words(lanes[:, pl.ds(first, page)]),
+                       _Words(lanes[:, pl.ds(first + page, page)]))
+    new = pltpu.roll(window, (page - shift) % page, 1)
+    out[0] = pltpu.bitcast(jnp.where(keep, new, _Words(old[0])), out.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _WriteCall(page_ids, k_pages, v_pages, k_new, v_new, mask, *,
+def _WriteCall(pairs, prefetch, k_pages, v_pages, k_new, v_new, *,
                interpret: bool):
-  """A grid of (row, page) writes: program i rewrites page `page_ids[i]` of
-  both pools with `new[i]` where `mask[i]` says so. Pages and new pages
-  `[.., Nk * H, P]`, as the pool lies."""
-  nw, rows, page = k_new.shape
-  by_page = lambda i, ids: (ids[i], 0, 0)
-  by_write = lambda i, ids: (i, 0, 0)
-  block = (1, rows, page)
+  """_WriteKernel over the step's `pairs` live (row, page) pairs (a traced
+  grid length, as rba._GroupedCall's: a step runs the programs it has pages
+  for). prefetch: (page ids, first packed token, first lane, last lane a
+  pair, [the tiles of P tokens the step holds]); pages `[NP, Nk * H, P]`, as
+  the pool lies; new `[T', Nk * H]`, T' whole tiles. A `jit` of its own and
+  the scope inside it, as rba._GroupedCall and for its reasons."""
+  _, rows, page = k_pages.shape
+  by_page = lambda i, ids, *_: (ids[i], 0, 0)
+  block = pl.BlockSpec((1, rows, page), by_page)
+  whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+  lanes = pltpu.VMEM((rows, k_new.shape[0] + 2 * page), k_pages.dtype)
   with observe.Scope("kv_write"):     # inside the jit: the kernel's name
     return pl.pallas_call(
         _WriteKernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nw,),
-            in_specs=[pl.BlockSpec(block, by_page),
-                      pl.BlockSpec(block, by_page),
-                      pl.BlockSpec(block, by_write),
-                      pl.BlockSpec(block, by_write),
-                      pl.BlockSpec((1, 1, page), by_write)],
-            out_specs=[pl.BlockSpec(block, by_page),
-                       pl.BlockSpec(block, by_page)]),
+            num_scalar_prefetch=len(prefetch),
+            grid=(pairs,),
+            in_specs=[block, block, whole, whole],
+            out_specs=[block, block],
+            scratch_shapes=[lanes, lanes]),
         out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        input_output_aliases={1: 0, 2: 1},
+        input_output_aliases={len(prefetch): 0, len(prefetch) + 1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_ids, k_pages, v_pages, k_new, v_new, mask)
+    )(*prefetch, k_pages, v_pages, k_new, v_new)
 
 
 def PageWrites(b: int, t: int, page_size: int) -> int:
@@ -256,14 +291,18 @@ def PageWrites(b: int, t: int, page_size: int) -> int:
 
 
 class WritePlan(NamedTuple):
-  """The (row, logical page) pairs a step writes, rows in slot order (NW =
-  `PageWrites` of them, static), and what lays the new tokens out for them:
-  all of it the step's rows' alone, none of it an owner's."""
+  """The (row, logical page) pairs a step writes, rows in slot order, the
+  live ones first (NW = `PageWrites` of them, static; the kernel's grid runs
+  the first `pairs`), and where a pair's new lanes come from: all of it the
+  step's rows' alone, none of it an owner's."""
   r: jnp.ndarray      # [NW] int32 the pair's row
   lp: jnp.ndarray     # [NW] int32 its logical page, inside the table
-  live: jnp.ndarray   # [NW] bool; a dead pair writes the trash page, nothing
-  mask: jnp.ndarray   # [NW, 1, P] int32 the page's lanes the step writes
-  tok: jnp.ndarray    # [NW, P] int32 the packed token a lane is taken from
+  live: jnp.ndarray   # [NW] bool; a dead pair runs no program
+  tok0: jnp.ndarray   # [NW] int32 the packed token its first new lane takes
+  lane0: jnp.ndarray  # [NW] int32 the page's first lane the step writes
+  lane1: jnp.ndarray  # [NW] int32 and the lane after its last
+  pairs: jnp.ndarray  # [] int32 the live pairs
+  tiles: jnp.ndarray  # [1] int32 the tiles of P packed tokens the rows reach
 
 
 def BuildWritePlan(rows, b: int, t_pages: int, page: int) -> WritePlan:
@@ -272,6 +311,7 @@ def BuildWritePlan(rows, b: int, t_pages: int, page: int) -> WritePlan:
   t = rows.row_of.shape[0]
   p0 = rows.row_q_pos.astype(jnp.int32)
   n = rows.row_len.astype(jnp.int32)
+  col0 = rows.row_cols[:, 0].astype(jnp.int32)
   first_page = p0 // page
   n_pages = jnp.where(n > 0, (p0 + n - 1) // page - first_page + 1, 0)
   cum = jnp.cumsum(n_pages)
@@ -279,13 +319,16 @@ def BuildWritePlan(rows, b: int, t_pages: int, page: int) -> WritePlan:
   r = jnp.clip(jnp.searchsorted(cum, i, side="right"), 0, b - 1)
   lp = first_page[r] + i - (cum[r] - n_pages[r])
   live = i < cum[-1]
-  slot = lp[:, None] * page + jnp.arange(page, dtype=jnp.int32)[None]
-  mask = live[:, None] & (slot >= p0[r][:, None]) & (
-      slot < (p0 + n)[r][:, None])
-  tok = jnp.clip(rows.row_cols[r, 0][:, None] + slot - p0[r][:, None],
-                 0, t - 1)                                    # [NW, P]
-  return WritePlan(r=r, lp=jnp.clip(lp, 0, t_pages - 1), live=live,
-                   mask=mask.astype(jnp.int32)[:, None, :], tok=tok)
+  # the slots of the page the row's tokens fill, [lo, hi); a dead pair's
+  # are read by no program
+  lo = jnp.maximum(p0[r], lp * page)
+  hi = jnp.minimum((p0 + n)[r], (lp + 1) * page)
+  reach = jnp.max(jnp.where(n > 0, col0 + n, 0))
+  return WritePlan(
+      r=r, lp=jnp.clip(lp, 0, t_pages - 1), live=live,
+      tok0=col0[r] + lo - p0[r], lane0=lo - lp * page, lane1=hi - lp * page,
+      pairs=cum[-1],
+      tiles=jnp.clip(-(-reach // page), 0, -(-t // page))[None])
 
 
 def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
@@ -299,8 +342,9 @@ def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
   The XLA lowering scatters rows of `[Nk, H]`. On the chip a token is a
   LANE of its page (module docstring), and a scatter there re-lays the whole
   pool out, so the kernel rewrites whole pages: a program a (row, page) pair
-  the step touches (`PageWrites` of them, the dead ones on the trash page
-  with nothing to write), the page's new lanes gathered beforehand.
+  the step writes and no other (`WritePlan.pairs` of them; a page the step
+  does not write, the trash page among them, is not touched), which lays
+  the new tokens out itself (`_WriteKernel`).
   plan: the step's BuildWritePlan over these rows and this table's shape (a
   stack builds it once for all its owners); the kernel's call builds its own
   when handed none. The scatter takes none."""
@@ -322,18 +366,21 @@ def WritePages(k_pool, v_pool, k_new, v_new, block_tables, rows, *,
   with observe.Scope("kv_layout"):
     if plan is None:
       plan = BuildWritePlan(rows, b, t_pages, page)
-    page_ids = jnp.where(plan.live, tables[plan.r, plan.lp], np_total - 1)
+    prefetch = (tables[plan.r, plan.lp], plan.tok0, plan.lane0, plan.lane1,
+                plan.tiles)
 
-    def _NewPages(new):
-      lanes = new.reshape(t, nk * h).astype(k_pool.dtype)[plan.tok]
-      return lanes.swapaxes(1, 2)                          # [NW, rows, P]
+    def _Tokens(new):
+      # whole tiles of P tokens: the pad is the pack's, not a page's
+      return jnp.pad(new.reshape(t, nk * h).astype(k_pool.dtype),
+                     ((0, -t % page), (0, 0)))
 
-    operands = (page_ids, AsItLies(k_pool), AsItLies(v_pool),
-                _NewPages(k_new), _NewPages(v_new), plan.mask)
+    operands = (AsItLies(k_pool), AsItLies(v_pool), _Tokens(k_new),
+                _Tokens(v_new))
   if interpret is None:
     interpret = jax.default_backend() != "tpu"
   # the write itself stays outside `kv_layout`: its kernel is `kv_write`
-  k_pages, v_pages = _WriteCall(*operands, interpret=interpret)
+  k_pages, v_pages = _WriteCall(plan.pairs, prefetch, *operands,
+                                interpret=interpret)
   with observe.Scope("kv_layout"):
     return _FromAsItLies(k_pages, nk), _FromAsItLies(v_pages, nk)
 

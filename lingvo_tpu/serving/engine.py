@@ -98,6 +98,7 @@ from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.core import sampling
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as observe_trace
+from lingvo_tpu.ops import diff_attend
 from lingvo_tpu.ops import power_retention
 from lingvo_tpu.ops import ragged_block_attend
 from lingvo_tpu.ops import run_write
@@ -554,6 +555,13 @@ class ServingLoop:
     # some layer writes its pages by the step's runs (ops/run_write.py)
     self._kv_write_by_runs = any(
         getattr(m, "writes_by_runs", False) for m, _ in self._mixer_layers)
+    # some layer writes whole pages through the step's WritePlan
+    # (ops/diff_attend.WritePages' kernel: the stack's own condition,
+    # BlockSequence.RaggedStep): the pairs such a step's grid is bounded by
+    self._kv_page_write_bound = diff_attend.PageWrites(
+        max_batch, self._ragged_t, page_size) if getattr(
+            task.stack, "WritesWholePages", lambda states: False)(
+                self._states) else 0
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
@@ -1314,11 +1322,16 @@ class ServingLoop:
           ragged_block_attend.ClearPairs(key, desc.row_q_pos, row_len,
                                          self._table_pages)
           for key in self._attend_clear_keys))
-    if self._kv_write_by_runs:
+    if self._kv_write_by_runs or self._kv_page_write_bound:
       runs, tokens = run_write.RunCounts(desc.row_q_pos, row_len,
                                          self.page_size)
-      self._counters["kv_write_runs"].Inc(runs)
-      self._counters["kv_write_tokens"].Inc(tokens)
+      if self._kv_write_by_runs:
+        self._counters["kv_write_runs"].Inc(runs)
+        self._counters["kv_write_tokens"].Inc(tokens)
+      if self._kv_page_write_bound:
+        # a run is a row's tokens in one page: a (row, page) pair
+        self._counters["kv_page_writes"].Inc(runs)
+        self._counters["kv_page_write_bound"].Inc(self._kv_page_write_bound)
     if self.paged_path == "dense":
       self._counters["dense_fallback_steps"].Inc()
     if self._kv_quantized:

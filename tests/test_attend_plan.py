@@ -25,6 +25,7 @@ from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.models.lm.params import phi4flash
 from lingvo_tpu.ops import diff_attend
 from lingvo_tpu.ops import ragged_block_attend as rba
+from lingvo_tpu.ops import run_write
 from lingvo_tpu.serving import engine as engine_lib
 
 import lingvo_tpu.models.all_params  # noqa: F401  (fills the registry)
@@ -45,7 +46,7 @@ def _Rows(pack):
   """The pack's RaggedRows with padding tokens moved in before its first row
   and between its rows (the scheduler packs rows back to back; the ops take
   padding anywhere)."""
-  lens, q_pos, parents = PACKS[pack]
+  lens, q_pos, parents = PACKS[pack] if isinstance(pack, str) else pack
   rows = ragged_lib.BuildRaggedRows(np.array(lens), np.array(q_pos), T - 5,
                                     WMAX, row_parents=parents)
   gaps = np.cumsum([2] + [1 if n else 0 for n in lens])[:-1]     # per slot
@@ -139,27 +140,89 @@ def test_diff_attend_with_the_steps_plan_is_bitwise_the_call_without(window):
   np.testing.assert_allclose(np.asarray(out), np.asarray(twin), atol=2e-5)
 
 
-@pytest.mark.parametrize("pack", list(PACKS))
-def test_page_writes_with_the_steps_plan_are_bitwise_the_call_without(pack):
+# name -> ([tokens a slot], [first position a slot]) of a step the whole-page
+# write is held on beside PACKS' two, over tables of T_PAGES pages of PAGE
+WRITE_PACKS = {
+    # 64 one-token rows, every one a page of its own: the decode-only step
+    "decode_only_64": ([1] * 64, [(7 * i + 3) % (T_PAGES * PAGE - 1)
+                                  for i in range(64)]),
+    # rows that start mid-page at an odd packed offset (behind a one-token
+    # row and the padding `_Rows` moves in) and cross one page boundary, two
+    "one_boundary": ([1, 12, 0, 1], [37, 9, 3, 80]),
+    "two_boundaries": ([3, 0, 27, 1], [2, 0, 13, 95]),
+    # one live pair where the list has room for eleven: the traced grid
+    "far_under_the_bound": ([0, 0, 1, 0], [4, 4, 21, 4]),
+    "empty": ([0, 0, 0, 0], [1, 1, 1, 1]),
+}
+
+
+def _WriteRows(pack):
+  """(rows, packed tokens) of `pack`: PACKS' through `_Rows` (padding moved
+  in between the rows), WRITE_PACKS' likewise where they fit its width."""
+  if pack in PACKS:
+    return _Rows(pack), T
+  lens, q_pos = WRITE_PACKS[pack]
+  if len(lens) == len(PACKS["chain"][0]):
+    return _Rows((lens, q_pos, None)), T
+  t = len(lens) + 16
+  rows = ragged_lib.BuildRaggedRows(np.array(lens), np.array(q_pos), t, 17)
+  return ragged_lib.RaggedRows(*(jnp.asarray(m) for m in rows)), t
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("pack", list(PACKS) + list(WRITE_PACKS))
+def test_page_writes_with_the_steps_plan_are_bitwise_the_call_without(
+    pack, dtype):
+  """`WritePages`' kernel (interpret mode) with the step's plan and with its
+  own against the XLA scatter, BITWISE, over pools whose every page the step
+  does not write, the trash page among them, was poisoned beforehand (NaNs:
+  arithmetic on one would show) and is compared bit for bit afterwards."""
   nk, h = 4, 8
-  rows = _Rows(pack)
-  kp, vp, tables = _Pools(nk, h)
+  dtype = jnp.float32 if dtype == "f32" else jnp.bfloat16
+  rows, t = _WriteRows(pack)
+  lens, q_pos = (np.asarray(x) for x in (rows.row_len, rows.row_q_pos))
+  b = lens.shape[0]
   rng = np.random.RandomState(2)
-  kn, vn = (jnp.asarray(rng.randn(T, nk, h), jnp.float32) for _ in range(2))
+  np_total = b * T_PAGES + 1
+  tables = rng.permutation(np_total - 1).reshape(b, T_PAGES).astype(np.int32)
+  written = sorted({int(tables[r, lp]) for r in range(b) if lens[r]
+                    for lp in range(q_pos[r] // PAGE,
+                                    (q_pos[r] + lens[r] - 1) // PAGE + 1)})
+  poisoned = np.setdiff1d(np.arange(np_total), written)
+  assert np_total - 1 in poisoned
+
+  def _Pool():
+    pool = rng.randn(np_total, PAGE, nk, h).astype(np.float32)
+    pool[poisoned] = np.nan
+    return jnp.asarray(pool, dtype)
+
+  kp, vp = _Pool(), _Pool()
+  kn, vn = (jnp.asarray(rng.randn(t, nk, h), dtype) for _ in range(2))
+  tables = jnp.asarray(tables)
   key = diff_attend.DiffPlanKey(8, nk, h, PAGE, kn.dtype, kp.dtype,
                                 lowering="pallas")
   plan = attention_lib.BuildRaggedPlan([key], rows, *tables.shape,
                                        page_writes=True)
-  assert plan.writes.tok.shape == (
-      diff_attend.PageWrites(tables.shape[0], T, PAGE), PAGE)
+  bound = diff_attend.PageWrites(b, t, PAGE)
+  assert plan.writes.tok0.shape == (bound,)
+  # the grid's traced length is the pairs the step holds, which the host
+  # counts from its own rows
+  assert int(plan.writes.pairs) == int(plan.writes.live.sum()) == len(
+      written) == run_write.RunCounts(q_pos, lens, PAGE)[0]
+  if pack == "far_under_the_bound":
+    assert (len(written), bound) == (1, 11)
   call = lambda **kw: diff_attend.WritePages(
       kp, vp, kn, vn, tables, rows, lowering="pallas", interpret=True, **kw)
   got, own = call(plan=plan.writes), call()
   scatter = diff_attend.WritePages(kp, vp, kn, vn, tables, rows,
                                    lowering="xla")
-  for a, b, c in zip(got, own, scatter):
-    _Same(a, b)
-    _Same(a[:-1], c[:-1])     # all but the trash page (the scatter's padding)
+  bits = lambda x: np.asarray(x).view(
+      np.uint32 if x.dtype == jnp.float32 else np.uint16)
+  for a, b_, c, old in zip(got, own, scatter, (kp, vp)):
+    _Same(bits(a), bits(b_))
+    _Same(bits(a[:-1]), bits(c[:-1]))   # the trash page: the scatter's padding
+    _Same(bits(a)[poisoned], bits(old)[poisoned])
+    assert not np.isnan(np.asarray(a.astype(jnp.float32))[written]).any()
 
 
 def _LookupDescriptors(row_of, ends, starts, lo, hi, *, bq, nb, page_size,
@@ -586,3 +649,71 @@ def test_the_kernels_program_is_the_twins_within_rounding(programs):
   assert twin.shape == kernels.shape
   np.testing.assert_allclose(np.asarray(kernels), np.asarray(twin),
                              atol=2e-4, rtol=0)
+
+
+# -- the whole-page write's counters, and the programs it must leave alone ----
+
+
+def test_stats_count_the_page_writes_the_steps_plan_holds():
+  """`kv_page_writes` is the sum of `WritePlan.live` over the steps an engine
+  dispatched (the host counts from its own rows the pairs the kernel's grid
+  runs, a program each in every owning layer), `kv_page_write_bound` the
+  room of the plan's list a step; both stay 0 where the twins run and in a
+  stack that writes by runs."""
+  task, theta = _Task("phi4flash", 8)
+  with pytest.MonkeyPatch.context() as mp:
+    mp.setattr(rba, "Lowering", lambda lowering: (
+        "pallas" if lowering == "auto" else lowering))
+    eng, seen = _StepArgs(task, theta)
+    runs_task, runs_theta = _Task("smallthinker", 4)
+    by_runs = _StepArgs(runs_task, runs_theta)[0].Stats()
+  stats = eng.Stats()
+  live = bound = 0
+  for _, _, _, rows, tables in seen:
+    plan = diff_attend.BuildWritePlan(rows, *tables.shape[-2:], PAGE)
+    assert int(plan.pairs) == int(plan.live.sum())
+    live += int(plan.live.sum())
+    bound += plan.live.shape[0]
+  assert stats["steps"] == len(seen) == 2
+  assert (stats["kv_page_writes"], stats["kv_page_write_bound"]) == (
+      live, bound)
+  assert 0 < live < bound == 2 * diff_attend.PageWrites(4, eng._ragged_t, PAGE)
+  assert (stats["kv_write_runs"], stats["kv_write_tokens"]) == (0, 0)
+  assert by_runs["kv_write_runs"] > 0
+  twin = _StepArgs(task, theta)[0].Stats()
+  for other in (by_runs, twin):
+    assert (other["kv_page_writes"], other["kv_page_write_bound"]) == (0, 0)
+
+
+# The step program of each family whose stack has no whole-page writer, as
+# the PARENT of PR 56 (`9336827`) lowers it under JAX 0.9.0 on the CPU:
+# tests/test_head_cols' engines at their mixed step (a decode row, a finishing
+# prompt, a chunk, an empty slot; f32), lines of `lower().as_text()` and the
+# first 16 hex digits of its sha256. The test below is the recipe: run it on
+# a parent's tree to take a number again.
+_PARENT_STEP = {
+    "dense": (1290, "1876dbf11e99e5cf"),
+    "smallthinker": (3634, "4ab22d507292c1e1"),
+    "nemotron_h": (5302, "0e84d3f4da43a21f"),
+    "brumby": (1608, "137c387cefa12e2f"),
+    "mistral4": (1374, "df1550569c9ec9f4"),
+}
+
+
+@pytest.mark.parametrize("family", list(_PARENT_STEP))
+def test_a_stack_with_no_whole_page_writer_lowers_the_parents_step(family):
+  """The whole-page write is one family's (differential attention's): the
+  plan's new fields, the stack's `WritesWholePages` and the engine's two
+  counters leave every other family's step program the parent's text."""
+  import hashlib
+  from tests import test_head_cols
+  task, theta = {**test_head_cols._FAMILIES,
+                 **test_head_cols._NEWER_FAMILIES}[family](jnp.float32)
+  eng, calls, _ = test_head_cols._MixedStepEngine(task, theta)
+  text = eng._ragged_fn.lower(*calls.calls[-1][0]).as_text()
+  lines, digest = _PARENT_STEP[family]
+  stats = eng.Stats()
+  assert (stats["kv_page_writes"], stats["kv_page_write_bound"]) == (0, 0)
+  assert len(text.splitlines()) == lines
+  if jax.__version__ == "0.9.0":      # the text is that version's
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

@@ -64,6 +64,13 @@ _RETENTION_SERVING = dict(t=528, n=40, n_kv=8, h=128, page=128,
                           table_pages=128, pool_pages=833, rows=16, wmax=512)
 
 
+# The whole-page write at the shapes `phi4flash_serve_reason` runs it at: 64
+# slots + a 512-token budget, 20 KV heads of 64 (a token is a LANE of its
+# page), the nine owners' one pool of 9 x 729 pages, 64 pages a row.
+_REASON_SERVING = dict(t=576, n=40, n_kv=20, h=64, page=128, table_pages=64,
+                       pool_pages=6561, rows=64)
+
+
 def _RetentionServingArgs(d=_RETENTION_SERVING):
   import jax.numpy as jnp
   from lingvo_tpu.core import ragged
@@ -180,6 +187,9 @@ def compiles():
               "docs": (_SERVING,), "docs_int8": (_SERVING, jnp.int8),
               "mixed": (_GROUPED_SERVING,), "agent": (_AGENT_SERVING,),
           }.items()})
+      futures["diff_write_serving_reason"] = pool.submit(
+          _Compile, _CASES["diff_write_pages_plan"],
+          _RunWriteServingArgs(_REASON_SERVING))
       futures["grouped_serving_agent"] = pool.submit(
           _Compile, _CASES["ragged_attend_grouped"],
           _GroupedServingArgs(_AGENT_SERVING))
@@ -213,6 +223,13 @@ def test_run_write_compiles_at_serving_shapes(cell, compiles):
   # token rows of 16, 4 and 2 KV heads (4 KB, 1 KB and 512 B of bf16) and of
   # int8: each a whole number of the tiles Mosaic lays that pool out in
   assert "tpu_custom_call" in compiles[f"run_write_serving_{cell}"].result(
+      timeout=300)
+
+
+def test_diff_page_write_compiles_at_serving_shapes(compiles):
+  # the step's new K and V whole in VMEM beside the lanes they are laid out
+  # on and the pages' double buffers; a bf16 page rolled as 32-bit words
+  assert "tpu_custom_call" in compiles["diff_write_serving_reason"].result(
       timeout=300)
 
 

@@ -186,18 +186,21 @@ def test_diff_attend_is_two_softmaxes_a_pair(lowering, window):
   np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
 
-def test_page_writes_land_where_the_scatter_puts_them():
-  """The chip's page writes (a program a (row, page) pair, whole pages
-  rewritten) against the scatter: a decode row, a row that crosses two page
-  boundaries, a row that starts a page, a row with nothing."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_page_writes_land_where_the_scatter_puts_them(dtype):
+  """The chip's page writes (a program a (row, page) pair the step writes,
+  whole pages rewritten; a 16-bit pool's lanes moved as 32-bit words)
+  against the scatter: a decode row, a row that crosses two page boundaries,
+  a row that starts a page, a row with nothing."""
   rng = np.random.RandomState(2)
   page, num_pages, nk, h, t_ = 16, 60, 4, 8, 48
   rows = ragged_lib.BuildRaggedRows(np.array([1, 0, 30, 5]),
                                     np.array([37, 9, 10, 0]), t_, 32)
   rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in rows))
-  f32 = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
-  kp, vp = f32(num_pages, page, nk, h), f32(num_pages, page, nk, h)
-  kn, vn = f32(t_, nk, h), f32(t_, nk, h)
+  rand = lambda *s: jnp.asarray(rng.randn(*s), dtype)
+  kp, vp = rand(num_pages, page, nk, h), rand(num_pages, page, nk, h)
+  kn, vn = rand(t_, nk, h), rand(t_, nk, h)
   tables = jnp.asarray(rng.permutation(num_pages - 1)[:32].reshape(4, 8),
                        jnp.int32)
   want = diff_attend.WritePages(kp, vp, kn, vn, tables, rows, lowering="xla")
@@ -205,9 +208,11 @@ def test_page_writes_land_where_the_scatter_puts_them():
                                lowering="pallas")
   for a, b, old in zip(got, want, (kp, vp)):
     # all but the trash page, which only the scatter's padding writes
-    np.testing.assert_array_equal(np.asarray(a[:-1]), np.asarray(b[:-1]))
-    assert int((np.asarray(a[:-1]) != np.asarray(old[:-1])).any(
-        axis=(2, 3)).sum()) == 36                 # the valid tokens' slots
+    a, b, old = (np.asarray(x.astype(jnp.float32)) for x in (a, b, old))
+    np.testing.assert_array_equal(a[:-1], b[:-1])
+    assert int((a[:-1] != old[:-1]).any(axis=(2, 3)).sum()) == 36  # the
+    #                                              valid tokens' slots
+    np.testing.assert_array_equal(a[-1], old[-1])  # nothing writes the trash
   assert diff_attend.PageWrites(64, 576, 128) == 132
 
 
