@@ -1325,9 +1325,22 @@ class PooledAttention(MultiHeadedAttention):
   kv_owner = True
   writes_by_plan = False
 
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("score_scale", None,
+             "The factor on q . k before the softmax; None = the base "
+             "class's (dim_per_head ** -0.5, or the learned per-dim scale).")
+    return p
+
   @property
   def _h(self) -> int:
     return self._dim_per_head
+
+  def _ScaleQuery(self, theta, q):
+    if self.p.score_scale is None:
+      return super()._ScaleQuery(theta, q)
+    return q * self.p.score_scale
 
   def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
             depth=None):

@@ -830,6 +830,12 @@ class SharedEmbeddingSoftmaxLayer(base_layer.BaseLayer):
     p.Define("vocab_size", 0, "Vocab.")
     p.Define("embedding_dim", 0, "Depth.")
     p.Define("scale_sqrt_depth", True, "Scale embeddings by sqrt(dim).")
+    p.Define("embedding_multiplier", 1.0,
+             "A constant the looked-up embeddings are multiplied by (beside "
+             "scale_sqrt_depth; 1: none, and no op).")
+    p.Define("logits_divisor", 1.0,
+             "A constant the logits are divided by, before any cap (1: none, "
+             "and no op).")
     p.Define("logits_soft_max", 0.0, "If >0, cap logits with tanh.")
     p.Define("xent_block_size", 0,
              "If >0, FProp with class_ids computes the fused blockwise "
@@ -863,6 +869,8 @@ class SharedEmbeddingSoftmaxLayer(base_layer.BaseLayer):
       out = jnp.take(emb, ids, axis=0)
     if p.scale_sqrt_depth:
       out = out * math.sqrt(p.embedding_dim)
+    if p.embedding_multiplier != 1.0:
+      out = out * p.embedding_multiplier
     return out
 
   def Logits(self, theta, inputs):
@@ -872,6 +880,8 @@ class SharedEmbeddingSoftmaxLayer(base_layer.BaseLayer):
       logits = th.emb.Einsum(self.ToFPropDtype(inputs))
     else:
       logits = jnp.einsum("...d,vd->...v", self.ToFPropDtype(inputs), th.emb)
+    if self.p.logits_divisor != 1.0:
+      logits = logits / self.p.logits_divisor
     if self.p.logits_soft_max > 0:
       logits = self.p.logits_soft_max * jnp.tanh(logits / self.p.logits_soft_max)
     return logits

@@ -132,6 +132,9 @@ class DroplessMoELayer(base_layer.BaseLayer):
     p.Define("shared_hidden_dim", 0,
              "Width of the shared expert every token goes through (same "
              "activation); 0 = none.")
+    p.Define("residual_scale", 1.0,
+             "Factor on the layer's output (routed and shared) before it is "
+             "added to its input (1: none, and no op).")
     p.Define("router_reads", "layer_input",
              "'layer_input': the logits are handed in (RouterLogits of the "
              "transformer layer's un-normed input). 'normed_input': the "
@@ -319,7 +322,9 @@ class DroplessMoELayer(base_layer.BaseLayer):
       out, counts = self._Experts(
           theta, x.reshape(-1, d),
           router_logits.reshape(-1, p.num_experts), valid, layer)
-      out = inputs + out.reshape(inputs.shape)
+      out = out.reshape(inputs.shape)
+      out = inputs + (out if p.residual_scale == 1.0
+                      else p.residual_scale * out)
     return out, counts
 
   def FProp(self, theta, inputs, paddings=None, *, router_logits=None):
@@ -339,6 +344,16 @@ class DroplessMoELayer(base_layer.BaseLayer):
       states.elsewhere = jnp.zeros((), jnp.int32)
     return states
 
+  def CountLeaves(self, counts, valid_tokens) -> NestedMap:
+    """A step's count leaves, as `InitPagedStates` lays them out, from its
+    tokens by held expert `counts` [Eh] and the number of its tokens that
+    were routed: `elsewhere` is what is left of their k pairs."""
+    leaves = NestedMap(routed=counts)
+    if self.num_held != self.p.num_experts:
+      leaves.elsewhere = (self.p.num_experts_per_token * valid_tokens
+                          - jnp.sum(counts))
+    return leaves
+
   def RaggedStep(self, theta, inputs, cached_states, rows, *,
                  router_logits=None, layer=None):
     """inputs [1, T, D] packed tokens (core/ragged.py RaggedRows); the
@@ -349,11 +364,8 @@ class DroplessMoELayer(base_layer.BaseLayer):
     out, counts = self.FPropWithCounts(
         theta, inputs, router_logits, paddings,
         layer=layer if theta.w_up.ndim == 4 else None)
-    new_states = NestedMap(routed=counts)
-    if "elsewhere" in cached_states:
-      new_states.elsewhere = (
-          self.p.num_experts_per_token * jnp.sum(rows.valid.astype(jnp.int32))
-          - jnp.sum(counts))
+    new_states = self.CountLeaves(
+        counts, jnp.sum(rows.valid.astype(jnp.int32)))
     if layer is None:
       return out, new_states
     return out, jax.tree_util.tree_map(

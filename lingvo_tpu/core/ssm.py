@@ -731,12 +731,27 @@ class Mamba2Layer(base_layer.BaseLayer):
   where a row starts a request (`rows.row_q_pos == 0`) and carries them from
   one chunk of a prompt to the next; they are leaves of the engine's states,
   so the engine's slot gather / scatter (spill, restore) moves them with the
-  rest. The scan runs on the packed token axis (ops/packed_ssd_scan.py).
+  rest. The scan runs on the packed token axis (ops/packed_ssd_scan.py): its
+  pass over the slots' states is a kernel wherever a group's Hm / G x P
+  channels are whole lane tiles (`packed_ssd_scan.SupportedOnTpu`), a program
+  a (slot, tile of at most 512 channels), so eight groups of 512 and ONE
+  group of 8,192 run the same kernel; a program holds its [512, N] block of
+  the state three times (in, hand-over, out), about 3 MB of VMEM with the
+  pipeline's second buffers.
 
   The published initialisation, so that state neither dies nor saturates:
   A uniform in [1, 16] a head, step sizes log-uniform in [0.001, 0.1]
   (`dt_bias` their inverse softplus), d_skip = 1.
   """
+
+  # the slot-state leaf a scanned block hands over whole, [repeats, slots,
+  # Hm, P, N], with the repeat's index (`layer`): the row pass reads and
+  # writes its layer's where they lie; sliced a trip and stacked back they
+  # would be copied twice a layer (268 MB each at 64 slots of 8,192 x 128)
+  stack_states = ("scan",)
+  # the engine counter of (live rows x layers) whose whole slot state a step
+  # reads and writes (serving/engine.py, observe/schema.py)
+  state_rows_counter = "ssd_state_rows"
 
   @classmethod
   def Params(cls):
@@ -890,10 +905,13 @@ class Mamba2Layer(base_layer.BaseLayer):
         conv=jnp.zeros((num_slots, p.conv_width - 1, self._c), jnp.float32))
 
   def RaggedMix(self, theta, x, states, shared, rows, table=None,
-                depth=None, plan=None):
+                depth=None, plan=None, layer=None):
     """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
     ((y [1, T, Hm, P] f32, z [1, T, E]), new states, shared). What precedes
-    the scan runs over the rows the step holds, as Mamba1Layer's does."""
+    the scan runs over the rows the step holds, as Mamba1Layer's does.
+    layer: in a scanned block (`stack_states`), this layer's index in
+    `states.scan` [repeats, slots, Hm, P, N]; the new `scan` is the whole
+    stack."""
     del table, depth
     from lingvo_tpu.ops import packed_ssd_scan
 
@@ -913,7 +931,8 @@ class Mamba2Layer(base_layer.BaseLayer):
     th = self.CastTheta(theta)
     y, scan = packed_ssd_scan.PackedSsdScan(
         u, delta, -jnp.exp(th.a_log.astype(jnp.float32)), b_t, c_t,
-        th.d_skip, states.scan, rows, chunk_size=self.p.chunk_size)
+        th.d_skip, states.scan, rows, chunk_size=self.p.chunk_size,
+        layer=layer)
     return (y[None], z[None]), NestedMap(scan=scan, conv=new_tail), shared
 
   def RaggedOut(self, theta, y, z, depth=None):

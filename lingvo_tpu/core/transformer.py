@@ -14,6 +14,8 @@ axis can also serve as the pipeline stage axis (ref
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -40,6 +42,9 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
     p.Define("relu_dropout_prob", 0.0, "Dropout after the inner activation.")
     p.Define("norm_tpl", layers_lib.LayerNorm.Params(), "Norm template.")
     p.Define("add_skip_connection", True, "Residual connection.")
+    p.Define("residual_scale", 1.0,
+             "Factor on the block's output before it is added to its input "
+             "(1: none, and no op).")
     p.Define("has_bias", True, "Biases on the projections.")
     return p
 
@@ -96,13 +101,16 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
             keep_prob=1.0 - p.residual_dropout_prob, name_suffix="res")
       if paddings is not None:
         out = py_utils.ApplyPadding(paddings, out)
+      if p.residual_scale != 1.0:
+        out = p.residual_scale * out
       if p.add_skip_connection:
         out = inputs + out
     return out
 
 
-def _MixThenRows(mixer, theta, plan, mix, out, residual, then):
-  """`then(residual + Mixer(.))` in a serving step, the row-wise part over the
+def _MixThenRows(mixer, theta, plan, mix, out, residual, then, scale=1.0):
+  """`then(residual + scale * Mixer(.))` (scale 1: no factor and no op) in a
+  serving step, the row-wise part over the
   rows the step holds. mix(theta) -> (ctx, *rest) is the mixer's
   `RaggedMix` (ctx: a tuple of `[1, T, ...]` arrays; its own projections
   branch inside it); out(theta, *ctx) -> [1, n, D] its `RaggedOut`; then: what
@@ -132,7 +140,8 @@ def _MixThenRows(mixer, theta, plan, mix, out, residual, then):
 
   def _Finish(residual, *ctx):
     with observe.Scope("atten"):
-      x = residual + out(theta, *ctx)
+      branch = out(theta, *ctx)
+      x = residual + (branch if scale == 1.0 else scale * branch)
     return x if then is None else then(x)
 
   if then is None:
@@ -924,7 +933,15 @@ class SharedStateLayer(base_layer.BaseLayer):
   an expert layer (core/moe.DroplessMoELayer whose router reads its own
   normed input): its tokens by expert are the layer's `routed` state leaf,
   and a scanned block hands it the experts' matrices whole with the repeat's
-  index (`StackAddressed`, `repeat`)."""
+  index (`StackAddressed`, `repeat`). Where that layer holds a share of the
+  experts it routes over (`first_expert`, `num_experts_held`) the pairs whose
+  expert lives on another chip are the `elsewhere` leaf beside `routed`,
+  carried through `InitPagedStates` / `RaggedStep` / the scanned block as it
+  is, so that the engine counts them (`moe_pairs_elsewhere`).
+
+  `residual_multiplier`: a factor on each branch's output before it is added
+  to the stream, `h += f * Mixer(LN(h))` and `h += f * FeedForward(LN(h))`
+  (the expert layer's own residual included); 1 is no factor and no op."""
 
   @classmethod
   def Params(cls):
@@ -935,6 +952,8 @@ class SharedStateLayer(base_layer.BaseLayer):
     p.Define("tr_fflayer_tpl", TransformerFeedForwardLayer.Params(),
              "Feed-forward block (with its own norm and residual); None: "
              "no feed-forward.")
+    p.Define("residual_multiplier", 1.0,
+             "Factor on a branch's output before the residual add.")
     return p
 
   def __init__(self, params):
@@ -948,16 +967,14 @@ class SharedStateLayer(base_layer.BaseLayer):
       self.mixer = self.atten
     self._experts = False
     if p.tr_fflayer_tpl is not None:
-      self.CreateChild("fflayer",
-                       p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim))
+      fflayer = p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim)
+      if p.residual_multiplier != 1.0:
+        fflayer.residual_scale = p.residual_multiplier
+      self.CreateChild("fflayer", fflayer)
       self._experts = hasattr(self.fflayer, "FPropWithCounts")
       assert not self._experts or (
           self.fflayer.p.router_reads == "normed_input"), (
               "an expert layer of a BlockSequence routes from its own input")
-      assert not self._experts or (
-          self.fflayer.num_held == self.fflayer.p.num_experts), (
-              "an expert layer of a BlockSequence holds every expert it "
-              "routes over: its step keeps no count of the pairs elsewhere")
 
   def StackAddressed(self) -> set:
     """As TransformerLayer.StackAddressed: the experts' matrices."""
@@ -973,14 +990,18 @@ class SharedStateLayer(base_layer.BaseLayer):
     return tuple(getattr(self.mixer, "stack_states", ()))
 
   def _FeedForward(self, theta, x, paddings, repeat):
-    """-> (x, tokens by expert or None)."""
+    """-> (x, the expert layer's count leaves (`routed`, and `elsewhere`
+    where it holds a share) or None)."""
     if self.p.tr_fflayer_tpl is None:
       return x, None
     if not self._experts:
       return self.fflayer.FProp(theta.fflayer, x, paddings), None
-    return self.fflayer.FPropWithCounts(
+    x, counts = self.fflayer.FPropWithCounts(
         theta.fflayer, x, None, paddings,
         layer=repeat if theta.fflayer.w_up.ndim == 4 else None)
+    valid = (math.prod(x.shape[:-1]) if paddings is None
+             else jnp.sum((paddings < 0.5).astype(jnp.int32)))
+    return x, self.fflayer.CountLeaves(counts, valid)
 
   def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
             depth=0, repeat=None):
@@ -991,14 +1012,15 @@ class SharedStateLayer(base_layer.BaseLayer):
         out, shared = self.atten.FProp(theta.atten, normed, shared,
                                        paddings=paddings,
                                        segment_ids=segment_ids, depth=depth)
-        x = x + out
+        scale = self.p.residual_multiplier
+        x = x + (out if scale == 1.0 else scale * out)
     return self._FeedForward(theta, x, paddings, repeat)[0], shared
 
   def InitPagedStates(self, theta, num_slots):
     states = (self.atten.InitPagedStates(theta.atten, num_slots)
               if self.mixer is not None else NestedMap())
     if self._experts:
-      states.routed = self.fflayer.InitPagedStates(theta.fflayer).routed
+      states.update(self.fflayer.InitPagedStates(theta.fflayer))
     return states
 
   def RaggedStep(self, theta, x, states, shared, rows, table, depth, plan,
@@ -1008,7 +1030,8 @@ class SharedStateLayer(base_layer.BaseLayer):
                     ) if dense else None
     if self.mixer is not None:
       if self._experts:
-        states = NestedMap({k: v for k, v in states.items() if k != "routed"})
+        states = NestedMap({k: v for k, v in states.items()
+                            if k not in ("routed", "elsewhere")})
       with observe.Scope("norm"):
         normed = self.ln.FProp(theta.ln, x)
       # a mixer whose state is too large to slice a trip is handed the
@@ -1020,16 +1043,33 @@ class SharedStateLayer(base_layer.BaseLayer):
               theta, normed, states, shared, rows, table=table, depth=depth,
               plan=plan, **extra),
           lambda theta, *ctx: self.atten.RaggedOut(theta, *ctx, depth=depth),
-          x, feed_forward)
+          x, feed_forward, scale=self.p.residual_multiplier)
     elif dense:
       # a layer that is its dense feed-forward alone
       x = ragged.OverLiveRows(feed_forward, plan, x)
     if self._experts:
       # the step's padding tokens are routed nowhere
-      x, counts = self._FeedForward(
-          theta, x, 1.0 - rows.valid.astype(jnp.float32)[None], repeat)
+      paddings = 1.0 - rows.valid.astype(jnp.float32)[None]
+      experts = lambda x, paddings: self._FeedForward(theta, x, paddings,
+                                                      repeat)
+      # in a step whose live tokens fit the pack's decode width the layer
+      # runs over those rows alone: the grouped matmuls skip dead rows by
+      # themselves, what is round them (the gather of [T x k, D] rows, the
+      # weighing, the unsort, the sum over k) does not
+      narrow = getattr(plan, "narrow", None)
+      if narrow is None:
+        x, counts = experts(x, paddings)
+      else:
+        t, w = x.shape[1], narrow.rows
+
+        def _Narrow(x, paddings):
+          # the valid tokens lead the pack: the first W hold every live one
+          y, counts = experts(x[:, :w], paddings[:, :w])
+          return jnp.pad(y, ((0, 0), (0, t - w), (0, 0))), counts
+
+        x, counts = jax.lax.cond(narrow.fits, _Narrow, experts, x, paddings)
       states = states.Copy()
-      states.routed = counts
+      states.update(counts)
     return x, states, shared
 
 

@@ -14,6 +14,7 @@ try:
   from lingvo_tpu.models.lm.params import nemotron_h  # noqa: F401
   from lingvo_tpu.models.lm.params import brumby  # noqa: F401
   from lingvo_tpu.models.lm.params import mistral4  # noqa: F401
+  from lingvo_tpu.models.lm.params import granite_hybrid  # noqa: F401
   from lingvo_tpu.models.lm.params import phi4flash  # noqa: F401
   from lingvo_tpu.models.lm.params import smallthinker  # noqa: F401
 except ImportError:

@@ -109,7 +109,10 @@ class TransformerLm(base_model.BaseTask):
         "layers: 'M' = 'mamba2', 'E' = 'experts', '*' = 'gqa'; and 'R' = "
         "'retention', a mixer WITH the dense feed-forward. The stack "
         "is its first num_layers letters, so a cut of a published depth "
-        "keeps the pattern's start. None = layer_kinds as given.")
+        "keeps the pattern's start. A published layer of TWO pre-norm "
+        "branches (a mixer, then experts) is written as its two letters, "
+        "'ME' or '*E': num_layers then counts branches, two a published "
+        "layer (granite_hybrid.py). None = layer_kinds as given.")
     p.Define("use_rotary", True, "RoPE instead of absolute positions.")
     p.Define("rope_theta", 1e4,
              "RoPE base where a layer rotates. KV heads and a head size "
@@ -145,6 +148,16 @@ class TransformerLm(base_model.BaseTask):
              "own, [vocab_size, model_dim].")
     p.Define("scale_emb_sqrt_depth", True,
              "Embeddings times sqrt(model_dim).")
+    p.Define("embedding_multiplier", 1.0,
+             "A constant on the looked-up embeddings (beside "
+             "scale_emb_sqrt_depth).")
+    p.Define("logits_scaling", 1.0,
+             "A constant the logits are divided by (the head's, tied or "
+             "not), before the cap.")
+    p.Define("residual_multiplier", 1.0,
+             "A constant on every branch's output before it is added to the "
+             "stream, in a stack told by layer_kinds / "
+             "hybrid_override_pattern (transformer.SharedStateLayer).")
     p.Define(
         "kv_cache_dtype", None,
         "Decode KV-cache storage dtype for every attention layer in the "
@@ -201,7 +214,13 @@ class TransformerLm(base_model.BaseTask):
             logits_soft_max=p.softmax_logits_soft_max,
             xent_block_size=p.xent_block_size,
             scale_sqrt_depth=p.scale_emb_sqrt_depth,
+            embedding_multiplier=p.embedding_multiplier,
+            logits_divisor=p.logits_scaling,
             weight_split_dims_mapping=("model", None)))
+    # the fused blockwise xent and the sampled softmax read the table
+    # themselves and know no divisor
+    assert p.logits_scaling == 1.0 or (
+        p.softmax_num_sampled == 0 and p.xent_block_size == 0)
     if not p.tie_embeddings:
       assert p.softmax_num_sampled == 0 and p.xent_block_size == 0
       self.CreateChild("head", self.emb.p.Copy())
@@ -270,7 +289,8 @@ class TransformerLm(base_model.BaseTask):
         "gqa": lambda: atten.Copy().Set(window=0),
     }
     assert p.sliding_window_size > 0 or "window" not in layer_kinds
-    layer = transformer_lib.SharedStateLayer.Params()
+    layer = transformer_lib.SharedStateLayer.Params().Set(
+        residual_multiplier=p.residual_multiplier)
     layer.tr_fflayer_tpl.Set(
         hidden_dim=p.hidden_dim, activation="SILU", use_gated_activation=True,
         has_bias=False, residual_dropout_prob=p.residual_dropout_prob)
@@ -296,6 +316,9 @@ class TransformerLm(base_model.BaseTask):
 
   def _CreateLayoutStack(self):
     p = self.p
+    assert p.residual_multiplier == 1.0, (
+        "residual_multiplier is a SharedStateLayer's (layer_kinds / "
+        "hybrid_override_pattern)")
     layer_body = transformer_lib.TransformerLayer.Params().Set(
         input_dim=p.model_dim, num_heads=p.num_heads,
         hidden_dim=p.hidden_dim, mask_self_atten=not p.bidirectional)

@@ -65,7 +65,9 @@ class Nemotron3Nano30BA3B(synthetic_packed_input.DenseLmTemplate):
     p = super().Task()
     p.name = "nemotron_h"
     # the stack is the pattern's first num_layers letters: a file that cuts
-    # the depth to one period writes num_layers alone
+    # the depth to one period writes num_layers alone. A letter is ONE
+    # branch: a model whose published layer is two pre-norm branches writes
+    # it as its two letters (granite_hybrid.py)
     p.hybrid_override_pattern = self.PATTERN
     p.norm_tpl = layers_lib.RmsNorm.Params().Set(epsilon=1e-5)
     p.mixer_tpl = ssm_lib.Mamba2Layer.Params().Set(

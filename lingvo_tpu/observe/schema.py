@@ -97,6 +97,10 @@ ENGINE_COUNTER_KEYS = (
     # without them. Beside them in Stats(), not a counter:
     # `shared_kv_read_layers`.
     "ssm_tokens", "cross_tokens_unread",
+    # Mamba-2 layers (core/ssm.Mamba2Layer): live rows times the layers whose
+    # slot state (scan state and convolution tail) a step read and wrote,
+    # counted when the step is dispatched; 0 on a stack without them
+    "ssd_state_rows",
 )
 
 # Static engine configuration facts (set once at construction). `head_rows`:
@@ -353,7 +357,8 @@ WATCHDOG_STATS_KEYS = frozenset({
 # stretch of the step program; a child names a lump inside its block. XLA
 # names a Pallas kernel's op after the innermost scope round its call, and
 # the benchmark's readers find kernels by that name, so no scope that is
-# new goes directly round a `pallas_call` (docs/observability.md).
+# new goes directly round a `pallas_call` (docs/observability.md) unless the
+# kernel is to have a name of its own (`ssd_row_pass`, PR 57).
 # `benchmarks/harness/scope_ms.py` reads this tree: milliseconds a step by
 # block. Names are path segments of `op_name`: none may be a JAX primitive's
 # or a jitted function's.
@@ -422,7 +427,11 @@ DEVICE_SCOPES = {
     "ssd_scan": ("ssm_scan", "the scalar-decay scan over the packed tokens "
                  "(ops/packed_ssd_scan.py), slot state in and out: the "
                  "chunked form's operations and the pass over the slots' "
-                 "states (its kernel is named after it)"),
+                 "states"),
+    "ssd_row_pass": ("ssd_scan", "that pass: the kernel named after it "
+                     "(ops/packed_ssd_scan._PallasRowPass), a program a "
+                     "(slot, channel tile) that reads and writes its block "
+                     "of the state once"),
     "ssd_gate_norm": ("atten", "the gate by z and the RMSNorm over groups of "
                       "channels after it"),
     "ssd_out_proj": ("atten", "the output projection"),

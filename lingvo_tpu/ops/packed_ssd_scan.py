@@ -39,9 +39,10 @@ hold, every chunk at once:
                    chunk's tokens of that row up to it.
 
 The last two touch every slot's state and are one pass over it: a Pallas
-kernel on a TPU (`_PallasRowPass`: a program a (slot, group) reads its
-`[R * P, N]` block once, multiplies the row's first Q tokens' C into it,
-decays and adds, and writes it once, in place), the same arithmetic in XLA
+kernel on a TPU (`_PallasRowPass`: a program a (slot, channel tile) reads its
+`[Wt, N]` block once, multiplies the row's first Q tokens' C into it,
+decays and adds, and writes it once, in place; a group's `R * P` channels
+are one tile or several, `ChannelTile`), the same arithmetic in XLA
 elsewhere (`_XlaRowPass`). Everything else is XLA in both.
 
 All arithmetic f32 (the recurrence compounds over thousands of tokens).
@@ -99,12 +100,15 @@ def _SequentialPackedScan(x, dt, a, b, c, d_skip, state, rows):
   return jnp.where(rows.valid[:, None, None], y, 0.0), s
 
 
-def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass):
+def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass,
+                       layer):
+  """layer: this layer's index in `state` [L, B, Hm, P, N], the states of a
+  scanned block's L layers in one stack (PackedSsdScan)."""
   t, hm, p = x.shape
   g, n = b.shape[1:]
   r = hm // g
   w = r * p                    # a group's channels: the lanes of x and y
-  slots = state.shape[0]
+  slots = state.shape[1]
   nc = -(-t // q)
   pad = nc * q - t
   valid = rows.valid
@@ -162,7 +166,7 @@ def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass):
                       & ~fresh[jnp.clip(r_last, 0)])[:, None],
                      jnp.exp(since[:, -1]), 0.0)              # [nc, Hm]
   local = local + _Wide(inject)[..., None] * state[
-      jnp.clip(r_last, 0)].reshape(nc, g, w, n)
+      layer, jnp.clip(r_last, 0)].reshape(nc, g, w, n)
   carry_on = _Wide(jnp.where((open_ & (first_last < 0))[:, None],
                              jnp.exp(run[:, -1]), 0.0))       # [nc, G, W]
 
@@ -193,10 +197,10 @@ def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass):
   flags = (fresh * _FRESH + (start < c_end * q) * _BEFORE
            + (row_len > 0) * _LIVE).astype(jnp.int32)
   y_rows, new_state = row_pass(
-      state.reshape(slots, g * w, n), came_in.reshape(nc, g * w, n),
+      state.reshape(-1, g * w, n), came_in.reshape(nc, g * w, n),
       flat(cc)[at].reshape(slots, q, g * n), reads,
       xdt.reshape(nc, q, g * w), upto, bc.reshape(nc, q, g * n),
-      jnp.exp(flat(since)[end]), c_end, flags)
+      jnp.exp(flat(since)[end]), c_end, flags, layer * slots)
   # a token in the chunk its row started in reads the slot's state
   started_here = flat(live & (first >= 0))
   place = jnp.clip(flat(rowc), 0) * q + jnp.clip(flat(colc), 0, q - 1)
@@ -206,7 +210,7 @@ def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass):
 
   y = y.reshape(nc * q, hm, p)[:t] + d_skip[None, :, None] * x
   return (jnp.where(valid[:, None, None], y, 0.0),
-          new_state.reshape(slots, hm, p, n))
+          new_state.reshape(state.shape))
 
 
 # -- the pass over the slots' states ------------------------------------------
@@ -228,8 +232,20 @@ def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass):
 #     tokens, new state [B, G * W, N]).
 
 
-def _XlaRowPass(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
-                flags, *, g):
+def _XlaRowPass(stack, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
+                flags, first, *, g):
+  """The twin takes the layer's states out of the stack and lays them back
+  (it may copy)."""
+  with observe.Scope("ssd_row_pass"):
+    slots = reads.shape[0]
+    y_rows, new = _XlaRows(
+        jax.lax.dynamic_slice_in_dim(stack, first, slots), came_in, c_rows,
+        reads, xdt, upto, bc, dec, c_end, flags, g)
+    return y_rows, jax.lax.dynamic_update_slice_in_dim(stack, new, first, 0)
+
+
+def _XlaRows(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end, flags,
+             g):
   slots, gw, n = state.shape
   q, hm = reads.shape[1:]
   w = gw // g
@@ -249,13 +265,14 @@ def _XlaRowPass(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
   return y_rows.reshape(slots, q, gw), new.reshape(slots, gw, n)
 
 
-def _RowKernel(c_end_ref, flag_ref, state_ref, came_ref, c_ref, reads_ref,
-               xdt_ref, upto_ref, b_ref, dec_ref, expand_ref, y_ref, out_ref):
-  """One (slot, group): its [W, N] block of the state, read once and written
-  once."""
-  del c_end_ref                       # the index maps read it
+def _RowKernel(c_end_ref, flag_ref, first_ref, state_ref, came_ref, c_ref,
+               reads_ref, xdt_ref, upto_ref, b_ref, dec_ref, expand_ref,
+               y_ref, out_ref):
+  """One (slot, channel tile): its [Wt, N] block of the state, read once and
+  written once; C and B are its group's, whatever tile of the group it is."""
+  del c_end_ref, first_ref            # the index maps read them
   flag = flag_ref[pl.program_id(0)]
-  expand = expand_ref[...]            # [Hm, W] 0 / 1: a head to its channels
+  expand = expand_ref[...]            # [Hm, Wt] 0 / 1: a head to its channels
 
   def _Dot(a, b, contract):
     return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
@@ -267,8 +284,8 @@ def _RowKernel(c_end_ref, flag_ref, state_ref, came_ref, c_ref, reads_ref,
   y_ref[0] = _Dot(c_ref[0], held, (1, 1)) * wide(reads_ref[0])  # [q, W]
   base = jnp.where((flag & _BEFORE) != 0, came_ref[0], held)
   added = _Dot(xdt_ref[0] * wide(upto_ref[0]), b_ref[0], (0, 0))  # [W, N]
-  # the decay a channel lies on the lanes ([1, W]); the state wants it a
-  # row: a diagonal mask moves it exactly (one nonzero a sum)
+  # the decay a channel lies on the lanes ([1, Wt]); the state wants it a
+  # row: a diagonal mask over the tile moves it exactly (one nonzero a sum)
   w = held.shape[0]
   eye = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
          == jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
@@ -277,71 +294,109 @@ def _RowKernel(c_end_ref, flag_ref, state_ref, came_ref, c_ref, reads_ref,
   out_ref[0] = jnp.where((flag & _LIVE) != 0, dec * base + added, held)
 
 
+_MAX_CHANNEL_TILE = 512    # channels a program: a [512, 128] f32 block is
+#                            256 KB, and the diagonal mask 512 x 512
+
+
+def ChannelTile(group_channels: int) -> int:
+  """Channels Wt of a group's W that one program of the row pass holds: the
+  largest whole number of lane tiles that divides W and is at most 512 (W
+  itself up to there: a group of 512 is one program, as it was before the
+  pass tiled)."""
+  assert group_channels % LANES == 0, group_channels
+  return max(t for t in range(LANES, _MAX_CHANNEL_TILE + 1, LANES)
+             if group_channels % t == 0)
+
+
 @functools.partial(jax.jit, static_argnames=("g", "interpret"))
 def _PallasRowPass(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
-                   flags, *, g: int, interpret: bool):
-  """The kernel over its grid (slots, groups). A `jit` of its own, as
-  selective_scan._ScanCall: the layers of a stack share one trace, and the
-  scope keeps the kernel's name."""
-  slots, gw, n = state.shape
+                   flags, first, *, g: int, interpret: bool):
+  """The kernel over its grid (slots, channel tiles): tile k of the G * W
+  channels lies in group k // (W / Wt) and reads that group's B and C. Where
+  a group is one tile the grid is (slots, groups). first: the row of `state`
+  [L * B, G * W, N] (a scanned block's stack of L layers' states) at which
+  this layer's B slots start: a third prefetched scalar that the state's two
+  index maps add, so that the kernel reads and writes its layer's blocks
+  where they lie and the rest of the stack is the aliased buffer's. A `jit`
+  of its own, as selective_scan._ScanCall: the layers of a stack share one
+  trace, and the scope keeps the kernel's name."""
+  gw, n = state.shape[1:]
+  slots = reads.shape[0]
   q, hm = reads.shape[1:]
-  w = gw // g
+  wt = ChannelTile(gw // g)
+  per_group = gw // g // wt
+  group = (lambda k: k) if per_group == 1 else (lambda k: k // per_group)
   expand = (jnp.arange(hm)[:, None] == jnp.arange(gw)[None] // (gw // hm)
             ).astype(jnp.float32)
-  mine = lambda b, k, *_: (b, k, 0)
-  last = lambda b, k, c_end, _: (c_end[b], 0, k)
-  by_token = lambda b, k, *_: (b, 0, k)
+  mine = lambda b, k, c_end, flags, first: (first[0] + b, k, 0)
   whole = lambda b, k, *_: (b, 0, 0)
-  with observe.Scope("ssd_scan"):
+  with observe.Scope("ssd_row_pass"):
     return pl.pallas_call(
         _RowKernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(slots, g),
+            num_scalar_prefetch=3,
+            grid=(slots, gw // wt),
             in_specs=[
-                pl.BlockSpec((1, w, n), mine),
-                pl.BlockSpec((1, w, n),
-                             lambda b, k, c_end, _: (c_end[b], k, 0)),
-                pl.BlockSpec((1, q, n), by_token),
+                pl.BlockSpec((1, wt, n), mine),
+                pl.BlockSpec((1, wt, n),
+                             lambda b, k, c_end, *_: (c_end[b], k, 0)),
+                pl.BlockSpec((1, q, n), lambda b, k, *_: (b, 0, group(k))),
                 pl.BlockSpec((1, q, hm), whole),
-                pl.BlockSpec((1, q, w), last),
+                pl.BlockSpec((1, q, wt),
+                             lambda b, k, c_end, *_: (c_end[b], 0, k)),
                 pl.BlockSpec((1, q, hm), whole),
-                pl.BlockSpec((1, q, n), last),
+                pl.BlockSpec((1, q, n),
+                             lambda b, k, c_end, *_: (c_end[b], 0, group(k))),
                 pl.BlockSpec((1, SUBLANES, hm), whole),
-                pl.BlockSpec((hm, w), lambda b, k, *_: (0, k)),
+                pl.BlockSpec((hm, wt), lambda b, k, *_: (0, k)),
             ],
             out_specs=[
-                pl.BlockSpec((1, q, w), by_token),
-                pl.BlockSpec((1, w, n), mine),
+                pl.BlockSpec((1, q, wt), lambda b, k, *_: (b, 0, k)),
+                pl.BlockSpec((1, wt, n), mine),
             ]),
         out_shape=[jax.ShapeDtypeStruct((slots, q, gw), jnp.float32),
-                   jax.ShapeDtypeStruct((slots, gw, n), jnp.float32)],
-        input_output_aliases={2: 1},
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={3: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(c_end, flags, state, came_in, c_rows, reads, xdt, upto, bc,
+    )(c_end, flags, jnp.asarray(first, jnp.int32).reshape(1), state, came_in,
+      c_rows, reads, xdt, upto, bc,
       jnp.broadcast_to(dec[:, None], (slots, SUBLANES, hm)), expand)
 
 
 def SupportedOnTpu(chunk_size: int, group_channels: int,
                    state_dim: int) -> bool:
-  """Mosaic's tiling: a group's channels and the state indices on whole
-  lanes, a chunk's tokens on whole sublanes."""
+  """Mosaic's tiling: a group's channels whole lane tiles (`ChannelTile`
+  then finds a tile of at most 512 that divides them, however wide the
+  group: 8 groups of 512 and one group of 8,192 alike), the state indices on
+  whole lanes, a chunk's tokens on whole sublanes. What a program holds in
+  VMEM at a tile of 512, N = 128, Q = 64, Hm = 128: the state's block three
+  times (in, hand-over, out; 256 KB each), the head-to-channel expansion
+  [Hm, 512] (256 KB), dt x and y of the chunk (128 KB each) and under 150 KB
+  of the rest, each twice for the pipeline: about 3 MB of the 16 the
+  compiler grants."""
   return (chunk_size % SUBLANES == 0 and group_channels % LANES == 0
           and state_dim % LANES == 0)
 
 
 def PackedSsdScan(x, dt, a, b, c, d_skip, state, rows, *, chunk_size: int = 64,
-                  lowering: str = "auto", interpret: bool | None = None):
+                  lowering: str = "auto", interpret: bool | None = None,
+                  layer=None):
   """The packed step's scan (module docstring). x: [T, Hm, P]; dt: [T, Hm],
   positive; a: [Hm], negative; b, c: [T, G, N], G dividing Hm; d_skip: [Hm];
   state: [B, Hm, P, N]; rows: the step's `core/ragged.RaggedRows` (chains
   only). All f32 inside, whatever arrives. -> (y [T, Hm, P] f32, zeros at
-  padding tokens; new state [B, Hm, P, N] f32). lowering: 'auto' (the
-  chunked form, its pass over the slots' states the kernel on a TPU where
-  `SupportedOnTpu`, XLA elsewhere) | 'pallas' | 'xla' | 'sequential' (the
-  twin a token at a time)."""
+  padding tokens; new state [B, Hm, P, N] f32). layer: where given (a traced
+  index), `state` is [L, B, Hm, P, N], the states of a scanned block's L
+  layers in one stack, and this call reads and writes layer `layer`'s where
+  they lie: the new state is the whole stack, the kernel's aliased buffer
+  (a block's scan that sliced a layer's 268 MB out and stacked them back
+  would copy them twice a layer). One layer's state alone is a stack of one.
+  lowering: 'auto' (the chunked form, its pass over the slots' states the
+  kernel on a TPU where `SupportedOnTpu`: any group whose channels are whole
+  lane tiles; XLA elsewhere) | 'pallas' | 'xla' | 'sequential' (the twin a
+  token at a time)."""
   assert lowering in ("auto", "pallas", "xla", "sequential"), lowering
   x, dt, a, b, c, d_skip, state = (
       v.astype(jnp.float32) for v in (x, dt, a, b, c, d_skip, state))
@@ -351,19 +406,26 @@ def PackedSsdScan(x, dt, a, b, c, d_skip, state, rows, *, chunk_size: int = 64,
   if lowering == "auto":
     lowering = ("pallas" if on_tpu and SupportedOnTpu(
         chunk_size, hm // g * x.shape[2], b.shape[2]) else "xla")
+  alone = layer is None
+  if alone:
+    state, layer = state[None], 0
   if lowering == "sequential":
     with observe.Scope("ssd_scan"):
-      return _SequentialPackedScan(x, dt, a, b, c, d_skip, state, rows)
-  return _ChunkedCall(
-      x, dt, a, b, c, d_skip, state, rows, q=int(chunk_size),
-      kernel=lowering != "xla",
-      interpret=(not on_tpu) if interpret is None else interpret)
+      y, new = _SequentialPackedScan(x, dt, a, b, c, d_skip, state[layer],
+                                     rows)
+      new = state.at[layer].set(new)
+  else:
+    y, new = _ChunkedCall(
+        x, dt, a, b, c, d_skip, state, rows, layer, q=int(chunk_size),
+        kernel=lowering != "xla",
+        interpret=(not on_tpu) if interpret is None else interpret)
+  return y, new[0] if alone else new
 
 
 @functools.partial(jax.jit, static_argnames=("q", "kernel", "interpret"),
                    inline=True)
-def _ChunkedCall(x, dt, a, b, c, d_skip, state, rows, *, q: int, kernel: bool,
-                 interpret: bool):
+def _ChunkedCall(x, dt, a, b, c, d_skip, state, rows, layer, *, q: int,
+                 kernel: bool, interpret: bool):
   """`_ChunkedPackedScan` as a `jit` of its own, inlined where it is called:
   the Mamba-2 layers of a stack (and a probe's program) share ONE trace of
   its hundred and fifty `jnp` calls, which the step program would else
@@ -374,4 +436,4 @@ def _ChunkedCall(x, dt, a, b, c, d_skip, state, rows, *, q: int, kernel: bool,
               if kernel else functools.partial(_XlaRowPass, g=g))
   with observe.Scope("ssd_scan"):
     return _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q,
-                              row_pass)
+                              row_pass, layer)

@@ -570,6 +570,11 @@ class ServingLoop:
     self._retention_layers = sum(
         reps for m, reps in self._mixer_layers
         if hasattr(m, "StateBytesPerSlot") and hasattr(m, "KvBytesPerToken"))
+    # layers whose slot state every live row reads and writes whole each
+    # step, by the counter the mixer names (Mamba-2)
+    self._ssd_layers = sum(
+        reps for m, reps in self._mixer_layers
+        if getattr(m, "state_rows_counter", None) == "ssd_state_rows")
     # layers that read pages another layer owns (0: the stack has none)
     self._shared_kv_read_layers = getattr(
         task.stack, "SharedKvReadLayers", lambda: 0)()
@@ -1257,6 +1262,8 @@ class ServingLoop:
     if self.state_pool is not None:
       out.update((k, self._counters[k].value) for k in (
           "ssm_tokens", "cross_tokens_unread"))
+    if self._ssd_layers:
+      out["ssd_state_rows"] = self._counters["ssd_state_rows"].value
     if self._retention_layers:
       out.update((k, self._counters[k].value) for k in (
           "retention_rows", "retention_folds", "retention_chunk_tokens"))
@@ -1280,6 +1287,9 @@ class ServingLoop:
           for seq, n in zip(batch.rows, row_len)
           if seq is not None and n > 0
           and seq.state is scheduler_lib.SeqState.PREFILL))
+    if self._ssd_layers:
+      self._counters["ssd_state_rows"].Inc(
+          self._ssd_layers * int((row_len > 0).sum()))
     if self._retention_layers:
       # power-retention layers: rows with a state, pages folded into one and
       # keys attended in open chunks, a layer (before the cursors advance)

@@ -199,6 +199,57 @@ def compiles():
     compilation_cache.reset_cache()
 
 
+# The tiled row pass of the packed Mamba-2 scan at the two shapes the cells
+# run it at: `nemotron3nano_serve_agent` (64 heads of 64 in 8 groups: a group
+# of 512 channels is ONE tile) and `granite4hsmall_serve_tools` (128 heads of
+# 64 in ONE group: 16 tiles of 512), each with one layer's state alone (a
+# stack of one) and with the state read and written in its scanned block's
+# stack of layers, as the cells run it; 64 slots + a 1,024-token budget.
+_ROW_PASS = {"agent": dict(hm=64, g=8, stack=0),
+             "agent_in_stack": dict(hm=64, g=8, stack=5),
+             "tools": dict(hm=128, g=1, stack=0),
+             "tools_in_stack": dict(hm=128, g=1, stack=5)}
+
+
+@pytest.mark.parametrize("shape", sorted(_ROW_PASS))
+def test_the_row_pass_compiles_at_serving_shapes(shape):
+  import jax.numpy as jnp
+  from lingvo_tpu.core import ragged
+  from lingvo_tpu.ops import packed_ssd_scan
+  try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+  except Exception as e:  # noqa: BLE001 - no TPU compiler
+    pytest.skip(f"cannot describe a v5e topology: {e}")
+  one_chip = SingleDeviceSharding(topo.devices[0])
+  d = _ROW_PASS[shape]
+  t, slots, wmax, p, n = 1088, 64, 1024, 64, 128
+  f32, i32 = jnp.float32, jnp.int32
+  sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+  tok = sds((t,), i32)
+  rows = ragged.RaggedRows(
+      row_of=tok, col_of=tok, pos=tok, valid=sds((t,), jnp.bool_),
+      row_q_pos=sds((slots,), i32), row_len=sds((slots,), i32),
+      row_cols=sds((slots, wmax), i32), pos_ids=tok, anc_lo=tok, anc_hi=tok,
+      col_parent=sds((slots, wmax), i32))
+  state = (slots, d["hm"], p, n)
+  args = [sds((t, d["hm"], p), f32), sds((t, d["hm"]), f32),
+          sds((d["hm"],), f32), sds((t, d["g"], n), f32),
+          sds((t, d["g"], n), f32), sds((d["hm"],), f32),
+          sds(((d["stack"],) if d["stack"] else ()) + state, f32), rows]
+  layer = {"layer": 3} if d["stack"] else {}
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    text = jax.jit(lambda *a: packed_ssd_scan.PackedSsdScan(
+        *a, chunk_size=64, lowering="pallas", interpret=False, **layer),
+                   donate_argnums=(6,)).lower(*args).compile().as_text()
+  finally:
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+  assert "tpu_custom_call" in text and "%ssd_row_pass" in text
+
+
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_kernel_compiles_for_v5e(name, compiles):
   # raises what the chip's compiler would raise

@@ -927,15 +927,20 @@ def test_the_mixer_has_no_dense_decode_contract(method):
       layer.ExtendStep(None)
 
 
-def test_a_window_and_a_block_sequence_are_refused_by_name():
+def test_a_window_is_refused_by_name_and_a_block_sequence_takes_a_share():
   with pytest.raises(AssertionError, match="no window"):
     _Mixer(window=16)
+  # refused until PR 57 ("holds every expert it routes over"): the layer of
+  # a BlockSequence now carries the share's `elsewhere` leaf beside `routed`
   from lingvo_tpu.core import moe as moe_lib
   from lingvo_tpu.core import transformer as transformer_lib
-  with pytest.raises(AssertionError, match="holds every expert"):
-    p = transformer_lib.SharedStateLayer.Params().Set(
-        name="layer", input_dim=48, mixer_tpl=None,
-        tr_fflayer_tpl=moe_lib.DroplessMoELayer.Params().Set(
-            hidden_dim=8, num_experts=8, num_experts_held=2,
-            router_reads="normed_input"))
-    p.Instantiate()
+  p = transformer_lib.SharedStateLayer.Params().Set(
+      name="layer", input_dim=48, mixer_tpl=None,
+      tr_fflayer_tpl=moe_lib.DroplessMoELayer.Params().Set(
+          hidden_dim=8, num_experts=8, num_experts_held=2,
+          router_reads="normed_input"))
+  layer = p.Instantiate()
+  layer.FinalizePaths()
+  theta = layer.InstantiateVariables(jax.random.PRNGKey(0))
+  states = layer.InitPagedStates(theta, 4)
+  assert states.routed.shape == (2,) and states.elsewhere.shape == ()

@@ -786,16 +786,18 @@ def test_the_serving_step_branches_once_a_layer_on_whole_stacks():
 # conditionals a layer (docs/ragged_step.md's table): what precedes a mixer's
 # kernel where that is row-wise and its weights are not re-laid, and one more
 # for what follows it (the output projection and the residual) WITH a dense
-# feed-forward; a layer that is its mixer alone branches nothing behind it
+# feed-forward; a layer that is its mixer alone branches nothing behind it;
+# an expert layer of a BlockSequence runs at the width the step holds under a
+# conditional of its own (PR 57)
 _MIXER_CONDS = {"Mamba1Layer": 1, "Mamba2Layer": 1,
                 "DifferentialAttention": 1, "GatedMemoryUnit": 0,
                 "PooledAttention": 0, "PowerRetention": 0,
                 "MultiHeadedAttention": 0}
 
 
-def _LayerConds(mixer, dense: bool) -> int:
+def _LayerConds(mixer, dense: bool, experts: bool = False) -> int:
   before = _MIXER_CONDS[type(mixer).__name__] if mixer else 0
-  return before + (1 if dense else 0)
+  return before + (1 if dense or experts else 0)
 
 
 @pytest.mark.parametrize("family", list(_FAMILIES))
@@ -813,7 +815,7 @@ def test_conditionals_a_traced_step_by_stack(family):
   stack = task.stack
   if hasattr(stack, "_bodies"):
     want = sum(_LayerConds(l.mixer, hasattr(l, "fflayer")
-                           and not l._experts)
+                           and not l._experts, l._experts)
                for layers in stack._bodies for l in layers)
   else:
     layers = getattr(stack.body, "x_layers", [stack.body])
