@@ -99,8 +99,12 @@ ENGINE_COUNTER_KEYS = (
     "ssm_tokens", "cross_tokens_unread",
     # Mamba-2 layers (core/ssm.Mamba2Layer): live rows times the layers whose
     # slot state (scan state and convolution tail) a step read and wrote,
-    # counted when the step is dispatched; 0 on a stack without them
-    "ssd_state_rows",
+    # counted when the step is dispatched; and those of them whose row holds
+    # ONE token in the step, for which the scan's row pass
+    # (ops/packed_ssd_scan._PallasRowPass) runs its narrow body:
+    # `ssd_narrow_rows / ssd_state_rows` is the share of the live (row,
+    # layer) pairs that take it. Both 0 on a stack without such layers
+    "ssd_state_rows", "ssd_narrow_rows",
 )
 
 # Static engine configuration facts (set once at construction). `head_rows`:

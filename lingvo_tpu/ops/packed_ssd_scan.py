@@ -45,6 +45,21 @@ decays and adds, and writes it once, in place; a group's `R * P` channels
 are one tile or several, `ChannelTile`), the same arithmetic in XLA
 elsewhere (`_XlaRowPass`). Everything else is XLA in both.
 
+A program of the kernel is sized by what its row holds in the step, which it
+reads from the row's flags (prefetched scalars; no Param, no model's name).
+TWO BODIES in the one call: a row of two tokens or more runs the products
+over a chunk's Q tokens (its first chunk's C, its last chunk's dt x and B,
+the hand-over into that chunk if it came in from an earlier one); a row of
+ONE token (a decode row; `_NARROW`), which is 63 of a serving step's 64, has
+one rank-one update and one read-out to do, takes its token's dt x, decay, C
+and B from two `[8, .]` blocks gathered in front of the call and leaves its
+read-out in an `[8, Wt]` block of a second, narrow output. The index maps
+read the same flags: an operand that only the other body reads, and the
+hand-over block of a row that did not come in, stand still at one block,
+which the pipeline holds and does not copy again. The narrow read-outs are
+laid into `y_rows` at the rows' first tokens behind the call (64 rows written
+in place), so `_ChunkedPackedScan` gathers from one array, as it did.
+
 All arithmetic f32 (the recurrence compounds over thousands of tokens).
 `_SequentialPackedScan` is the twin the tests hold both to: the row view
 `[slots, wmax]` of the pack scanned a column at a time.
@@ -63,7 +78,7 @@ from lingvo_tpu import observe
 from lingvo_tpu.ops.flash_attention import LANES, SUBLANES
 
 _HIGHEST = jax.lax.Precision.HIGHEST
-_FRESH, _BEFORE, _LIVE = 1, 2, 4     # bits of a row's flags
+_FRESH, _BEFORE, _LIVE, _NARROW = 1, 2, 4, 8     # bits of a row's flags
 
 
 def _Einsum(spec, *args):
@@ -195,18 +210,19 @@ def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass,
                    jnp.exp(run_end[jnp.arange(slots), i_end][:, None]
                            - run_end), 0.0)                   # [B, q, Hm]
   flags = (fresh * _FRESH + (start < c_end * q) * _BEFORE
-           + (row_len > 0) * _LIVE).astype(jnp.int32)
+           + (row_len > 0) * _LIVE + (row_len <= 1) * _NARROW
+           ).astype(jnp.int32)
   y_rows, new_state = row_pass(
       state.reshape(-1, g * w, n), came_in.reshape(nc, g * w, n),
       flat(cc)[at].reshape(slots, q, g * n), reads,
       xdt.reshape(nc, q, g * w), upto, bc.reshape(nc, q, g * n),
-      jnp.exp(flat(since)[end]), c_end, flags, layer * slots)
+      jnp.exp(flat(since)[end]), end, flags, layer * slots)
   # a token in the chunk its row started in reads the slot's state
   started_here = flat(live & (first >= 0))
   place = jnp.clip(flat(rowc), 0) * q + jnp.clip(flat(colc), 0, q - 1)
   y = flat(y) + jnp.where(
       started_here[:, None, None],
-      y_rows.reshape(slots * q, g, w)[place], 0.0)
+      y_rows.reshape(-1, g, w)[place], 0.0)
 
   y = y.reshape(nc * q, hm, p)[:t] + d_skip[None, :, None] * x
   return (jnp.where(valid[:, None, None], y, 0.0),
@@ -223,24 +239,26 @@ def _ChunkedPackedScan(x, dt, a, b, c, d_skip, state, rows, q, row_pass,
 # G * N]: dt x and B of every chunk's tokens, and upto [B, q, Hm] the decay
 # of the tokens of the row's LAST chunk to the row's end (zero where a token
 # is not the row's or lies behind its end); dec [B, Hm]: the decay from the
-# row's start (or its last chunk's) to its end; c_end [B]: the row's last
-# chunk; flags [B]: _FRESH the row starts a request (its slot's state reads
-# as zeros), _BEFORE it came into its last chunk from an earlier one (the
-# state to decay is that chunk's hand-over, not the slot's), _LIVE it has
-# tokens in this step. What is a head's reaches its P channels inside.
-# -> (y_rows [B, q, G * W]: reads * (C_j . state) for the row's first q
-#     tokens, new state [B, G * W, N]).
+# row's start (or its last chunk's) to its end; end [B]: the row's last
+# token in the padded pack (chunk end // q, index end % q); flags [B]: _FRESH
+# the row starts a request (its slot's state reads as zeros), _BEFORE it came
+# into its last chunk from an earlier one (the state to decay is that chunk's
+# hand-over, not the slot's), _LIVE it has tokens in this step, _NARROW it
+# has at most one (reads[b, 0] and dec[b] are then the same decay, and upto
+# is 1 at the token). What is a head's reaches its P channels inside.
+# -> (y_rows [>= B, q, G * W]: reads * (C_j . state) for the row's first q
+#     tokens (of a _NARROW row the first alone), new state [B, G * W, N]).
 
 
-def _XlaRowPass(stack, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
+def _XlaRowPass(stack, came_in, c_rows, reads, xdt, upto, bc, dec, end,
                 flags, first, *, g):
   """The twin takes the layer's states out of the stack and lays them back
-  (it may copy)."""
+  (it may copy); one body for every row."""
   with observe.Scope("ssd_row_pass"):
-    slots = reads.shape[0]
+    slots, q = reads.shape[:2]
     y_rows, new = _XlaRows(
         jax.lax.dynamic_slice_in_dim(stack, first, slots), came_in, c_rows,
-        reads, xdt, upto, bc, dec, c_end, flags, g)
+        reads, xdt, upto, bc, dec, end // q, flags, g)
     return y_rows, jax.lax.dynamic_update_slice_in_dim(stack, new, first, 0)
 
 
@@ -265,33 +283,54 @@ def _XlaRows(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end, flags,
   return y_rows.reshape(slots, q, gw), new.reshape(slots, gw, n)
 
 
-def _RowKernel(c_end_ref, flag_ref, first_ref, state_ref, came_ref, c_ref,
+def _Dot(a, b, contract):
+  return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                             precision=_HIGHEST,
+                             preferred_element_type=jnp.float32)
+
+
+def _RowKernel(end_ref, flag_ref, first_ref, state_ref, came_ref, c_ref,
                reads_ref, xdt_ref, upto_ref, b_ref, dec_ref, expand_ref,
-               y_ref, out_ref):
+               tok_w_ref, tok_g_ref, y_ref, out_ref, y_tok_ref):
   """One (slot, channel tile): its [Wt, N] block of the state, read once and
-  written once; C and B are its group's, whatever tile of the group it is."""
-  del c_end_ref, first_ref            # the index maps read them
+  written once; C and B are its group's, whatever tile of the group it is.
+  Two bodies, the row's flags decide: a row of at most one token (`_NARROW`)
+  reads its token's operands from two [8, .] blocks and writes its read-out
+  to one; a row of more runs the products over a chunk's q tokens."""
+  del end_ref, first_ref              # the index maps read them
   flag = flag_ref[pl.program_id(0)]
-  expand = expand_ref[...]            # [Hm, Wt] 0 / 1: a head to its channels
+  held = jnp.where((flag & _FRESH) != 0, 0.0, state_ref[0])    # [Wt, N]
+  live = (flag & _LIVE) != 0
+  narrow = (flag & _NARROW) != 0
 
-  def _Dot(a, b, contract):
-    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
-                               precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
+  @pl.when(narrow)
+  def _OneToken():
+    # rows of tok_w: the token's dt x, the decay of its step, each a
+    # channel; of tok_g: its C, its B
+    tok_w, tok_g = tok_w_ref[0], tok_g_ref[0]        # [8, Wt], [8, N]
+    y_tok_ref[0] = _Dot(tok_g, held, (1, 1)) * tok_w[1:2]      # row 0: C's
+    # what is a channel's lies on the lanes; the state wants it down its
+    # rows, the same across the N lanes: the row broadcast and transposed
+    # (exact, and no product: the probe's fastest form, PERF.md section 6)
+    col = lambda r: jnp.transpose(
+        jnp.broadcast_to(tok_w[r:r + 1], held.shape[::-1]))    # [Wt, N]
+    out_ref[0] = jnp.where(live, col(1) * held + col(0) * tok_g[1:2], held)
 
-  wide = lambda v: _Dot(v, expand, (1, 0))                      # [., W]
-  held = jnp.where((flag & _FRESH) != 0, 0.0, state_ref[0])    # [W, N]
-  y_ref[0] = _Dot(c_ref[0], held, (1, 1)) * wide(reads_ref[0])  # [q, W]
-  base = jnp.where((flag & _BEFORE) != 0, came_ref[0], held)
-  added = _Dot(xdt_ref[0] * wide(upto_ref[0]), b_ref[0], (0, 0))  # [W, N]
-  # the decay a channel lies on the lanes ([1, Wt]); the state wants it a
-  # row: a diagonal mask over the tile moves it exactly (one nonzero a sum)
-  w = held.shape[0]
-  eye = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
-         == jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
-  dec = jnp.sum(jnp.where(eye, wide(dec_ref[0])[:1], 0.0), axis=1,
-                keepdims=True)                                  # [W, 1]
-  out_ref[0] = jnp.where((flag & _LIVE) != 0, dec * base + added, held)
+  @pl.when(jnp.logical_not(narrow))
+  def _Chunk():
+    expand = expand_ref[...]          # [Hm, Wt] 0 / 1: a head to its channels
+    wide = lambda v: _Dot(v, expand, (1, 0))                      # [., Wt]
+    y_ref[0] = _Dot(c_ref[0], held, (1, 1)) * wide(reads_ref[0])  # [q, Wt]
+    base = jnp.where((flag & _BEFORE) != 0, came_ref[0], held)
+    added = _Dot(xdt_ref[0] * wide(upto_ref[0]), b_ref[0], (0, 0))  # [Wt, N]
+    # the decay a channel lies on the lanes ([1, Wt]); the state wants it a
+    # row: a diagonal mask over the tile moves it exactly (one nonzero a sum)
+    w = held.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
+    dec = jnp.sum(jnp.where(eye, wide(dec_ref[0])[:1], 0.0), axis=1,
+                  keepdims=True)                                  # [Wt, 1]
+    out_ref[0] = jnp.where(live, dec * base + added, held)
 
 
 _MAX_CHANNEL_TILE = 512    # channels a program: a [512, 128] f32 block is
@@ -308,9 +347,14 @@ def ChannelTile(group_channels: int) -> int:
              if group_channels % t == 0)
 
 
-@functools.partial(jax.jit, static_argnames=("g", "interpret"))
-def _PallasRowPass(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
-                   flags, first, *, g: int, interpret: bool):
+CUTS = ("hand_over", "narrow")   # what a program no longer fetches or
+#                                   multiplies in vain; tools/kernel_probe.py
+#                                   times the kernel with each alone
+
+
+@functools.partial(jax.jit, static_argnames=("g", "interpret", "cuts"))
+def _PallasRowPass(state, came_in, c_rows, reads, xdt, upto, bc, dec, end,
+                   flags, first, *, g: int, interpret: bool, cuts=CUTS):
   """The kernel over its grid (slots, channel tiles): tile k of the G * W
   channels lies in group k // (W / Wt) and reads that group's B and C. Where
   a group is one tile the grid is (slots, groups). first: the row of `state`
@@ -319,50 +363,103 @@ def _PallasRowPass(state, came_in, c_rows, reads, xdt, upto, bc, dec, c_end,
   index maps add, so that the kernel reads and writes its layer's blocks
   where they lie and the rest of the stack is the aliased buffer's. A `jit`
   of its own, as selective_scan._ScanCall: the layers of a stack share one
-  trace, and the scope keeps the kernel's name."""
+  trace, and the scope keeps the kernel's name.
+
+  A program moves what its row holds in this step, by the row's flags in the
+  index maps: an operand that only the other body reads, and the hand-over of
+  a row that did not come in, stand still at one block, which the pipeline
+  keeps and does not copy again."""
   gw, n = state.shape[1:]
-  slots = reads.shape[0]
-  q, hm = reads.shape[1:]
+  slots, q, hm = reads.shape
   wt = ChannelTile(gw // g)
   per_group = gw // g // wt
   group = (lambda k: k) if per_group == 1 else (lambda k: k // per_group)
+  if "narrow" not in cuts:
+    flags = flags & ~_NARROW
+  # a one-token row's operands, 8 rows a slot: its token's dt x and its
+  # step's decay (a head's, beside the head's channels); its C and its B
+  c_end, i_end = end // q, end % q
+  tok_w = jnp.stack([xdt[c_end, i_end], jnp.repeat(dec, gw // hm, axis=-1)],
+                    axis=1)
+  tok_g = jnp.stack([c_rows[:, 0], bc[c_end, i_end]], axis=1)
+  rows8 = lambda v: jnp.pad(v, ((0, 0), (0, SUBLANES - v.shape[1]), (0, 0)))
+
+  def _Wide(b, flags):
+    return 1 - ((flags[b] & _NARROW) != 0).astype(jnp.int32)
+
+  def _Came(b, k, end, flags, first):
+    on = (((flags[b] & _BEFORE) != 0).astype(jnp.int32)
+          if "hand_over" in cuts else 1)
+    return end[b] // q * on, k * on, 0
+
+  def _Slot(tile):      # a chunk row's own [q, .] block of its slot's
+    def _Map(b, k, end, flags, first):
+      on = _Wide(b, flags)
+      return b * on, 0, tile(k) * on
+    return _Map
+
+  def _LastChunk(tile):       # ... and of the chunk it ends in
+    def _Map(b, k, end, flags, first):
+      on = _Wide(b, flags)
+      return end[b] // q * on, 0, tile(k) * on
+    return _Map
+
+  def _Token(tile):           # a one-token row's [8, .] block
+    def _Map(b, k, end, flags, first):
+      return b, 0, tile(k) * (1 - _Wide(b, flags))
+    return _Map
+
+  def _Rows(b, k, end, flags, first):
+    # y_rows has one block row more than slots: where the one-token rows'
+    # programs leave what they did not write
+    on = _Wide(b, flags)
+    return b * on + slots * (1 - on), 0, k * on
+
+  mine = lambda b, k, end, flags, first: (first[0] + b, k, 0)
+  tile, whole = (lambda k: k), (lambda k: 0)
   expand = (jnp.arange(hm)[:, None] == jnp.arange(gw)[None] // (gw // hm)
             ).astype(jnp.float32)
-  mine = lambda b, k, c_end, flags, first: (first[0] + b, k, 0)
-  whole = lambda b, k, *_: (b, 0, 0)
   with observe.Scope("ssd_row_pass"):
-    return pl.pallas_call(
+    y_rows, new, y_tok = pl.pallas_call(
         _RowKernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(slots, gw // wt),
             in_specs=[
                 pl.BlockSpec((1, wt, n), mine),
-                pl.BlockSpec((1, wt, n),
-                             lambda b, k, c_end, *_: (c_end[b], k, 0)),
-                pl.BlockSpec((1, q, n), lambda b, k, *_: (b, 0, group(k))),
-                pl.BlockSpec((1, q, hm), whole),
-                pl.BlockSpec((1, q, wt),
-                             lambda b, k, c_end, *_: (c_end[b], 0, k)),
-                pl.BlockSpec((1, q, hm), whole),
-                pl.BlockSpec((1, q, n),
-                             lambda b, k, c_end, *_: (c_end[b], 0, group(k))),
-                pl.BlockSpec((1, SUBLANES, hm), whole),
-                pl.BlockSpec((hm, wt), lambda b, k, *_: (0, k)),
+                pl.BlockSpec((1, wt, n), _Came),
+                pl.BlockSpec((1, q, n), _Slot(group)),
+                pl.BlockSpec((1, q, hm), _Slot(whole)),
+                pl.BlockSpec((1, q, wt), _LastChunk(tile)),
+                pl.BlockSpec((1, q, hm), _Slot(whole)),
+                pl.BlockSpec((1, q, n), _LastChunk(group)),
+                pl.BlockSpec((1, SUBLANES, hm), _Slot(whole)),
+                pl.BlockSpec((hm, wt), lambda b, k, end, flags, first: (
+                    0, k * _Wide(b, flags))),
+                pl.BlockSpec((1, SUBLANES, wt), _Token(tile)),
+                pl.BlockSpec((1, SUBLANES, n), _Token(group)),
             ],
             out_specs=[
-                pl.BlockSpec((1, q, wt), lambda b, k, *_: (b, 0, k)),
+                pl.BlockSpec((1, q, wt), _Rows),
                 pl.BlockSpec((1, wt, n), mine),
+                pl.BlockSpec((1, SUBLANES, wt), _Token(tile)),
             ]),
-        out_shape=[jax.ShapeDtypeStruct((slots, q, gw), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((slots + 1, q, gw), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((slots, SUBLANES, gw), jnp.float32)],
         input_output_aliases={3: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(c_end, flags, jnp.asarray(first, jnp.int32).reshape(1), state, came_in,
+    )(end, flags, jnp.asarray(first, jnp.int32).reshape(1), state, came_in,
       c_rows, reads, xdt, upto, bc,
-      jnp.broadcast_to(dec[:, None], (slots, SUBLANES, hm)), expand)
+      jnp.broadcast_to(dec[:, None], (slots, SUBLANES, hm)), expand,
+      rows8(tok_w), rows8(tok_g))
+  # a one-token row's read-out goes where a chunk row's first token's lies
+  # (64 rows written into the kernel's own output, in place)
+  narrow = ((flags & _NARROW) != 0)[:, None]
+  return y_rows.at[:slots, 0].set(
+      jnp.where(narrow, y_tok[:, 0], y_rows[:slots, 0])), new
 
 
 def SupportedOnTpu(chunk_size: int, group_channels: int,
@@ -373,9 +470,9 @@ def SupportedOnTpu(chunk_size: int, group_channels: int,
   whole lanes, a chunk's tokens on whole sublanes. What a program holds in
   VMEM at a tile of 512, N = 128, Q = 64, Hm = 128: the state's block three
   times (in, hand-over, out; 256 KB each), the head-to-channel expansion
-  [Hm, 512] (256 KB), dt x and y of the chunk (128 KB each) and under 150 KB
-  of the rest, each twice for the pipeline: about 3 MB of the 16 the
-  compiler grants."""
+  [Hm, 512] (256 KB), dt x and y of the chunk (128 KB each) and under 200 KB
+  of the rest (the one-token body's three [8, .] blocks among it), each twice
+  for the pipeline: about 3 MB of the 16 the compiler grants."""
   return (chunk_size % SUBLANES == 0 and group_channels % LANES == 0
           and state_dim % LANES == 0)
 

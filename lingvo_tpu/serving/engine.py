@@ -1263,7 +1263,8 @@ class ServingLoop:
       out.update((k, self._counters[k].value) for k in (
           "ssm_tokens", "cross_tokens_unread"))
     if self._ssd_layers:
-      out["ssd_state_rows"] = self._counters["ssd_state_rows"].value
+      out.update((k, self._counters[k].value) for k in (
+          "ssd_state_rows", "ssd_narrow_rows"))
     if self._retention_layers:
       out.update((k, self._counters[k].value) for k in (
           "retention_rows", "retention_folds", "retention_chunk_tokens"))
@@ -1290,6 +1291,9 @@ class ServingLoop:
     if self._ssd_layers:
       self._counters["ssd_state_rows"].Inc(
           self._ssd_layers * int((row_len > 0).sum()))
+      # ... and those whose row is one token: the row pass's narrow body
+      self._counters["ssd_narrow_rows"].Inc(
+          self._ssd_layers * int((row_len == 1).sum()))
     if self._retention_layers:
       # power-retention layers: rows with a state, pages folded into one and
       # keys attended in open chunks, a layer (before the cursors advance)

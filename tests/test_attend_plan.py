@@ -690,14 +690,17 @@ def test_stats_count_the_page_writes_the_steps_plan_holds():
 # tests/test_head_cols' engines at their mixed step (a decode row, a finishing
 # prompt, a chunk, an empty slot; f32), lines of `lower().as_text()` and the
 # first 16 hex digits of its sha256. The test below is the recipe: run it on
-# a parent's tree to take a number again. `nemotron_h`'s is PR 57's own tree:
-# that PR changed its step on purpose (the expert layers at the decode width
+# a parent's tree to take a number again. `nemotron_h`'s is PR 58's own tree:
+# PR 57 changed its step on purpose (the expert layers at the decode width
 # under a conditional, the scan states read and written in the block's
-# stack; the parent's was 5302 lines, "0e84d3f4da43a21f").
+# stack; the parent's was 5302 lines, "0e84d3f4da43a21f"), and so did PR 58
+# (the Mamba-2 row pass is handed each row's last TOKEN and a fourth flag,
+# the XLA twin divides; PR 57's was 6106 lines, "95362a350e6f1e71"). The
+# other four are what they were: no step without a Mamba-2 layer moved.
 _PARENT_STEP = {
     "dense": (1290, "1876dbf11e99e5cf"),
     "smallthinker": (3634, "4ab22d507292c1e1"),
-    "nemotron_h": (6106, "95362a350e6f1e71"),
+    "nemotron_h": (6136, "220727d9511a9186"),
     "brumby": (1608, "137c387cefa12e2f"),
     "mistral4": (1374, "df1550569c9ec9f4"),
 }

@@ -7,6 +7,8 @@ the embedding's, the scores' and the logits' constants, a tied head, and the
 tiny registered sibling served by ServingLoop in chunks and decode steps
 through slot state and one layer's pages."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -287,9 +289,14 @@ def test_a_block_sequence_counts_the_pairs_of_its_share(tiny, served):
                    "PooledAttention": 1}
   # a live row reads and writes the state of each of the nine Mamba-2 layers
   assert stats["ssd_state_rows"] % 9 == 0 and stats["ssd_state_rows"] > 0
+  # ... and those of one token are the row pass's narrow body's: the decode
+  # rows, and never the chunks of the prompts
+  assert stats["ssd_narrow_rows"] % 9 == 0
+  assert 0 < stats["ssd_narrow_rows"] < stats["ssd_state_rows"]
   records = eng._recorder.Steps() if getattr(eng, "_recorder", None) else []
   for rec in records[-1:]:
     assert "ssd_state_rows" in rec.counters
+    assert "ssd_narrow_rows" in rec.counters
     assert "moe_pairs_elsewhere" in rec.counters
 
 
@@ -382,6 +389,14 @@ _ROWS = {
     "decode_only": ((1, 1, 1, 1, 1), (4, 0, 9, 2, 7)),
     "across_chunks": ((3, 21, 0, 9, 5), (0, 7, 1, 0, 2)),
     "long_row": ((0, 38, 0, 0, 0), (1, 0, 1, 1, 1)),
+    # one-token rows at a scan chunk's two edges: slot 1's token is the LAST
+    # of chunk 0 (index 7 of 8), slot 2's the FIRST of chunk 1
+    "decode_at_chunk_edges": ((7, 1, 1, 1, 1), (0, 3, 5, 0, 9)),
+    # the step's only chunk row (tokens 2..21) came into its last chunk from
+    # an earlier one; every other slot's hand-over index stands still
+    "one_row_came_in": ((1, 1, 20, 1, 1), (4, 2, 6, 0, 3)),
+    # slots without a token beside one-token rows (one a request's first)
+    "idle_beside_decode": ((0, 1, 0, 1, 0), (2, 5, 1, 0, 7)),
 }
 
 
@@ -425,8 +440,9 @@ def _RowPassGrids(hm, p, g, n):
     for eqn in j.eqns:
       if eqn.primitive.name == "pallas_call":
         gm = eqn.params["grid_mapping"]
-        found.append((tuple(gm.grid),
-                      tuple(gm.block_mappings[0].block_shape)))
+        found.append((tuple(gm.grid), [
+            [int(getattr(b, "block_size", b)) for b in bm.block_shape]
+            for bm in gm.block_mappings]))
       for sub in jax.core.jaxprs_in_params(eqn.params):
         _Walk(sub)
 
@@ -434,27 +450,95 @@ def _RowPassGrids(hm, p, g, n):
   return found
 
 
-def test_nemotrons_groups_keep_their_grid_and_blocks():
+@pytest.mark.parametrize("hm,g,tiles", [(64, 8, 8), (128, 1, 16)])
+def test_nemotrons_groups_keep_their_grid_and_blocks(hm, g, tiles):
   """At `nemotron3nano`'s G = 8, W = 512 a group is ONE tile: the grid is
   (slots, groups) and the state's block [1, 512, N], as before the pass
-  tiled; at Granite's G = 1 the same block walks one group's 16 tiles."""
-  (grid, block), = _RowPassGrids(64, 64, 8, 128)
-  assert grid == (5, 8) and [int(getattr(b, "block_size", b))
-                             for b in block] == [1, 512, 128]
-  (grid, block), = _RowPassGrids(128, 64, 1, 128)
-  assert grid == (5, 16) and [int(getattr(b, "block_size", b))
-                              for b in block] == [1, 512, 128]
+  tiled; at Granite's G = 1 the same block walks one group's 16 tiles.
+  Since PR 58 ONE call with two bodies: beside the chunk body's [q, .]
+  blocks the one-token body's [8, .] blocks (the token's operands in, its
+  read-out out)."""
+  (grid, blocks), = _RowPassGrids(hm, 64, g, 128)
+  assert grid == (5, tiles)
+  state, tokens, groups, heads = [1, 512, 128], [1, 8, 512], [1, 8, 128], hm
+  assert blocks == [
+      state, state,                                # the slot's, the hand-over
+      [1, 8, 128], [1, 8, heads], tokens, [1, 8, heads], [1, 8, 128],
+      [1, 8, heads], [heads, 512],                 # the chunk body's (q = 8)
+      tokens, groups,                              # the one-token body's
+      tokens, state, tokens]                       # y_rows, the state, y_tok
 
 
+@pytest.mark.parametrize("twin", ["xla", "sequential"])
 @pytest.mark.parametrize("case", sorted(_ROWS))
-def test_nemotrons_groups_read_what_the_xla_twin_reads(case):
+def test_nemotrons_groups_read_what_the_xla_twin_reads(case, twin):
   args, rows = _ScanInputs(16, 64, 2, 128), _Rows(case)   # W = 512, G = 2
   y, s = packed_ssd_scan.PackedSsdScan(*args, rows, chunk_size=8,
                                        lowering="pallas")
   want_y, want_s = packed_ssd_scan.PackedSsdScan(*args, rows, chunk_size=8,
-                                                 lowering="xla")
+                                                 lowering=twin)
   np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=3e-5)
   np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=3e-5)
+
+
+@pytest.mark.parametrize("g", [1, 2])
+@pytest.mark.parametrize("case", ["long_row", "idle_beside_decode"])
+def test_a_row_with_no_token_keeps_its_state_to_the_bit(case, g):
+  """A slot without a token in the step (not a request's first) is read and
+  written back unchanged, beside a chunk row's body and, in a step whose
+  chunk body's operands all stand still, beside one-token rows."""
+  row_len, rows = _ROWS[case][0], _Rows(case)
+  args = _ScanInputs(16, 64, g, 128)
+  _, s = packed_ssd_scan.PackedSsdScan(*args, rows, chunk_size=8,
+                                       lowering="pallas")
+  idle = [i for i, n in enumerate(row_len) if n == 0]
+  np.testing.assert_array_equal(np.asarray(s)[idle],
+                                np.asarray(args[-1])[idle])
+  busy = [i for i, n in enumerate(row_len) if n > 0]
+  assert busy and not np.array_equal(np.asarray(s)[busy],
+                                     np.asarray(args[-1])[busy])
+
+
+@pytest.mark.parametrize("case", ["decode_only", "one_row_came_in"])
+def test_each_cut_alone_is_the_kernel_with_none(case):
+  """`CUTS`, what `tools/kernel_probe.py` switches one at a time: the pass
+  with any one of them reads what the pass with none reads."""
+  args, rows = _ScanInputs(16, 64, 1, 128), _Rows(case)
+  *ops, state = args
+
+  def _Scan(cuts):
+    row_pass = functools.partial(packed_ssd_scan._PallasRowPass, g=1,
+                                 interpret=True, cuts=cuts)
+    return packed_ssd_scan._ChunkedPackedScan(*ops, state[None], rows, 8,
+                                              row_pass, 0)
+
+  want_y, want_s = _Scan(())
+  for cut in packed_ssd_scan.CUTS:
+    y, s = _Scan((cut,))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=3e-5)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=3e-5)
+
+
+def test_the_kernel_probe_builds_the_row_pass_and_runs_it(capsys):
+  """tools/kernel_probe.py --case row_pass at the CPU's rehearsal sizes:
+  its module's chunked form builds the kernel's operands, the kernel runs
+  alone (interpret mode) and every variant is held to the first."""
+  import importlib.util
+  import json
+  import os
+  spec = importlib.util.spec_from_file_location("kernel_probe", os.path.join(
+      os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools",
+      "kernel_probe.py"))
+  kernel_probe = importlib.util.module_from_spec(spec)
+  spec.loader.exec_module(kernel_probe)
+  assert kernel_probe.main(["--case", "row_pass", "--tiny", "--calls", "1",
+                            "--shapes", "granite", "--steps", "chunk",
+                            "--variants", "none,all"]) == 0
+  lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+           if l.startswith("{")]
+  assert [l["variant"] for l in lines] == ["none", "all"]
+  assert lines[1]["within_3e-5"] and lines[0]["heads"] == 16
+  assert all(l["tiny"] and l["device"]["platform"] == "cpu" for l in lines)
 
 
 @pytest.mark.parametrize("lowering", ["pallas", "xla", "sequential"])

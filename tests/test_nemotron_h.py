@@ -142,7 +142,14 @@ def _NaiveScan(x, dt, a, b, c, d, state, row_len, q_pos):
     ((0, 0, 13, 0, 0), (3, 3, 40, 3, 3)),         # one chunk, not from zero
     ((3, 21, 0, 9, 5), (0, 7, 1, 0, 2)),          # rows across scan chunks
     ((0, 38, 0, 0, 0), (1, 0, 1, 1, 1)),          # one row over five chunks
-], ids=["mixed", "decode_only", "one_chunk", "across_chunks", "long_row"])
+    # the row pass's one-token body (PR 58): a decode token that is the LAST
+    # of a scan chunk and one that is the FIRST; the only chunk row come in
+    # from an earlier chunk beside decode rows; slots without a token
+    ((7, 1, 1, 1, 1), (0, 3, 5, 0, 9)),
+    ((1, 1, 20, 1, 1), (4, 2, 6, 0, 3)),
+    ((0, 1, 0, 1, 0), (2, 5, 1, 0, 7)),
+], ids=["mixed", "decode_only", "one_chunk", "across_chunks", "long_row",
+        "decode_at_chunk_edges", "one_row_came_in", "idle_beside_decode"])
 def test_ssd_scan_on_the_packed_axis(lowering, row_len, q_pos):
   rng = np.random.RandomState(0)
   slots, hm, p, g, n, t_, w_ = 5, 4, 64, 2, 128, 40, 38

@@ -1,7 +1,10 @@
 #!/usr/bin/env python3
-"""One layer's whole-page write (ops/diff_attend.WritePages) alone, at the
-shapes `phi4flash_serve_reason` runs it at: a pool `[6561, 128, 20, 64]` bf16
-for K and one for V, a pack of 576 tokens over 64 rows with 64 pages each.
+"""ONE layer's call of a kernel alone, at a cell's shapes. Two cases.
+
+`--case page_write` (the default): the whole-page write
+(ops/diff_attend.WritePages) at the shapes `phi4flash_serve_reason` runs it
+at: a pool `[6561, 128, 20, 64]` bf16 for K and one for V, a pack of 576
+tokens over 64 rows with 64 pages each.
 
   python3 tools/kernel_probe.py [--steps decode,chunk] [--calls 50]
       [--variants scatter,kernel] [--parent DIR] [--seed 0]
@@ -24,6 +27,29 @@ pools (the plan built outside it, as a step builds it once for its nine
 owners; the new tokens behind a barrier a trip, so the preparation in front
 of the kernel is not hoisted out of the loop) over `--calls`: milliseconds a
 layer's write. Prints one JSON line a (step, variant).
+
+`--case row_pass`: the Mamba-2 scan's pass over the slots' states
+(ops/packed_ssd_scan._PallasRowPass) at the two cells that run it:
+`--shapes granite` (128 heads of 64 in ONE group: 16 tiles of 512 a slot)
+and `nemotron` (64 heads in 8 groups of 512), 64 slots, a pack of 1,088,
+chunks of 64, N = 128, the layer's states the fourth of a stack of five.
+
+  python3 tools/kernel_probe.py --case row_pass [--shapes granite,nemotron]
+      [--steps decode,chunk] [--variants none,hand_over,narrow,all]
+      [--parent DIR] [--calls 50] [--tiny]
+
+Steps: `decode` (64 one-token rows) and `chunk` (63 beside one chunk of
+1,024 that comes into its last scan chunk from an earlier one). Variants:
+`none` is the kernel with none of `packed_ssd_scan.CUTS`, each cut's name
+that cut alone (several joined by `+`), `all` the kernel as the step runs
+it, `parent` DIR's kernel.
+Every variant's scan (y and the stack of states) is held to the first's on
+the device at the tests' tolerance (3e-5). Two times a (shape, step,
+variant): `row_pass_ms`, the kernel's `jit` (what it gathers in front of the
+call included) over operands its own module's chunked form built once, and
+`scan_ms`, the layer's whole `PackedSsdScan`; both a loop of `--calls` trips
+that carries the stack. `--tiny` is the CPU's rehearsal (interpret mode, 4
+slots, heads of the tests' cases): counts, never a time.
 
 Its readings are a builder's, never the ledger's: one call in a loop has no
 neighbours to share the chip's memory system with, and no step round it.
@@ -60,11 +86,11 @@ VARIANTS["scatter"] = _Lowered("xla")
 VARIANTS["kernel"] = _Lowered("pallas")
 
 
-def _ParentModule(root):
-  """DIR/lingvo_tpu/ops/diff_attend.py under a name of its own; what it
-  imports is this tree's."""
-  path = os.path.join(root, "lingvo_tpu", "ops", "diff_attend.py")
-  spec = importlib.util.spec_from_file_location("parent_diff_attend", path)
+def _ParentModule(root, name="diff_attend"):
+  """DIR/lingvo_tpu/ops/<name>.py under a name of its own; what it imports
+  is this tree's."""
+  path = os.path.join(root, "lingvo_tpu", "ops", name + ".py")
+  spec = importlib.util.spec_from_file_location("parent_" + name, path)
   module = importlib.util.module_from_spec(spec)
   spec.loader.exec_module(module)
   return module
@@ -85,16 +111,180 @@ def StepRows(kind: str, rng, t: int, wmax: int):
   return lens, context
 
 
+# -- the row pass of the packed Mamba-2 scan -----------------------------------
+
+# heads and groups (benchmarks/configs/granite4hsmall.json, nemotron3nano.json)
+ROW_PASS_SHAPES = {"granite": (128, 1), "nemotron": (64, 8)}
+ROW_PASS_TINY = {"granite": (16, 1), "nemotron": (16, 2)}   # the tests' cases
+HEAD_CHANNELS, STATE, CHUNK, STACK, LAYER = 64, 128, 64, 5, 3
+
+
+def RowPassInputs(shape: str, step: str, seed: int, tiny: bool):
+  """(x, dt, a, b, c, d_skip, stack of states, rows, chunk) of one layer's
+  scan in a `step` step at `shape`'s heads and groups, all seeded."""
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+  from lingvo_tpu.core import ragged as ragged_lib
+  hm, g = (ROW_PASS_TINY if tiny else ROW_PASS_SHAPES)[shape]
+  slots, budget, q = (4, 24, 8) if tiny else (ROWS, 1024, CHUNK)
+  t = slots + budget
+  rng = np.random.RandomState(seed)
+  lens = np.ones(slots, np.int64)
+  context = rng.randint(1, 4096, size=slots)
+  if step == "chunk":
+    lens[slots // 2], context[slots // 2] = budget, 2 * budget
+  else:
+    assert step == "decode", step
+  rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in
+                                 ragged_lib.BuildRaggedRows(
+                                     lens, context, t, budget + 1)))
+  f32 = lambda v: jnp.asarray(v, jnp.float32)
+  dt = f32(rng.uniform(0.001, 0.5, (t, hm)))
+  x, b, c = (f32(rng.randn(t, *k)) for k in (
+      (hm, HEAD_CHANNELS), (g, STATE), (g, STATE)))
+  a, d_skip = -f32(rng.uniform(1, 16, hm)), f32(rng.randn(hm))
+  stack = jax.random.normal(jax.random.PRNGKey(seed), (
+      STACK, slots, hm, HEAD_CHANNELS, STATE), jnp.float32)
+  return (x, dt, a, b, c, d_skip, stack, rows), q
+
+
+def RowPassVariant(module, cuts, interpret: bool):
+  """-> (operands(inputs..., q): what `module`'s chunked form hands its row
+  pass; kernel(operands): the pass's `jit` -> the stack; scan(inputs..., q):
+  the layer's whole scan -> (y, stack)), the kernel with `cuts` (None: the
+  module's own, a parent's that knows none)."""
+  import functools
+  import jax
+  kw = {} if cuts is None else {"cuts": tuple(cuts)}
+
+  def _Pass(g):
+    return functools.partial(module._PallasRowPass, g=g, interpret=interpret,
+                             **kw)
+
+  def _Operands(x, dt, a, b, c, d_skip, stack, rows, q):
+    seen = []
+
+    def _Spy(*operands):
+      seen.append(operands)
+      return module._XlaRowPass(*operands, g=b.shape[1])
+
+    module._ChunkedPackedScan(x, dt, a, b, c, d_skip, stack, rows, q, _Spy,
+                              LAYER)
+    return seen[0]
+
+  def _Kernel(operands, g):
+    return _Pass(g)(*operands)[-1]
+
+  def _Scan(x, dt, a, b, c, d_skip, stack, rows, q):
+    with jax.named_scope("ssd_scan"):
+      return module._ChunkedPackedScan(x, dt, a, b, c, d_skip, stack, rows,
+                                       q, _Pass(b.shape[1]), LAYER)
+
+  return _Operands, _Kernel, _Scan
+
+
+def RowPassMain(args) -> int:
+  import jax
+  import jax.numpy as jnp
+  from lingvo_tpu.core import compile_cache
+  from lingvo_tpu.ops import packed_ssd_scan
+
+  compile_cache.Configure()
+  on_tpu = jax.default_backend() == "tpu"
+  assert on_tpu or args.tiny, "a time comes from the chip; --tiny rehearses"
+  # a variant is its cuts, joined by "+"
+  variants = {"none": (packed_ssd_scan, ()),
+              "all": (packed_ssd_scan, packed_ssd_scan.CUTS)}
+  if args.parent:
+    variants["parent"] = (_ParentModule(args.parent, "packed_ssd_scan"), None)
+  names = args.variants.split(",") + (["parent"] if args.parent else [])
+
+  def _MsACall(body, carry, rest):
+    """body(carry, rest) -> carry, `--calls` trips in one program; the rest
+    behind a barrier with the trip's index: what a call prepares in front
+    of its kernel is the trip's, not the loop's."""
+    def _Run(carry, *rest):
+      def _Trip(i, carry):
+        _, held = jax.lax.optimization_barrier((i, rest))
+        return body(carry, held)
+      return jax.lax.fori_loop(0, args.calls, _Trip, carry)
+
+    loop = jax.jit(_Run, donate_argnums=0)
+    carry = jax.block_until_ready(loop(carry, *rest))           # compiles
+    start = time.perf_counter()
+    jax.block_until_ready(loop(carry, *rest))
+    return (time.perf_counter() - start) * 1e3 / args.calls
+
+  device = jax.devices()[0]
+  lines = []
+  for shape in args.shapes.split(","):
+    for step in args.steps.split(","):
+      (*ops, stack, rows), q = RowPassInputs(shape, step, args.seed,
+                                             args.tiny)
+      g = ops[3].shape[1]
+      first = None
+      for name in names:
+        module, cuts = variants.get(name) or (packed_ssd_scan,
+                                              name.split("+"))
+        operands, kernel, scan = RowPassVariant(module, cuts, not on_tpu)
+        y, new = jax.jit(scan, static_argnums=8)(*ops, stack + 0.0, rows, q)
+        if first is None:
+          first, err = (y, new), None
+        else:
+          err = float(max(jnp.max(jnp.abs(got - want))
+                          for got, want in zip((y, new), first)))
+        del y, new
+        state, *given = jax.jit(operands, static_argnums=8)(*ops, stack, rows,
+                                                            q)
+        pass_ms = _MsACall(
+            lambda state, held: kernel((state, *held), g), state, given)
+        del state, given
+        scan_ms = _MsACall(
+            lambda stack, held: scan(*held[:-1], stack, held[-1], q)[1],
+            stack + 0.0, (*ops, rows))
+        lines.append({
+            "case": "row_pass", "shape": shape, "step": step,
+            "variant": name, "row_pass_ms": pass_ms, "scan_ms": scan_ms,
+            "max_abs_err_to_first": err,
+            "within_3e-5": None if err is None else err <= 3e-5,
+            "heads": int(ops[0].shape[1]), "groups": int(g),
+            "slots": int(stack.shape[1]), "tokens": int(ops[0].shape[0]),
+            "calls": args.calls, "seed": args.seed, "tiny": args.tiny,
+            "device": {"platform": device.platform,
+                       "kind": device.device_kind}})
+        print(json.dumps(lines[-1]), flush=True)
+      del first
+  _Append(args.out, lines)
+  return 0 if all(l["within_3e-5"] is not False for l in lines) else 1
+
+
+def _Append(path, lines):
+  if path:
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    with open(path, "a") as f:
+      for line in lines:
+        f.write(json.dumps(line) + "\n")
+
+
 def main(argv=None) -> int:
   ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+  ap.add_argument("--case", default="page_write",
+                  choices=["page_write", "row_pass"])
   ap.add_argument("--steps", default="decode,chunk")
-  ap.add_argument("--variants", default="scatter,kernel")
+  ap.add_argument("--variants", default="")
+  ap.add_argument("--shapes", default="granite,nemotron")
+  ap.add_argument("--tiny", action="store_true")
   ap.add_argument("--parent", default="")
   ap.add_argument("--calls", type=int, default=50)
   ap.add_argument("--seed", type=int, default=0)
   ap.add_argument("--pool_pages", type=int, default=6561)
   ap.add_argument("--out", default="")
   args = ap.parse_args(argv)
+  if args.case == "row_pass":
+    args.variants = args.variants or "none,hand_over,narrow,all"
+    return RowPassMain(args)
+  args.variants = args.variants or "scatter,kernel"
 
   import jax
   import jax.numpy as jnp
@@ -175,11 +365,7 @@ def main(argv=None) -> int:
           "device": {"platform": device.platform, "kind": device.device_kind}})
       print(json.dumps(lines[-1]), flush=True)
     del first
-  if args.out:
-    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-    with open(args.out, "a") as f:
-      for line in lines:
-        f.write(json.dumps(line) + "\n")
+  _Append(args.out, lines)
   return 0 if all(l["bitwise_the_first"] is not False for l in lines) else 1
 
 
