@@ -11,8 +11,9 @@ The in-process pillars (ISSUE 12), one import:
   trace-event JSON export (Perfetto-openable; one row per decode slot).
 - `ProfileWindow` / `CompileLog` (observe/profile.py): on-demand
   jax.profiler trace windows (no-op when unsupported) and one-shot
-  per-compiled-program records (compile wall time, XLA memory plan,
-  donation set).
+  per-compiled-program records (compile wall time and what it was made
+  of, XLA memory plan, donation set); `Startup()`, the process's record of
+  its own set-up: phases, named programs, every compile event JAX reports.
 
 And the fleet-facing layer (ISSUE 13) on top:
 
@@ -40,7 +41,8 @@ from lingvo_tpu.observe.goodput import (  # noqa: F401
 from lingvo_tpu.observe.metrics import (  # noqa: F401
     DEFAULT_BOUNDS, Default, HistogramQuantiles, MetricsRegistry)
 from lingvo_tpu.observe.profile import (  # noqa: F401
-    CompileInfo, CompileLog, ProfileWindow, ProfilerSupported)
+    CompileInfo, CompileLog, ProfileWindow, ProfilerSupported, Startup,
+    StartupRecord)
 from lingvo_tpu.observe.schema import Scope  # noqa: F401
 from lingvo_tpu.observe.trace import (  # noqa: F401
     RequestTrace, TraceRecorder)

@@ -35,6 +35,7 @@ from typing import Optional
 
 import jax
 
+from lingvo_tpu.observe import profile as profile_lib
 from lingvo_tpu.observe import schema
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
@@ -254,6 +255,7 @@ class StatusServer:
         "describe": (self._registry.Describe()
                      if self._registry is not None else {}),
         "stats": self._statusz_fn() if self._statusz_fn is not None else None,
+        "startup": profile_lib.Startup().Document(),
     }
     if self._watchdog is not None:
       doc["watchdog"] = self._watchdog.Stats()

@@ -83,15 +83,32 @@ class GoodputTracker:
   """
 
   def __init__(self, registry=None, clock=time.perf_counter,
-               section: str = "goodput"):
+               section: str = "goodput", compile_record=None):
     self._clock = clock
     self._lock = threading.Lock()
     self._t0 = clock()
     self._buckets = {b: 0.0 for b in schema.GOODPUT_BUCKETS if b != "other"}
     # compile seconds by the thread that compiled (CompileSeconds)
     self._compile_by_thread: dict[int, float] = {}
+    # the record whose compile events (self seconds, so a nested event
+    # counts once) are this tracker's `compile` bucket beside what callers
+    # Add: observe.profile's start-up record for the process's tracker
+    # (`Get()`), none for a tracker of a test's own. The marks are the
+    # record's seconds at the last Reset.
+    self._record = compile_record
+    self._MarkRecord()
     if registry is not None:
       registry.SectionFn(section, self.Stats)
+
+  def _MarkRecord(self):
+    rec = self._record
+    self._record_mark = rec.TotalCompileSeconds() if rec else 0.0
+    self._record_thread_marks = rec.CompileSecondsByThread() if rec else {}
+
+  def _RecordSeconds(self) -> float:
+    if self._record is None:
+      return 0.0
+    return max(self._record.TotalCompileSeconds() - self._record_mark, 0.0)
 
   def Add(self, bucket: str, seconds: float):
     assert bucket in self._buckets, (
@@ -115,14 +132,20 @@ class GoodputTracker:
     if thread is None:
       thread = threading.get_ident()
     with self._lock:
-      return self._compile_by_thread.get(thread, 0.0)
+      added = self._compile_by_thread.get(thread, 0.0)
+      if self._record is None:
+        return added
+      return added + max(self._record.CompileSeconds(thread)
+                         - self._record_thread_marks.get(thread, 0.0), 0.0)
 
   def Snapshot(self) -> dict:
     """Raw bucket totals {bucket: seconds} at this instant — a cheap
     before/after basis for windowed deltas (bench sections, tests)
     without the wall/residual derivation Stats() adds."""
     with self._lock:
-      return dict(self._buckets)
+      out = dict(self._buckets)
+    out["compile"] += self._RecordSeconds()
+    return out
 
   @contextlib.contextmanager
   def Track(self, bucket: str):
@@ -135,7 +158,7 @@ class GoodputTracker:
 
   @contextlib.contextmanager
   def TrackExcludingCompile(self, bucket: str):
-    """Like Track, minus any compile seconds the jax.monitoring listener
+    """Like Track, minus any compile seconds the start-up record's listener
     attributed to this thread during the block — lazy jit compiles inside
     a step/eval window must not be double-counted as productive (or eval)
     time."""
@@ -154,16 +177,18 @@ class GoodputTracker:
       for b in self._buckets:
         self._buckets[b] = 0.0
       self._compile_by_thread.clear()
+      self._MarkRecord()
 
   def Stats(self) -> dict:
     """`goodput/*` section: per-bucket seconds + wall + productive ratio.
     `other_s` is the residual (clamped at 0), so the buckets sum to wall —
-    up to the slight compile-event overcount noted above."""
+    up to what a side thread compiled beside a step (noted below)."""
+    buckets = self.Snapshot()
     with self._lock:
       wall = max(self._clock() - self._t0, 0.0)
-      out = {f"{b}_s": round(v, 6) for b, v in self._buckets.items()}
-      accounted = sum(self._buckets.values())
-      productive = sum(self._buckets[b] for b in schema.GOODPUT_PRODUCTIVE)
+      out = {f"{b}_s": round(v, 6) for b, v in buckets.items()}
+      accounted = sum(buckets.values())
+      productive = sum(buckets[b] for b in schema.GOODPUT_PRODUCTIVE)
     out["other_s"] = round(max(wall - accounted, 0.0), 6)
     out["wall_s"] = round(wall, 6)
     out["productive_ratio"] = round(productive / wall, 6) if wall else 0.0
@@ -174,22 +199,14 @@ class GoodputTracker:
 _GET_LOCK = threading.Lock()
 _TRACKER: GoodputTracker | None = None
 
-# duration events covering the whole compile pipeline: jaxpr trace,
-# MLIR lowering, XLA backend compile — they fire on every cache miss,
-# AOT or lazy, so the listener sees each compile exactly once. Inner-jit
-# trace/lowering events nest inside the outer jit's, so the compile
-# bucket can overcount by the nested fraction (<1% in practice), and by
-# what a side thread compiles beside a running step: the buckets sum to
-# ~wall, not exactly wall.
-_COMPILE_EVENT_PREFIX = "/jax/core/compile/"
-
-
-def _OnJaxEvent(event: str, duration_s: float, **_):
-  """jax.monitoring duration listener feeding the global tracker. This is
-  how lazily-jitted programs (no AOT CompileLog) still land their compile
-  wall in the compile bucket instead of hiding inside a step window."""
-  if event.startswith(_COMPILE_EVENT_PREFIX) and _TRACKER is not None:
-    _TRACKER.Add("compile", duration_s)
+# The compile bucket of the process's tracker is fed by observe.profile's
+# start-up record, the program's one jax.monitoring listener: every trace,
+# lowering and backend compile (or cache fetch), AOT or lazy, with an event
+# nested in another of its thread counted for its self time only. What a
+# side thread compiles beside a running step still counts in full, so the
+# buckets sum to ~wall, not exactly wall. This is how lazily-jitted programs
+# (no AOT CompileLog) land their compile wall in the compile bucket instead
+# of hiding inside a step window.
 
 
 def Get() -> GoodputTracker:
@@ -198,11 +215,9 @@ def Get() -> GoodputTracker:
   with _GET_LOCK:
     if _TRACKER is None:
       from lingvo_tpu.observe import metrics as metrics_lib
-      _TRACKER = GoodputTracker(registry=metrics_lib.Default())
-      try:
-        jax.monitoring.register_event_duration_secs_listener(_OnJaxEvent)
-      except Exception:  # noqa: BLE001 - accounting must never break jax
-        pass
+      from lingvo_tpu.observe import profile as profile_lib
+      _TRACKER = GoodputTracker(registry=metrics_lib.Default(),
+                                compile_record=profile_lib.Startup())
     return _TRACKER
 
 

@@ -303,7 +303,10 @@ ENDPOINT_PATHS = ("/metrics", "/statusz", "/traces", "/healthz")
 # records) or None; `build` is BuildInfo() below.
 STATUSZ_REQUIRED = frozenset({"name", "build", "snapshot", "describe",
                               "stats"})
-STATUSZ_OPTIONAL = frozenset({"watchdog"})
+# `startup`: the process's start-up record (observe.profile.Startup().
+# Document(): set-up phases, one row a named program with what its compile
+# was made of, the compile events under no named program by fun_name)
+STATUSZ_OPTIONAL = frozenset({"watchdog", "startup"})
 
 # observe/export.py BuildInfo() — the jax/config facts /statusz carries.
 BUILD_INFO_KEYS = frozenset({

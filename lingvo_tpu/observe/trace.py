@@ -85,10 +85,10 @@ class StepTrace:
   seconds throughout)."""
 
   __slots__ = ("step", "start_ts", "loop_s", "segments_s", "valid_tokens",
-               "prefill_tokens", "rows", "counters")
+               "prefill_tokens", "rows", "counters", "compile_s")
 
   def __init__(self, step, start_ts, loop_s, segments_s, valid_tokens,
-               prefill_tokens, rows, counters=None):
+               prefill_tokens, rows, counters=None, compile_s=0.0):
     self.step = step
     self.start_ts = start_ts
     self.loop_s = loop_s
@@ -98,8 +98,15 @@ class StepTrace:
     self.rows = rows
     # {name: value so far} of the engine's cumulative counters that a
     # reader wants between two steps (expert load, window pages), as they
-    # stood when the step's record closed; None where the engine has none
+    # stood when the step's record closed; None where the engine has none.
+    # A step that compiled carries the programs' names beside them, under
+    # `compile_fun_names`
     self.counters = counters
+    # seconds of compile events (observe.profile's start-up record: trace,
+    # lowering, backend compile or cache fetch, self time) that ended on the
+    # engine's thread while this record was open; 0.0 for a step that found
+    # its programs built
+    self.compile_s = compile_s
 
   @property
   def span_s(self) -> float:
@@ -121,7 +128,10 @@ class StepTrace:
     return {"step": self.step, "start_s": self.start_ts,
             "span_s": self.span_s, "loop_s": self.loop_s,
             "phases_s": self.Phases(), "valid_tokens": self.valid_tokens,
-            "prefill_tokens": self.prefill_tokens, "rows": self.rows}
+            "prefill_tokens": self.prefill_tokens, "rows": self.rows,
+            "compile_s": self.compile_s,
+            "compile_fun_names": (self.counters or {}).get(
+                "compile_fun_names", [])}
 
 
 class RequestTrace:
@@ -311,13 +321,14 @@ class TraceRecorder:
 
   def StepDone(self, step: int, start_ts: float, loop_s: float, segments_s,
                valid_tokens: int = 0, prefill_tokens: int = 0,
-               rows: int = 0, counters=None):
+               rows: int = 0, counters=None, compile_s: float = 0.0):
     """Records one engine step: the one call a step costs. segments_s: the
     seconds spent in each of STEP_SEGMENTS, which tile the step from
-    start_ts on; loop_s: from the previous step's end to start_ts."""
+    start_ts on; loop_s: from the previous step's end to start_ts;
+    compile_s: StepTrace.compile_s."""
     global _last_stepped
     rec = StepTrace(step, start_ts, loop_s, tuple(segments_s), valid_tokens,
-                    prefill_tokens, rows, counters)
+                    prefill_tokens, rows, counters, compile_s)
     assert len(rec.segments_s) == len(STEP_SEGMENTS), rec.segments_s
     with self._lock:
       self._steps.append(rec)
@@ -434,7 +445,8 @@ class TraceRecorder:
                  "ts": self._Us(st.start_ts), **row,
                  "args": {"valid_tokens": st.valid_tokens,
                           "prefill_tokens": st.prefill_tokens,
-                          "rows": st.rows, "loop_ms": st.loop_s * 1e3}})
+                          "rows": st.rows, "loop_ms": st.loop_s * 1e3,
+                          "compile_ms": st.compile_s * 1e3}})
       t = st.start_ts
       for name, dur in zip(STEP_SEGMENTS, st.segments_s):
         if dur > 0:

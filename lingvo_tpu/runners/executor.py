@@ -18,6 +18,7 @@ import jax
 
 from lingvo_tpu import observe
 from lingvo_tpu.observe import goodput as goodput_lib
+from lingvo_tpu.observe import profile as profile_lib
 from lingvo_tpu.core import checkpointer as checkpointer_lib
 from lingvo_tpu.core import py_utils
 from lingvo_tpu.core.nested_map import NestedMap
@@ -25,6 +26,7 @@ from lingvo_tpu.core.nested_map import NestedMap
 
 class ExecutorTpu:
 
+  @profile_lib.InPhase("build")
   def __init__(self, model_params, logdir: str, schedule=None, task=None,
                init_seed: int = 1234, precompile: bool = False,
                max_train_retries: int = 3, mlperf_benchmark: str = "",
@@ -228,7 +230,31 @@ class ExecutorTpu:
     `max_train_retries` consecutive failures; anything else (compile errors,
     OOM, shape bugs) is fatal immediately.
     """
-    state = self._PlaceState(self._CreateTrainState())
+    # the start-up record's `build` goes on to the programs' Compile():
+    # the train state (`train_state`), then the restore and the warm start
+    with profile_lib.Startup().Phase("build"):
+      state, start_step = self._BuildState()
+    if self._precompile and self._schedule is not None:
+      for prog in self._schedule.programs:
+        prog.Compile(state)
+
+    if self._mlperf is not None:
+      self._mlperf.Print(self._mllog.INIT_STOP)
+      self._mlperf.Print(self._mllog.RUN_START)
+    try:
+      return self._MainLoop(state, start_step)
+    except BaseException:
+      if self._mlperf is not None:
+        self._mlperf.Print(self._mllog.RUN_STOP,
+                           metadata={"status": "aborted"})
+        self._mlperf.Close()
+      raise
+
+  def _BuildState(self) -> tuple[NestedMap, int]:
+    """The train state as the main loop starts from it: created and placed,
+    restored from the newest checkpoint, or warm-started on a fresh run."""
+    with profile_lib.Startup().Phase("train_state"):
+      state = self._PlaceState(self._CreateTrainState())
     # 'no checkpoint at all' (fresh run) is distinct from 'restored the
     # step-0 checkpoint' — warm start must apply only to the former
     fresh_run = self._checkpointer.LatestStep() is None
@@ -245,21 +271,7 @@ class ExecutorTpu:
         state = checkpointer_lib.ImportNpzCheckpoint(
             state, npz,
             getattr(self._task.p.train, "init_from_npz_rules", None))
-    if self._precompile and self._schedule is not None:
-      for prog in self._schedule.programs:
-        prog.Compile(state)
-
-    if self._mlperf is not None:
-      self._mlperf.Print(self._mllog.INIT_STOP)
-      self._mlperf.Print(self._mllog.RUN_START)
-    try:
-      return self._MainLoop(state, start_step)
-    except BaseException:
-      if self._mlperf is not None:
-        self._mlperf.Print(self._mllog.RUN_STOP,
-                           metadata={"status": "aborted"})
-        self._mlperf.Close()
-      raise
+    return state, start_step
 
   def _SchedulePrograms(self):
     return list(getattr(self._schedule, "programs", None) or [])

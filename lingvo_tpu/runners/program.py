@@ -11,6 +11,7 @@ weighted metric accumulators (ref `TpuEvalMetrics`). `SimpleProgramSchedule`
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import json
 import os
@@ -24,11 +25,21 @@ import numpy as np
 
 from lingvo_tpu import observe
 from lingvo_tpu.observe import goodput as goodput_lib
+from lingvo_tpu.observe import profile as profile_lib
 from lingvo_tpu.core import base_layer
 from lingvo_tpu.core import hyperparams
 from lingvo_tpu.core import metrics as metrics_lib
 from lingvo_tpu.core import py_utils
 from lingvo_tpu.core.nested_map import NestedMap
+
+
+# Set-up under the program's own names (observe.profile's start-up record,
+# each phase a `lingvo/setup/<phase>` span too): `build`, a program's
+# construction (and `infeed`, the producer's start, where it runs);
+# `compile_step`, Compile(), which holds the named programs `step` / `loop`;
+# and, in the record only, `first_steps`: from Compile()'s return (the first
+# Run's start where none came first) to the first loop's completion.
+_STARTUP = profile_lib.Startup()
 
 
 def _StateDonation() -> tuple:
@@ -95,6 +106,7 @@ class BaseProgram:
              "fallback).")
     return p
 
+  @profile_lib.InPhase("build")
   def __init__(self, params, task=None, input_generator=None):
     self.p = params.Copy()
     self._task = task if task is not None else params.task.Instantiate()
@@ -138,6 +150,12 @@ class BaseProgram:
     self._pipe_wait_mark = 0.0
     self._pipe_compile_mark = 0.0
     self._pipe_thread: int | None = None   # the thread that dispatches
+    # the start-up record's `first_steps` phase (TrainProgram): None until
+    # Compile() returns or the first Run starts, open until the first
+    # loop's completion, False from then on
+    self._first_steps = None
+    self._run_unit = None   # the Run open on the dispatching thread
+    self._named_unbuilt: set = set()   # _Unbuilt's labels, each given once
     from lingvo_tpu.core import summary_utils
     self._tb = summary_utils.SummaryWriter(
         self._program_dir, enabled=self.p.write_tensorboard)
@@ -206,6 +224,7 @@ class BaseProgram:
       return mesh_lib.MeshContext(self.p.mesh)
     return contextlib.nullcontext()
 
+  @profile_lib.InPhase("compile_step")
   def Compile(self, state: NestedMap) -> None:
     """Ahead-of-time compile with a real batch (ref Compile:355)."""
     batch = self._PutBatch(self.input_generator.GetPreprocessedInputBatch())
@@ -220,13 +239,14 @@ class BaseProgram:
     pillar 3: per-compiled-program records for train/eval programs).
     Dispatch behavior is unchanged: like the previous Compile(), the
     executable is discarded and Run keeps calling the jit wrapper."""
-    t0 = time.perf_counter()
-    # exclude the listener-attributed backend-compile seconds so the AOT
-    # window's remainder (lowering glue) is all that lands here extra
-    with self._goodput.TrackExcludingCompile("compile"):
+    # the record is the named program's row in the start-up record, which
+    # stamps `compile_wall_s` and what it was made of. Exclude the
+    # listener-attributed compile seconds so the AOT window's remainder
+    # (lowering glue) is all that lands here extra
+    rec = {"name": name}
+    with self._goodput.TrackExcludingCompile("compile"), \
+        _STARTUP.Program(self._ProgramLabel(name), rec):
       compiled = fn.lower(*args).compile()
-    rec = {"name": name,
-           "compile_wall_s": round(time.perf_counter() - t0, 6)}
     rec.update(observe.CompileInfo(compiled))
     from lingvo_tpu.core import computation_cost
     try:
@@ -248,6 +268,12 @@ class BaseProgram:
   def _OnCompileRecord(self, name: str, rec: dict) -> None:
     """Subclass hook after every AOT compile record (TrainProgram uses it
     to derive flops/step and publish `train/mfu`)."""
+
+  def _ProgramLabel(self, name: str, what: str = "compile") -> str:
+    """The named program `name` ('step', 'loop') as the start-up record
+    labels it: `<program>/compile/<name>`, as the registry's gauges
+    (`<program>/flops/<name>`: TrainProgram._Unbuilt)."""
+    return f"{self.p.name or type(self).__name__}/{what}/{name}"
 
   def _GetStepFn(self, state: NestedMap | None = None):
     raise NotImplementedError
@@ -500,8 +526,16 @@ class TrainProgram(BaseProgram):
     return self._step_fn
 
   def Compile(self, state: NestedMap) -> None:
-    if not self.p.on_device_loop:
-      return super().Compile(state)
+    try:
+      if self.p.on_device_loop:
+        self._CompileLoop(state)
+      else:
+        super().Compile(state)
+    finally:
+      self._OpenFirstSteps()
+
+  @profile_lib.InPhase("compile_step")
+  def _CompileLoop(self, state: NestedMap) -> None:
     # shapes only: tile ONE batch rather than consuming steps_per_loop
     # real batches from a possibly-finite stream
     batch = self.input_generator.GetPreprocessedInputBatch()
@@ -597,11 +631,12 @@ class TrainProgram(BaseProgram):
       from lingvo_tpu.runners import infeed as infeed_lib
       p = self.p
       place = self._PutStackedBatch if p.on_device_loop else self._PutBatch
-      self._infeed = infeed_lib.DeviceInfeed(
-          self._MakeTrainIter, place_fn=place, depth=p.infeed_depth,
-          place_in_producer=self._PlaceInProducer(),
-          name=f"{p.name or 'train'}-infeed",
-          stream_key=id(self.input_generator), registry=self.metrics)
+      with _STARTUP.Phase("infeed"):
+        self._infeed = infeed_lib.DeviceInfeed(
+            self._MakeTrainIter, place_fn=place, depth=p.infeed_depth,
+            place_in_producer=self._PlaceInProducer(),
+            name=f"{p.name or 'train'}-infeed",
+            stream_key=id(self.input_generator), registry=self.metrics)
     return self._infeed
 
   def _GetTelemetry(self):
@@ -634,7 +669,8 @@ class TrainProgram(BaseProgram):
     if self._flops_per_step is not None or not hasattr(fn, "lower"):
       return
     try:
-      cost = fn.lower(*args).cost_analysis()
+      with self._Unbuilt("flops"):
+        cost = fn.lower(*args).cost_analysis()
       if isinstance(cost, (list, tuple)):
         cost = cost[0]
       flops = float((cost or {}).get("flops", 0.0))
@@ -682,16 +718,54 @@ class TrainProgram(BaseProgram):
         self._step_fn = None
       self._host_sched_key = key
 
+  def _Unbuilt(self, what: str):
+    """Round the first piece of work on a program that no Compile() built:
+    `compile`, the first dispatch of the step or loop function, which
+    traces, lowers and compiles (or fetches) it there; `flops`, the lowering
+    _MaybePublishMfu counts its operations from. Its compile events fall
+    under `<program>/<what>/<step|loop>` in the start-up record, and no
+    other program's do (batch placement, the infeed's jits, eager ops)."""
+    name = "loop" if self.p.on_device_loop else "step"
+    label = self._ProgramLabel(name, what)
+    if name in self.compile_records or label in self._named_unbuilt:
+      return contextlib.nullcontext()
+    self._named_unbuilt.add(label)
+    return _STARTUP.Program(label)
+
+  def _OpenFirstSteps(self) -> None:
+    if self._first_steps is None:
+      self._first_steps = _STARTUP.OpenPhase("first_steps")
+
+  def _NoteLoopDone(self, unit, result: dict) -> None:
+    """A loop's completion: what compiled on the dispatching thread while
+    its Run was open goes into its result beside `host_overhead_s` (0.0 for
+    a loop that found its programs built; the programs' names where not),
+    and the loop into the start-up record, whose `first_steps` the first
+    completion ends."""
+    unit.done = time.perf_counter()
+    result["compile_s"] = round(unit.compile_s, 6)
+    if unit.compile_s:
+      result["compile_fun_names"] = list(unit.fun_names)
+    _STARTUP.LoopDone(unit)
+    if self._first_steps:
+      self._first_steps.Close(unit.done)
+      self._first_steps = False
+
   def Run(self, state: NestedMap) -> tuple[NestedMap, dict[str, float]]:
     self._RefreshHostSchedules()
     self._loops_run += 1
+    self._OpenFirstSteps()
+    unit = self._run_unit = _STARTUP.OpenUnit("loop")
     # spans: jax.profiler.TraceAnnotation, on the host plane of a profiler
     # trace beside the device ops; a flag test when no trace is running
-    with jax.profiler.TraceAnnotation("lingvo/train/loop",
-                                      loop=self._loops_run):
-      if not self.p.async_infeed:
-        return self._RunSync(state)
-      return self._RunAsync(state)
+    try:
+      with jax.profiler.TraceAnnotation("lingvo/train/loop",
+                                        loop=self._loops_run):
+        if not self.p.async_infeed:
+          return self._RunSync(state)
+        return self._RunAsync(state)
+    finally:
+      _STARTUP.CloseUnit(unit)
 
   def _RunSync(self, state: NestedMap) -> tuple[NestedMap, dict[str, float]]:
     """The legacy fully-synchronous loop (p.async_infeed = False): host
@@ -715,7 +789,8 @@ class TrainProgram(BaseProgram):
       fn = self._GetLoopFn(state)
       self._MaybePublishMfu(fn, state, stacked, steps=p.steps_per_loop)
       with self._MeshScope(), self._ProfilerScope():
-        with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+        with jax.profiler.TraceAnnotation("lingvo/train/dispatch"), \
+            self._Unbuilt("compile"):
           state, acc, stats_acc = fn(state, stacked)
         with jax.profiler.TraceAnnotation("lingvo/train/backpressure"):
           jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
@@ -733,7 +808,8 @@ class TrainProgram(BaseProgram):
             batch = self._PutBatch(batch)
           infeed_wait_s += time.perf_counter() - t_in
           self._MaybePublishMfu(fn, state, batch)
-          with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+          with jax.profiler.TraceAnnotation("lingvo/train/dispatch"), \
+              self._Unbuilt("compile"):
             state, out = fn(state, batch)
           with jax.profiler.TraceAnnotation("lingvo/train/accumulate"):
             acc = metrics_lib.AccumulateMetrics(acc, out.metrics)
@@ -763,6 +839,7 @@ class TrainProgram(BaseProgram):
       result["infeed_wait_s"] = round(infeed_wait_s, 6)
       result["host_overhead_s"] = round(
           infeed_wait_s + (time.perf_counter() - t_tel), 6)
+      self._NoteLoopDone(self._run_unit, result)
       for k, v in self._InputStatsOf(self.input_generator).items():
         result[f"input_{k}"] = v
       # smoothed cross-Run rate incl. eval gaps (ref StepRateTracker:393)
@@ -809,7 +886,8 @@ class TrainProgram(BaseProgram):
       fn = self._GetLoopFn(state)
       self._MaybePublishMfu(fn, state, stacked, steps=p.steps_per_loop)
       with self._MeshScope(), self._ProfilerScope():
-        with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+        with jax.profiler.TraceAnnotation("lingvo/train/dispatch"), \
+            self._Unbuilt("compile"):
           state, acc, stats_acc = fn(state, stacked)
         if self._profiling_run:
           # opt-in diagnostics: keep the device work inside the trace
@@ -828,7 +906,8 @@ class TrainProgram(BaseProgram):
             with jax.profiler.TraceAnnotation("lingvo/train/put_batch"):
               batch = self._PutBatch(batch)
           self._MaybePublishMfu(fn, state, batch)
-          with jax.profiler.TraceAnnotation("lingvo/train/dispatch"):
+          with jax.profiler.TraceAnnotation("lingvo/train/dispatch"), \
+              self._Unbuilt("compile"):
             state, out = fn(state, batch)
           with jax.profiler.TraceAnnotation("lingvo/train/accumulate"):
             acc = metrics_lib.AccumulateMetrics(acc, out.metrics)
@@ -859,7 +938,7 @@ class TrainProgram(BaseProgram):
     job = functools.partial(
         self._FinalizeLoop, step_val, acc, stats_acc, t0,
         host_overhead_s, infeed_wait_s, queue_depth, input_stats,
-        pipelined=pipelined)
+        pipelined=pipelined, unit=self._run_unit)
     if not p.defer_telemetry:
       result = job()[1]
       self._AttributeRunWall(t0, infeed_wait_s)
@@ -920,7 +999,7 @@ class TrainProgram(BaseProgram):
 
   def _FinalizeLoop(self, step_val, acc, stats_acc, t_start,
                     host_overhead_s, infeed_wait_s, queue_depth,
-                    input_stats, pipelined: bool = False,
+                    input_stats, pipelined: bool = False, unit=None,
                     ) -> tuple[int, dict[str, float]]:
     """Telemetry-worker job: device_get of one loop's metrics + summary
     write. The np.asarray inside FinalizeMetrics synchronizes on the loop's
@@ -944,6 +1023,8 @@ class TrainProgram(BaseProgram):
           p.steps_per_loop * self.input_generator.GlobalBatchSize() / wall)
       result["infeed_wait_s"] = round(infeed_wait_s, 6)
       result["host_overhead_s"] = round(host_overhead_s, 6)
+      if unit is not None:
+        self._NoteLoopDone(unit, result)
       result["infeed_queue_depth"] = queue_depth
       for k, v in input_stats.items():
         result[f"input_{k}"] = v

@@ -96,6 +96,7 @@ import numpy as np
 from lingvo_tpu import observe
 from lingvo_tpu.core import ragged as ragged_lib
 from lingvo_tpu.core import sampling
+from lingvo_tpu.observe import profile as observe_profile
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as observe_trace
 from lingvo_tpu.ops import diff_attend
@@ -138,6 +139,14 @@ _SEGMENT_SPANS = {
     "device_wait": "lingvo/serve/device_wait",
     "commit": "lingvo/serve/commit"}
 _SEGMENTS = observe_trace.STEP_SEGMENTS
+# Set-up has spans of its own, `lingvo/setup/<phase>` (observe.profile's
+# start-up record keeps each as an entry too): `build`, the constructor, with
+# `int8_theta`, `states` (InitPagedDecodeState) and `layout`
+# (state_layout.Detect) inside it where they run; `compile_step`,
+# _CompileStep; and, in the record only, `first_steps`: from Start()'s
+# return (or the first StepOnce of a caller who drives the loop) to the
+# commit of the first step that emitted a token.
+_STARTUP = observe_profile.Startup()
 
 
 class _StepSpans:
@@ -155,8 +164,10 @@ class _StepSpans:
     self._step_ann = None
     self._seg_ann = None
     self._acc = None
+    self._unit = None         # what compiled while the step's record is open
 
   def Begin(self):
+    self._unit = _STARTUP.OpenUnit("step")
     self._step_ann = jax.profiler.TraceAnnotation(_STEP_SPAN)
     self._t0 = self._t_seg = self._clock()
     self._acc = [0.0] * len(_SEGMENTS)
@@ -182,6 +193,7 @@ class _StepSpans:
       self._step_ann.set_metadata(**metadata)
     self._step_ann.__exit__(None, None, None)
     self._step_ann = self._seg_ann = None
+    _STARTUP.CloseUnit(self._unit)
     return now
 
   def Abandon(self):
@@ -197,9 +209,14 @@ class _StepSpans:
                       prefill_tokens=prefill_tokens, rows=rows)
     loop_s = self._t0 - self._last_end if self._last_end is not None else 0.0
     self._last_end = now
+    unit = self._unit
+    if unit.compile_s:
+      # a step that compiled says what: a late compile is a stall with a name
+      counters = dict(counters or {}, compile_fun_names=list(unit.fun_names))
     if self._recorder is not None:
       self._recorder.StepDone(step, self._t0, loop_s, self._acc,
-                              valid_tokens, prefill_tokens, rows, counters)
+                              valid_tokens, prefill_tokens, rows, counters,
+                              compile_s=unit.compile_s)
 
 
 def _MoeCountLeaves(states, name="routed", width=None):
@@ -279,6 +296,7 @@ class StreamHandle:
 class ServingLoop:
   """Continuous-batching decode service over a block-table page pool."""
 
+  @observe_profile.InPhase("build")
   def __init__(self, task, theta, *, page_size: int, num_pages: int,
                max_batch: int, max_seq_len: int, prefill_chunk: int = 8,
                default_max_new: int = 32, eos_id: Optional[int] = None,
@@ -355,7 +373,8 @@ class ServingLoop:
     self._task = task
     self.serve_int8_weights = bool(serve_int8_weights)
     if serve_int8_weights:
-      theta, _ = quant_weights.Int8ServingTheta(theta)
+      with _STARTUP.Phase("int8_theta"):
+        theta, _ = quant_weights.Int8ServingTheta(theta)
     self._theta = theta
     self.page_size = page_size
     self.num_pages = num_pages
@@ -464,8 +483,9 @@ class ServingLoop:
     # num_slots sizes the per-slot O(1) mixer states (attention ignores it);
     # the kv dtype override is a static string arg (hashable)
     init_fn = jax.jit(task.InitPagedDecodeState, static_argnums=(1, 2, 3, 4))
-    self._states = init_fn(theta, self.alloc.num_pages + 1, page_size,
-                           max_batch, kv_cache_dtype)
+    with _STARTUP.Phase("states"):
+      self._states = init_fn(theta, self.alloc.num_pages + 1, page_size,
+                             max_batch, kv_cache_dtype)
     # donate the pool into each step off-cpu (XLA:CPU can't alias + warns)
     donate = (1,) if jax.default_backend() != "cpu" else ()
     # observability (observe/): per-engine metrics registry, per-request
@@ -620,6 +640,10 @@ class ServingLoop:
     self._work = threading.Condition(self._lock)
     self._thread: Optional[threading.Thread] = None
     self._running = False
+    # the start-up record's `first_steps` phase: None until the loop is
+    # started or first stepped, open until a step's commit emits a token,
+    # False from then on
+    self._first_steps = None
     self._cancel_open = False   # Stop(drain=False) asked; _CancelOpen answers
     self._seq_counter = 0
     self._adopt_counter = 0   # transient page-handoff allocation owners
@@ -645,9 +669,10 @@ class ServingLoop:
     """The decode state's layout (serving/state_layout.py), detected the
     first time something moves state by page, by token or by slot."""
     if self._layout is None:
-      self._layout = state_layout.Detect(
-          self._task, self._theta, self.num_pages + 1, self.page_size,
-          self.max_batch, self._kv_override)
+      with _STARTUP.Phase("layout"):
+        self._layout = state_layout.Detect(
+            self._task, self._theta, self.num_pages + 1, self.page_size,
+            self.max_batch, self._kv_override)
     return self._layout
 
   def _ClassifyPath(self) -> str:
@@ -1010,6 +1035,7 @@ class ServingLoop:
 
   # -- async API -------------------------------------------------------------
 
+  @observe_profile.InPhase("compile_step")
   def _CompileStep(self):
     """Builds THE step program here, in the thread that starts the engine,
     from an idle step's arguments placed as _Dispatch places a real one's
@@ -1043,6 +1069,8 @@ class ServingLoop:
       if self._running:
         return self
       self._CompileStep()
+      if self._first_steps is None:
+        self._first_steps = _STARTUP.OpenPhase("first_steps")
       self._running = True
       self._thread = threading.Thread(target=self._Loop, daemon=True,
                                       name="serving-loop")
@@ -1213,6 +1241,8 @@ class ServingLoop:
     one, which is the serial order. An iteration that finds nothing to
     build retires what is in flight."""
     spans = self._spans
+    if self._first_steps is None:
+      self._first_steps = _STARTUP.OpenPhase("first_steps")
     spans.Begin()
     try:
       spans.To("lock_wait")
@@ -1457,6 +1487,9 @@ class ServingLoop:
       self._PushEvents(events)
       self._TickProfile()
       self._BeatWatchdog()
+    if events and self._first_steps:
+      self._first_steps.Close()
+      self._first_steps = False
     return events
 
   def _CancelOpen(self):

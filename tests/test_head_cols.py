@@ -294,7 +294,10 @@ def test_plain_step_program_holds_no_packed_logits():
   # a constant of the step program: a gauge, not a column of every record
   assert eng.metrics.Snapshot()["serving/head_rows"] == b
   records = eng.trace.Steps()
-  assert records and all(r.counters is None for r in records)
+  # (a step that compiled carries its programs' names there, nothing else)
+  assert records and all(
+      set(r.counters or ()) <= {"compile_fun_names"} for r in records)
+  assert all((r.counters is None) == (r.compile_s == 0.0) for r in records)
 
 
 def test_a_stand_in_for_one_step_hands_back_every_columns_argmax(tiny_lm):

@@ -300,17 +300,39 @@ class TestGoodput:
       gp.Add("compile", 4.0)
     assert gp.Stats()["eval_s"] == 0.0
 
-  def test_jax_compile_listener_feeds_global_tracker(self):
-    saved = goodput_lib._TRACKER
-    gp = goodput_lib.GoodputTracker(clock=_FakeClock())
-    goodput_lib._TRACKER = gp
-    try:
-      goodput_lib._OnJaxEvent(
-          "/jax/core/compile/backend_compile_duration", 2.5)
-      goodput_lib._OnJaxEvent("/jax/core/something_else", 9.0)
-      assert gp.Stats()["compile_s"] == pytest.approx(2.5)
-    finally:
-      goodput_lib._TRACKER = saved
+  def test_the_startup_records_listener_feeds_the_tracker(self):
+    """The compile bucket reads observe.profile's start-up record (the one
+    listener): self seconds, so an event nested in another counts once, by
+    the thread that compiled, from the tracker's last Reset on."""
+    from lingvo_tpu.observe import profile as profile_lib
+    clock = _FakeClock(100.0)
+    rec = profile_lib.StartupRecord(clock=clock)
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    backend = "/jax/core/compile/backend_compile_duration"
+    rec.EventBegins(backend)
+    clock.t += 1.0
+    rec.EventEnds(backend, "before")            # before the tracker: not its
+    gp = goodput_lib.GoodputTracker(clock=clock, compile_record=rec)
+    rec.EventBegins(trace)
+    clock.t += 1.0
+    rec.EventBegins(trace)                      # an inner jit, nested
+    clock.t += 0.5
+    rec.EventEnds(trace, "inner")
+    clock.t += 1.0
+    rec.EventEnds(trace, "outer")               # 2.5 s in all, not 3.0
+    rec.EventBegins(backend, thread=7)
+    clock.t += 2.0
+    rec.EventEnds(backend, "elsewhere", thread=7)
+    assert gp.Stats()["compile_s"] == pytest.approx(4.5)
+    assert gp.CompileSeconds() == pytest.approx(2.5)
+    assert gp.CompileSeconds(7) == pytest.approx(2.0)
+    gp.Add("compile", 0.25)                     # a caller's own still adds
+    assert gp.Stats()["compile_s"] == pytest.approx(4.75)
+    gp.Reset()
+    assert gp.Stats()["compile_s"] == 0.0 and gp.CompileSeconds(7) == 0.0
+    # the process's tracker reads the process's record
+    assert goodput_lib.Get()._record is profile_lib.Startup()
+    assert not hasattr(goodput_lib, "_OnJaxEvent")
 
   def test_peak_flops_lookup(self):
     assert goodput_lib.PeakFlopsPerDevice("TPU v4") == 275e12

@@ -13,7 +13,8 @@ key (ignored by trace viewers) and prints:
 - where the file holds `perStep` (the engine's step records): the phase
   table of the engine loop, median and p95 of each `lingvo/serve/*`
   phase, of `loop`, and of the host's time between one step's results and
-  the next step's launch.
+  the next step's launch; and the stalled steps, each with the seconds
+  that compiled inside it and the programs' names (`compile_s`).
 
 With MULTIPLE trace files (one per serving replica) it prints a merged
 per-replica latency table instead — one row per file plus a fleet row
@@ -93,6 +94,10 @@ def Summary(trace: dict) -> dict:
 _HOST_HEAD = ("lock_wait", "admit", "build", "draft", "h2d", "dispatch")
 
 
+_STALL_FACTOR = 2.0     # a period over this many median periods is a stall
+_STALLS_KEPT = 20       # the longest of them are listed
+
+
 def StepSummary(trace: dict) -> dict:
   """Median and p95 (ms) over the file's step records: the step span, the
   loop's turn-around, each phase, and `host`: commit of step n, loop, and
@@ -130,7 +135,34 @@ def _StepTable(trace: dict) -> list:
   rows.append(("host n->n+1", s["host_ms"]))
   for name, p in rows:
     lines.append(f"  {name:<12} {p['p50']:>10.3f} {p['p95']:>10.3f}")
+  stalled = StalledSteps(trace)
+  if stalled:
+    lines += ["", f"stalled steps (period over {_STALL_FACTOR:g} medians, or "
+              "a compile):",
+              f"  {'step':>8} {'period_ms':>10} {'compile_ms':>10}  compiled"]
+    for r in stalled:
+      lines.append(f"  {r['step']:>8} {r['period_ms']:>10.3f} "
+                   f"{r['compile_ms']:>10.3f}  "
+                   f"{', '.join(r['compile_fun_names']) or '-'}")
   return lines
+
+
+def StalledSteps(trace: dict) -> list:
+  """The steps whose period (loop + span) is over _STALL_FACTOR median
+  periods, and every step that compiled (`compile_s`: a late compile is a
+  stall with a name, any other a stop of the machine or the host), the
+  _STALLS_KEPT longest, longest first. A file from before the key reads 0."""
+  steps = trace.get("perStep") or []
+  if not steps:
+    return []
+  periods = [s["loop_s"] + s["span_s"] for s in steps]
+  limit = _STALL_FACTOR * float(np.median(periods))
+  rows = [{"step": s["step"], "period_ms": p * 1e3,
+           "compile_ms": s.get("compile_s", 0.0) * 1e3,
+           "compile_fun_names": s.get("compile_fun_names", [])}
+          for s, p in zip(steps, periods)
+          if p > limit or s.get("compile_s", 0.0) > 0]
+  return sorted(rows, key=lambda r: -r["period_ms"])[:_STALLS_KEPT]
 
 
 def _Ms(v) -> str:
