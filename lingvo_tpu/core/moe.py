@@ -19,7 +19,8 @@ there is no exchange to shape the dispatch for, so this layer sorts instead:
             'reglu': three, (relu(x W_gate) * (x W_up)) W_down; 'swiglu':
             three, (silu(x W_gate) * (x W_up)) W_down; 'relu2': two,
             relu(x W_up)^2 W_down, and no W_gate exists;
-  combine   each row weighted, unsorted, and a token's k rows summed;
+  combine   the rows un-sorted by ONE gather in the matmuls' dtype, `k` the
+            major axis `[k, T, D]`; weighted and a token's k summed in f32;
   shared    where `shared_hidden_dim` > 0, one more expert of that width
             that every token goes through, added to the routed sum.
 
@@ -245,8 +246,7 @@ class DroplessMoELayer(base_layer.BaseLayer):
     [layer * Eh, (layer + 1) * Eh) of the stack seen as one run of groups."""
     p = self.p
     th = self.CastTheta(theta)
-    t, d = x.shape
-    e, k = self.num_held, p.num_experts_per_token
+    t, e, k = x.shape[0], self.num_held, p.num_experts_per_token
     with observe.Scope("moe_route"):
       top_idx, weights = self._Route(th, logits)                   # [T, k]
       if e != p.num_experts:
@@ -279,12 +279,12 @@ class DroplessMoELayer(base_layer.BaseLayer):
         h = jnp.square(jax.nn.relu(GroupedMatmul(xs, flat(th.w_up), sizes)))
       ys = GroupedMatmul(h.astype(xs.dtype), flat(th.w_down), sizes)
     with observe.Scope("moe_combine"):
-      w_sorted = weights.reshape(-1)[order]
-      live = jnp.arange(t * k) < jnp.sum(counts)
-      ys = jnp.where(live[:, None], ys.astype(jnp.float32)
-                     * w_sorted[:, None], 0.0)
-      # unsort by a gather through the inverse permutation
-      out = ys[jnp.argsort(order)].reshape(t, k, d).sum(axis=1)
+      # each pair's place in sorted order, k MAJOR: ONE gather of the matmul's
+      # rows; row AND weight masked by value (either may be NaN: 0 x NaN)
+      live = (top_idx < e).T[..., None]                          # [k, T, 1]
+      pos = jnp.argsort(order).reshape(t, k).T                      # [k, T]
+      ys = jnp.where(live, ys[pos], 0).astype(jnp.float32)       # [k, T, D]
+      out = jnp.sum(ys * jnp.where(live, weights.T[..., None], 0.0), axis=0)
     if p.shared_hidden_dim:
       with observe.Scope("moe_shared"):
         up = jnp.einsum("td,df->tf", x, th.w_shared_up)

@@ -468,7 +468,9 @@ DEVICE_SCOPES = {
     "moe_experts": ("ffn", "the grouped matmuls, three of a gated expert or two "
                     "of an ungated one (megablox names its "
                     "kernels `gmm` inside it)"),
-    "moe_combine": ("ffn", "weighting, un-sort and sum of a token's experts"),
+    "moe_combine": ("ffn", "un-sort of the experts' rows by one gather in "
+                    "their own dtype, k major; then the weighting and the "
+                    "sum of a token's experts in f32"),
     "moe_shared": ("ffn", "the shared expert every token goes through, "
                    "beside the routed ones"),
     "layer_scan": (None, "a scan over stacked layers, less what its layers "

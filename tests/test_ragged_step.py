@@ -709,12 +709,14 @@ def test_a_whole_sequence_forward_traces_no_conditional(family):
 # of PR 51 (`6246f70`) traces it under JAX 0.9.0: equations, sub-jaxprs
 # included, and the first 16 hex digits of the sha256 of the jaxpr's text.
 # The test below is the recipe: run it on a parent's tree to take a number
-# again.
+# again. The two families with an expert layer were taken again at PR 60,
+# whose combine (core/moe.py: one gather, k major) `FProp` shares with the
+# step: 716 / "de7d6e176f8724fe" and 795 / "a25d30d52123d314" before it.
 _PARENT_FORWARD = {
     "dense": (453, "0127a45b397c9af1"),
-    "smallthinker": (716, "de7d6e176f8724fe"),
+    "smallthinker": (712, "f54d05066bc821bb"),
     "phi4flash": (831, "e7e44788e2b4d76b"),
-    "nemotron_h": (795, "a25d30d52123d314"),
+    "nemotron_h": (792, "a0148a3ba89aa8e0"),
     "brumby": (153, "c3fea7069c9406d6"),
 }
 

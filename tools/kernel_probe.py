@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""ONE layer's call of a kernel alone, at a cell's shapes. Two cases.
+"""ONE layer's call of a kernel alone, at a cell's shapes. Three cases.
 
 `--case page_write` (the default): the whole-page write
 (ops/diff_attend.WritePages) at the shapes `phi4flash_serve_reason` runs it
@@ -50,6 +50,37 @@ call included) over operands its own module's chunked form built once, and
 `scan_ms`, the layer's whole `PackedSsdScan`; both a loop of `--calls` trips
 that carries the stack. `--tiny` is the CPU's rehearsal (interpret mode, 4
 slots, heads of the tests' cases): counts, never a time.
+
+`--case moe_combine`: ONE expert layer's combine (core/moe.py, the block
+under the scope `moe_combine`) at the four cells that run it, a full step of
+1,088 tokens: `--shapes granite` (k 10, D 4096, 36 of 72 experts held),
+`smallthinker` (6, 2560, all 64), `nemotron` (6, 2688, all 128), `mistral`
+(4, 4096, 32 of 128).
+
+  python3 tools/kernel_probe.py --case moe_combine
+      [--shapes granite,smallthinker,nemotron,mistral]
+      [--variants parent,argsort,scatter] [--calls 50] [--seed 0] [--tiny]
+
+The operands are what the layer's grouped matmul hands its combine: random
+bf16 `ys [T * k, D]`, NaN in every row past the last run; the permutation
+`order` a stable sort by expert of seeded choices gives (a pair of an expert
+held elsewhere carries the index E_held and sorts last); seeded weights
+`[T, k]` f32. Variants, each `fn(ys, order, top_idx, weights, e) -> f32
+[T, D]`: `parent` is the form PR 60 replaced, kept HERE as the reference
+(f32 product over the sorted rows, an f32 gather through a second argsort, a
+reshape to `[T, k, D]`, the sum over axis 1); `argsort` the layer's form (one
+gather of the bf16 rows into `[k, T, D]`, then weight, mask and sum over axis
+0 in f32) and `scatter` the same with the inverse permutation from
+`zeros.at[order].set(arange)`; a form under trial is registered in
+`COMBINE_VARIANTS`. Every variant is held to the first within the f32 sum's
+reordering (1e-5 of the largest magnitude). The time is a loop of `--calls`
+trips, each over one of FOUR layers' operands by the trip's index (nothing is
+the loop's to hoist, and four layers' rows do not fit the chip's 128 MiB of
+VMEM; XLA still stages a layer's `ys` there where the program holds nothing
+else, which a step does not promise), the result cast to bf16 as the layer's
+is; `gb_s` is the 276 MB form's bytes (the bf16 rows read and written by the
+gather, read by the fused pass, `[T, D]` bf16 written) over that time,
+whatever the variant moves. `--tiny` rehearses on the CPU.
 
 Its readings are a builder's, never the ledger's: one call in a loop has no
 neighbours to share the chip's memory system with, and no step round it.
@@ -259,6 +290,129 @@ def RowPassMain(args) -> int:
   return 0 if all(l["within_3e-5"] is not False for l in lines) else 1
 
 
+# -- the expert layer's combine ------------------------------------------------
+
+# k, D, experts held, experts routed over (benchmarks/configs/<cell>.json)
+COMBINE_SHAPES = {"granite": (10, 4096, 36, 72),
+                  "smallthinker": (6, 2560, 64, 64),
+                  "nemotron": (6, 2688, 128, 128),
+                  "mistral": (4, 4096, 32, 128)}
+COMBINE_TOKENS = 1088
+
+
+def _CombineParent(ys, order, top_idx, weights, e):
+  """The combine as it stood before PR 60: the reference."""
+  import jax.numpy as jnp
+  t, k = top_idx.shape
+  w_sorted = weights.reshape(-1)[order]
+  live = jnp.arange(t * k) < jnp.sum(top_idx < e)
+  ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_sorted[:, None],
+                 0.0)
+  return ys[jnp.argsort(order)].reshape(t, k, -1).sum(axis=1)
+
+
+def _CombineKMajor(scatter: bool):
+  """The layer's form; the pairs' places in sorted order by a second argsort
+  (the layer's) or, `scatter`, by `zeros.at[order].set(arange)`."""
+  def _Combine(ys, order, top_idx, weights, e):
+    import jax.numpy as jnp
+    t, k = top_idx.shape
+    pos = (jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=order.dtype)) if scatter
+           else jnp.argsort(order)).reshape(t, k).T
+    live = (top_idx < e).T[..., None]
+    ys = jnp.where(live, ys[pos], 0).astype(jnp.float32)
+    return jnp.sum(ys * jnp.where(live, weights.T[..., None], 0.0), axis=0)
+  return _Combine
+
+
+COMBINE_VARIANTS = {"parent": _CombineParent, "argsort": _CombineKMajor(False),
+                    "scatter": _CombineKMajor(True)}
+
+
+COMBINE_LAYERS = 4   # a trip reads one of as many layers' operands, by its
+#                      index: four layers' rows are more than the chip's VMEM
+
+
+def CombineInputs(shape: str, seed: int, tiny: bool):
+  """(ys bf16 [L, T * k, D], order [L, T * k], top_idx [L, T, k], weights f32
+  [L, T, k], experts held) of COMBINE_LAYERS layers' combines at `shape`,
+  seeded; a layer's last 64 tokens padding, as a step's that is not full."""
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+  k, d, held, routed = COMBINE_SHAPES[shape]
+  t = 64 if tiny else COMBINE_TOKENS
+  d = 256 if tiny else d
+  rng = np.random.RandomState(seed)
+  top_idx = np.argsort(rng.rand(COMBINE_LAYERS, t, routed), axis=-1)[..., :k]
+  top_idx = np.where(top_idx < held, top_idx, held)             # k distinct
+  top_idx[:, t - t // 17:] = held
+  flat = top_idx.reshape(COMBINE_LAYERS, -1)
+  order = np.argsort(flat, axis=-1, kind="stable")
+  live = np.arange(t * k) < (flat < held).sum(-1, keepdims=True)
+  ys = jnp.where(live[..., None], jax.random.normal(
+      jax.random.PRNGKey(seed), (COMBINE_LAYERS, t * k, d), jnp.bfloat16),
+                 jnp.nan)
+  weights = rng.dirichlet(np.ones(k), size=(COMBINE_LAYERS, t))
+  return (ys, jnp.asarray(order, jnp.int32), jnp.asarray(top_idx, jnp.int32),
+          jnp.asarray(weights, jnp.float32), held)
+
+
+def CombineMain(args) -> int:
+  import jax
+  import jax.numpy as jnp
+  from lingvo_tpu.core import compile_cache
+
+  compile_cache.Configure()
+  assert jax.default_backend() == "tpu" or args.tiny, (
+      "a time comes from the chip; --tiny rehearses")
+  device = jax.devices()[0]
+  lines = []
+  for shape in args.shapes.split(","):
+    *ops, e = CombineInputs(shape, args.seed, args.tiny)
+    (_, t, k), d = ops[2].shape, ops[0].shape[-1]
+    form_bytes = 3 * t * k * d * 2 + t * d * 2
+    first = None
+    for name in args.variants.split(","):
+      fn = COMBINE_VARIANTS[name]
+      out = jax.jit(lambda *ops, fn=fn: fn(*(a[0] for a in ops), e))(*ops)
+      if first is None:
+        first, err = out, None
+      else:
+        err = float(jnp.max(jnp.abs(out - first)) / jnp.max(jnp.abs(first)))
+      finite = bool(jnp.all(jnp.isfinite(out)))
+
+      def _Run(carry, *ops, fn=fn):
+        def _Trip(i, carry):
+          # the trip's layer by its index: nothing is the loop's to hoist
+          return fn(*(jax.lax.dynamic_index_in_dim(
+              a, i % COMBINE_LAYERS, keepdims=False) for a in ops),
+                    e).astype(carry.dtype)
+        return jax.lax.fori_loop(0, args.calls, _Trip, carry)
+
+      loop = jax.jit(_Run, donate_argnums=0)
+      carry = jax.block_until_ready(
+          loop(jnp.zeros((t, d), jnp.bfloat16), *ops))           # compiles
+      start = time.perf_counter()
+      jax.block_until_ready(loop(carry, *ops))
+      ms = (time.perf_counter() - start) * 1e3 / args.calls
+      lines.append({
+          "case": "moe_combine", "shape": shape, "variant": name,
+          "ms_a_layer": ms, "gb_s": form_bytes / ms / 1e6,
+          "form_mb": form_bytes / 1e6, "rel_err_to_first": err,
+          "within_1e-5": None if err is None else err <= 1e-5,
+          "finite": finite, "tokens": t, "k": k, "d": d, "held": e,
+          "live_pairs": int(jnp.sum(ops[2][0] < e)), "calls": args.calls,
+          "seed": args.seed, "tiny": args.tiny,
+          "device": {"platform": device.platform,
+                     "kind": device.device_kind}})
+      print(json.dumps(lines[-1]), flush=True)
+  _Append(args.out, lines)
+  return 0 if all(l["within_1e-5"] is not False and l["finite"]
+                  for l in lines) else 1
+
+
 def _Append(path, lines):
   if path:
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -270,10 +424,10 @@ def _Append(path, lines):
 def main(argv=None) -> int:
   ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
   ap.add_argument("--case", default="page_write",
-                  choices=["page_write", "row_pass"])
+                  choices=["page_write", "row_pass", "moe_combine"])
   ap.add_argument("--steps", default="decode,chunk")
   ap.add_argument("--variants", default="")
-  ap.add_argument("--shapes", default="granite,nemotron")
+  ap.add_argument("--shapes", default="")
   ap.add_argument("--tiny", action="store_true")
   ap.add_argument("--parent", default="")
   ap.add_argument("--calls", type=int, default=50)
@@ -281,7 +435,12 @@ def main(argv=None) -> int:
   ap.add_argument("--pool_pages", type=int, default=6561)
   ap.add_argument("--out", default="")
   args = ap.parse_args(argv)
+  if args.case == "moe_combine":
+    args.shapes = args.shapes or ",".join(COMBINE_SHAPES)
+    args.variants = args.variants or ",".join(COMBINE_VARIANTS)
+    return CombineMain(args)
   if args.case == "row_pass":
+    args.shapes = args.shapes or "granite,nemotron"
     args.variants = args.variants or "none,hand_over,narrow,all"
     return RowPassMain(args)
   args.variants = args.variants or "scatter,kernel"
