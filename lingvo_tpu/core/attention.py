@@ -263,6 +263,16 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     p.Define("rel_pos_emb_dim", 0,
              "If >0, learned relative position bias buckets (T5-style).")
     p.Define("rel_pos_max_distance", 128, "Relative bucket clip distance.")
+    p.Define("qk_norm_epsilon", None,
+             "If set, q and k each go through a layers.RmsNorm over a head's "
+             "dims at this epsilon (children `q_norm`, `k_norm`: a learned "
+             "scale of dim_per_head each, shared by the heads), BEFORE any "
+             "rotation. None: no norm, no variable and no op.")
+    p.Define("output_gate", False,
+             "The attend's output times sigmoid(x W_gate), W_gate [D, N, H] "
+             "of its own (a bias as use_bias says), elementwise over the "
+             "heads' dims, BEFORE the output projection. False: no gate, no "
+             "variable and no op.")
     p.Define("qdomain_weight", None,
              "QDomain params for the q/k/v/post projection weights (ref "
              "batch_major_attention.py:303 TrackQWeight).")
@@ -285,9 +295,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     assert p.window >= 0, p.window
     sd = p.source_dim or d
     wsdm = p.weight_split_dims_mapping  # e.g. (None, 'model', None)
-    for name, in_dim, heads in (("query", d, n),
-                                ("key", sd, self._num_kv_heads),
-                                ("value", sd, self._num_kv_heads)):
+    for name, in_dim, heads in (
+        ("query", d, n), ("key", sd, self._num_kv_heads),
+        ("value", sd, self._num_kv_heads)) + (("gate", d, n),) * bool(
+            p.output_gate):
       self.CreateVariable(
           f"w_{name}",
           WeightParams((in_dim, heads, h), p.params_init, p.dtype,
@@ -303,6 +314,10 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if p.use_bias:
       self.CreateVariable(
           "b_post", WeightParams((d,), WeightInit.Constant(0.0), p.dtype))
+    if p.qk_norm_epsilon is not None:
+      for name in ("q_norm", "k_norm"):
+        self.CreateChild(name, layers_lib.RmsNorm.Params().Set(
+            input_dim=h, epsilon=p.qk_norm_epsilon))
     if p.enable_per_dim_scale:
       self.CreateChild("per_dim_scale",
                        PerDimScaleLayer.Params().Set(dim=h))
@@ -374,6 +389,25 @@ class MultiHeadedAttention(base_layer.BaseLayer):
           self.ChildTheta(theta, "per_dim_scale"), q)
     return q * (1.0 / math.sqrt(self._dim_per_head))
 
+  def _QkNorm(self, theta, q, k):
+    """q [.., N, H], k [.., Nkv, H] as projected -> each normed over H
+    (`qk_norm_epsilon`), in the dtype they came in; before the rotation."""
+    if self.p.qk_norm_epsilon is None:
+      return q, k
+    with observe.Scope("qk_norm"):
+      return (self.q_norm.FProp(self.ChildTheta(theta, "q_norm"), q),
+              self.k_norm.FProp(self.ChildTheta(theta, "k_norm"), k))
+
+  def _Gated(self, theta, x, ctx):
+    """ctx [B, T, N, H], the attend's output for the layer's input x
+    [B, T, D] -> ctx * sigmoid(x W_gate) (`output_gate`): what the output
+    projection reads."""
+    if not self.p.output_gate:
+      return ctx
+    with observe.Scope("atten_gate"):
+      gate = self._HeadsProj(theta, "gate", x)
+      return ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
+
   @property
   def kv_group(self) -> int:
     """Query heads a KV head serves (1: plain multi-head attention)."""
@@ -391,6 +425,13 @@ class MultiHeadedAttention(base_layer.BaseLayer):
           f"a layer with num_kv_heads={self._num_kv_heads} of "
           f"{self.p.num_heads} heads or window={self.p.window} is served "
           "by RaggedStep (ServingLoop) and trained by FProp")
+
+  def _RequireNoNormNoGate(self, method: str):
+    """The head norm and the output gate are built where a model that has
+    them runs: FProp, the dense decode contracts and the serving step."""
+    if self.p.qk_norm_epsilon is not None or self.p.output_gate:
+      raise NotImplementedError(
+          f"{method} has no head norm (qk_norm_epsilon) and no output_gate")
 
   def _RelPosBias(self, theta, t: int, s: int):
     p = self.p
@@ -462,6 +503,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       q = self._HeadsProj(theta, "query", query_vec)
       k = self._HeadsProj(theta, "key", key_vec)
       v = self._HeadsProj(theta, "value", value_vec)
+    q, k = self._QkNorm(theta, q, k)
     with observe.Scope("rope"):
       if self.p.use_rotary_position_emb:
         rt = self.ChildTheta(theta, "rotary")
@@ -487,6 +529,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         # downstream consumer mixing across time without re-masking would
         # see different numerics depending on the engaged path. Zero them.
         ctx = py_utils.ApplyPadding(paddings, ctx)
+      ctx = self._Gated(theta, query_vec, ctx)
       with observe.Scope("out_proj"):
         return self._PostProj(theta, ctx), None
     mask = atten_mask
@@ -507,6 +550,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       mask = sm if mask is None else mask + sm
     ctx, probs = self._Atten(theta, q, self._RepeatKv(k), self._RepeatKv(v),
                              mask)
+    ctx = self._Gated(theta, query_vec, ctx)
     with observe.Scope("out_proj"):
       return self._PostProj(theta, ctx), probs
 
@@ -549,6 +593,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         [cached_states.value, v_new.astype(cached_states.value.dtype)],
         axis=1)
     pad_cat = jnp.concatenate([cached_states.paddings, paddings], axis=1)
+    self._RequireNoNormNoGate("StreamStep")
     if p.use_rotary_position_emb:
       rt = self.ChildTheta(theta, "rotary")
       s = ctx_len + c
@@ -630,6 +675,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     q = self._HeadsProj(theta, "query", query_vec)
     k_new = self._HeadsProj(theta, "key", query_vec)
     v_new = self._HeadsProj(theta, "value", query_vec)
+    q, k_new = self._QkNorm(theta, q, k_new)
     if self.p.use_rotary_position_emb:
       rt = self.ChildTheta(theta, "rotary")
       pos = t.astype(jnp.float32)[None, None]
@@ -677,6 +723,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if quantized:
       new_states.key_scale = key_scale
       new_states.value_scale = value_scale
+    ctx = self._Gated(theta, query_vec, ctx)
     return self._PostProj(theta, ctx), new_states
 
   def Prefill(self, theta, query_vec, cached_states: NestedMap,
@@ -704,6 +751,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     q = self._HeadsProj(theta, "query", query_vec)
     k_new = self._HeadsProj(theta, "key", query_vec)
     v_new = self._HeadsProj(theta, "value", query_vec)
+    q, k_new = self._QkNorm(theta, q, k_new)
     if self.p.use_rotary_position_emb:
       rt = self.ChildTheta(theta, "rotary")
       pos = (t + jnp.arange(c, dtype=jnp.int32)).astype(jnp.float32)[None, :]
@@ -741,6 +789,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     if quantized:
       new_states.key_scale = key_scale
       new_states.value_scale = value_scale
+    ctx = self._Gated(theta, query_vec, ctx)
     return self._PostProj(theta, ctx), new_states
 
   # -- block-table paged decode (serving engine) -----------------------------
@@ -882,6 +931,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     k_new = self._HeadsProj(theta, "key", query_vec)
     v_new = self._HeadsProj(theta, "value", query_vec)
     pos_i = q_pos[:, None] + jnp.arange(c, dtype=jnp.int32)[None]  # [B, C]
+    q, k_new = self._QkNorm(theta, q, k_new)
     if p.use_rotary_position_emb:
       rt = self.ChildTheta(theta, "rotary")
       pos = pos_i.astype(jnp.float32)
@@ -945,6 +995,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       slot = jnp.arange(t_pages * page_size)[None, None, None, :]
       mask = jnp.where(slot <= pos_i[:, None, :, None], 0.0, _NEG_INF)
       ctx, _ = self._Atten(theta, q, k_dense, v_dense, mask)
+    ctx = self._Gated(theta, query_vec, ctx)
     return self._PostProj(theta, ctx), new_states
 
   def RaggedStep(self, theta, query_vec, cached_states: NestedMap,
@@ -1031,6 +1082,7 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       q = self._HeadsProj(theta, "query", query_vec)               # [1,T,N,H]
       k_new = self._HeadsProj(theta, "key", query_vec)
       v_new = self._HeadsProj(theta, "value", query_vec)
+    q, k_new = self._QkNorm(theta, q, k_new)
     with observe.Scope("rope"):
       if p.use_rotary_position_emb:
         rt = self.ChildTheta(theta, "rotary")
@@ -1114,7 +1166,9 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       ctx, _ = self._Atten(theta, q[0][:, None], k_dense[row],
                            v_dense[row], mask)
       ctx = ctx[:, 0][None]
-    return ctx, new_states
+    # the gate's product runs over the whole pack beside the other
+    # projections (`relaid_weights`), so the gated context is what leaves
+    return self._Gated(theta, query_vec, ctx), new_states
 
 
 class LocalSelfAttention(MultiHeadedAttention):
@@ -1161,6 +1215,7 @@ class LocalSelfAttention(MultiHeadedAttention):
       raise NotImplementedError(
           "LocalSelfAttention cannot apply a dense [T, T] atten_mask to its "
           "windowed logits; use segment_ids (packed inputs) or paddings.")
+    self._RequireNoNormNoGate("LocalSelfAttention")
     b, t, d = query_vec.shape
     w = p.block_size
     num_blocks = -(-t // w)
@@ -1261,6 +1316,7 @@ class ChunkwiseSelfAttention(MultiHeadedAttention):
       raise NotImplementedError(
           "ChunkwiseSelfAttention cannot apply a dense [T, T] atten_mask to "
           "its chunked logits; use segment_ids (packed inputs) or paddings.")
+    self._RequireNoNormNoGate("ChunkwiseSelfAttention")
     b, t, d = query_vec.shape
     c = p.chunk_size
     num_chunks = -(-t // c)
@@ -1317,7 +1373,9 @@ class PooledAttention(MultiHeadedAttention):
   num_kv_heads, H]) through its own block table, and the step's RaggedPlan
   carries its query-block descriptors, as for DifferentialAttention. It
   speaks the mixer contract (transformer.SharedStateLayer), causal, with no
-  state of a slot's own."""
+  state of a slot's own. Window, rotation, head norm and output gate are the
+  base class's Params: `models/lm/layers.py` builds it over everything with
+  no position ('gqa') or within a window and rotated ('gqa_window')."""
 
   # what BlockSequence asks a mixer: it projects and caches K and V in pages
   # of its own table, through the base class's own `kv_write` (the step's

@@ -136,6 +136,9 @@ class DroplessMoELayer(base_layer.BaseLayer):
     p.Define("residual_scale", 1.0,
              "Factor on the layer's output (routed and shared) before it is "
              "added to its input (1: none, and no op).")
+    p.Define("post_norm_tpl", None,
+             "A norm on the layer's output (routed and shared), before the "
+             "residual add; None: none, no variable and no op.")
     p.Define("router_reads", "layer_input",
              "'layer_input': the logits are handed in (RouterLogits of the "
              "transformer layer's un-normed input). 'normed_input': the "
@@ -157,6 +160,8 @@ class DroplessMoELayer(base_layer.BaseLayer):
         p.first_expert, held, e)
     f = _StoredWidth(d, p.hidden_dim)
     self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=d))
+    if p.post_norm_tpl is not None:
+      self.CreateChild("post_ln", p.post_norm_tpl.Copy().Set(input_dim=d))
     self.CreateVariable(
         "w_router", WeightParams((d, e), WeightInit.Gaussian(
             1.0 / math.sqrt(d)), p.dtype))
@@ -323,6 +328,9 @@ class DroplessMoELayer(base_layer.BaseLayer):
           theta, x.reshape(-1, d),
           router_logits.reshape(-1, p.num_experts), valid, layer)
       out = out.reshape(inputs.shape)
+      if p.post_norm_tpl is not None:
+        with observe.Scope("post_norm"):
+          out = self.post_ln.FProp(theta.post_ln, out)
       out = inputs + (out if p.residual_scale == 1.0
                       else p.residual_scale * out)
     return out, counts

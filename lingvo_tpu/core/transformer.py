@@ -46,6 +46,9 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
              "Factor on the block's output before it is added to its input "
              "(1: none, and no op).")
     p.Define("has_bias", True, "Biases on the projections.")
+    p.Define("post_norm_tpl", None,
+             "A norm on the block's OUTPUT, before the residual add; None: "
+             "none, no variable and no op.")
     return p
 
   def __init__(self, params):
@@ -53,6 +56,9 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
     p = self.p
     assert p.input_dim > 0 and p.hidden_dim > 0
     self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=p.input_dim))
+    if p.post_norm_tpl is not None:
+      self.CreateChild("post_ln",
+                       p.post_norm_tpl.Copy().Set(input_dim=p.input_dim))
     wsdm_in = p.weight_split_dims_mapping  # (None, 'model') typical
     wsdm_out = tuple(reversed(wsdm_in)) if wsdm_in else None
     self.CreateChild(
@@ -95,6 +101,9 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
             self.ChildTheta(theta, "dropout"), h,
             keep_prob=1.0 - p.relu_dropout_prob, name_suffix="relu")
       out = self.ffn_out.FProp(theta.ffn_out, h)
+      if p.post_norm_tpl is not None:
+        with observe.Scope("post_norm"):
+          out = self.post_ln.FProp(theta.post_ln, out)
       if p.residual_dropout_prob > 0:
         out = self.dropout.FProp(
             self.ChildTheta(theta, "dropout"), out,
@@ -108,8 +117,10 @@ class TransformerFeedForwardLayer(base_layer.BaseLayer):
     return out
 
 
-def _MixThenRows(mixer, theta, plan, mix, out, residual, then, scale=1.0):
-  """`then(residual + scale * Mixer(.))` (scale 1: no factor and no op) in a
+def _MixThenRows(mixer, theta, plan, mix, out, residual, then, scale=1.0,
+                 post=None):
+  """`then(residual + scale * post(Mixer(.)))` (scale 1: no factor and no op;
+  post: a norm on the branch's output, or None) in a
   serving step, the row-wise part over the
   rows the step holds. mix(theta) -> (ctx, *rest) is the mixer's
   `RaggedMix` (ctx: a tuple of `[1, T, ...]` arrays; its own projections
@@ -141,6 +152,9 @@ def _MixThenRows(mixer, theta, plan, mix, out, residual, then, scale=1.0):
   def _Finish(residual, *ctx):
     with observe.Scope("atten"):
       branch = out(theta, *ctx)
+      if post is not None:
+        with observe.Scope("post_norm"):
+          branch = post(branch)
       x = residual + (branch if scale == 1.0 else scale * branch)
     return x if then is None else then(x)
 
@@ -941,7 +955,12 @@ class SharedStateLayer(base_layer.BaseLayer):
 
   `residual_multiplier`: a factor on each branch's output before it is added
   to the stream, `h += f * Mixer(LN(h))` and `h += f * FeedForward(LN(h))`
-  (the expert layer's own residual included); 1 is no factor and no op."""
+  (the expert layer's own residual included); 1 is no factor and no op.
+
+  `post_norm_tpl`: a norm on the MIXER branch's output before the residual
+  add, `h += PostLN(Mixer(LN(h)))`; the feed-forward's is its own template's
+  (`tr_fflayer_tpl.post_norm_tpl`, dense or experts). None: none, no
+  variable and no op."""
 
   @classmethod
   def Params(cls):
@@ -954,6 +973,8 @@ class SharedStateLayer(base_layer.BaseLayer):
              "no feed-forward.")
     p.Define("residual_multiplier", 1.0,
              "Factor on a branch's output before the residual add.")
+    p.Define("post_norm_tpl", None,
+             "A norm on the mixer's output before the residual add.")
     return p
 
   def __init__(self, params):
@@ -965,6 +986,9 @@ class SharedStateLayer(base_layer.BaseLayer):
       self.CreateChild("ln", p.norm_tpl.Copy().Set(input_dim=p.input_dim))
       self.CreateChild("atten", p.mixer_tpl.Copy().Set(input_dim=p.input_dim))
       self.mixer = self.atten
+      if p.post_norm_tpl is not None:
+        self.CreateChild("post_ln",
+                         p.post_norm_tpl.Copy().Set(input_dim=p.input_dim))
     self._experts = False
     if p.tr_fflayer_tpl is not None:
       fflayer = p.tr_fflayer_tpl.Copy().Set(input_dim=p.input_dim)
@@ -1012,6 +1036,9 @@ class SharedStateLayer(base_layer.BaseLayer):
         out, shared = self.atten.FProp(theta.atten, normed, shared,
                                        paddings=paddings,
                                        segment_ids=segment_ids, depth=depth)
+        if self.p.post_norm_tpl is not None:
+          with observe.Scope("post_norm"):
+            out = self.post_ln.FProp(theta.post_ln, out)
         scale = self.p.residual_multiplier
         x = x + (out if scale == 1.0 else scale * out)
     return self._FeedForward(theta, x, paddings, repeat)[0], shared
@@ -1043,7 +1070,9 @@ class SharedStateLayer(base_layer.BaseLayer):
               theta, normed, states, shared, rows, table=table, depth=depth,
               plan=plan, **extra),
           lambda theta, *ctx: self.atten.RaggedOut(theta, *ctx, depth=depth),
-          x, feed_forward, scale=self.p.residual_multiplier)
+          x, feed_forward, scale=self.p.residual_multiplier,
+          post=None if self.p.post_norm_tpl is None else (
+              lambda y: self.post_ln.FProp(theta.post_ln, y)))
     elif dense:
       # a layer that is its dense feed-forward alone
       x = ragged.OverLiveRows(feed_forward, plan, x)

@@ -17,6 +17,7 @@ try:
   from lingvo_tpu.models.lm.params import granite_hybrid  # noqa: F401
   from lingvo_tpu.models.lm.params import phi4flash  # noqa: F401
   from lingvo_tpu.models.lm.params import smallthinker  # noqa: F401
+  from lingvo_tpu.models.lm.params import trinity  # noqa: F401
 except ImportError:
   pass
 try:

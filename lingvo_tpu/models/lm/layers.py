@@ -98,10 +98,17 @@ class TransformerLm(base_model.BaseTask):
         "feed-forward), 'gqa' (atten_tpl, an attention.PooledAttention "
         "over everything that owns its pages, and no feed-forward), "
         "'experts' (expert_ffn_tpl, a core/moe.DroplessMoELayer whose "
-        "router reads its own normed input, and no mixer). Stretches "
+        "router reads its own normed input, and no mixer), 'gqa_window' "
+        "(as 'gqa', within sliding_window_size and rotated by rope_theta). "
+        "A name that says mixer and feed-forward apart, '<mixer>+dense' or "
+        "'<mixer>+experts', is ONE layer of both: the mixer one of those "
+        "that may stand alone ('mamba2', 'gqa', 'gqa_window'), then the "
+        "dense feed-forward (hidden_dim) or the expert layer, so leading "
+        "dense layers before expert ones are ['gqa_window+dense', "
+        "'gqa_window+experts', ...]. Stretches "
         "that repeat are scanned, what lies between them is a block of "
         "its own (transformer.BlockSequence); no layer but a 'retention' "
-        "one carries a position. "
+        "and a 'gqa_window' one carries a position. "
         "None = the layouts below.")
     p.Define(
         "hybrid_override_pattern", None,
@@ -158,6 +165,11 @@ class TransformerLm(base_model.BaseTask):
              "A constant on every branch's output before it is added to the "
              "stream, in a stack told by layer_kinds / "
              "hybrid_override_pattern (transformer.SharedStateLayer).")
+    p.Define("post_norm", False,
+             "Every branch's output goes through a norm of its own (norm_tpl) "
+             "before the residual add, beside the norm on its input: "
+             "h += PostLN(Branch(LN(h))), in a stack told by layer_kinds / "
+             "hybrid_override_pattern.")
     p.Define(
         "kv_cache_dtype", None,
         "Decode KV-cache storage dtype for every attention layer in the "
@@ -262,12 +274,16 @@ class TransformerLm(base_model.BaseTask):
     from lingvo_tpu.core import ssm as ssm_lib
     p = self.p
     assert len(layer_kinds) == p.num_layers, (layer_kinds, p.num_layers)
-    kinds = set(layer_kinds)
-    # the templates a kind reads: both, unless every layer is a retention one
-    assert p.mixer_tpl is not None
-    assert p.atten_tpl is not None or kinds <= {"retention"}, kinds
+    # a name's two halves: its mixer and, behind a '+', its feed-forward
+    halves = [kind.partition("+")[::2] for kind in layer_kinds]
+    kinds = {mixer for mixer, _ in halves}
+    has_experts = any("experts" in h for h in halves)
+    # the templates a kind reads
+    reads_atten = {"window", "full", "cross", "gqa", "gqa_window"}
+    assert p.mixer_tpl is not None or kinds <= reads_atten | {"experts"}, kinds
+    assert p.atten_tpl is not None or not kinds & reads_atten, kinds
     assert p.num_experts == 0
-    assert (p.expert_ffn_tpl is not None) == ("experts" in layer_kinds)
+    assert (p.expert_ffn_tpl is not None) == has_experts
     assert not p.bidirectional
     atten = (p.atten_tpl.Copy().Set(num_heads=p.num_heads)
              if p.atten_tpl is not None else None)
@@ -283,42 +299,57 @@ class TransformerLm(base_model.BaseTask):
         "gmu": lambda: ssm_lib.GatedMemoryUnit.Params().Set(
             memory_dim=p.mixer_tpl.expand * p.model_dim),
     }
-    # one branch alone
+    # one branch alone, or with the feed-forward its name says behind a '+'
     alone = {
         "mamba2": lambda: p.mixer_tpl.Copy(),
         "gqa": lambda: atten.Copy().Set(window=0),
+        "gqa_window": lambda: atten.Copy().Set(
+            window=p.sliding_window_size, use_rotary_position_emb=True,
+            rope_max_timescale=p.rope_theta),
     }
-    assert p.sliding_window_size > 0 or "window" not in layer_kinds
+    assert p.sliding_window_size > 0 or not kinds & {"window", "gqa_window"}
     layer = transformer_lib.SharedStateLayer.Params().Set(
         residual_multiplier=p.residual_multiplier)
     layer.tr_fflayer_tpl.Set(
         hidden_dim=p.hidden_dim, activation="SILU", use_gated_activation=True,
         has_bias=False, residual_dropout_prob=p.residual_dropout_prob)
+    experts = p.expert_ffn_tpl.Copy() if has_experts else None
     if p.norm_tpl is not None:
       layer.norm_tpl = p.norm_tpl.Copy()
-      layer.tr_fflayer_tpl.norm_tpl = p.norm_tpl.Copy()
+      for ff in (layer.tr_fflayer_tpl, experts):
+        if ff is not None:
+          ff.norm_tpl = p.norm_tpl.Copy()
+    if p.post_norm:
+      post = (p.norm_tpl or layers_lib.LayerNorm.Params()).Copy()
+      layer.post_norm_tpl = post.Copy()
+      for ff in (layer.tr_fflayer_tpl, experts):
+        if ff is not None:
+          ff.post_norm_tpl = post.Copy()
 
-    def _Layer(kind):
-      if kind in mixers:
-        return layer.Copy().Set(mixer_tpl=mixers[kind]())
-      if kind in alone:
-        return layer.Copy().Set(mixer_tpl=alone[kind](), tr_fflayer_tpl=None)
-      assert kind == "experts", kind
-      experts = p.expert_ffn_tpl.Copy()
-      if p.norm_tpl is not None:
-        experts.norm_tpl = p.norm_tpl.Copy()
-      return layer.Copy().Set(mixer_tpl=None, tr_fflayer_tpl=experts)
+    def _Layer(mixer, ff):
+      if ff:
+        assert mixer in alone and ff in ("dense", "experts"), (mixer, ff)
+        return layer.Copy().Set(
+            mixer_tpl=alone[mixer](),
+            tr_fflayer_tpl=(layer.tr_fflayer_tpl if ff == "dense"
+                            else experts).Copy())
+      if mixer in mixers:
+        return layer.Copy().Set(mixer_tpl=mixers[mixer]())
+      if mixer in alone:
+        return layer.Copy().Set(mixer_tpl=alone[mixer](), tr_fflayer_tpl=None)
+      assert mixer == "experts", mixer
+      return layer.Copy().Set(mixer_tpl=None, tr_fflayer_tpl=experts.Copy())
 
-    blocks = [([_Layer(kind) for kind in kinds], reps)
+    blocks = [([_Layer(*kind.partition("+")[::2]) for kind in kinds], reps)
               for kinds, reps in KindBlocks(layer_kinds)]
     return transformer_lib.BlockSequence.Params().Set(
         input_dim=p.model_dim, blocks=blocks)
 
   def _CreateLayoutStack(self):
     p = self.p
-    assert p.residual_multiplier == 1.0, (
-        "residual_multiplier is a SharedStateLayer's (layer_kinds / "
-        "hybrid_override_pattern)")
+    assert p.residual_multiplier == 1.0 and not p.post_norm, (
+        "residual_multiplier and post_norm are a SharedStateLayer's "
+        "(layer_kinds / hybrid_override_pattern)")
     layer_body = transformer_lib.TransformerLayer.Params().Set(
         input_dim=p.model_dim, num_heads=p.num_heads,
         hidden_dim=p.hidden_dim, mask_self_atten=not p.bidirectional)

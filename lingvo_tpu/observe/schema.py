@@ -442,8 +442,18 @@ DEVICE_SCOPES = {
     "ssd_gate_norm": ("atten", "the gate by z and the RMSNorm over groups of "
                       "channels after it"),
     "ssd_out_proj": ("atten", "the output projection"),
-    "qk_norm": ("atten", "the RMSNorm over each head of q and of k (a "
-                "power-retention layer's, before the rotation)"),
+    "qk_norm": ("atten", "the RMSNorm over each head of q and of k, before "
+                "the rotation (a power-retention layer's; attention."
+                "MultiHeadedAttention's `qk_norm_epsilon`)"),
+    "atten_gate": ("atten", "an attention layer's output gate "
+                   "(`output_gate`): the gate's own projection of the "
+                   "layer's input, the sigmoid and the product with the "
+                   "attend's output, before the output projection"),
+    "post_norm": (None, "a norm on a branch's OUTPUT before the residual "
+                  "add (`post_norm_tpl`), entered inside `atten` for the "
+                  "mixer's and inside `ffn` for the feed-forward's (dense or "
+                  "experts); one name in a tree of one parent a scope, so "
+                  "it rolls up into neither, as `norm` does"),
     "retention_gate": ("atten", "a power-retention layer's gates: the "
                        "log-sigmoid, the log-gates cumulated over a row's "
                        "open chunk and the step's tokens, the page each "

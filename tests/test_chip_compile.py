@@ -71,6 +71,15 @@ _REASON_SERVING = dict(t=576, n=40, n_kv=20, h=64, page=128, table_pages=64,
                        pool_pages=6561, rows=64)
 
 
+# The grouped kernel and the runs' write at the shapes
+# `trinitymini_serve_repo_agent` runs them at: 64 slots + a 1,024-token
+# budget, 32 query heads over 4 KV heads of 128 (a group of EIGHT query heads
+# a KV head), the five layers' one pool of 5 x 2,000 pages and the trash
+# page, 272 pages a row (34,816 tokens): the longest block tables a cell has.
+_REPO_AGENT_SERVING = dict(t=1088, n=32, n_kv=4, h=128, page=128,
+                           table_pages=272, pool_pages=10001, rows=64)
+
+
 def _RetentionServingArgs(d=_RETENTION_SERVING):
   import jax.numpy as jnp
   from lingvo_tpu.core import ragged
@@ -193,6 +202,13 @@ def compiles():
       futures["grouped_serving_agent"] = pool.submit(
           _Compile, _CASES["ragged_attend_grouped"],
           _GroupedServingArgs(_AGENT_SERVING))
+      futures.update({
+          f"grouped_serving_repo_agent_{variant}": pool.submit(
+              _Compile, _CASES[name], _GroupedServingArgs(_REPO_AGENT_SERVING))
+          for variant, name in _GROUPED_CASES.items()})
+      futures["run_write_serving_repo_agent"] = pool.submit(
+          _Compile, _CASES["run_write"],
+          _RunWriteServingArgs(_REPO_AGENT_SERVING))
       yield futures
   finally:
     jax.config.update("jax_enable_compilation_cache", True)
@@ -262,14 +278,16 @@ def test_ragged_attend_compiles_at_serving_shapes(variant, compiles):
       timeout=300)
 
 
-@pytest.mark.parametrize("variant", sorted(_GROUPED_CASES) + ["agent"])
+@pytest.mark.parametrize("variant", sorted(_GROUPED_CASES) + [
+    "agent", "repo_agent_full", "repo_agent_window"])
 def test_grouped_attend_compiles_at_serving_shapes(variant, compiles):
   # every rung of the ladder is a branch of the one program Mosaic lowers
   assert "tpu_custom_call" in compiles[f"grouped_serving_{variant}"].result(
       timeout=300)
 
 
-@pytest.mark.parametrize("cell", ["docs", "docs_int8", "mixed", "agent"])
+@pytest.mark.parametrize("cell", ["docs", "docs_int8", "mixed", "agent",
+                                  "repo_agent"])
 def test_run_write_compiles_at_serving_shapes(cell, compiles):
   # token rows of 16, 4 and 2 KV heads (4 KB, 1 KB and 512 B of bf16) and of
   # int8: each a whole number of the tiles Mosaic lays that pool out in

@@ -76,6 +76,7 @@ _FAMILIES = {
 _NEWER_FAMILIES = {
     "mistral4": lambda dtype: _Registered(
         "lm.mistral4.MistralSmall4Tiny", dtype),
+    "trinity": lambda dtype: _Registered("lm.trinity.TrinityTiny", dtype),
 }
 
 
