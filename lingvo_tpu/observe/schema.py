@@ -48,9 +48,11 @@ ENGINE_COUNTER_KEYS = (
     # which is the grid every call ran before PR 46: their ratio is the share
     # of that grid that held work. Both 0 where the twins run.
     "attend_live_pairs", "attend_grid_pairs",
-    # the live pairs whose program ran no mask (ops/latent_attend.py: a block
-    # of the widest rung at a page that lies whole under every query's
-    # horizon), by ops/ragged_block_attend.ClearPairs from the host's rows:
+    # the live pairs whose program ran no mask (the grouped attend kernel's
+    # and ops/latent_attend.py's: a block of the widest rung at a page that
+    # lies whole under every query's horizon and, in a window layer, whole
+    # inside every query's window), by ops/ragged_block_attend.ClearPairs from
+    # the host's rows:
     # over `attend_live_pairs`, the share of programs that ran unmasked. 0
     # where no kernel of the step reads the plan's `clear`.
     "attend_clear_pairs",

@@ -319,8 +319,9 @@ class PlanKey(NamedTuple):
   #                chain sentinels (q_start 0, every ancestor bit set)
   kernel: bool   # the Pallas lowering serves the call: a twin's call takes
   #                no descriptors and none are built for it
-  clear: bool = False  # the call's kernel reads `AttendPlan.clear` (ops/
-  #                latent_attend.py's does); built for no other key
+  clear: bool = False  # the call's kernel reads `AttendPlan.clear_lo` and
+  #                `.clear` (_GroupedAttendKernel's does, and ops/
+  #                latent_attend.py's); built for no other key
 
 
 def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
@@ -331,9 +332,12 @@ def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
   kernel = Lowering(lowering) == "pallas"
   grouped = kernel and Grouped(n, n_kv)
   lanes = GroupLanes(n // n_kv) if grouped else n // n_kv
+  # the grouped kernel tells a clear page from another; the head-batched one
+  # runs one body
   return PlanKey(page_size, int(window),
                  QueryBlock(n_kv, h, page_size, q_dtype, kv_dtype,
-                            grouped=grouped), lanes, tree, kernel)
+                            grouped=grouped), lanes, tree, kernel,
+                 clear=grouped)
 
 
 class AttendPlan(NamedTuple):
@@ -359,9 +363,15 @@ class AttendPlan(NamedTuple):
   blk: jnp.ndarray    # [NB * grid_pages] a pair's block
   page: jnp.ndarray   # [NB * grid_pages] a pair's logical page
   pairs: jnp.ndarray  # [] the live pairs: the grid's length
-  clear: object = None  # [NB] the leading logical pages every query of the
-  #                     block sees WHOLE (no mask changes a score there);
-  #                     None unless the key asks for it (`PlanKey.clear`)
+  clear: object = None  # [NB] one past the last logical page every query of
+  #                     the block sees WHOLE (no mask changes a score there):
+  #                     the pages under its narrowest reach. None unless the
+  #                     key asks for it (`PlanKey.clear`)
+  clear_lo: object = None  # [NB] the first such page: `page0` without a
+  #                     window; with one, the first page that lies whole
+  #                     inside the window of the block's widest horizon. The
+  #                     clear pages are `clear_lo <= page < clear` (none where
+  #                     that is empty). None as `clear` is
 
 
 def _LivePairs(n, page0, last, size: int):
@@ -484,14 +494,13 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
     # sees a slot before it
     page0 = lax.min(lax.div(lax.max(lax.sub(narrowest(blk_ends), i32(window)),
                                     i32(0)), i32(page_size)), last)
-  cleared = None
+  cleared = clear_lo = None
   if clear:
     # A query sees every slot under its horizon `q_end` if it is a chain's
     # (every ancestor bit set); a tree's sees them under `q_start + 1` too
     # (slots at or below `q_start` clip to bit 0 of `_AncestorOk`, where that
     # is set) and none for sure otherwise. The block's narrowest such reach,
     # in whole pages.
-    assert not window, "a window's far edge is not counted here"
     one = np.ones((nb, bq), i32)
     reach = lax.select(
         lax.eq(both(part(2), part(3)), np.negative(one)), blk_ends,
@@ -499,6 +508,14 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                    lax.min(blk_ends, lax.add(part(1), i32(1))),
                    np.zeros((nb, bq), i32)))
     cleared = lax.min(lax.div(narrowest(reach), i32(page_size)), i32(t_pages))
+    clear_lo = page0
+    if window:
+      # a window's far edge: a query sees no slot before `q_end - window`, so
+      # every query of the block sees the slots from its WIDEST horizon less
+      # the window on. The first whole page of them.
+      clear_lo = lax.div(
+          lax.add(lax.max(lax.sub(lax.reduce_max(blk_ends, (1,)), i32(window)),
+                          i32(0)), i32(page_size - 1)), i32(page_size))
   col0 = lax.index_in_dim(cols, 0, 1, keepdims=False)       # [NB, 4]
   blk, page, pairs = _LivePairs(
       n, page0, last, nb * WindowPages(window, bq, page_size, t_pages))
@@ -511,7 +528,8 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                     first=first, cols=cols,
                     col0=tuple(lax.index_in_dim(col0, c, 1, keepdims=False)
                                for c in range(4)),
-                    blk=blk, page=page, pairs=pairs, clear=cleared)
+                    blk=blk, page=page, pairs=pairs, clear=cleared,
+                    clear_lo=clear_lo)
 
 
 def GridPairs(key: PlanKey, b: int, t: int, t_pages: int) -> int:
@@ -524,11 +542,11 @@ def GridPairs(key: PlanKey, b: int, t: int, t_pages: int) -> int:
 
 
 def _HostBlocks(key: PlanKey, row_q_pos, row_len, t_pages: int):
-  """(live, queries, narrowest horizon, page0, last), `[B, blocks]` each, of
-  the blocks a step's rows are cut into, from the host's own view of them
-  (numpy): row r brings `row_len[r]` tokens at positions `row_q_pos[r] ...`,
-  each `key.lanes` queries of its own horizon, cut into blocks of
-  `key.bq`."""
+  """(live, queries, narrowest horizon, widest horizon, page0, last), `[B,
+  blocks]` each, of the blocks a step's rows are cut into, from the host's
+  own view of them (numpy): row r brings `row_len[r]` tokens at positions
+  `row_q_pos[r] ...`, each `key.lanes` queries of its own horizon, cut into
+  blocks of `key.bq`."""
   start = np.asarray(row_q_pos, np.int64)[:, None]
   queries = np.asarray(row_len, np.int64)[:, None] * key.lanes
   lo = np.arange(-(-int(queries.max(initial=0)) // key.bq))[None] * key.bq
@@ -542,13 +560,13 @@ def _HostBlocks(key: PlanKey, row_q_pos, row_len, t_pages: int):
   if key.window:
     page0 = np.minimum(
         np.maximum(narrowest - key.window, 0) // key.page_size, last)
-  return live, hi - lo + 1, narrowest, page0, last
+  return live, hi - lo + 1, narrowest, widest, page0, last
 
 
 def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
   """`AttendPlan.pairs` of a step from the host's own view of its rows
   (numpy; `_LivePairs`' twin, as `BlockRows` is `BlockRungs`')."""
-  live, _, _, page0, last = _HostBlocks(key, row_q_pos, row_len, t_pages)
+  live, _, _, _, page0, last = _HostBlocks(key, row_q_pos, row_len, t_pages)
   return int(np.sum(np.where(live, last - page0 + 1, 0)))
 
 
@@ -561,16 +579,20 @@ def ClearRung(rungs: tuple[int, ...]) -> int:
 
 def ClearPairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
   """The pairs of `LivePairs` whose program ran no mask (numpy; what the
-  plan's `clear` gives the kernel, counted over CHAIN rows, which is what the
-  host knows it sent): a block of the widest rung at a page that lies whole
-  under its narrowest horizon. 0 for a key whose kernel reads no `clear`."""
+  plan's `clear_lo` and `clear` give the kernel, counted over CHAIN rows,
+  which is what the host knows it sent): a block of the widest rung at a page
+  that lies whole under its narrowest horizon and, with a window, whole inside
+  the window of its widest. 0 for a key whose kernel reads no `clear`."""
   if not key.clear:
     return 0
-  live, queries, narrowest, _, last = _HostBlocks(key, row_q_pos, row_len,
-                                                  t_pages)
+  live, queries, narrowest, widest, page0, last = _HostBlocks(
+      key, row_q_pos, row_len, t_pages)
   wide = live & (queries > ClearRung(BlockRungs(key.bq, key.lanes)))
-  return int(np.sum(np.where(
-      wide, np.minimum(narrowest // key.page_size, last + 1), 0)))
+  clear_lo = page0
+  if key.window:
+    clear_lo = -(-np.maximum(widest - key.window, 0) // key.page_size)
+  return int(np.sum(np.where(wide, np.maximum(np.minimum(
+      narrowest // key.page_size, last + 1) - clear_lo, 0), 0)))
 
 
 def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
@@ -781,8 +803,9 @@ def _HeadPages(ref, heads: int):
 
 def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
                          tables_ref, n_ref, first_ref, end0_ref, start0_ref,
-                         lo0_ref, hi0_ref, *rest, page_size: int, window: int,
-                         heads: int, rungs: tuple[int, ...]):
+                         lo0_ref, hi0_ref, clear_lo_ref, clear_ref, *rest,
+                         page_size: int, window: int, heads: int,
+                         rungs: tuple[int, ...]):
   """The (query block, logical page) program where a KV head serves a GROUP
   of query heads: the group rides the packed axis (RaggedAttend), so a
   block is up to Bq queries of which each has one vector per KV head. q and
@@ -807,7 +830,29 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
   multiple of 8 queries: q and the output cross HBM in f32, whose tile is 8
   rows (a 16-bit tile is 16: half the rows would start mid-tile), and the
   block's queries are cast once, at its first page. Window and masks as in
-  _RaggedAttendKernel."""
+  _RaggedAttendKernel.
+
+  A program does the vector work its page needs: the widest rung has two
+  bodies. A page in `clear_lo_ref[i] <= page < clear_ref[i]` lies whole under
+  the horizon of every query of the block and whole inside every query's
+  window (`AttendPlan.clear_lo`, `.clear`): its body reads no mask column,
+  builds no `keep`, selects nothing and guards no unseen row
+  (`_BlockPageAttend(keep=None)`), and carries a head's statistics as the
+  lane-replicated `[rows, 128]` the scratch holds, no `[:, :1]` slice in and
+  no broadcast out (a page of 128 slots; another page size keeps the `[rows,
+  1]` form). Any other page (the one or two the block's own tokens sit in, a
+  window's first) takes the masked body as it was. Per (query, head, slot)
+  both run the same float ops in the same order: the output is bitwise the
+  masked kernel's. A masked page leaves the rung's rows that are not the
+  block's at an exact zero by itself, a clear one does not, so `_Emit` zeroes
+  them, once a block, by `n_ref`. The 8-row rung keeps its one masked body: a
+  decode pair is its fixed cost, and a second body is a second trace. Measured
+  (`tools/kernel_probe.py --case grouped_attend`, PERF.md section 6, PR 62): a
+  chunk pair 3.75-3.97 us masked -> 1.50-2.01 us with nine in ten of its
+  pages clear; the same body over `[rows, 1]` statistics LOST (4.18-4.20 us:
+  the mask's passes cost less than the slices and broadcasts it is then left
+  with), the rows in halves or quarters gained 4-7% more at two and four
+  times the traced body."""
   pair = pl.program_id(0)
   i, page = blk_ref[pair], page_ref[pair]
   q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
@@ -836,35 +881,51 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
       lb[:, held] = jnp.zeros((heads, rows, LANES), lb.dtype)
       accb[held] = jnp.zeros((rows, heads * h), accb.dtype)
 
-    def _Accumulate():
-      slot = page * page_size + jax.lax.broadcasted_iota(
-          jnp.int32, (1, page_size), 1)                       # [1, P]
-      cols = cols_ref[0, held]                                # [rows, 4]
-      keep = (slot < cols[:, 0:1]) & _AncestorOk(
-          slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [rows, P]
-      if window:
-        keep &= slot >= cols[:, 0:1] - window
+    def _Page(masked: bool):
+      keep = None
+      if masked:
+        slot = page * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)                     # [1, P]
+        cols = cols_ref[0, held]                              # [rows, 4]
+        keep = (slot < cols[:, 0:1]) & _AncestorOk(
+            slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [rows, P]
+        if window:
+          keep &= slot >= cols[:, 0:1] - window
       keys, values = _HeadPages(k_ref, heads), _HeadPages(v_ref, heads)
+      # a clear page's statistics ride as the scratch holds them, lane-
+      # replicated, where a page is as wide as they are
+      stat = slice(None) if not masked and page_size == LANES else slice(1)
       for g in range(heads):
         lanes = pl.ds(g * h, h)
         m, l, acc = _BlockPageAttend(
-            qh[held, lanes], keys[g], values[g], keep,
-            mb[g, held, :1], lb[g, held, :1], accb[held, lanes],
-            (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+            qh[held, lanes], keys[g], values[g], keep, mb[g, held, stat],
+            lb[g, held, stat], accb[held, lanes], (((1,), (1,)), ((), ())),
+            (((1,), (0,)), ((), ())))
         mb[g, held] = jnp.broadcast_to(m, (rows, LANES))
         lb[g, held] = jnp.broadcast_to(l, (rows, LANES))
         accb[held, lanes] = acc
 
-    _Accumulate()
+    two_bodies = rows > ClearRung(rungs)
+    if two_bodies:
+      is_clear = jnp.logical_and(page >= clear_lo_ref[i], page < clear_ref[i])
+      pl.when(is_clear)(functools.partial(_Page, False))
+      pl.when(jnp.logical_not(is_clear))(functools.partial(_Page, True))
+    else:
+      _Page(True)
 
     @pl.when(page == last_ref[i])
     def _Emit():
       # a query of the rung's rows that is not this block's comes out an
-      # exact zero, as in _RaggedAttendKernel
+      # exact zero, as in _RaggedAttendKernel: a masked page leaves it one by
+      # itself (its `q_end` 0 masks every slot), a clear page does not
+      if two_bodies:
+        mine = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < nv
       for g in range(heads):
         lanes = pl.ds(g * h, h)
-        qb[held, lanes] = _Finish(lb[g, held, :1], accb[held, lanes],
-                                  qb.dtype)
+        out = _Finish(lb[g, held, :1], accb[held, lanes], qb.dtype)
+        if two_bodies:
+          out = jnp.where(mine, out, jnp.zeros((), out.dtype))
+        qb[held, lanes] = out
       _Copy(qb.at[held], out_hbm.at[window_q])
 
   # A rung's two bounds are two nested branches, not one `&`: what the block
@@ -986,7 +1047,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
   prefetch = _Prefetch(blocks, tables) + blocks.col0
   if grouped:
     out = _GroupedCall(
-        blocks.pairs, prefetch,
+        blocks.pairs, prefetch + (blocks.clear_lo, blocks.clear),
         jnp.pad(q.reshape(t, n * h).astype(jnp.float32), ((0, bq), (0, 0))),
         blocks.cols, k_pool.reshape(np_total, page * n, h),
         v_pool.reshape(np_total, page * n, h), page_size=page_size, heads=n,

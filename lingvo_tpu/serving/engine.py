@@ -569,8 +569,9 @@ class ServingLoop:
                                       table_pages)
         for key in self._attend_plan_keys)
     self._table_pages = table_pages
-    # those whose kernel runs no mask at a page under every query's horizon
-    # (ops/latent_attend.py), counted as `attend_clear_pairs`
+    # those whose kernel runs no mask at a page every query of a block sees
+    # whole (the grouped attend kernel's and ops/latent_attend.py's), counted
+    # as `attend_clear_pairs`
     self._attend_clear_keys = {k for k in self._attend_plan_keys if k.clear}
     # some layer writes its pages by the step's runs (ops/run_write.py)
     self._kv_write_by_runs = any(

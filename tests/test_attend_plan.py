@@ -393,30 +393,95 @@ def test_clear_is_the_pages_under_every_members_reach(lanes, bq, pack):
   want = [reach[first[i]:first[i] + n[i]].min() // PAGE for i in range(live)]
   assert np.asarray(got.clear)[:live].tolist() == want
   assert max(want) > 0
-  with pytest.raises(AssertionError, match="window"):
-    rba.BuildAttendPlan(key._replace(window=24), *args, b=b, t_pages=T_PAGES)
+  # without a window the clear pages start where the block's pages do
+  assert got.clear_lo is got.page0 and plain.clear_lo is None
+
+
+# the two packs, and one whose chunk sits deep enough in its row for a window
+# to leave pages behind it
+WINDOW_PACKS = {**PACKS, "deep": ([1, 0, 24, 3], [37, 9, 60, 5], None)}
+
+
+def _Keep(ends, starts, lo, hi, window):
+  """[queries, slots] what `_AncestorOk`, the horizon and the window let a
+  query see, a (query, slot) at a time in numpy."""
+  slot = np.arange(T_PAGES * PAGE, dtype=np.int64)[None]
+  ends, starts, lo, hi = (x[:, None] for x in (ends, starts, lo, hi))
+  cc = np.clip(slot - starts, 0, 63)
+  word = np.where(cc < 32, lo, hi).astype(np.int64) & 0xFFFFFFFF
+  ok = (word >> np.where(cc < 32, cc, cc - 32)) & 1 == 1
+  keep = (slot < ends) & ok
+  if window:
+    keep &= slot >= ends - window
+  return keep
+
+
+@pytest.mark.parametrize("pack", list(WINDOW_PACKS))
+@pytest.mark.parametrize("window", [0, 24, 37])
+@pytest.mark.parametrize("lanes,bq", [(1, 16), (8, 64), (16, 128)])
+def test_the_clear_range_is_pages_every_member_sees_whole(lanes, bq, window,
+                                                         pack):
+  """`clear_lo <= page < clear` of a key with a window (and without) against
+  a brute-force reading of every (query, slot): at a page of the range `keep`
+  is all ones for every query of the block; for chain rows the range is
+  EVERY such page. Blocks start and end mid-page, 37 is no page multiple.
+  The other fields are the plan's of the key that does not ask."""
+  rows = _Rows(WINDOW_PACKS[pack])
+  b = len(WINDOW_PACKS[pack][0])
+  tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
+  args = (tok.row, tok.q_end, tok.q_start, rows.anc_lo, rows.anc_hi)
+  key = rba.PlanKey(PAGE, window, bq, lanes, tree=True, kernel=True,
+                    clear=True)
+  got = rba.BuildAttendPlan(key, *args, b=b, t_pages=T_PAGES)
+  plain = rba.BuildAttendPlan(key._replace(clear=False), *args, b=b,
+                              t_pages=T_PAGES)
+  for a, b_ in zip(jax.tree.leaves(got._replace(clear=None, clear_lo=None)),
+                   jax.tree.leaves(plain)):
+    _Same(a, b_)
+  keep = _Keep(*(np.repeat(np.asarray(x, np.int64), lanes)
+                 for x in args[1:]), window)
+  whole = keep.reshape(keep.shape[0], T_PAGES, PAGE).all(-1)  # [queries, pages]
+  n, first, lo, hi, page0, last = (np.asarray(x) for x in (
+      got.n, got.first, got.clear_lo, got.clear, got.page0, got.last))
+  found = 0
+  for i in range(int(np.sum(n > 0))):
+    seen = whole[first[i]:first[i] + n[i]].all(0)
+    ranged = (np.arange(T_PAGES) >= lo[i]) & (np.arange(T_PAGES) < hi[i])
+    assert not np.any(ranged & ~seen), (i, lo[i], hi[i], seen)
+    if pack != "tree":
+      assert ranged.tolist() == seen.tolist(), (i, lo[i], hi[i], seen)
+    # a clear page is one of the block's live pages
+    assert not np.any(ranged[:page0[i]]) and not np.any(ranged[last[i] + 1:])
+    found += int(ranged.sum())
+  assert found > 0 or (window == 24 and pack != "deep" and lanes > 1), found
 
 
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("kind", PAIR_KINDS)
+@pytest.mark.parametrize("window", [0, 24, 37])
 @pytest.mark.parametrize("lanes,bq", [(1, 16), (8, 64), (16, 16)])
-def test_the_host_counts_the_clear_pairs_the_plan_holds(lanes, bq, kind, seed):
+def test_the_host_counts_the_clear_pairs_the_plan_holds(lanes, bq, window,
+                                                        kind, seed):
   """`ClearPairs` (the host's rows) against the plan's own count: the pairs
-  of a block of the widest rung whose page lies under its `clear`. Never more
-  than the live pairs, and none under a key that does not ask."""
+  of a block of the widest rung whose page lies in its `clear_lo <= page <
+  clear`, with a window and without. Never more than the live pairs, and
+  none under a key that does not ask."""
   rows, tables = _PairRows(kind, seed)
   b = tables.shape[0]
   tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
-  key = rba.PlanKey(PAGE, 0, bq, lanes, tree=False, kernel=True, clear=True)
+  key = rba.PlanKey(PAGE, window, bq, lanes, tree=False, kernel=True,
+                    clear=True)
   plan = rba.BuildAttendPlan(key, tok.row, tok.q_end, b=b, t_pages=T_PAGES)
-  n, last, clear = (np.asarray(x) for x in (plan.n, plan.last, plan.clear))
+  n, last, lo, clear = (np.asarray(x) for x in (
+      plan.n, plan.last, plan.clear_lo, plan.clear))
   wide = n > rba.ClearRung(rba.BlockRungs(bq, lanes))
-  want = int(np.sum(np.where(wide, np.minimum(clear, last + 1), 0)))
+  want = int(np.sum(np.where(
+      wide, np.maximum(np.minimum(clear, last + 1) - lo, 0), 0)))
   got = rba.ClearPairs(key, rows.row_q_pos, rows.row_len, T_PAGES)
   assert got == want <= int(plan.pairs)
   if kind in ("decode_only", "empty") and lanes < bq:
     assert got == 0          # a decode row's rung runs one body
-  if kind == "full_pool":
+  if kind == "full_pool" and window != 24:
     assert got > 0
   assert rba.ClearPairs(key._replace(clear=False), rows.row_q_pos,
                         rows.row_len, T_PAGES) == 0
@@ -728,3 +793,96 @@ def test_a_stack_with_no_whole_page_writer_lowers_the_parents_step(family):
   assert len(text.splitlines()) == lines
   if jax.__version__ == "0.9.0":      # the text is that version's
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# -- the grouped kernel's clear pages: who else's step, and the counter --------
+
+# The step program of each family whose attend kernel is NOT the grouped one
+# (dense: head-batched; Phi-4-flash: differential attention's own, its key
+# through `AttendPlanKey`; Mistral-Small-4: the latent kernel, its key
+# `clear` since PR 55), with the kernels' lowering forced, as the PARENT of
+# PR 62 (`abe1377`) lowers it under JAX 0.9.0 on the CPU: lines of
+# `lower().as_text()` and the first 16 hex digits of its sha256. The test
+# below is the recipe: run it on a parent's tree to take a number again.
+_PARENT_KERNEL_STEP = {
+    "dense": (2234, "e39ffac52564fa07"),
+    "phi4flash": (9184, "a0b827fa28090b9b"),
+    "mistral4": (2627, "709f2b404996366f"),
+}
+
+
+@pytest.mark.parametrize("family", list(_PARENT_KERNEL_STEP))
+def test_a_step_without_the_grouped_kernel_lowers_the_parents_program(family):
+  """The plan's `clear_lo`, the grouped key's `clear` and the kernel's second
+  body leave the programs of the other three attend kernels the parent's
+  text, byte for byte: their keys, plans and bodies are what they were."""
+  import hashlib
+  from lingvo_tpu.core import mla as mla_lib
+  from tests import test_head_cols
+  if family == "mistral4":
+    task, theta = test_head_cols._NEWER_FAMILIES[family](jnp.float32)
+    _, calls, _ = test_head_cols._MixedStepEngine(task, theta)
+    args = calls.calls[-1][0][:5]
+  else:
+    task, theta = _Task(family, 4 if family == "dense" else 8)
+    args = _StepArgs(task, theta)[1][-1]
+  with pytest.MonkeyPatch.context() as mp:
+    mp.setattr(rba, "Lowering", lambda lowering: (
+        "pallas" if lowering == "auto" else lowering))
+    mp.setattr(mla_lib.MultiHeadLatentAttention, "_Lowering",
+               lambda self, page_size: "pallas")
+    keys = task.stack.RaggedPlanKeys(args[1])
+    text = jax.jit(lambda th, st, ids, rows, tables: task.RaggedStep(
+        th, ids[None], st, tables, rows)[0]).lower(*args).as_text()
+  assert keys and all(k.kernel for k in keys)
+  assert [k.clear for k in set(keys)] == [family == "mistral4"] * len(set(keys))
+  lines, digest = _PARENT_KERNEL_STEP[family]
+  got = (len(text.splitlines()), hashlib.sha256(text.encode()).hexdigest()[:16])
+  assert got[0] == lines, got
+  if jax.__version__ == "0.9.0":      # the text is that version's
+    assert got[1] == digest, got
+
+
+def test_the_engine_counts_a_grouped_models_clear_pairs():
+  """`attend_clear_pairs` of a model whose layers run the grouped kernel (a
+  full layer and three window layers a period: two keys): the host's own
+  count over the steps dispatched, positive once a chunk's block holds a
+  page under every query's horizon, and unmoved by a decode-only step."""
+  task, theta = _Task("smallthinker", 4)
+  twin = engine_lib.ServingLoop(
+      task, theta, page_size=PAGE, num_pages=48, max_batch=4, max_seq_len=128,
+      prefill_token_budget=8).Stats()
+  assert twin["attend_plans"] == 0 and not twin.get("attend_clear_pairs")
+  with pytest.MonkeyPatch.context() as mp:
+    mp.setattr(rba, "Lowering", lambda lowering: (
+        "pallas" if lowering == "auto" else lowering))
+    eng = engine_lib.ServingLoop(
+        task, theta, page_size=PAGE, num_pages=48, max_batch=4,
+        max_seq_len=128, prefill_token_budget=8)
+    keys = eng._attend_clear_keys
+    assert len(keys) == 2 == eng.Stats()["attend_plans"]
+    assert sorted(k.window > 0 for k in keys) == [False, True]
+    seen, note = [], eng._NoteDispatch
+
+    def _Note(batch):
+      seen.append((np.array(batch.rows_desc.row_q_pos),
+                   np.array(batch.rows_desc.row_len)))
+      return note(batch)
+
+    eng._NoteDispatch = _Note
+    long = eng.Submit(list(range(1, 41)), 4, eos_id=None, seed=13)
+    while not long.done:
+      eng.StepOnce()
+  pages = eng._table_pages
+  by_step = [sum(rba.ClearPairs(k, q_pos, n, pages) for k in keys)
+             for q_pos, n in seen]
+  assert sum(by_step) == eng.Stats()["attend_clear_pairs"]
+  chunk = [int(n.max()) > 1 for _, n in seen]
+  # the first chunk sits in its row's first page; a later one has a page
+  # under its narrowest horizon; a decode-only step runs the one masked body
+  assert by_step[0] == 0 and max(by_step) > 0
+  assert not all(chunk) and not any(
+      c for c, is_chunk in zip(by_step, chunk) if not is_chunk)
+  st = eng.Stats()
+  assert 0 < st["attend_clear_pairs"] < st["attend_live_pairs"] == sum(
+      rba.LivePairs(k, q_pos, n, pages) for k in keys for q_pos, n in seen)

@@ -502,3 +502,139 @@ class TestQueryBlocks:
     np.testing.assert_allclose(out_p, ref, rtol=0, atol=_ATOL)
     np.testing.assert_array_equal(out_p[cursor:],
                                   np.zeros_like(out_p[cursor:]))
+
+
+class TestGroupedClearPages:
+  """`_GroupedAttendKernel`'s two bodies of the widest rung: a page in the
+  plan's `clear_lo <= page < clear` runs no mask, and the output is BITWISE
+  what the masked body gives at every page."""
+
+  # rows: a decode row deep in its context, an empty slot, a chunk that starts
+  # mid-page and ends in a partial block, a short chunk (or a 13-node tree)
+  LENS, Q_POS = [1, 0, 100, 13], [650, 9, 700, 40]
+  PARENTS = [-1, 0, 0, 2, -1, 4, 4, 6, -1, 8, 9, 9]
+  T, H, SLOTS = 120, 128, 1024
+
+  def _Case(self, heads, page, window, tree):
+    n, nk = heads
+    t_pages = self.SLOTS // page
+    b = len(self.LENS)
+    rows = ragged.BuildRaggedRows(
+        np.array(self.LENS), np.array(self.Q_POS), self.T, 128,
+        row_parents={3: self.PARENTS} if tree else None)
+    rows = ragged.RaggedRows(*(jnp.asarray(m) for m in rows))
+    tok = ragged.BuildTokenView(rows, b, t_pages, page)
+    rng = np.random.RandomState(5)
+    np_total = b * t_pages + 1
+    tables = rng.permutation(np_total - 1).reshape(b, t_pages).astype(np.int32)
+    # every page no query may see is poison: behind a row's window, past its
+    # horizon, and the pages no table names
+    seen = np.zeros((np_total,), bool)
+    for r, (n_r, pos) in enumerate(zip(self.LENS, self.Q_POS)):
+      if n_r:
+        lo = max(pos + 1 - window, 0) // page if window else 0
+        seen[tables[r, lo:(pos + n_r - 1) // page + 1]] = True
+    kp, vp = (jnp.asarray(np.where(seen[:, None, None, None],
+                                   rng.randn(np_total, page, nk, self.H),
+                                   np.nan), jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.randn(self.T, n, self.H) * self.H ** -0.5, jnp.float32)
+    key = ragged_block_attend.AttendPlanKey(
+        n, nk, self.H, page, q.dtype, kp.dtype, window=window,
+        lowering="pallas")
+    tree_kw = dict(q_start=tok.q_start, anc_lo=rows.anc_lo,
+                   anc_hi=rows.anc_hi)
+    blocks = ragged_block_attend.BuildAttendPlan(
+        key, tok.row, tok.q_end, *tree_kw.values(), b=b, t_pages=t_pages)
+
+    def _Call(lowering, kp=kp, vp=vp, **kw):
+      return np.asarray(ragged_block_attend.RaggedAttend(
+          q, kp, vp, jnp.asarray(tables), tok.row, tok.q_end, page_size=page,
+          window=window, lowering=lowering, **tree_kw, **kw))
+
+    return key, blocks, rows, tok, t_pages, _Call, (kp, vp)
+
+  @pytest.mark.parametrize("tree", [False, True], ids=["chains", "tree"])
+  @pytest.mark.parametrize("window", [0, 300])
+  @pytest.mark.parametrize("page", [16, 128])
+  @pytest.mark.parametrize("heads", [(8, 2), (32, 2), (16, 8)],
+                           ids=["group4", "group16", "eight_kv_heads"])
+  def test_clear_pages_are_bitwise_the_masked_pages(self, heads, page, window,
+                                                    tree):
+    key, blocks, rows, tok, t_pages, call, pools = self._Case(
+        heads, page, window, tree)
+    assert key.clear and key.bq == 512 and key.lanes in (8, 16)
+    n, last, lo, hi = (np.asarray(x) for x in (
+        blocks.n, blocks.last, blocks.clear_lo, blocks.clear))
+    wide = n > ragged_block_attend.ClearRung(
+        ragged_block_attend.BlockRungs(key.bq, key.lanes))
+    clear_pairs = np.where(wide, np.maximum(np.minimum(hi, last + 1) - lo, 0),
+                           0)
+    # the chunk's blocks (the last one partial) hold clear pages; with a
+    # window some of the block's pages lie behind the range as well
+    assert int(np.sum(clear_pairs > 0)) >= 2 and int(np.sum(wide)) >= 3
+    if not tree:
+      assert int(clear_pairs.sum()) == ragged_block_attend.ClearPairs(
+          key, rows.row_q_pos, rows.row_len, t_pages)
+    if window:
+      assert np.any(lo[wide] > np.asarray(blocks.page0)[wide])
+    out = call("pallas", interpret=True, plan={key: blocks})
+    masked = call("pallas", interpret=True, plan={
+        key: blocks._replace(clear=jnp.zeros_like(blocks.clear))})
+    assert np.all(np.isfinite(out))
+    np.testing.assert_array_equal(out, masked)
+    np.testing.assert_array_equal(out[np.asarray(tok.q_end) == 0], 0.0)
+    # and the comparison sees a page that runs unmasked and is not clear
+    wrong = call("pallas", interpret=True, plan={
+        key: blocks._replace(clear=blocks.clear + 1)})
+    assert not np.array_equal(wrong, out)
+    # the twin masks what it gathers, and a masked NaN is still one
+    clean = [jnp.nan_to_num(x) for x in pools]
+    np.testing.assert_allclose(out, call("xla", *clean), rtol=0, atol=2e-5)
+
+  def test_the_grouped_key_asks_and_the_other_kernels_keys_do_not(self):
+    """`clear` is a function of shapes: set where the Pallas lowering runs
+    the grouped kernel, for no head-batched call, no twin's and not for
+    differential attention's kernel, which reads none."""
+    from lingvo_tpu.ops import diff_attend
+    key = lambda n, nk, **kw: ragged_block_attend.AttendPlanKey(
+        n, nk, 128, 128, jnp.bfloat16, jnp.bfloat16, **kw)
+    for n, nk in ((28, 4), (32, 4), (32, 2), (40, 8)):
+      for window in (0, 2048):
+        assert key(n, nk, window=window, lowering="pallas").clear
+        assert not key(n, nk, window=window, lowering="xla").clear
+    assert not key(16, 16, lowering="pallas").clear
+    diff = diff_attend.DiffPlanKey(40, 20, 64, 128, jnp.bfloat16, jnp.bfloat16,
+                                   window=512, lowering="pallas")
+    assert diff.kernel and diff.lanes == 8 and not diff.clear
+
+
+@pytest.mark.parametrize("shape", ["smallthinker", "trinity"])
+def test_the_kernel_probe_holds_the_clear_body_to_the_masked_one(shape,
+                                                                 capsys):
+  """tools/kernel_probe.py --case grouped_attend at the CPU's rehearsal
+  sizes: a full and a window layer, a decode-only step and one with a chunk
+  whose blocks hold clear pages; `clear` is bitwise `masked`."""
+  import importlib.util
+  import json
+  import os
+  spec = importlib.util.spec_from_file_location("kernel_probe", os.path.join(
+      os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools",
+      "kernel_probe.py"))
+  kernel_probe = importlib.util.module_from_spec(spec)
+  spec.loader.exec_module(kernel_probe)
+  assert kernel_probe.main(["--case", "grouped_attend", "--tiny", "--calls",
+                            "1", "--shapes", shape]) == 0
+  lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+           if l.startswith("{")]
+  n, n_kv, windows = kernel_probe.ATTEND_SHAPES[shape][:3]
+  assert [(l["window"], l["step"], l["variant"]) for l in lines] == [
+      (w, s, v) for w in windows for s in ("decode", "chunk@0k")
+      for v in ("masked", "clear")]
+  assert all(l["bitwise_the_first"] for l in lines[1::2])
+  assert all(l["tiny"] and l["device"]["platform"] == "cpu"
+             and (l["heads"], l["kv_heads"]) == (n, n_kv) for l in lines)
+  for l in lines:
+    chunk = l["step"] != "decode"
+    assert (l["chunk_pairs"] > 0) == chunk and l["decode_pairs"] > 0
+    assert (l["clear_pairs"] > 0) == (chunk and l["variant"] == "clear")
+    assert ("us_a_chunk_pair" in l) == chunk

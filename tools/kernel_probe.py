@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""ONE layer's call of a kernel alone, at a cell's shapes. Three cases.
+"""ONE layer's call of a kernel alone, at a cell's shapes. Four cases.
 
 `--case page_write` (the default): the whole-page write
 (ops/diff_attend.WritePages) at the shapes `phi4flash_serve_reason` runs it
@@ -82,6 +82,34 @@ is; `gb_s` is the 276 MB form's bytes (the bf16 rows read and written by the
 gather, read by the fused pass, `[T, D]` bf16 written) over that time,
 whatever the variant moves. `--tiny` rehearses on the CPU.
 
+`--case grouped_attend`: ONE layer's `RaggedAttend` through the grouped
+kernel (ops/ragged_block_attend._GroupedAttendKernel) at the two cells whose
+steps it holds the largest share of: `--shapes smallthinker` (28 query heads
+over 4 KV heads of 128, windows 0 and 4,096, a table of 128 pages) and
+`trinity` (32 over 4, windows 0 and 2,048, 272 pages); bf16 pages of 128, 64
+rows, a pack of 1,088 tokens.
+
+  python3 tools/kernel_probe.py --case grouped_attend
+      [--shapes smallthinker,trinity] [--steps decode,chunk]
+      [--chunk_at 3k,12k,30k] [--variants masked,clear] [--parent DIR]
+      [--calls 50] [--seed 0] [--tiny]
+
+Steps: `decode` (64 one-token rows at seeded contexts) and `chunk` (63 such
+rows beside one 1,024-token chunk that starts mid-page past each of
+`--chunk_at`, where the shape's table holds it: `chunk@3k` ...). Variants,
+each `fn(q, k_pool, v_pool, tables, tokens, plan) -> [T, N, H]`: `masked` is
+the kernel with the plan's clear range emptied (every page takes the masked
+body: the parent's arithmetic), `clear` the kernel as the step runs it, and
+`parent` the same call through DIR/lingvo_tpu/ops/ragged_block_attend.py. A
+form under trial is registered in `ATTEND_VARIANTS` from a script of the
+builder's own. The plan is built outside the timed loop (a step builds it
+once for its layers); every variant's output is held BITWISE to the first's
+on the device. The time is a loop of `--calls` trips over the same pools, the
+queries behind a barrier with the trip's index. Prints ms a call and, from
+the host's count of the step's pairs (`LivePairs` of the one-token rows and
+of the chunk), us a decode pair (the decode step's) and us a chunk pair (the
+chunk step's time less its one-token rows' pairs at the decode step's price).
+
 Its readings are a builder's, never the ledger's: one call in a loop has no
 neighbours to share the chip's memory system with, and no step round it.
 """
@@ -100,6 +128,7 @@ sys.path.insert(0, ROOT)
 
 # the cell's shapes (benchmarks/configs/phi4flash.json, `serving`)
 PAGE, KV_HEADS, HEAD, ROWS, BUDGET, TABLE_PAGES = 128, 20, 64, 64, 512, 64
+HEAD_DIM = 128   # the grouped attend's head size (both of its shapes)
 
 VARIANTS = {}   # name -> fn(diff_attend module) -> the variant's function
 
@@ -413,6 +442,175 @@ def CombineMain(args) -> int:
                   for l in lines) else 1
 
 
+# -- the grouped attend kernel -------------------------------------------------
+
+# query heads, KV heads, the layer's windows, table pages (benchmarks/configs/
+# smallthinker21b.json, trinitymini.json: `serving.max_seq_len` / 128), and
+# the deepest context a one-token row is seeded at
+ATTEND_SHAPES = {"smallthinker": (28, 4, (0, 4096), 128, 6000),
+                 "trinity": (32, 4, (0, 2048), 272, 12000)}
+ATTEND_BUDGET = 1024
+
+
+def _AttendMasked(rba):
+  """The call with the plan's clear range emptied: one body at every page."""
+  def _Call(q, kp, vp, tables, tokens, plan, **kw):
+    key, = plan
+    blocks = plan[key]
+    if blocks.clear is not None:
+      plan = {key: blocks._replace(clear=blocks.clear * 0)}
+    return rba.RaggedAttend(q, kp, vp, tables, *tokens, plan=plan, **kw)
+  return _Call
+
+
+def _AttendAsBuilt(rba):
+  return lambda q, kp, vp, tables, tokens, plan, **kw: rba.RaggedAttend(
+      q, kp, vp, tables, *tokens, plan=plan, **kw)
+
+
+ATTEND_VARIANTS = {"masked": _AttendMasked, "clear": _AttendAsBuilt}
+
+
+def AttendRows(step: str, rng, rows: int, budget: int, deepest: int,
+               table_pages: int):
+  """(tokens a row, first position a row) of a grouped-attend step: `decode`,
+  or `chunk@<n>k` (the chunk in the middle row, from n * 1024 + 293: two
+  pages and 37 slots in)."""
+  import numpy as np
+  context = rng.randint(PAGE, deepest, size=rows)
+  lens = np.ones(rows, np.int64)
+  if step.startswith("chunk@"):
+    lens[rows // 2] = budget
+    context[rows // 2] = int(step[6:-1]) * 1024 + 293
+  else:
+    assert step == "decode", step
+  assert (context + lens).max() <= table_pages * PAGE, (step, table_pages)
+  return lens, context
+
+
+def AttendMain(args) -> int:
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+  from lingvo_tpu.core import compile_cache
+  from lingvo_tpu.core import ragged as ragged_lib
+  from lingvo_tpu.ops import ragged_block_attend
+
+  compile_cache.Configure()
+  on_tpu = jax.default_backend() == "tpu"
+  assert on_tpu or args.tiny, "a time comes from the chip; --tiny rehearses"
+  parent = (_ParentModule(args.parent, "ragged_block_attend")
+            if args.parent else None)
+  names = args.variants.split(",") + (["parent"] if parent else [])
+  rows_n, budget = (4, 128) if args.tiny else (ROWS, ATTEND_BUDGET)
+  t = rows_n + budget
+  kw = dict(page_size=PAGE, lowering="pallas", interpret=not on_tpu)
+  device = jax.devices()[0]
+  lines = []
+  for shape in args.shapes.split(","):
+    n, n_kv, windows, table_pages, deepest = ATTEND_SHAPES[shape]
+    if args.tiny:
+      table_pages, deepest = 8, 600
+    steps = []
+    for step in args.steps.split(","):
+      steps += [step] if step == "decode" else [
+          "chunk@" + at for at in (["0k"] if args.tiny
+                                   else args.chunk_at.split(","))
+          if int(at[:-1]) * 1024 + 293 + budget <= table_pages * PAGE]
+    for window in windows:
+      decode_us = {}
+      for step in steps:
+        rng = np.random.RandomState(args.seed)
+        lens, context = AttendRows(step, rng, rows_n, budget, deepest,
+                                   table_pages)
+        rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in
+                                       ragged_lib.BuildRaggedRows(
+                                           lens, context, t, budget + 1)))
+        tok = ragged_lib.BuildTokenView(rows, rows_n, table_pages, PAGE)
+        tokens = (tok.row, tok.q_end)
+        tree = dict(q_start=tok.q_start, anc_lo=rows.anc_lo,
+                    anc_hi=rows.anc_hi)
+        held = -(-(context + lens) // PAGE)                  # pages a row
+        pool_pages = int(held.sum()) + 1
+        tables = np.zeros((rows_n, table_pages), np.int32)
+        perm = rng.permutation(pool_pages - 1)
+        for r, (a, b) in enumerate(zip(np.cumsum(held) - held,
+                                       np.cumsum(held))):
+          tables[r, :b - a] = perm[a:b]
+        tables = jnp.asarray(tables)
+        key = jax.random.PRNGKey(args.seed)
+        kq, kk, kv = jax.random.split(key, 3)
+        q = (jax.random.normal(kq, (t, n, HEAD_DIM), jnp.float32)
+             * HEAD_DIM ** -0.5).astype(jnp.bfloat16)
+        kp, vp = (jax.random.normal(k, (pool_pages, PAGE, n_kv, HEAD_DIM),
+                                    jnp.bfloat16) for k in (kk, kv))
+        first = None
+        for name in names:
+          rba = parent if name == "parent" else ragged_block_attend
+          fn = ATTEND_VARIANTS.get(name, _AttendAsBuilt)(rba)
+          plan_key = rba.AttendPlanKey(n, n_kv, HEAD_DIM, PAGE, q.dtype,
+                                       kp.dtype, window=window,
+                                       lowering="pallas")
+          plan = {plan_key: jax.jit(lambda tokens, tree, rba=rba, k=plan_key:
+                                    rba.BuildAttendPlan(
+                                        k, *tokens, tree["q_start"],
+                                        tree["anc_lo"], tree["anc_hi"],
+                                        b=rows_n, t_pages=table_pages))(
+                                            tokens, tree)}
+          # every array an argument: a closed-over pool is a constant of the
+          # program, gigabytes of it
+          call = lambda q, kp, vp, tables, tokens, tree, plan, fn=fn: fn(
+              q, kp, vp, tables, tokens, plan, window=window, **tree, **kw)
+          rest = (kp, vp, tables, tokens, tree, plan)
+          out = jax.block_until_ready(jax.jit(call)(q, *rest))
+          same = None if first is None else bool(jnp.array_equal(out, first))
+          if first is None:
+            first = out
+
+          def _Run(carry, q, *rest, call=call):
+            def _Trip(i, carry):
+              # behind a barrier with the trip's index and the last trip's
+              # output: a trip's call is the trip's, not the loop's
+              _, held_q, _ = jax.lax.optimization_barrier((i, q, carry))
+              return call(held_q, *rest)
+            return jax.lax.fori_loop(0, args.calls, _Trip, carry)
+
+          loop = jax.jit(_Run)
+          jax.block_until_ready(loop(out, q, *rest))             # compiles
+          start = time.perf_counter()
+          jax.block_until_ready(loop(out, q, *rest))
+          ms = (time.perf_counter() - start) * 1e3 / args.calls
+          del out
+          one = lens == 1
+          decode_pairs = rba.LivePairs(plan_key, context[one], lens[one],
+                                       table_pages)
+          chunk_pairs = rba.LivePairs(plan_key, context[~one], lens[~one],
+                                      table_pages)
+          line = {
+              "case": "grouped_attend", "shape": shape, "window": window,
+              "step": step, "variant": name, "ms_a_call": ms,
+              "bitwise_the_first": same, "decode_pairs": decode_pairs,
+              "chunk_pairs": chunk_pairs,
+              "clear_pairs": 0 if name == "masked" else (
+                  ragged_block_attend.ClearPairs(plan_key, context, lens,
+                                                 table_pages)),
+              "heads": n, "kv_heads": n_kv, "pool_pages": pool_pages,
+              "calls": args.calls, "seed": args.seed, "tiny": args.tiny,
+              "device": {"platform": device.platform,
+                         "kind": device.device_kind}}
+          if step == "decode":
+            decode_us[name] = ms * 1e3 / max(decode_pairs, 1)
+            line["us_a_decode_pair"] = decode_us[name]
+          elif name in decode_us:
+            line["us_a_chunk_pair"] = (
+                ms * 1e3 - decode_us[name] * decode_pairs) / chunk_pairs
+          lines.append(line)
+          print(json.dumps(line), flush=True)
+          _Append(args.out, [line])     # a line at a time: a long case
+        del first, kp, vp
+  return 0 if all(l["bitwise_the_first"] is not False for l in lines) else 1
+
+
 def _Append(path, lines):
   if path:
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -424,10 +622,12 @@ def _Append(path, lines):
 def main(argv=None) -> int:
   ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
   ap.add_argument("--case", default="page_write",
-                  choices=["page_write", "row_pass", "moe_combine"])
+                  choices=["page_write", "row_pass", "moe_combine",
+                           "grouped_attend"])
   ap.add_argument("--steps", default="decode,chunk")
   ap.add_argument("--variants", default="")
   ap.add_argument("--shapes", default="")
+  ap.add_argument("--chunk_at", default="3k,12k,30k")
   ap.add_argument("--tiny", action="store_true")
   ap.add_argument("--parent", default="")
   ap.add_argument("--calls", type=int, default=50)
@@ -435,6 +635,10 @@ def main(argv=None) -> int:
   ap.add_argument("--pool_pages", type=int, default=6561)
   ap.add_argument("--out", default="")
   args = ap.parse_args(argv)
+  if args.case == "grouped_attend":
+    args.shapes = args.shapes or ",".join(ATTEND_SHAPES)
+    args.variants = args.variants or ",".join(ATTEND_VARIANTS)
+    return AttendMain(args)
   if args.case == "moe_combine":
     args.shapes = args.shapes or ",".join(COMBINE_SHAPES)
     args.variants = args.variants or ",".join(COMBINE_VARIANTS)
