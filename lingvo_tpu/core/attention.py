@@ -168,13 +168,18 @@ class PerDimScaleLayer(base_layer.BaseLayer):
 
 
 def KvPagePool(num_pages: int, page_size: int, num_kv_heads: int,
-               dim_per_head: int, dtype, quantized: bool = False) -> NestedMap:
+               dim_per_head: int, dtype, quantized: bool = False,
+               tile_heads: int = 1) -> NestedMap:
   """The leaves of a pool of K/V pages: `key` and `value` [num_pages,
   page_size, KV heads, H] and, quantized, their f32 scale sidecars
   [num_pages, KV heads, page_size] (transposed so that the Pallas scale
   block's minor dimension is page_size: lingvo_tpu/quant/kv.py). Every mixer
-  that keeps K and V in pages declares them here."""
-  n, h = num_kv_heads, dim_per_head
+  that keeps K and V in pages declares them here. tile_heads r > 1: a
+  token's row holds r KV heads side by side, [num_pages, page_size, KV heads
+  / r, r * H], the same numbers in the same order (ops/ragged_block_attend.
+  TileHeads says when and why)."""
+  assert num_kv_heads % tile_heads == 0 and not (quantized and tile_heads > 1)
+  n, h = num_kv_heads // tile_heads, dim_per_head * tile_heads
   pool = NestedMap(key=jnp.zeros((num_pages, page_size, n, h), dtype),
                    value=jnp.zeros((num_pages, page_size, n, h), dtype))
   if quantized:
@@ -877,11 +882,19 @@ class MultiHeadedAttention(base_layer.BaseLayer):
     p = self.p
     if jax.default_backend() == "tpu":
       from lingvo_tpu.ops import block_decode
-      if not block_decode.SupportedOnTpu(page_size, self._dim_per_head):
+      # what tiles the lanes is a token's row of the pool: a head, or the
+      # heads it holds side by side (`_PoolTileHeads`)
+      if not block_decode.SupportedOnTpu(
+          page_size, self._dim_per_head * self._PoolTileHeads()):
         return False
     return (page_size > 0 and p.rel_pos_emb_dim == 0
             and p.atten_logit_cap == 0 and p.atten_dropout_prob == 0.0
             and p.qdomain_softmax is None)
+
+  def _PoolTileHeads(self) -> int:
+    """KV heads a token's row of this layer's pool holds side by side (1:
+    the pool is [pages, P, KV heads, H]); PooledAttention says otherwise."""
+    return 1
 
   def QuantizedDecodeEligible(self, page_size: int) -> bool:
     """Whether the int8 block-table kernels can serve this layer: the
@@ -1117,9 +1130,12 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         v_scale = cached_states.value_scale.at[phys, :, tokens.off].set(
             v_s[0])
     with observe.Scope("kv_write"):
+      # a token's K and V as the pool's row holds them: a KV head a row, or
+      # the heads it lays side by side (the same numbers in the same order)
       k_pool, v_pool = run_write.WriteRuns(
-          k_pool, v_pool, k_new[0].astype(k_pool.dtype),
-          v_new[0].astype(v_pool.dtype),
+          k_pool, v_pool,
+          k_new[0].astype(k_pool.dtype).reshape((-1,) + k_pool.shape[2:]),
+          v_new[0].astype(v_pool.dtype).reshape((-1,) + v_pool.shape[2:]),
           tables[runs.row, runs.logical] + base, runs)
     tables = tables + base
     new_states = NestedMap(key=k_pool, value=v_pool)
@@ -1146,6 +1162,8 @@ class MultiHeadedAttention(base_layer.BaseLayer):
       # logit cap / dropout / prob quant exactly like PagedStep's)
       k_dense = block_decode.GatherPages(k_pool, tables)
       v_dense = block_decode.GatherPages(v_pool, tables)
+      k_dense, v_dense = (x.reshape(x.shape[:-2] + k_new.shape[2:])
+                          for x in (k_dense, v_dense))
       if quantized:
         k_dense = kv_quant.DequantKv(
             k_dense, block_decode.GatherScales(k_scale, tables))
@@ -1419,7 +1437,13 @@ class PooledAttention(MultiHeadedAttention):
     class's K and V, in the fprop dtype."""
     RequireFloatPages(kv_cache_dtype, "PooledAttention in a BlockSequence")
     return KvPagePool(num_pages, page_size, self._num_kv_heads,
-                      self._dim_per_head, self.fprop_dtype)
+                      self._dim_per_head, self.fprop_dtype,
+                      tile_heads=self._PoolTileHeads())
+
+  def _PoolTileHeads(self) -> int:
+    from lingvo_tpu.ops import ragged_block_attend
+    return ragged_block_attend.TileHeads(
+        self.p.num_heads, self._num_kv_heads, self._dim_per_head)
 
   def RaggedMix(self, theta, x, states, shared, rows, table=None, depth=0,
                 plan=None):

@@ -944,3 +944,121 @@ class Mamba2Layer(base_layer.BaseLayer):
       gated = self._GateNorm(th, y, z)
     with observe.Scope("ssd_out_proj"):
       return jnp.einsum("...e,ed->...d", gated, th.w_out)
+
+
+class ShortConvLayer(base_layer.BaseLayer):
+  """A gated short convolution and nothing else (the LFM2 family's `conv`
+  operator) for `transformer.BlockSequence`: no scan, no pages, no
+  activation, no bias.
+
+  x [.., D]; K taps over the D channels, depthwise and causal:
+
+      [B; C; X] = x W_in                                   D -> 3 D, that order
+      u = B * X                                            the gate going in
+      c_t = sum_{k<K} w_conv[k] * u_{t-K+1+k}              depthwise
+      y = C * c                                            the gate coming out
+      out = y W_out                                        D -> D
+
+  What a sequence carries from token to token is the last K - 1 rows of u.
+  Serving keeps them a slot (`InitPagedStates`: `conv` [slots, K - 1, D],
+  f32), zeroed where a row starts a request and carried from one chunk of a
+  prompt to the next, exactly as the Mamba layers keep their tail: the
+  packed sum and the tail are theirs (`_PackedConv`, `_PackedConvTail`,
+  `_FreshTail`), with gates on both sides where they have a bias and a
+  silu. The leaf is the engine's to move with the rest of a slot's state
+  (spill, restore, hand-off). The gates and the sum run in f32; the two
+  projections in the fprop dtype.
+  """
+
+  # the engine counter of (live rows x layers) whose tail a step rewrote
+  # (serving/engine.py, observe/schema.py)
+  state_rows_counter = "conv_tail_rows"
+
+  @classmethod
+  def Params(cls):
+    p = super().Params()
+    p.Define("input_dim", 0, "Model dim (set by the wrapping layer).")
+    p.Define("conv_width", 3, "Taps K of the causal depthwise convolution.")
+    return p
+
+  def __init__(self, params):
+    super().__init__(params)
+    p = self.p
+    assert p.input_dim > 0 and p.conv_width > 1
+    d, k = p.input_dim, p.conv_width
+    self.CreateVariable("w_in", WeightParams((d, 3 * d), p.params_init,
+                                             p.dtype))
+    self.CreateVariable("conv_w", WeightParams(
+        (k, d), WeightInit.Uniform(k ** -0.5), p.dtype))
+    self.CreateVariable("w_out", WeightParams((d, d), p.params_init, p.dtype))
+
+  def StateBytesPerSlot(self) -> int:
+    """The convolution tail of one sequence, f32."""
+    return 4 * (self.p.conv_width - 1) * self.p.input_dim
+
+  def _Gates(self, th, x):
+    """x [.., D] -> (u = B * X [.., D] f32, C [.., D] f32)."""
+    b, c, xx = jnp.split(
+        jnp.einsum("...d,df->...f", x, th.w_in).astype(jnp.float32), 3,
+        axis=-1)
+    return b * xx, c
+
+  def FProp(self, theta, x, shared, paddings=None, segment_ids=None,
+            depth=None):
+    """x: [B, T, D] -> ([B, T, D], shared)."""
+    del depth
+    if segment_ids is not None:
+      raise NotImplementedError(
+          "ShortConvLayer.FProp does not cut its taps between packed segments")
+    th = self.CastTheta(theta)
+    k, t = self.p.conv_width, x.shape[1]
+    with observe.Scope("short_conv"):
+      u, c = self._Gates(th, x)
+      if paddings is not None:
+        u = u * (1.0 - paddings.astype(jnp.float32))[..., None]
+      with observe.Scope("short_conv_taps"):
+        padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+        w = th.conv_w.astype(jnp.float32)
+        conv = sum(w[i] * padded[:, i:i + t] for i in range(k))
+      out = jnp.einsum("...e,ed->...d", (c * conv).astype(self.fprop_dtype),
+                       th.w_out)
+    if paddings is not None:
+      out = py_utils.ApplyPadding(paddings, out)
+    return out, shared
+
+  # -- continuous-batching serving -------------------------------------------
+
+  def InitPagedStates(self, theta, num_slots: int) -> NestedMap:
+    del theta
+    assert num_slots > 0, "ShortConvLayer keeps a tail a slot"
+    p = self.p
+    return NestedMap(conv=jnp.zeros(
+        (num_slots, p.conv_width - 1, p.input_dim), jnp.float32))
+
+  def RaggedMix(self, theta, x, states, shared, rows, table=None,
+                depth=None, plan=None):
+    """x: [1, T, D] packed tokens (core/ragged.RaggedRows, chains only) ->
+    ((y [1, T, D],), new states, shared): everything up to the output
+    projection, over the rows the step holds."""
+    del table, depth
+
+    def _Gated(x, row_of, col_of):
+      th = self.CastTheta(theta)
+      u, c = self._Gates(th, x)
+      with observe.Scope("short_conv_taps"):
+        conv = _PackedConv(u, states.conv, th.conv_w,
+                           rows._replace(row_of=row_of, col_of=col_of))
+      return u, (c * conv).astype(self.fprop_dtype)
+
+    with observe.Scope("short_conv"):
+      u, y = ragged.OverLiveRows(_Gated, plan, x[0], rows.row_of,
+                                 rows.col_of, axis=0)
+      with observe.Scope("short_conv_taps"):
+        new_tail = _PackedConvTail(u, _FreshTail(states.conv, rows), rows)
+    return (y[None],), NestedMap(conv=new_tail), shared
+
+  def RaggedOut(self, theta, y, depth=None):
+    """What follows the taps, row by row: the output projection."""
+    del depth
+    with observe.Scope("short_conv"):
+      return jnp.einsum("...e,ed->...d", y, self.CastTheta(theta).w_out)

@@ -18,6 +18,7 @@ try:
   from lingvo_tpu.models.lm.params import phi4flash  # noqa: F401
   from lingvo_tpu.models.lm.params import smallthinker  # noqa: F401
   from lingvo_tpu.models.lm.params import trinity  # noqa: F401
+  from lingvo_tpu.models.lm.params import lfm2  # noqa: F401
 except ImportError:
   pass
 try:

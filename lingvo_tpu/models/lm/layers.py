@@ -99,16 +99,20 @@ class TransformerLm(base_model.BaseTask):
         "over everything that owns its pages, and no feed-forward), "
         "'experts' (expert_ffn_tpl, a core/moe.DroplessMoELayer whose "
         "router reads its own normed input, and no mixer), 'gqa_window' "
-        "(as 'gqa', within sliding_window_size and rotated by rope_theta). "
+        "(as 'gqa', within sliding_window_size and rotated by rope_theta), "
+        "'gqa_rope' (as 'gqa', over everything, rotated by rope_theta), "
+        "'short_conv' (an ssm.ShortConvLayer: a gated short convolution "
+        "whose state is its tail a slot; it reads no template). "
         "A name that says mixer and feed-forward apart, '<mixer>+dense' or "
         "'<mixer>+experts', is ONE layer of both: the mixer one of those "
-        "that may stand alone ('mamba2', 'gqa', 'gqa_window'), then the "
+        "that may stand alone ('mamba2', 'gqa', 'gqa_window', 'gqa_rope', "
+        "'short_conv'), then the "
         "dense feed-forward (hidden_dim) or the expert layer, so leading "
         "dense layers before expert ones are ['gqa_window+dense', "
         "'gqa_window+experts', ...]. Stretches "
         "that repeat are scanned, what lies between them is a block of "
-        "its own (transformer.BlockSequence); no layer but a 'retention' "
-        "and a 'gqa_window' one carries a position. "
+        "its own (transformer.BlockSequence); no layer but a 'retention', "
+        "a 'gqa_window' and a 'gqa_rope' one carries a position. "
         "None = the layouts below.")
     p.Define(
         "hybrid_override_pattern", None,
@@ -279,8 +283,9 @@ class TransformerLm(base_model.BaseTask):
     kinds = {mixer for mixer, _ in halves}
     has_experts = any("experts" in h for h in halves)
     # the templates a kind reads
-    reads_atten = {"window", "full", "cross", "gqa", "gqa_window"}
-    assert p.mixer_tpl is not None or kinds <= reads_atten | {"experts"}, kinds
+    reads_atten = {"window", "full", "cross", "gqa", "gqa_window", "gqa_rope"}
+    assert p.mixer_tpl is not None or kinds <= reads_atten | {
+        "experts", "short_conv"}, kinds
     assert p.atten_tpl is not None or not kinds & reads_atten, kinds
     assert p.num_experts == 0
     assert (p.expert_ffn_tpl is not None) == has_experts
@@ -306,6 +311,10 @@ class TransformerLm(base_model.BaseTask):
         "gqa_window": lambda: atten.Copy().Set(
             window=p.sliding_window_size, use_rotary_position_emb=True,
             rope_max_timescale=p.rope_theta),
+        "gqa_rope": lambda: atten.Copy().Set(
+            window=0, use_rotary_position_emb=True,
+            rope_max_timescale=p.rope_theta),
+        "short_conv": ssm_lib.ShortConvLayer.Params,
     }
     assert p.sliding_window_size > 0 or not kinds & {"window", "gqa_window"}
     layer = transformer_lib.SharedStateLayer.Params().Set(

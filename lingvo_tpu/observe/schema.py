@@ -107,6 +107,14 @@ ENGINE_COUNTER_KEYS = (
     # `ssd_narrow_rows / ssd_state_rows` is the share of the live (row,
     # layer) pairs that take it. Both 0 on a stack without such layers
     "ssd_state_rows", "ssd_narrow_rows",
+    # gated short-convolution layers (core/ssm.ShortConvLayer), counted when a
+    # step is dispatched: live rows times the layers whose convolution tail
+    # the step rewrote; and, over EVERY mixer of the stack that keeps a state
+    # a slot (that tail, a Mamba layer's scan state and tail, a retention
+    # layer's state), the bytes of it the step's live rows read and wrote
+    # (`StateBytesPerSlot`, once in and once out). The first is 0 on a stack
+    # without such layers, the second on one without slot state
+    "conv_tail_rows", "slot_state_bytes",
 )
 
 # Static engine configuration facts (set once at construction). `head_rows`:
@@ -451,6 +459,12 @@ DEVICE_SCOPES = {
                    "(`output_gate`): the gate's own projection of the "
                    "layer's input, the sigmoid and the product with the "
                    "attend's output, before the output projection"),
+    "short_conv": ("atten", "a gated short-convolution mixer, whole "
+                   "(core/ssm.ShortConvLayer): the input projection to B, C "
+                   "and X, the two gates and the output projection"),
+    "short_conv_taps": ("short_conv", "inside it, what is not a matmul or a "
+                        "gate: the packed causal depthwise sum, the slot "
+                        "tail's gather and its write-back"),
     "post_norm": (None, "a norm on a branch's OUTPUT before the residual "
                   "add (`post_norm_tpl`), entered inside `atten` for the "
                   "mixer's and inside `ffn` for the feed-forward's (dense or "

@@ -222,6 +222,18 @@ def Grouped(n: int, n_kv: int) -> bool:
   return n != n_kv
 
 
+def TileHeads(n: int, n_kv: int, h: int) -> int:
+  """KV heads a token's row of the pool holds side by side on its lanes,
+  `[pages, P, n_kv / r, r * h]`: 2 where the grouped kernel serves heads of
+  half a lane tile (64), which it attends a pair a tile (the pairs one, or
+  an even number: `_HeadPages`); else 1, the pool as `[pages, P, n_kv, h]`. A
+  function of shapes alone, the same on every backend: a pool's layout is its
+  owner's word (PooledAttention.PagePool), and `RaggedAttend` and the runs'
+  write read it off the pool's shape."""
+  return 2 if Grouped(n, n_kv) and 2 * h == LANES and (
+      n_kv == 2 or n_kv % 4 == 0) else 1
+
+
 def Lowering(lowering: str) -> str:
   """'auto' resolved: the Pallas kernel on a TPU, the XLA twin elsewhere."""
   assert lowering in ("auto", "pallas", "xla"), lowering
@@ -805,7 +817,7 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
                          tables_ref, n_ref, first_ref, end0_ref, start0_ref,
                          lo0_ref, hi0_ref, clear_lo_ref, clear_ref, *rest,
                          page_size: int, window: int, heads: int,
-                         rungs: tuple[int, ...]):
+                         rungs: tuple[int, ...], tile_heads: int = 1):
   """The (query block, logical page) program where a KV head serves a GROUP
   of query heads: the group rides the packed axis (RaggedAttend), so a
   block is up to Bq queries of which each has one vector per KV head. q and
@@ -852,12 +864,29 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
   pages clear; the same body over `[rows, 1]` statistics LOST (4.18-4.20 us:
   the mask's passes cost less than the slices and broadcasts it is then left
   with), the rows in halves or quarters gained 4-7% more at two and four
-  times the traced body."""
+  times the traced body.
+
+  Heads of HALF a lane tile (tile_heads 2, head size 64): the pool holds two
+  KV heads side by side on a token's row (`TileHeads`), `heads` counts those
+  rows, and a row of `h` lanes here is the pair's. Nothing is sliced inside a
+  tile: each head of the pair runs the SAME two products over the whole row,
+  its queries with the tile-mate's lanes zeroed (made once a block, at its
+  first page: `qh[j]`), so `q . k` sums its own 64 dims and exact zeros, and
+  `p . v` comes back a row wide of which its own lanes are kept; the pair's
+  accumulator stays one row, each half scaled by its own head's `alpha`. The
+  statistics are a head's, `heads * tile_heads` of them. The MXU does a head
+  of 128's work for a head of 64 (its columns are 128 either way); the page's
+  bytes are the heads' own."""
   pair = pl.program_id(0)
   i, page = blk_ref[pair], page_ref[pair]
   q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
   h = qb.shape[1] // heads
   nv = n_ref[i]
+  if tile_heads > 1:
+    # which head of its row a lane is, over a row and over the block's width
+    head_of = lambda width: (jax.lax.broadcasted_iota(
+        jnp.int32, (1, width), 1) // (h // tile_heads)) % tile_heads
+    lane_head, lane_head_all = head_of(h), head_of(heads * h)
   # a token's group is padded to whole sublane tiles (RaggedAttend), so a
   # block starts on one: the packed axis is the tiled one here
   first = pl.multiple_of(first_ref[i], SUBLANES)
@@ -876,9 +905,15 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
     @pl.when(page == page0_ref[i])
     def _Init():
       _Copy(q_hbm.at[window_q], qb.at[held])
-      qh[held] = qb[held].astype(qh.dtype)
-      mb[:, held] = jnp.full((heads, rows, LANES), NEG_INF, mb.dtype)
-      lb[:, held] = jnp.zeros((heads, rows, LANES), lb.dtype)
+      if tile_heads == 1:
+        qh[held] = qb[held].astype(qh.dtype)
+      else:
+        for j in range(tile_heads):
+          qh[j, held] = jnp.where(lane_head_all == j, qb[held],
+                                  0.0).astype(qh.dtype)
+      mb[:, held] = jnp.full((heads * tile_heads, rows, LANES), NEG_INF,
+                             mb.dtype)
+      lb[:, held] = jnp.zeros((heads * tile_heads, rows, LANES), lb.dtype)
       accb[held] = jnp.zeros((rows, heads * h), accb.dtype)
 
     def _Page(masked: bool):
@@ -894,9 +929,23 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
       keys, values = _HeadPages(k_ref, heads), _HeadPages(v_ref, heads)
       # a clear page's statistics ride as the scratch holds them, lane-
       # replicated, where a page is as wide as they are
-      stat = slice(None) if not masked and page_size == LANES else slice(1)
+      stat = slice(None) if (not masked and page_size == LANES
+                             and h % LANES == 0) else slice(1)
       for g in range(heads):
         lanes = pl.ds(g * h, h)
+        if tile_heads > 1:
+          acc_row = new_row = accb[held, lanes]
+          for j in range(tile_heads):
+            hd = g * tile_heads + j
+            m, l, acc = _BlockPageAttend(
+                qh[j, held, lanes], keys[g], values[g], keep,
+                mb[hd, held, stat], lb[hd, held, stat], acc_row,
+                (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+            mb[hd, held] = jnp.broadcast_to(m, (rows, LANES))
+            lb[hd, held] = jnp.broadcast_to(l, (rows, LANES))
+            new_row = jnp.where(lane_head == j, acc, new_row)
+          accb[held, lanes] = new_row
+          continue
         m, l, acc = _BlockPageAttend(
             qh[held, lanes], keys[g], values[g], keep, mb[g, held, stat],
             lb[g, held, stat], accb[held, lanes], (((1,), (1,)), ((), ())),
@@ -922,7 +971,12 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
         mine = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < nv
       for g in range(heads):
         lanes = pl.ds(g * h, h)
-        out = _Finish(lb[g, held, :1], accb[held, lanes], qb.dtype)
+        out = _Finish(lb[g * tile_heads, held, :1], accb[held, lanes],
+                      qb.dtype)
+        for j in range(1, tile_heads):
+          out = jnp.where(lane_head == j, _Finish(
+              lb[g * tile_heads + j, held, :1], accb[held, lanes], qb.dtype),
+                          out)
         if two_bodies:
           out = jnp.where(mine, out, jnp.zeros((), out.dtype))
         qb[held, lanes] = out
@@ -966,14 +1020,16 @@ def _Prefetch(blocks: AttendPlan, tables) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "page_size", "heads", "window", "rungs", "interpret"))
+    "page_size", "heads", "window", "rungs", "interpret", "tile_heads"))
 def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
                  page_size: int, heads: int, window: int,
-                 rungs: tuple[int, ...], interpret: bool):
+                 rungs: tuple[int, ...], interpret: bool,
+                 tile_heads: int = 1):
   """_GroupedAttendKernel over _PallasRaggedAttend's grid and descriptors.
   pairs: [] the grid's length; q: [T + Bq, Nkv * H] f32; cols: [NB, Bq, 4];
   pages as rows [NP, P * Nkv, H] -> the output, [T + Bq, Nkv * H] f32, zeros
-  where no block wrote.
+  where no block wrote. tile_heads 2: `heads` rows a token of two KV heads
+  each, `[NP, P * Nkv / 2, 2 H]` (`TileHeads`).
 
   A `jit` of its own: a kernel's body is traced anew at every
   `pallas_call`, a rung of this one costs 0.4-0.6 s on the benchmark's host,
@@ -991,7 +1047,8 @@ def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
   with observe.Scope("ragged_attend"):
     return pl.pallas_call(
         functools.partial(_GroupedAttendKernel, page_size=page_size,
-                          window=window, heads=heads, rungs=rungs),
+                          window=window, heads=heads, rungs=rungs,
+                          tile_heads=tile_heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(pairs,),
@@ -1005,9 +1062,10 @@ def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
             out_specs=hbm,
             scratch_shapes=[
                 pltpu.VMEM((bq, heads * h), jnp.float32),
-                pltpu.VMEM((bq, heads * h), k_pages.dtype),
-                pltpu.VMEM((heads, bq, LANES), jnp.float32),
-                pltpu.VMEM((heads, bq, LANES), jnp.float32),
+                pltpu.VMEM(((tile_heads,) if tile_heads > 1 else ()) + (
+                    bq, heads * h), k_pages.dtype),
+                pltpu.VMEM((heads * tile_heads, bq, LANES), jnp.float32),
+                pltpu.VMEM((heads * tile_heads, bq, LANES), jnp.float32),
                 pltpu.VMEM((bq, heads * h), jnp.float32),
                 pltpu.SemaphoreType.DMA(()),
             ]),
@@ -1037,7 +1095,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
   holds no block): block i + 1 starts where block i's queries end, so its
   window overwrites the zeros block i left past its own."""
   t, n, h = q.shape
-  np_total, page, _, _ = k_pool.shape
+  np_total, page, pool_heads, row = k_pool.shape
   assert page == page_size, (page, page_size)
   b = block_tables.shape[0]
   tables = jnp.clip(block_tables.astype(jnp.int32), 0, np_total - 1)
@@ -1046,12 +1104,14 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
       "descriptors of another pack", blocks.cols.shape, (b, t))
   prefetch = _Prefetch(blocks, tables) + blocks.col0
   if grouped:
+    # the pool's own rows: a KV head each, or two side by side (TileHeads)
     out = _GroupedCall(
         blocks.pairs, prefetch + (blocks.clear_lo, blocks.clear),
         jnp.pad(q.reshape(t, n * h).astype(jnp.float32), ((0, bq), (0, 0))),
-        blocks.cols, k_pool.reshape(np_total, page * n, h),
-        v_pool.reshape(np_total, page * n, h), page_size=page_size, heads=n,
-        window=window, rungs=BlockRungs(bq, grouped), interpret=interpret)
+        blocks.cols, k_pool.reshape(np_total, page * pool_heads, row),
+        v_pool.reshape(np_total, page * pool_heads, row), page_size=page_size,
+        heads=pool_heads, window=window, rungs=BlockRungs(bq, grouped),
+        interpret=interpret, tile_heads=n // pool_heads)
     return out[:t].astype(q.dtype).reshape(t, n, h)
   page_idx, cols_idx = _PairIndexMaps(3)
   hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -1158,7 +1218,10 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
   if k_scale is not None:
     assert k_pool.dtype == jnp.int8, k_pool.dtype
   t, n, h = q.shape
-  n_kv = k_pool.shape[2]
+  # a token's row of the pool holds `tile` KV heads side by side (TileHeads)
+  tile = k_pool.shape[3] // h
+  assert k_pool.shape[3] == tile * h, (k_pool.shape, h)
+  n_kv = k_pool.shape[2] * tile
   assert n % n_kv == 0, (n, n_kv)
   group = n // n_kv
   key = AttendPlanKey(n, n_kv, h, page_size, q.dtype, k_pool.dtype,
@@ -1168,13 +1231,21 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
   # tile: the group is padded with zero queries of the token's own horizon
   # (computed, dropped)
   grouped = key.kernel and Grouped(n, n_kv)
-  if grouped and (k_scale is not None or h % LANES or (
-      k_pool.dtype.itemsize == 2 and n_kv > 1 and n_kv % 2)):
+  if interpret is None:
+    interpret = jax.default_backend() != "tpu"
+  pool_heads = n_kv // tile
+  # a token's row of the pool is whole lane tiles (the interpreter takes a
+  # pair's row of any width: the tests' heads of 16)
+  tiled = (tile * h) % LANES == 0 or (tile == 2 and interpret)
+  if grouped and (k_scale is not None or not tiled or tile > 2 or (
+      k_pool.dtype.itemsize == 2 and pool_heads > 1 and pool_heads % 2)):
     raise NotImplementedError(
         f"the Pallas lowering serves {n} query heads over {n_kv} KV heads "
         "from f32 pages, or bf16 pages of one or an even number of KV "
-        f"heads, whose heads tile the lanes; got {k_pool.dtype} pages, head "
-        f"size {h}" + (", int8 scales" if k_scale is not None else ""))
+        "heads, whose heads tile the lanes, one a tile or (a head size of "
+        f"64) two side by side in the pool's row; got {k_pool.dtype} pages "
+        f"{tuple(k_pool.shape[2:])} a token, head size {h}" + (
+            ", int8 scales" if k_scale is not None else ""))
   lanes = key.lanes
   if group > 1:
     # [T, Nkv, G, H] -> [T * G', Nkv, H]: the group beside the tokens
@@ -1188,6 +1259,9 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
     tokens = [jnp.asarray(x) for x in tokens]
     if group > 1:
       tokens = [jnp.repeat(x, lanes) for x in tokens]
+    if tile > 1:
+      k_pool, v_pool = (x.reshape(x.shape[:2] + (n_kv, h))
+                        for x in (k_pool, v_pool))
     out = _XlaRaggedAttend(q, k_pool, v_pool, block_tables, *tokens[:2],
                            page_size, k_scale, v_scale, *tokens[2:],
                            window=key.window)
@@ -1199,8 +1273,6 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
             b=block_tables.shape[0], t_pages=block_tables.shape[1])
     else:
       blocks = plan[key]
-    if interpret is None:
-      interpret = jax.default_backend() != "tpu"
     out = _PallasRaggedAttend(
         q, k_pool, v_pool, block_tables, blocks, page_size,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,

@@ -596,6 +596,15 @@ class ServingLoop:
     self._ssd_layers = sum(
         reps for m, reps in self._mixer_layers
         if getattr(m, "state_rows_counter", None) == "ssd_state_rows")
+    # layers whose slot state is a convolution tail alone (ShortConvLayer),
+    # and the bytes of slot state a live row reads and writes a step over
+    # every mixer that keeps one
+    self._conv_tail_layers = sum(
+        reps for m, reps in self._mixer_layers
+        if getattr(m, "state_rows_counter", None) == "conv_tail_rows")
+    self._slot_state_bytes_a_row = 2 * sum(
+        reps * m.StateBytesPerSlot() for m, reps in self._mixer_layers
+        if hasattr(m, "StateBytesPerSlot"))
     # layers that read pages another layer owns (0: the stack has none)
     self._shared_kv_read_layers = getattr(
         task.stack, "SharedKvReadLayers", lambda: 0)()
@@ -1299,6 +1308,9 @@ class ServingLoop:
     if self._retention_layers:
       out.update((k, self._counters[k].value) for k in (
           "retention_rows", "retention_folds", "retention_chunk_tokens"))
+    if self._conv_tail_layers:
+      out.update((k, self._counters[k].value) for k in (
+          "conv_tail_rows", "slot_state_bytes"))
     if self._attend_clear_keys:
       out.update((k, self._counters[k].value) for k in (
           "attend_live_pairs", "attend_clear_pairs"))
@@ -1325,6 +1337,11 @@ class ServingLoop:
       # ... and those whose row is one token: the row pass's narrow body
       self._counters["ssd_narrow_rows"].Inc(
           self._ssd_layers * int((row_len == 1).sum()))
+    if self._slot_state_bytes_a_row:
+      live = int((row_len > 0).sum())
+      self._counters["conv_tail_rows"].Inc(self._conv_tail_layers * live)
+      self._counters["slot_state_bytes"].Inc(
+          self._slot_state_bytes_a_row * live)
     if self._retention_layers:
       # power-retention layers: rows with a state, pages folded into one and
       # keys attended in open chunks, a layer (before the cursors advance)

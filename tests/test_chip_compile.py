@@ -80,6 +80,16 @@ _REPO_AGENT_SERVING = dict(t=1088, n=32, n_kv=4, h=128, page=128,
                            table_pages=272, pool_pages=10001, rows=64)
 
 
+# The grouped kernel and the runs' write at the shapes
+# `lfm2_24b_serve_chat_wide` runs them at: 256 slots + a 1,024-token budget
+# (1,280 packed rows: the widest pack a cell has), 32 query heads over 8 KV
+# heads of 64, TWO KV heads side by side on a token's row of the pool (`row`:
+# the pool is [pages, 128, 4, 128], ops/ragged_block_attend.TileHeads), the
+# two layers' one pool of 2 x 4,600 pages and the trash page, 76 pages a row.
+_CHAT_WIDE_SERVING = dict(t=1280, n=32, n_kv=8, h=64, row=2, page=128,
+                          table_pages=76, pool_pages=9201, rows=256)
+
+
 def _RetentionServingArgs(d=_RETENTION_SERVING):
   import jax.numpy as jnp
   from lingvo_tpu.core import ragged
@@ -109,10 +119,12 @@ def _RunWriteServingArgs(d, kv_dtype=None):
   from lingvo_tpu.core import ragged
   sds = jax.ShapeDtypeStruct
   i32 = jnp.int32
-  n = d.get("n_kv", d["n"])
+  # a token's row of the pool: a KV head, or `row` of them side by side
+  n = d.get("n_kv", d["n"]) // d.get("row", 1)
+  h = d["h"] * d.get("row", 1)
   dtype = kv_dtype or jnp.bfloat16
-  pool = sds((d["pool_pages"], d["page"], n, d["h"]), dtype)
-  new = sds((d["t"], n, d["h"]), dtype)
+  pool = sds((d["pool_pages"], d["page"], n, h), dtype)
+  new = sds((d["t"], n, h), dtype)
   tok, row = sds((d["t"],), i32), sds((d["rows"],), i32)
   cols = sds((d["rows"], d["t"] - d["rows"] + 1), i32)
   rows = ragged.RaggedRows(
@@ -125,7 +137,9 @@ def _RunWriteServingArgs(d, kv_dtype=None):
 def _GroupedServingArgs(d=_GROUPED_SERVING):
   import jax.numpy as jnp
   sds = jax.ShapeDtypeStruct
-  pool = sds((d["pool_pages"], d["page"], d["n_kv"], d["h"]), jnp.bfloat16)
+  row = d.get("row", 1)
+  pool = sds((d["pool_pages"], d["page"], d["n_kv"] // row, d["h"] * row),
+             jnp.bfloat16)
   tok = sds((d["t"],), jnp.int32)
   return (sds((d["t"], d["n"], d["h"]), jnp.bfloat16), pool, pool,
           sds((d["rows"], d["table_pages"]), jnp.int32), tok, tok)
@@ -209,6 +223,12 @@ def compiles():
       futures["run_write_serving_repo_agent"] = pool.submit(
           _Compile, _CASES["run_write"],
           _RunWriteServingArgs(_REPO_AGENT_SERVING))
+      futures["grouped_serving_chat_wide"] = pool.submit(
+          _Compile, _CASES["ragged_attend_grouped"],
+          _GroupedServingArgs(_CHAT_WIDE_SERVING))
+      futures["run_write_serving_chat_wide"] = pool.submit(
+          _Compile, _CASES["run_write"],
+          _RunWriteServingArgs(_CHAT_WIDE_SERVING))
       yield futures
   finally:
     jax.config.update("jax_enable_compilation_cache", True)
@@ -279,15 +299,17 @@ def test_ragged_attend_compiles_at_serving_shapes(variant, compiles):
 
 
 @pytest.mark.parametrize("variant", sorted(_GROUPED_CASES) + [
-    "agent", "repo_agent_full", "repo_agent_window"])
+    "agent", "repo_agent_full", "repo_agent_window", "chat_wide"])
 def test_grouped_attend_compiles_at_serving_shapes(variant, compiles):
-  # every rung of the ladder is a branch of the one program Mosaic lowers
+  # every rung of the ladder is a branch of the one program Mosaic lowers:
+  # a decode row's 8 rows and a chunk's 512 with its clear and masked bodies
+  # (`chat_wide`: heads of 64, a pair a row of the pool)
   assert "tpu_custom_call" in compiles[f"grouped_serving_{variant}"].result(
       timeout=300)
 
 
 @pytest.mark.parametrize("cell", ["docs", "docs_int8", "mixed", "agent",
-                                  "repo_agent"])
+                                  "repo_agent", "chat_wide"])
 def test_run_write_compiles_at_serving_shapes(cell, compiles):
   # token rows of 16, 4 and 2 KV heads (4 KB, 1 KB and 512 B of bf16) and of
   # int8: each a whole number of the tiles Mosaic lays that pool out in

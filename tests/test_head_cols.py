@@ -77,6 +77,7 @@ _NEWER_FAMILIES = {
     "mistral4": lambda dtype: _Registered(
         "lm.mistral4.MistralSmall4Tiny", dtype),
     "trinity": lambda dtype: _Registered("lm.trinity.TrinityTiny", dtype),
+    "lfm2": lambda dtype: _Registered("lm.lfm2.Lfm2Tiny", dtype),
 }
 
 

@@ -465,7 +465,8 @@ class TestScopeNames:
 # -- the one declared tree (observe.schema.DEVICE_SCOPES) ---------------------
 
 _PROGRAMS = ("dense_train", "dense", "smallthinker", "phi4flash",
-             "nemotron_h", "brumby", "mistral4", "trinity", "pallas_attend")
+             "nemotron_h", "brumby", "mistral4", "trinity", "lfm2",
+             "pallas_attend")
 
 
 def _Avals(tree):

@@ -608,12 +608,13 @@ class TestGroupedClearPages:
     assert diff.kernel and diff.lanes == 8 and not diff.clear
 
 
-@pytest.mark.parametrize("shape", ["smallthinker", "trinity"])
+@pytest.mark.parametrize("shape", ["smallthinker", "trinity", "lfm2"])
 def test_the_kernel_probe_holds_the_clear_body_to_the_masked_one(shape,
                                                                  capsys):
   """tools/kernel_probe.py --case grouped_attend at the CPU's rehearsal
   sizes: a full and a window layer, a decode-only step and one with a chunk
-  whose blocks hold clear pages; `clear` is bitwise `masked`."""
+  whose blocks hold clear pages; `clear` is bitwise `masked` (`lfm2`: heads
+  of 64, two KV heads a row of the pool)."""
   import importlib.util
   import json
   import os

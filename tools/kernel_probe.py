@@ -87,10 +87,15 @@ kernel (ops/ragged_block_attend._GroupedAttendKernel) at the two cells whose
 steps it holds the largest share of: `--shapes smallthinker` (28 query heads
 over 4 KV heads of 128, windows 0 and 4,096, a table of 128 pages) and
 `trinity` (32 over 4, windows 0 and 2,048, 272 pages); bf16 pages of 128, 64
-rows, a pack of 1,088 tokens.
+rows, a pack of 1,088 tokens. `--shapes lfm2` is `lfm2_24b_serve_chat_wide`'s
+attention layer: 32 query heads over 8 KV heads of 64, TWO KV heads side by
+side on a token's row of the pool (`[pages, 128, 4, 128]`), a table of 76
+pages, 256 rows, a pack of 1,280; `lfm2_padded` the form that did not land,
+every head padded to 128 lanes (the kernel as it was over 8 KV heads of 128:
+twice the pool's bytes).
 
   python3 tools/kernel_probe.py --case grouped_attend
-      [--shapes smallthinker,trinity] [--steps decode,chunk]
+      [--shapes smallthinker,trinity,lfm2,lfm2_padded] [--steps decode,chunk]
       [--chunk_at 3k,12k,30k] [--variants masked,clear] [--parent DIR]
       [--calls 50] [--seed 0] [--tiny]
 
@@ -448,7 +453,12 @@ def CombineMain(args) -> int:
 # smallthinker21b.json, trinitymini.json: `serving.max_seq_len` / 128), and
 # the deepest context a one-token row is seeded at
 ATTEND_SHAPES = {"smallthinker": (28, 4, (0, 4096), 128, 6000),
-                 "trinity": (32, 4, (0, 2048), 272, 12000)}
+                 "trinity": (32, 4, (0, 2048), 272, 12000),
+                 "lfm2": (32, 8, (0,), 76, 4000),
+                 "lfm2_padded": (32, 8, (0,), 76, 4000)}
+# (head size, KV heads a row of the pool, rows) where they are not (HEAD_DIM,
+# 1, ROWS)
+ATTEND_HEADS = {"lfm2": (64, 2, 256), "lfm2_padded": (128, 1, 256)}
 ATTEND_BUDGET = 1024
 
 
@@ -502,13 +512,14 @@ def AttendMain(args) -> int:
   parent = (_ParentModule(args.parent, "ragged_block_attend")
             if args.parent else None)
   names = args.variants.split(",") + (["parent"] if parent else [])
-  rows_n, budget = (4, 128) if args.tiny else (ROWS, ATTEND_BUDGET)
-  t = rows_n + budget
   kw = dict(page_size=PAGE, lowering="pallas", interpret=not on_tpu)
   device = jax.devices()[0]
   lines = []
   for shape in args.shapes.split(","):
     n, n_kv, windows, table_pages, deepest = ATTEND_SHAPES[shape]
+    head_dim, tile, rows_n = ATTEND_HEADS.get(shape, (HEAD_DIM, 1, ROWS))
+    rows_n, budget = (4, 128) if args.tiny else (rows_n, ATTEND_BUDGET)
+    t = rows_n + budget
     if args.tiny:
       table_pages, deepest = 8, 600
     steps = []
@@ -540,15 +551,16 @@ def AttendMain(args) -> int:
         tables = jnp.asarray(tables)
         key = jax.random.PRNGKey(args.seed)
         kq, kk, kv = jax.random.split(key, 3)
-        q = (jax.random.normal(kq, (t, n, HEAD_DIM), jnp.float32)
-             * HEAD_DIM ** -0.5).astype(jnp.bfloat16)
-        kp, vp = (jax.random.normal(k, (pool_pages, PAGE, n_kv, HEAD_DIM),
-                                    jnp.bfloat16) for k in (kk, kv))
+        q = (jax.random.normal(kq, (t, n, head_dim), jnp.float32)
+             * head_dim ** -0.5).astype(jnp.bfloat16)
+        kp, vp = (jax.random.normal(
+            k, (pool_pages, PAGE, n_kv // tile, head_dim * tile),
+            jnp.bfloat16) for k in (kk, kv))
         first = None
         for name in names:
           rba = parent if name == "parent" else ragged_block_attend
           fn = ATTEND_VARIANTS.get(name, _AttendAsBuilt)(rba)
-          plan_key = rba.AttendPlanKey(n, n_kv, HEAD_DIM, PAGE, q.dtype,
+          plan_key = rba.AttendPlanKey(n, n_kv, head_dim, PAGE, q.dtype,
                                        kp.dtype, window=window,
                                        lowering="pallas")
           plan = {plan_key: jax.jit(lambda tokens, tree, rba=rba, k=plan_key:
@@ -594,7 +606,9 @@ def AttendMain(args) -> int:
               "clear_pairs": 0 if name == "masked" else (
                   ragged_block_attend.ClearPairs(plan_key, context, lens,
                                                  table_pages)),
-              "heads": n, "kv_heads": n_kv, "pool_pages": pool_pages,
+              "heads": n, "kv_heads": n_kv, "head_dim": head_dim,
+              "heads_a_pool_row": tile, "rows": rows_n,
+              "pool_pages": pool_pages,
               "calls": args.calls, "seed": args.seed, "tiny": args.tiny,
               "device": {"platform": device.platform,
                          "kind": device.device_kind}}
