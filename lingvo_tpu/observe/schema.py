@@ -56,6 +56,13 @@ ENGINE_COUNTER_KEYS = (
     # over `attend_live_pairs`, the share of programs that ran unmasked. 0
     # where no kernel of the step reads the plan's `clear`.
     "attend_clear_pairs",
+    # the programs the attend kernels' grids ran (`AttendPlan.pairs` summed
+    # over the step's plans, by ops/ragged_block_attend.Programs from the
+    # host's rows): a program a live pair, but in the grouped attend kernel,
+    # where a decode row's program walks a span of its pages.
+    # `attend_live_pairs` over it is the pages a program: 1.0 where no kernel
+    # of the step walks spans.
+    "attend_programs",
     # the page write by runs (ops/run_write.py), counted when a step is
     # dispatched from the host's own rows: the runs of tokens the step's list
     # holds (a layer moves each as a few copies) and the tokens in them. Their

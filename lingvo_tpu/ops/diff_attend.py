@@ -389,11 +389,11 @@ def DiffPlanKey(nq: int, nk: int, h: int, page_size: int, q_dtype, kv_dtype,
                 *, window: int = 0, lowering: str = "auto") -> rba.PlanKey:
   """The rba.PlanKey of DiffAttend called with `nq` query heads over `nk` K
   heads of size `h`: grouped-query attention of 2H-wide queries over Nk / 2
-  wide heads, chains only. Its kernel runs one body a rung and reads no
-  `clear`."""
+  wide heads, chains only. Its kernel runs one body a rung, reads no `clear`
+  and runs a program a page (`span` 1)."""
   return rba.AttendPlanKey(
       nq, nk // 2, 2 * h, page_size, q_dtype, kv_dtype, window=window,
-      tree=False, lowering=lowering)._replace(clear=False)
+      tree=False, lowering=lowering)._replace(clear=False, span=1)
 
 
 def _PallasDiffAttend(q2, k_pool, v_pool, block_tables, blocks: rba.AttendPlan,

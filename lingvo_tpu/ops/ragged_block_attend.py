@@ -61,6 +61,22 @@ online softmax and accumulator), held to each other within rounding:
     `[Bq, 1]` columns against the `[1, P]` slot iota.
   - Only a live pair is ever named, so a stale table entry never reaches
     VMEM: by construction, not by a clamp.
+  - Where a KV head serves a group of query heads (`_GroupedAttendKernel`)
+    the unit is (block, SPAN of up to G = `_GROUPED_SPAN` consecutive logical
+    pages) for a block that is one token's group, a decode row, and (block,
+    one page) for every other. The plan's list holds an entry a span
+    (`PlanKey.span`; `ceil(pages / G)` entries from the block's `page0`,
+    wherever a window puts it), the pools stay in HBM, and a program's pages
+    are copied into one half of a `[2, G, P * heads, h]` scratch by the
+    program BEFORE it while that one computes on the other half. A slot of a
+    span past the block's last page is no copy and no work: the guarantee
+    above holds a slot at a time, a copy names `tables[row, page + s]` for
+    `page + s <= last` alone. A span's pages run in one basic block with the
+    block's statistics in registers, so a page's products need not wait for
+    the softmax of the page before (that chain, not a program's fixed cost,
+    was a decode pair's time: `_GroupedAttendKernel` has the readings).
+    Pages in order, the same float operations a page: the output is bitwise
+    the grid of a page a program.
   - `Bq` is `QueryBlock(shapes, dtypes)`: one page of queries, halved
     while the working set passes the scoped-VMEM budget. The arithmetic
     adapts per block to what the kernel sees: a one-query block (a decode
@@ -207,6 +223,14 @@ _VMEM_BUDGET = 12 * 2**20   # of the 16 MiB a kernel may scope by default
 
 _GROUPED_BQ = 512   # queries a block of the grouped kernel: 64 tokens by a
 #                     group padded to 8 (PERF.md section 6, PR 35)
+_GROUPED_SPAN = 4   # logical pages a program of the grouped kernel walks where
+#                     its block is one token's group (a decode row), in one
+#                     basic block. us a decode pair at 2 / 4 / 8 (`tools/
+#                     kernel_probe.py --case grouped_attend`, PERF.md section
+#                     6, PR 64): 0.72 0.70 / 0.69 0.69 / 0.70 0.72 at 28 heads
+#                     (full, window), 0.68 0.70 / 0.64 0.71 / 0.63 0.76 at 32,
+#                     0.83 / 0.74 / 0.75 at heads of 64; the parent 0.82-0.87
+#                     and 1.33
 
 
 def GroupLanes(group: int) -> int:
@@ -334,6 +358,10 @@ class PlanKey(NamedTuple):
   clear: bool = False  # the call's kernel reads `AttendPlan.clear_lo` and
   #                `.clear` (_GroupedAttendKernel's does, and ops/
   #                latent_attend.py's); built for no other key
+  span: int = 1  # logical pages an entry of the plan's list stands for where
+  #                its block runs the rung with one body (`ClearRung`: a decode
+  #                row); every other block's entry, and every entry at 1, is a
+  #                page (_GroupedAttendKernel walks spans, no other kernel)
 
 
 def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
@@ -349,7 +377,7 @@ def AttendPlanKey(n: int, n_kv: int, h: int, page_size: int, q_dtype,
   return PlanKey(page_size, int(window),
                  QueryBlock(n_kv, h, page_size, q_dtype, kv_dtype,
                             grouped=grouped), lanes, tree, kernel,
-                 clear=grouped)
+                 clear=grouped, span=_GROUPED_SPAN if grouped else 1)
 
 
 class AttendPlan(NamedTuple):
@@ -361,7 +389,11 @@ class AttendPlan(NamedTuple):
   past the live blocks repeat the last live block with `n == 0`: no pair
   names them. Pair k is block `blk[k]` at logical page `page[k]`, blocks in
   packed order and a block's pages ascending; `pairs` of them are live, and
-  the entries past those repeat the last live pair."""
+  the entries past those repeat the last live pair. Under a key whose `span`
+  is G > 1 an entry of a block of at most `ClearRung` queries (a decode row)
+  stands for the block's pages `page[k] .. min(page[k] + G - 1, last)`: its
+  spans start at `page0` and step by G, ascending, and `pairs` still counts
+  the entries, which is the grid's length."""
   row: jnp.ndarray    # [NB] block-table row
   last: jnp.ndarray   # [NB] last live logical page (of the widest horizon)
   page0: jnp.ndarray  # [NB] first logical page a query's window reaches
@@ -386,7 +418,7 @@ class AttendPlan(NamedTuple):
   #                     that is empty). None as `clear` is
 
 
-def _LivePairs(n, page0, last, size: int):
+def _LivePairs(n, page0, last, size: int, span: int = 1, rung: int = 0):
   """(blk, page, pairs) of AttendPlan from its blocks' `n`, `page0`, `last`.
 
   Block i's pairs are the `last[i] - page0[i] + 1` entries that follow those
@@ -397,12 +429,23 @@ def _LivePairs(n, page0, last, size: int):
   k), not lookups by `blk`: the chip runs a lookup an index at a time, and a
   `[NB, size]` compare with two sums is what `first` already costs. size:
   `NB * grid_pages`, which holds any step's pairs whatever pages its rows
-  share. (`jax.lax` over constants of numpy, as `_BuildQueryBlocks`.)"""
+  share. (`jax.lax` over constants of numpy, as `_BuildQueryBlocks`.)
+
+  span > 1 (`PlanKey.span`): a block of at most `rung` queries takes an entry
+  a SPAN of its pages, `ceil(pages / span)` of them, entry j at page `page0 +
+  j * span`: its page is `(k - pairs before i) * span + page0[i]`, and the
+  factor of k is a third sum of the same kind. Every other block keeps an
+  entry a page. span 1 is the list as it was, operation for operation."""
   lax, i32 = jax.lax, np.int32
   nb = n.shape[0]
   live = lax.gt(n, i32(0))
-  count = lax.select(live, lax.add(lax.sub(last, page0), i32(1)),
-                     np.zeros((nb,), i32))
+  count = lax.add(lax.sub(last, page0), i32(1))
+  if span > 1:
+    walks = lax.le(n, i32(rung))
+    stride = lax.select(walks, np.full((nb,), span, i32), np.ones((nb,), i32))
+    count = lax.select(walks, lax.div(lax.add(count, i32(span - 1)),
+                                      i32(span)), count)
+  count = lax.select(live, count, np.zeros((nb,), i32))
   upto = lax.cumsum(count)
   pairs = lax.index_in_dim(upto, nb - 1, 0, keepdims=False)
   before = lax.sub(upto, count)
@@ -412,19 +455,27 @@ def _LivePairs(n, page0, last, size: int):
   started = lax.bitwise_and(
       over(live), lax.le(over(before), lax.broadcast_in_dim(k, (nb, size),
                                                             (1,))))
-  offset = lax.sub(page0, before)
-  step = lax.sub(offset, lax.pad(offset, i32(0), ((1, -1, 0),)))
+  # a block's step over the block before it, and what the steps of the blocks
+  # started by k add up to: the last started block's own value
+  step_of = lambda x: lax.sub(x, lax.pad(x, i32(0), ((1, -1, 0),)))
+  of_last = lambda step: lax.reduce_sum(
+      lax.select(started, over(step), np.zeros((nb, size), i32)), (0,))
+  if span > 1:
+    before = lax.mul(before, stride)
+  step = step_of(lax.sub(page0, before))
   blk = lax.sub(lax.reduce_sum(lax.convert_element_type(started, i32), (0,)),
                 i32(1))
-  page = lax.add(k, lax.reduce_sum(
-      lax.select(started, over(step), np.zeros((nb, size), i32)), (0,)))
+  if span > 1:
+    k = lax.mul(k, of_last(step_of(stride)))
+  page = lax.add(k, of_last(step))
   # a step with no live block: nothing runs, and the entries name block 0
   return lax.max(blk, i32(0)), lax.max(page, i32(0)), pairs
 
 
 def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                       page_size: int, t_pages: int, window: int = 0,
-                      clear: bool = False) -> AttendPlan:
+                      clear: bool = False, span: int = 1,
+                      lanes: int = 1) -> AttendPlan:
   """Cuts each row's run of tokens into blocks of Bq queries.
 
   A few [T]- and [NB, Bq]-sized integer ops on what the step already has
@@ -530,7 +581,8 @@ def _BuildQueryBlocks(row_of, ends, starts, lo, hi, *, bq: int, nb: int,
                           i32(0)), i32(page_size - 1)), i32(page_size))
   col0 = lax.index_in_dim(cols, 0, 1, keepdims=False)       # [NB, 4]
   blk, page, pairs = _LivePairs(
-      n, page0, last, nb * WindowPages(window, bq, page_size, t_pages))
+      n, page0, last, nb * WindowPages(window, bq, page_size, t_pages), span,
+      ClearRung(BlockRungs(bq, lanes)))
   row = lax.gather(
       row_of, lax.reshape(first, (nb, 1)),
       lax.GatherDimensionNumbers(offset_dims=(), collapsed_slice_dims=(0,),
@@ -575,36 +627,55 @@ def _HostBlocks(key: PlanKey, row_q_pos, row_len, t_pages: int):
   return live, hi - lo + 1, narrowest, widest, page0, last
 
 
-def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
-  """`AttendPlan.pairs` of a step from the host's own view of its rows
-  (numpy; `_LivePairs`' twin, as `BlockRows` is `BlockRungs`')."""
-  live, _, _, _, page0, last = _HostBlocks(key, row_q_pos, row_len, t_pages)
-  return int(np.sum(np.where(live, last - page0 + 1, 0)))
-
-
 def ClearRung(rungs: tuple[int, ...]) -> int:
   """The queries a block must pass to run the rung whose programs tell a
-  clear page from another (the widest; a lower rung runs one body: its
-  program is its fixed cost and its page's copy)."""
+  clear page from another (the widest; a lower rung runs one body, over a
+  span of its block's pages where the key's `span` says so)."""
   return rungs[-2] if len(rungs) > 1 else 0
 
 
-def ClearPairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
-  """The pairs of `LivePairs` whose program ran no mask (numpy; what the
-  plan's `clear_lo` and `clear` give the kernel, counted over CHAIN rows,
-  which is what the host knows it sent): a block of the widest rung at a page
-  that lies whole under its narrowest horizon and, with a window, whole inside
-  the window of its widest. 0 for a key whose kernel reads no `clear`."""
-  if not key.clear:
-    return 0
+def PairCounts(key: PlanKey, row_q_pos, row_len,
+               t_pages: int) -> tuple[int, int, int]:
+  """(live pairs, clear pairs, programs) of a step from ONE view of its rows
+  (numpy; the engine's three counters a key a step):
+
+  - live pairs: the PAGES its blocks attend, whatever the grid's unit is
+    (`AttendPlan.pairs` where `key.span` is 1);
+  - clear pairs: those of them whose program ran no mask (what the plan's
+    `clear_lo` and `clear` give the kernel, counted over CHAIN rows, which is
+    what the host knows it sent): a block of the widest rung at a page that
+    lies whole under its narrowest horizon and, with a window, whole inside
+    the window of its widest. 0 for a key whose kernel reads no `clear`;
+  - programs: `AttendPlan.pairs`, the grid's length (`_LivePairs`' twin, as
+    `BlockRows` is `BlockRungs`'): a program a page, or a span of `key.span`
+    pages where the block runs the rung with one body. Live pairs over it is
+    the pages a program: 1.0 where `key.span` is 1."""
   live, queries, narrowest, widest, page0, last = _HostBlocks(
       key, row_q_pos, row_len, t_pages)
-  wide = live & (queries > ClearRung(BlockRungs(key.bq, key.lanes)))
-  clear_lo = page0
-  if key.window:
-    clear_lo = -(-np.maximum(widest - key.window, 0) // key.page_size)
-  return int(np.sum(np.where(wide, np.maximum(np.minimum(
-      narrowest // key.page_size, last + 1) - clear_lo, 0), 0)))
+  count = lambda x: int(np.sum(np.where(live, x, 0)))
+  pages = last - page0 + 1
+  wide = queries > ClearRung(BlockRungs(key.bq, key.lanes))
+  clear = 0
+  if key.clear:
+    clear_lo = page0
+    if key.window:
+      clear_lo = -(-np.maximum(widest - key.window, 0) // key.page_size)
+    clear = count(np.where(wide, np.maximum(np.minimum(
+        narrowest // key.page_size, last + 1) - clear_lo, 0), 0))
+  return count(pages), clear, count(
+      np.where(wide, pages, -(-pages // key.span)))
+
+
+def LivePairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
+  return PairCounts(key, row_q_pos, row_len, t_pages)[0]
+
+
+def ClearPairs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
+  return PairCounts(key, row_q_pos, row_len, t_pages)[1]
+
+
+def Programs(key: PlanKey, row_q_pos, row_len, t_pages: int) -> int:
+  return PairCounts(key, row_q_pos, row_len, t_pages)[2]
 
 
 def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
@@ -637,7 +708,8 @@ def BuildAttendPlan(key: PlanKey, row_of, q_end, q_start=None, anc_lo=None,
   return _BuildQueryBlocks(
       rows, ends, starts, lo, hi, bq=key.bq,
       nb=NumQueryBlocks(b, rows.shape[0], key.bq), page_size=key.page_size,
-      t_pages=t_pages, window=key.window, clear=key.clear)
+      t_pages=t_pages, window=key.window, clear=key.clear, span=key.span,
+      lanes=key.lanes)
 
 
 def _BlockPageAttend(q, k, v, keep, m, l, acc, dims_qk, dims_pv):
@@ -786,26 +858,29 @@ def _RaggedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
 _EVEN_ROW_LOW = True
 
 
-def _HeadPages(ref, heads: int):
-  """ref: one page as rows, block `[1, P * heads, H]` with row p * heads + g
-  the vector of token p, KV head g. -> `heads` arrays `[P, H]`, a head's
-  rows. 32-bit pages: a strided load a head. 16-bit pages: Mosaic loads
+def _HeadPages(ref, heads: int, at=(0,)):
+  """ref: pages as rows, `[..., P * heads, H]` with row p * heads + g the
+  vector of token p, KV head g; `at` picks ONE page by its leading indices (a
+  block `[1, P * heads, H]`: its only one). -> `heads` arrays `[P, H]`, a
+  head's rows. 32-bit pages: a strided load a head. 16-bit pages: Mosaic loads
   with a stride only 32-bit rows, and two consecutive rows share a word
   row, so the page is read as words (rows 2w and 2w + 1 in the halves of
   word row w), every (heads / 2)-th word row from s holds heads 2s and
   2s + 1 of every token, and each half, shifted to the top of a word, IS
   the 16-bit float's value as an f32."""
-  rows = ref.shape[1]
+  rows = ref.shape[-2]
   tokens = rows // heads
+  at = tuple(at)
   if heads == 1:
-    return [ref[0]]
+    return [ref[at]]
   if jnp.dtype(ref.dtype).itemsize == 4:
-    return [ref[0, pl.ds(g, tokens, stride=heads), :] for g in range(heads)]
+    return [ref[at + (pl.ds(g, tokens, stride=heads), slice(None))]
+            for g in range(heads)]
   assert ref.dtype == jnp.bfloat16 and heads % 2 == 0, (ref.dtype, heads)
-  words = ref.bitcast(jnp.uint32)                      # [1, rows / 2, H]
+  words = ref.bitcast(jnp.uint32)                      # [..., rows / 2, H]
   out = []
   for s in range(heads // 2):
-    w = words[0, pl.ds(s, tokens, stride=heads // 2), :]
+    w = words[at + (pl.ds(s, tokens, stride=heads // 2), slice(None))]
     low = pltpu.bitcast(w << 16, jnp.float32).astype(ref.dtype)
     high = pltpu.bitcast(w & jnp.uint32(0xFFFF0000),
                          jnp.float32).astype(ref.dtype)
@@ -815,9 +890,10 @@ def _HeadPages(ref, heads: int):
 
 def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
                          tables_ref, n_ref, first_ref, end0_ref, start0_ref,
-                         lo0_ref, hi0_ref, clear_lo_ref, clear_ref, *rest,
-                         page_size: int, window: int, heads: int,
-                         rungs: tuple[int, ...], tile_heads: int = 1):
+                         lo0_ref, hi0_ref, clear_lo_ref, clear_ref, pairs_ref,
+                         *rest, page_size: int, window: int, heads: int,
+                         rungs: tuple[int, ...], tile_heads: int = 1,
+                         span: int = 1):
   """The (query block, logical page) program where a KV head serves a GROUP
   of query heads: the group rides the packed axis (RaggedAttend), so a
   block is up to Bq queries of which each has one vector per KV head. q and
@@ -857,14 +933,36 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
   both run the same float ops in the same order: the output is bitwise the
   masked kernel's. A masked page leaves the rung's rows that are not the
   block's at an exact zero by itself, a clear one does not, so `_Emit` zeroes
-  them, once a block, by `n_ref`. The 8-row rung keeps its one masked body: a
-  decode pair is its fixed cost, and a second body is a second trace. Measured
+  them, once a block, by `n_ref`. The 8-row rung keeps its one masked body (a
+  second body is a second trace; what its pair costs: below, PR 64). Measured
   (`tools/kernel_probe.py --case grouped_attend`, PERF.md section 6, PR 62): a
   chunk pair 3.75-3.97 us masked -> 1.50-2.01 us with nine in ten of its
   pages clear; the same body over `[rows, 1]` statistics LOST (4.18-4.20 us:
   the mask's passes cost less than the slices and broadcasts it is then left
   with), the rows in halves or quarters gained 4-7% more at two and four
   times the traced body.
+
+  A decode row's program walks a SPAN of its pages (PR 64). A block of the
+  rung with one body (one token's group: 8 or 16 rows) is named by the plan
+  once a span of up to `span` consecutive logical pages (`PlanKey.span`,
+  `_GROUPED_SPAN`; `lead` its first, `pages` how many of them are the
+  block's), every other block once a page. The pools stay in HBM: a program
+  starts the copies of the NEXT program's pages into one half of the `[2,
+  span, P * heads, h]` scratches and awaits its own in the other (`_Fetch`),
+  a copy a live page, `tables[row, lead + s]` for `lead + s <= last` alone, so
+  a slot past the block's last page is never named, copied or attended: the
+  page-reuse guarantee holds a slot at a time. A whole span then runs in ONE
+  basic block (`_Pages`): the statistics and the accumulator are read once,
+  ride the pages as values and are written once, so the second page's
+  products need not wait for the first page's softmax; a row's last, shorter
+  span runs a page a trip of a loop. What the probe found (`tools/
+  kernel_probe.py --case grouped_attend`, PERF.md section 6, PR 64): a
+  program's fixed cost was NOT what a decode pair cost: the same body over
+  the same spans one page a loop trip, or a page a branch, read the parent's
+  0.83-0.88 us a page at G = 1, 2, 4 and 8 alike (the copies alone 0.40, the
+  products alone 0.77-0.84: eight `[8, 128] x [128, 128]` products, each a
+  weight load of the MXU, in a chain a page long); the pages of a span in one
+  block read 0.64-0.71 us at G = 4 (0.68-0.72 at 2, 0.63-0.76 at 8).
 
   Heads of HALF a lane tile (tile_heads 2, head size 64): the pool holds two
   KV heads side by side on a token's row (`TileHeads`), `heads` counts those
@@ -876,12 +974,57 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
   accumulator stays one row, each half scaled by its own head's `alpha`. The
   statistics are a head's, `heads * tile_heads` of them. The MXU does a head
   of 128's work for a head of 64 (its columns are 128 either way); the page's
-  bytes are the heads' own."""
+  bytes are the heads' own. In the one-body rung the pair's queries lie ONE
+  UNDER THE OTHER (`qh[0]`'s leading `2 * rows` rows, made at the block's
+  first page), so both heads meet the row's keys in one product and its
+  values in one more, where two products each loaded the same `[128, 128]`
+  weights twice: a product's rows are independent, so every score and every
+  sum is the one it was (1.33 -> 0.74 us a decode pair with the span, 0.90
+  without; the same probe)."""
   pair = pl.program_id(0)
-  i, page = blk_ref[pair], page_ref[pair]
-  q_hbm, cols_ref, k_ref, v_ref, _, out_hbm, qb, qh, mb, lb, accb, sem = rest
+  i, lead = blk_ref[pair], page_ref[pair]   # its block, its first page
+  (q_hbm, cols_ref, k_hbm, v_hbm, _, out_hbm, qb, qh, mb, lb, accb, k_scr,
+   v_scr, sem, page_sem) = rest
   h = qb.shape[1] // heads
   nv = n_ref[i]
+  walk_rung = ClearRung(rungs) if span > 1 else 0
+
+  def _Span(k):
+    """(table row, first logical page, live pages) of program k's span."""
+    j, first_page = blk_ref[k], page_ref[k]
+    pages = 1
+    if walk_rung:
+      pages = jnp.where(
+          n_ref[j] <= walk_rung,
+          jnp.minimum(last_ref[j] - first_page + 1, span), 1)
+    return row_ref[j], first_page, pages
+
+  def _Fetch(k, buf, wait: bool = False):
+    """Starts (or awaits) the copies of program k's pages into half `buf` of
+    the page scratch: slot s is logical page `first_page + s`, a LIVE page of
+    the program's block, and a slot past the block's last is no copy at all."""
+    row, first_page, pages = _Span(k)
+
+    def _Slot(s):
+      # a wait names its copy by shape alone
+      pid = 0 if wait else tables_ref[row, first_page + s]
+      for pool, scr in ((k_hbm, k_scr), (v_hbm, v_scr)):
+        copy = pltpu.make_async_copy(pool.at[pid], scr.at[buf, s],
+                                     page_sem.at[buf])
+        copy.wait() if wait else copy.start()
+
+    if walk_rung:
+      jax.lax.fori_loop(0, pages, lambda s, c: (_Slot(s), c)[1], 0)
+    else:
+      _Slot(0)
+
+  # The pools stay in HBM and a program's pages are copied by the program
+  # before it: two halves of the scratch, one in use and one filling. The
+  # first program starts its own.
+  buf = jax.lax.rem(pair, 2)
+  pl.when(pair == 0)(lambda: _Fetch(pair, buf))
+  pl.when(pair + 1 < pairs_ref[0])(lambda: _Fetch(pair + 1, 1 - buf))
+  _Fetch(pair, buf, wait=True)
   if tile_heads > 1:
     # which head of its row a lane is, over a row and over the block's width
     head_of = lambda width: (jax.lax.broadcasted_iota(
@@ -901,16 +1044,27 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
     scratch (static): window in, accumulate, window out."""
     held = pl.ds(0, rows)
     window_q = pl.ds(first, rows)
+    two_bodies = rows > ClearRung(rungs)
+    # the rung with one body walks a span of its block's pages a program
+    pages = jnp.minimum(last_ref[i] - lead + 1, span) if (
+        walk_rung and not two_bodies) else 1
+    # ... and, where a row of the pool holds a pair of heads, lays the pair's
+    # queries one under the other: both meet the row's keys in ONE product
+    stacked = pl.ds(0, rows * tile_heads)
 
-    @pl.when(page == page0_ref[i])
+    @pl.when(lead == page0_ref[i])
     def _Init():
       _Copy(q_hbm.at[window_q], qb.at[held])
       if tile_heads == 1:
         qh[held] = qb[held].astype(qh.dtype)
-      else:
+      elif two_bodies:
         for j in range(tile_heads):
           qh[j, held] = jnp.where(lane_head_all == j, qb[held],
                                   0.0).astype(qh.dtype)
+      else:
+        qh[0, stacked] = jnp.concatenate(
+            [jnp.where(lane_head_all == j, qb[held], 0.0)
+             for j in range(tile_heads)], axis=0).astype(qh.dtype)
       mb[:, held] = jnp.full((heads * tile_heads, rows, LANES), NEG_INF,
                              mb.dtype)
       lb[:, held] = jnp.zeros((heads * tile_heads, rows, LANES), lb.dtype)
@@ -919,14 +1073,15 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
     def _Page(masked: bool):
       keep = None
       if masked:
-        slot = page * page_size + jax.lax.broadcasted_iota(
+        slot = lead * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)                     # [1, P]
         cols = cols_ref[0, held]                              # [rows, 4]
         keep = (slot < cols[:, 0:1]) & _AncestorOk(
             slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [rows, P]
         if window:
           keep &= slot >= cols[:, 0:1] - window
-      keys, values = _HeadPages(k_ref, heads), _HeadPages(v_ref, heads)
+      keys, values = (_HeadPages(scr, heads, (buf, 0))
+                      for scr in (k_scr, v_scr))
       # a clear page's statistics ride as the scratch holds them, lane-
       # replicated, where a page is as wide as they are
       stat = slice(None) if (not masked and page_size == LANES
@@ -954,15 +1109,69 @@ def _GroupedAttendKernel(blk_ref, page_ref, row_ref, last_ref, page0_ref,
         lb[g, held] = jnp.broadcast_to(l, (rows, LANES))
         accb[held, lanes] = acc
 
-    two_bodies = rows > ClearRung(rungs)
+    def _Pages(count: int, s0=0):
+      """`count` (static) consecutive pages of the program's span from slot
+      `s0`, each through the masked body, in ONE basic block: the block's
+      statistics and accumulator are read once, ride the pages as values and
+      are written once, so what ties a page to the one before it is a maximum
+      and two multiply-adds a head, and the pages' products can follow one
+      another into the MXU without waiting for a softmax between them. Per
+      (query, head, slot) the float operations and their order are `_Page`'s."""
+      cols = cols_ref[0, held]                                # [rows, 4]
+      every = heads * tile_heads
+      ms = [mb[hd, held, :1] for hd in range(every)]
+      ls = [lb[hd, held, :1] for hd in range(every)]
+      accs = [accb[held, pl.ds(g * h, h)] for g in range(heads)]
+      if tile_heads == 1:
+        qs = [qh[held, pl.ds(g * h, h)] for g in range(heads)]
+      else:
+        qs = [qh[0, stacked, pl.ds(g * h, h)] for g in range(heads)]
+      stack = lambda xs: jnp.concatenate(xs, axis=0)
+      dims = ((((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+      for s in range(count):
+        slot = (lead + s0 + s) * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)                     # [1, P]
+        keep = (slot < cols[:, 0:1]) & _AncestorOk(
+            slot, slot - cols[:, 1:2], cols[:, 2:3], cols[:, 3:4])  # [rows, P]
+        if window:
+          keep &= slot >= cols[:, 0:1] - window
+        keys, values = (_HeadPages(scr, heads, (buf, s0 + s))
+                        for scr in (k_scr, v_scr))
+        for g in range(heads):
+          if tile_heads == 1:
+            ms[g], ls[g], accs[g] = _BlockPageAttend(
+                qs[g], keys[g], values[g], keep, ms[g], ls[g], accs[g], *dims)
+            continue
+          mine = range(g * tile_heads, (g + 1) * tile_heads)
+          m, l, acc = _BlockPageAttend(
+              qs[g], keys[g], values[g], stack([keep] * tile_heads),
+              stack([ms[hd] for hd in mine]), stack([ls[hd] for hd in mine]),
+              stack([accs[g]] * tile_heads), *dims)
+          for j, hd in enumerate(mine):
+            of_head = slice(j * rows, (j + 1) * rows)
+            ms[hd], ls[hd] = m[of_head], l[of_head]
+            accs[g] = jnp.where(lane_head == j, acc[of_head], accs[g])
+      for hd in range(every):
+        mb[hd, held] = jnp.broadcast_to(ms[hd], (rows, LANES))
+        lb[hd, held] = jnp.broadcast_to(ls[hd], (rows, LANES))
+      for g in range(heads):
+        accb[held, pl.ds(g * h, h)] = accs[g]
+
     if two_bodies:
-      is_clear = jnp.logical_and(page >= clear_lo_ref[i], page < clear_ref[i])
+      is_clear = jnp.logical_and(lead >= clear_lo_ref[i], lead < clear_ref[i])
       pl.when(is_clear)(functools.partial(_Page, False))
       pl.when(jnp.logical_not(is_clear))(functools.partial(_Page, True))
+    elif not walk_rung:
+      _Pages(1)
     else:
-      _Page(True)
+      # a whole span in one block; a row's last, shorter one a page a trip
+      pl.when(pages == span)(functools.partial(_Pages, span))
 
-    @pl.when(page == last_ref[i])
+      @pl.when(pages < span)
+      def _ShortSpan():
+        jax.lax.fori_loop(0, pages, lambda s, c: (_Pages(1, s), c)[1], 0)
+
+    @pl.when(lead + pages - 1 == last_ref[i])
     def _Emit():
       # a query of the rung's rows that is not this block's comes out an
       # exact zero, as in _RaggedAttendKernel: a masked page leaves it one by
@@ -1020,12 +1229,14 @@ def _Prefetch(blocks: AttendPlan, tables) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "page_size", "heads", "window", "rungs", "interpret", "tile_heads"))
+    "page_size", "heads", "window", "rungs", "interpret", "tile_heads",
+    "span"))
 def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
                  page_size: int, heads: int, window: int,
                  rungs: tuple[int, ...], interpret: bool,
-                 tile_heads: int = 1):
-  """_GroupedAttendKernel over _PallasRaggedAttend's grid and descriptors.
+                 tile_heads: int = 1, span: int = 1):
+  """_GroupedAttendKernel over _PallasRaggedAttend's descriptors and the
+  plan's list at `span` pages an entry of the one-body rung (`PlanKey.span`).
   pairs: [] the grid's length; q: [T + Bq, Nkv * H] f32; cols: [NB, Bq, 4];
   pages as rows [NP, P * Nkv, H] -> the output, [T + Bq, Nkv * H] f32, zeros
   where no block wrote. tile_heads 2: `heads` rows a token of two KV heads
@@ -1042,23 +1253,18 @@ def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
   name: the scope here keeps the name the callers' scope gives it."""
   bq = cols.shape[1]
   h = q.shape[1] // heads
-  page_idx, cols_idx = _PairIndexMaps(2)
+  _, cols_idx = _PairIndexMaps(2)
   hbm = pl.BlockSpec(memory_space=pl.ANY)
+  pages = (2, span, page_size * heads, h)    # two halves of `span` pages
   with observe.Scope("ragged_attend"):
     return pl.pallas_call(
         functools.partial(_GroupedAttendKernel, page_size=page_size,
                           window=window, heads=heads, rungs=rungs,
-                          tile_heads=tile_heads),
+                          tile_heads=tile_heads, span=span),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
+            num_scalar_prefetch=len(prefetch) + 1,
             grid=(pairs,),
-            in_specs=[
-                hbm,
-                pl.BlockSpec((1, bq, 4), cols_idx),
-                pl.BlockSpec((1, page_size * heads, h), page_idx),
-                pl.BlockSpec((1, page_size * heads, h), page_idx),
-                hbm,
-            ],
+            in_specs=[hbm, pl.BlockSpec((1, bq, 4), cols_idx), hbm, hbm, hbm],
             out_specs=hbm,
             scratch_shapes=[
                 pltpu.VMEM((bq, heads * h), jnp.float32),
@@ -1067,20 +1273,24 @@ def _GroupedCall(pairs, prefetch, q, cols, k_pages, v_pages, *,
                 pltpu.VMEM((heads * tile_heads, bq, LANES), jnp.float32),
                 pltpu.VMEM((heads * tile_heads, bq, LANES), jnp.float32),
                 pltpu.VMEM((bq, heads * h), jnp.float32),
+                pltpu.VMEM(pages, k_pages.dtype),
+                pltpu.VMEM(pages, v_pages.dtype),
                 pltpu.SemaphoreType.DMA(()),
+                pltpu.SemaphoreType.DMA((2,)),
             ]),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        input_output_aliases={len(prefetch) + 4: 0},
+        input_output_aliases={len(prefetch) + 5: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*prefetch, q, cols, k_pages, v_pages, jnp.zeros(q.shape, jnp.float32))
+    )(*prefetch, jnp.reshape(pairs, (1,)), q, cols, k_pages, v_pages,
+      jnp.zeros(q.shape, jnp.float32))
 
 
 def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
                         page_size: int, interpret: bool = False,
                         k_scale=None, v_scale=None, window: int = 0,
-                        grouped: int = 0):
+                        grouped: int = 0, span: int = 1):
   """Pallas lowering of _XlaRaggedAttend. q: [T, N, H] -> [T, N, H].
 
   blocks: the call's descriptors (BuildAttendPlan over the packed tokens
@@ -1089,6 +1299,9 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
   they are a KV head's group laid beside the tokens (RaggedAttend) and the
   pages float: the same grid, descriptors and page index map run
   _GroupedAttendKernel over pages seen as rows.
+
+  span (static): `PlanKey.span` of the key `blocks` was built under, the
+  pages an entry of its list stands for where the grouped kernel walks them.
 
   Grid `(blocks.pairs,)`, the step's live (block, page) pairs in order (a
   traced length: a step runs the programs it has work for, and none when it
@@ -1111,7 +1324,7 @@ def _PallasRaggedAttend(q, k_pool, v_pool, block_tables, blocks: AttendPlan,
         blocks.cols, k_pool.reshape(np_total, page * pool_heads, row),
         v_pool.reshape(np_total, page * pool_heads, row), page_size=page_size,
         heads=pool_heads, window=window, rungs=BlockRungs(bq, grouped),
-        interpret=interpret, tile_heads=n // pool_heads)
+        interpret=interpret, tile_heads=n // pool_heads, span=span)
     return out[:t].astype(q.dtype).reshape(t, n, h)
   page_idx, cols_idx = _PairIndexMaps(3)
   hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -1276,7 +1489,7 @@ def RaggedAttend(q, k_pool, v_pool, block_tables, row_of, q_end, *,
     out = _PallasRaggedAttend(
         q, k_pool, v_pool, block_tables, blocks, page_size,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
-        window=key.window, grouped=lanes if grouped else 0)
+        window=key.window, grouped=lanes if grouped else 0, span=key.span)
   if group > 1:
     out = out.reshape(t, lanes, n_kv, h)[:, :group]
     out = out.swapaxes(1, 2).reshape(t, n, h)

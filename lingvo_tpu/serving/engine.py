@@ -1313,7 +1313,7 @@ class ServingLoop:
           "conv_tail_rows", "slot_state_bytes"))
     if self._attend_clear_keys:
       out.update((k, self._counters[k].value) for k in (
-          "attend_live_pairs", "attend_clear_pairs"))
+          "attend_live_pairs", "attend_clear_pairs", "attend_programs"))
     return out or None
 
   def _NoteDispatch(self, batch):
@@ -1375,15 +1375,14 @@ class ServingLoop:
       self._counters["attend_block_rows"].Inc(int(np.sum(
           whole * self._attend_bq + self._attend_rows(rest))))
     if self._attend_plan_keys:
-      self._counters["attend_live_pairs"].Inc(sum(
-          ragged_block_attend.LivePairs(key, desc.row_q_pos, row_len,
-                                        self._table_pages)
-          for key in self._attend_plan_keys))
-      self._counters["attend_grid_pairs"].Inc(self._attend_grid_pairs)
-      self._counters["attend_clear_pairs"].Inc(sum(
-          ragged_block_attend.ClearPairs(key, desc.row_q_pos, row_len,
+      live, clear, programs = np.sum([
+          ragged_block_attend.PairCounts(key, desc.row_q_pos, row_len,
                                          self._table_pages)
-          for key in self._attend_clear_keys))
+          for key in self._attend_plan_keys], axis=0)
+      self._counters["attend_live_pairs"].Inc(int(live))
+      self._counters["attend_clear_pairs"].Inc(int(clear))
+      self._counters["attend_programs"].Inc(int(programs))
+      self._counters["attend_grid_pairs"].Inc(self._attend_grid_pairs)
     if self._kv_write_by_runs or self._kv_page_write_bound:
       runs, tokens = run_write.RunCounts(desc.row_q_pos, row_len,
                                          self.page_size)

@@ -487,6 +487,60 @@ def test_the_host_counts_the_clear_pairs_the_plan_holds(lanes, bq, window,
                         rows.row_len, T_PAGES) == 0
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("kind", PAIR_KINDS)
+@pytest.mark.parametrize("window", [0, 24, 37])
+@pytest.mark.parametrize("lanes,bq,span", [(1, 16, 1), (8, 64, 1), (8, 64, 2),
+                                           (8, 64, 4), (16, 16, 4)],
+                         ids=["heads", "grouped_span1", "grouped_span2",
+                              "grouped_span4", "one_rung_span4"])
+def test_the_host_counts_the_programs_the_plans_list_holds(lanes, bq, span,
+                                                           window, kind, seed):
+  """`Programs` (the host's rows) against the plan's `pairs`, the grid's
+  length, and the list against its enumeration: under a key of `span` G a
+  block of the one-body rung (a decode row) takes an entry a span of G pages
+  from its `page0` (wherever a window puts that) and every other block an
+  entry a page, blocks in packed order, spans and pages ascending; `LivePairs`
+  keeps counting pages. A key whose `span` is 1, and one whose blocks run one
+  rung (none is a decode row's), lists a page an entry."""
+  rows, tables = _PairRows(kind, seed)
+  b = tables.shape[0]
+  tok = ragged_lib.BuildTokenView(rows, b, T_PAGES, PAGE)
+  key = rba.PlanKey(PAGE, window, bq, lanes, tree=False, kernel=True,
+                    clear=lanes > 1, span=span)
+  plan = rba.BuildAttendPlan(key, tok.row, tok.q_end, b=b, t_pages=T_PAGES)
+  n, page0, last = (np.asarray(x) for x in (plan.n, plan.page0, plan.last))
+  rung = rba.ClearRung(rba.BlockRungs(bq, lanes))
+  want = []
+  for i in np.flatnonzero(n > 0):
+    stride = span if n[i] <= rung else 1
+    want += [(int(i), int(p)) for p in range(page0[i], last[i] + 1, stride)]
+  pairs = int(plan.pairs)
+  assert pairs == len(want) == rba.Programs(key, rows.row_q_pos, rows.row_len,
+                                            T_PAGES)
+  got = list(zip(np.asarray(plan.blk).tolist(), np.asarray(plan.page).tolist()))
+  assert got[:pairs] == want
+  assert set(got[pairs:]) <= {want[-1] if want else (0, 0)}
+  pages = rba.LivePairs(key, rows.row_q_pos, rows.row_len, T_PAGES)
+  assert pages == int(np.sum(np.where(n > 0, last - page0 + 1, 0)))
+  # the plan's other descriptors are the span-1 key's, to the bit
+  flat = rba.BuildAttendPlan(key._replace(span=1), tok.row, tok.q_end, b=b,
+                             t_pages=T_PAGES)
+  for name in ("row", "last", "page0", "n", "first", "cols", "clear",
+               "clear_lo"):
+    if getattr(plan, name) is not None:
+      _Same(getattr(plan, name), getattr(flat, name))
+  assert int(flat.pairs) == pages
+  if span == 1 or not rung:
+    assert pairs == pages
+  elif kind == "decode_only" and not window:
+    # rows deep in their tables: fewer programs than pages
+    decode = n[n > 0]
+    assert np.all(decode <= rung) and pairs == int(np.sum(
+        -(-(last - page0 + 1)[n > 0] // span)))
+    assert pairs < pages or np.all((last - page0)[n > 0] == 0)
+
+
 @pytest.mark.parametrize("kind", PAIR_KINDS)
 @pytest.mark.parametrize("window", [0, 24])
 @pytest.mark.parametrize("kernel", ["heads", "grouped", "diff"])
@@ -683,10 +737,12 @@ def test_stats_count_what_the_stack_declares(programs):
 
 @pytest.mark.parametrize("family,depth", [("dense", 2), ("smallthinker", 4)])
 def test_stats_count_the_pairs_the_steps_plans_hold(family, depth):
-  """`attend_live_pairs` is the sum of `AttendPlan.pairs` over the steps an
-  engine dispatched and the plans of each (the host counts from its own rows
-  what the device lists from the same), `attend_grid_pairs` the room of those
-  lists; both stay 0 where the twins run."""
+  """`attend_programs` is the sum of `AttendPlan.pairs`, the grids' lengths,
+  over the steps an engine dispatched and the plans of each (the host counts
+  from its own rows what the device lists from the same), `attend_live_pairs`
+  the pages those programs attend (the same where no key walks spans),
+  `attend_grid_pairs` the room of those lists; all stay 0 where the twins
+  run."""
   task, theta = _Task(family, depth)
   with pytest.MonkeyPatch.context() as mp:
     mp.setattr(rba, "Lowering", lambda lowering: (
@@ -695,18 +751,27 @@ def test_stats_count_the_pairs_the_steps_plans_hold(family, depth):
     keys = sorted(set(task.stack.RaggedPlanKeys(eng._states)))
   stats = eng.Stats()
   assert len(keys) == stats["attend_plans"] == DECLARED[family][1]
-  live = grid = 0
+  programs = live = grid = 0
   for _, _, _, rows, tables in seen:
     plan = attention_lib.BuildRaggedPlan(keys, rows, *tables.shape[-2:])
-    live += sum(int(blocks.pairs) for blocks in plan.blocks.values())
+    # the grid's length is the plan's `pairs`: `attend_programs`. The live
+    # pages are the same key's list at a page an entry (`span` 1)
+    programs += sum(int(blocks.pairs) for blocks in plan.blocks.values())
+    flat = attention_lib.BuildRaggedPlan(
+        [k._replace(span=1) for k in keys], rows, *tables.shape[-2:])
+    live += sum(int(blocks.pairs) for blocks in flat.blocks.values())
     grid += sum(blocks.blk.shape[0] for blocks in plan.blocks.values())
   assert stats["steps"] == len(seen) == 2
-  assert (stats["attend_live_pairs"], stats["attend_grid_pairs"]) == (
-      live, grid)
-  assert 0 < live < grid
+  assert (stats["attend_programs"], stats["attend_live_pairs"],
+          stats["attend_grid_pairs"]) == (programs, live, grid)
+  assert 0 < programs <= live < grid
+  # a key that is not grouped runs a program a page
+  grouped = any(k.span > 1 for k in keys)
+  assert grouped == (family == "smallthinker")
+  assert grouped or programs == live
   twin_stats = _StepArgs(task, theta)[0].Stats()
-  assert (twin_stats["attend_live_pairs"],
-          twin_stats["attend_grid_pairs"]) == (0, 0)
+  assert (twin_stats["attend_live_pairs"], twin_stats["attend_grid_pairs"],
+          twin_stats["attend_programs"]) == (0, 0, 0)
 
 
 def test_the_kernels_program_is_the_twins_within_rounding(programs):

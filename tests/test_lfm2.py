@@ -373,14 +373,19 @@ def test_the_kernel_says_what_it_does_not_serve():
 # mixed step, f32), and the grouped kernel at heads of 128 through the
 # interpreter, a full and a window layer: lines of `lower().as_text()` and the
 # first 16 hex digits of its sha256. The recipe is the test: run it on a
-# parent's tree to take a number again.
+# parent's tree to take a number again. The two `grouped_kernel_*` pins were
+# RE-TAKEN FROM PR 64's OWN TREE, which changes that kernel on purpose (a
+# decode row's program walks a span of its pages: the plan's list, the pools in
+# HBM, the span's pages in one block; 3464 / "6462fd6c086a8839" and 3506 /
+# "0e22dfab41628703" at PR 63): they hold later PRs to PR 64's text. The four
+# step programs above them are the parent's, untouched.
 _PARENT = {
     "dense": (1290, "1876dbf11e99e5cf"),
     "smallthinker": (3612, "5a744b3068ca2ff2"),
     "trinity": (3921, "63ccd2a42e9e9e9e"),
     "nemotron_h": (6106, "1b1bed999b069ddd"),
-    "grouped_kernel_full": (3464, "6462fd6c086a8839"),
-    "grouped_kernel_window": (3506, "0e22dfab41628703"),
+    "grouped_kernel_full": (6021, "98c73ed9d51e02cb"),
+    "grouped_kernel_window": (6095, "8e0508c01e516255"),
 }
 
 

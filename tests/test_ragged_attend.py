@@ -608,13 +608,171 @@ class TestGroupedClearPages:
     assert diff.kernel and diff.lanes == 8 and not diff.clear
 
 
+class TestGroupedSpans:
+  """A decode row's program of `_GroupedAttendKernel` walks a span of up to
+  G = `_GROUPED_SPAN` of its pages (a chunk's and a tree row's keep a page):
+  the output is BITWISE the same kernel's at G forced to 1, a program a page,
+  and the twin's within rounding; a table entry past a row's last page never
+  reaches it, wherever in a span it lies."""
+
+  G = ragged_block_attend._GROUPED_SPAN
+  PAGE, T, T_PAGES = 16, 64, 12
+  PARENTS = [-1, 0, 0, 2, -1, 4, 4, 6, -1, 8, 9, 9]
+
+  @classmethod
+  def _Packs(cls):
+    g, page = cls.G, cls.PAGE
+    deep = lambda pages, slots=7: (pages - 1) * page + slots - 1  # position
+    return {
+        # decode rows of 1, G - 1, G, G + 1 and 2G + 1 pages, the last page
+        # part full
+        "decode_rows": ([1] * 5, [deep(n) for n in (1, g - 1, g, g + 1,
+                                                   2 * g + 1)], 0, None),
+        # the same rows, the last page ONE slot full
+        "last_page_one_slot": ([1] * 5, [deep(n, 1) for n in (
+            1, g - 1, g, g + 1, 2 * g + 1)], 0, None),
+        # and the last page full to its last slot
+        "last_page_full": ([1] * 5, [deep(n, page) for n in (
+            1, g - 1, g, g + 1, 2 * g + 1)], 0, None),
+        # a window whose first page lies mid-stride: page0 = 3, 6 and 1 with
+        # G + 1, G and 2 pages in reach
+        "window_mid_stride": ([1, 1, 1, 0], [
+            (3 + g) * page + 5, (6 + g) * page - 2, 2 * page + 9, 4],
+                              g * page + 3, None),
+        # decode rows beside a chunk that starts mid-page and a 13-node tree
+        "decode_chunk_tree": ([1, 21, 1, 13, 1], [
+            deep(2 * g + 1), 5 * page + 3, deep(g + 1, 1), 3 * page + 1,
+            deep(2)], 0, {3: cls.PARENTS}),
+        "decode_chunk_tree_window": ([1, 21, 1, 13, 1], [
+            deep(2 * g + 1), 5 * page + 3, deep(g + 1, 1), 3 * page + 1,
+            deep(2)], 3 * page + 5, {3: cls.PARENTS}),
+    }
+
+  def _Case(self, name, heads, seed=7, poison=True):
+    lens, q_pos, window, parents = self._Packs()[name]
+    n, nk, h, tile = heads
+    page, t_pages, b = self.PAGE, self.T_PAGES, len(lens)
+    rows = ragged.BuildRaggedRows(np.array(lens), np.array(q_pos), self.T, 32,
+                                  row_parents=parents)
+    rows = ragged.RaggedRows(*(jnp.asarray(m) for m in rows))
+    tok = ragged.BuildTokenView(rows, b, t_pages, page)
+    rng = np.random.RandomState(seed)
+    np_total = b * t_pages + 1
+    tables = rng.permutation(np_total - 1).reshape(b, t_pages).astype(np.int32)
+    # every page no query may see is poison
+    seen = np.zeros((np_total,), bool)
+    for r, (n_r, pos) in enumerate(zip(lens, q_pos)):
+      if n_r:
+        lo = max(pos + 1 - window, 0) // page if window else 0
+        seen[tables[r, lo:(pos + n_r - 1) // page + 1]] = True
+    pools = [np.where(seen[:, None, None, None] | (not poison),
+                      rng.randn(np_total, page, nk // tile, h * tile), np.nan)
+             for _ in range(2)]
+    q = jnp.asarray(rng.randn(self.T, n, h) * h ** -0.5, jnp.float32)
+    tree_kw = dict(q_start=tok.q_start, anc_lo=rows.anc_lo,
+                   anc_hi=rows.anc_hi) if parents else {}
+
+    def _Call(lowering, tables=tables, pools=pools, span=None):
+      with pytest.MonkeyPatch.context() as mp:
+        if span is not None:
+          mp.setattr(ragged_block_attend, "_GROUPED_SPAN", span)
+        kp, vp = (jnp.asarray(x, jnp.float32) for x in pools)
+        return np.asarray(ragged_block_attend.RaggedAttend(
+            q, kp, vp, jnp.asarray(tables), tok.row, tok.q_end,
+            page_size=page, window=window, lowering=lowering, interpret=True,
+            **tree_kw))
+
+    key = ragged_block_attend.AttendPlanKey(
+        n, nk, h, page, q.dtype, jnp.float32, window=window,
+        tree=bool(parents), lowering="pallas")
+    blocks = ragged_block_attend.BuildAttendPlan(
+        key, tok.row, tok.q_end, *tree_kw.values(), b=b, t_pages=t_pages)
+    return key, blocks, rows, tok, tables, pools, seen, _Call
+
+  HEADS = {"group4": (8, 2, 128, 1), "group7_of_4": (28, 4, 128, 1),
+           "two_heads_a_row": (8, 2, 64, 2), "four_rows_of_two": (32, 8, 64, 2)}
+
+  @pytest.mark.parametrize("heads", list(HEADS))
+  @pytest.mark.parametrize("name", [
+      "decode_rows", "last_page_one_slot", "last_page_full",
+      "window_mid_stride", "decode_chunk_tree", "decode_chunk_tree_window"])
+  def test_a_span_a_program_is_bitwise_a_page_a_program(self, name, heads):
+    key, blocks, rows, tok, _, pools, _, call = self._Case(
+        name, self.HEADS[heads])
+    g = self.G
+    assert key.span == g > 1 and key.clear
+    assert ragged_block_attend.TileHeads(*self.HEADS[heads][:3]) == (
+        self.HEADS[heads][3])
+    n, page0, last = (np.asarray(x) for x in (blocks.n, blocks.page0,
+                                              blocks.last))
+    pairs = int(blocks.pairs)
+    blk, page = (np.asarray(x)[:pairs] for x in (blocks.blk, blocks.page))
+    rung = ragged_block_attend.ClearRung(
+        ragged_block_attend.BlockRungs(key.bq, key.lanes))
+    for i in np.flatnonzero(n > 0):
+      mine = page[blk == i]
+      if n[i] <= rung:       # a decode row: spans from page0, G pages apart
+        assert mine.tolist() == list(range(page0[i], last[i] + 1, g))
+      else:                  # a chunk's, a tree row's block: a page an entry
+        assert mine.tolist() == list(range(page0[i], last[i] + 1))
+    if not key.tree:
+      assert pairs == ragged_block_attend.Programs(
+          key, rows.row_q_pos, rows.row_len, self.T_PAGES)
+      assert pairs < ragged_block_attend.LivePairs(
+          key, rows.row_q_pos, rows.row_len, self.T_PAGES)
+    if name == "window_mid_stride":
+      assert sorted(page0[n > 0] % g)[-1] > 0 and np.any(
+          (last - page0)[n > 0] >= g)
+    out = call("pallas")
+    assert np.all(np.isfinite(out))
+    np.testing.assert_array_equal(out, call("pallas", span=1))
+    np.testing.assert_array_equal(out[np.asarray(tok.q_end) == 0], 0.0)
+    # the twin masks what it gathers, and a masked NaN is still one
+    clean = [np.nan_to_num(x) for x in pools]
+    np.testing.assert_allclose(out, call("xla", pools=clean), rtol=0,
+                               atol=2e-5)
+
+  @pytest.mark.parametrize("heads", ["group4", "two_heads_a_row"])
+  @pytest.mark.parametrize("name", ["decode_rows", "window_mid_stride",
+                                    "decode_chunk_tree"])
+  def test_a_stale_entry_inside_a_span_never_leaks(self, name, heads):
+    """`test_stale_table_entries_never_leak`'s page reuse, moved into a span:
+    every table entry past a row's last live page (the slots of its last span
+    that lie past `last`, and all behind them) and behind its window names
+    another row's LIVE page or a page of NaN; the output does not move by a
+    bit, at G and at G forced to 1."""
+    key, blocks, _, tok, tables, pools, seen, call = self._Case(
+        name, self.HEADS[heads])
+    lens, q_pos, window, _ = self._Packs()[name]
+    live_pages = np.flatnonzero(seen)
+    dead_pages = np.flatnonzero(~seen)
+    hostile = tables.copy()
+    rng = np.random.RandomState(11)
+    spans_with_dead_slots = 0
+    for r, (n_r, pos) in enumerate(zip(lens, q_pos)):
+      last = (pos + max(n_r, 1) - 1) // self.PAGE if n_r else -1
+      lo = max(pos + 1 - window, 0) // self.PAGE if window and n_r else 0
+      for p in range(self.T_PAGES):
+        if p > last or p < lo:
+          foreign = [x for x in live_pages if x not in tables[r, lo:last + 1]]
+          hostile[r, p] = rng.choice(foreign if (p + r) % 2 else dead_pages)
+      if n_r == 1 and (last - lo + 1) % self.G:
+        spans_with_dead_slots += 1
+    assert spans_with_dead_slots >= 2 and np.any(hostile != tables)
+    out = call("pallas")
+    assert np.all(np.isfinite(out))
+    np.testing.assert_array_equal(out, call("pallas", tables=hostile))
+    np.testing.assert_array_equal(out, call("pallas", tables=hostile, span=1))
+
+
 @pytest.mark.parametrize("shape", ["smallthinker", "trinity", "lfm2"])
 def test_the_kernel_probe_holds_the_clear_body_to_the_masked_one(shape,
                                                                  capsys):
   """tools/kernel_probe.py --case grouped_attend at the CPU's rehearsal
   sizes: a full and a window layer, a decode-only step and one with a chunk
-  whose blocks hold clear pages; `clear` is bitwise `masked` (`lfm2`: heads
-  of 64, two KV heads a row of the pool)."""
+  whose blocks hold clear pages; `clear` is bitwise `masked`, and so is
+  `span1`, the kernel at a program a page (`lfm2`: heads of 64, two KV heads a
+  row of the pool)."""
   import importlib.util
   import json
   import os
@@ -630,12 +788,19 @@ def test_the_kernel_probe_holds_the_clear_body_to_the_masked_one(shape,
   n, n_kv, windows = kernel_probe.ATTEND_SHAPES[shape][:3]
   assert [(l["window"], l["step"], l["variant"]) for l in lines] == [
       (w, s, v) for w in windows for s in ("decode", "chunk@0k")
-      for v in ("masked", "clear")]
-  assert all(l["bitwise_the_first"] for l in lines[1::2])
+      for v in ("masked", "clear", "span1")]
+  assert all(l["bitwise_the_first"] for l in lines
+             if l["variant"] != "masked")
+  # `span1` runs a program a page; the kernel as built fewer where a decode
+  # row holds more pages than one
+  for l in lines:
+    pages = l["decode_pairs"] + l["chunk_pairs"]
+    assert l["programs"] == pages if l["variant"] == "span1" else (
+        l["programs"] < pages)
   assert all(l["tiny"] and l["device"]["platform"] == "cpu"
              and (l["heads"], l["kv_heads"]) == (n, n_kv) for l in lines)
   for l in lines:
     chunk = l["step"] != "decode"
     assert (l["chunk_pairs"] > 0) == chunk and l["decode_pairs"] > 0
-    assert (l["clear_pairs"] > 0) == (chunk and l["variant"] == "clear")
+    assert (l["clear_pairs"] > 0) == (chunk and l["variant"] != "masked")
     assert ("us_a_chunk_pair" in l) == chunk

@@ -104,10 +104,12 @@ rows beside one 1,024-token chunk that starts mid-page past each of
 `--chunk_at`, where the shape's table holds it: `chunk@3k` ...). Variants,
 each `fn(q, k_pool, v_pool, tables, tokens, plan) -> [T, N, H]`: `masked` is
 the kernel with the plan's clear range emptied (every page takes the masked
-body: the parent's arithmetic), `clear` the kernel as the step runs it, and
-`parent` the same call through DIR/lingvo_tpu/ops/ragged_block_attend.py. A
-form under trial is registered in `ATTEND_VARIANTS` from a script of the
-builder's own. The plan is built outside the timed loop (a step builds it
+body: the parent's arithmetic), `clear` the kernel as the step runs it,
+`span1` the kernel with the pages a decode row's program walks
+(`_GROUPED_SPAN`) forced to 1 (PR 63's grid: a program a page; `span<n>`
+forces any n), and `parent` the same call through
+DIR/lingvo_tpu/ops/ragged_block_attend.py. A form under trial is registered
+in `ATTEND_VARIANTS` from a script of the builder's own. The plan is built outside the timed loop (a step builds it
 once for its layers); every variant's output is held BITWISE to the first's
 on the device. The time is a loop of `--calls` trips over the same pools, the
 queries behind a barrier with the trip's index. Prints ms a call and, from
@@ -478,7 +480,14 @@ def _AttendAsBuilt(rba):
       q, kp, vp, tables, *tokens, plan=plan, **kw)
 
 
-ATTEND_VARIANTS = {"masked": _AttendMasked, "clear": _AttendAsBuilt}
+ATTEND_VARIANTS = {"masked": _AttendMasked, "clear": _AttendAsBuilt,
+                   "span1": _AttendAsBuilt}
+
+
+def _ForcedSpan(name: str):
+  """The n of a variant `span<n>`, else None."""
+  return int(name[4:]) if name.startswith("span") and name[4:].isdigit() \
+      else None
 
 
 def AttendRows(step: str, rng, rows: int, budget: int, deepest: int,
@@ -560,6 +569,10 @@ def AttendMain(args) -> int:
         for name in names:
           rba = parent if name == "parent" else ragged_block_attend
           fn = ATTEND_VARIANTS.get(name, _AttendAsBuilt)(rba)
+          # the constant is read where a key is made, at every trace below
+          built_span = getattr(rba, "_GROUPED_SPAN", 1)
+          if _ForcedSpan(name) is not None:
+            rba._GROUPED_SPAN = _ForcedSpan(name)
           plan_key = rba.AttendPlanKey(n, n_kv, head_dim, PAGE, q.dtype,
                                        kp.dtype, window=window,
                                        lowering="pallas")
@@ -603,6 +616,8 @@ def AttendMain(args) -> int:
               "step": step, "variant": name, "ms_a_call": ms,
               "bitwise_the_first": same, "decode_pairs": decode_pairs,
               "chunk_pairs": chunk_pairs,
+              "programs": getattr(rba, "Programs", rba.LivePairs)(
+                  plan_key, context, lens, table_pages),
               "clear_pairs": 0 if name == "masked" else (
                   ragged_block_attend.ClearPairs(plan_key, context, lens,
                                                  table_pages)),
@@ -618,6 +633,7 @@ def AttendMain(args) -> int:
           elif name in decode_us:
             line["us_a_chunk_pair"] = (
                 ms * 1e3 - decode_us[name] * decode_pairs) / chunk_pairs
+          rba._GROUPED_SPAN = built_span
           lines.append(line)
           print(json.dumps(line), flush=True)
           _Append(args.out, [line])     # a line at a time: a long case
