@@ -17,7 +17,6 @@ the reference's sharding-by-annotation design (§2.9 of SURVEY.md).
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import NamedTuple, Sequence
 
@@ -203,9 +202,6 @@ class MultiHeadedAttention(base_layer.BaseLayer):
   against a KV cache (the Step-API equivalent, all-static shapes for jit).
   """
 
-  # RaggedStep writes its pages by the step's runs (ops/run_write.py): what
-  # the serving engine's `kv_write_runs` / `kv_write_tokens` count
-  writes_by_runs = True
   # the [D, N, H] projections (heads of 128) are re-laid for the MXU a layer
   # at a time, a copy as large as the weights, whichever width the product
   # runs: narrowed to the rows a step holds (ragged.OverLiveRows) the product
@@ -833,26 +829,15 @@ class MultiHeadedAttention(base_layer.BaseLayer):
         grouped=ragged_block_attend.Grouped(self.p.num_heads,
                                             self._num_kv_heads))
 
-  def RaggedQueriesPerToken(self) -> tuple[int, int]:
-    """(queries of a token the ragged kernel lays on the packed axis, those
-    of them that are the token's own): (1, 1) for plain multi-head
-    attention; a KV head's group where it serves several query heads,
-    padded to whole sublane tiles (the grouped kernel)."""
+  def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
+    """The engine's counters (ragged.StackStepCounts): block fill, by runs."""
     from lingvo_tpu.ops import ragged_block_attend
-    if self.kv_group == 1:
-      return 1, 1
-    return ragged_block_attend.GroupLanes(self.kv_group), self.kv_group
-
-  def RaggedBlockRows(self, page_size: int, kv_cache_dtype=None):
-    """queries -> the rows of M the ragged kernel's products run for a block
-    of that many valid queries (ops/ragged_block_attend.BlockRows on this
-    layer's ladder): what the engine's `attend_block_rows` sums."""
-    from lingvo_tpu.ops import ragged_block_attend
-    return functools.partial(
-        ragged_block_attend.BlockRows,
-        rungs=ragged_block_attend.BlockRungs(
-            self.RaggedQueryBlock(page_size, kv_cache_dtype),
-            self.RaggedQueriesPerToken()[0]))
+    del layers
+    group = self.kv_group
+    laid = ragged_block_attend.GroupLanes(group) if group > 1 else 1
+    return [ragged.BlockFillCount(
+        self.RaggedQueryBlock(geometry.page_size, geometry.kv_cache_dtype),
+        laid, group), ragged.RunWriteCount(geometry.page_size)]
 
   def _RaggedEligible(self, cached_states) -> bool:
     """Whether RaggedStep over these paged states calls RaggedAttend (else
@@ -1562,18 +1547,14 @@ class DifferentialAttention(base_layer.BaseLayer):
         n_kv, h, page_size, self.fprop_dtype, self.fprop_dtype,
         grouped=ragged_block_attend.Grouped(n, n_kv))
 
-  def RaggedQueriesPerToken(self) -> tuple[int, int]:
+  def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
+    """The block fill; the page write (`writes_by_plan`) is the stack's count."""
     from lingvo_tpu.ops import ragged_block_attend
+    del layers
     n, n_kv, _ = self._Grouped()
-    return ragged_block_attend.GroupLanes(n // n_kv), n // n_kv
-
-  def RaggedBlockRows(self, page_size: int, kv_cache_dtype=None):
-    from lingvo_tpu.ops import ragged_block_attend
-    return functools.partial(
-        ragged_block_attend.BlockRows,
-        rungs=ragged_block_attend.BlockRungs(
-            self.RaggedQueryBlock(page_size, kv_cache_dtype),
-            self.RaggedQueriesPerToken()[0]))
+    return [ragged.BlockFillCount(
+        self.RaggedQueryBlock(geometry.page_size, geometry.kv_cache_dtype),
+        ragged_block_attend.GroupLanes(n // n_kv), n // n_kv)]
 
   def BlockDecodeEligible(self, page_size: int) -> bool:
     """Whether the Pallas kernel serves this layer on a TPU (else the XLA

@@ -47,7 +47,6 @@ source's pass runs PagedStep).
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -109,7 +108,6 @@ def RotateInterleaved(x, pos, inv_freq):
 class MultiHeadLatentAttention(base_layer.BaseLayer):
   """MLA as a `TransformerAttentionLayer` mixer (`atten_tpl`)."""
 
-  writes_by_runs = True   # the page write is ops/run_write.WriteRowRuns
   # [.., N, H] projections, as MultiHeadedAttention's: kept outside the
   # step's conditionals on variables taken from their stacks whole
   relaid_weights = True
@@ -303,19 +301,15 @@ class MultiHeadLatentAttention(base_layer.BaseLayer):
     del page_size, kv_cache_dtype
     return latent_attend.QueryBlock(self.p.num_heads)
 
-  def RaggedQueriesPerToken(self) -> tuple[int, int]:
-    """(queries a token lays on the packed axis, its own): the heads padded
-    to whole tiles, and the heads."""
+  def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
+    """The engine's counters of this mixer's work: the block fill (a token's
+    heads, padded to whole tiles) and the page write, by the step's runs."""
     from lingvo_tpu.ops import latent_attend
-    return latent_attend.Lanes(self.p.num_heads), self.p.num_heads
-
-  def RaggedBlockRows(self, page_size: int, kv_cache_dtype=None):
-    from lingvo_tpu.ops import ragged_block_attend
-    return functools.partial(
-        ragged_block_attend.BlockRows,
-        rungs=ragged_block_attend.BlockRungs(
-            self.RaggedQueryBlock(page_size, kv_cache_dtype),
-            self.RaggedQueriesPerToken()[0]))
+    del layers
+    heads = self.p.num_heads
+    return [ragged.BlockFillCount(self.RaggedQueryBlock(geometry.page_size),
+                                  latent_attend.Lanes(heads), heads),
+            ragged.RunWriteCount(geometry.page_size)]
 
   # -- the serving step: the absorbed form -----------------------------------
 

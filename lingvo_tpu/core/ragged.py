@@ -67,7 +67,7 @@ one step program.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -280,3 +280,109 @@ def BuildRaggedRows(row_lens, row_q_pos, t: int, wmax: int,
                     row_q_pos=row_q_pos, row_len=row_lens,
                     row_cols=row_cols, pos_ids=pos_ids,
                     anc_lo=anc_lo, anc_hi=anc_hi, col_parent=col_parent)
+
+
+# -- what a step cost a stack's mixers, in the serving engine's counters -------
+
+
+class StepGeometry(NamedTuple):
+  """What every step of a serving engine is cut to (all static)."""
+  page_size: int
+  kv_cache_dtype: str | None  # the engine's override (None: each layer's own)
+  max_batch: int              # B, a step's rows
+  tokens: int                 # T, its packed width
+  table_pages: int            # its block tables' width
+
+
+class StepCount(NamedTuple):
+  """Names of observe.schema.ENGINE_COUNTER_KEYS and what a step adds to each:
+  the engine calls `count` once a step; all else is bound beforehand."""
+  names: tuple[str, ...]
+  count: Callable            # (row_q_pos, row_len: the host's numpy [B]
+  #                            int64) -> a tuple of ints, one a name
+  in_record: bool = False    # a step's trace record carries the names
+
+
+def BlockFillCount(bq: int, laid: int = 1, own: int = 1) -> StepCount:
+  """The block fill of an attention mixer whose kernel cuts a row's queries
+  into blocks of `bq`: a token lays `laid` queries on the packed axis (a KV
+  head's group, padded to whole tiles), `own` of them its own (the group)."""
+  from lingvo_tpu.ops import ragged_block_attend
+  rungs = ragged_block_attend.BlockRungs(bq, laid)
+
+  def _Count(row_q_pos, row_len):
+    # a row's queries fill whole blocks and one that holds the rest, whose
+    # products run the rows of M that many queries need (BlockRungs)
+    whole, rest = np.divmod(row_len * laid, bq)
+    return (int(np.sum(whole + (rest > 0))), int(np.sum(row_len)) * own,
+            int(np.sum(whole * bq
+                       + ragged_block_attend.BlockRows(rest, rungs))))
+
+  return StepCount(("attend_query_blocks", "attend_block_queries",
+                    "attend_block_rows"), _Count)
+
+
+_RUN_WRITES = ("kv_write_runs", "kv_write_tokens")
+
+
+def RunWriteCount(page_size: int) -> StepCount:
+  """A mixer that writes its pages by the step's runs: ops/run_write.py's."""
+  from lingvo_tpu.ops import run_write
+  return StepCount(_RUN_WRITES, lambda row_q_pos, row_len: run_write.RunCounts(
+      row_q_pos, row_len, page_size))
+
+
+def StackStepCounts(stack, cached_states, g: StepGeometry,
+                    page_writes: bool = False) -> list[StepCount]:
+  """A stack's `StepCounts(cached_states, geometry)`: the one list a serving
+  engine adds up a step (docs/serving_engine.md). A mixer contributes through
+  `StepCounts(geometry, layers)`, asked once a counting method with the layers
+  that share it; a name is fed by the first mixer that offers it. Beside
+  them, what the layers share (`page_writes`: the stack's own condition)."""
+  from lingvo_tpu.ops import diff_attend, ragged_block_attend, run_write
+  mixers = stack.MixerLayers()
+  kinds = {}   # counting method -> [its first mixer, layers that share it]
+  for m, reps in mixers:
+    if hasattr(m, "StepCounts"):
+      kinds.setdefault(type(m).StepCounts, [m, 0])[1] += reps
+  counts, fed = [], set()
+  for m, layers in kinds.values():
+    for c in m.StepCounts(g, layers):
+      if fed.isdisjoint(c.names):
+        counts.append(c)
+        fed.update(c.names)
+  # the slot state a live row reads and writes, over every mixer that keeps
+  # one; in the record beside a tail's rows (ShortConvLayer's stack)
+  slot_bytes = 2 * sum(reps * m.StateBytesPerSlot() for m, reps in mixers
+                       if hasattr(m, "StateBytesPerSlot"))
+  if slot_bytes:
+    counts.append(StepCount(("slot_state_bytes",), lambda row_q_pos, row_len: (
+        slot_bytes * int((row_len > 0).sum()),), "conv_tail_rows" in fed))
+  # the attend kernels' plans, one a DISTINCT key (not a layer): their pages,
+  # unmasked pages and programs, in the record where a kernel reads `clear`;
+  # and the pairs their lists have room for (the grids before PR 46)
+  keys = {k for k in stack.RaggedPlanKeys(cached_states) if k.kernel}
+  if keys:
+    grid = sum(ragged_block_attend.GridPairs(k, g.max_batch, g.tokens,
+                                             g.table_pages) for k in keys)
+    pairs = lambda row_q_pos, row_len: tuple(int(n) for n in np.sum([
+        ragged_block_attend.PairCounts(k, row_q_pos, row_len, g.table_pages)
+        for k in keys], axis=0))
+    counts += [
+        StepCount(("attend_live_pairs", "attend_clear_pairs",
+                   "attend_programs"), pairs, any(k.clear for k in keys)),
+        StepCount(("attend_grid_pairs",), lambda row_q_pos, row_len: (grid,))]
+  if page_writes:
+    # ops/diff_attend.WritePages: a run is a (row, page) pair, under the
+    # bound its grid ran before PR 56; one count where a layer writes by runs
+    bound = diff_attend.PageWrites(g.max_batch, g.tokens, g.page_size)
+    names = ("kv_page_writes", "kv_page_write_bound") + (
+        _RUN_WRITES if _RUN_WRITES[0] in fed else ())
+
+    def _Writes(row_q_pos, row_len):
+      runs, tokens = run_write.RunCounts(row_q_pos, row_len, g.page_size)
+      return (runs, bound, runs, tokens)[:len(names)]
+
+    counts = [c for c in counts if c.names != _RUN_WRITES] + [
+        StepCount(names, _Writes)]
+  return counts

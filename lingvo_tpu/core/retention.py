@@ -28,6 +28,7 @@ cursor on, which is the open chunk's).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
@@ -109,6 +110,14 @@ class PowerRetention(base_layer.BaseLayer):
   def StateBytesPerSlot(self) -> int:
     """S and z of one sequence as stored, f32."""
     return op.StateBytes(self._nk, self._h)
+
+  def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
+    """The engine's counters (ragged.StackStepCounts): A LAYER's, not times
+    the layers (op.StepCounts)."""
+    del layers
+    return [ragged.StepCount(
+        ("retention_rows", "retention_folds", "retention_chunk_tokens"),
+        functools.partial(op.StepCounts, page=geometry.page_size), True)]
 
   def StoredFeatureDim(self) -> int:
     return op.StoredDim(self._h)

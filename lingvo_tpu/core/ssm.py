@@ -749,9 +749,6 @@ class Mamba2Layer(base_layer.BaseLayer):
   # writes its layer's where they lie; sliced a trip and stacked back they
   # would be copied twice a layer (268 MB each at 64 slots of 8,192 x 128)
   stack_states = ("scan",)
-  # the engine counter of (live rows x layers) whose whole slot state a step
-  # reads and writes (serving/engine.py, observe/schema.py)
-  state_rows_counter = "ssd_state_rows"
 
   @classmethod
   def Params(cls):
@@ -809,6 +806,15 @@ class Mamba2Layer(base_layer.BaseLayer):
     """Scan state and convolution tail of one sequence, f32."""
     p = self.p
     return 4 * (self._e * p.state_dim + (p.conv_width - 1) * self._c)
+
+  def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
+    """The engine's counters (ragged.StackStepCounts): the live rows (each reads
+    and writes its whole state) and the one-token rows, times the layers."""
+    del geometry
+    return [ragged.StepCount(
+        ("ssd_state_rows", "ssd_narrow_rows"), lambda row_q_pos, row_len: (
+            layers * int((row_len > 0).sum()),
+            layers * int((row_len == 1).sum())), True)]
 
   # -- the layer's arithmetic ------------------------------------------------
 
@@ -970,10 +976,6 @@ class ShortConvLayer(base_layer.BaseLayer):
   projections in the fprop dtype.
   """
 
-  # the engine counter of (live rows x layers) whose tail a step rewrote
-  # (serving/engine.py, observe/schema.py)
-  state_rows_counter = "conv_tail_rows"
-
   @classmethod
   def Params(cls):
     p = super().Params()
@@ -995,6 +997,12 @@ class ShortConvLayer(base_layer.BaseLayer):
   def StateBytesPerSlot(self) -> int:
     """The convolution tail of one sequence, f32."""
     return 4 * (self.p.conv_width - 1) * self.p.input_dim
+
+  def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
+    """The engine's counter (ragged.StackStepCounts): live rows x layers."""
+    del geometry
+    return [ragged.StepCount(("conv_tail_rows",), lambda row_q_pos, row_len: (
+        layers * int((row_len > 0).sum()),), True)]
 
   def _Gates(self, th, x):
     """x [.., D] -> (u = B * X [.., D] f32, C [.., D] f32)."""

@@ -559,6 +559,10 @@ class StackedTransformerLayers(base_layer.BaseLayer):
     the serving census counts and prices (serving/kv_cache.StackCensus)."""
     return [(l.self_atten.atten, 1) for l in self.x_layers]
 
+  # StepCounts(cached_states, geometry): what a step of the host's rows cost
+  # this stack's mixers, in the serving engine's counters; asked once
+  StepCounts = ragged.StackStepCounts
+
   def PageWindows(self):
     """None, or where this block's layers are attention layers of two
     kinds, full and sliding-window: each layer's window (0 = full). Such a
@@ -809,6 +813,8 @@ class RepeatedTransformerLayer(base_layer.BaseLayer):
     else:
       inner = []
     return [(m, reps * self.p.num_layers) for m, reps in inner]
+
+  StepCounts = ragged.StackStepCounts   # as StackedTransformerLayers'
 
   def PageWindows(self):
     """The body's (StackedTransformerLayers.PageWindows), or None."""
@@ -1246,6 +1252,10 @@ class BlockSequence(base_layer.BaseLayer):
     ops/diff_attend.WritePages' kernel, which takes the step's WritePlan."""
     return any(a.writes_by_plan and key.kernel for a, key in zip(
         self._AttentionMixers(), self.RaggedPlanKeys(cached_states)))
+
+  def StepCounts(self, cached_states, geometry: ragged.StepGeometry) -> list:
+    return ragged.StackStepCounts(self, cached_states, geometry,
+                                  self.WritesWholePages(cached_states))
 
   def SharedKvReadLayers(self) -> int:
     """Layers that read pages they do not own."""

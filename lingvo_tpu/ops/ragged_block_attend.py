@@ -276,7 +276,7 @@ def QueryBlock(n: int, h: int, page_size: int, q_dtype, kv_dtype,
   pages dequantize; the q block and the f32 accumulator; the lane-broadcast
   softmax statistics; the per-query mask columns; two `[N, Bq, P]` f32
   score tiles) passes `_VMEM_BUDGET`. A function of shapes and dtypes
-  alone: the engine calls it for its counters, nothing chooses it."""
+  alone: the counters read it (a mixer's `StepCounts`), nothing chooses it."""
   q_bytes = jnp.dtype(q_dtype).itemsize
   kv_bytes = jnp.dtype(kv_dtype).itemsize
   lanes_p = max(page_size, LANES)
@@ -314,8 +314,7 @@ def BlockRungs(bq: int, lanes: int = 1) -> tuple[int, ...]:
   16 tokens (5.29 against 9.09 ms, 64 such rows), which no cell sends, and
   every rung is traced again for every kernel of a step program, 0.4-0.6 s
   each on the benchmark's host (PERF.md section 6, PR 36). A function of
-  shapes alone, like `QueryBlock`: the engine calls it for its counters,
-  nothing chooses it."""
+  shapes alone, like `QueryBlock`: the counters read it, nothing chooses it."""
   return (lanes, bq) if lanes < bq else (bq,)
 
 

@@ -2,16 +2,12 @@
 
 Glues the layers below into a running service:
 
-    ops/ragged_block_attend.py   the packed-token paged attention kernel
+    core/transformer.py          the stack: the step's forward pass, its counts
     serving/kv_cache.py          host-side page and slot ownership, and the
                                  census of the stack that prices them
     serving/state_layout.py      which leaves of the decode state are pages
                                  or a slot's; the gather / scatter over them
     serving/scheduler.py         admission / step building / retirement
-
-(ops/block_decode.py, the [B, C]-shape paged attention kernels, is not among
-them: no engine step calls it. The draft sources' PagedStep and GatedSSMLayer
-do: serving/spec_decode.py.)
 
 Device-side there is ONE compiled step program: every serving iteration
 packs its work onto a single static [T] token axis (core/ragged.py) — a
@@ -99,10 +95,6 @@ from lingvo_tpu.core import sampling
 from lingvo_tpu.observe import profile as observe_profile
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.observe import trace as observe_trace
-from lingvo_tpu.ops import diff_attend
-from lingvo_tpu.ops import power_retention
-from lingvo_tpu.ops import ragged_block_attend
-from lingvo_tpu.ops import run_write
 from lingvo_tpu.quant import weights as quant_weights
 from lingvo_tpu.serving import kv_cache
 from lingvo_tpu.serving import prefix_cache as prefix_cache_lib
@@ -538,73 +530,20 @@ class ServingLoop:
     # compiled step will take, and count ineligible (dense-fallback) steps
     self.paged_path = self._ClassifyPath()
     # block size of the ragged attend kernel at this stack's shapes (0: no
-    # attention layer); only the block-fill counters read it
-    # (the ragged attend kernels': a mixer that reads its pages through
-    # kernels of its own, core/retention.PowerRetention, has none)
-    attens = [m for m in self._page_readers
-              if hasattr(m, "RaggedQueryBlock")]
+    # attention layer, or one with kernels of its own, PowerRetention): what
+    # the block-fill counters' reader divides by (benchmarks/harness)
+    attens = [m for m in self._page_readers if hasattr(m, "RaggedQueryBlock")]
     self._attend_bq = (attens[0].RaggedQueryBlock(page_size, kv_cache_dtype)
                        if attens else 0)
-    # a token is (laid, own) of the kernel's queries where a KV head serves
-    # a group of query heads (ops/ragged_block_attend.RaggedAttend)
-    self._attend_laid, self._attend_own = (
-        attens[0].RaggedQueriesPerToken() if attens else (1, 1))
-    # queries of a block -> the rows of M its products run
-    self._attend_rows = (attens[0].RaggedBlockRows(page_size, kv_cache_dtype)
-                         if attens else None)
-    # the attend kernels the step program calls, and the plans (sets of
-    # query-block descriptors) it builds for them once a step: a count of
-    # the program, from what the stack declares over these states
-    kernel_keys = [k for k in getattr(
-        task.stack, "RaggedPlanKeys", lambda states: [])(self._states)
+    # the attend kernels the step program calls, and the plans it builds
+    kernel_keys = [k for k in task.stack.RaggedPlanKeys(self._states)
                    if k.kernel]
     self.attend_calls = len(kernel_keys)
     self.attend_plans = len(set(kernel_keys))
-    # the plans' keys, and the pairs their lists have room for together: the
-    # (block, page) grids the kernels ran before they ran the step's live
-    # pairs alone
-    self._attend_plan_keys = set(kernel_keys)
-    self._attend_grid_pairs = sum(
-        ragged_block_attend.GridPairs(key, max_batch, self._ragged_t,
-                                      table_pages)
-        for key in self._attend_plan_keys)
-    self._table_pages = table_pages
-    # those whose kernel runs no mask at a page every query of a block sees
-    # whole (the grouped attend kernel's and ops/latent_attend.py's), counted
-    # as `attend_clear_pairs`
-    self._attend_clear_keys = {k for k in self._attend_plan_keys if k.clear}
-    # some layer writes its pages by the step's runs (ops/run_write.py)
-    self._kv_write_by_runs = any(
-        getattr(m, "writes_by_runs", False) for m, _ in self._mixer_layers)
-    # some layer writes whole pages through the step's WritePlan
-    # (ops/diff_attend.WritePages' kernel: the stack's own condition,
-    # BlockSequence.RaggedStep): the pairs such a step's grid is bounded by
-    self._kv_page_write_bound = diff_attend.PageWrites(
-        max_batch, self._ragged_t, page_size) if getattr(
-            task.stack, "WritesWholePages", lambda states: False)(
-                self._states) else 0
     # expert layers: their [layers, experts] token counts leave the step
     # program beside the tokens (None: the stack has none)
     self._moe_layers = _MoeCountLeaves(self._states)
     self._moe_shares = _MoeCountLeaves(self._states, "elsewhere", 1) is not None
-    # power-retention layers: mixers that hold pages AND a slot state
-    self._retention_layers = sum(
-        reps for m, reps in self._mixer_layers
-        if hasattr(m, "StateBytesPerSlot") and hasattr(m, "KvBytesPerToken"))
-    # layers whose slot state every live row reads and writes whole each
-    # step, by the counter the mixer names (Mamba-2)
-    self._ssd_layers = sum(
-        reps for m, reps in self._mixer_layers
-        if getattr(m, "state_rows_counter", None) == "ssd_state_rows")
-    # layers whose slot state is a convolution tail alone (ShortConvLayer),
-    # and the bytes of slot state a live row reads and writes a step over
-    # every mixer that keeps one
-    self._conv_tail_layers = sum(
-        reps for m, reps in self._mixer_layers
-        if getattr(m, "state_rows_counter", None) == "conv_tail_rows")
-    self._slot_state_bytes_a_row = 2 * sum(
-        reps * m.StateBytesPerSlot() for m, reps in self._mixer_layers
-        if hasattr(m, "StateBytesPerSlot"))
     # layers that read pages another layer owns (0: the stack has none)
     self._shared_kv_read_layers = getattr(
         task.stack, "SharedKvReadLayers", lambda: 0)()
@@ -616,6 +555,14 @@ class ServingLoop:
     self._counters = {
         k: self.metrics.Counter(f"serving/{k}")
         for k in observe_schema.ENGINE_COUNTER_KEYS}
+    # what a step's rows cost the stack's mixers, in their own counters: the
+    # stack's word (core/ragged.StackStepCounts), asked once
+    step_counts = task.stack.StepCounts(self._states, ragged_lib.StepGeometry(
+        page_size, kv_cache_dtype, max_batch, self._ragged_t, table_pages))
+    self._step_counts = [([self._counters[k] for k in c.names], c.count)
+                         for c in step_counts]
+    self._record_counts = [k for c in step_counts if c.in_record
+                           for k in c.names]
     # engine configuration facts + live sub-surfaces. Section callbacks
     # deliberately read WITHOUT the engine lock (a registry snapshot
     # holding the registry lock must never wait on the engine lock —
@@ -1286,11 +1233,10 @@ class ServingLoop:
       spans.Abandon()   # a no-op after the step's End
 
   def _StepCounters(self):
-    """What a step's record carries of the cumulative counters whose
-    readers want them between two steps: expert load as of the newest
-    RETIRED step (one behind the record's own), window pages, the hybrid
-    stack's token counts and the attend kernels' pairs as of this step's
-    dispatch. None where the stack has none of them."""
+    """What a step's record carries of the cumulative counters whose readers
+    want them between two steps: expert load as of the newest RETIRED step
+    (one behind the record's own), window pages, and as of this step's dispatch
+    the hybrid stack's tokens and what the stack's `StepCounts` asks."""
     out = {}
     if self._moe_layers is not None:
       out.update((k, self._counters[k].value) for k in (
@@ -1302,18 +1248,7 @@ class ServingLoop:
     if self.state_pool is not None:
       out.update((k, self._counters[k].value) for k in (
           "ssm_tokens", "cross_tokens_unread"))
-    if self._ssd_layers:
-      out.update((k, self._counters[k].value) for k in (
-          "ssd_state_rows", "ssd_narrow_rows"))
-    if self._retention_layers:
-      out.update((k, self._counters[k].value) for k in (
-          "retention_rows", "retention_folds", "retention_chunk_tokens"))
-    if self._conv_tail_layers:
-      out.update((k, self._counters[k].value) for k in (
-          "conv_tail_rows", "slot_state_bytes"))
-    if self._attend_clear_keys:
-      out.update((k, self._counters[k].value) for k in (
-          "attend_live_pairs", "attend_clear_pairs", "attend_programs"))
+    out.update((k, self._counters[k].value) for k in self._record_counts)
     return out or None
 
   def _NoteDispatch(self, batch):
@@ -1322,8 +1257,9 @@ class ServingLoop:
     count it."""
     desc = batch.rows_desc
     row_len = np.asarray(desc.row_len, np.int64)
+    tokens = int(row_len.sum())
     if self.state_pool is not None:
-      self._counters["ssm_tokens"].Inc(int(row_len.sum()))
+      self._counters["ssm_tokens"].Inc(tokens)
       # of a prefill row's tokens only the prompt's last one is sampled from
       # (before the cursors advance: prompt_remaining is as the step finds it)
       self._counters["cross_tokens_unread"].Inc(sum(
@@ -1331,25 +1267,9 @@ class ServingLoop:
           for seq, n in zip(batch.rows, row_len)
           if seq is not None and n > 0
           and seq.state is scheduler_lib.SeqState.PREFILL))
-    if self._ssd_layers:
-      self._counters["ssd_state_rows"].Inc(
-          self._ssd_layers * int((row_len > 0).sum()))
-      # ... and those whose row is one token: the row pass's narrow body
-      self._counters["ssd_narrow_rows"].Inc(
-          self._ssd_layers * int((row_len == 1).sum()))
-    if self._slot_state_bytes_a_row:
-      live = int((row_len > 0).sum())
-      self._counters["conv_tail_rows"].Inc(self._conv_tail_layers * live)
-      self._counters["slot_state_bytes"].Inc(
-          self._slot_state_bytes_a_row * live)
-    if self._retention_layers:
-      # power-retention layers: rows with a state, pages folded into one and
-      # keys attended in open chunks, a layer (before the cursors advance)
-      live, folds, attended = power_retention.StepCounts(
-          desc.row_q_pos, row_len, self.page_size)
-      self._counters["retention_rows"].Inc(live)
-      self._counters["retention_folds"].Inc(folds)
-      self._counters["retention_chunk_tokens"].Inc(attended)
+    for counters, count in self._step_counts:   # each by its owner's function
+      for counter, n in zip(counters, count(desc.row_q_pos, row_len)):
+        counter.Inc(n)
     if self.trace is not None and batch.mixed:
       # emit prefill-chunk spans BEFORE the cursors advance
       for i, seq in enumerate(batch.rows):
@@ -1362,37 +1282,9 @@ class ServingLoop:
     if self._in_flight:
       self._counters["steps_overlapped"].Inc()
     self._counters["mixed_steps" if batch.mixed else "decode_steps"].Inc()
-    if 0 < self._narrow_rows and int(row_len.sum()) <= self._narrow_rows:
+    if 0 < self._narrow_rows and tokens <= self._narrow_rows:
       self._counters["narrow_steps"].Inc()
     self._counters["prompt_tokens"].Inc(batch.prompt_tokens)
-    if self._attend_bq:
-      # a row's queries fill whole blocks and then one that holds the rest
-      whole, rest = np.divmod(row_len * self._attend_laid, self._attend_bq)
-      self._counters["attend_query_blocks"].Inc(
-          int(np.sum(whole + (rest > 0))))
-      self._counters["attend_block_queries"].Inc(
-          int(np.sum(row_len)) * self._attend_own)
-      self._counters["attend_block_rows"].Inc(int(np.sum(
-          whole * self._attend_bq + self._attend_rows(rest))))
-    if self._attend_plan_keys:
-      live, clear, programs = np.sum([
-          ragged_block_attend.PairCounts(key, desc.row_q_pos, row_len,
-                                         self._table_pages)
-          for key in self._attend_plan_keys], axis=0)
-      self._counters["attend_live_pairs"].Inc(int(live))
-      self._counters["attend_clear_pairs"].Inc(int(clear))
-      self._counters["attend_programs"].Inc(int(programs))
-      self._counters["attend_grid_pairs"].Inc(self._attend_grid_pairs)
-    if self._kv_write_by_runs or self._kv_page_write_bound:
-      runs, tokens = run_write.RunCounts(desc.row_q_pos, row_len,
-                                         self.page_size)
-      if self._kv_write_by_runs:
-        self._counters["kv_write_runs"].Inc(runs)
-        self._counters["kv_write_tokens"].Inc(tokens)
-      if self._kv_page_write_bound:
-        # a run is a row's tokens in one page: a (row, page) pair
-        self._counters["kv_page_writes"].Inc(runs)
-        self._counters["kv_page_write_bound"].Inc(self._kv_page_write_bound)
     if self.paged_path == "dense":
       self._counters["dense_fallback_steps"].Inc()
     if self._kv_quantized:

@@ -924,7 +924,8 @@ def test_the_engine_counts_a_grouped_models_clear_pairs():
     eng = engine_lib.ServingLoop(
         task, theta, page_size=PAGE, num_pages=48, max_batch=4,
         max_seq_len=128, prefill_token_budget=8)
-    keys = eng._attend_clear_keys
+    keys = {k for k in task.stack.RaggedPlanKeys(eng._states)
+            if k.kernel and k.clear}
     assert len(keys) == 2 == eng.Stats()["attend_plans"]
     assert sorted(k.window > 0 for k in keys) == [False, True]
     seen, note = [], eng._NoteDispatch
@@ -938,7 +939,7 @@ def test_the_engine_counts_a_grouped_models_clear_pairs():
     long = eng.Submit(list(range(1, 41)), 4, eos_id=None, seed=13)
     while not long.done:
       eng.StepOnce()
-  pages = eng._table_pages
+  pages = eng.sched.table_pages
   by_step = [sum(rba.ClearPairs(k, q_pos, n, pages) for k in keys)
              for q_pos, n in seen]
   assert sum(by_step) == eng.Stats()["attend_clear_pairs"]

@@ -736,7 +736,7 @@ def test_the_engine_counts_the_pairs_that_ran_no_mask(tiny, monkeypatch):
   monkeypatch.setattr(mla_lib.MultiHeadLatentAttention, "_Lowering",
                       lambda self, page_size: "pallas")
   eng = _Engine(task, theta, 2)
-  (key,) = eng._attend_clear_keys
+  (key,) = {k for k in task.stack.RaggedPlanKeys(eng._states) if k.kernel}
   assert key.clear and eng.Stats()["attend_plans"] == 1
   seen, note = [], eng._NoteDispatch
 
@@ -751,7 +751,7 @@ def test_the_engine_counts_the_pairs_that_ran_no_mask(tiny, monkeypatch):
   while not long.done:
     eng.StepOnce()
   st = eng.Stats()
-  pages = eng._table_pages
+  pages = eng.sched.table_pages
   assert st["attend_clear_pairs"] == sum(
       rba.ClearPairs(key, q_pos, n, pages) for q_pos, n in seen)
   assert st["attend_live_pairs"] == sum(

@@ -249,8 +249,10 @@ class TestCompileRecord:
 # -- phases -------------------------------------------------------------------
 
 
-def _PhasesSince(n):
-  return profile_lib.Startup().Phases()[n:]
+def _PhasesSince(t):
+  """The phases that began after `t` on the record's clock (by time, not by
+  position: the record's lists are bounded and drop their oldest)."""
+  return [p for p in profile_lib.Startup().Phases() if p["start"] >= t]
 
 
 def _Inside(inner, outer):
@@ -293,7 +295,7 @@ class TestPhases:
     assert p["thread"] == t.ident != threading.get_ident()
 
   def test_serving_loop_phases_nest_and_lie_in_order(self, tiny_lm):  # noqa: F811
-    n = len(profile_lib.Startup().Phases())
+    n = profile_lib.Startup().clock()
     eng = _Engine(tiny_lm)
     eng.Start()
     try:
@@ -335,7 +337,7 @@ class TestPhases:
       assert k in records["ragged"], k
 
   def test_an_engine_stepped_by_its_caller_opens_first_steps(self, tiny_lm):  # noqa: F811
-    n = len(profile_lib.Startup().Phases())
+    n = profile_lib.Startup().clock()
     eng = _Engine(tiny_lm)
     eng.RunBatch(np.ones((1, 3), np.int32), np.array([3]), 2)
     names = [p["phase"] for p in _PhasesSince(n)]
@@ -343,7 +345,7 @@ class TestPhases:
     assert _PhasesSince(n)[-1]["thread"] == threading.get_ident()
 
   def test_layout_is_a_phase_where_it_runs(self, tiny_lm):  # noqa: F811
-    n = len(profile_lib.Startup().Phases())
+    n = profile_lib.Startup().clock()
     eng = _Engine(tiny_lm)
     eng._Layout()
     eng._Layout()
@@ -354,7 +356,7 @@ class TestPhases:
                                   dict(async_infeed=True)],
                            ids=["sync", "async"])
   def test_train_program_phases_nest_and_lie_in_order(self, tmp_path, kw):
-    n = len(profile_lib.Startup().Phases())
+    n = profile_lib.Startup().clock()
     n_loops = len(profile_lib.Startup().Loops())
     task, prog = _TrainProgram(str(tmp_path), **kw)
     state = task.CreateTrainState(jax.random.PRNGKey(0))
@@ -390,8 +392,15 @@ class TestPhases:
 
   def test_a_first_run_with_no_compile_before_it_is_the_named_program(
       self, tmp_path):
-    n = len(profile_lib.Startup().Programs())
-    n_events = len(profile_lib.Startup().Events())
+    # The process's record is bounded (its lists drop their oldest entries),
+    # so a position in it means nothing on a worker that compiled a thousand
+    # programs before this test: the test's own rows and events are those of
+    # its two programs' names that began after it did. What a warm worker may
+    # change is where an executable came from: a cached one reads `backend_s`
+    # 0.0 with `fetch_s` > 0, which is why only their sum is held below.
+    record = profile_lib.Startup()
+    began = record.clock()
+    mine = ("train/flops/step", "train/compile/step")
     task, prog = _TrainProgram(str(tmp_path))
     state = task.CreateTrainState(jax.random.PRNGKey(0))
     results = []
@@ -400,18 +409,18 @@ class TestPhases:
       results.append(res)
     prog.Flush()
     prog.Shutdown()
-    rows = profile_lib.Startup().Programs()[n:]
+    rows = [r for r in record.Programs() if r["program"] in mine
+            and record.zero + r["at_s"] >= began - 1e-3]
     # the lowering the flops are counted from, then the first dispatch: each
     # once, round the step function alone (no batch placement, no infeed)
-    assert [r["program"] for r in rows] == ["train/flops/step",
-                                            "train/compile/step"]
+    assert [r["program"] for r in rows] == list(mine)
     flops, step = rows
     assert flops["trace_s"] > 0 and flops["lower_s"] > 0
     assert flops["backend_s"] == flops["fetch_s"] == 0.0
     assert step["backend_s"] + step["fetch_s"] > 0
-    mine = [e for e in profile_lib.Startup().Events()[n_events:]
-            if e.program in ("train/flops/step", "train/compile/step")]
-    assert mine and {e.unit.kind for e in mine} == {"loop"}
+    events = [e for e in record.Events()
+              if e.program in mine and e.start >= began]
+    assert events and {e.unit.kind for e in events} == {"loop"}
     assert prog.compile_records == {}                  # an AOT record's place
     # the loop that compiled says so, beside host_overhead_s; the next is 0
     first, second = results[0], results[-1]

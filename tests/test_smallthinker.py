@@ -413,6 +413,18 @@ def test_released_window_pages_are_never_read(tiny, case):
   assert all(np.isfinite(v).all() for v in seen.values())
 
 
+def _BlockFill(task, eng):
+  """The block-fill entry of the first attention mixer's counting method
+  (ragged.StepCount), at the engine's geometry."""
+  mixer = task.stack.MixerLayers()[0][0]
+  (fill, _) = mixer.StepCounts(ragged_lib.StepGeometry(
+      eng.page_size, None, eng.max_batch, eng._ragged_t,
+      eng.sched.table_pages), 1)
+  assert fill.names == ("attend_query_blocks", "attend_block_queries",
+                        "attend_block_rows")
+  return fill
+
+
 def test_one_pool_of_uniform_pages_for_both_kinds(tiny):
   task, theta = tiny
   eng = engine_lib.ServingLoop(task, theta, page_size=8, num_pages=48,
@@ -428,8 +440,10 @@ def test_one_pool_of_uniform_pages_for_both_kinds(tiny):
   kv = eng.Stats()["kv_pages"]
   assert kv["num_pages"] == 192 and kv["window_cap_pages"] == 6
   assert set(kv["kinds"]) == {"full", "window"}
-  # a KV head's group of three query heads rides the packed axis
-  assert eng._attend_own == 3
+  # a KV head's group of three query heads rides the packed axis: a
+  # one-token row is three of its block's queries
+  assert _BlockFill(task, eng).count(np.zeros(1, np.int64),
+                                     np.ones(1, np.int64))[:2] == (1, 3)
 
 
 @pytest.mark.parametrize("kw,names", [
@@ -479,7 +493,9 @@ def test_engine_counts_the_rows_a_block_runs(tiny):
   while not handle.done:
     eng.StepOnce()
   st = eng.Stats()
-  laid, own = eng._attend_laid, eng._attend_own
+  # a one-token row: a block, the token's own queries, the rows it runs
+  _, own, laid = _BlockFill(task, eng).count(np.zeros(1, np.int64),
+                                             np.ones(1, np.int64))
   bq = eng._attend_bq
   assert (laid, bq) == (8, 512)
   assert rba.BlockRungs(bq, laid) == (laid, bq)
