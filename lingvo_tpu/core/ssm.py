@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from lingvo_tpu import observe
 from lingvo_tpu.core import base_layer
@@ -409,25 +410,59 @@ def _PackedConv(u32, held_tail, conv_w, rows):
   """The causal depthwise convolution's sum over the packed token axis
   (bias and activation are the caller's). u32: [T, C] f32, this step's
   inputs; held_tail: [B, K - 1, C], every slot's last K - 1 inputs; conv_w:
-  [K, C]. -> sum [T, C] f32. A token reads tokens BEFORE it on the packed
-  axis and its row's tail alone, so the sum over a prefix of the axis (u32,
-  `rows.row_of` and `rows.col_of` cut to it) is that prefix of the sum."""
+  [K, C]. -> sum [T, C] f32, in two parts:
+
+  - the step's own tokens: `w[K-1-back] * u32[t - back]` where the token's
+    row has that token (`col >= back`), zero where it has not: elementwise
+    over shifted views of the one operand, which XLA fuses into one pass;
+  - the tails: a token reads its slot's tail only while `col < K - 1`, at
+    most K - 1 tokens a row. Their share, `sum_{back > col} w[K-1-back] *
+    tail[row, K-1-back+col]`, is a dense [K - 1, B, C] expression over
+    `_FreshTail`, laid at those tokens' places (`rows.row_cols`) by a one-hot
+    product [T, (K - 1) B] x [(K - 1) B, C] that XLA fuses into the same
+    pass (exact in f32: one term a token, at the highest precision). No array
+    of T rows is gathered from the tails. The tokens it reaches are the
+    engine's `conv_tail_tokens` (the layers' `StepCounts`).
+
+  A token reads tokens BEFORE it on the packed axis and its row's tail alone,
+  so the sum over a prefix of the axis (u32, `rows.row_of` and `rows.col_of`
+  cut to it; the per-row fields whole: a tail's place beyond the prefix
+  meets no token) is that prefix of the sum."""
   k = conv_w.shape[0]
   t = u32.shape[0]
-  slots = held_tail.shape[0]
-  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, slots - 1)
-  col = rows.col_of.astype(jnp.int32)
-  tail = _FreshTail(held_tail, rows)                            # [B, K-1, C]
   w = conv_w.astype(jnp.float32)
+  has = rows.col_of.astype(jnp.int32)[:, None] >= np.arange(1, k)  # [T, K-1]
   conv = w[k - 1] * u32
   for back in range(1, k):
-    # the input `back` tokens before: of this step where the row has
-    # it, else of the slot's tail
-    here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
-    held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
-    conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here,
-                                        held)
-  return conv
+    # the operand moved down by `back` rows: one `lax.pad`, the far edge cut
+    here = jax.lax.pad(u32, np.float32(0), ((back, -back, 0), (0, 0, 0)))
+    conv += jnp.where(has[:, back - 1:back], w[k - 1 - back] * here, 0.0)
+  # the token at column j of a row reads its tail's entries j .. K - 2: the
+  # shares column by column, [n, B, C], over the tails laid entry by entry
+  n = min(k - 1, rows.row_cols.shape[1])
+  tail = jnp.pad(jnp.swapaxes(_FreshTail(held_tail, rows), 0, 1),
+                 ((0, k - 2), (0, 0), (0, 0)))                  # [2K-3, B, C]
+  share = w[0] * tail[:n]
+  for i in range(1, k - 1):
+    share += w[i] * tail[i:i + n]
+  # zero where no token reads it: a slot the step does not hold may keep
+  # anything, and its place in `row_cols` is then some token's (+ 0.0)
+  reads = np.arange(n)[:, None] < rows.row_len.astype(jnp.int32)[None]
+  share = jnp.where(reads[..., None], share, 0.0)
+  at = rows.row_cols[:, :n].astype(jnp.int32).T                 # [n, B]
+  hot = np.arange(t, dtype=np.int32)[:, None] == at.reshape(1, -1)
+  return conv + jnp.dot(hot.astype(jnp.float32),
+                        share.reshape(-1, share.shape[-1]),
+                        precision=jax.lax.Precision.HIGHEST)
+
+
+def _ConvTailTokens(k: int, layers: int) -> ragged.StepCount:
+  """The engine's counter of what `_PackedConv`'s second part reaches: the
+  tokens of a step that read their slot's tail (a row's first K - 1), times
+  the layers; over the step's live tokens, the share of the operand the
+  tails' path touches."""
+  return ragged.StepCount(("conv_tail_tokens",), lambda row_q_pos, row_len: (
+      layers * int(np.minimum(row_len, k - 1).sum()),), True)
 
 
 def _FreshTail(held_tail, rows):
@@ -438,16 +473,20 @@ def _FreshTail(held_tail, rows):
 
 def _PackedConvTail(u, tail, rows):
   """Every row's last K - 1 inputs after the step: the tail `_PackedConv`
-  read and this step's tokens u [T, C] together. -> [B, K - 1, C] f32."""
+  read and this step's tokens u [T, C] together. -> [B, K - 1, C] f32. A row
+  of n < K - 1 tokens keeps its tail's last K - 1 - n entries, moved up by n:
+  a select between the tail's K - 1 shifts, no gather along its own axis."""
   k = tail.shape[1] + 1
   t = u.shape[0]
   n = rows.row_len.astype(jnp.int32)[:, None]                    # [B, 1]
-  i = jnp.arange(k - 1, dtype=jnp.int32)[None]                   # [1, K-1]
-  at = n - (k - 1) + i                                           # in the row
+  at = n - (k - 1) + np.arange(k - 1, dtype=np.int32)[None]      # in the row
   cols = jnp.take_along_axis(
       rows.row_cols, jnp.clip(at, 0, rows.row_cols.shape[1] - 1), axis=1)
-  old = jnp.take_along_axis(tail, jnp.clip(n + i, 0, k - 2)[..., None],
-                            axis=1)
+  old = tail
+  for moved in range(1, k - 1):
+    up = jax.lax.pad(tail, np.zeros((), tail.dtype),
+                     ((0, 0, 0), (-moved, moved, 0), (0, 0, 0)))
+    old = jnp.where((n == moved)[..., None], up, old)
   return jnp.where((at >= 0)[..., None],
                    u[jnp.clip(cols, 0, t - 1)].astype(jnp.float32), old)
 
@@ -809,12 +848,14 @@ class Mamba2Layer(base_layer.BaseLayer):
 
   def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
     """The engine's counters (ragged.StackStepCounts): the live rows (each reads
-    and writes its whole state) and the one-token rows, times the layers."""
+    and writes its whole state), the one-token rows and the tokens that read a
+    convolution tail, times the layers."""
     del geometry
     return [ragged.StepCount(
         ("ssd_state_rows", "ssd_narrow_rows"), lambda row_q_pos, row_len: (
             layers * int((row_len > 0).sum()),
-            layers * int((row_len == 1).sum())), True)]
+            layers * int((row_len == 1).sum())), True),
+            _ConvTailTokens(self.p.conv_width, layers)]
 
   # -- the layer's arithmetic ------------------------------------------------
 
@@ -999,10 +1040,12 @@ class ShortConvLayer(base_layer.BaseLayer):
     return 4 * (self.p.conv_width - 1) * self.p.input_dim
 
   def StepCounts(self, geometry: ragged.StepGeometry, layers: int) -> list:
-    """The engine's counter (ragged.StackStepCounts): live rows x layers."""
+    """The engine's counters (ragged.StackStepCounts): live rows x layers,
+    and the tokens that read a tail."""
     del geometry
     return [ragged.StepCount(("conv_tail_rows",), lambda row_q_pos, row_len: (
-        layers * int((row_len > 0).sum()),), True)]
+        layers * int((row_len > 0).sum()),), True),
+            _ConvTailTokens(self.p.conv_width, layers)]
 
   def _Gates(self, th, x):
     """x [.., D] -> (u = B * X [.., D] f32, C [.., D] f32)."""

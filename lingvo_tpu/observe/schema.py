@@ -122,6 +122,13 @@ ENGINE_COUNTER_KEYS = (
     # (`StateBytesPerSlot`, once in and once out). The first is 0 on a stack
     # without such layers, the second on one without slot state
     "conv_tail_rows", "slot_state_bytes",
+    # the packed convolution of the Mamba-2 and gated short-convolution layers
+    # (core/ssm._PackedConv), counted when a step is dispatched: the tokens
+    # that read their slot's tail (a row's first K - 1 of the step:
+    # `sum(min(row_len, K - 1))`), times such layers. Over the step's live
+    # tokens times the layers: the share of the convolution's operand that
+    # the tails' path reaches. 0 on a stack without such layers
+    "conv_tail_tokens",
 )
 
 # Static engine configuration facts (set once at construction). `head_rows`:

@@ -831,11 +831,15 @@ def test_stats_count_the_page_writes_the_steps_plan_holds():
 # layer's combine on purpose (core/moe.py: one gather of the matmuls' rows, k
 # major, then mask, weights and sum); before it `smallthinker` was 3634 lines,
 # "4ab22d507292c1e1", `nemotron_h` 6136, "220727d9511a9186", `mistral4` 1374,
-# "df1550569c9ec9f4". No step without an expert layer moved.
+# "df1550569c9ec9f4". No step without an expert layer moved. `nemotron_h`'s
+# is PR 66's own tree: it changed the Mamba-2 layers' packed convolution on
+# purpose (core/ssm._PackedConv: one fused pass and the tails' share by a
+# one-hot product; `_PackedConvTail`: a select between the tail's shifts);
+# before it 6106 lines, "1b1bed999b069ddd". No step without such a layer moved.
 _PARENT_STEP = {
     "dense": (1290, "1876dbf11e99e5cf"),
     "smallthinker": (3612, "5a744b3068ca2ff2"),
-    "nemotron_h": (6106, "1b1bed999b069ddd"),
+    "nemotron_h": (5877, "3d62a2b3985911f7"),
     "brumby": (1608, "137c387cefa12e2f"),
     "mistral4": (1373, "6dd2bffb46b2b356"),
 }
@@ -869,9 +873,13 @@ def test_a_stack_with_no_whole_page_writer_lowers_the_parents_step(family):
 # PR 62 (`abe1377`) lowers it under JAX 0.9.0 on the CPU: lines of
 # `lower().as_text()` and the first 16 hex digits of its sha256. The test
 # below is the recipe: run it on a parent's tree to take a number again.
+# `phi4flash`'s is PR 66's own tree: its Mamba-1 layers share the packed
+# convolution that PR changed on purpose (core/ssm._PackedConv,
+# `_PackedConvTail`; before it 9184 lines, "a0b827fa28090b9b"); its attend
+# kernel's key, plan and body are what they were.
 _PARENT_KERNEL_STEP = {
     "dense": (2234, "e39ffac52564fa07"),
-    "phi4flash": (9184, "a0b827fa28090b9b"),
+    "phi4flash": (9012, "42a973303f39c898"),
     "mistral4": (2627, "709f2b404996366f"),
 }
 

@@ -293,10 +293,16 @@ def test_a_block_sequence_counts_the_pairs_of_its_share(tiny, served):
   # rows, and never the chunks of the prompts
   assert stats["ssd_narrow_rows"] % 9 == 0
   assert 0 < stats["ssd_narrow_rows"] < stats["ssd_state_rows"]
+  # a live row's first K - 1 = 3 tokens of a step read its convolution tail:
+  # one in a decode row, up to three in a chunk of a prompt
+  assert stats["conv_tail_tokens"] % 9 == 0
+  assert (stats["ssd_state_rows"] < stats["conv_tail_tokens"]
+          < 3 * stats["ssd_state_rows"])
   records = eng._recorder.Steps() if getattr(eng, "_recorder", None) else []
   for rec in records[-1:]:
     assert "ssd_state_rows" in rec.counters
     assert "ssd_narrow_rows" in rec.counters
+    assert "conv_tail_tokens" in rec.counters
     assert "moe_pairs_elsewhere" in rec.counters
 
 

@@ -377,13 +377,17 @@ def test_the_kernel_says_what_it_does_not_serve():
 # RE-TAKEN FROM PR 64's OWN TREE, which changes that kernel on purpose (a
 # decode row's program walks a span of its pages: the plan's list, the pools in
 # HBM, the span's pages in one block; 3464 / "6462fd6c086a8839" and 3506 /
-# "0e22dfab41628703" at PR 63): they hold later PRs to PR 64's text. The four
-# step programs above them are the parent's, untouched.
+# "0e22dfab41628703" at PR 63): they hold later PRs to PR 64's text. The
+# `nemotron_h` pin was RE-TAKEN FROM PR 66's OWN TREE, which changes its
+# Mamba-2 layers' packed convolution on purpose (`core/ssm._PackedConv`: the
+# tails' share by a one-hot product, no gather a tap; `_PackedConvTail`: a
+# select between the tail's shifts; 6106 / "1b1bed999b069ddd" at PR 63's
+# parent). The three step programs above it are PR 63's parent's, untouched.
 _PARENT = {
     "dense": (1290, "1876dbf11e99e5cf"),
     "smallthinker": (3612, "5a744b3068ca2ff2"),
     "trinity": (3921, "63ccd2a42e9e9e9e"),
-    "nemotron_h": (6106, "1b1bed999b069ddd"),
+    "nemotron_h": (5877, "3d62a2b3985911f7"),
     "grouped_kernel_full": (6021, "98c73ed9d51e02cb"),
     "grouped_kernel_window": (6095, "8e0508c01e516255"),
 }
@@ -675,11 +679,16 @@ def test_the_engine_counts_tails_and_slot_state(served):
   live = sum(int((n > 0).sum()) for _, n in probe.steps)
   assert stats["conv_tail_rows"] == 7 * live
   assert stats["slot_state_bytes"] == 7 * live * 2 * (2 * 48 * 4)
+  # a row's first K - 1 = 2 tokens of a step read its tail, in seven layers
+  assert stats["conv_tail_tokens"] == 7 * sum(
+      int(np.minimum(n, 2).sum()) for _, n in probe.steps)
+  assert 7 * live < stats["conv_tail_tokens"] < 2 * 7 * live
   tokens = sum(_PROMPTS["uneven_chunks_in_one_step"]) + sum(
       len(o) - 1 for o in outs)
   assert stats["moe_tokens_routed"] == 8 * 3 * tokens
   records = [r for r in eng.trace.Steps() if r.counters]
-  for name in ("conv_tail_rows", "slot_state_bytes", "moe_tokens_routed"):
+  for name in ("conv_tail_rows", "slot_state_bytes", "moe_tokens_routed",
+               "conv_tail_tokens"):
     assert name in records[-1].counters, name
   assert stats["layer_kinds"] == {
       "ShortConvLayer+TransformerFeedForwardLayer": 1,
@@ -698,7 +707,8 @@ def test_a_stack_without_tails_counts_none():
     eng.StepOnce()
   stats = eng.Stats()
   assert stats["conv_tail_rows"] == stats["slot_state_bytes"] == 0
-  assert all("conv_tail_rows" not in (r.counters or {})
+  assert stats["conv_tail_tokens"] == 0
+  assert all(not {"conv_tail_rows", "conv_tail_tokens"} & set(r.counters or {})
              for r in eng.trace.Steps())
 
 
@@ -720,5 +730,5 @@ def test_the_step_program_enters_the_scopes(tiny):
     assert scope in text, scope
   assert schema.DEVICE_SCOPES["short_conv"][0] == "atten"
   assert schema.DEVICE_SCOPES["short_conv_taps"][0] == "short_conv"
-  assert {"conv_tail_rows", "slot_state_bytes"} <= set(
+  assert {"conv_tail_rows", "slot_state_bytes", "conv_tail_tokens"} <= set(
       schema.ENGINE_COUNTER_KEYS)

@@ -25,6 +25,7 @@ import pytest
 import lingvo_tpu
 from lingvo_tpu.core import mla as mla_lib
 from lingvo_tpu.core import ragged as ragged_lib
+from lingvo_tpu.core import ssm
 from lingvo_tpu.observe import schema as observe_schema
 from lingvo_tpu.ops import diff_attend
 from lingvo_tpu.ops import ragged_block_attend as rba
@@ -37,7 +38,10 @@ _MOE = {"moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
         "moe_experts_active", "moe_pairs_elsewhere"}
 _WINDOW = {"window_pages_released", "window_pages_allocated"}
 _SLOTS = {"ssm_tokens", "cross_tokens_unread"}
-_SSD = {"ssd_state_rows", "ssd_narrow_rows"}
+# (`conv_tail_tokens`: PR 66's, beside the parent's names wherever a stack
+# has a Mamba-2 or a gated short-convolution layer)
+_SSD = {"ssd_state_rows", "ssd_narrow_rows", "conv_tail_tokens"}
+_TAILS = {"conv_tail_rows", "slot_state_bytes", "conv_tail_tokens"}
 _PAIRS = {"attend_live_pairs", "attend_clear_pairs", "attend_programs"}
 # family -> (the keys of a step's record, `_attend_bq`) at the parent; with
 # `+kernels` the attend kernels' lowering is forced, as on the chip
@@ -51,12 +55,10 @@ _PARENT_RECORD = {
     "mistral4": (_MOE, 1024),
     "granite": (_MOE | _WINDOW | _SLOTS | _SSD, 512),
     "trinity": (_MOE | _WINDOW, 512),
-    "lfm2": (_MOE | _WINDOW | _SLOTS | {"conv_tail_rows", "slot_state_bytes"},
-             512),
+    "lfm2": (_MOE | _WINDOW | _SLOTS | _TAILS, 512),
     "dense+kernels": (set(), 8),
     "mistral4+kernels": (_MOE | _PAIRS, 1024),
-    "lfm2+kernels": (_MOE | _WINDOW | _SLOTS | _PAIRS
-                     | {"conv_tail_rows", "slot_state_bytes"}, 512),
+    "lfm2+kernels": (_MOE | _WINDOW | _SLOTS | _PAIRS | _TAILS, 512),
 }
 _FAMILIES = {
     **test_head_cols._FAMILIES, **test_head_cols._NEWER_FAMILIES,
@@ -196,6 +198,30 @@ def test_slot_state_bytes_ride_the_record_beside_a_tails_rows():
   assert _Totals(counts) == {"conv_tail_rows": 5 * 3,
                              "slot_state_bytes": 2 * (5 * 40 + 100) * 3}
   assert all(c.in_record for c in counts)
+
+
+@pytest.mark.parametrize("kind,k,layers", [
+    ("mamba2", 4, 9), ("mamba2", 2, 1), ("short_conv", 3, 7),
+    ("short_conv", 4, 2)])
+def test_the_tokens_that_read_a_tail_against_a_hand_count(kind, k, layers):
+  """`conv_tail_tokens`: a row's first K - 1 tokens of the step read its
+  slot's tail (`ssm._PackedConv`'s second part), times the layers. By hand
+  over the pack above: the decode row and the one-token prompt one each, the
+  chunk of five min(5, K - 1), the empty slot none."""
+  if kind == "mamba2":
+    p = ssm.Mamba2Layer.Params().Set(name="m", input_dim=16, num_heads=2,
+                                     head_dim=8, state_dim=4, conv_width=k)
+  else:
+    p = ssm.ShortConvLayer.Params().Set(name="c", input_dim=16, conv_width=k)
+  counts = p.Instantiate().StepCounts(_GEOMETRY, layers)
+  totals = _Totals(counts)
+  assert totals["conv_tail_tokens"] == layers * (1 + 1 + min(5, k - 1) + 0)
+  # beside the names the layer fed before, which count what they counted
+  rows = "ssd_state_rows" if kind == "mamba2" else "conv_tail_rows"
+  assert totals[rows] == layers * 3
+  assert all(c.in_record for c in counts)
+  # over the live tokens: the share of the operand the tails' path reaches
+  assert totals["conv_tail_tokens"] <= layers * int(_LEN.sum())
 
 
 def test_a_name_is_its_first_mixers():

@@ -11,7 +11,8 @@ CPU, times are not asserted.
   trace in one event,
 - the packed convolution of the Mamba layers (`core/ssm._PackedConv`,
   `_FreshTail`, `_PackedConvTail`) against a token-by-token numpy twin, and
-  on a prefix of the packed axis (what `ragged.OverLiveRows` runs it over),
+  on prefixes of the packed axis (what `ragged.OverLiveRows` runs it over);
+  its jaxpr gathers no array of T rows from the slots' tails (PR 66),
 - each tiny stack's whole first step stays under a ceiling of trace events
   that stands beside what the tree before the step's conditionals traced
   (core/ragged.OverLiveRows traces a row-wise block once a width),
@@ -213,31 +214,124 @@ def _NumpyConv(u, held, w, rows):
   return conv, new_tail
 
 
-@pytest.mark.parametrize("k", [2, 4])
-def test_the_packed_convolution_is_its_numpy_twin(k):
+# the packs above and, added to them, what the two parts of the sum meet at
+# their edges (6 rows, 40 columns)
+_CONV_PACKS = {
+    **_PACKS,
+    "a_row_shorter_than_the_tail": ([2, 1, 2, 0, 1, 2], [5, 9, 0, 0, 14, 3]),
+    "a_request_starts_beside_a_chunk_that_continues": (
+        [12, 12, 1, 0, 0, 1], [0, 24, 7, 0, 0, 0]),
+    "every_token_reads_a_tail": ([1] * 6, [4, 0, 9, 1, 30, 2]),
+    "padding_behind_the_live_tokens": ([3, 0, 2, 0, 0, 1],
+                                       [8, 0, 0, 5, 0, 11]),
+    "a_cut_that_splits_a_rows_head": ([2, 3, 9, 1, 0, 4],
+                                      [6, 2, 0, 17, 0, 3]),
+}
+# prefixes of the packed axis (W of `ragged.OverLiveRows`): 3 ends after the
+# first token of a row that has more, 6 is the decode-only step's (a token a
+# slot), 16 splits the chunks
+_CUTS = (3, 6, 16)
+
+
+@pytest.mark.parametrize("k", [2, 3, 4])
+@pytest.mark.parametrize("pack", list(_CONV_PACKS))
+def test_the_packed_convolution_is_its_numpy_twin(pack, k):
   rng = np.random.default_rng(k)
   fn = jax.jit(lambda u, held, w, rows: (
       ssm._PackedConv(u, held, w, rows),
       ssm._PackedConvTail(u, ssm._FreshTail(held, rows), rows)))
-  for lens, p0 in _PACKS.values():
-    rows = ragged_lib.BuildRaggedRows(lens, p0, 40, 32)
-    u = rng.normal(size=(40, 6)).astype(np.float32)
-    held = rng.normal(size=(6, k - 1, 6)).astype(np.float32)
-    w = rng.normal(size=(k, 6)).astype(np.float32)
-    conv, tail = fn(u, held, w, jax.tree_util.tree_map(jnp.asarray, rows))
-    want_conv, want_tail = _NumpyConv(u, held, w, rows)
-    live = np.flatnonzero(rows.valid)
-    np.testing.assert_allclose(np.asarray(conv)[live], want_conv[live],
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(tail), want_tail)
-    # a prefix of the packed axis gives that prefix of the sum
-    # (ragged.OverLiveRows runs it over the first W rows)
-    cut = rows._replace(row_of=jnp.asarray(rows.row_of[:16]),
-                        col_of=jnp.asarray(rows.col_of[:16]))
-    np.testing.assert_allclose(
-        np.asarray(ssm._PackedConv(jnp.asarray(u[:16]), jnp.asarray(held),
-                                   jnp.asarray(w), cut)),
-        np.asarray(conv)[:16], rtol=1e-5, atol=1e-5)
+  lens, p0 = _CONV_PACKS[pack]
+  rows = ragged_lib.BuildRaggedRows(lens, p0, 40, 32)
+  u = rng.normal(size=(40, 6)).astype(np.float32)
+  held = rng.normal(size=(6, k - 1, 6)).astype(np.float32)
+  # a slot the step does not hold keeps anything: nothing of it reaches a
+  # token, and its tail stays what it was
+  held[np.asarray(lens) == 0] = np.nan
+  w = rng.normal(size=(k, 6)).astype(np.float32)
+  conv, tail = fn(u, held, w, jax.tree_util.tree_map(jnp.asarray, rows))
+  want_conv, want_tail = _NumpyConv(u, held, w, rows)
+  live = np.flatnonzero(rows.valid)
+  np.testing.assert_allclose(np.asarray(conv)[live], want_conv[live],
+                             rtol=1e-5, atol=1e-5)
+  assert np.isfinite(np.asarray(conv)).all()      # the padding tokens' too
+  np.testing.assert_array_equal(np.asarray(tail), want_tail)
+  # a prefix of the packed axis gives that prefix of the sum
+  # (ragged.OverLiveRows runs it over the first W rows): the per-row fields
+  # whole, a tail's place beyond the prefix dropped
+  for cut in _CUTS:
+    cut_rows = rows._replace(row_of=jnp.asarray(rows.row_of[:cut]),
+                             col_of=jnp.asarray(rows.col_of[:cut]))
+    got = ssm._PackedConv(jnp.asarray(u[:cut]), jnp.asarray(held),
+                          jnp.asarray(w), cut_rows)
+    assert got.shape == (cut, 6) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(conv)[:cut],
+                               rtol=1e-5, atol=1e-5, err_msg=f"W = {cut}")
+
+
+def _KernelProbe():
+  """tools/kernel_probe.py as a module."""
+  import importlib.util
+  import os
+  spec = importlib.util.spec_from_file_location("kernel_probe", os.path.join(
+      os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools",
+      "kernel_probe.py"))
+  kernel_probe = importlib.util.module_from_spec(spec)
+  spec.loader.exec_module(kernel_probe)
+  return kernel_probe
+
+
+@pytest.mark.parametrize("k,slots", [(4, 8), (3, 16), (2, 8)])
+def test_the_packed_convolution_gathers_no_array_of_t_rows(k, slots):
+  """The mechanism of PR 66, on a CPU: only a row's first K - 1 tokens read
+  its slot's tail, so the sum at [T, C] builds no [T, C] array out of the
+  tails by a gather (the form it replaced gathered one a tap: the probe
+  keeps it, tools/kernel_probe.py `_ConvLoop`, and it is counted here beside
+  it, by the probe's own count); it stays f32 throughout."""
+  kernel_probe = _KernelProbe()
+  t, c = 72, 24
+  rows = jax.tree_util.tree_map(jnp.asarray, ragged_lib.BuildRaggedRows(
+      [1] * (slots - 1) + [40], list(range(3, 3 + slots)), t, 48))
+  operands = (jnp.zeros((t, c)), jnp.zeros((slots, k - 1, c)),
+              jnp.zeros((k, c), jnp.bfloat16), rows)
+  jaxpr = jax.make_jaxpr(ssm._PackedConv)(*operands)
+  assert kernel_probe._TokenRowGathers(jaxpr.jaxpr, t) == 0
+  assert [v.aval.dtype for v in jaxpr.jaxpr.outvars] == [jnp.float32]
+  assert jaxpr.out_avals[0].shape == (t, c)
+  floats = {v.aval.dtype for e in jaxpr.jaxpr.eqns for v in e.outvars
+            if jnp.issubdtype(v.aval.dtype, jnp.floating)
+            and v.aval.shape[-1:] == (c,) and v.aval.ndim > 1}
+  assert floats == {jnp.dtype(jnp.float32)}, floats
+  before = jax.make_jaxpr(
+      lambda *ops: kernel_probe._ConvLoop(ssm, *ops))(*operands)
+  assert kernel_probe._TokenRowGathers(before.jaxpr, t) == k - 1
+
+
+def test_the_kernel_probe_runs_the_packed_convolution(capsys):
+  """tools/kernel_probe.py --case packed_conv at the CPU's rehearsal sizes:
+  one layer's scope through the form PR 66 replaced, the tree's and a form
+  that lost, each held to the first; counts, never a time."""
+  import json
+  kernel_probe = _KernelProbe()
+  assert kernel_probe.main(["--case", "packed_conv", "--tiny", "--calls", "1",
+                            "--shapes", "granite,lfm2", "--variants",
+                            "loop,tree,scatter_add"]) == 0
+  lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+           if l.startswith("{")]
+  assert [(l["shape"], l["step"], l["variant"]) for l in lines] == [
+      (shape, step, variant) for shape in ("granite", "lfm2")
+      for step in ("decode", "chunk")
+      for variant in ("loop", "tree", "scatter_add")]
+  for l in lines:
+    assert l["tiny"] and l["device"]["platform"] == "cpu"
+    assert l["ms_a_layer"] is None
+    k = l["k"]
+    assert (k, l["slots"]) == ((4, 4) if l["shape"] == "granite" else (3, 4))
+    assert l["token_row_gathers"] == (k - 1 if l["variant"] == "loop" else 0)
+    # decode: a token a slot; chunk: three such rows beside a chunk of 24
+    assert l["conv_tail_tokens"] == (4 if l["step"] == "decode"
+                                     else 3 + (k - 1))
+    if l["variant"] != "loop":
+      assert l["within_1e-5"] and l["tail_equal_first"]
 
 
 # -- a whole step --------------------------------------------------------------

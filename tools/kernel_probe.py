@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""ONE layer's call of a kernel alone, at a cell's shapes. Four cases.
+"""ONE layer's call of a kernel alone, at a cell's shapes. Five cases.
 
 `--case page_write` (the default): the whole-page write
 (ops/diff_attend.WritePages) at the shapes `phi4flash_serve_reason` runs it
@@ -117,6 +117,59 @@ the host's count of the step's pairs (`LivePairs` of the one-token rows and
 of the chunk), us a decode pair (the decode step's) and us a chunk pair (the
 chunk step's time less its one-token rows' pairs at the decode step's price).
 
+`--case packed_conv`: ONE layer's convolution scope (`ssd_conv`, `ssm_conv`,
+`short_conv_taps`: core/ssm._PackedConv over the packed axis, what the layer
+does with the sum before it hands it on, and `_PackedConvTail`) at the four
+cells that run it: `--shapes granite` (C 8448 = E 8192 + 2 x 128, K 4, 64
+slots, a pack of 1,088; bias, silu and the split into u, B, C in f32),
+`nemotron` (C 6144, the same), `lfm2` (C 2048, K 3, 256 slots, a pack of
+1,280; the gate's product, cast to bf16) and `phi4flash` (C 5120, K 4, 64
+slots, a pack of 576; bias and silu, cast to bf16).
+
+  python3 tools/kernel_probe.py --case packed_conv
+      [--shapes granite,nemotron,lfm2,phi4flash] [--steps decode,chunk]
+      [--variants loop,tree,scatter_add,gather_share]
+      [--parent DIR] [--calls 50] [--seed 0] [--tiny]
+
+Steps: `decode` (every slot a one-token row, the pack cut to the B columns
+`ragged.OverLiveRows` runs such a step over: EVERY token reads a tail) and
+`chunk` (B - 1 such rows beside one chunk of the whole budget that continues a
+prompt, one slot starting a request). Variants, each `fn(ssm, u32, held_tail,
+conv_w, rows) -> [T, C] f32`: `loop` is the form PR 66 replaced, kept HERE as
+the reference (a pad, a gather of a `[T, C]` array from the tails and a select
+a tap); `tree` this tree's `_PackedConv`; `parent` DIR/lingvo_tpu/core/ssm.py's
+`_PackedConv` AND its `_PackedConvTail`; the forms that lost lay the same
+tails' share `[B, K - 1, C]` into the same fused sum another way:
+`scatter_add` (`.at[places].add`) and `gather_share` (ONE gather of T rows
+from the share; a third, the share scattered into zeros and added in the
+pass, read 0.48 / 0.26 / 0.14 / 0.13 ms at the four chunk steps and is gone). A form under
+trial is registered in `CONV_VARIANTS`. Every variant's sum is held to the
+first's within the f32 sum's reordering (1e-5 of the largest magnitude, live
+tokens) and its new tails to equality; `token_row_gathers` counts the gathers
+of T rows in its jaxpr. The time is a loop of `--calls` trips, each over one
+of FOUR layers' inputs by the trip's index and the tails the trip before
+left; `once_ms_at_819gb_s` is the operand read once in bf16 and the scope's
+result written once, at the chip's HBM speed. `--tiny` rehearses on the CPU
+(counts, and no time at all).
+
+The rows that settled PR 66 (one v5e, ms a layer, `parent` -> `tree`, the
+final tree; granite's chunk step is the one its cell runs nine times a step):
+granite chunk 0.985 -> 0.268, decode 0.118 -> 0.064; nemotron 0.481 -> 0.201
+and 0.096 -> 0.057; lfm2 0.139 -> 0.102 and 0.079 -> 0.055; phi4flash 0.203 ->
+0.086 and 0.073 -> 0.042. What lost, in the same call: `scatter_add` 0.286 at
+granite's chunk but 0.384 at nemotron's (its scatter 208 us there for 46 at
+granite's, the same 192 update rows: 0.2-1.1 us a row, by a rule of XLA's that
+the shapes do not tell), 0.170 at lfm2's (512 update rows; OVER the parent's
+0.139) and over the parent at three of the four decode steps; `gather_share`
+0.506 at granite's chunk (one gather of 1,088 rows of 33 KB: 111 us, and a
+layout XLA then chose worse), level with `tree` at the decode steps and 0.005
+ahead at lfm2's chunk. A one-hot product costs what its FLOPs cost (T x (K -
+1) B x C, three bf16 passes: 36-65 us a layer), fuses into the pass, and is
+the same at every shape: one form, no choice by shape. Of a decode step's
+scope two thirds were `_PackedConvTail` (a 26 us gather along the tail's own
+axis, relayouts of `[B, K - 1, C]`), which is why it was touched too, and why
+the share is built entry-major (`[K - 1, B, C]`).
+
 Its readings are a builder's, never the ledger's: one call in a loop has no
 neighbours to share the chip's memory system with, and no step round it.
 """
@@ -153,10 +206,10 @@ VARIANTS["scatter"] = _Lowered("xla")
 VARIANTS["kernel"] = _Lowered("pallas")
 
 
-def _ParentModule(root, name="diff_attend"):
-  """DIR/lingvo_tpu/ops/<name>.py under a name of its own; what it imports
-  is this tree's."""
-  path = os.path.join(root, "lingvo_tpu", "ops", name + ".py")
+def _ParentModule(root, name="diff_attend", package="ops"):
+  """DIR/lingvo_tpu/<package>/<name>.py under a name of its own; what it
+  imports is this tree's."""
+  path = os.path.join(root, "lingvo_tpu", package, name + ".py")
   spec = importlib.util.spec_from_file_location("parent_" + name, path)
   module = importlib.util.module_from_spec(spec)
   spec.loader.exec_module(module)
@@ -641,6 +694,245 @@ def AttendMain(args) -> int:
   return 0 if all(l["bitwise_the_first"] is not False for l in lines) else 1
 
 
+# -- the packed convolution ----------------------------------------------------
+
+# channels, taps, slots, prefill budget, what follows the sum in the scope
+# (benchmarks/configs/<cell>.json): `ssd` is Mamba2Layer's bias, silu and split
+# into u [E], B and C; `silu` Mamba1Layer's bias and silu, cast for `w_x`;
+# `gate` ShortConvLayer's product with C, cast for `w_out`
+CONV_SHAPES = {"granite": (8448, 4, 64, 1024, ("ssd", 8192)),
+               "nemotron": (6144, 4, 64, 1024, ("ssd", 4096)),
+               "lfm2": (2048, 3, 256, 1024, ("gate",)),
+               "phi4flash": (5120, 4, 64, 512, ("silu",))}
+CONV_LAYERS = 4   # a trip reads one of as many layers' inputs, by its index
+
+
+def _ConvLoop(ssm, u32, held_tail, conv_w, rows):
+  """The sum as it stood before PR 66: the reference. A loop over the
+  earlier taps, each a pad of the operand, a gather of a [T, C] array from
+  the slots' tails, and a select between the two."""
+  import jax.numpy as jnp
+  k, t = conv_w.shape[0], u32.shape[0]
+  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, held_tail.shape[0] - 1)
+  col = rows.col_of.astype(jnp.int32)
+  tail = ssm._FreshTail(held_tail, rows)
+  w = conv_w.astype(jnp.float32)
+  conv = w[k - 1] * u32
+  for back in range(1, k):
+    here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
+    held = tail[row, jnp.clip(k - 1 - back + col, 0, k - 2)]
+    conv += w[k - 1 - back] * jnp.where((col >= back)[:, None], here, held)
+  return conv
+
+
+def _ConvParts(ssm, u32, held_tail, conv_w, rows):
+  """-> (the step's own tokens' sum [T, C]; the tails' share [B, n, C]; the
+  share's places on the packed axis [B, n], T where a row has no such token),
+  what every form below lays together."""
+  import jax.numpy as jnp
+  import numpy as np
+  k, t = conv_w.shape[0], u32.shape[0]
+  col = rows.col_of.astype(jnp.int32)[:, None]
+  w = conv_w.astype(jnp.float32)
+  conv = w[k - 1] * u32
+  for back in range(1, k):
+    here = jnp.pad(u32, ((back, 0), (0, 0)))[:t]
+    conv += jnp.where(col >= back, w[k - 1 - back] * here, 0.0)
+  n = min(k - 1, rows.row_cols.shape[1])
+  tail = ssm._FreshTail(held_tail, rows)
+  share = jnp.stack([sum(w[i - j] * tail[:, i] for i in range(j, k - 1))
+                     for j in range(n)], axis=1)
+  reads = np.arange(n)[None] < rows.row_len.astype(jnp.int32)[:, None]
+  at = jnp.where(reads, rows.row_cols[:, :n].astype(jnp.int32), t)
+  return conv, share, at
+
+
+def _ConvScatterAdd(ssm, *operands):
+  """The share added at its places in the own tokens' sum."""
+  conv, share, at = _ConvParts(ssm, *operands)
+  return conv.at[at].add(share, mode="drop")
+
+
+def _ConvGatherShare(ssm, u32, held_tail, conv_w, rows):
+  """ONE gather of T rows from the share (a token's row and column), selected
+  where the token reads a tail: what is cheap where T <= B (K - 1)."""
+  import jax.numpy as jnp
+  conv, share, _ = _ConvParts(ssm, u32, held_tail, conv_w, rows)
+  n = share.shape[1]
+  row = jnp.clip(rows.row_of.astype(jnp.int32), 0, share.shape[0] - 1)
+  col = rows.col_of.astype(jnp.int32)
+  return conv + jnp.where((col < n)[:, None],
+                          share[row, jnp.clip(col, 0, n - 1)], 0.0)
+
+
+CONV_VARIANTS = {"loop": _ConvLoop,
+                 "tree": lambda ssm, *operands: ssm._PackedConv(*operands),
+                 "scatter_add": _ConvScatterAdd,
+                 "gather_share": _ConvGatherShare}
+
+
+def ConvInputs(shape: str, step: str, seed: int, tiny: bool):
+  """(u bf16 [L, T, C], tails f32 [B, K - 1, C], w [K, C], bias [C], gate
+  bf16 [T, C], rows), what follows the sum, and the rows' lengths, of
+  CONV_LAYERS layers' scopes in a `step` step at `shape`, seeded. `decode`: every slot a one-token row, the
+  pack cut to the B columns `ragged.OverLiveRows` runs such a step over
+  (every token reads a tail); `chunk`: B - 1 such rows beside one chunk of
+  the whole budget that continues a prompt, on the whole pack, one slot of
+  them starting a request."""
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+  from lingvo_tpu.core import ragged as ragged_lib
+  c, k, slots, budget, after = CONV_SHAPES[shape]
+  if tiny:
+    after = ("ssd", 192) if after[0] == "ssd" else after
+    c, slots, budget = 256, 4, 24
+  rng = np.random.RandomState(seed)
+  lens = np.ones(slots, np.int64)
+  context = rng.randint(1, 4096, size=slots)
+  context[0] = 0
+  t = slots + budget
+  if step == "chunk":
+    lens[slots // 2], context[slots // 2] = budget, 2 * budget
+  else:
+    assert step == "decode", step
+  built = ragged_lib.BuildRaggedRows(lens, context, t, budget)
+  if step == "decode":
+    t = ragged_lib.DecodeWidth(t, budget)
+    assert t == slots
+    built = built._replace(row_of=built.row_of[:t], col_of=built.col_of[:t])
+  rows = ragged_lib.RaggedRows(*(jnp.asarray(m) for m in built))
+  keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+  u = jax.random.normal(keys[0], (CONV_LAYERS, t, c), jnp.bfloat16)
+  tails = jax.random.normal(keys[1], (slots, k - 1, c), jnp.float32)
+  w = jax.random.normal(keys[2], (k, c), jnp.bfloat16)
+  bias = jax.random.normal(keys[3], (c,), jnp.bfloat16)
+  gate = jax.random.normal(keys[4], (t, c), jnp.bfloat16)
+  return (u, tails, w, bias, gate, rows), after, lens
+
+
+def ConvScope(ssm, conv_fn, after):
+  """-> scope(u bf16 [T, C], tails, w, bias, gate, rows) -> (what the scope
+  hands on, the new tails): one layer's convolution scope, the sum through
+  `conv_fn`."""
+  import jax
+  import jax.numpy as jnp
+
+  def _Scope(u, tails, w, bias, gate, rows):
+    with jax.named_scope("packed_conv"):
+      conv = conv_fn(ssm, u.astype(jnp.float32), tails, w, rows)
+      if after[0] == "ssd":
+        e = after[1]
+        gn = (conv.shape[1] - e) // 2
+        xbc = jax.nn.silu(conv + bias.astype(jnp.float32))
+        out = (xbc[:, :e].reshape(-1, e // 64, 64), xbc[:, e:e + gn],
+               xbc[:, e + gn:])
+      elif after[0] == "silu":
+        out = (jax.nn.silu(conv + bias.astype(jnp.float32))
+               .astype(jnp.bfloat16),)
+      else:
+        out = ((gate.astype(jnp.float32) * conv).astype(jnp.bfloat16),)
+      new = ssm._PackedConvTail(u, ssm._FreshTail(tails, rows), rows)
+    return out, new
+
+  return _Scope
+
+
+def ConvMain(args) -> int:
+  import jax
+  import jax.numpy as jnp
+  from lingvo_tpu.core import compile_cache
+  from lingvo_tpu.core import ssm
+
+  compile_cache.Configure()
+  assert jax.default_backend() == "tpu" or args.tiny, (
+      "a time comes from the chip; --tiny rehearses")
+  modules = ({"parent": _ParentModule(args.parent, "ssm", "core")}
+             if args.parent else {})
+  names = args.variants.split(",") + list(modules)
+  device = jax.devices()[0]
+  lines = []
+  for shape in args.shapes.split(","):
+    for step in args.steps.split(","):
+      (u, tails, w, bias, gate, rows), after, lens = ConvInputs(
+          shape, step, args.seed, args.tiny)
+      k = w.shape[0]
+      first = None
+      for name in names:
+        module = modules.get(name, ssm)
+        conv_fn = CONV_VARIANTS["tree" if name == "parent" else name]
+        scope = ConvScope(module, conv_fn, after)
+        conv = jax.jit(lambda u, *rest, fn=conv_fn, m=module: fn(
+            m, u.astype(jnp.float32), *rest))(u[0], tails, w, rows)
+        _, new = jax.jit(scope)(u[0], tails, w, bias, gate, rows)
+        live = (jnp.arange(conv.shape[0]) < int(lens.sum()))[:, None]
+        conv = jnp.where(live, conv, 0.0)
+        if first is None:
+          first, err, same = (conv, new), None, None
+        else:
+          err = float(jnp.max(jnp.abs(conv - first[0]))
+                      / jnp.max(jnp.abs(first[0])))
+          same = bool(jnp.array_equal(new, first[1]))
+        gathers = _TokenRowGathers(jax.make_jaxpr(
+            lambda *ops, fn=conv_fn, m=module: fn(m, *ops))(
+                u[0].astype(jnp.float32), tails, w, rows).jaxpr,
+                                   conv.shape[0])
+
+        def _Run(carry, u, w, bias, gate, rows, scope=scope):
+          def _Trip(i, carry):
+            # the trip's layer by its index, the tails the trip's before
+            # left: nothing is the loop's to hoist
+            return scope(jax.lax.dynamic_index_in_dim(
+                u, i % CONV_LAYERS, keepdims=False), carry[1], w, bias, gate,
+                         rows)
+          return jax.lax.fori_loop(0, args.calls, _Trip, carry)
+
+        loop = jax.jit(_Run, donate_argnums=0)
+        carry = jax.jit(scope)(u[0], tails, w, bias, gate, rows)
+        carry = jax.block_until_ready(
+            loop(carry, u, w, bias, gate, rows))                # compiles
+        start = time.perf_counter()
+        jax.block_until_ready(loop(carry, u, w, bias, gate, rows))
+        ms = ((time.perf_counter() - start) * 1e3 / args.calls
+              if device.platform == "tpu" else None)    # a time is the chip's
+        del carry
+        t, c = conv.shape
+        out_bytes = 4 if after[0] == "ssd" else 2
+        lines.append({
+            "case": "packed_conv", "shape": shape, "step": step,
+            "variant": name, "ms_a_layer": ms,
+            "once_mb": t * c * (2 + out_bytes) / 1e6,
+            "once_ms_at_819gb_s": t * c * (2 + out_bytes) / 819e6,
+            "rel_err_to_first": err,
+            "within_1e-5": None if err is None else err <= 1e-5,
+            "tail_equal_first": same, "token_row_gathers": gathers,
+            "tokens": int(lens.sum()) if step == "chunk" else t,
+            "conv_tail_tokens": int(sum(min(int(n), k - 1) for n in lens)),
+            "t": t, "c": c, "k": k, "slots": int(tails.shape[0]),
+            "calls": args.calls, "seed": args.seed, "tiny": args.tiny,
+            "device": {"platform": device.platform,
+                       "kind": device.device_kind}})
+        print(json.dumps(lines[-1]), flush=True)
+      del first
+  _Append(args.out, lines)
+  return 0 if all(l["within_1e-5"] is not False
+                  and l["tail_equal_first"] is not False for l in lines) else 1
+
+
+def _TokenRowGathers(jaxpr, t: int) -> int:
+  """Gathers in `jaxpr` (and in what it calls) whose result has `t` rows: the
+  arrays of T rows built from the slots' tails."""
+  import jax
+  count = 0
+  for eqn in jaxpr.eqns:
+    shape = eqn.outvars[0].aval.shape
+    count += (eqn.primitive.name == "gather" and len(shape) > 1
+              and shape[0] == t)
+    count += sum(_TokenRowGathers(sub, t)
+                 for sub in jax.core.jaxprs_in_params(eqn.params))
+  return count
+
+
 def _Append(path, lines):
   if path:
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -653,7 +945,7 @@ def main(argv=None) -> int:
   ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
   ap.add_argument("--case", default="page_write",
                   choices=["page_write", "row_pass", "moe_combine",
-                           "grouped_attend"])
+                           "grouped_attend", "packed_conv"])
   ap.add_argument("--steps", default="decode,chunk")
   ap.add_argument("--variants", default="")
   ap.add_argument("--shapes", default="")
@@ -669,6 +961,10 @@ def main(argv=None) -> int:
     args.shapes = args.shapes or ",".join(ATTEND_SHAPES)
     args.variants = args.variants or ",".join(ATTEND_VARIANTS)
     return AttendMain(args)
+  if args.case == "packed_conv":
+    args.shapes = args.shapes or ",".join(CONV_SHAPES)
+    args.variants = args.variants or "loop,tree"
+    return ConvMain(args)
   if args.case == "moe_combine":
     args.shapes = args.shapes or ",".join(COMBINE_SHAPES)
     args.variants = args.variants or ",".join(COMBINE_VARIANTS)
